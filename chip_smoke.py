@@ -1,0 +1,437 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that paddle_tpu still runs on the chip.
+
+Drives the two main paths once, through the entry points a user calls, at
+the full width of one supported model each, on ONE TPU (everything runs in
+this one process — a chip belongs to one process at a time):
+
+  A  trainer  ResNet-50 (224x224, 1000 classes, s2d stem, bf16), batch 256:
+              startup, 5 Executor.run steps on one fixed batch, then one
+              run_steps(steps=4) dispatch. Loss finite and lower at the end,
+              state on the device, peak device memory printed.
+  C  (only with >= 4 devices) the same program under ParallelExecutor on
+              four chips, global batch 1024, 3 steps: every chip holds its
+              share and nothing is parked on device 0.
+  B  server   Transformer-base decoder (vocab 32000, d_model 512, 8 heads,
+              6 layers, d_ff 2048; 8 slots, cache 512, block-paged):
+              export_decode -> DecodingPredictor, 8 concurrent prompts
+              against sequential generate(); then a second predictor on the
+              same artifact must load the AOT sidecars and agree.
+  K  kernels  fused_multihead_attention where the policy picks the Pallas
+              flash kernel (non-causal S=512, causal S=4096), forward and
+              backward, against a plain float32 jax.numpy composition.
+
+Weights and data are random from fixed seeds; depth is what the builders
+give. One JSON line per phase (platform, device_kind, device count, cache
+directory, XLA compiles and cache hits inside the phase, seconds), non-zero
+exit at the first failure, and as the LAST stdout line
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+Without a TPU it fails, naming the platforms jax did find, and prints no
+result. Seconds printed here are set-up information, not benchmark numbers.
+
+--cpu-rehearsal runs the same code at toy sizes on the host cpu for
+debugging: every line says "platform": "cpu" and the last line says
+"ok": false, so it cannot be read as a chip pass.
+
+The compile cache lives at $JAX_COMPILATION_CACHE_DIR when set, else at the
+fixed <checkout>/.compile_cache; artifacts go under --out
+(default <checkout>/chip_smoke_out). No network, no git, no child process.
+"""
+import argparse
+import gc
+import json
+import os
+import re
+import sys
+import time
+import warnings
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+
+FULL = {
+    'resnet': dict(dshape=(3, 224, 224), class_dim=1000, depth=50,
+                   batch=256),
+    'decode': dict(vocab=32000, d_model=512, n_head=8, n_layer=6, d_ff=2048,
+                   max_slots=8, max_cache_len=512, block_size=16,
+                   chunk_sizes=(32, 128)),
+    'prompt_lens': (8, 128), 'max_new': 32,
+    # (B, H, S, D, causal): one shape on each side of _flash_policy
+    'attn': ((2, 4, 512, 64, False), (1, 2, 4096, 64, True)),
+}
+TOY = {
+    'resnet': dict(dshape=(3, 32, 32), class_dim=10, depth=50, batch=8),
+    'decode': dict(vocab=128, d_model=32, n_head=4, n_layer=2, d_ff=64,
+                   max_slots=8, max_cache_len=64, block_size=8,
+                   chunk_sizes=(8, 16)),
+    'prompt_lens': (4, 24), 'max_new': 8,
+    'attn': ((1, 2, 512, 64, False),),
+}
+# a phase that warns one of these did not run the path it claims to prove
+FALLBACK = re.compile(r'fall(ing|s)? back|fallback|unusable|unavailable',
+                      re.I)
+
+
+class Smoke(object):
+    def __init__(self, cfg, out_dir, dev, n_dev):
+        from paddle_tpu.core import compile_cache
+        self.cfg, self.out_dir, self.dev, self.n_dev = cfg, out_dir, dev, n_dev
+        self.cc = compile_cache
+
+    def phase(self, name, fn):
+        """Run one phase; print its JSON line; exit non-zero if it failed
+        or warned a fallback."""
+        s0, t0 = self.cc.stats(), time.perf_counter()
+        line = {'phase': name, 'ok': False,
+                'platform': self.dev.platform,
+                'device_kind': self.dev.device_kind,
+                'device_count': self.n_dev,
+                'cache_dir': self.cc.cache_dir()}
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter('always')
+            try:
+                line.update(fn() or {})
+                line['ok'] = True
+            except Exception as e:     # the phase boundary: report, exit
+                import traceback
+                traceback.print_exc()
+                line['error'] = '%s: %s' % (type(e).__name__,
+                                            str(e).splitlines()[0][:300]
+                                            if str(e) else '')
+        msgs = sorted({str(w.message)[:200] for w in caught})
+        bad = [m for m in msgs if FALLBACK.search(m)]
+        if bad:
+            line['ok'] = False
+            line['fallback_warnings'] = bad
+        elif msgs:
+            line['warnings'] = msgs[:5]
+        s1 = self.cc.stats()
+        compiles = s1['xla_compiles'] - s0['xla_compiles']
+        hits = s1['xla_pcache_hits'] - s0['xla_pcache_hits']
+        line.update(
+            # every backend compile request in the phase, how many of them
+            # jax's persistent cache answered, and what was really compiled
+            xla_compiles=compiles, xla_cache_hits=hits,
+            xla_compiles_net=compiles - hits,
+            exec_tier_hits=s1['exec_hits'] - s0['exec_hits'],
+            seconds=round(time.perf_counter() - t0, 2))
+        print(json.dumps(line), flush=True)
+        with open(os.path.join(self.out_dir, 'lines.jsonl'), 'a') as f:
+            f.write(json.dumps(line) + '\n')
+        if not line['ok']:
+            sys.exit(1)
+        return line
+
+    # -- the trainer -------------------------------------------------------
+    def _resnet(self, fluid):
+        """ResNet-50 train program + startup in a fresh scope, bf16."""
+        from models.resnet import build_train_net
+        r = self.cfg['resnet']
+        main, startup = fluid.Program(), fluid.Program()
+        main.random_seed = startup.random_seed = 7
+        with fluid.program_guard(main, startup), fluid.unique_name.guard():
+            # lr: "loss lower at the end" on ONE fixed random batch needs a
+            # step that does not overshoot; the builder's 0.1 does (full
+            # width on cpu, batch 32: 7.36 -> 3.84 -> ... -> 17.1 -> 9.12)
+            _, _, loss, _ = build_train_net(
+                dshape=r['dshape'], class_dim=r['class_dim'],
+                depth=r['depth'], imagenet=True, s2d_stem=True, lr=0.01)
+        fluid.contrib.mixed_precision.enable_bf16(main)
+        return main, startup, loss
+
+    def _batch(self, n):
+        import numpy as np
+        r = self.cfg['resnet']
+        rng = np.random.RandomState(0)
+        return {'data': rng.randn(n, *r['dshape']).astype(np.float32),
+                'label': rng.randint(0, r['class_dim'],
+                                     (n, 1)).astype(np.int64)}
+
+    def phase_a(self):
+        import numpy as np
+        import paddle_tpu as fluid
+        main, startup, loss = self._resnet(fluid)
+        feed = self._batch(self.cfg['resnet']['batch'])
+        scope = fluid.core.Scope()
+        place = (fluid.TPUPlace() if self.dev.platform == 'tpu'
+                 else fluid.CPUPlace())
+        exe = fluid.Executor(place)
+        with fluid.scope_guard(scope):
+            t0 = time.perf_counter()
+            exe.run(startup)
+            losses = [float(np.asarray(exe.run(
+                main, feed=feed, fetch_list=[loss])[0]).reshape(-1)[0])]
+            first_step_s = time.perf_counter() - t0
+            for _ in range(4):
+                losses.append(float(np.asarray(exe.run(
+                    main, feed=feed, fetch_list=[loss])[0]).reshape(-1)[0]))
+            k_feed = {n: np.stack([v] * 4) for n, v in feed.items()}
+            t0 = time.perf_counter()
+            out = exe.run_steps(main, feed=k_feed, fetch_list=[loss],
+                                steps=4)
+            losses.append(float(np.asarray(out[0]).reshape(-1)[0]))
+            run_steps_s = time.perf_counter() - t0
+            if not all(np.isfinite(losses)):
+                raise AssertionError('non-finite loss: %s' % losses)
+            if not losses[-1] < losses[0]:
+                raise AssertionError('loss did not fall: %s' % losses)
+            where = set()
+            for name in scope.local_var_names():
+                val = scope.get(name)
+                if hasattr(val, 'devices'):
+                    where |= {d.platform for d in val.devices()}
+            if where != {self.dev.platform}:
+                raise AssertionError('state lives on %s, not on %s'
+                                     % (sorted(where), self.dev.platform))
+        exe.close()
+        st = self.dev.memory_stats()       # None on cpu
+        return {'losses': [round(l, 4) for l in losses],
+                'first_step_s': round(first_step_s, 2),
+                'run_steps_s': round(run_steps_s, 2),
+                'peak_bytes_in_use':
+                    int(st['peak_bytes_in_use']) if st else None}
+
+    def phase_c(self):
+        """Phase A's program over four chips: global batch 4x, 3 steps.
+        Every device must end up holding its share of state (bytes_in_use
+        non-zero, within 2x of the others), and the batch must be SHARDED.
+        memory_stats() counts buffers, not a program's scratch (phase A
+        peaks at ~1 GB with a 616 MB stacked feed), so on devices 1-3 —
+        whose peak is this phase's alone — peak minus what stays resident
+        is the feed plus at most one more copy of the state: under
+        state + half the global feed when each holds a quarter of the
+        batch, over it when each holds all of it."""
+        import jax
+        import numpy as np
+        import paddle_tpu as fluid
+        gc.collect()               # phase A's state must be off device 0
+        main, startup, loss = self._resnet(fluid)
+        feed = self._batch(4 * self.cfg['resnet']['batch'])
+        scope = fluid.core.Scope()
+        with fluid.scope_guard(scope):
+            fluid.Executor().run(startup)
+            pe = fluid.ParallelExecutor(use_cuda=False, loss_name=loss.name,
+                                        main_program=main, scope=scope)
+            if pe.device_count != self.n_dev:
+                raise AssertionError('mesh has %d devices, jax sees %d'
+                                     % (pe.device_count, self.n_dev))
+            losses = [float(np.asarray(pe.run(
+                [loss.name], feed=feed)[0]).reshape(-1)[0])
+                for _ in range(3)]
+            if not all(np.isfinite(losses)):
+                raise AssertionError('non-finite loss: %s' % losses)
+            devs = jax.devices()
+            stats = [d.memory_stats() for d in devs]
+        out = {'losses': [round(l, 4) for l in losses],
+               'mesh_devices': pe.device_count}
+        if all(stats):                 # cpu reports none
+            in_use = [int(s['bytes_in_use']) for s in stats]
+            peaks = [int(s['peak_bytes_in_use']) for s in stats]
+            out.update(bytes_in_use=in_use, peak_bytes_in_use=peaks)
+            if min(in_use) <= 0 or max(in_use) > 2 * min(in_use):
+                raise AssertionError('state is not spread over the chips: '
+                                     'bytes_in_use %s' % in_use)
+            feed_bytes = sum(v.nbytes for v in feed.values())
+            for use, peak in list(zip(in_use, peaks))[1:]:
+                if peak - use > use + feed_bytes // 2:
+                    raise AssertionError(
+                        'feeds were not batch-sharded: peak %s, resident '
+                        '%s, global feed %d bytes'
+                        % (peaks, in_use, feed_bytes))
+        return out
+
+    # -- the server --------------------------------------------------------
+    def phase_b(self):
+        import numpy as np
+        import paddle_tpu as fluid
+        from models.transformer import build_decode_spec
+        from paddle_tpu.inference import DecodingPredictor, export_decode
+        d = self.cfg['decode']
+        art = os.path.join(self.out_dir, 'decode_art')
+        scope = fluid.core.Scope()
+        with fluid.scope_guard(scope), fluid.unique_name.guard():
+            spec = build_decode_spec(eos_id=1, **d)
+            spec['startup'].random_seed = 11
+            fluid.Executor().run(spec['startup'], scope=scope)
+            t0 = time.perf_counter()
+            export_decode(spec, art, scope=scope)
+            export_s = time.perf_counter() - t0
+        del scope, spec
+        gc.collect()
+        module_bytes = sidecar_bytes = 0
+        for root, _, names in os.walk(art):
+            for n in names:
+                size = os.path.getsize(os.path.join(root, n))
+                if n.endswith('.jaxexport'):
+                    module_bytes += size
+                elif n.endswith('.jaxexec'):
+                    sidecar_bytes += size
+        rng = np.random.RandomState(3)
+        lo, hi = self.cfg['prompt_lens']
+        lens = [lo, hi] + [int(x) for x in rng.randint(lo, hi + 1, 6)]
+        prompts = [rng.randint(2, d['vocab'], n) for n in lens]
+        max_new = self.cfg['max_new']
+
+        def concurrent(pred):
+            streams = [pred.submit(p, max_new_tokens=max_new)
+                       for p in prompts]
+            return [list(s.result(600)) for s in streams]
+
+        with DecodingPredictor(art) as pred:
+            t0 = time.perf_counter()
+            pred.warmup()
+            warmup_s = time.perf_counter() - t0
+            con = concurrent(pred)
+            # both arms start from an empty prefix cache (bench.py's rule):
+            # else the sequential arm re-serves prompts the first arm cached
+            pred.block_manager.evict_all_prefixes()
+            seq = [list(pred.generate(p, max_new_tokens=max_new,
+                                      timeout=600)) for p in prompts]
+        if con != seq:
+            raise AssertionError('continuous transcripts diverged from '
+                                 'sequential generate()')
+        # a fresh replica on the same artifact: loads the AOT sidecars (a
+        # "falling back to compiling" warning fails the phase), compiles
+        # nothing, and serves the same transcripts
+        s0 = self.cc.stats()
+        t0 = time.perf_counter()
+        with DecodingPredictor(art) as pred2:
+            pred2.warmup()
+            reload_s = time.perf_counter() - t0
+            again = concurrent(pred2)
+        s1 = self.cc.stats()
+        reload_compiles = s1['xla_compiles_net'] - s0['xla_compiles_net']
+        if again != con:
+            raise AssertionError('reloaded predictor transcripts differ')
+        if reload_compiles:
+            raise AssertionError('reloaded predictor compiled %d program(s)'
+                                 % reload_compiles)
+        return {'prompt_lens': lens,
+                'tokens': sum(len(t) for t in con),
+                'export_s': round(export_s, 2),
+                'module_bytes': module_bytes, 'sidecar_bytes': sidecar_bytes,
+                'warmup_s': round(warmup_s, 2),
+                'reload_s': round(reload_s, 2),
+                'reload_xla_compiles': reload_compiles}
+
+    # -- the kernels -------------------------------------------------------
+    def phase_k(self):
+        """The op through the Executor on the device (the Pallas kernel on
+        a TPU: the policy picks it at these shapes and the op raises if the
+        kernel is refused), forward and backward, against a plain float32
+        jax.numpy composition."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        import paddle_tpu as fluid
+        from paddle_tpu.ops.nn_ops import _flash_policy
+
+        def ref(q, k, v, causal, scale):
+            s = jnp.einsum('bhqd,bhkd->bhqk', q * scale, k,
+                           precision='highest')
+            if causal:
+                n = q.shape[2]
+                s = jnp.where(jnp.tril(jnp.ones((n, n), bool)), s, -1e30)
+            return jnp.einsum('bhqk,bhkd->bhqd', jax.nn.softmax(s, -1), v,
+                              precision='highest')
+
+        place = (fluid.TPUPlace() if self.dev.platform == 'tpu'
+                 else fluid.CPUPlace())
+        errs = {}
+        for B, H, S, D, causal in self.cfg['attn']:
+            if not _flash_policy(S, causal)[0]:
+                raise AssertionError('policy does not pick flash at S=%d '
+                                     'causal=%s' % (S, causal))
+            main = fluid.Program()
+            with fluid.program_guard(main, fluid.Program()), \
+                    fluid.unique_name.guard():
+                q, k, v = (fluid.layers.data(name=n, shape=[H, S, D],
+                                             dtype='float32')
+                           for n in 'qkv')
+                for var in (q, k, v):
+                    var.stop_gradient = False
+                out = fluid.layers.fused_multihead_attention(
+                    q, k, v, causal=causal, scale=D ** -0.5)
+                fluid.append_backward(fluid.layers.reduce_sum(
+                    fluid.layers.elementwise_mul(out, out)))
+            rng = np.random.RandomState(S)
+            feed = {n: rng.randn(B, H, S, D).astype(np.float32)
+                    for n in 'qkv'}
+            with fluid.scope_guard(fluid.core.Scope()):
+                got = fluid.Executor(place).run(
+                    main, feed=feed,
+                    fetch_list=[out, 'q@GRAD', 'k@GRAD', 'v@GRAD'])
+            args = [jnp.asarray(feed[n]) for n in 'qkv']
+            want = [ref(*args, causal, D ** -0.5)] + list(jax.grad(
+                lambda q, k, v: (ref(q, k, v, causal, D ** -0.5) ** 2).sum(),
+                argnums=(0, 1, 2))(*args))
+            worst = 0.0
+            for g, r in zip(got, want):
+                g, r = np.asarray(g), np.asarray(r)
+                if g.shape != r.shape or not np.isfinite(g).all():
+                    raise AssertionError('S=%d: bad kernel output' % S)
+                worst = max(worst, float(np.abs(g - r).max()
+                                         / (np.abs(r).max() + 1e-6)))
+            errs['S%d_%s' % (S, 'causal' if causal else 'full')] = \
+                round(worst, 6)
+            # the kernel's f32 matmuls may run as bf16 MXU passes: agree
+            # with the float32 reference to a bf16-sized relative error
+            if worst > 2e-2:
+                raise AssertionError('S=%d causal=%s: kernel vs composition '
+                                     'rel err %.4g' % (S, causal, worst))
+        return {'max_rel_err': errs}
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
+    ap.add_argument('--out', default=os.path.join(HERE, 'chip_smoke_out'),
+                    help='directory for artifacts and lines.jsonl')
+    ap.add_argument('--cpu-rehearsal', action='store_true',
+                    help='toy sizes on the host cpu; never a chip pass')
+    args = ap.parse_args(argv)
+    if args.cpu_rehearsal:
+        os.environ['JAX_PLATFORMS'] = 'cpu'
+        os.environ.setdefault(
+            'XLA_FLAGS', '--xla_force_host_platform_device_count=4')
+    want = 'cpu' if args.cpu_rehearsal else 'tpu'
+
+    import jax
+    try:
+        devs = jax.devices()
+    except RuntimeError as e:
+        sys.stderr.write('chip_smoke: jax found no device: %s\n' % e)
+        return 2
+    if devs[0].platform != want:
+        sys.stderr.write(
+            'chip_smoke: needs a TPU, but jax found only %s '
+            '(JAX_PLATFORMS=%r)\n'
+            % (sorted({d.platform for d in devs}),
+               os.environ.get('JAX_PLATFORMS')))
+        return 2
+
+    sys.path.insert(0, HERE)
+    from paddle_tpu.core import compile_cache
+    # the budget bounds what the package itself put on disk; phase B's
+    # programs carry their weights (1.6 GB of executables in jax's tier),
+    # and the default 512 MB would evict phase A's entries behind them
+    compile_cache.enable(max_mb=4096)
+    os.makedirs(args.out, exist_ok=True)
+    open(os.path.join(args.out, 'lines.jsonl'), 'w').close()
+
+    smoke = Smoke(TOY if args.cpu_rehearsal else FULL, args.out, devs[0],
+                  len(devs))
+    smoke.phase('A', smoke.phase_a)
+    if len(devs) >= 4:
+        smoke.phase('C', smoke.phase_c)
+    smoke.phase('B', smoke.phase_b)
+    smoke.phase('K', smoke.phase_k)
+    result = {'ok': not args.cpu_rehearsal,
+              'device': {'platform': devs[0].platform,
+                         'kind': devs[0].device_kind, 'count': len(devs)}}
+    if args.cpu_rehearsal:
+        result['rehearsal'] = 'passed'
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == '__main__':
+    sys.exit(main())

@@ -20,8 +20,7 @@ import os as _os
 # Keep the barriers alive on CPU (TPU handles them natively); opt out
 # with PTPU_KEEP_CSE_BARRIERS=0. Must run before jax initializes.
 if _os.environ.get('PTPU_KEEP_CSE_BARRIERS', '1') != '0' \
-        and 'cpu' in (_os.environ.get('PTPU_PLATFORM')
-                      or _os.environ.get('JAX_PLATFORMS', '')):
+        and 'cpu' in _os.environ.get('JAX_PLATFORMS', ''):
     _flags = _os.environ.get('XLA_FLAGS', '')
     if 'cse_barrier_expander' not in _flags:
         _os.environ['XLA_FLAGS'] = (

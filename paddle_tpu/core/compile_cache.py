@@ -18,10 +18,18 @@ Three tiers, tried in order:
      re-trace and still XLA-compiles. This tier also survives jaxlib
      upgrades that invalidate tier 1 (export has its own compatibility
      window).
-  3. **JAX persistent compilation cache** underneath (`<dir>/xla`):
-     enabled for the whole process when this cache is enabled, so even
-     compiles that bypass this module (utility jits, the bulk-infer scan)
-     warm-start at the XLA level.
+  3. **JAX persistent compilation cache** underneath: enabled for the
+     whole process when this cache is enabled, so even compiles that
+     bypass this module (utility jits, the bulk-infer scan) warm-start at
+     the XLA level.
+
+Placement comes from OUTSIDE: with ``JAX_COMPILATION_CACHE_DIR`` set, that
+directory is the root — tier 3 is the directory itself (jax reads the
+variable; nothing here re-points it), tiers 1-2 live in its ``entries/``
+subdirectory, and the cache is on. Unset, the root is the fixed
+``<checkout>/.compile_cache`` (tier 3 in its ``xla/``) — never $HOME, a
+temp name, a pid or a time: the path is part of jax's cache key, and a
+sealed machine keeps nothing outside the checkout.
 
 Content-addressed keys: sha256 over (serialized program desc, feed/fetch
 signatures, arg avals + shardings, amp/mesh/K, rng impl + dropout bits,
@@ -30,10 +38,10 @@ the compiled numerics changes the key — a miss is always safe, a false hit
 never happens.
 
 Knobs: ``PTPU_COMPILE_CACHE=1`` enables (also implied by setting
-``PTPU_COMPILE_CACHE_DIR``), ``PTPU_COMPILE_CACHE_DIR`` places it
-(default ``~/.cache/paddle_tpu/compile``), ``PTPU_COMPILE_CACHE_MAX_MB``
-bounds it (LRU by last-use mtime, default 512). Programmatic:
-``enable(dir)`` / ``disable()``.
+``JAX_COMPILATION_CACHE_DIR``), ``PTPU_COMPILE_CACHE_MAX_MB`` bounds what
+this module wrote (LRU by last-use mtime, default 512; an externally
+placed tier 3 is jax's own to bound, ``jax_compilation_cache_max_size``).
+Programmatic: ``enable(dir)`` / ``disable()``.
 
 Discipline: flock-guarded writes/eviction (the elastic-journal pattern,
 reader/elastic.py), atomic tmp+rename entry files, and LOUD fallback —
@@ -50,7 +58,6 @@ per process, never mixed mid-stream.)
 """
 from __future__ import annotations
 
-import contextlib
 import hashlib
 import json
 import os
@@ -98,22 +105,30 @@ _dir_ready = set()
 
 # -- knobs -------------------------------------------------------------------
 
+_JAX_DIR_ENV = 'JAX_COMPILATION_CACHE_DIR'
+# <checkout>/.compile_cache: this file is <checkout>/paddle_tpu/core/...
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), '.compile_cache')
+
+
 def enabled():
     """Cache on? enable()/disable() override > PTPU_COMPILE_CACHE env >
-    implied-on when PTPU_COMPILE_CACHE_DIR is set."""
+    implied-on when JAX_COMPILATION_CACHE_DIR is set."""
     if _override_enabled is not None:
         return _override_enabled
     v = os.environ.get('PTPU_COMPILE_CACHE')
     if v is not None:
         return v not in ('0', 'false', 'off', '')
-    return bool(os.environ.get('PTPU_COMPILE_CACHE_DIR'))
+    return bool(os.environ.get(_JAX_DIR_ENV))
 
 
 def cache_dir():
+    """The cache root: enable(dir=) > $JAX_COMPILATION_CACHE_DIR > the
+    fixed in-checkout path."""
     if _override_dir is not None:
         return _override_dir
-    return os.environ.get('PTPU_COMPILE_CACHE_DIR') or os.path.join(
-        os.path.expanduser('~'), '.cache', 'paddle_tpu', 'compile')
+    return os.environ.get(_JAX_DIR_ENV) or _DEFAULT_DIR
 
 
 def max_mb():
@@ -151,7 +166,7 @@ def _ensure_ready():
     d = cache_dir()
     if d not in _dir_ready:
         os.makedirs(_entries_dir(), exist_ok=True)
-        _enable_jax_pcache(os.path.join(d, 'xla'))
+        _enable_jax_pcache()
         _dir_ready.add(d)
     _ensure_listener()
     _register_profiler_source()
@@ -160,29 +175,26 @@ def _ensure_ready():
 _pcache_dir_set = None   # the xla dir THIS module configured (if any)
 
 
-def _enable_jax_pcache(xla_dir):
-    """Tier 3: JAX's own persistent compilation cache. Set it when unset;
-    RE-point it when a later enable(dir=...) moves the cache and the
-    current value is one this module set (a user-configured dir is never
-    touched) — otherwise tier-3 traffic would silently keep landing in
-    the old dir, invisible to stats/prune on the new one."""
+def _enable_jax_pcache():
+    """Tier 3: JAX's own persistent compilation cache. Placed from
+    outside by JAX_COMPILATION_CACHE_DIR (jax reads it; this module sets
+    no other directory then). Otherwise point it at <root>/xla: set it
+    when unset, RE-point it when a later enable(dir=...) moves the cache
+    and the current value is one this module set — tier-3 traffic would
+    else keep landing in the old dir, invisible to stats/prune."""
     global _pcache_dir_set
     import jax
-    try:
-        cur = jax.config.jax_compilation_cache_dir
-        if cur is None or (cur == _pcache_dir_set and cur != xla_dir):
-            jax.config.update('jax_compilation_cache_dir', xla_dir)
-            # cache everything: tiny executor steps matter here, and the
-            # default min-entry/min-time thresholds would skip them
-            jax.config.update('jax_persistent_cache_min_entry_size_bytes',
-                              -1)
-            jax.config.update('jax_persistent_cache_min_compile_time_secs',
-                              0)
-            _pcache_dir_set = xla_dir
-    except Exception as e:          # older jaxlib without the knobs
-        warnings.warn('compile cache: could not enable the jax persistent '
-                      'compilation cache (%s: %s); tiers 1/2 still work'
-                      % (type(e).__name__, e), RuntimeWarning)
+    # cache everything: tiny executor steps matter here, and the default
+    # min-entry/min-time thresholds would skip them
+    jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
+    jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)
+    xla_dir = _xla_dir()
+    if xla_dir is None:
+        return
+    cur = jax.config.jax_compilation_cache_dir
+    if cur is None or (cur == _pcache_dir_set and cur != xla_dir):
+        jax.config.update('jax_compilation_cache_dir', xla_dir)
+        _pcache_dir_set = xla_dir
 
 
 # -- compile-event counter (profiler register_compile_source feed) -----------
@@ -196,10 +208,7 @@ def _ensure_listener():
     if _listener_on:
         return
     _listener_on = True
-    try:
-        from jax._src import monitoring
-    except ImportError:
-        return
+    from jax import monitoring
 
     def _dur(event, secs, **kw):
         if event == '/jax/core/compile/backend_compile_duration':
@@ -373,7 +382,7 @@ def _paths(key):
 
 
 class _flocked(object):
-    """Exclusive flock on <dir>/.lock around writes/eviction — the
+    """Exclusive flock on entries/.lock around writes/eviction — the
     elastic-journal discipline (reader/elastic.py): concurrent replicas
     warming one shared cache dir must not interleave eviction with a
     half-written entry. Filesystems without flock degrade to unlocked
@@ -386,7 +395,7 @@ class _flocked(object):
         if fcntl is None:
             return self
         try:
-            self._f = open(os.path.join(cache_dir(), '.lock'), 'a+')
+            self._f = open(os.path.join(_entries_dir(), '.lock'), 'a+')
             fcntl.flock(self._f, fcntl.LOCK_EX)
         except OSError:
             if self._f is not None:
@@ -446,12 +455,12 @@ def load(key, donate_argnums=()):
     t0 = time.perf_counter()
     if os.path.exists(exec_p):
         try:
-            from jax.experimental.serialize_executable import (
-                deserialize_and_load)
+            from ..inference import serve as _serve
             with open(exec_p, 'rb') as f:
                 blob = f.read()
-            payload, in_tree, out_tree = pickle.loads(blob)
-            fn = deserialize_and_load(payload, in_tree, out_tree)
+            # the one loader shared with the AOT sidecars: onto the
+            # client and devices the entry was compiled for
+            fn = _serve._load_executable(pickle.loads(blob))
             with _stats_lock:
                 _stats['exec_hits'] += 1
                 _stats['bytes_read'] += len(blob)
@@ -508,11 +517,10 @@ def store(key, compiled=None, exported_bytes=None, tag='program',
         with _flocked():
             if compiled is not None:
                 try:
-                    from jax.experimental.serialize_executable import (
-                        serialize)
-                    payload, in_tree, out_tree = serialize(compiled)
+                    from ..inference import serve as _serve
                     wrote += _atomic_write(
-                        exec_p, pickle.dumps((payload, in_tree, out_tree)))
+                        exec_p,
+                        pickle.dumps(_serve._pack_executable(compiled)))
                 except Exception as e:
                     # backend without executable serialization: tier-2 only
                     warnings.warn('compile cache: executable tier '
@@ -558,15 +566,22 @@ def _entry_index():
 
 
 def _xla_dir():
+    """The tier-3 dir this module places and bounds, <root>/xla — or None
+    when JAX_COMPILATION_CACHE_DIR places tier 3 (at the root itself):
+    that directory is jax's own to fill and bound."""
+    if _override_dir is None and os.environ.get(_JAX_DIR_ENV):
+        return None
     return os.path.join(cache_dir(), 'xla')
 
 
 def _xla_index():
-    """{path: (bytes, mtime)} over the tier-3 jax persistent-cache dir —
-    those bytes count against the SAME budget (the module's MAX_MB claim
-    must hold for the whole cache dir, not just entries/)."""
+    """{path: (bytes, mtime)} over the tier-3 dir when this module placed
+    it — those bytes count against the SAME budget (the MAX_MB claim must
+    hold for everything the module put on disk, not just entries/)."""
     idx = {}
     d = _xla_dir()
+    if d is None:
+        return idx
     try:
         names = os.listdir(d)
     except OSError:
@@ -589,7 +604,7 @@ def _sweep_stale_tmp(max_age_s=3600.0):
     write in another process is never torn."""
     n = 0
     cutoff = time.time() - max_age_s
-    for d in (_entries_dir(), _xla_dir()):
+    for d in filter(None, (_entries_dir(), _xla_dir())):
         try:
             names = os.listdir(d)
         except OSError:
@@ -760,14 +775,22 @@ def aot_or_jit(jitted, args, key_parts, tag='program', fun=None,
     compiled = None
     donated = False
     # fresh_compile: the executable below goes to store()'s tier 1 via
-    # serialize_executable — a tier-3-satisfied compile would serialize
-    # into a blob no other process can load
+    # serialize_executable — on cpu a tier-3-satisfied compile would
+    # serialize into a blob no other process can run
+    from ..inference.serve import _fresh_compile
+    if mesh is not None:
+        platform = mesh.devices.flat[0].platform
+    else:
+        platform = (device or jax.devices()[0]).platform
     if use_export:
         try:
             from jax import export as jexport
-            exp = jexport.export(cache_jit)(*args)
+            # for the platform compiled FOR: export's default is the
+            # process's default backend, which a cpu executor on a TPU
+            # host is not on
+            exp = jexport.export(cache_jit, platforms=[platform])(*args)
             exported_bytes = exp.serialize()
-            with fresh_compile():
+            with _fresh_compile(platform):
                 compiled, donated = _compile_maybe_donated(
                     jax, exp.call, donate, args)
         except Exception:
@@ -777,7 +800,7 @@ def aot_or_jit(jitted, args, key_parts, tag='program', fun=None,
         # programs jax.export cannot carry (host callbacks, exotic
         # shardings): direct AOT compile — tier 1 only
         try:
-            with fresh_compile():
+            with _fresh_compile(platform):
                 if donate and fun is not None:
                     compiled, donated = _compile_maybe_donated(
                         jax, fun, donate, args)
@@ -795,43 +818,6 @@ def aot_or_jit(jitted, args, key_parts, tag='program', fun=None,
     store(key, compiled=compiled, exported_bytes=exported_bytes, tag=tag,
           donated=donated)
     return compiled
-
-
-@contextlib.contextmanager
-def fresh_compile():
-    """Compile with jax's persistent compilation cache (tier 3)
-    DISABLED. An executable that tier 3 satisfied re-serializes into a
-    blob other processes CANNOT deserialize ('Symbols not found: ...'
-    at load — measured on XLA:CPU, ISSUE 12 round): anything destined
-    for serialize_executable (tier-1 entries, AOT warm-start sidecars)
-    must come from a genuinely fresh XLA compile. Scoped and
-    exception-safe; a no-op on jax versions without the flag.
-
-    jax latches cache-enablement ONCE per process
-    (compilation_cache.is_cache_used caches its verdict), so toggling
-    the flag alone is ignored after the first compile — the latch is
-    reset around the scope (and re-reset after, so the surrounding
-    run's tier-3 behavior is unchanged)."""
-    import jax
-
-    def _unlatch():
-        try:
-            from jax._src import compilation_cache as _jcc
-            _jcc.reset_cache()
-        except Exception:
-            pass
-    try:
-        old = bool(jax.config.jax_enable_compilation_cache)
-    except AttributeError:
-        yield
-        return
-    try:
-        jax.config.update('jax_enable_compilation_cache', False)
-        _unlatch()
-        yield
-    finally:
-        jax.config.update('jax_enable_compilation_cache', old)
-        _unlatch()
 
 
 def _compile_maybe_donated(jax, fn, donate, args):

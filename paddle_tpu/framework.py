@@ -507,15 +507,26 @@ class CUDAPinnedPlace(Place):
     _kind = 'cpu'
 
 
-def _place_backend(place):
-    """Resolve a Place to a jax backend string, falling back to whatever
-    accelerator is present (PTPU_PLATFORM env pins it — core/config.py)."""
+def place_device(place):
+    """Resolve a Place to the local jax device it names. TPUPlace/CUDAPlace
+    mean the first local TPU device and CPUPlace the first cpu device —
+    or RuntimeError naming the platforms that ARE present, never another
+    backend in their stead. `place=None` is jax's default backend (or the
+    core.config.set_backend() pin). local_devices: under multi-host,
+    devices() is the GLOBAL list and entry 0 belongs to process 0 —
+    single-device work must stay on a device THIS process owns."""
+    import jax
     from .core.config import get_backend
-    if place is None:
-        return get_backend()
-    if place._kind == 'cpu':
-        return 'cpu'
-    return get_backend()
+    backend = get_backend() if place is None else place._kind
+    try:
+        return jax.local_devices(backend=backend)[0]
+    except RuntimeError as e:
+        raise RuntimeError(
+            "%s needs a %r device, but this process has only %s (%s)"
+            % (place if place is not None else 'set_backend(%r)' % backend,
+               backend,
+               sorted({d.platform for d in jax.local_devices()}), e)) \
+            from None
 
 
 def grad_var_name(name):

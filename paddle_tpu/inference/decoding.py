@@ -560,7 +560,7 @@ def _precompile_decode_dir(d, state_specs, arg_specs, donate,
         state_specs = [jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=ns)
                        for s, ns in zip(state_specs,
                                         mesh_ctx['state_ns'])]
-        with _serve._fresh_compile():
+        with _serve._fresh_compile(mesh_ctx['platform']):
             compiled = jax.jit(exp.call, **kw).lower(
                 state_specs, *arg_specs).compile()
         return _serve._save_aot(
@@ -568,7 +568,7 @@ def _precompile_decode_dir(d, state_specs, arg_specs, donate,
             compiled, _serve._module_sha(module_bytes))
     plat = platform or _serve._aot_platform()
     dev = jax.devices(plat)[0]
-    with jax.default_device(dev), _serve._fresh_compile():
+    with jax.default_device(dev), _serve._fresh_compile(plat):
         compiled = jax.jit(exp.call, **kw).lower(
             state_specs, *arg_specs).compile()
     return _serve._save_aot(os.path.join(d, _serve._AOT_SIDECAR % plat),
@@ -593,7 +593,8 @@ def _sig_mesh_ctx(sig, platform=None):
         mesh, mesh_sig.get('state_shardings'),
         [e['name'] for e in sig['state']])
     return {'mesh': mesh, 'rep': rep, 'state_ns': state_ns,
-            'tag': mesh_sig['tag'], 'platform': plat}
+            'tag': mesh_sig['tag'],
+            'platform': mesh.devices.flat[0].platform}
 
 
 def precompile_decode_artifact(artifact_dir, platform=None):
@@ -705,7 +706,6 @@ class DecodingPredictor(object):
         self._layout = self._sig.get('layout', 'slot')
         self._default_max_new = int(default_max_new_tokens)
         self._max_queue = int(max_queue) if max_queue else None
-        platform = platform or os.environ.get('PTPU_PLATFORM')
         # sharded artifact (ISSUE 13): rebuild the export mesh; state
         # places per the recorded shardings, programs load through the
         # mesh-tagged AOT sidecars, feeds/fetches stay replicated

@@ -519,14 +519,22 @@ class FleetRouter(object):
 
     def _await_ready(self, rid):
         rep = self._replicas[rid]
-        if not rep.ready_evt.wait(self._spinup_timeout_s) \
-                or rep.state not in ('serving', 'canary'):
-            self._on_replica_failure(rep, 'failed to start (state %r)'
-                                     % rep.state)
+        deadline = time.monotonic() + self._spinup_timeout_s
+        # poll the child while waiting for its hello: a worker that died
+        # (e.g. could not get its device) fails the spawn at once, with
+        # its own reason already on our stderr
+        while not rep.ready_evt.wait(0.1):
+            if rep.proc.poll() is not None or time.monotonic() > deadline:
+                break
+        if rep.state not in ('serving', 'canary'):
+            rc = rep.proc.poll()
+            why = ('exited with code %d during start-up' % rc
+                   if rc is not None else
+                   'sent no hello within %.0fs' % self._spinup_timeout_s)
+            self._on_replica_failure(rep, 'failed to start (%s)' % why)
             raise RuntimeError(
-                'fleet replica %d failed to start within %.0fs '
-                '(state %r) — see its stderr above'
-                % (rid, self._spinup_timeout_s, rep.state))
+                'fleet replica %d failed to start: %s (state %r) — see '
+                'its stderr above' % (rid, why, rep.state))
         return rid
 
     def _accept_loop(self):

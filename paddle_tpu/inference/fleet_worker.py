@@ -379,19 +379,35 @@ def main():
     opts = json.loads(opts_json)
     plat = opts.get('platform')
     if plat:
-        os.environ.setdefault('JAX_PLATFORMS', plat)
-        os.environ.setdefault('PTPU_PLATFORM', plat)
+        os.environ['JAX_PLATFORMS'] = plat
+
+    # one process per chip: a replica that cannot get its device (another
+    # process holds the chip, or the platform is absent) says so on the
+    # router's stderr and exits — the router sees the dead child at once
+    # instead of waiting out its spin-up timeout
+    import jax
+    from jax import monitoring
+    from jax._src import xla_bridge
+    try:
+        jax.devices()
+        # with JAX_PLATFORMS unset jax answers a failed accelerator with
+        # the cpu and a log line; a replica must not serve there quietly
+        failed = dict(xla_bridge._backend_errors)
+        if failed:
+            raise RuntimeError('; '.join(
+                '%s: %s' % kv for kv in sorted(failed.items())))
+    except RuntimeError as e:
+        sys.stderr.write('fleet worker %d: cannot get its device '
+                         '(JAX_PLATFORMS=%r): %s\n'
+                         % (rid, os.environ.get('JAX_PLATFORMS'), e))
+        sys.exit(3)
 
     compiles = [0]
-    try:
-        from jax import monitoring
 
-        def _listener(event, secs, **kw):
-            if event == '/jax/core/compile/backend_compile_duration':
-                compiles[0] += 1
-        monitoring.register_event_duration_secs_listener(_listener)
-    except Exception:
-        compiles[0] = -1  # unknown
+    def _listener(event, secs, **kw):
+        if event == '/jax/core/compile/backend_compile_duration':
+            compiles[0] += 1
+    monitoring.register_event_duration_secs_listener(_listener)
 
     kind = opts.get('kind') or _fleet.detect_kind(artifact)
     endpoint = _ENDPOINTS[kind](artifact, opts)

@@ -45,10 +45,9 @@ class Predictor(object):
     def __init__(self, config):
         from ..executor import Executor
         from ..core.scope import Scope
-        from ..framework import TPUPlace
         self._config = config
         self._scope = Scope()
-        self._exe = Executor(config._place or TPUPlace())
+        self._exe = Executor(config._place)
         # bulk dispatches (run_batches) report as an inference source in
         # the profiler, not a training one
         self._exe._profile_role = 'infer'

@@ -44,15 +44,10 @@ from ..core.registry import register
 # symmetric convention — keeps w and -w representable at equal error)
 QMAX = 127.0
 
-_platform_dependent = getattr(lax, 'platform_dependent', None)
-
-
 def _per_platform(args, tpu_fn, ref_fn):
     """tpu_fn on TPU, ref_fn elsewhere — one traced module carries both
     branches (multi-platform jax.export keeps platform_dependent)."""
-    if _platform_dependent is None:  # very old jax: reference path only
-        return ref_fn(*args)
-    return _platform_dependent(*args, tpu=tpu_fn, default=ref_fn)
+    return lax.platform_dependent(*args, tpu=tpu_fn, default=ref_fn)
 
 
 def quantize_array(x, scale):

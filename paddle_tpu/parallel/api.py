@@ -53,7 +53,6 @@ class MultiStepTrainer(object):
                  fetch_policy='final', place=None, scope=None,
                  executor=None, checkpoint=None, preemptible=False):
         from ..executor import Executor
-        from ..framework import TPUPlace
         if int(steps_per_dispatch) < 1:
             raise ValueError("steps_per_dispatch must be >= 1, got %d"
                              % int(steps_per_dispatch))
@@ -62,8 +61,8 @@ class MultiStepTrainer(object):
         self.fetch_list = list(fetch_list or [])
         self.fetch_policy = fetch_policy
         self.scope = scope
-        self.executor = executor if executor is not None else Executor(
-            place if place is not None else TPUPlace())
+        self.executor = executor if executor is not None \
+            else Executor(place)
         # fault-tolerance policy (core/checkpoint.py): evaluated at every
         # dispatch boundary; startup() restores from the newest committed
         # checkpoint so a SIGKILLed trainer resumes where it stopped

@@ -26,15 +26,12 @@ _initialized = {'done': False}
 
 
 def _effective_platform(platform):
-    """The platform the backend will initialize with, best-effort: the
-    explicit argument wins, then the env pins tests use."""
+    """The platform the backend will initialize with: the explicit
+    argument wins, then JAX_PLATFORMS."""
     if platform is not None:
         return platform
-    for env in ('JAX_PLATFORMS', 'PTPU_PLATFORM'):
-        v = os.environ.get(env)
-        if v:
-            return v.split(',')[0]
-    return None
+    v = os.environ.get('JAX_PLATFORMS')
+    return v.split(',')[0] if v else None
 
 
 def init_distributed(coordinator_address=None, num_trainers=None,

@@ -39,12 +39,7 @@ def gpipe_apply(layer_fn, stacked_params, x_microbatches, mesh,
         only its own batch shard — layers never mix rows).
     Returns [M, mb, ...]: layer P-1(...layer 0(x)).
     """
-    try:
-        from jax import shard_map
-        rep_kw = {'check_vma': False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        rep_kw = {'check_rep': False}
+    from jax import shard_map
 
     nstages = int(mesh.shape[pp_axis])
     m = x_microbatches.shape[0]
@@ -60,7 +55,7 @@ def gpipe_apply(layer_fn, stacked_params, x_microbatches, mesh,
     @functools.partial(
         shard_map, mesh=mesh,
         in_specs=(param_specs, xs_spec),
-        out_specs=P(pp_axis, None, b_ax, *extra), **rep_kw)
+        out_specs=P(pp_axis, None, b_ax, *extra), check_vma=False)
     def pipe(params_local, xs):
         rank = jax.lax.axis_index(pp_axis)
         p_local = jax.tree.map(lambda a: a[0], params_local)  # this stage

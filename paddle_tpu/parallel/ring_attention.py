@@ -35,12 +35,7 @@ def ring_attention(q, k, v, mesh, causal=False, scale=1.0,
     """Attention over [B, H, S, D] with S sharded on `seq_axis` of `mesh`.
     B additionally shards over `batch_axis` and H over `head_axis` when
     those axes exist in the mesh. Returns [B, H, S, D], S-sharded."""
-    try:
-        from jax import shard_map                      # jax >= 0.8
-        rep_kw = {'check_vma': False}
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        rep_kw = {'check_rep': False}
+    from jax import shard_map
 
     nsp = int(mesh.shape[seq_axis])
     if q.shape[2] % nsp != 0:
@@ -60,7 +55,7 @@ def ring_attention(q, k, v, mesh, causal=False, scale=1.0,
 
     @functools.partial(shard_map, mesh=mesh,
                        in_specs=(spec, spec, spec), out_specs=spec,
-                       **rep_kw)
+                       check_vma=False)
     def ring(ql, kl, vl):
         rank = jax.lax.axis_index(seq_axis)
         sl = ql.shape[2]

@@ -24,7 +24,6 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 os.environ.setdefault('JAX_PLATFORMS', 'cpu')
-os.environ.setdefault('PTPU_PLATFORM', 'cpu')
 
 WORKER = os.path.join(REPO, 'tests', 'checkpoint_kill_worker.py')
 TOTAL, K, EVERY, KILL_AT = 24, 4, 4, 12
@@ -59,7 +58,7 @@ def run_worker(env, ckpt, out, kill_at=0):
 def kill_resume_phase(work):
     env = dict(os.environ)
     env['PTPU_COMPILE_CACHE'] = '1'
-    env['PTPU_COMPILE_CACHE_DIR'] = os.path.join(work, 'cache')
+    env['JAX_COMPILATION_CACHE_DIR'] = os.path.join(work, 'cache')
 
     r, ref_wall = run_worker(env, '-', os.path.join(work, 'ref.txt'))
     assert r.returncode == 0, r.stderr[-2000:]

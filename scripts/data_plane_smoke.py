@@ -21,7 +21,6 @@ import hashlib
 import tempfile
 
 os.environ.setdefault('JAX_PLATFORMS', 'cpu')
-os.environ.setdefault('PTPU_PLATFORM', 'cpu')
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), '..'))
 

@@ -27,7 +27,6 @@ import urllib.request
 import warnings
 
 os.environ.setdefault('JAX_PLATFORMS', 'cpu')
-os.environ.setdefault('PTPU_PLATFORM', 'cpu')
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)

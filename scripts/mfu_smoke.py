@@ -23,7 +23,6 @@ import sys
 import time
 
 os.environ.setdefault('JAX_PLATFORMS', 'cpu')
-os.environ.setdefault('PTPU_PLATFORM', 'cpu')
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np

@@ -18,7 +18,6 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 os.environ.setdefault('JAX_PLATFORMS', 'cpu')
-os.environ.setdefault('PTPU_PLATFORM', 'cpu')
 
 import numpy as np  # noqa: E402
 

@@ -82,7 +82,6 @@ def parse(stdout, marker):
 def main():
     import numpy as np
     os.environ.setdefault('JAX_PLATFORMS', 'cpu')
-    os.environ.setdefault('PTPU_PLATFORM', 'cpu')
     tmp = tempfile.mkdtemp(prefix='ptpu_warm_smoke_')
     art = os.path.join(tmp, 'artifact')
     cache = os.path.join(tmp, 'cache')

@@ -30,7 +30,6 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ['JAX_PLATFORMS'] = 'cpu'
-os.environ['PTPU_PLATFORM'] = 'cpu'
 
 BATCH = 8
 

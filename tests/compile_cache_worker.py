@@ -19,9 +19,8 @@ import sys
 def main():
     cache_dir, out_path = sys.argv[1], sys.argv[2]
     os.environ['JAX_PLATFORMS'] = 'cpu'
-    os.environ['PTPU_PLATFORM'] = 'cpu'
     os.environ['PTPU_COMPILE_CACHE'] = '1'
-    os.environ['PTPU_COMPILE_CACHE_DIR'] = cache_dir
+    os.environ['JAX_COMPILATION_CACHE_DIR'] = cache_dir
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, repo)
 

@@ -78,7 +78,7 @@ if __name__ == '__main__' and len(sys.argv) > 1 \
     sys.exit(0)
 
 os.environ.setdefault('XLA_FLAGS', '--xla_force_host_platform_device_count=2')
-os.environ['PTPU_PLATFORM'] = 'cpu'
+os.environ['JAX_PLATFORMS'] = 'cpu'
 
 from paddle_tpu.parallel import multihost  # noqa: E402
 

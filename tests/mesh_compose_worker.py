@@ -36,7 +36,6 @@ def main():
     os.environ['XLA_FLAGS'] = (
         flags + ' --xla_force_host_platform_device_count=%d' % n).strip()
     os.environ['JAX_PLATFORMS'] = 'cpu'
-    os.environ['PTPU_PLATFORM'] = 'cpu'
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     sys.path.insert(0, repo)
 
@@ -100,7 +99,7 @@ def main():
     # (test_pipeline.py:86); observed divergence is ~1e-7 relative
     np.testing.assert_allclose(single, multi, rtol=2e-3, atol=1e-5)
     # persistent compile cache (ISSUE 5): when the caller points
-    # PTPU_COMPILE_CACHE_DIR at a shared dir, report the counters so the
+    # JAX_COMPILATION_CACHE_DIR at a shared dir, report the counters so the
     # test can assert a warm re-run skips the recompile of the largest
     # mesh ever compiled here
     from paddle_tpu.core import compile_cache as cc

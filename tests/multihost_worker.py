@@ -9,7 +9,7 @@ import os
 import sys
 
 os.environ.setdefault('XLA_FLAGS', '--xla_force_host_platform_device_count=4')
-os.environ['PTPU_PLATFORM'] = 'cpu'
+os.environ['JAX_PLATFORMS'] = 'cpu'
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from paddle_tpu.parallel import multihost

@@ -31,7 +31,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ.setdefault('XLA_FLAGS', '--xla_force_host_platform_device_count=2')
-os.environ['PTPU_PLATFORM'] = 'cpu'
+os.environ['JAX_PLATFORMS'] = 'cpu'
 
 from paddle_tpu.parallel import multihost
 

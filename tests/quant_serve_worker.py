@@ -20,7 +20,6 @@ def main():
     artifact, in_path = sys.argv[1], sys.argv[2]
     tier = sys.argv[3] if len(sys.argv) > 3 else 'int8'
     os.environ.setdefault('JAX_PLATFORMS', 'cpu')
-    os.environ.setdefault('PTPU_PLATFORM', 'cpu')
     import numpy as np
     from jax import monitoring
 

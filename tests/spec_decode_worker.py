@@ -21,7 +21,6 @@ def main():
     artifact, seed, n, max_new = (sys.argv[1], int(sys.argv[2]),
                                   int(sys.argv[3]), int(sys.argv[4]))
     os.environ.setdefault('JAX_PLATFORMS', 'cpu')
-    os.environ.setdefault('PTPU_PLATFORM', 'cpu')
     import numpy as np
     from jax import monitoring
 

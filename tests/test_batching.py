@@ -510,7 +510,6 @@ def test_serve_bench_cli_fresh_process_framework_free(artifacts, tmp_path):
         % (artifacts['multi'], in_path,
            os.path.join(REPO, 'paddle_tpu', 'inference', 'serve.py')))
     env = dict(os.environ)
-    env['PTPU_PLATFORM'] = 'cpu'
     env['JAX_PLATFORMS'] = 'cpu'
     r = subprocess.run([sys.executable, '-c', probe], env=env,
                        capture_output=True, text=True, timeout=600)

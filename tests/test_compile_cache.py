@@ -195,6 +195,56 @@ def test_prune_clear(tmp_path):
     assert cc.disk_stats()['entries'] == 0
 
 
+def test_cache_root_follows_jax_env_else_fixed_in_checkout(monkeypatch,
+                                                          tmp_path):
+    """The cache is placed from outside: $JAX_COMPILATION_CACHE_DIR is the
+    root (jax's tier the directory itself — this module never re-points
+    it — ours its entries/ subdirectory, and the cache is on); unset, the
+    root is the fixed <checkout>/.compile_cache."""
+    import jax
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv('JAX_COMPILATION_CACHE_DIR', raising=False)
+    monkeypatch.delenv('PTPU_COMPILE_CACHE', raising=False)
+    assert cc.cache_dir() == os.path.join(repo, '.compile_cache')
+    assert cc._xla_dir() == os.path.join(repo, '.compile_cache', 'xla')
+    assert not cc.enabled()
+    root = str(tmp_path / 'placed')
+    monkeypatch.setenv('JAX_COMPILATION_CACHE_DIR', root)
+    assert cc.enabled() and cc.cache_dir() == root
+    assert cc._entries_dir() == os.path.join(root, 'entries')
+    assert cc._xla_dir() is None          # jax's own to place and bound
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        cc._ensure_ready()
+        assert jax.config.jax_compilation_cache_dir == before
+        assert os.path.isdir(os.path.join(root, 'entries'))
+    finally:
+        cc._dir_ready.clear()
+
+
+def test_tier1_entry_reloads_on_the_device_it_was_compiled_for(tmp_path):
+    """A tier-1 entry compiled for device k of the 8 virtual devices
+    reloads (zero compiles) and runs on device k — through the loader the
+    AOT sidecars share (serve._load_executable)."""
+    import jax
+    import jax.numpy as jnp
+    dev = jax.devices('cpu')[3]
+    cc.enable(dir=str(tmp_path / 'c'))
+
+    def f(x):
+        return x * 2 + 1
+    x = jax.device_put(jnp.arange(4.0), dev)
+    cold = cc.aot_or_jit(jax.jit(f), (x,), ('t',), device=dev)
+    want = np.asarray(cold(x))
+    cc.reset_stats()
+    warm = cc.aot_or_jit(jax.jit(f), (x,), ('t',), device=dev)
+    st = cc.stats()
+    assert (st['exec_hits'], st['misses'], st['corrupt']) == (1, 0, 0)
+    out = warm(x)
+    assert out.devices() == {dev}
+    np.testing.assert_array_equal(np.asarray(out), want)
+
+
 def test_opt_cache_lru_capped():
     from paddle_tpu.parallel.compiler import CompiledProgram, _OPT_CACHE_MAX
     prog = fluid.Program()

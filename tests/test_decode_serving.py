@@ -221,7 +221,7 @@ def test_warm_fresh_subprocess_zero_compiles(artifact):
     in-process run — the ISSUE 8 warm-start acceptance bar."""
     worker = os.path.join(os.path.dirname(__file__),
                           'decode_serve_worker.py')
-    env = dict(os.environ, JAX_PLATFORMS='cpu', PTPU_PLATFORM='cpu')
+    env = dict(os.environ, JAX_PLATFORMS='cpu')
     out = subprocess.run(
         [sys.executable, worker, artifact, '23', '5', '7'],
         capture_output=True, text=True, env=env, timeout=300)

@@ -160,7 +160,7 @@ def test_crnn_artifact_fresh_process_no_framework(tmp_path):
         % (art, str(tmp_path / 'in.npz'), str(tmp_path / 'out.npz'),
            os.path.join(REPO, 'paddle_tpu', 'inference', 'serve.py')))
     env = dict(os.environ)
-    env['PTPU_PLATFORM'] = 'cpu'
+    env['JAX_PLATFORMS'] = 'cpu'
     r = subprocess.run([sys.executable, '-c', probe], env=env,
                        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]

@@ -78,7 +78,7 @@ def test_serve_fresh_process_never_imports_framework(tmp_path):
         % (art_dir, str(tmp_path / 'in.npz'), str(tmp_path / 'out.npz'),
            os.path.join(REPO, 'paddle_tpu', 'inference', 'serve.py')))
     env = dict(os.environ)
-    env['PTPU_PLATFORM'] = 'cpu'
+    env['JAX_PLATFORMS'] = 'cpu'
     r = subprocess.run([sys.executable, '-c', probe], env=env,
                        capture_output=True, text=True, timeout=300)
     # SystemExit(0) from main() is fine; any other failure is not

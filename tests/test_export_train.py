@@ -130,7 +130,7 @@ def test_train_fresh_process_never_imports_framework(tmp_path):
            STEPS, str(tmp_path / 'ckpt.npz'),
            os.path.join(REPO, 'paddle_tpu', 'inference', 'serve.py')))
     env = dict(os.environ)
-    env['PTPU_PLATFORM'] = 'cpu'
+    env['JAX_PLATFORMS'] = 'cpu'
     r = subprocess.run([sys.executable, '-c', probe], env=env,
                        capture_output=True, text=True, timeout=300)
     assert r.returncode == 0, r.stderr[-2000:]

@@ -52,7 +52,7 @@ def test_64dev_dp4_sp2_ep2_pp4_warm_start(tmp_path):
     scenario) must hit the executable tier and skip the recompile."""
     spec = ['dp=4', 'mp=1', 'sp=2', 'ep=2', 'pp=4']
     env = {'PTPU_COMPILE_CACHE': '1',
-           'PTPU_COMPILE_CACHE_DIR': str(tmp_path / 'cc')}
+           'JAX_COMPILATION_CACHE_DIR': str(tmp_path / 'cc')}
     cold = _run(spec, timeout=2400, env_extra=env)
     warm = _run(spec, timeout=2400, env_extra=env)
     assert cold is not None and warm is not None

@@ -32,7 +32,6 @@ def test_two_host_bert_dryrun(tmp_path):
     for pid in range(2):
         env = dict(os.environ)
         env.pop('JAX_PLATFORMS', None)
-        env.pop('PTPU_PLATFORM', None)
         env.update({
             'PADDLE_TRAINERS': '2',
             'PADDLE_TRAINER_ID': str(pid),

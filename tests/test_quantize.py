@@ -307,9 +307,18 @@ def test_tier_loading_and_parity(tiered_artifact):
     p_b = CompiledPredictor(adir)
     p_q = CompiledPredictor(adir, tier='int8')
     assert (p_b.tier, p_q.tier) == ('bf16', 'int8')
-    x = calib[0]['img']
-    ob, oq = p_b.run([x])[0], p_q.run([x])[0]
-    assert (ob.argmax(1) == oq.argmax(1)).all()
+    ob = np.concatenate([p_b.run([c['img']])[0] for c in calib])
+    oq = np.concatenate([p_q.run([c['img']])[0] for c in calib])
+    # top-1 parity wherever the float tier's own top-2 margin exceeds
+    # what int8 moves a probability by here (a random 10-class model has
+    # near-ties: under jax 0.9.0's random stream one row is 0.2205 vs
+    # 0.2209 and int8 flips it), and the tiers stay close
+    err = float(np.abs(ob - oq).max())
+    assert err < 0.02
+    top2 = np.sort(ob, axis=1)[:, -2:]
+    clear = (top2[:, 1] - top2[:, 0]) > 2 * err
+    assert clear.sum() >= len(ob) // 2
+    assert (ob.argmax(1) == oq.argmax(1))[clear].all()
     with pytest.raises(ValueError, match='has no .* tier'):
         CompiledPredictor(adir, tier='fp8')
     # env preference degrades silently when the tier is absent (a bucket

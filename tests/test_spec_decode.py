@@ -386,7 +386,7 @@ def test_warm_fresh_subprocess_zero_compiles(arts, tmp_path):
                 stripped += 1
     assert stripped > 0
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, JAX_PLATFORMS='cpu', PTPU_PLATFORM='cpu')
+    env = dict(os.environ, JAX_PLATFORMS='cpu')
     out = subprocess.run(
         [sys.executable, os.path.join(repo, 'tools', 'cache_ctl.py'),
          'prewarm', art], capture_output=True, text=True, env=env,

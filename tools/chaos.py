@@ -172,7 +172,7 @@ def run_pod(ckpt_dir, out_paths, total, every, kill_rank=None, kill_at=0,
         })
         if cache_dir:
             env['PTPU_COMPILE_CACHE'] = '1'
-            env['PTPU_COMPILE_CACHE_DIR'] = cache_dir
+            env['JAX_COMPILATION_CACHE_DIR'] = cache_dir
         argv = [sys.executable, worker or POD_WORKER, ckpt_dir]
         if data_file:
             argv.append(data_file)

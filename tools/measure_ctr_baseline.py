@@ -16,7 +16,7 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault('PTPU_PLATFORM', 'cpu')
+os.environ.setdefault('JAX_PLATFORMS', 'cpu')
 
 import numpy as np
 
