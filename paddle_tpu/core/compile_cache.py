@@ -68,6 +68,8 @@ import warnings
 
 import numpy as np
 
+from ..profiler import span as _span
+
 try:
     import fcntl
 except ImportError:          # non-POSIX: no advisory locking available
@@ -456,11 +458,12 @@ def load(key, donate_argnums=()):
     if os.path.exists(exec_p):
         try:
             from ..inference import serve as _serve
-            with open(exec_p, 'rb') as f:
-                blob = f.read()
-            # the one loader shared with the AOT sidecars: onto the
-            # client and devices the entry was compiled for
-            fn = _serve._load_executable(pickle.loads(blob))
+            with _span('compile/load_exec', key=key[:12]):
+                with open(exec_p, 'rb') as f:
+                    blob = f.read()
+                # the one loader shared with the AOT sidecars: onto the
+                # client and devices the entry was compiled for
+                fn = _serve._load_executable(pickle.loads(blob))
             with _stats_lock:
                 _stats['exec_hits'] += 1
                 _stats['bytes_read'] += len(blob)
@@ -484,8 +487,9 @@ def load(key, donate_argnums=()):
             from jax import export as jexport
             with open(hlo_p, 'rb') as f:
                 blob = f.read()
+            from ..inference.serve import _named_call
             exp = jexport.deserialize(blob)
-            fn = jax.jit(exp.call,
+            fn = jax.jit(_named_call(exp),
                          donate_argnums=tuple(donate_argnums or ()))
             with _stats_lock:
                 _stats['hlo_hits'] += 1
@@ -777,7 +781,7 @@ def aot_or_jit(jitted, args, key_parts, tag='program', fun=None,
     # fresh_compile: the executable below goes to store()'s tier 1 via
     # serialize_executable — on cpu a tier-3-satisfied compile would
     # serialize into a blob no other process can run
-    from ..inference.serve import _fresh_compile
+    from ..inference.serve import _fresh_compile, _named_call
     if mesh is not None:
         platform = mesh.devices.flat[0].platform
     else:
@@ -790,9 +794,10 @@ def aot_or_jit(jitted, args, key_parts, tag='program', fun=None,
             # host is not on
             exp = jexport.export(cache_jit, platforms=[platform])(*args)
             exported_bytes = exp.serialize()
-            with _fresh_compile(platform):
+            with _fresh_compile(platform), \
+                    _span('compile/xla', key=key[:12]):
                 compiled, donated = _compile_maybe_donated(
-                    jax, exp.call, donate, args)
+                    jax, _named_call(exp), donate, args)
         except Exception:
             exported_bytes = None
             compiled = None
@@ -800,7 +805,8 @@ def aot_or_jit(jitted, args, key_parts, tag='program', fun=None,
         # programs jax.export cannot carry (host callbacks, exotic
         # shardings): direct AOT compile — tier 1 only
         try:
-            with _fresh_compile(platform):
+            with _fresh_compile(platform), \
+                    _span('compile/xla', key=key[:12]):
                 if donate and fun is not None:
                     compiled, donated = _compile_maybe_donated(
                         jax, fun, donate, args)

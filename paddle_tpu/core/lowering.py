@@ -143,6 +143,15 @@ class Tracer(object):
         if t == 'fetch':
             self.fetches.append(self.read(op.inputs['X'][0], op))
             return
+        # every HLO op this lowering emits carries the Fluid op's type in
+        # its op_name metadata ('jit(train_step)/.../conv2d/...'), so a
+        # device trace's ops can be traced back to the op that made them;
+        # trace-time only, nothing at run time
+        with jax.named_scope(t):
+            return self._lower_op(op, block)
+
+    def _lower_op(self, op, block):
+        t = op.type
         d = registry.get(t)
         if d is None:
             if t.endswith('_grad'):
@@ -192,7 +201,8 @@ class Tracer(object):
                 "%r to activate" % (op, act, slot))
         shadow = _FusedActOp(act, op.attrs.get('fuse_act_attrs', {}), op)
         ctx = OpCtx(self, shadow, block)
-        acted = d.lower(ctx, {'X': [unwrap(vals[0])]})['Out'][0]
+        with jax.named_scope(act):
+            acted = d.lower(ctx, {'X': [unwrap(vals[0])]})['Out'][0]
         outs = dict(outs)
         outs[slot] = [acted] + list(vals[1:])
         return outs

@@ -21,6 +21,7 @@ from .core.scope import Scope, global_scope, scope_guard  # re-export
 from .core.lowering import Tracer, TraceError
 from .core.lod import LoDArray, unwrap
 from .core import amp
+from .profiler import span as _span
 
 
 import contextlib
@@ -166,6 +167,17 @@ def _program_analysis(program):
     return out
 
 
+def _step_name(program, multi=False):
+    """The jitted step's name — what a device trace's 'XLA Modules' line
+    prints as jit_<name>: 'train_step' for a program with a backward pass,
+    'program_step' for any other (startup, inference); '..._steps' for the
+    K-step scan of run_steps."""
+    train = any(op.type.endswith('_grad')
+                for b in program.blocks for op in b.ops)
+    return ('train_step' if train else 'program_step') + ('s' if multi
+                                                          else '')
+
+
 class Executor(object):
     def __init__(self, place=None):
         self.place = place
@@ -201,6 +213,15 @@ class Executor(object):
     def run(self, program=None, feed=None, fetch_list=None, feed_var_name='feed',
             fetch_var_name='fetch', scope=None, return_numpy=True,
             use_program_cache=True, checkpoint=None):
+        # spans (profiler.span; inert unless a jax profiler trace runs):
+        # exe/run > exe/feed, exe/prepare, exe/build (cache miss only),
+        # exe/rng, exe/dispatch (> exe/place on a mesh), exe/finish
+        with _span('exe/run') as sp:
+            return self._run(sp, program, feed, fetch_list, scope,
+                             return_numpy, checkpoint)
+
+    def _run(self, sp, program, feed, fetch_list, scope, return_numpy,
+             checkpoint):
         import time as _time
         t_run = _time.perf_counter() if checkpoint is not None else None
         program = program if program is not None else default_main_program()
@@ -227,42 +248,49 @@ class Executor(object):
         feed = feed or {}
 
         feed_vals = {}
-        for name, value in feed.items():
-            feed_vals[name] = self._to_device_value(value,
-                                                    self._feed_var(program, name))
+        with _span('exe/feed', n=len(feed)):
+            for name, value in feed.items():
+                feed_vals[name] = self._to_device_value(
+                    value, self._feed_var(program, name))
 
-        # py_reader path: pull a staged batch for data vars not explicitly fed
-        for reader in getattr(program, '_py_readers', []):
-            if not all(n in feed_vals for n in reader.var_names):
-                batch = reader._next_batch()  # raises EOFException at end
-                for n, v in batch.items():
-                    if n not in feed_vals:
-                        feed_vals[n] = self._to_device_value(
-                            v, self._feed_var(program, n))
+            # py_reader path: pull a staged batch for data vars not
+            # explicitly fed
+            for reader in getattr(program, '_py_readers', []):
+                if not all(n in feed_vals for n in reader.var_names):
+                    batch = reader._next_batch()  # raises EOFException
+                    for n, v in batch.items():
+                        if n not in feed_vals:
+                            feed_vals[n] = self._to_device_value(
+                                v, self._feed_var(program, n))
 
-        # static lint (warn-only; PTPU_STRICT_VERIFY=1 raises) before the
-        # analysis cache — malformed programs fail loudly at build time
-        _verify_before_run(program, set(feed_vals), fetch_names)
+        with _span('exe/prepare'):
+            # static lint (warn-only; PTPU_STRICT_VERIFY=1 raises) before
+            # the analysis cache — malformed programs fail loudly at
+            # build time
+            _verify_before_run(program, set(feed_vals), fetch_names)
 
-        # persistable state present in scope
-        state, persist_written, out_state_names = self._gather_state(
-            program, scope)
+            # persistable state present in scope
+            state, persist_written, out_state_names = self._gather_state(
+                program, scope)
 
-        mesh_key = (tuple(mesh.shape.items()) if mesh is not None else None)
-        key = self._cache_key(program, feed_vals, fetch_names, state,
-                              out_state_names) + (mesh_key,)
-        fn = self._cache.get(key)
+            mesh_key = (tuple(mesh.shape.items()) if mesh is not None
+                        else None)
+            key = self._cache_key(program, feed_vals, fetch_names, state,
+                                  out_state_names) + (mesh_key,)
+            fn = self._cache.get(key)
         if fn is None:
-            self._evict_stale(program)
-            fn = self._build(program, tuple(sorted(feed_vals)), tuple(fetch_names),
-                             tuple(sorted(state)), out_state_names, mesh,
-                             feed_vals)
-            self._cache[key] = fn
-            self._cache_index.setdefault(program._uid, set()).add(key)
+            with _span('exe/build'):
+                self._evict_stale(program)
+                fn = self._build(program, tuple(sorted(feed_vals)),
+                                 tuple(fetch_names), tuple(sorted(state)),
+                                 out_state_names, mesh, feed_vals)
+                self._cache[key] = fn
+                self._cache_index.setdefault(program._uid, set()).add(key)
 
         counter_uid = getattr(program, '_ptpu_counter_uid', program._uid)
         step = self._step_counters.get(counter_uid, 0)
         self._step_counters[counter_uid] = step + 1
+        sp.set_metadata(program=program._uid, step=step)
         from .core import config as _config
         # carried as RAW key data (uint32) so multi-host placement can
         # treat it like any other array; step() re-wraps it. Computed on
@@ -270,11 +298,11 @@ class Executor(object):
         # an accelerator is 2-3 tiny dispatches per step that throttle
         # every small-model step. Key derivation is deterministic math,
         # so the stream is identical wherever it is computed.
-        rng = self._host_rng(self._step_seed(program), _config.rng_impl(),
-                             step)
+        with _span('exe/rng'):
+            rng = self._host_rng(self._step_seed(program),
+                                 _config.rng_impl(), step)
 
-        fetches, new_state = self._dispatch(
-            fn, state, feed_vals, rng, 'executor_run#%d' % program._uid)
+        fetches, new_state = self._dispatch(fn, state, feed_vals, rng)
         out = self._finish(scope, new_state, fetches, return_numpy)
         if checkpoint is not None:
             # the mesh-path equivalent of run_steps' boundary: the scope
@@ -328,12 +356,9 @@ class Executor(object):
                 else _process_entropy()
         return seed
 
-    def _dispatch(self, fn, state, feed_vals, rng, tag):
+    def _dispatch(self, fn, state, feed_vals, rng):
         from .core import config as _config
-        from . import profiler as _profiler
-        prof_ctx = (_profiler.record_event(tag)
-                    if _profiler.is_profiling() else _nullcontext())
-        with prof_ctx:
+        with _span('exe/dispatch'):
             if _config.get_flag('check_nan_inf'):
                 # reference FLAGS_check_nan_inf scans every op output
                 # (operator.cc:896-905); jax.debug_nans re-runs the step
@@ -344,11 +369,12 @@ class Executor(object):
 
     @staticmethod
     def _finish(scope, new_state, fetches, return_numpy):
-        for name, val in new_state.items():
-            scope.set(name, val)
-        if return_numpy:
-            return [np.asarray(unwrap(v)) for v in fetches]
-        return list(fetches)
+        with _span('exe/finish'):
+            for name, val in new_state.items():
+                scope.set(name, val)
+            if return_numpy:
+                return [np.asarray(unwrap(v)) for v in fetches]
+            return list(fetches)
 
     def close(self):
         self._cache.clear()
@@ -401,6 +427,13 @@ class Executor(object):
         write happens on the manager's background thread, and the stall
         is reported as ckpt%% in profiler.training_report().
         """
+        with _span('exe/run_steps') as sp:
+            return self._run_steps(sp, program, reader, fetch_list, steps,
+                                   feed, scope, return_numpy, fetch_policy,
+                                   checkpoint)
+
+    def _run_steps(self, sp, program, reader, fetch_list, steps, feed, scope,
+                   return_numpy, fetch_policy, checkpoint):
         if fetch_policy not in ('final', 'stack'):
             raise ValueError("fetch_policy must be 'final' or 'stack', "
                              "got %r" % (fetch_policy,))
@@ -421,42 +454,47 @@ class Executor(object):
 
         import time as _time
         t_run = t0 = _time.perf_counter()
-        feed_vals, k, want = self._gather_step_group(program, reader, feed,
-                                                     steps)
+        with _span('exe/feed') as feed_sp:
+            feed_vals, k, want = self._gather_step_group(program, reader,
+                                                         feed, steps)
+            feed_sp.set_metadata(n=len(feed_vals))
         stall = _time.perf_counter() - t0
 
-        _verify_before_run(program, set(feed_vals), fetch_names)
+        with _span('exe/prepare'):
+            _verify_before_run(program, set(feed_vals), fetch_names)
 
-        state, persist_written, out_state_names = self._gather_state(
-            program, scope)
-        missing = sorted(persist_written - set(state))
-        if missing:
-            raise RuntimeError(
-                "run_steps: state %r is written by the program but absent "
-                "from the scope — run the startup program first so every "
-                "state var is materialized (a scan carry cannot create "
-                "entries mid-loop)" % (missing,))
+            state, persist_written, out_state_names = self._gather_state(
+                program, scope)
+            missing = sorted(persist_written - set(state))
+            if missing:
+                raise RuntimeError(
+                    "run_steps: state %r is written by the program but "
+                    "absent from the scope — run the startup program first "
+                    "so every state var is materialized (a scan carry "
+                    "cannot create entries mid-loop)" % (missing,))
 
-        key = self._cache_key(program, feed_vals, fetch_names, state,
-                              out_state_names) + ('multi', k, fetch_policy)
-        fn = self._cache.get(key)
+            key = self._cache_key(program, feed_vals, fetch_names, state,
+                                  out_state_names) + ('multi', k,
+                                                      fetch_policy)
+            fn = self._cache.get(key)
         if fn is None:
-            self._evict_stale(program)
-            fn = self._build_multi(program, tuple(sorted(feed_vals)),
-                                   tuple(fetch_names),
-                                   out_state_names, k, fetch_policy)
-            self._cache[key] = fn
-            self._cache_index.setdefault(program._uid, set()).add(key)
+            with _span('exe/build'):
+                self._evict_stale(program)
+                fn = self._build_multi(program, tuple(sorted(feed_vals)),
+                                       tuple(fetch_names),
+                                       out_state_names, k, fetch_policy)
+                self._cache[key] = fn
+                self._cache_index.setdefault(program._uid, set()).add(key)
 
         step0 = self._step_counters.get(program._uid, 0)
         self._step_counters[program._uid] = step0 + k
+        sp.set_metadata(program=program._uid, step=step0)
         from .core import config as _config
-        rngs = self._host_rng_group(self._step_seed(program),
-                                    _config.rng_impl(), step0, k)
+        with _span('exe/rng'):
+            rngs = self._host_rng_group(self._step_seed(program),
+                                        _config.rng_impl(), step0, k)
 
-        fetches, new_state = self._dispatch(
-            fn, state, feed_vals, rngs,
-            'executor_run_steps#%d' % program._uid)
+        fetches, new_state = self._dispatch(fn, state, feed_vals, rngs)
 
         st = self._dispatch_stats
         st['dispatches'] += 1
@@ -703,6 +741,8 @@ class Executor(object):
             fetches = ys if fetch_policy == 'stack' else last_f
             new_state = {n: st[n] for n in out_state_names if n in st}
             return fetches, new_state
+        step_k.__name__ = step_k.__qualname__ = _step_name(program,
+                                                           multi=True)
 
         return self._pin_and_call(
             jax.jit(step_k, donate_argnums=(0,)),
@@ -1165,6 +1205,7 @@ class Executor(object):
                out_state_names, mesh=None, feed_vals=None):
         step = self._trace_step_fn(program, fetch_names, out_state_names,
                                    mesh)
+        step.__name__ = step.__qualname__ = _step_name(program)
 
         if mesh is None:
             return self._pin_and_call(
@@ -1228,6 +1269,7 @@ class Executor(object):
                     v, state_shardings.get(n, rep))
                 for n, v in new_state.items()}
             return fetches, new_state
+        step.__name__ = step.__qualname__ = base_step.__name__
         jitted = jax.jit(step, donate_argnums=(0,))
 
         def _place_feed(n, v):
@@ -1263,10 +1305,11 @@ class Executor(object):
         def run_with_mesh(state, feed, rng):
             # place inputs on the mesh (resharding no-op when already there);
             # jit compiles to the arg shardings, GSPMD does the rest
-            state = {n: _mesh_put(v, state_shardings.get(n, rep))
-                     for n, v in state.items()}
-            feed = {n: _place_feed(n, v) for n, v in feed.items()}
-            rng = _mesh_put(rng, rep)
+            with _span('exe/place', n=len(state) + len(feed) + 1):
+                state = {n: _mesh_put(v, state_shardings.get(n, rep))
+                         for n, v in state.items()}
+                feed = {n: _place_feed(n, v) for n, v in feed.items()}
+                rng = _mesh_put(rng, rep)
             fn = fn_box[0]
             if fn is None:
                 from .core import compile_cache as _cc

@@ -53,6 +53,7 @@ fixed [max_slots, K+1] compiled shape as masked pad rows.
 Framework-free: imports only stdlib + numpy + jax (+ sibling serve.py /
 batching.py for the artifact AOT helpers and the shedding exceptions).
 """
+import itertools
 import json
 import os
 import queue
@@ -79,6 +80,12 @@ _STOP = object()
 _WAKE = object()   # no-op queue item: rouse an idle scheduler (drain)
 _SOURCE_SEQ = _serve._SOURCE_SEQ
 _maybe_profiler = _serve._maybe_profiler
+# the program's one span primitive (profiler.span), reached through
+# serve.py so this module stays framework-free. Names are
+# 'decode/<what>'; ids ride in stats. Inert unless a jax profiler trace
+# is running.
+_span = _serve.span
+_REQUEST_SEQ = itertools.count(1)   # process-wide: joins one request's spans
 select_bucket = _batching.select_bucket
 ServerOverloaded = _batching.ServerOverloaded
 DeadlineExceeded = _batching.DeadlineExceeded
@@ -465,12 +472,13 @@ class _Request(object):
                  'scores', 'finished', 'hyps', 't_first', 't_last',
                  'tables', 'next_start', 'prefilling', 'match',
                  'match_epoch', 'draft_strikes', 'draft_cooldown',
-                 'request_id')
+                 'request_id', 'seq')
 
     def __init__(self, prompt, max_new, beam, stream, deadline_ms,
                  request_id=None):
         self.prompt = prompt
         self.request_id = request_id      # caller trace id (gateway)
+        self.seq = next(_REQUEST_SEQ)     # the 'request' stat of its spans
         self.max_new = max_new
         self.beam = beam                  # None = greedy
         self.stream = stream
@@ -497,6 +505,21 @@ class _Request(object):
         self.draft_cooldown = 0           # plain ticks before re-drafting
 
 
+def _req_span(name, req, **stats):
+    """A span of one request's life: the `request` stat joins them
+    submit -> admit -> slices -> first token -> finish; the caller's
+    trace id (the gateway's request_id) rides along when there is one."""
+    if req.request_id is not None:
+        stats['request_id'] = str(req.request_id)
+    return _span(name, request=req.seq, **stats)
+
+
+def _fail(req, exc):
+    """Resolve a request's stream to an error, on the record."""
+    with _req_span('decode/finish', req, outcome=type(exc).__name__):
+        req.stream._fail(exc)
+
+
 class _DecodeModule(object):
     """One exported decode program: lazy StableHLO deserialize, AOT
     warm-start sidecar (zero compiles when present), fresh bookkept jit
@@ -504,9 +527,13 @@ class _DecodeModule(object):
     bookkeeping guards the cold path; the sidecar carries certified
     aliasing for the warm path)."""
 
-    def __init__(self, d, donate_state, device=None, aot_tag=None):
-        with open(os.path.join(d, _serve._MODULE), 'rb') as f:
-            self._module_bytes = f.read()
+    def __init__(self, d, donate_state, device=None, aot_tag=None,
+                 name='program'):
+        self.name = name      # the 'program' stat of its dispatch spans
+        with _span('load/read') as sp:
+            with open(os.path.join(d, _serve._MODULE), 'rb') as f:
+                self._module_bytes = f.read()
+            sp.set_metadata(bytes=len(self._module_bytes))
         self._donate = bool(donate_state)
         self._fn = None
         self._aot = None
@@ -528,12 +555,16 @@ class _DecodeModule(object):
             from jax import export as jexport
             exp = jexport.deserialize(self._module_bytes)
             kw = {'donate_argnums': (0,)} if self._donate else {}
-            self._fn = jax.jit(exp.call, **kw)
+            self._fn = jax.jit(_serve._named_call(exp), **kw)
         return self._fn
 
     def call(self, *args):
+        """THE one dispatch site of the decode programs (step, verify,
+        chunk, prefill, blockcopy, reorder): returns once the call is
+        enqueued."""
         fn = self._aot if self._aot is not None else self._jitted()
-        with warnings.catch_warnings():
+        with _span('decode/dispatch', program=self.name), \
+                warnings.catch_warnings():
             # backends without donation support (XLA:CPU) warn per call;
             # the fallback is a copy, not a correctness issue
             warnings.filterwarnings(
@@ -561,7 +592,7 @@ def _precompile_decode_dir(d, state_specs, arg_specs, donate,
                        for s, ns in zip(state_specs,
                                         mesh_ctx['state_ns'])]
         with _serve._fresh_compile(mesh_ctx['platform']):
-            compiled = jax.jit(exp.call, **kw).lower(
+            compiled = jax.jit(_serve._named_call(exp), **kw).lower(
                 state_specs, *arg_specs).compile()
         return _serve._save_aot(
             os.path.join(d, _serve._AOT_SIDECAR % mesh_ctx['tag']),
@@ -569,7 +600,7 @@ def _precompile_decode_dir(d, state_specs, arg_specs, donate,
     plat = platform or _serve._aot_platform()
     dev = jax.devices(plat)[0]
     with jax.default_device(dev), _serve._fresh_compile(plat):
-        compiled = jax.jit(exp.call, **kw).lower(
+        compiled = jax.jit(_serve._named_call(exp), **kw).lower(
             state_specs, *arg_specs).compile()
     return _serve._save_aot(os.path.join(d, _serve._AOT_SIDECAR % plat),
                             compiled, _serve._module_sha(module_bytes))
@@ -718,10 +749,10 @@ class DecodingPredictor(object):
             self._device = jax.devices(platform)[0] if platform else None
         self._step_mod = _DecodeModule(
             os.path.join(artifact_dir, _STEP_DIR), donate_state=True,
-            device=self._device, aot_tag=aot_tag)
+            device=self._device, aot_tag=aot_tag, name='step')
         self._reorder_mod = _DecodeModule(
             os.path.join(artifact_dir, _REORDER_DIR), donate_state=False,
-            device=self._device, aot_tag=aot_tag)
+            device=self._device, aot_tag=aot_tag, name='reorder')
         self._step_feeds = [e['name'] for e in self._sig['step']['feeds']]
         # speculative decoding (ISSUE 17): load the verify program when
         # the artifact carries one; attach a drafter only on request
@@ -732,7 +763,8 @@ class DecodingPredictor(object):
         if vsig is not None:
             self._verify_mod = _DecodeModule(
                 os.path.join(artifact_dir, _VERIFY_DIR),
-                donate_state=True, device=self._device, aot_tag=aot_tag)
+                donate_state=True, device=self._device, aot_tag=aot_tag,
+                name='verify')
             self._verify_feeds = [e['name'] for e in vsig['feeds']]
             self._K = int(vsig['draft_k'])
         if draft is not None:
@@ -770,14 +802,15 @@ class DecodingPredictor(object):
                 c: _DecodeModule(
                     os.path.join(artifact_dir, _CHUNK_DIR % c),
                     donate_state=True, device=self._device,
-                    aot_tag=aot_tag)
+                    aot_tag=aot_tag, name='chunk_%d' % c)
                 for c in self._chunks}
             self._chunk_feeds = {
                 c: [e['name'] for e in self._sig['chunk'][str(c)]['feeds']]
                 for c in self._chunks}
             self._blockcopy_mod = _DecodeModule(
                 os.path.join(artifact_dir, _BLOCKCOPY_DIR),
-                donate_state=True, device=self._device, aot_tag=aot_tag)
+                donate_state=True, device=self._device, aot_tag=aot_tag,
+                name='blockcopy')
             self._buckets = list(self._chunks)
         else:
             # sorted once at load: select_bucket prefers the smallest
@@ -789,7 +822,7 @@ class DecodingPredictor(object):
                 b: _DecodeModule(
                     os.path.join(artifact_dir, _PREFILL_DIR % b),
                     donate_state=True, device=self._device,
-                    aot_tag=aot_tag)
+                    aot_tag=aot_tag, name='prefill_%d' % b)
                 for b in self._buckets}
             self._prefill_feeds = {
                 b: [e['name']
@@ -807,7 +840,9 @@ class DecodingPredictor(object):
         # tier rides the stats into serving_report's tier column
         self.stats.tier = ('int8' if self._sig.get('kv_cache_dtype')
                            == 'int8' else 'bf16')
-        self._reset_state()
+        with _span('load/reset_state'):
+            self._reset_state()
+        self._tick = 0                    # the 'tick' stat of decode/tick
         self._sched_t = threading.Thread(
             target=self._sched_loop, name='ptpu-decode-sched', daemon=True)
         self._sched_t.start()
@@ -859,6 +894,12 @@ class DecodingPredictor(object):
         shed with ServerOverloaded before any device work. `request_id`
         is an optional caller trace id, named in every shed/expiry
         message and surfaced in stats `recent_failures`."""
+        with _span('decode/submit') as sp:
+            return self._submit(sp, prompt_ids, max_new_tokens, beam,
+                                deadline_ms, request_id)
+
+    def _submit(self, sp, prompt_ids, max_new_tokens, beam, deadline_ms,
+                request_id):
         if self._closed:
             raise RuntimeError('DecodingPredictor is closed')
         beam = int(beam) if beam else None
@@ -911,6 +952,7 @@ class DecodingPredictor(object):
             return stream
         req = _Request(prompt, max_new, beam, stream, deadline_ms,
                        request_id=request_id)
+        sp.set_metadata(request=req.seq, prompt_len=int(prompt.size))
         with self._lifecycle:
             if self._closed:
                 raise RuntimeError('DecodingPredictor is closed')
@@ -1072,6 +1114,21 @@ class DecodingPredictor(object):
             self.stats.block_source = self._blocks.stats
             self.stats.block_reset = self._blocks.reset_counters
 
+    def _to_host(self, fetch, program):
+        """The logits of one dispatch as a host array, in two spans: the
+        wait for the device to finish the program (launch latency and
+        the program's own time sit here), then what is left of the
+        device-to-host copy. The copy is queued behind the program
+        FIRST, as a bare np.asarray would queue it: waiting for the
+        program before asking for the copy would put a host wake-up
+        between the two (measured: +2 % on the inter-token gap)."""
+        import jax
+        fetch.copy_to_host_async()
+        with _span('decode/device_wait', program=program):
+            jax.block_until_ready(fetch)
+        with _span('decode/d2h', program=program, bytes=int(fetch.nbytes)):
+            return np.asarray(fetch)
+
     def _dispatch_step(self, tokens, pos, tables=None):
         feed = {'tokens': tokens, 'pos': pos}
         if tables is not None:
@@ -1083,7 +1140,7 @@ class DecodingPredictor(object):
         self._state = list(new_state)
         with self.stats._lock:
             self.stats.steps += 1
-        return np.asarray(fetches[0])                      # [S, V] sync
+        return self._to_host(fetches[0], 'step')           # [S, V] sync
 
     def _dispatch_verify(self, tokens, pos, tables=None):
         """One speculative verify dispatch (ISSUE 17): tokens/pos are
@@ -1100,7 +1157,7 @@ class DecodingPredictor(object):
         self._state = list(new_state)
         with self.stats._lock:
             self.stats.verify_steps += 1
-        return np.asarray(fetches[0])                   # [S, K+1, V] sync
+        return self._to_host(fetches[0], 'verify')      # [S, K+1, V] sync
 
     def _dispatch_prefill(self, bucket, padded, plen, slot):
         feed = {'prompt_ids': padded,
@@ -1113,7 +1170,8 @@ class DecodingPredictor(object):
         self._state = list(new_state)
         with self.stats._lock:
             self.stats.prefills += 1
-        return np.asarray(fetches[0])[0]                   # [V] sync
+        return self._to_host(fetches[0],                   # [V] sync
+                             self._prefill_mods[bucket].name)[0]
 
     def _dispatch_chunk(self, size, ids, start, take, table_row):
         """One chunked-prefill slice: `take` real rows of one prompt at
@@ -1131,7 +1189,8 @@ class DecodingPredictor(object):
         with self.stats._lock:
             self.stats.prefills += 1
             self.stats.chunk_slices += 1
-        return np.asarray(fetches[0])[0]                   # [V] sync
+        return self._to_host(fetches[0],                   # [V] sync
+                             self._chunk_mods[size].name)[0]
 
     def _dispatch_blockcopy(self, pairs):
         """One block-copy dispatch: every (dst, src) PHYSICAL-BLOCK pair
@@ -1213,37 +1272,46 @@ class DecodingPredictor(object):
             if item is not None:
                 waiting.append(item)
                 continue  # keep draining submissions before dispatching
-            t0 = time.perf_counter()
+            self._tick += 1
+            with _span('decode/tick', tick=self._tick):
+                self._run_tick(waiting)
+            if self._draining and not waiting \
+                    and not any(s is not None for s in self._slots):
+                self._idle_evt.set()
+
+    def _run_tick(self, waiting):
+        """One scheduler iteration with work to look at — the interval
+        stats.busy_s times: expire, admit, one prefill slice per
+        admitting request, one step of the running batch."""
+        t0 = time.perf_counter()
+        with _span('decode/expire'):
             if self._draining:
                 # scale-in drain: shed the waiting queue loudly (safe to
                 # re-route — never dispatched); active streams keep
                 # stepping to completion below
                 self._shed_waiting(waiting)
             self._expire(waiting)
-            if not self._draining:
+        if not self._draining:
+            with _span('decode/admit') as sp:
+                sp.set_metadata(admitted=(
+                    self._admit_block(waiting) if self._layout == 'block'
+                    else self._admit(waiting)))
+        if any(s is not None for s in self._slots):
+            try:
                 if self._layout == 'block':
-                    self._admit_block(waiting)
+                    # one prefill slice per admitting request, then one
+                    # step for the running batch: a long prompt
+                    # interleaves instead of stalling every stream
+                    self._prefill_tick()
+                    if any(e is not None and not e[0].prefilling
+                           for e in self._slots):
+                        self._step_block(waiting)
                 else:
-                    self._admit(waiting)
-            if any(s is not None for s in self._slots):
-                try:
-                    if self._layout == 'block':
-                        # one prefill slice per admitting request, then
-                        # one step for the running batch: a long prompt
-                        # interleaves instead of stalling every stream
-                        self._prefill_tick()
-                        if any(e is not None and not e[0].prefilling
-                               for e in self._slots):
-                            self._step_block(waiting)
-                    else:
-                        self._step()
-                except Exception as e:
-                    self._fail_all(e, waiting)
-                with self.stats._lock:
-                    self.stats.busy_s += time.perf_counter() - t0
-            if self._draining and not waiting \
-                    and not any(s is not None for s in self._slots):
-                self._idle_evt.set()
+                    self._step()
+            except Exception as e:
+                self._fail_all(e, waiting)
+            with self.stats._lock:
+                self.stats.busy_s += time.perf_counter() - t0
 
     def _shed_waiting(self, waiting):
         """drain() in progress: fail every WAITING request with
@@ -1257,7 +1325,7 @@ class DecodingPredictor(object):
                 self.stats.shed += 1
                 self.stats.drained += 1
             self.stats.record_failure(req.request_id, 'drained')
-            req.stream._fail(ServerOverloaded(
+            _fail(req, ServerOverloaded(
                 'request shed: endpoint draining for scale-in%s'
                 % (' (request %s)' % req.request_id
                    if req.request_id else '')))
@@ -1266,12 +1334,12 @@ class DecodingPredictor(object):
         err = RuntimeError('DecodingPredictor closed')
         for req in self._active_requests():
             self._release(req)
-            req.stream._fail(err)
+            _fail(req, err)
         for req in waiting:
             self._drop_match(req)
             with self.stats._lock:
                 self.stats.queue_depth -= 1
-            req.stream._fail(err)
+            _fail(req, err)
         while True:
             try:
                 req = self._queue.get_nowait()
@@ -1280,7 +1348,7 @@ class DecodingPredictor(object):
             if req is not _STOP:
                 with self.stats._lock:
                     self.stats.queue_depth -= 1
-                req.stream._fail(err)
+                _fail(req, err)
 
     def _expire(self, waiting):
         now = time.perf_counter()
@@ -1296,10 +1364,10 @@ class DecodingPredictor(object):
                     if not cancelled:
                         self.stats.expired += 1
                 if cancelled:
-                    req.stream._fail(RuntimeError('request cancelled'))
+                    _fail(req, RuntimeError('request cancelled'))
                 else:
                     self.stats.record_failure(req.request_id, 'expired')
-                    req.stream._fail(DeadlineExceeded(
+                    _fail(req, DeadlineExceeded(
                         'request expired after %.1f ms in queue%s'
                         % ((now - req.t_submit) * 1e3,
                            ' (request %s)' % req.request_id
@@ -1315,12 +1383,12 @@ class DecodingPredictor(object):
                                          and now > req.deadline):
                 self._release(req)
                 if req.stream._cancelled:
-                    req.stream._fail(RuntimeError('request cancelled'))
+                    _fail(req, RuntimeError('request cancelled'))
                 else:
                     with self.stats._lock:
                         self.stats.expired += 1
                     self.stats.record_failure(req.request_id, 'expired')
-                    req.stream._fail(DeadlineExceeded(
+                    _fail(req, DeadlineExceeded(
                         'deadline elapsed mid-decode after %d token(s); '
                         'slot freed%s'
                         % (req.produced,
@@ -1330,17 +1398,20 @@ class DecodingPredictor(object):
     def _admit(self, waiting):
         """Strict-FIFO admission at the step boundary: one prefill
         dispatch per admitted request; beam requests wait for enough
-        free slots."""
+        free slots. Returns how many it admitted."""
+        admitted = 0
         while waiting:
             req = waiting[0]
             need = req.beam or 1
             free = self._free_slots()
             if len(free) < need:
-                return
-            waiting.popleft()
-            with self.stats._lock:
-                self.stats.queue_depth -= 1
-            req.slots = free[:need]
+                return admitted
+            with self._admit_span(req, 0):
+                waiting.popleft()
+                with self.stats._lock:
+                    self.stats.queue_depth -= 1
+                req.slots = free[:need]
+            admitted += 1
             try:
                 self._prefill(req)
             except Exception as e:
@@ -1349,19 +1420,32 @@ class DecodingPredictor(object):
                 # as a step failure, so recover the same way (fail the
                 # co-resident requests loudly, rebuild zero state)
                 self._release(req)
-                req.stream._fail(e)
+                _fail(req, e)
                 self._fail_all(e, waiting)
-                return
+                return admitted
+        return admitted
+
+    def _admit_span(self, req, covered):
+        """The marker of one admission: how long the request queued."""
+        return _span('decode/admit_request', request=req.seq,
+                     waited_us=int((time.perf_counter() - req.t_submit)
+                                   * 1e6),
+                     prompt_len=int(req.prompt.size),
+                     prefix_covered=int(covered))
 
     def _prefill(self, req):
         plen = int(req.prompt.size)
         bucket = select_bucket(self._buckets, plen)
-        padded = np.zeros((1, bucket), np.int64)
-        padded[0, :plen] = req.prompt
-        logits = self._dispatch_prefill(bucket, padded, plen, req.slots[0])
-        for i, s in enumerate(req.slots):
-            self._slots[s] = (req, i)
-        self._first_token(req, logits)
+        with _span('decode/prefill_slice', request=req.seq, size=bucket,
+                   take=plen, start=0):
+            padded = np.zeros((1, bucket), np.int64)
+            padded[0, :plen] = req.prompt
+            logits = self._dispatch_prefill(bucket, padded, plen,
+                                            req.slots[0])
+            for i, s in enumerate(req.slots):
+                self._slots[s] = (req, i)
+            with _req_span('decode/first_token', req):
+                self._first_token(req, logits)
 
     def _first_token(self, req, logits):
         """Emit a request's first token from its prompt logits: greedy
@@ -1413,13 +1497,14 @@ class DecodingPredictor(object):
         skips allocating (and later prefilling) the covered span; the
         match is cached on the request across attempts, so its refs pin
         the matched blocks against eviction while the request waits at
-        the head of the queue."""
+        the head of the queue. Returns how many it admitted."""
+        admitted = 0
         while waiting:
             req = waiting[0]
             need = req.beam or 1
             free = self._free_slots()
             if len(free) < need:
-                return
+                return admitted
             plen = int(req.prompt.size)
             if req.match is None or (not req.match[0] and
                                      req.match_epoch
@@ -1439,7 +1524,7 @@ class DecodingPredictor(object):
                     self._blocks.blocks_for(plen) - len(shared))
             except BlockPoolExhausted:
                 if self._active_requests():
-                    return   # head-of-line waits for blocks to free
+                    return admitted  # head-of-line waits for free blocks
                 # nothing running will ever free blocks: this prompt can
                 # never fit — shed loudly instead of deadlocking
                 waiting.popleft()
@@ -1447,21 +1532,24 @@ class DecodingPredictor(object):
                 with self.stats._lock:
                     self.stats.queue_depth -= 1
                     self.stats.shed += 1
-                req.stream._fail(ServerOverloaded(
+                _fail(req, ServerOverloaded(
                     'KV block pool exhausted: prompt of %d token(s) '
                     'needs more blocks than the pool can free'
                     % plen))
                 continue
-            waiting.popleft()
-            req.match = None        # refs transferred into the table
-            with self.stats._lock:
-                self.stats.queue_depth -= 1
-            req.tables = [list(shared) + list(fresh)]
-            req.next_start = int(covered)
-            req.prefilling = True
-            req.slots = free[:need]
-            for i, s in enumerate(req.slots):
-                self._slots[s] = (req, i)
+            with self._admit_span(req, covered):
+                waiting.popleft()
+                req.match = None        # refs transferred into the table
+                with self.stats._lock:
+                    self.stats.queue_depth -= 1
+                req.tables = [list(shared) + list(fresh)]
+                req.next_start = int(covered)
+                req.prefilling = True
+                req.slots = free[:need]
+                for i, s in enumerate(req.slots):
+                    self._slots[s] = (req, i)
+            admitted += 1
+        return admitted
 
     def _prefill_tick(self):
         """One chunked-prefill slice per ADMITTING request: the
@@ -1478,19 +1566,23 @@ class DecodingPredictor(object):
             size = select_bucket(self._chunks,
                                  min(remaining, self._chunks[-1]))
             take = min(size, remaining)
-            ids = np.zeros((1, size), np.int64)
-            ids[0, :take] = req.prompt[req.next_start:
-                                       req.next_start + take]
-            logits = self._dispatch_chunk(size, ids, req.next_start,
-                                          take,
-                                          self._table_row(req.tables[0]))
-            req.next_start += take
-            if req.next_start < plen:
-                continue
-            req.prefilling = False
-            # publish the prompt's FULL blocks for prefix reuse (the
-            # partial tail stays private: decode writes land there)
-            self._blocks.register_prefix(req.prompt, req.tables[0])
+            with _span('decode/prefill_slice', request=req.seq, size=size,
+                       take=take, start=req.next_start):
+                self._prefill_slice(req, size, take)
+
+    def _prefill_slice(self, req, size, take):
+        ids = np.zeros((1, size), np.int64)
+        ids[0, :take] = req.prompt[req.next_start:req.next_start + take]
+        logits = self._dispatch_chunk(size, ids, req.next_start, take,
+                                      self._table_row(req.tables[0]))
+        req.next_start += take
+        if req.next_start < int(req.prompt.size):
+            return
+        req.prefilling = False
+        # publish the prompt's FULL blocks for prefix reuse (the
+        # partial tail stays private: decode writes land there)
+        self._blocks.register_prefix(req.prompt, req.tables[0])
+        with _req_span('decode/first_token', req):
             self._first_token(req, logits)
 
     def _live_rows(self, skip=()):
@@ -1563,7 +1655,7 @@ class DecodingPredictor(object):
             self._release(victim)
             with self.stats._lock:
                 self.stats.shed += 1
-            victim.stream._fail(MidStreamEvicted(
+            _fail(victim, MidStreamEvicted(
                 'evicted under KV block-pool pressure after %d '
                 'token(s): pool fully pinned by older requests'
                 % victim.produced))
@@ -1595,9 +1687,31 @@ class DecodingPredictor(object):
         slots holding drafts ride ONE verify dispatch first (ISSUE 17)
         and the plain step below covers only the undrafted remainder —
         a fully-drafted batch skips the plain dispatch entirely."""
-        drafted = self._collect_drafts()
-        if drafted:
-            self._verify_block(drafted, waiting)
+        with _span('decode/step') as sp:
+            with _span('decode/build_feed'):
+                drafted = self._collect_drafts()
+            if drafted:
+                self._verify_block(drafted, waiting)
+            with _span('decode/build_feed'):
+                tokens, pos, tables, cow, active = self._step_feed_block(
+                    waiting, drafted)
+            sp.set_metadata(active=active)
+            if not active:
+                return   # every live stream drafted (or shed): no plain step
+            with self.stats._lock:
+                self.stats.active_slot_steps += active
+                self.stats.slot_steps += self._S
+            if cow:
+                self._dispatch_blockcopy(cow)
+            logits = self._dispatch_step(tokens, pos, tables=tables)
+            with _span('decode/advance', rows=active):
+                self._advance_block(logits, drafted)
+
+    def _step_feed_block(self, waiting, drafted):
+        """The plain step's feed over the block pool: reserve and make
+        writable every block this step writes, then fill tokens / pos /
+        tables for the live undrafted rows. Returns them with the CoW
+        pairs to copy first and the number of live rows."""
         tokens = np.zeros((self._S, 1), np.int64)
         pos = np.zeros((self._S, 1), np.int32)
         tables = np.full((self._S, self._maxb), self._trash, np.int32)
@@ -1615,14 +1729,12 @@ class DecodingPredictor(object):
             pos[s, 0] = p
             table = req.tables[bi]
             tables[s, :len(table)] = table
-        if not active:
-            return   # every live stream drafted (or shed): no plain step
-        with self.stats._lock:
-            self.stats.active_slot_steps += active
-            self.stats.slot_steps += self._S
-        if cow:
-            self._dispatch_blockcopy(cow)
-        logits = self._dispatch_step(tokens, pos, tables=tables)
+        return tokens, pos, tables, cow, active
+
+    def _advance_block(self, logits, drafted):
+        """After the step: argmax / beam scoring over the fetched
+        logits, emit to the streams, finish what ended. Argmax and emit
+        interleave per request, so one span holds both."""
         now = time.perf_counter()
         for req in self._active_requests():
             if req.prefilling or req in drafted:
@@ -1759,26 +1871,28 @@ class DecodingPredictor(object):
         write-before-attend program order overwrites them before any
         mask admits them."""
         R = self._K + 1
-        tokens = np.zeros((self._S, R), np.int64)
-        pos = np.full((self._S, R), self._T, np.int32)
-        live = self._active_requests()
-        rows = [(req, d) for req, d in drafted.items() if req in live]
+        with _span('decode/build_feed'):
+            tokens = np.zeros((self._S, R), np.int64)
+            pos = np.full((self._S, R), self._T, np.int32)
+            live = self._active_requests()
+            rows = [(req, d) for req, d in drafted.items() if req in live]
+            for req, draft in rows:
+                s = req.slots[0]
+                p = int(req.prompt.size) + req.produced - 1
+                k = len(draft)
+                tokens[s, 0] = req.last_tokens[0]
+                tokens[s, 1:1 + k] = draft
+                pos[s, :k + 1] = p + np.arange(k + 1, dtype=np.int32)
         if not rows:
             return
-        for req, draft in rows:
-            s = req.slots[0]
-            p = int(req.prompt.size) + req.produced - 1
-            k = len(draft)
-            tokens[s, 0] = req.last_tokens[0]
-            tokens[s, 1:1 + k] = draft
-            pos[s, :k + 1] = p + np.arange(k + 1, dtype=np.int32)
         with self.stats._lock:
             self.stats.active_slot_steps += len(rows)
             self.stats.slot_steps += self._S
         logits = self._dispatch_verify(tokens, pos)
-        now = time.perf_counter()
-        for req, draft in rows:
-            self._advance_spec(req, draft, logits[req.slots[0]], now)
+        with _span('decode/advance', rows=len(rows)):
+            now = time.perf_counter()
+            for req, draft in rows:
+                self._advance_spec(req, draft, logits[req.slots[0]], now)
 
     def _verify_block(self, drafted, waiting):
         """Verify tick, block layout: preflight/extend/CoW every block
@@ -1797,25 +1911,26 @@ class DecodingPredictor(object):
                      len(d) + 1)
                     for req, d in drafted.items() if req in live]
 
-        self._preflight_blocks(waiting, rows_fn=rows_fn)
-        rows = rows_fn()
+        with _span('decode/build_feed'):
+            self._preflight_blocks(waiting, rows_fn=rows_fn)
+            rows = rows_fn()
+            cow = []
+            tokens = np.zeros((self._S, R), np.int64)
+            pos = np.full((self._S, R), pad_pos, np.int32)
+            tables = np.full((self._S, self._maxb), self._trash, np.int32)
+            for req, bi, p, span in rows:
+                for q in range(p, p + span):
+                    self._ensure_writable(req, bi, q, cow)
+                draft = drafted[req]
+                s = req.slots[0]
+                k = len(draft)
+                tokens[s, 0] = req.last_tokens[0]
+                tokens[s, 1:1 + k] = draft
+                pos[s, :k + 1] = p + np.arange(k + 1, dtype=np.int32)
+                table = req.tables[0]
+                tables[s, :len(table)] = table
         if not rows:
             return   # preflight shed every drafted stream
-        cow = []
-        tokens = np.zeros((self._S, R), np.int64)
-        pos = np.full((self._S, R), pad_pos, np.int32)
-        tables = np.full((self._S, self._maxb), self._trash, np.int32)
-        for req, bi, p, span in rows:
-            for q in range(p, p + span):
-                self._ensure_writable(req, bi, q, cow)
-            draft = drafted[req]
-            s = req.slots[0]
-            k = len(draft)
-            tokens[s, 0] = req.last_tokens[0]
-            tokens[s, 1:1 + k] = draft
-            pos[s, :k + 1] = p + np.arange(k + 1, dtype=np.int32)
-            table = req.tables[0]
-            tables[s, :len(table)] = table
         with self.stats._lock:
             self.stats.active_slot_steps += len(rows)
             self.stats.slot_steps += self._S
@@ -1824,17 +1939,19 @@ class DecodingPredictor(object):
         for i in range(0, len(cow), self._S):
             self._dispatch_blockcopy(cow[i:i + self._S])
         logits = self._dispatch_verify(tokens, pos, tables=tables)
-        now = time.perf_counter()
-        for req, bi, p, span in rows:
-            s = req.slots[0]
-            self._advance_spec(req, drafted[req], logits[s], now)
-            if self._slots[s] is not None and self._slots[s][0] is req:
-                # still decoding: positions 0..plen+produced-2 hold real
-                # KV (the newest emitted token writes NEXT tick); drop
-                # the wholly-speculative tail blocks
-                self._blocks.rollback(
-                    req.tables[0],
-                    int(req.prompt.size) + req.produced - 1)
+        with _span('decode/advance', rows=len(rows)):
+            now = time.perf_counter()
+            for req, bi, p, span in rows:
+                s = req.slots[0]
+                self._advance_spec(req, drafted[req], logits[s], now)
+                if self._slots[s] is not None \
+                        and self._slots[s][0] is req:
+                    # still decoding: positions 0..plen+produced-2 hold
+                    # real KV (the newest emitted token writes NEXT
+                    # tick); drop the wholly-speculative tail blocks
+                    self._blocks.rollback(
+                        req.tables[0],
+                        int(req.prompt.size) + req.produced - 1)
 
     def _score_beam(self, req, logits):
         """Fixed-width beam candidate scoring (finished beams
@@ -1882,18 +1999,20 @@ class DecodingPredictor(object):
         req.t_last = now
 
     def _finish_greedy(self, req):
-        self._release(req)
-        with self.stats._lock:
-            self.stats.requests += 1
-        req.stream._finish(list(req.tokens))
+        with _req_span('decode/finish', req, outcome='done'):
+            self._release(req)
+            with self.stats._lock:
+                self.stats.requests += 1
+            req.stream._finish(list(req.tokens))
 
     def _finish_beam(self, req):
-        self._release(req)
-        with self.stats._lock:
-            self.stats.requests += 1
-        ids = np.asarray(req.hyps, np.int64)
-        scores = np.asarray(req.scores, np.float64)
-        req.stream._finish((ids, scores))
+        with _req_span('decode/finish', req, outcome='done'):
+            self._release(req)
+            with self.stats._lock:
+                self.stats.requests += 1
+            ids = np.asarray(req.hyps, np.int64)
+            scores = np.asarray(req.scores, np.float64)
+            req.stream._finish((ids, scores))
 
     def _step(self):
         """One iteration of the continuous batch: every active slot
@@ -1904,9 +2023,24 @@ class DecodingPredictor(object):
         the garbage row is overwritten by a real write before any
         attention mask admits it — and a fully-drafted batch skips the
         plain dispatch entirely."""
-        drafted = self._collect_drafts()
-        if drafted:
-            self._verify_slot(drafted)
+        with _span('decode/step') as sp:
+            with _span('decode/build_feed'):
+                drafted = self._collect_drafts()
+            if drafted:
+                self._verify_slot(drafted)
+            with _span('decode/build_feed'):
+                tokens, pos, active = self._step_feed_slot(drafted)
+            sp.set_metadata(active=active)
+            if not active:
+                return   # every live stream drafted: no plain step
+            with self.stats._lock:
+                self.stats.active_slot_steps += active
+                self.stats.slot_steps += self._S
+            logits = self._dispatch_step(tokens, pos)
+            with _span('decode/advance', rows=active):
+                self._advance_slot(logits, drafted)
+
+    def _step_feed_slot(self, drafted):
         tokens = np.zeros((self._S, 1), np.int64)
         pos = np.zeros((self._S, 1), np.int32)
         active = 0
@@ -1921,12 +2055,9 @@ class DecodingPredictor(object):
             tokens[s, 0] = req.last_tokens[bi]
             # this token writes at position len(prompt) + produced - 1
             pos[s, 0] = req.prompt.size + req.produced - 1
-        if not active:
-            return   # every live stream drafted: no plain step
-        with self.stats._lock:
-            self.stats.active_slot_steps += active
-            self.stats.slot_steps += self._S
-        logits = self._dispatch_step(tokens, pos)
+        return tokens, pos, active
+
+    def _advance_slot(self, logits, drafted):
         now = time.perf_counter()
         src = np.arange(self._S, dtype=np.int32)
         for req in self._active_requests():
@@ -1960,7 +2091,7 @@ class DecodingPredictor(object):
         dead scheduler."""
         for req in self._active_requests():
             self._release(req)
-            req.stream._fail(exc)
+            _fail(req, exc)
         for req in waiting:
             # cached prefix matches hold block ids of the manager the
             # rebuild below discards: a stale HIT would map dead blocks

@@ -27,6 +27,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import shutil
 
 import numpy as np
@@ -586,6 +587,10 @@ def _export_serialize(fn, in_specs, out_dir, shard=None,
     path."""
     import jax
     from jax import export as jexport
+    # the program's name in a device trace (jit_decode_step,
+    # jit_prefill_chunk_32, ...): its artifact directory's, unpadded
+    fn.__name__ = fn.__qualname__ = re.sub(
+        r'_0+(?=\d)', '_', os.path.basename(os.path.normpath(out_dir)))
     if shard is None:
         jitted = jax.jit(fn)
         platforms = ['cpu', 'tpu']
@@ -976,7 +981,7 @@ def export_train_step(program, sample_inputs, fetch_list, out_dir,
             seed = (1234567 if _config.get_flag('deterministic')
                     else _process_entropy())
 
-    def fn(state_list, feed_list, rng_raw):
+    def train_step(state_list, feed_list, rng_raw):
         rng = jax.random.wrap_key_data(rng_raw, impl=rng_impl)
         with amp.scope(amp_on):
             tracer = Tracer(program, rng)
@@ -994,7 +999,7 @@ def export_train_step(program, sample_inputs, fetch_list, out_dir,
                   for n in feed_names]
     key_data = jax.random.key_data(jax.random.key(0, impl=rng_impl))
     rng_spec = jax.ShapeDtypeStruct(key_data.shape, key_data.dtype)
-    exp = jexport.export(jax.jit(fn), platforms=['cpu', 'tpu'])(
+    exp = jexport.export(jax.jit(train_step), platforms=['cpu', 'tpu'])(
         state_specs, feed_specs, rng_spec)
 
     os.makedirs(out_dir, exist_ok=True)

@@ -701,6 +701,10 @@ class Gateway(object):
                     headers=None, t0=None):
         body = json.dumps(obj).encode('utf-8')
         ttfb = (time.perf_counter() - t0) if t0 is not None else None
+        # counted BEFORE the reply goes out: a client that has its reply
+        # must find its own request in the stats it reads next
+        if tenant != '-':
+            self.stats.record(tenant, code, category, ttfb_s=ttfb)
         try:
             handler.send_response(code)
             handler.send_header('Content-Type', 'application/json')
@@ -714,8 +718,6 @@ class Gateway(object):
         except (BrokenPipeError, ConnectionResetError):
             with self._lock:
                 self.stats.disconnects += 1
-        if tenant != '-':
-            self.stats.record(tenant, code, category, ttfb_s=ttfb)
 
     def _reply_error(self, handler, exc, rid, tenant, t0=None):
         code = status_for(exc)
@@ -897,6 +899,7 @@ class Gateway(object):
         ttfb = None
         ttft = None
         n_sent = 0
+        done = False
         try:
             for kind, payload in events:
                 if not headers_out:
@@ -916,19 +919,25 @@ class Gateway(object):
                     n_sent += len(payload)
                     self._sse(handler, None,
                               {'toks': [int(t) for t in payload]})
-                else:  # done
+                else:  # done: counted BEFORE its last frame goes out
+                    # (the _reply_json order), so a client that has its
+                    # whole reply finds its request in the stats
+                    with self._lock:
+                        self.stats.streams += 1
+                    self.stats.record(tenant.name, 200, ttfb_s=ttfb,
+                                      ttft_s=ttft)
+                    done = True
                     self._sse(handler, 'done',
                               {'tokens': [int(t) for t in payload],
                                'n': len(payload), 'request_id': rid})
-            with self._lock:
-                self.stats.streams += 1
-            self.stats.record(tenant.name, 200, ttfb_s=ttfb,
-                              ttft_s=ttft)
         except (BrokenPipeError, ConnectionResetError):
             with self._lock:
                 self.stats.disconnects += 1
-            self.stats.record(tenant.name, 499, category='failed')
+            if not done:
+                self.stats.record(tenant.name, 499, category='failed')
         except Exception as e:
+            if done:        # the last frame's write failed: counted already
+                return
             code = status_for(e)
             if not headers_out:
                 self._reply_error(handler, e, rid, tenant.name, t0=t0)

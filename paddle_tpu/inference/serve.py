@@ -42,6 +42,21 @@ def _maybe_profiler():
     except Exception:
         return None
 
+
+def span(name, **stats):
+    """The program's one span primitive (paddle_tpu.profiler.span) for the
+    framework-free serving modules: with the framework loaded it IS that
+    class (so profiler.profiler() reports the serving spans too); without
+    it, the bare jax.profiler.TraceAnnotation underneath. Either way the
+    span lands in whatever jax profiler trace is running, on the device
+    trace's clock, and is inert when none is."""
+    prof = sys.modules.get('paddle_tpu.profiler')
+    if prof is not None:
+        return prof.span(name, **stats)
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **stats)
+
+
 def _np_threefry_fold(seed, step):
     """fold_in(key(seed), step) raw key data with numpy only — the
     Threefry-2x32 core, bit-identical to jax's (the same math as
@@ -183,6 +198,17 @@ def _pack_executable(compiled):
             'device_ids': [d.id for d in devs]}
 
 
+def _named_call(exp):
+    """`exp.call` under the exported function's own name: a jit of it is
+    then 'jit_<fun_name>' in the compiled module and in a device trace's
+    'XLA Modules' line (train_step, decode_step, prefill_chunk_32, ...),
+    where a jit of the bound method reads 'jit_call' for every program."""
+    def call(*args):
+        return exp.call(*args)
+    call.__name__ = call.__qualname__ = exp.fun_name
+    return call
+
+
 def _load_executable(packed):
     """THE one loader for serialized executables (AOT sidecars here,
     tier-1 entries in core/compile_cache.py): hands jax the client and the
@@ -230,17 +256,23 @@ def _load_aot(path, module_sha):
     import jax
     import jaxlib
     try:
-        with open(path, 'rb') as f:
-            d = pickle.loads(f.read())
-        if d.get('sha') != module_sha:
-            raise ValueError('sidecar was compiled from a different module')
-        if (d.get('jax'), d.get('jaxlib')) != (jax.__version__,
-                                               jaxlib.__version__):
-            raise ValueError(
-                'sidecar built with jax %s / jaxlib %s, process runs %s/%s'
-                % (d.get('jax'), d.get('jaxlib'), jax.__version__,
-                   jaxlib.__version__))
-        return _load_executable(d)
+        with span('load/read') as sp:
+            with open(path, 'rb') as f:
+                blob = f.read()
+            sp.set_metadata(bytes=len(blob))
+        with span('load/deserialize', bytes=len(blob)):
+            d = pickle.loads(blob)
+            del blob
+            if d.get('sha') != module_sha:
+                raise ValueError(
+                    'sidecar was compiled from a different module')
+            if (d.get('jax'), d.get('jaxlib')) != (jax.__version__,
+                                                   jaxlib.__version__):
+                raise ValueError(
+                    'sidecar built with jax %s / jaxlib %s, process runs '
+                    '%s/%s' % (d.get('jax'), d.get('jaxlib'),
+                               jax.__version__, jaxlib.__version__))
+            return _load_executable(d)
     except Exception as e:
         warnings.warn('AOT sidecar %s unusable (%s: %s) — falling back to '
                       'compiling the module; re-run `cache_ctl.py prewarm` '
@@ -276,7 +308,8 @@ def _precompile_infer_dir(d, platform=None):
     dev = jax.devices(plat)[0]
     exp = jexport.deserialize(module_bytes)
     with jax.default_device(dev), _fresh_compile(plat):
-        compiled = jax.jit(exp.call).lower(*_infer_flat_specs(sig)).compile()
+        compiled = jax.jit(_named_call(exp)).lower(
+            *_infer_flat_specs(sig)).compile()
     return _save_aot(os.path.join(d, _AOT_SIDECAR % plat), compiled,
                      _module_sha(module_bytes))
 
@@ -302,8 +335,8 @@ def _precompile_train_dir(d, platform=None):
                                     np.dtype(sig['rng']['key_dtype']))
     exp = jexport.deserialize(module_bytes)
     with jax.default_device(dev), _fresh_compile(plat):
-        compiled = jax.jit(exp.call).lower(state_specs, feed_specs,
-                                           rng_spec).compile()
+        compiled = jax.jit(_named_call(exp)).lower(
+            state_specs, feed_specs, rng_spec).compile()
     return _save_aot(os.path.join(d, _TRAIN_AOT_SIDECAR % plat), compiled,
                      _module_sha(module_bytes))
 

@@ -5,11 +5,16 @@ TPU-native split of responsibilities:
 - DEVICE time: the whole step is one XLA program; jax.profiler traces
   capture per-kernel spans for TensorBoard/Perfetto (subsuming the
   reference's CUPTI DeviceTracer).
-- HOST time: RecordEvent-style spans (`record_event`, plus per-run events
-  the Executor emits while profiling is on) aggregate into the reference's
-  min/max/avg/total report at stop_profiler, and export to Chrome
-  tracing JSON via `export_chrome_tracing` — the tools/timeline.py
-  capability without the proto intermediary.
+- HOST time: `span(name, **stats)` (reference name: `record_event`) is
+  the ONE span primitive of the program — the executor, the compile
+  cache, the passes and (through inference/serve.py) the decode scheduler
+  all use it. It is a jax.profiler.TraceAnnotation, so it lands on the
+  device trace's own clock in ANY running jax profiler trace, whoever
+  started it, and is inert otherwise. Under start_profiler/profiler() the
+  spans also aggregate into the reference's min/max/avg/total report at
+  stop_profiler, and export to Chrome tracing JSON via
+  `export_chrome_tracing` — the tools/timeline.py capability without the
+  proto intermediary.
 """
 from __future__ import annotations
 
@@ -18,6 +23,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 _trace_dir = None
 _events = []            # (name, start_s, dur_s, tid)
@@ -641,13 +648,33 @@ def profiler(state='All', sorted_key=None, profile_path='/tmp/profile',
         stop_profiler(sorted_key, profile_path)
 
 
-@contextlib.contextmanager
-def record_event(name):
-    """Host-side RAII event (ref platform::RecordEvent) — annotates the jax
-    profiler trace when active, and records wall time always."""
-    import jax
-    t0 = time.perf_counter()
-    with jax.profiler.TraceAnnotation(name):
-        yield
-    _events.append((name, t0 - _EPOCH, time.perf_counter() - t0,
-                    threading.get_ident() % 10000))
+class span(_TraceAnnotation):
+    """`with span('<layer>/<what>', **stats):` — a named interval on the
+    calling thread, in whatever jax profiler trace is running (ref
+    platform::RecordEvent). Names carry no ids: those go in `stats`,
+    plain ints/strs already at hand; a stat known only once the work is
+    done is added inside the block with `.set_metadata(k=v)`. With no
+    trace running it costs about a microsecond and records nothing.
+    While `is_profiling()` it also keeps (name, start, dur, tid) for the
+    host-event report and `export_chrome_tracing`."""
+
+    def __init__(self, name, **stats):
+        super().__init__(name, **stats)
+        self._name = name
+        self._t0 = None
+
+    def __enter__(self):
+        if _active:
+            self._t0 = time.perf_counter()
+        return super().__enter__()
+
+    def __exit__(self, *exc):
+        super().__exit__(*exc)
+        if self._t0 is not None:
+            _events.append((self._name, self._t0 - _EPOCH,
+                            time.perf_counter() - self._t0,
+                            threading.get_ident() % 10000))
+        return False
+
+
+record_event = span     # the reference API's name for the same thing

@@ -21,15 +21,17 @@ def test_profiler_events_and_chrome_export(tmp_path, capsys):
             exe.run(feed={'x': np.ones((2, 4), np.float32)},
                     fetch_list=[y])
     out = capsys.readouterr().out
-    # the aggregate report lists the executor's per-run events
-    assert 'executor_run' in out and 'Calls' in out
+    # the aggregate report lists the executor's per-run spans
+    assert 'exe/run' in out and 'exe/dispatch' in out and 'Calls' in out
     path = profiler.export_chrome_tracing(str(tmp_path / 'trace.json'))
     with open(path) as f:
         trace = json.load(f)
-    evs = [e for e in trace['traceEvents']
-           if e['name'].startswith('executor_run')]
-    assert len(evs) >= 3
-    assert all(e['ph'] == 'X' and e['dur'] >= 0 for e in evs)
+    for name in ('exe/run', 'exe/dispatch'):
+        evs = [e for e in trace['traceEvents'] if e['name'] == name]
+        assert len(evs) >= 3
+        assert all(e['ph'] == 'X' and e['dur'] >= 0 for e in evs)
+    # ids ride in stats, never in names
+    assert not any('#' in e['name'] for e in trace['traceEvents'])
 
 
 def test_chunk_evaluator_accumulates():

@@ -1,0 +1,292 @@
+"""The program's own spans (profiler.span over jax.profiler.TraceAnnotation)
+in a jax profiler trace that somebody else started: the decode scheduler's
+and the executor's, their nesting, the stats that join one request's spans,
+what a span costs with no trace running, and the device-side names (Fluid
+op types in op_name metadata, stable names on the jitted programs).
+
+On the cpu backend, read back with jax.profiler.ProfileData — the same way
+benchmark/layer_metrics/_spans.py reads a chip trace."""
+import glob
+import os
+import statistics
+import time
+
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu import profiler
+from paddle_tpu.inference import DecodingPredictor, export_decode
+
+VOCAB, SLOTS, CACHE = 41, 4, 64
+_TOL_NS = 1000      # an event's end is start + duration, both rounded
+
+
+class _Trace(object):
+    """Host spans of one .xplane.pb: (name, start, end, thread, stats)."""
+
+    def __init__(self, trace_dir):
+        from jax.profiler import ProfileData
+        path = max(glob.glob(os.path.join(trace_dir, 'plugins', 'profile',
+                                          '*', '*.xplane.pb')),
+                   key=os.path.getmtime)
+        self.spans = []
+        for plane in ProfileData.from_file(path).planes:
+            if not plane.name.startswith('/host:CPU'):
+                continue
+            for line in plane.lines:
+                for e in line.events:
+                    if '/' in e.name and e.name.split('/')[0] in (
+                            'decode', 'exe', 'load', 'compile', 'pass'):
+                        self.spans.append(
+                            (e.name, e.start_ns, e.start_ns + e.duration_ns,
+                             line.name, dict(e.stats)))
+        self.spans.sort(key=lambda s: s[1])
+
+    def named(self, name):
+        return [s for s in self.spans if s[0] == name]
+
+    def parent_of(self, span, names):
+        """The innermost span named one of `names` that holds `span` on
+        its own thread, or None."""
+        _, s, e, thread, _ = span
+        holders = [p for p in self.spans
+                   if p[0] in names and p[3] == thread and p is not span
+                   and p[1] <= s + _TOL_NS and e <= p[2] + _TOL_NS]
+        return max(holders, key=lambda p: p[1]) if holders else None
+
+
+def _traced(tmp, body):
+    import jax
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(tmp), profiler_options=opts)
+    try:
+        out = body()
+    finally:
+        jax.profiler.stop_trace()
+    return _Trace(str(tmp)), out
+
+
+# -- (a) the decode scheduler ------------------------------------------------
+
+@pytest.fixture(scope='module')
+def decode_art(tmp_path_factory):
+    """A small block-layout decode artifact, built as
+    tests/test_kv_blocks.py builds its own."""
+    from models.transformer import build_decode_spec
+    art = str(tmp_path_factory.mktemp('spans') / 'art')
+    scope = fluid.core.Scope()
+    with fluid.scope_guard(scope), fluid.unique_name.guard():
+        spec = build_decode_spec(
+            vocab=VOCAB, d_model=16, n_head=2, n_layer=2, d_ff=32,
+            max_slots=SLOTS, max_cache_len=CACHE, eos_id=1,
+            prompt_buckets=(4, 8), block_size=4)
+        fluid.Executor(fluid.CPUPlace()).run(spec['startup'])
+        export_decode(spec, art, scope=scope)
+    return art
+
+
+@pytest.fixture(scope='module')
+def decode_trace(decode_art, tmp_path_factory):
+    """Three requests (one carrying a gateway request id, one long enough
+    for two prefill slices) served inside a trace the TEST started — the
+    program is told nothing."""
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(2, VOCAB, n) for n in (3, 11, 6)]
+
+    def serve():
+        with DecodingPredictor(decode_art) as pred:
+            streams = [pred.submit(p, max_new_tokens=4,
+                                   request_id='gw-7' if i == 1 else None)
+                       for i, p in enumerate(prompts)]
+            return [list(s.result(120)) for s in streams]
+
+    trace, tokens = _traced(tmp_path_factory.mktemp('decode_trace'), serve)
+    assert all(tokens)
+    return trace
+
+
+_DECODE_SPANS = ('decode/submit', 'decode/tick', 'decode/expire',
+                 'decode/admit', 'decode/admit_request',
+                 'decode/prefill_slice', 'decode/step', 'decode/build_feed',
+                 'decode/dispatch', 'decode/device_wait', 'decode/d2h',
+                 'decode/advance', 'decode/first_token', 'decode/finish',
+                 'load/read', 'load/reset_state')
+
+
+@pytest.mark.parametrize('name', _DECODE_SPANS)
+def test_decode_span_is_in_the_trace(decode_trace, name):
+    assert decode_trace.named(name), \
+        'no %s span; have %s' % (name, sorted({s[0] for s in
+                                               decode_trace.spans}))
+
+
+@pytest.mark.parametrize('child,parents', [
+    ('decode/expire', ('decode/tick',)),
+    ('decode/admit', ('decode/tick',)),
+    ('decode/admit_request', ('decode/admit',)),
+    ('decode/prefill_slice', ('decode/tick',)),
+    ('decode/step', ('decode/tick',)),
+    ('decode/build_feed', ('decode/step',)),
+    ('decode/advance', ('decode/step',)),
+    ('decode/dispatch', ('decode/step', 'decode/prefill_slice')),
+    ('decode/device_wait', ('decode/step', 'decode/prefill_slice')),
+    ('decode/d2h', ('decode/step', 'decode/prefill_slice')),
+    ('decode/first_token', ('decode/prefill_slice',)),
+    ('decode/finish', ('decode/advance', 'decode/first_token')),
+])
+def test_decode_children_lie_inside_their_parents(decode_trace, child,
+                                                  parents):
+    spans = [s for s in decode_trace.named(child)
+             # the constructor's state reset dispatches outside any tick
+             if s[4].get('program') != 'reorder']
+    assert spans
+    for span in spans:
+        assert decode_trace.parent_of(span, parents) is not None, \
+            '%s at %d has no %s around it on thread %s' % (
+                child, span[1], ' / '.join(parents), span[3])
+
+
+def test_one_request_stat_joins_a_requests_spans(decode_trace):
+    submits = decode_trace.named('decode/submit')
+    assert len(submits) == 3
+    seqs = [s[4]['request'] for s in submits]
+    assert len(set(seqs)) == 3
+    for seq, plen in zip(seqs, (3, 11, 6)):
+        mine = {name: [s for s in decode_trace.named(name)
+                       if s[4].get('request') == seq]
+                for name in ('decode/submit', 'decode/admit_request',
+                             'decode/prefill_slice', 'decode/first_token',
+                             'decode/finish')}
+        assert all(len(v) >= 1 for v in mine.values()), mine
+        assert mine['decode/submit'][0][4]['prompt_len'] == plen
+        admit = mine['decode/admit_request'][0][4]
+        assert admit['prompt_len'] == plen and admit['waited_us'] >= 0
+        assert admit['prefix_covered'] == 0
+        # chunks are 4 and 8 wide: the 11-token prompt takes two slices
+        slices = [s[4] for s in mine['decode/prefill_slice']]
+        assert sum(s['take'] for s in slices) == plen
+        assert len(slices) == (2 if plen == 11 else 1)
+        assert [s['start'] for s in slices] == \
+            [0, 8][:len(slices)]
+        # submit -> admit -> first token -> finish, in that order
+        order = [mine[n][0][1] for n in (
+            'decode/submit', 'decode/admit_request', 'decode/first_token',
+            'decode/finish')]
+        assert order == sorted(order)
+    # the caller's trace id rides on the spans of the request that had one
+    tagged = [s for s in decode_trace.spans if 'request_id' in s[4]]
+    assert {s[4]['request_id'] for s in tagged} == {'gw-7'}
+    assert {s[0] for s in tagged} == {'decode/first_token', 'decode/finish'}
+    assert {s[4]['request'] for s in tagged} == {seqs[1]}
+
+
+def test_step_d2h_bytes_is_slots_x_vocab_x_4(decode_trace):
+    step = [s for s in decode_trace.named('decode/d2h')
+            if s[4]['program'] == 'step']
+    assert step
+    assert {s[4]['bytes'] for s in step} == {SLOTS * VOCAB * 4}
+    programs = {s[4]['program'] for s in decode_trace.named('decode/dispatch')}
+    assert {'step', 'chunk_4', 'chunk_8', 'reorder'} <= programs
+    ticks = [s[4]['tick'] for s in decode_trace.named('decode/tick')]
+    assert ticks == sorted(ticks) and len(set(ticks)) == len(ticks)
+
+
+@pytest.mark.parametrize('sub,name', [
+    ('decode_step', 'decode_step'), ('prefill_chunk_00004', 'prefill_chunk_4'),
+    ('prefill_chunk_00008', 'prefill_chunk_8'),
+    ('decode_reorder', 'decode_reorder'),
+    ('decode_blockcopy', 'decode_blockcopy')])
+def test_exported_decode_programs_have_stable_names(decode_art, sub, name):
+    """What 'XLA Modules' prints as jit_<name> for an AOT-loaded program."""
+    from jax import export as jexport
+    from paddle_tpu.inference import serve
+    with open(os.path.join(decode_art, sub, serve._MODULE), 'rb') as f:
+        exp = jexport.deserialize(f.read())
+    assert exp.fun_name == name
+    assert serve._named_call(exp).__name__ == name
+
+
+# -- (b) the executor --------------------------------------------------------
+
+def test_executor_run_spans(tmp_path):
+    x = fluid.layers.data(name='x', shape=[4], dtype='float32')
+    y = fluid.layers.fc(x, size=2, act='relu')
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    feed = {'x': np.ones((2, 4), np.float32)}
+
+    def three():
+        for _ in range(3):
+            exe.run(feed=feed, fetch_list=[y])
+
+    trace, _ = _traced(tmp_path, three)
+    runs = trace.named('exe/run')
+    assert len(runs) == 3
+    assert [r[4]['step'] for r in runs] == [0, 1, 2]
+    assert len({r[4]['program'] for r in runs}) == 1
+    for i, run in enumerate(runs):
+        inside = [s[0] for s in trace.spans
+                  if s[0].startswith('exe/') and s is not run
+                  and trace.parent_of(s, ('exe/run',)) is run]
+        want = ['exe/feed', 'exe/prepare', 'exe/rng', 'exe/dispatch',
+                'exe/finish']
+        if i == 0:          # the cache miss, and only it, builds
+            want.insert(2, 'exe/build')
+        assert inside == want
+    assert trace.named('exe/feed')[0][4]['n'] == 1
+
+
+# -- (c) what it costs with no trace running ----------------------------------
+
+def test_inactive_spans_record_nothing_and_cost_microseconds():
+    assert not profiler.is_profiling()
+    profiler.reset_profiler()
+    for _ in range(10000):
+        with profiler.record_event('pass/none'):
+            pass
+    assert profiler._events == []
+    costs = []
+    for i in range(2000):
+        t0 = time.perf_counter()
+        with profiler.span('decode/none', request=i, program='step'):
+            pass
+        costs.append(time.perf_counter() - t0)
+    assert statistics.median(costs) < 25e-6
+
+
+# -- (d) the device side: op types in op_name, names on the programs ---------
+
+def _lowered_step(train):
+    import jax
+    x = fluid.layers.data(name='x', shape=[4], dtype='float32')
+    h = fluid.layers.fc(x, size=3, act='relu')
+    fetch = h
+    if train:
+        fetch = fluid.layers.mean(h)
+        fluid.optimizer.SGD(0.1).minimize(fetch)
+    exe = fluid.Executor(fluid.CPUPlace())
+    exe.run(fluid.default_startup_program())
+    prog = fluid.default_main_program()
+    from paddle_tpu import executor
+    state, _, out_names = exe._gather_state(prog, fluid.global_scope())
+    step = exe._trace_step_fn(prog, (fetch.name,), out_names, None)
+    step.__name__ = executor._step_name(prog)
+    rng = exe._host_rng(1, 'threefry2x32', 0)
+    return jax.jit(step).lower(state, {'x': np.ones((2, 4), np.float32)},
+                               rng)
+
+
+@pytest.mark.parametrize('train,module,scopes', [
+    (False, 'jit_program_step', ('/mul/', '/relu/')),
+    (True, 'jit_train_step', ('/mul/', '/relu/', '/mean/', '/mul_grad/',
+                              '/sgd/')),
+])
+def test_lowered_text_names_the_fluid_ops(train, module, scopes):
+    low = _lowered_step(train)
+    text = low.as_text(debug_info=True)
+    assert '@%s' % module in text
+    for scope in scopes:
+        assert scope in text, 'no %s scope in the op_name metadata' % scope
