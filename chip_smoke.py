@@ -19,7 +19,11 @@ this one process — a chip belongs to one process at a time):
               same artifact must load the AOT sidecars and agree.
   K  kernels  fused_multihead_attention where the policy picks the Pallas
               flash kernel (non-causal S=512, causal S=4096), forward and
-              backward, against a plain float32 jax.numpy composition.
+              backward, against a plain float32 jax.numpy composition; and
+              kv_block_attention at the benchmark's decode shape (128 slots,
+              128 x 16-row pages a slot, d_model 512, 8 heads, ragged pos),
+              the op as a TPU program lowers it — the paged Pallas kernel —
+              against its float32 jax.numpy body.
 
 Weights and data are random from fixed seeds; depth is what the builders
 give. One JSON line per phase (platform, device_kind, device count, cache
@@ -44,6 +48,7 @@ import os
 import re
 import sys
 import time
+import types
 import warnings
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -57,6 +62,8 @@ FULL = {
     'prompt_lens': (8, 128), 'max_new': 32,
     # (B, H, S, D, causal): one shape on each side of _flash_policy
     'attn': ((2, 4, 512, 64, False), (1, 2, 4096, 64, True)),
+    'paged': dict(slots=128, num_blocks=16385, block_size=16, d_model=512,
+                  n_head=8, max_blocks=128),
 }
 TOY = {
     'resnet': dict(dshape=(3, 32, 32), class_dim=10, depth=50, batch=8),
@@ -65,6 +72,8 @@ TOY = {
                    chunk_sizes=(8, 16)),
     'prompt_lens': (4, 24), 'max_new': 8,
     'attn': ((1, 2, 512, 64, False),),
+    'paged': dict(slots=16, num_blocks=401, block_size=8, d_model=128,
+                  n_head=2, max_blocks=40),
 }
 # a phase that warns one of these did not run the path it claims to prove
 FALLBACK = re.compile(r'fall(ing|s)? back|fallback|unusable|unavailable',
@@ -280,6 +289,7 @@ class Smoke(object):
             t0 = time.perf_counter()
             pred.warmup()
             warmup_s = time.perf_counter() - t0
+            attention = pred.stats.snapshot()['attention']
             con = concurrent(pred)
             # both arms start from an empty prefix cache (bench.py's rule):
             # else the sequential arm re-serves prompts the first arm cached
@@ -289,6 +299,9 @@ class Smoke(object):
         if con != seq:
             raise AssertionError('continuous transcripts diverged from '
                                  'sequential generate()')
+        if self.cfg is FULL and attention != 'kernel':
+            raise AssertionError('the step serves the %s attention body, '
+                                 'not the paged kernel' % attention)
         # a fresh replica on the same artifact: loads the AOT sidecars (a
         # "falling back to compiling" warning fails the phase), compiles
         # nothing, and serves the same transcripts
@@ -305,7 +318,7 @@ class Smoke(object):
         if reload_compiles:
             raise AssertionError('reloaded predictor compiled %d program(s)'
                                  % reload_compiles)
-        return {'prompt_lens': lens,
+        return {'prompt_lens': lens, 'step_attention': attention,
                 'tokens': sum(len(t) for t in con),
                 'export_s': round(export_s, 2),
                 'module_bytes': module_bytes, 'sidecar_bytes': sidecar_bytes,
@@ -378,7 +391,76 @@ class Smoke(object):
             if worst > 2e-2:
                 raise AssertionError('S=%d causal=%s: kernel vs composition '
                                      'rel err %.4g' % (S, causal, worst))
-        return {'max_rel_err': errs}
+        return {'max_rel_err': errs, 'paged_attention': self._paged()}
+
+    def _paged(self):
+        """kv_block_attention's two bodies on the device, side by side.
+        On a TPU the op itself, as a program lowers it: the compiled
+        program must hold the paged Pallas kernel. On the cpu rehearsal
+        the op lowers to the jnp body, so the kernel is called directly
+        in interpret mode. Half the slots live at ragged positions — 0,
+        the page edges, the kernel's 256-row block edge, the last row of
+        a full table — on shuffled pages; the rest idle on the trash
+        block."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        from paddle_tpu.ops import decode_ops
+        from paddle_tpu.ops import pallas_paged_attention as ppa
+        c = self.cfg['paged']
+        S, NB, BS, D, H, MAXB = (c[k] for k in (
+            'slots', 'num_blocks', 'block_size', 'd_model', 'n_head',
+            'max_blocks'))
+        keys = jax.random.split(jax.random.key(25), 3)
+        kc = jax.random.normal(keys[0], (NB, BS, D), jnp.float32)
+        vc = jax.random.normal(keys[1], (NB, BS, D), jnp.float32)
+        q = jax.random.normal(keys[2], (S, D), jnp.float32)
+        rng = np.random.RandomState(25)
+        last = MAXB * BS - 1
+        live = [0, last, BS - 1, BS, BS + 1, 255, 256] + [
+            int(x) for x in rng.randint(0, last + 1, S // 2 - 7)]
+        pos = np.zeros(S, np.int32)
+        table = np.zeros((S, MAXB), np.int32)
+        free = iter(rng.permutation(np.arange(1, NB)))
+        for s, p in zip(rng.permutation(S)[:len(live)], live):
+            pos[s] = p
+            table[s, :p // BS + 1] = [next(free) for _ in range(p // BS + 1)]
+        args = (q, kc, vc, jnp.asarray(pos), jnp.asarray(table))
+        ctx = types.SimpleNamespace(         # core/lowering.py OpCtx
+            attr=lambda name, default=None: {'n_head': H}.get(name, default),
+            abstract=False,
+            tracer=types.SimpleNamespace(lowered_bodies=[]))
+
+        def op(q, kc, vc, pos, table):
+            return decode_ops._kv_block_attention(
+                ctx, {'Q': [q], 'KCache': [kc], 'VCache': [vc],
+                      'Pos': [pos], 'BlockTable': [table]})['Out'][0]
+
+        if self.dev.platform == 'tpu':
+            kernel = jax.jit(op).lower(*args).compile()
+            if 'kv_block_paged_attention' not in kernel.as_text():
+                raise AssertionError('the op compiled for the TPU does not '
+                                     'hold the paged kernel')
+        else:
+            kernel = jax.jit(lambda *a: ppa.paged_attention(
+                *a, n_head=H, scale=(D // H) ** -0.5, interpret=True))
+        got = np.asarray(kernel(*args))
+        with jax.default_matmul_precision('highest'):
+            want = np.asarray(jax.jit(
+                lambda *a: decode_ops._kv_block_attention_jnp(ctx, *a))(
+                    *args))
+        if not np.isfinite(got).all():
+            raise AssertionError('paged kernel: non-finite output')
+        err = float(np.abs(got - want).max())
+        rel = err / float(np.abs(want).max())
+        # both bodies are float32 throughout and differ by the online
+        # softmax's rounding (~2e-7); a dropped page of a slot's ~64, or
+        # one row too many or too few of up to 2048, moves it by >= 1e-4
+        if rel > 1e-5:
+            raise AssertionError('paged kernel vs jnp body: max abs %.3g, '
+                                 'relative %.3g' % (err, rel))
+        return {'shape': [S, NB, BS, D, H, MAXB], 'live_slots': len(live),
+                'max_abs_err': err, 'max_rel_err': rel}
 
 
 def main(argv=None):

@@ -113,6 +113,10 @@ class Tracer(object):
         # sequence_slice offsets) can read them even under jit
         self.static_lengths = {}
         self.host_consts = {}
+        # (op type, body) from each op that picks between bodies as it
+        # lowers (kv_block_attention: 'kernel' | 'jnp'); export_decode
+        # writes them into the artifact's signature
+        self.lowered_bodies = []
 
     def read(self, name, op):
         if name in self.env:

@@ -183,6 +183,9 @@ class DecodeStats(object):
         # carried a request_id): what a gateway/operator correlates
         self._failures = deque(maxlen=16)
         self.tier = 'bf16'   # KV-cache tier (bf16, or int8 paged cache)
+        # the step's attention body as it runs here: 'kernel' (the paged
+        # Pallas kernel, a TPU's) or 'jnp' (the gathered view)
+        self.attention = 'jnp'
         self.queue_depth = 0
         self.requests = 0        # completed requests
         self.tokens = 0          # tokens decoded (all beams)
@@ -264,6 +267,7 @@ class DecodeStats(object):
                    if self.slot_steps else 0.0)
             snap = {'kind': 'decode',
                     'tier': self.tier,
+                    'attention': self.attention,
                     'queue_depth': int(self.queue_depth),
                     'requests': int(self.requests),
                     'tokens': int(self.tokens),
@@ -840,6 +844,9 @@ class DecodingPredictor(object):
         # tier rides the stats into serving_report's tier column
         self.stats.tier = ('int8' if self._sig.get('kv_cache_dtype')
                            == 'int8' else 'bf16')
+        step_bodies = self.attention_bodies.get('step', {})
+        if set(step_bodies.get('kv_block_attention', ())) == {'kernel'}:
+            self.stats.attention = 'kernel'
         with _span('load/reset_state'):
             self._reset_state()
         self._tick = 0                    # the 'tick' stat of decode/tick
@@ -856,6 +863,33 @@ class DecodingPredictor(object):
             self._profiler_name = name
 
     # -- public API --------------------------------------------------------
+    @property
+    def attention_bodies(self):
+        """{program: {op type: {body: count}}} for the kv_*attention* ops
+        of the loaded programs ('step', 'verify', 'chunk_<C>',
+        'prefill_<L>') as THIS platform runs them: what export_decode
+        wrote into the signature, with 'kernel' — the body a module
+        holds for a TPU — read as 'jnp' anywhere else. Empty for an
+        artifact exported before the signature carried it."""
+        import jax
+        sig = self._sig
+        progs = {'step': sig['step'], 'verify': sig.get('verify', {})}
+        for kind in ('chunk', 'prefill'):
+            for size, entry in sig.get(kind, {}).items():
+                progs['%s_%s' % (kind, size)] = entry
+        platform = (self._mesh_ctx['platform'] if self._mesh_ctx is not None
+                    else (self._device or jax.devices()[0]).platform)
+        out = {}
+        for name, entry in progs.items():
+            bodies = entry.get('attention')
+            if not bodies:
+                continue
+            if platform != 'tpu':
+                bodies = {op: {'jnp': sum(by_body.values())}
+                          for op, by_body in bodies.items()}
+            out[name] = bodies
+        return out
+
     @property
     def max_slots(self):
         return self._S

@@ -454,9 +454,10 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                          % (step_want, step['feeds']))
     os.makedirs(out_dir, exist_ok=True)
 
-    step_feeds = _export_decode_program(
+    step_sig = dict(_export_decode_program(
         step, state_names, state0, scope,
-        os.path.join(out_dir, _decoding._STEP_DIR), shard=shard)
+        os.path.join(out_dir, _decoding._STEP_DIR), shard=shard),
+        fetches=list(step['fetches']))
     verify_sig = None
     verify = spec.get('verify')
     if verify is not None:
@@ -465,13 +466,13 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
         if sorted(verify['feeds']) != step_want:
             raise ValueError("decode-verify feeds must be %r, got %r"
                              % (step_want, verify['feeds']))
-        verify_sig = {
-            'feeds': _export_decode_program(
+        verify_sig = dict(
+            _export_decode_program(
                 verify, state_names, state0, scope,
                 os.path.join(out_dir, _decoding._VERIFY_DIR),
                 shard=shard),
-            'fetches': list(verify['fetches']),
-            'draft_k': int(spec['draft_k'])}
+            fetches=list(verify['fetches']),
+            draft_k=int(spec['draft_k']))
     prefill_sig = {}
     chunk_sig = {}
     if layout == 'block':
@@ -486,12 +487,12 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                 raise ValueError(
                     "chunk feeds must be ['chunk_ids', 'start', "
                     "'chunk_len', 'block_table'], got %r" % (p['feeds'],))
-            chunk_sig[str(C)] = {
-                'feeds': _export_decode_program(
+            chunk_sig[str(C)] = dict(
+                _export_decode_program(
                     p, state_names, state0, scope,
                     os.path.join(out_dir, _decoding._CHUNK_DIR % C),
                     shard=shard),
-                'fetches': list(p['fetches'])}
+                fetches=list(p['fetches']))
         _export_decode_blockcopy(
             state0, int(spec['max_slots']),
             os.path.join(out_dir, _decoding._BLOCKCOPY_DIR), shard=shard)
@@ -507,12 +508,12 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                 raise ValueError(
                     "prefill feeds must be ['prompt_ids', 'prompt_len', "
                     "'slot'], got %r" % (p['feeds'],))
-            prefill_sig[str(L)] = {
-                'feeds': _export_decode_program(
+            prefill_sig[str(L)] = dict(
+                _export_decode_program(
                     p, state_names, state0, scope,
                     os.path.join(out_dir, _decoding._PREFILL_DIR % L),
                     shard=shard),
-                'fetches': list(p['fetches'])}
+                fetches=list(p['fetches']))
         reorder_n = int(spec['max_slots'])
     _export_decode_reorder(state0, reorder_n,
                            os.path.join(out_dir, _decoding._REORDER_DIR),
@@ -530,7 +531,7 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
            'state': [{'name': n, 'shape': list(a.shape),
                       'dtype': a.dtype.name}
                      for n, a in zip(state_names, state0)],
-           'step': {'feeds': step_feeds, 'fetches': list(step['fetches'])}}
+           'step': step_sig}
     if verify_sig is not None:
         sig['verify'] = verify_sig
     if layout == 'block':
@@ -621,7 +622,14 @@ def _export_decode_program(entry, state_names, state0, scope, out_dir,
     weights genuinely partition across the mesh instead of replicating
     as constants), the KV state threads through mp-sharded input->output
     (fixed-point pinned), and feeds/fetches stay replicated (the host
-    scheduler sees full arrays). Returns the feed signature entries."""
+    scheduler sees full arrays). Returns the program's signature
+    entries: its 'feeds', and under 'attention' the body each of its
+    kv_*attention* ops holds where the module is compiled for a TPU, by
+    op type and counted ({'kv_block_attention': {'kernel': 6}}) —
+    'kernel' is the paged Pallas kernel, which only kv_block_attention
+    has and reports to its Tracer as it lowers (ops/decode_ops.py);
+    every other body, and every body on another platform, is the 'jnp'
+    expression over the gathered view."""
     import jax
     import jax.numpy as jnp
     from ..core.lowering import Tracer
@@ -672,9 +680,11 @@ def _export_decode_program(entry, state_names, state0, scope, out_dir,
         tracer.env.update(dict(zip(state_names, state_list)))
         tracer.env.update(dict(zip(feed_names, feed_list)))
         tracer.run_block(program.global_block())
+        lowered[:] = tracer.lowered_bodies
         return ([tracer.env[n] for n in fetch_names],
                 [tracer.env[n] for n in state_names])
 
+    lowered = []     # (op type, body) as the export's trace lowered them
     state_specs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in state0]
     feed_specs = [jax.ShapeDtypeStruct(samples[n].shape, samples[n].dtype)
                   for n in feed_names]
@@ -684,8 +694,19 @@ def _export_decode_program(entry, state_names, state0, scope, out_dir,
                   list(shard['state_ns']))
     _export_serialize(fn, (state_specs, feed_specs), out_dir, shard=shard,
                       out_shardings=out_sh)
-    return [{'name': n, 'shape': list(samples[n].shape),
-             'dtype': samples[n].dtype.name} for n in feed_names]
+    # ops that chose a body said so as they lowered; the other
+    # kv_*attention* ops have the one jnp body
+    reported = {op_type for op_type, _ in lowered}
+    attention = {}
+    for op_type, body in lowered + [
+            (op.type, 'jnp') for op in program.global_block().ops
+            if re.fullmatch(r'kv_\w*attention\w*', op.type)
+            and op.type not in reported]:
+        by_body = attention.setdefault(op_type, {})
+        by_body[body] = by_body.get(body, 0) + 1
+    return {'feeds': [{'name': n, 'shape': list(samples[n].shape),
+                       'dtype': samples[n].dtype.name} for n in feed_names],
+            'attention': attention}
 
 
 def _export_decode_reorder(state0, n_rows, out_dir, shard=None):

@@ -1699,10 +1699,17 @@ def kv_block_attention(query, k_cache, v_cache, pos, block_table,
                        n_head, scale=None):
     """One-token-per-slot attention over the block-paged cache: `query`
     [max_slots, d] attends its own slot's logically-ordered block view
-    (rows j <= pos) gathered through `block_table`. Masked rows get
-    exactly-zero weight — foreign blocks and trash-block garbage can
-    never perturb an active slot (the block form of the continuous-
-    batching bit-identity contract)."""
+    (rows j <= pos) through `block_table`. Rows beyond get exactly-zero
+    weight — foreign blocks and trash-block garbage can never perturb
+    an active slot, and a slot's output depends only on its own pages
+    and `pos` (the block form of the continuous-batching contract: a
+    stream is bit-identical to serving the request alone). The op has
+    two lowerings (ops/decode_ops.py): the gathered view through
+    kv_cache_attention's own expression on every platform but a TPU,
+    where block-paged therefore equals slot-paged bit for bit; and,
+    compiled for a TPU with a float32 / bfloat16 pool of whole-tile
+    pages, a Pallas kernel that reads pages 0 .. pos // block_size only
+    and rounds differently from the gathered body (float32 throughout)."""
     helper = LayerHelper('kv_block_attention')
     out = helper.create_variable_for_type_inference(query.dtype)
     helper.append_op(type='kv_block_attention',

@@ -23,6 +23,8 @@ TPU-native designs:
 """
 from __future__ import annotations
 
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -614,6 +616,23 @@ def _kv_cache_attention_quant(ctx, ins):
 # maps it into an attention window, so its (possibly write-racy, but
 # never read) bits cannot perturb any active slot — the same masked-
 # idle-slot determinism contract as the slot-paged ops above.
+#
+# What reads the pool how (ISSUE 25). Every op here gathers a slot's
+# whole logical view [MAXB * BS, D] through _block_view and runs the
+# slot-paged jnp expression over it, masking rows past pos — on those
+# bodies block-paged equals slot-paged BIT FOR BIT, which the cpu tests
+# pin. ONE op has a second body: where a program is compiled for a TPU,
+# the step's kv_block_attention is the Pallas kernel of
+# pallas_paged_attention.py, which copies pages 0 .. pos // BS of each
+# slot straight from the pool through its table row and never sees a
+# row past pos. Same function, float32 throughout, another summation
+# order (online softmax): on a TPU the step rounds differently from the
+# chunk / verify / _quant / slot-paged bodies, which keep the gathered
+# view and stay the reference the kernel is tested against. The choice
+# is made from what the lowering sees — the platform the program is
+# compiled for, the pool's dtype and page shape (ppa.supports), a trace
+# mesh — never from a knob; the op tells its Tracer (lowered_bodies) and
+# export_decode writes it into the signature.
 # ---------------------------------------------------------------------------
 
 def _block_view(cache, table_row):
@@ -691,22 +710,52 @@ def _kv_block_write(ctx, ins):
     return {'Out': [cache.at[bidx, boff].set(kv.astype(cache.dtype))]}
 
 
+def _kv_block_attention_jnp(ctx, q, kc, vc, pos, table):
+    """The reference body: gather each slot's logical view through its
+    table row, then the slot-paged op's masked attention — ONE
+    expression with kv_cache_attention, so on this body a slot's output
+    is bit-identical however its history is paged."""
+    kv_view = jax.vmap(lambda r: _block_view(kc, r))(table)  # [S, T', D]
+    vv_view = jax.vmap(lambda r: _block_view(vc, r))(table)
+    return _paged_attention_body(ctx, q, kv_view, vv_view, pos)
+
+
 @register('kv_block_attention', no_grad=True, lod='none')
 def _kv_block_attention(ctx, ins):
     """kv_cache_attention over the block pool: Q [S, D], KCache/VCache
     [NB, BS, D], Pos [S] int32, BlockTable [S, MAXB] int32. Each slot
-    attends its own table's logical view rows j <= pos; masked rows get
+    attends its own table's logical view rows j <= pos; rows beyond get
     exactly-zero weight, so foreign blocks and trash garbage can never
-    perturb an active slot (the fp body is the slot-paged op's, so a
-    slot's output is bit-identical however its history is paged)."""
+    perturb an active slot.
+
+    Two bodies (the comment block above). The jnp one — every platform
+    but a TPU, and on a TPU a pool the kernel cannot read or a sharded
+    trace — is the slot-paged op's expression over the gathered view,
+    bit-identical to kv_cache_attention. Compiled for a TPU, the Pallas
+    kernel reads pages 0 .. pos // BS through the table instead."""
+    from ..parallel.mesh import current_trace_mesh
+    from . import pallas_paged_attention as ppa
     q = ins['Q'][0]
     kc = ins['KCache'][0]
     vc = ins['VCache'][0]
     pos = ins['Pos'][0].reshape(-1).astype(jnp.int32)
     table = ins['BlockTable'][0].astype(jnp.int32)
-    kv_view = jax.vmap(lambda r: _block_view(kc, r))(table)  # [S, T', D]
-    vv_view = jax.vmap(lambda r: _block_view(vc, r))(table)
-    return {'Out': [_paged_attention_body(ctx, q, kv_view, vv_view, pos)]}
+    n_head = int(ctx.attr('n_head', 1))
+    jnp_body = functools.partial(_kv_block_attention_jnp, ctx)
+    if ctx.abstract:        # shape inference: no Tracer, and any body will do
+        return {'Out': [jnp_body(q, kc, vc, pos, table)]}
+    kernel = (current_trace_mesh() is None
+              and ppa.supports(q, kc, vc, n_head))
+    ctx.tracer.lowered_bodies.append(
+        ('kv_block_attention', 'kernel' if kernel else 'jnp'))
+    if not kernel:
+        return {'Out': [jnp_body(q, kc, vc, pos, table)]}
+    scale = (float(ctx.attr('scale', 0.0) or 0.0)
+             or (kc.shape[2] // n_head) ** -0.5)
+    return {'Out': [ppa.tpu_or_default(
+        q, kc, vc, pos, table, default=jnp_body,
+        tpu=functools.partial(ppa.paged_attention, n_head=n_head,
+                              scale=scale))]}
 
 
 def _chunk_attention_body(ctx, q, kview, vview, start, d):

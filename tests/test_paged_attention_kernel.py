@@ -1,0 +1,398 @@
+"""kv_block_attention's paged Pallas kernel (ops/pallas_paged_attention.py)
+against the jnp body it stands in for on a TPU — _paged_attention_body over
+_block_view — on the cpu through Pallas interpret mode; the rule that picks
+between them; what a cpu program and an exported artifact hold; and one
+compile of the kernel for the real chip at the benchmark's width.
+
+Every pool here is NaN wherever no slot attends: rows past pos in a slot's
+last page, the trash block past its row 0, blocks nobody owns. The jnp body
+is asked about the same pool with those NaN replaced (it gives a masked row
+weight 0.0, and 0.0 * NaN is NaN); the kernel has to come out finite and
+equal, so a row it must not use cannot have reached its output.
+
+Tolerance: the kernel's online softmax rescales a running sum block by
+block (256 positions a block) where the body takes one softmax over the
+whole span, all in float32: 1e-5 relative and absolute."""
+import functools
+import json
+import os
+import types
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as fluid
+from paddle_tpu.inference import DecodingPredictor, export_decode
+from paddle_tpu.inference import decoding
+from paddle_tpu.ops import decode_ops
+from paddle_tpu.ops import pallas_paged_attention as ppa
+
+TOL = dict(rtol=1e-5, atol=1e-5)
+
+
+def _attrs(n_head):
+    """What an op lowering is handed (core/lowering.py OpCtx), as far as
+    these ops ask: attrs, and the Tracer's record of chosen bodies."""
+    return types.SimpleNamespace(
+        attr=lambda name, default=None: {'n_head': n_head}.get(name, default),
+        abstract=False,
+        tracer=types.SimpleNamespace(lowered_bodies=[]))
+
+
+@functools.partial(jax.jit, static_argnames=('n_head',))
+def _kernel(q, kc, vc, pos, table, n_head):
+    return ppa.paged_attention(q, kc, vc, pos, table, n_head=n_head,
+                               scale=(q.shape[1] // n_head) ** -0.5,
+                               interpret=True)
+
+
+@functools.partial(jax.jit, static_argnames=('n_head',))
+def _body(q, kc, vc, pos, table, n_head):
+    """Today's expression, spelled out: the slot-paged body over each
+    slot's gathered view."""
+    kview = jax.vmap(lambda r: decode_ops._block_view(kc, r))(table)
+    vview = jax.vmap(lambda r: decode_ops._block_view(vc, r))(table)
+    return decode_ops._paged_attention_body(_attrs(n_head), q, kview, vview,
+                                            pos)
+
+
+def _lower_op(ctx, q, kc, vc, pos, table):
+    """The op as a program lowers it (core/lowering.py run_op)."""
+    return decode_ops._kv_block_attention(
+        ctx, {'Q': [q], 'KCache': [kc], 'VCache': [vc], 'Pos': [pos],
+              'BlockTable': [table]})['Out'][0]
+
+
+@functools.partial(jax.jit, static_argnames=('n_head',))
+def _op(q, kc, vc, pos, table, n_head):
+    return _lower_op(_attrs(n_head), q, kc, vc, pos, table)
+
+
+class _Pool(object):
+    """A block pool with block 0 the trash block, and tables built the
+    way the scheduler builds them: live columns first, trash after.
+    Blocks come off a shuffled free list, so tables are never monotone."""
+
+    def __init__(self, seed, s, bs, d, maxb, dtype):
+        self.s, self.bs, self.d, self.maxb = s, bs, d, maxb
+        self.dtype = dtype
+        self.nb = s * maxb + 2
+        self.rng = np.random.RandomState(seed)
+        self.q = self.rng.randn(s, d).astype(np.float32)
+        self.free = list(self.rng.permutation(np.arange(1, self.nb - 1)))
+        self.nobody = self.nb - 1           # a block no slot ever owns
+        self.pos = np.zeros(s, np.int32)
+        self.table = np.zeros((s, maxb), np.int32)
+
+    def live(self, slot, pos, blocks=()):
+        n = pos // self.bs + 1
+        blocks = list(blocks)[:n]
+        blocks += [self.free.pop() for _ in range(n - len(blocks))]
+        self.pos[slot] = pos
+        self.table[slot] = 0
+        self.table[slot, :n] = blocks
+        return blocks
+
+    def arrays(self):
+        """(poisoned, clean) argument tuples: the same q, pos and table;
+        K/V finite exactly on the rows some slot attends, NaN elsewhere
+        in `poisoned`, random elsewhere in `clean`."""
+        shape = (self.nb, self.bs, self.d)
+        attended = np.zeros(shape[:2], bool)
+        for s in range(self.s):
+            for p in range(self.pos[s] + 1):
+                attended[self.table[s, p // self.bs], p % self.bs] = True
+        pools = []
+        for _ in 'kv':
+            clean = self.rng.randn(*shape).astype(np.float32)
+            pools.append((np.where(attended[..., None], clean, np.nan),
+                          clean))
+
+        def args(which):
+            return (jnp.asarray(self.q),
+                    jnp.asarray(pools[0][which], self.dtype),
+                    jnp.asarray(pools[1][which], self.dtype),
+                    jnp.asarray(self.pos), jnp.asarray(self.table))
+        return args(0), args(1)
+
+
+def _all_at(pos):
+    def case(p):
+        for slot in range(p.s):
+            p.live(slot, pos(p))
+    return case
+
+
+def _mixed(p):
+    """Another pos in every slot: 0, the page edges, the kernel's compute
+    block edge (256 rows), the last row of a full table."""
+    for slot, pos in enumerate((0, p.bs - 1, p.bs, 3 * p.bs + 5, 256,
+                                p.maxb * p.bs - 1)):
+        p.live(slot, pos)
+
+
+def _idle_beside_live(p):
+    """Even slots idle — pos 0 and a table of trash blocks — between live
+    ones."""
+    for slot in range(1, p.s, 2):
+        p.live(slot, (slot + 1) * p.bs + slot)
+
+
+def _shared_prefix(p):
+    """Two slots share their first blocks (a prefix-cache hit) and end in
+    blocks of their own; the rest do not share."""
+    first = p.live(0, 5 * p.bs + 3)
+    p.live(1, 4 * p.bs, blocks=first[:3])
+    for slot in range(2, p.s):
+        p.live(slot, slot * p.bs + 1)
+
+
+def _permuted_table(p):
+    """Physical order against logical order: descending blocks, then an
+    interleave of low and high ones."""
+    n = 5
+    ids = sorted(p.free.pop() for _ in range(2 * n))
+    p.live(0, n * p.bs - 2, blocks=ids[:n][::-1])
+    p.live(1, n * p.bs - 1, blocks=[ids[n + (i // 2 if i % 2 else
+                                             n - 1 - i // 2)]
+                                    for i in range(n)])
+    for slot in range(2, p.s):
+        p.live(slot, 2 * p.bs + slot)
+
+
+def _foreign_columns(p):
+    """Table columns past pos // BS hold other slots' live blocks and a
+    block of NaN nobody owns, where the scheduler would leave trash."""
+    theirs = p.live(1, 6 * p.bs)
+    p.live(0, p.bs + 2)
+    p.table[0, 2:2 + len(theirs)] = theirs
+    p.table[0, 2 + len(theirs):] = p.nobody
+    for slot in range(2, p.s):
+        p.live(slot, slot)
+        p.table[slot, 1:] = p.nobody
+
+
+_CASES = {
+    'pos_0': _all_at(lambda p: 0),
+    'pos_page_last_row': _all_at(lambda p: p.bs - 1),           # 15
+    'pos_page_first_row': _all_at(lambda p: p.bs),              # 16
+    'pos_page_second_row': _all_at(lambda p: p.bs + 1),         # 17
+    'pos_mid_table': _all_at(lambda p: p.maxb * p.bs // 2 + 3),
+    'pos_compute_block_edge': _all_at(lambda p: 255),
+    'pos_table_end': _all_at(lambda p: p.maxb * p.bs - 1),
+    'mixed_pos': _mixed,
+    'idle_beside_live': _idle_beside_live,
+    'shared_prefix': _shared_prefix,
+    'permuted_table': _permuted_table,
+    'foreign_columns': _foreign_columns,
+}
+# (S, BS, D, H, MAXB, pool dtype): MAXB * BS = 320 > 256, so a full slot
+# takes more than one compute block
+_SHAPES = {'bs16_h8_f32': (6, 16, 128, 8, 20, np.float32),
+           'bs8_h1_f32': (6, 8, 128, 1, 40, np.float32),
+           'bs16_h2_bf16': (6, 16, 128, 2, 20, jnp.bfloat16)}
+
+
+@pytest.mark.parametrize('case', sorted(_CASES))
+@pytest.mark.parametrize('shape', sorted(_SHAPES))
+def test_kernel_matches_jnp_body(shape, case):
+    s, bs, d, h, maxb, dtype = _SHAPES[shape]
+    pool = _Pool(7, s, bs, d, maxb, dtype)
+    _CASES[case](pool)
+    poisoned, clean = pool.arrays()
+    got = np.asarray(_kernel(*poisoned, n_head=h))
+    want = np.asarray(_body(*clean, n_head=h))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, **TOL)
+
+
+def test_a_slots_output_is_its_own():
+    """A slot's output with its neighbours changed — other queries, other
+    positions, other tables, other pages — is bit-identical: per-slot
+    math never mixes rows."""
+    p = _Pool(11, 4, 16, 128, 20, np.float32)
+    mine = p.live(1, 17 * p.bs + 5)          # spans two compute blocks
+    p.live(0, 3)
+    p.live(2, 9 * p.bs)
+    _, first = p.arrays()
+    p.live(0, 0)
+    p.table[0] = 0
+    p.live(2, 19 * p.bs + 1)
+    p.live(3, 2 * p.bs)
+    q = p.rng.randn(*p.q.shape).astype(np.float32)
+    q[1] = p.q[1]
+    p.q = q
+    _, second = p.arrays()
+    for which in (1, 2):                     # K, V: keep my pages
+        pool = np.asarray(second[which]).copy()
+        pool[mine] = np.asarray(first[which])[mine]
+        second = second[:which] + (jnp.asarray(pool),) + second[which + 1:]
+    a = np.asarray(_kernel(*first, n_head=4))
+    b = np.asarray(_kernel(*second, n_head=4))
+    assert np.array_equal(a[1], b[1])
+    assert not np.array_equal(a[2], b[2])
+
+
+# -- which body ---------------------------------------------------------------
+
+def _sds(shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+@pytest.mark.parametrize('why,q,pool,n_head,want', [
+    ('the benchmark configuration', _sds((128, 512), np.float32),
+     _sds((16385, 16, 512), np.float32), 8, True),
+    ('a bfloat16 pool', _sds((8, 128), np.float32),
+     _sds((65, 16, 128), jnp.bfloat16), 2, True),
+    ('the rehearsal model: D = 32 is not whole lanes',
+     _sds((8, 32), np.float32), _sds((65, 8, 32), np.float32), 4, False),
+    ('an int8 pool', _sds((8, 128), np.float32),
+     _sds((65, 16, 128), np.int8), 2, False),
+    ('a page of 4 rows is not whole sublanes', _sds((8, 128), np.float32),
+     _sds((65, 4, 128), np.float32), 2, False),
+    ('bfloat16 packs 16 rows', _sds((8, 128), np.float32),
+     _sds((65, 8, 128), jnp.bfloat16), 2, False),
+    ('heads that do not divide D', _sds((8, 128), np.float32),
+     _sds((65, 8, 128), np.float32), 3, False),
+])
+def test_shape_rule(why, q, pool, n_head, want):
+    assert ppa.supports(q, pool, pool, n_head) is want, why
+
+
+def _op_args(d, bs=8, s=3, maxb=6):
+    p = _Pool(5, s, bs, d, maxb, np.float32)
+    for slot in range(s):
+        p.live(slot, slot * bs + 2)
+    return p.arrays()[1]
+
+
+def test_cpu_program_is_todays_expression_bit_for_bit():
+    """A shape the kernel takes, compiled for the cpu: the switch is in
+    the jaxpr, no custom call is in the program, and the result equals
+    the parent's expression bit for bit."""
+    args = _op_args(128)
+    ctx = _attrs(2)
+    jaxpr = jax.make_jaxpr(functools.partial(_lower_op, ctx))(*args)
+    assert ctx.tracer.lowered_bodies == [('kv_block_attention', 'kernel')]
+    assert 'kv_block_attention' in str(jaxpr)
+    text = _op.lower(*args, n_head=2).as_text()
+    assert 'custom_call' not in text and 'custom-call' not in text
+    assert np.array_equal(np.asarray(_op(*args, n_head=2)),
+                          np.asarray(_body(*args, n_head=2)))
+
+
+def test_refused_shape_takes_the_jnp_body():
+    """D = 32 is not whole lanes: the op lowers straight to the jnp body,
+    with no switch in between — the parent's program."""
+    args = _op_args(32)
+    ctx = _attrs(2)
+    jaxpr = jax.make_jaxpr(functools.partial(_lower_op, ctx))(*args)
+    assert ctx.tracer.lowered_bodies == [('kv_block_attention', 'jnp')]
+    assert 'kv_block_attention' not in str(jaxpr)
+    assert np.array_equal(np.asarray(_op(*args, n_head=2)),
+                          np.asarray(_body(*args, n_head=2)))
+
+
+# -- the artifact and the predictor -------------------------------------------
+
+VOCAB, SLOTS, CACHE = 50, 4, 64
+
+
+def _export(tmp, d_model, **kw):
+    from models.transformer import build_decode_spec
+    scope = fluid.core.Scope()
+    with fluid.scope_guard(scope), fluid.unique_name.guard():
+        spec = build_decode_spec(
+            vocab=VOCAB, d_model=d_model, n_head=2, n_layer=2, d_ff=32,
+            max_slots=SLOTS, max_cache_len=CACHE, eos_id=1,
+            prompt_buckets=(8, 16), **kw)
+        fluid.Executor(fluid.CPUPlace()).run(spec['startup'])
+        export_decode(spec, tmp, scope=scope)
+    return tmp
+
+
+@pytest.fixture(scope='module')
+def arts(tmp_path_factory):
+    t = tmp_path_factory.mktemp('paged')
+    return {'slot128': _export(str(t / 'slot128'), 128),
+            'block128': _export(str(t / 'block128'), 128, block_size=8),
+            'block32': _export(str(t / 'block32'), 32, block_size=8)}
+
+
+def _signature(art):
+    with open(os.path.join(art, decoding._DECODE_SIGNATURE)) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize('art,body', [('block128', 'kernel'),
+                                      ('block32', 'jnp')])
+def test_signature_names_the_body_each_attention_op_holds(arts, art, body):
+    """... and the exported module agrees: the kernel's custom call is in
+    the step or not, and never in a chunk program."""
+    sig = _signature(arts[art])
+    assert sig['step']['attention'] == {'kv_block_attention': {body: 2}}
+    for chunk in sig['chunk'].values():
+        assert chunk['attention'] == {'kv_block_chunk_attention': {'jnp': 2}}
+    with open(os.path.join(arts[art], decoding._STEP_DIR,
+                           'module.jaxexport'), 'rb') as f:
+        assert (b'tpu_custom_call' in f.read()) == (body == 'kernel')
+    for chunk in ('prefill_chunk_00008', 'prefill_chunk_00016'):
+        with open(os.path.join(arts[art], chunk, 'module.jaxexport'),
+                  'rb') as f:
+            assert b'tpu_custom_call' not in f.read()
+
+
+def test_slot_artifact_names_the_slot_ops_body(arts):
+    sig = _signature(arts['slot128'])
+    assert sig['step']['attention'] == {'kv_cache_attention': {'jnp': 2}}
+
+
+def test_on_the_cpu_the_kernels_artifact_serves_the_jnp_body(arts):
+    """An artifact whose step holds the kernel for a TPU runs the jnp
+    body here: the predictor says so, and block-paged equals slot-paged
+    bit for bit, as before."""
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(2, VOCAB, n) for n in (3, 9, 14, 6)]
+    with DecodingPredictor(arts['slot128']) as ps:
+        want = [ps.generate(p, max_new_tokens=7) for p in prompts]
+    with DecodingPredictor(arts['block128']) as pb:
+        assert pb.attention_bodies['step'] == {
+            'kv_block_attention': {'jnp': 2}}
+        assert pb.stats.snapshot()['attention'] == 'jnp'
+        streams = [pb.submit(p, max_new_tokens=7) for p in prompts]
+        got = [list(s.result(120)) for s in streams]
+    assert got == want
+
+
+# -- the real chip's compiler, without the chip --------------------------------
+
+@pytest.fixture(scope='module')
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:
+        pytest.skip('no v5e:2x2 topology can be described here: %s' % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize('s,nb,bs,d,h,maxb,dtype', [
+    (128, 16385, 16, 512, 8, 128, np.float32),     # the benchmark's
+    (8, 257, 16, 512, 8, 32, np.float32),          # chip_smoke phase B's
+    (8, 257, 16, 128, 2, 32, jnp.bfloat16),
+    (8, 257, 8, 128, 1, 32, np.float32)])
+def test_kernel_compiles_for_v5e(one_chip, s, nb, bs, d, h, maxb, dtype):
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    fn = jax.jit(functools.partial(ppa.paged_attention, n_head=h,
+                                   scale=(d // h) ** -0.5))
+    compiled = fn.lower(sds((s, d), np.float32), sds((nb, bs, d), dtype),
+                        sds((nb, bs, d), dtype), sds((s,), np.int32),
+                        sds((s, maxb), np.int32)).compile()
+    assert 'tpu_custom_call' in compiled.as_text()
