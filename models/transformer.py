@@ -239,6 +239,11 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
     programs; export_decode threads it as donated input->output state
     while baking every other parameter as constants.
 
+    kv_cache_dtype='bfloat16': the float cache holds bfloat16 rows (K and
+    V round once, at the write; attention reads them as they are) —
+    half the bytes of the float32 pool, and what the TPU's paged kernel
+    reads with block_size % 16 == 0.
+
     kv_cache_dtype='int8' (ISSUE 11): the paged cache stores int8 rows
     with one f32 scale per slot-page (kv_ks_<i>/kv_vs_<i> [S, T] ride
     the cache_vars state next to the int8 [S, T, D] pages) and the
@@ -279,9 +284,9 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
     """
     import numpy as np
     PA = fluid.ParamAttr
-    if kv_cache_dtype not in ('float32', 'int8'):
-        raise ValueError("kv_cache_dtype must be 'float32' or 'int8', "
-                         "got %r" % (kv_cache_dtype,))
+    if kv_cache_dtype not in ('float32', 'bfloat16', 'int8'):
+        raise ValueError("kv_cache_dtype must be 'float32', 'bfloat16' "
+                         "or 'int8', got %r" % (kv_cache_dtype,))
     if not 0 <= int(draft_k) <= int(max_cache_len) - 2:
         raise ValueError('draft_k must be in [0, max_cache_len - 2], '
                          'got %r' % (draft_k,))
@@ -321,7 +326,7 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
 
     def caches(i):
         zero = fluid.initializer.ConstantInitializer(0.0)
-        dt = 'int8' if kv_int8 else 'float32'
+        dt = 'int8' if kv_int8 else kv_cache_dtype
         k = const_param('kv_k_%d' % i, [S, T, D], zero, dt)
         v = const_param('kv_v_%d' % i, [S, T, D], zero, dt)
         if not kv_int8:
@@ -620,7 +625,7 @@ def _build_block_decode_spec(vocab, d_model, n_head, n_layer, d_ff,
 
     def caches(i):
         zero = fluid.initializer.ConstantInitializer(0.0)
-        dt = 'int8' if kv_int8 else 'float32'
+        dt = 'int8' if kv_int8 else kv_cache_dtype
         cspec = (None, None, 'mp') if mp else None
         k = const_param('kv_k_%d' % i, [NB, BS, D], zero, dt, spec=cspec)
         v = const_param('kv_v_%d' % i, [NB, BS, D], zero, dt, spec=cspec)

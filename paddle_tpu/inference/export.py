@@ -4,11 +4,18 @@ The reference ships a non-Python deployment path — a C++ API over a saved
 program (inference/api/paddle_api.h:1 PaddlePredictor,
 api/analysis_predictor.cc:359 CreatePaddlePredictor) and a C++ trainer demo
 (train/demo_trainer.cc:1). The TPU-native equivalent of "deploy without the
-framework" is an ahead-of-time compiled XLA artifact: the inference program
-is traced ONCE here, parameters are baked in as constants, and the result
-is serialized with `jax.export` (StableHLO + calling convention). The
-loader (serve.py) needs only jax + numpy — it never imports the Program IR,
-the op registry, or the tracer.
+framework" is an ahead-of-time compiled XLA artifact: the program is traced
+ONCE here and the result is serialized with `jax.export` (StableHLO +
+calling convention). The loader (serve.py, decoding.py) needs only jax +
+numpy — it never imports the Program IR, the op registry, or the tracer.
+
+Where the parameters live differs by kind of artifact. `export_compiled`
+(the stateless inference path) still bakes them into its one module as
+constants. `export_decode` and `export_train_step` hold nothing as a
+constant: a decode artifact's programs all take the SAME parameter list as
+their first, undonated argument, loaded once from the artifact's one
+weights file (decode_weights.bin, mapped by the signature's 'params'), and
+a train artifact threads parameters and optimizer state input -> output.
 
 Artifact layout (out_dir/):
   module.jaxexport   serialized jax.export artifact (StableHLO, params baked)
@@ -36,7 +43,7 @@ import numpy as np
 # writes exactly what serve reads
 from .serve import (_SIGNATURE, _MODULE, _BUCKET_DIR, _TIER_INT8,
                     _TRAIN_SIGNATURE, _TRAIN_MODULE, _TRAIN_STATE0,
-                    _AOT_SIDECAR, _aot_platform, _precompile_infer_dir,
+                    _DECODE_WEIGHTS, _AOT_SIDECAR, span as _span, _aot_platform, _precompile_infer_dir,
                     _precompile_train_dir)
 
 
@@ -326,24 +333,59 @@ def _mesh_tag(platform, axes):
         '%s%d' % (a, int(axes[a])) for a in sorted(axes)))
 
 
-def _decode_shard_ctx(spec, state_names, platform=None):
+def _decode_shard_ctx(spec, state_names, params, param_args, platform=None):
     """Resolve the spec's mesh annotations into concrete NamedShardings:
     returns None for unsharded specs, else {mesh, rep, state_ns (aligned
-    with state_names), param_ns, axes, tag}."""
+    with state_names), param_specs ({name: partition spec} of the
+    parameter ARGUMENTS), param_ns (the same as NamedShardings, aligned
+    with param_args; a pack is replicated), constrain ({name:
+    NamedSharding} applied inside the trace), axes, tag}. A parameter
+    whose annotated axis does not divide its dimension cannot be an
+    argument in that sharding (jit refuses an uneven argument): it enters
+    replicated and is constrained inside the program, where GSPMD pads."""
     axes = spec.get('mesh_axes')
     if not axes:
         return None
     from jax.sharding import NamedSharding, PartitionSpec
-    from .decoding import _state_shardings_ns
+    from .decoding import _arg_name, _state_shardings_ns
     mesh = _decode_mesh(axes, platform)
     rep, state_ns = _state_shardings_ns(
         mesh, spec.get('state_shardings'), state_names)
-    param_ns = {n: NamedSharding(mesh, PartitionSpec(*ps))
-                for n, ps in (spec.get('param_shardings') or {}).items()}
+    param_specs, constrain = {}, {}
+    for n, ps in (spec.get('param_shardings') or {}).items():
+        if n not in params:
+            continue
+        even = all(a is None or dim % int(axes[a]) == 0
+                   for dim, a in zip(np.shape(params[n]), ps))
+        if even:
+            param_specs[n] = tuple(ps)
+        else:
+            constrain[n] = NamedSharding(mesh, PartitionSpec(*ps))
+    _, param_ns = _state_shardings_ns(
+        mesh, param_specs, [_arg_name(a) for a in param_args])
     plat = np.asarray(mesh.devices).reshape(-1)[0].platform
     return {'mesh': mesh, 'rep': rep, 'state_ns': state_ns,
-            'param_ns': param_ns, 'axes': dict(axes),
+            'param_specs': param_specs, 'param_ns': param_ns,
+            'constrain': constrain, 'axes': dict(axes),
             'platform': plat, 'tag': _mesh_tag(plat, axes)}
+
+
+def _param_args(params, sharded):
+    """How the weights become program arguments, as lists of names: every
+    rank-1 parameter (norm weights, biases) of one dtype that no mesh
+    sharding names rides in ONE 1-D argument, its members end to end —
+    a dispatch pays for each argument buffer it passes (about 1.3 us
+    each on the v5e's host, PERF.md PR 26), a model has as many such
+    vectors as matrices, and a slice of a small vector costs nothing on
+    the device. Every other parameter is an argument of its own: a slice
+    of a stacked matrix would be copied out at every step."""
+    packs = {}
+    for n in sorted(params):
+        if np.ndim(params[n]) == 1 and n not in sharded:
+            packs.setdefault(np.dtype(params[n].dtype).name, []).append(n)
+    args = [names for _, names in sorted(packs.items()) if len(names) > 1]
+    packed = {n for names in args for n in names}
+    return args + [[n] for n in sorted(params) if n not in packed]
 
 
 def export_decode(spec, out_dir, scope=None, precompile=None,
@@ -370,19 +412,30 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                    program ([max_slots, max_cache_len, ...]).
       max_slots / max_cache_len / eos_id / vocab.
 
-    Every program is traced ONCE as fn(state, feeds) -> (fetches,
-    new_state): parameters bake in as constants, the cache state threads
-    through as donated inputs/outputs. The artifact also carries a
-    REORDER program (state gathered by a per-slot source index — beam
-    reordering, cache replication, and the serving tier's owned-buffer
-    init boundary) and per-program AOT warm-start sidecars, the step and
-    prefill tiers compiled WITH state donation (the paged cache updates
-    in place; the loader passes only XLA-owned buffers, the executor's
-    round-10 ownership discipline).
+    Every program is traced ONCE as fn(params, state, feeds) ->
+    (fetches, new_state). `params` is every persistable any program
+    reads, in one sorted list that is the same for all of them,
+    UNDONATED: no module holds a weight as a constant, the artifact
+    holds one copy of the weights (decode_weights.bin: raw bytes, mapped
+    by the signature's 'params' entries {name, shape, dtype, offset,
+    nbytes}) and the loader one set of device buffers that step, chunk,
+    prefill and verify share. The cache state threads through as
+    donated inputs/outputs. The artifact also carries a REORDER program
+    (state gathered by a per-slot source index — beam reordering, cache
+    replication), a ZEROS program (the cache state born on the device:
+    XLA-owned buffers, the pool held once, no host copy) and
+    per-program AOT warm-start sidecars, the model's programs compiled
+    WITH state donation (the paged cache updates in place; the loader
+    passes only XLA-owned buffers, the executor's round-10 ownership
+    discipline). The signature is version 4 for this convention; the
+    loader refuses an older artifact by name.
 
     Artifact layout (out_dir/):
-      decode_signature.json   shapes, buckets, state specs, eos/vocab
+      decode_signature.json   shapes, buckets, params and state specs,
+                              eos/vocab
+      decode_weights.bin      the one copy of the weights
       decode_step/            module.jaxexport (+ aot_<platform>.jaxexec)
+      decode_zeros/           the state's birth
       prefill_<bucket>/       one per prompt bucket
       decode_reorder/         slot-gather program (undonated)
 
@@ -399,12 +452,14 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     one program per chunk size), and the artifact carries a BLOCKCOPY
     program (decode_blockcopy/: up to max_slots (dst, src) block pairs
     copy per dispatch — beam copy-on-write moves diverged BLOCKS, not
-    slot rows) next to the reorder program (which gathers over blocks
-    and remains the owned-buffer init boundary).
+    slot rows) next to the reorder program (which gathers over blocks).
 
     Specs annotated for tensor-model sharding (build_decode_spec
-    mp_shard=k) trace every program over the composed mesh: params bake
-    in as mp-sharded constants, the KV block pool threads through as
+    mp_shard=k) trace every program over the composed mesh: the
+    parameter ARGUMENTS are pinned to their annotated shardings (one
+    whose axis does not divide its dimension enters replicated and is
+    constrained inside the program), the loader places each weight in
+    its recorded sharding, the KV block pool threads through as
     mp-sharded donated state (round-13 output-sharding pinning keeps
     the step a sharding-stable loop), and AOT sidecars are MESH-TAGGED
     (aot_<platform>_mp<k>.jaxexec). The signature records the mesh so
@@ -415,10 +470,9 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     Speculative-decode specs (ISSUE 17, build_decode_spec(draft_k=K))
     export a THIRD program, decode_verify/: [max_slots, K+1] token and
     position rows score in one dispatch over the same donated cache
-    state, with its own AOT warm-start sidecar. The signature bumps to
-    version 3 and gains an optional 'verify' block ({feeds, fetches,
-    draft_k}); version-2 artifacts keep loading (speculative decode
-    simply unavailable).
+    state, with its own AOT warm-start sidecar. The signature gains an
+    optional 'verify' block ({feeds, fetches, draft_k}); an artifact
+    without one serves without speculative decode.
 
     Load with inference/decoding.py DecodingPredictor (framework-free).
     Returns out_dir.
@@ -437,28 +491,23 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     scope = scope if scope is not None else global_scope()
     layout = spec.get('layout', 'slot')
     state_names = list(spec['cache_vars'])
-    state0 = []
+    state_specs = []
     for n in state_names:
         val = scope.get(n)
         if val is None:
             raise ValueError(
                 "cache var %r has no value in the scope — run the spec's "
                 "startup program before export_decode" % n)
-        state0.append(np.asarray(val))
-    shard = _decode_shard_ctx(spec, state_names)
+        # shape and dtype only: the pool itself never leaves the device
+        state_specs.append(jax.ShapeDtypeStruct(np.shape(val), val.dtype))
     step = spec['step']
     step_want = (['block_tables', 'pos', 'tokens'] if layout == 'block'
                  else ['pos', 'tokens'])
     if sorted(step['feeds']) != step_want:
         raise ValueError("decode-step feeds must be %r, got %r"
                          % (step_want, step['feeds']))
-    os.makedirs(out_dir, exist_ok=True)
-
-    step_sig = dict(_export_decode_program(
-        step, state_names, state0, scope,
-        os.path.join(out_dir, _decoding._STEP_DIR), shard=shard),
-        fetches=list(step['fetches']))
-    verify_sig = None
+    # every program of the artifact, by its directory
+    entries = {_decoding._STEP_DIR: step}
     verify = spec.get('verify')
     if verify is not None:
         # ISSUE 17: third program — same feed NAMES as the step (the
@@ -466,15 +515,7 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
         if sorted(verify['feeds']) != step_want:
             raise ValueError("decode-verify feeds must be %r, got %r"
                              % (step_want, verify['feeds']))
-        verify_sig = dict(
-            _export_decode_program(
-                verify, state_names, state0, scope,
-                os.path.join(out_dir, _decoding._VERIFY_DIR),
-                shard=shard),
-            fetches=list(verify['fetches']),
-            draft_k=int(spec['draft_k']))
-    prefill_sig = {}
-    chunk_sig = {}
+        entries[_decoding._VERIFY_DIR] = verify
     if layout == 'block':
         chunks = sorted(int(c) for c in spec['chunk'])
         if not chunks:
@@ -487,15 +528,7 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                 raise ValueError(
                     "chunk feeds must be ['chunk_ids', 'start', "
                     "'chunk_len', 'block_table'], got %r" % (p['feeds'],))
-            chunk_sig[str(C)] = dict(
-                _export_decode_program(
-                    p, state_names, state0, scope,
-                    os.path.join(out_dir, _decoding._CHUNK_DIR % C),
-                    shard=shard),
-                fetches=list(p['fetches']))
-        _export_decode_blockcopy(
-            state0, int(spec['max_slots']),
-            os.path.join(out_dir, _decoding._BLOCKCOPY_DIR), shard=shard)
+            entries[_decoding._CHUNK_DIR % C] = p
         reorder_n = int(spec['num_blocks'])
     else:
         buckets = sorted(int(b) for b in spec['prefill'])
@@ -508,18 +541,44 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                 raise ValueError(
                     "prefill feeds must be ['prompt_ids', 'prompt_len', "
                     "'slot'], got %r" % (p['feeds'],))
-            prefill_sig[str(L)] = dict(
-                _export_decode_program(
-                    p, state_names, state0, scope,
-                    os.path.join(out_dir, _decoding._PREFILL_DIR % L),
-                    shard=shard),
-                fetches=list(p['fetches']))
+            entries[_decoding._PREFILL_DIR % L] = p
         reorder_n = int(spec['max_slots'])
-    _export_decode_reorder(state0, reorder_n,
+    programs = {d: _optimize_decode_program(e, state_names)
+                for d, e in entries.items()}
+    # the ONE parameter list every program takes, in this order
+    params = _decode_params(programs.values(), state_names, scope)
+    param_args = _param_args(params, set(spec.get('param_shardings') or ()))
+    shard = _decode_shard_ctx(spec, state_names, params, param_args)
+    os.makedirs(out_dir, exist_ok=True)
+    param_sig = _write_decode_weights(
+        os.path.join(out_dir, _DECODE_WEIGHTS), param_args, params)
+    param_specs = _decoding.param_arg_specs(param_sig, param_args)
+    del params
+
+    sigs = {}
+    for d, e in entries.items():
+        with _span('export/program', program=d, params=len(param_sig)):
+            sigs[d] = dict(_export_decode_program(
+                e, programs[d], param_args, param_specs, state_names,
+                state_specs, os.path.join(out_dir, d), shard=shard),
+                fetches=list(e['fetches']))
+    if layout == 'block':
+        _export_decode_blockcopy(
+            state_specs, int(spec['max_slots']),
+            os.path.join(out_dir, _decoding._BLOCKCOPY_DIR), shard=shard)
+    _export_decode_reorder(state_specs, reorder_n,
                            os.path.join(out_dir, _decoding._REORDER_DIR),
                            shard=shard)
+    _export_decode_zeros(state_specs,
+                         os.path.join(out_dir, _decoding._ZEROS_DIR),
+                         shard=shard)
 
-    sig = {'version': 3, 'kind': 'decode',
+    state_bytes = [int(np.prod(s.shape)) * s.dtype.itemsize
+                   for s in state_specs]
+    # version 4: the weights are ARGUMENTS of every program (one
+    # decode_weights.bin, listed under 'params'); up to version 3 each
+    # program's module held them as constants
+    sig = {'version': 4, 'kind': 'decode',
            'layout': layout,
            'max_slots': int(spec['max_slots']),
            'max_cache_len': int(spec['max_cache_len']),
@@ -527,23 +586,29 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
            'kv_cache_dtype': spec_kv,
            # fixed-HBM capacity planning: what the paged cache state
            # costs per replica (int8 tier: int8 pages + f32 page scales)
-           'cache_bytes': int(sum(a.nbytes for a in state0)),
-           'state': [{'name': n, 'shape': list(a.shape),
-                      'dtype': a.dtype.name}
-                     for n, a in zip(state_names, state0)],
-           'step': step_sig}
-    if verify_sig is not None:
-        sig['verify'] = verify_sig
+           'cache_bytes': int(sum(state_bytes)),
+           'state': [{'name': n, 'shape': list(s.shape),
+                      'dtype': s.dtype.name}
+                     for n, s in zip(state_names, state_specs)],
+           'params': param_sig,
+           'param_args': param_args,
+           'weight_bytes': int(sum(e['nbytes'] for e in param_sig)),
+           'step': sigs[_decoding._STEP_DIR]}
+    if verify is not None:
+        sig['verify'] = dict(sigs[_decoding._VERIFY_DIR],
+                             draft_k=int(spec['draft_k']))
     if layout == 'block':
         sig['block'] = {'block_size': int(spec['block_size']),
                         'num_blocks': int(spec['num_blocks']),
                         'max_blocks_per_slot':
                             int(spec['max_blocks_per_slot'])}
         sig['chunk_buckets'] = chunks
-        sig['chunk'] = chunk_sig
+        sig['chunk'] = {str(C): sigs[_decoding._CHUNK_DIR % C]
+                        for C in chunks}
     else:
         sig['prompt_buckets'] = buckets
-        sig['prefill'] = prefill_sig
+        sig['prefill'] = {str(L): sigs[_decoding._PREFILL_DIR % L]
+                          for L in buckets}
     if shard is not None:
         sig['mesh'] = {'axes': {a: int(n) for a, n in
                                 shard['axes'].items()},
@@ -551,7 +616,10 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                        'tag': shard['tag'],
                        'state_shardings':
                            {n: list(ps) for n, ps in
-                            (spec.get('state_shardings') or {}).items()}}
+                            (spec.get('state_shardings') or {}).items()},
+                       'param_shardings':
+                           {n: list(ps) for n, ps in
+                            shard['param_specs'].items()}}
     with open(os.path.join(out_dir, _decoding._DECODE_SIGNATURE), 'w') as f:
         json.dump(sig, f, indent=1)
     if _should_precompile(precompile):
@@ -578,14 +646,14 @@ def _shard_trace_ctx(shard):
     return trace_mesh_scope(shard['mesh'])
 
 
-def _export_serialize(fn, in_specs, out_dir, shard=None,
+def _export_serialize(fn, in_specs, out_dir, shard=None, in_shardings=None,
                       out_shardings=None):
     """jit + jax.export one decode program and write its module. An
     unsharded program exports cross-platform (cpu+tpu); a sharded one is
-    single-platform (the mesh's) with the state pinned input AND output
-    to its annotated shardings — the round-13 fixed-point discipline
-    that keeps the step a sharding-stable loop under the AOT warm
-    path."""
+    single-platform (the mesh's) with its arguments and results pinned
+    to `in_shardings` / `out_shardings` — the state input AND output to
+    its annotated shardings, the round-13 fixed-point discipline that
+    keeps the step a sharding-stable loop under the AOT warm path."""
     import jax
     from jax import export as jexport
     # the program's name in a device trace (jit_decode_step,
@@ -596,14 +664,8 @@ def _export_serialize(fn, in_specs, out_dir, shard=None,
         jitted = jax.jit(fn)
         platforms = ['cpu', 'tpu']
     else:
-        def rep_like(spec_tree):
-            return jax.tree_util.tree_map(lambda _: shard['rep'],
-                                          spec_tree)
-        in_sh = (list(shard['state_ns']),) + tuple(
-            rep_like(s) for s in in_specs[1:])
-        out_sh = (out_shardings if out_shardings is not None
-                  else (None, list(shard['state_ns'])))
-        jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh)
+        jitted = jax.jit(fn, in_shardings=in_shardings,
+                         out_shardings=out_shardings)
         platforms = [shard['platform']]
     with _shard_trace_ctx(shard):
         exp = jexport.export(jitted, platforms=platforms)(*in_specs)
@@ -612,41 +674,18 @@ def _export_serialize(fn, in_specs, out_dir, shard=None,
         f.write(exp.serialize())
 
 
-def _export_decode_program(entry, state_names, state0, scope, out_dir,
-                           shard=None):
-    """Trace one decode program as fn(state, feeds) -> (fetches,
-    new_state) — export_train_step's state-threading convention minus
-    the rng (decode programs draw no randomness) — and serialize it.
-    With `shard` (_decode_shard_ctx), the trace runs over the composed
-    mesh: baked params CONSTRAIN to their annotated shardings (so the
-    weights genuinely partition across the mesh instead of replicating
-    as constants), the KV state threads through mp-sharded input->output
-    (fixed-point pinned), and feeds/fetches stay replicated (the host
-    scheduler sees full arrays). Returns the program's signature
-    entries: its 'feeds', and under 'attention' the body each of its
-    kv_*attention* ops holds where the module is compiled for a TPU, by
-    op type and counted ({'kv_block_attention': {'kernel': 6}}) —
-    'kernel' is the paged Pallas kernel, which only kv_block_attention
-    has and reports to its Tracer as it lowers (ops/decode_ops.py);
-    every other body, and every body on another platform, is the 'jnp'
-    expression over the gathered view."""
-    import jax
-    import jax.numpy as jnp
-    from ..core.lowering import Tracer
-    from ..core.lod import LoDArray
+def _optimize_decode_program(entry, state_names):
+    """One decode program through the inference pass pipeline; the
+    program as built when the pipeline fails on it."""
     from .. import passes
-
-    program = entry['program']
-    feed_names = list(entry['feeds'])
-    fetch_names = list(entry['fetches'])
-    samples = {n: np.asarray(entry['samples'][n]) for n in feed_names}
-    state_set = set(state_names)
     try:
         # liveness roots include the cache state: its in-place writes are
         # program outputs even though they are not fetched
         program, _ = passes.apply_inference_pipeline(
-            program, fetch_names=fetch_names + list(state_names),
-            feed_names=feed_names)
+            entry['program'],
+            fetch_names=list(entry['fetches']) + list(state_names),
+            feed_names=list(entry['feeds']))
+        return program
     except passes.ProgramVerifyError:
         raise
     except Exception as e:
@@ -655,28 +694,98 @@ def _export_decode_program(entry, state_names, state0, scope, out_dir,
             "export_decode optimization pipeline failed (%s: %s); "
             "exporting the unoptimized program" % (type(e).__name__, e),
             RuntimeWarning)
-        program = entry['program']
+        return entry['program']
 
-    baked = {}
-    for v in program.list_vars():
-        if v.persistable and v.name not in state_set:
-            val = scope.get(v.name)
-            if val is not None:
-                baked[v.name] = np.asarray(
-                    val.data if isinstance(val, LoDArray) else val)
+
+def _decode_params(programs, state_names, scope):
+    """{name: scope value} of every persistable that any of `programs`
+    reads and that is not cache state: the artifact's weights."""
+    from ..core.lod import LoDArray
+    state_set = set(state_names)
+    params = {}
+    for program in programs:
+        for v in program.list_vars():
+            if (v.persistable and v.name not in state_set
+                    and v.name not in params):
+                val = scope.get(v.name)
+                if val is not None:
+                    params[v.name] = (val.data if isinstance(val, LoDArray)
+                                      else val)
+    return params
+
+
+def _write_decode_weights(path, param_args, params):
+    """The artifact's ONE copy of the weights: each argument's bytes in
+    `param_args` order, each argument starting on a 64-byte boundary and
+    a pack's members end to end inside it. Returns the signature's
+    'params' list ({name, shape, dtype, offset, nbytes}), which with
+    'param_args' is all the loader needs to map the file."""
+    out = []
+    off = 0
+    with open(path, 'wb') as f:
+        for names in param_args:
+            pad = -off % 64
+            f.write(b'\0' * pad)
+            off += pad
+            for n in names:
+                a = np.ascontiguousarray(np.asarray(params[n]))
+                f.write(a.tobytes())
+                out.append({'name': n, 'shape': list(a.shape),
+                            'dtype': a.dtype.name, 'offset': off,
+                            'nbytes': int(a.nbytes)})
+                off += a.nbytes
+    return out
+
+
+def _export_decode_program(entry, program, param_args, param_specs,
+                           state_names, state_specs, out_dir, shard=None):
+    """Trace one decode program as fn(params, state, feeds) -> (fetches,
+    new_state) — export_train_step's state-threading convention minus
+    the rng (decode programs draw no randomness) — and serialize it.
+    `params` is the artifact's whole weight list as `param_args` groups
+    it (_param_args: the small vectors packed, every other parameter by
+    itself), the same for every program (one that reads only some
+    ignores the rest), UNDONATED: the loader holds one set of device buffers and
+    hands it to step, chunk and verify alike, and no module holds a
+    weight as a constant. `state` threads through donated.
+    With `shard` (_decode_shard_ctx), the trace runs over the composed
+    mesh: the parameter ARGUMENTS are pinned to their annotated
+    shardings (so the weights genuinely partition across the mesh), the
+    KV state threads through mp-sharded input->output (fixed-point
+    pinned), and feeds/fetches stay replicated (the host scheduler sees
+    full arrays). Returns the program's signature entries: its 'feeds',
+    and under 'attention' the body each of its kv_*attention* ops holds
+    where the module is compiled for a TPU, by op type and counted
+    ({'kv_block_attention': {'kernel': 6}}) — 'kernel' is the paged
+    Pallas kernel, which only kv_block_attention has and reports to its
+    Tracer as it lowers (ops/decode_ops.py); every other body, and
+    every body on another platform, is the 'jnp' expression over the
+    gathered view."""
+    import jax
+    from ..core.lowering import Tracer
+
+    feed_names = list(entry['feeds'])
+    fetch_names = list(entry['fetches'])
+    samples = {n: np.asarray(entry['samples'][n]) for n in feed_names}
     rng = jax.random.key(0)  # decode programs draw no randomness
-    param_ns = shard['param_ns'] if shard is not None else {}
+    constrain = shard['constrain'] if shard is not None else {}
+    sizes = {v.name: int(np.prod(v.shape)) for v in program.list_vars()
+             if v.persistable}
 
-    def fn(state_list, feed_list):
+    def fn(param_list, state_list, feed_list):
         tracer = Tracer(program, rng)
-        for n, v in baked.items():
-            ns = param_ns.get(n)
-            if ns is not None:
-                # baked constant -> sharded resident weight: without the
-                # constraint GSPMD may replicate the constant and the
-                # model stops fitting the per-chip HBM the mesh buys
-                v = jax.lax.with_sharding_constraint(jnp.asarray(v), ns)
-            tracer.env[n] = v
+        for names, arg in zip(param_args, param_list):
+            if len(names) == 1:
+                tracer.env[names[0]] = arg
+                continue
+            off = 0
+            for n in names:     # a pack: its members end to end
+                size = sizes[n]
+                tracer.env[n] = jax.lax.slice(arg, (off,), (off + size,))
+                off += size
+        for n, ns in constrain.items():
+            tracer.env[n] = jax.lax.with_sharding_constraint(
+                tracer.env[n], ns)
         tracer.env.update(dict(zip(state_names, state_list)))
         tracer.env.update(dict(zip(feed_names, feed_list)))
         tracer.run_block(program.global_block())
@@ -685,15 +794,16 @@ def _export_decode_program(entry, state_names, state0, scope, out_dir,
                 [tracer.env[n] for n in state_names])
 
     lowered = []     # (op type, body) as the export's trace lowered them
-    state_specs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in state0]
     feed_specs = [jax.ShapeDtypeStruct(samples[n].shape, samples[n].dtype)
                   for n in feed_names]
-    out_sh = None
+    in_sh = out_sh = None
     if shard is not None:
+        in_sh = (list(shard['param_ns']), list(shard['state_ns']),
+                 [shard['rep']] * len(feed_names))
         out_sh = ([shard['rep']] * len(fetch_names),
                   list(shard['state_ns']))
-    _export_serialize(fn, (state_specs, feed_specs), out_dir, shard=shard,
-                      out_shardings=out_sh)
+    _export_serialize(fn, (param_specs, state_specs, feed_specs), out_dir,
+                      shard=shard, in_shardings=in_sh, out_shardings=out_sh)
     # ops that chose a body said so as they lowered; the other
     # kv_*attention* ops have the one jnp body
     reported = {op_type for op_type, _ in lowered}
@@ -709,29 +819,54 @@ def _export_decode_program(entry, state_names, state0, scope, out_dir,
             'attention': attention}
 
 
-def _export_decode_reorder(state0, n_rows, out_dir, shard=None):
+def _state_program_shardings(shard, n_index_args):
+    """(in_shardings, out_shardings) of a program over the state list and
+    `n_index_args` replicated index vectors; (None, None) unsharded."""
+    if shard is None:
+        return None, None
+    state_ns = list(shard['state_ns'])
+    return (state_ns,) + (shard['rep'],) * n_index_args, state_ns
+
+
+def _export_decode_reorder(state_specs, n_rows, out_dir, shard=None):
     """Serialize the axis-0 gather program: new_state[i] = state[i][src]
     per cache var (src [n_rows] int32 — slot rows in the slot layout,
     PHYSICAL BLOCKS in the block layout). Pure structural jax — no
-    Program IR needed. Undonated by design: besides beam reordering, the
-    serving tier routes freshly loaded state through it once so every
-    buffer reaching the DONATED step/prefill executables is XLA-owned."""
+    Program IR needed. Undonated: beam reordering reads rows it also
+    writes."""
     import jax
     import jax.numpy as jnp
 
     def fn(state_list, src):
         return [jnp.take(s, src, axis=0) for s in state_list]
 
-    state_specs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in state0]
     src_spec = jax.ShapeDtypeStruct((n_rows,), np.int32)
-    out_sh = None
-    if shard is not None:
-        out_sh = list(shard['state_ns'])
+    in_sh, out_sh = _state_program_shardings(shard, 1)
     _export_serialize(fn, (state_specs, src_spec), out_dir, shard=shard,
-                      out_shardings=out_sh)
+                      in_shardings=in_sh, out_shardings=out_sh)
 
 
-def _export_decode_blockcopy(state0, max_pairs, out_dir, shard=None):
+def _export_decode_zeros(state_specs, out_dir, shard=None):
+    """Serialize the program that GIVES BIRTH to the cache state: one
+    zero array per cache var, made on the device. The loader's state
+    therefore starts life as XLA-owned buffers — the only kind that may
+    reach a donated reloaded executable — without a host copy of the
+    pool and without ever holding the pool twice. Its one argument, an
+    int32 [1] it does not read, is there to say WHERE: a call without
+    arguments has no devices to take a sharded program's mesh from."""
+    import jax
+    import jax.numpy as jnp
+
+    def fn(where):
+        return [jnp.zeros(s.shape, s.dtype) for s in state_specs]
+
+    _export_serialize(fn, (jax.ShapeDtypeStruct((1,), np.int32),), out_dir,
+                      shard=shard,
+                      in_shardings=shard and (shard['rep'],),
+                      out_shardings=shard and list(shard['state_ns']))
+
+
+def _export_decode_blockcopy(state_specs, max_pairs, out_dir, shard=None):
     """Serialize the block-copy program (block layout only): up to
     `max_pairs` (dst, src) PHYSICAL-BLOCK pairs copy per dispatch —
     new_state[i] = state[i].at[dst].set(state[i][src]) for every pool
@@ -745,13 +880,10 @@ def _export_decode_blockcopy(state0, max_pairs, out_dir, shard=None):
     def fn(state_list, dst, src):
         return [s.at[dst].set(s[src]) for s in state_list]
 
-    state_specs = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in state0]
     idx_spec = jax.ShapeDtypeStruct((max_pairs,), np.int32)
-    out_sh = None
-    if shard is not None:
-        out_sh = list(shard['state_ns'])
+    in_sh, out_sh = _state_program_shardings(shard, 2)
     _export_serialize(fn, (state_specs, idx_spec, idx_spec), out_dir,
-                      shard=shard, out_shardings=out_sh)
+                      shard=shard, in_shardings=in_sh, out_shardings=out_sh)
 
 
 def _optimize_for_export(predictor):

@@ -97,6 +97,9 @@ _TIER_INT8 = 'int8'
 _TRAIN_SIGNATURE = 'train_signature.json'
 _TRAIN_MODULE = 'train_module.jaxexport'
 _TRAIN_STATE0 = 'train_state0.npz'
+# export_decode's ONE copy of the weights: raw bytes, mapped through the
+# decode signature's 'params' list (decoding.py loads it once per replica)
+_DECODE_WEIGHTS = 'decode_weights.bin'
 # AOT warm-start sidecars (ISSUE 5): the module's XLA executable,
 # serialized per platform next to the module it was compiled from —
 # loading one skips BOTH the StableHLO deserialize-compile and the trace,

@@ -1533,6 +1533,74 @@ def switch_moe_ffn(input, num_experts, d_ff, capacity_factor=1.25,
     return out, aux
 
 
+def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
+    """Root-mean-square norm over the last axis: x * rsqrt(mean(x^2) +
+    epsilon) * weight, computed and returned in float32. The weight [D]
+    starts at one."""
+    helper = LayerHelper('rms_norm', param_attr=param_attr, name=name)
+    scale = helper.create_parameter(
+        attr=helper.param_attr, shape=[int(input.shape[-1])],
+        dtype='float32', default_initializer=ConstantInitializer(1.0))
+    out = helper.create_variable_for_type_inference('float32')
+    helper.append_op(type='rms_norm', inputs={'X': input, 'Scale': scale},
+                     outputs={'Y': out}, attrs={'epsilon': float(epsilon)})
+    return out
+
+
+def rotary_embedding(input, pos, n_head, theta=10000.0):
+    """Rotate-half rotary position embedding of `input` [..., n_head *
+    d_head] at the FED positions `pos` (one per row of input's leading
+    axes, any integer shape of that many elements): within each head,
+    channel i of the first half pairs with channel i of the second and
+    the pair turns by pos * theta^(-2i / d_head). float32 out."""
+    helper = LayerHelper('rotary_embedding')
+    out = helper.create_variable_for_type_inference('float32')
+    helper.append_op(type='rotary_embedding',
+                     inputs={'X': input, 'Pos': pos}, outputs={'Out': out},
+                     attrs={'n_head': int(n_head), 'theta': float(theta)})
+    return out
+
+
+def swiglu(gate, up):
+    """The gated FFN's activation: silu(gate) * up, elementwise."""
+    helper = LayerHelper('swiglu')
+    out = helper.create_variable_for_type_inference(gate.dtype)
+    helper.append_op(type='swiglu', inputs={'Gate': gate, 'Up': up},
+                     outputs={'Out': out}, attrs={})
+    return out
+
+
+def moe_topk_ffn(input, num_experts, d_ff, k, norm_topk_prob=False,
+                 dtype=None, param_attr=None, name=None):
+    """Dropless top-k mixture of SwiGLU experts over the last axis of
+    `input` (ops/moe_ops.py moe_topk_ffn): a float32 softmax router
+    [D, num_experts], each token's `k` best experts (weights
+    renormalised only with norm_topk_prob), and per expert gate / up
+    [D, d_ff] and down [d_ff, D] matrices stacked [num_experts, ...].
+    Every (token, expert) pair is computed: there is no capacity.
+    Weights are created in `dtype` (default: input's) under
+    param_attr's name + '_router' / '_gate' / '_up' / '_down'; float32
+    out."""
+    helper = LayerHelper('moe_topk_ffn', name=name)
+    d = int(input.shape[-1])
+    dtype = dtype or input.dtype
+    shapes = {'RouterW': ('_router', [d, num_experts]),
+              'WGate': ('_gate', [num_experts, d, d_ff]),
+              'WUp': ('_up', [num_experts, d, d_ff]),
+              'WDown': ('_down', [num_experts, d_ff, d])}
+    inputs = {'X': input}
+    for slot, (suffix, shape) in shapes.items():
+        inputs[slot] = helper.create_parameter(
+            attr=_suffixed_attr(param_attr, suffix), shape=shape,
+            dtype=dtype)
+    out = helper.create_variable_for_type_inference('float32')
+    helper.append_op(type='moe_topk_ffn', inputs=inputs,
+                     outputs={'Out': out},
+                     attrs={'k': int(k),
+                            'norm_topk_prob': bool(norm_topk_prob)})
+    return out
+
+
 def pipelined_ffn_stack(input, num_layers, d_ff, num_microbatches=0,
                         pipe_axis='pp', param_attr=None, name=None):
     """A stack of `num_layers` residual FFN layers (x + W2·relu(W1·x))

@@ -18,4 +18,5 @@ from . import rnn_ops         # noqa: F401
 from . import sparse_ops      # noqa: F401
 from . import detection_ops   # noqa: F401
 from . import moe_ops         # noqa: F401
+from . import llm_ops         # noqa: F401
 from . import pipeline_ops    # noqa: F401

@@ -220,7 +220,12 @@ def _mul(ctx, ins):
     yn = ctx.attr('y_num_col_dims', 1)
     x2 = _flatten2(x, xn)
     y2 = y.reshape(int(np.prod(y.shape[:yn])), -1)
-    out = amp.matmul(x2, y2, preferred_element_type=x2.dtype)
+    if x2.dtype == jnp.float32 and y2.dtype == jnp.bfloat16:
+        # a weight STORED in bfloat16 under a float32 activation: the
+        # MXU's native product (bf16 x bf16, float32 accumulation) on the
+        # stored bytes, not an upcast copy of the weight
+        x2 = x2.astype(jnp.bfloat16)
+    out = amp.matmul(x2, y2, preferred_element_type=x.dtype)
     out_shape = x.shape[:xn] + y.shape[yn:]
     return {'Out': [out.reshape(out_shape)]}
 

@@ -67,3 +67,52 @@ def _switch_moe_ffn(ctx, ins):
     out = amp.restore(out.astype(x_in.dtype), x_in)
     return {'Out': [out.reshape(*lead, d)],
             'AuxLoss': [aux.reshape(1)]}
+
+
+@register('moe_topk_ffn', diff_inputs=('X', 'RouterW', 'WGate', 'WUp',
+                                       'WDown'))
+def _moe_topk_ffn(ctx, ins):
+    """Dropless top-k routed SwiGLU FFN: X [..., D], RouterW [D, E],
+    WGate / WUp [E, D, F], WDown [E, F, D] -> Out [..., D] float32.
+
+    p = softmax(X RouterW) over the E experts in float32 (the product at
+    'highest': a router that rounds its input picks other experts); the
+    top-k values and indices (ties to the lower index, lax.top_k's
+    rule), renormalised only when attr norm_topk_prob; out = sum_k p_k *
+    WDown_e(silu(WGate_e x) * WUp_e x). No capacity and no
+    [tokens, experts, capacity] tensor: the N * k (token, expert) pairs
+    are sorted by expert and every expert multiplies its own contiguous
+    rows (lax.ragged_dot, operands in the weights' dtype, float32
+    accumulation), so no pair is dropped however uneven the routing.
+    Each token's k partial results are summed in its own top-k order, so
+    a row's output does not depend on what else is in the batch."""
+    from .llm_ops import swiglu
+    x_in = ins['X'][0]
+    router_w, w_gate, w_up, w_down = (ins[n][0] for n in
+                                      ('RouterW', 'WGate', 'WUp', 'WDown'))
+    k = int(ctx.attr('k'))
+    e, d, _ = w_gate.shape
+    x = x_in.reshape(-1, d)
+    n = x.shape[0]
+    with jax.named_scope('router'):
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            router_w.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)
+        vals, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        if ctx.attr('norm_topk_prob', False):
+            vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+    with jax.named_scope('dispatch'):
+        expert = idx.reshape(-1)                       # [N * k]
+        order = jnp.argsort(expert, stable=True)
+        rows = x.astype(w_gate.dtype)[order // k]      # sorted by expert
+        sizes = jnp.bincount(expert, length=e).astype(jnp.int32)
+    with jax.named_scope('experts'):
+        def grouped(a, w):
+            return jax.lax.ragged_dot(a, w, sizes,
+                                      preferred_element_type=jnp.float32)
+        h = swiglu(grouped(rows, w_gate), grouped(rows, w_up))
+        y = grouped(h.astype(w_down.dtype), w_down)    # [N * k, D]
+    with jax.named_scope('combine'):
+        y = y[jnp.argsort(order)].reshape(n, k, d)     # back to top-k order
+        out = jnp.sum(y * vals[..., None], axis=1)
+    return {'Out': [out.reshape(x_in.shape)]}

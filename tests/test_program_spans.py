@@ -113,7 +113,7 @@ _DECODE_SPANS = ('decode/submit', 'decode/tick', 'decode/expire',
                  'decode/prefill_slice', 'decode/step', 'decode/build_feed',
                  'decode/dispatch', 'decode/device_wait', 'decode/d2h',
                  'decode/advance', 'decode/first_token', 'decode/finish',
-                 'load/read', 'load/reset_state')
+                 'load/read', 'load/weights', 'load/reset_state')
 
 
 @pytest.mark.parametrize('name', _DECODE_SPANS)
@@ -141,7 +141,7 @@ def test_decode_children_lie_inside_their_parents(decode_trace, child,
                                                   parents):
     spans = [s for s in decode_trace.named(child)
              # the constructor's state reset dispatches outside any tick
-             if s[4].get('program') != 'reorder']
+             if s[4].get('program') != 'zeros']
     assert spans
     for span in spans:
         assert decode_trace.parent_of(span, parents) is not None, \
@@ -189,7 +189,7 @@ def test_step_d2h_bytes_is_slots_x_vocab_x_4(decode_trace):
     assert step
     assert {s[4]['bytes'] for s in step} == {SLOTS * VOCAB * 4}
     programs = {s[4]['program'] for s in decode_trace.named('decode/dispatch')}
-    assert {'step', 'chunk_4', 'chunk_8', 'reorder'} <= programs
+    assert {'step', 'chunk_4', 'chunk_8', 'zeros'} <= programs
     ticks = [s[4]['tick'] for s in decode_trace.named('decode/tick')]
     assert ticks == sorted(ticks) and len(set(ticks)) == len(ticks)
 
@@ -197,7 +197,7 @@ def test_step_d2h_bytes_is_slots_x_vocab_x_4(decode_trace):
 @pytest.mark.parametrize('sub,name', [
     ('decode_step', 'decode_step'), ('prefill_chunk_00004', 'prefill_chunk_4'),
     ('prefill_chunk_00008', 'prefill_chunk_8'),
-    ('decode_reorder', 'decode_reorder'),
+    ('decode_reorder', 'decode_reorder'), ('decode_zeros', 'decode_zeros'),
     ('decode_blockcopy', 'decode_blockcopy')])
 def test_exported_decode_programs_have_stable_names(decode_art, sub, name):
     """What 'XLA Modules' prints as jit_<name> for an AOT-loaded program."""
