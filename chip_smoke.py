@@ -29,7 +29,7 @@ this one process — a chip belongs to one process at a time):
               64 experts of 1024 top-8, vocab 50,304; 6 layers, 4 slots of
               2048 positions, bfloat16 weights and pool): 4 seeded prompts
               of 100-1500 tokens prefilled in chunks and 96 tokens decoded
-              through the block cache, the programs' LOGITS (fetch 0 of
+              through the block cache, the programs' LOGITS (fetch 1 of
               chunk and step, through the predictor's own dispatch)
               against the plain reference's full forward pass
               (benchmark/reference/olmoe.py) over prompt + served tokens.

@@ -10,6 +10,12 @@ vLLM-style preallocated, slot-paged KV cache):
    per prompt-length bucket (one request: writes the prompt's K/V rows
    into one cache slot and returns first-token logits) and ONE
    DECODE-STEP program ([max_slots] requests advance one token each).
+   Every token-emitting program returns TWO fetches (signature version
+   5): fetch 0 `ids`, int32, the argmax of its logits over the
+   vocabulary (lowest index on ties, np.argmax's rule) — [max_slots],
+   [max_slots, K+1] for verify, [1] for a prefill or chunk — and fetch
+   1 the float32 logits. The scheduler copies the ids; the logits stay
+   on the device unless a beam row is live in that dispatch.
    Idle slots are masked by each slot's own attention window, so a
    partially full batch runs the same compiled shape — ZERO recompiles
    in steady state, and zero compiles at all in a warm fresh process
@@ -36,8 +42,9 @@ vLLM-style preallocated, slot-paged KV cache):
 Determinism contract: a request's token stream is bit-identical whether
 it decodes alone or co-resident with any other requests — every per-slot
 computation is row-independent and masked rows carry exactly-zero
-attention weight (ops/decode_ops.py). Greedy and fixed-width beam search
-run host-side over the fetched logits with deterministic tie-breaking.
+attention weight (ops/decode_ops.py). Greedy decoding reads the ids the
+program chose on the device; fixed-width beam search runs host-side over
+the fetched logits with deterministic tie-breaking.
 
 Speculative decoding (ISSUE 17): artifacts exported with a VERIFY
 program (build_decode_spec(draft_k=K)) can serve greedy streams
@@ -119,8 +126,10 @@ _VERIFY_DIR = 'decode_verify'
 # XLA-owned buffers, the pool held once)
 _ZEROS_DIR = 'decode_zeros'
 # signature version 4: weights are arguments of every program, loaded
-# once from serve._DECODE_WEIGHTS; up to 3 they were module constants
-_SIG_VERSION = 4
+# once from serve._DECODE_WEIGHTS; up to 3 they were module constants.
+# Version 5: fetch 0 of every token-emitting program is the argmax ids,
+# fetch 1 the logits; up to 4 the logits were the one fetch
+_SIG_VERSION = 5
 
 
 def _dtype(name):
@@ -210,6 +219,12 @@ def _percentiles(values, qs):
     return [round(float(p), 3) for p in np.percentile(arr, qs)]
 
 
+def _one_row(ids, logits):
+    """(token, [V] logits or None) of a one-request program's [1] ids
+    and [1, V] logits."""
+    return int(ids[0]), None if logits is None else logits[0]
+
+
 def _log_softmax(row):
     """Deterministic host log-softmax (float64): beam scoring must give
     the same bits for the same logits regardless of co-residency."""
@@ -241,6 +256,9 @@ class DecodeStats(object):
         self.tokens = 0          # tokens decoded (all beams)
         self.prefills = 0        # prefill dispatches
         self.steps = 0           # decode-step dispatches
+        # dispatches whose LOGITS were copied to the host (a beam row
+        # was live, or a caller asked); every other one copied its ids
+        self.logits_fetches = 0
         self.reorders = 0        # slot-gather dispatches (beam/replicate)
         self.active_slot_steps = 0
         self.slot_steps = 0
@@ -278,6 +296,7 @@ class DecodeStats(object):
             self.tokens = 0
             self.prefills = 0
             self.steps = 0
+            self.logits_fetches = 0
             self.reorders = 0
             self.active_slot_steps = 0
             self.slot_steps = 0
@@ -323,6 +342,7 @@ class DecodeStats(object):
                     'tokens': int(self.tokens),
                     'prefills': int(self.prefills),
                     'steps': int(self.steps),
+                    'logits_fetches': int(self.logits_fetches),
                     'reorders': int(self.reorders),
                     'occupancy': round(occ, 4),
                     'tokens_s': round(self.tokens / self.busy_s, 2)
@@ -669,13 +689,17 @@ def _precompile_decode_dir(d, arg_specs, donate=None, platform=None,
 def _load_signature(artifact_dir):
     with open(os.path.join(artifact_dir, _DECODE_SIGNATURE)) as f:
         sig = json.load(f)
-    if int(sig.get('version', 0)) < _SIG_VERSION:
+    version = int(sig.get('version', 0))
+    if version < _SIG_VERSION:
         raise ValueError(
             'decode artifact %s has signature version %s: its programs '
-            'hold the weights as constants. Version %d programs take '
-            'them as arguments (one %s) — export it again with this '
-            'export_decode' % (artifact_dir, sig.get('version'),
-                               _SIG_VERSION, _serve._DECODE_WEIGHTS))
+            '%s. Version %d programs take the weights as arguments (one '
+            '%s) and return the argmax ids as fetch 0 beside the logits '
+            '— export it again with this export_decode'
+            % (artifact_dir, sig.get('version'),
+               'hold the weights as constants' if version < 4
+               else 'return their logits alone',
+               _SIG_VERSION, _serve._DECODE_WEIGHTS))
     return sig
 
 
@@ -1255,22 +1279,35 @@ class DecodingPredictor(object):
             self.stats.block_source = self._blocks.stats
             self.stats.block_reset = self._blocks.reset_counters
 
-    def _to_host(self, fetch, program):
-        """The logits of one dispatch as a host array, in two spans: the
-        wait for the device to finish the program (launch latency and
-        the program's own time sit here), then what is left of the
-        device-to-host copy. The copy is queued behind the program
-        FIRST, as a bare np.asarray would queue it: waiting for the
-        program before asking for the copy would put a host wake-up
-        between the two (measured: +2 % on the inter-token gap)."""
+    def _to_host(self, fetches, program, logits):
+        """(ids, logits) of one dispatch as host arrays, in two spans:
+        the wait for the device to finish the program (launch latency
+        and the program's own time sit here), then what is left of the
+        device-to-host copy. The ids (fetch 0) always come; the logits
+        (fetch 1) stay a device array, and None here, unless `logits` —
+        a beam row live in this dispatch — asks for them. The copy is
+        queued behind the program FIRST, as a bare np.asarray would
+        queue it: waiting for the program before asking for the copy
+        would put a host wake-up between the two (measured: +2 % on the
+        inter-token gap)."""
         import jax
-        fetch.copy_to_host_async()
+        copied = fetches[:2] if logits else fetches[:1]
+        for f in copied:
+            f.copy_to_host_async()
         with _span('decode/device_wait', program=program):
-            jax.block_until_ready(fetch)
-        with _span('decode/d2h', program=program, bytes=int(fetch.nbytes)):
-            return np.asarray(fetch)
+            jax.block_until_ready(copied)
+        with _span('decode/d2h', program=program,
+                   bytes=sum(int(f.nbytes) for f in copied),
+                   fetch='logits' if logits else 'ids'):
+            host = [np.asarray(f) for f in copied]
+        if logits:
+            with self.stats._lock:
+                self.stats.logits_fetches += 1
+        return host[0], host[1] if logits else None
 
-    def _dispatch_step(self, tokens, pos, tables=None):
+    def _dispatch_step(self, tokens, pos, tables=None, logits=False):
+        """One decode step: ids [S] int32 and, if `logits`, the [S, V]
+        float32 rows they are the argmax of (else None)."""
         feed = {'tokens': tokens, 'pos': pos}
         if tables is not None:
             feed['block_tables'] = tables
@@ -1282,14 +1319,16 @@ class DecodingPredictor(object):
         self._state = list(new_state)
         with self.stats._lock:
             self.stats.steps += 1
-        return self._to_host(fetches[0], 'step')           # [S, V] sync
+        return self._to_host(fetches, 'step', logits)      # sync
 
-    def _dispatch_verify(self, tokens, pos, tables=None):
+    def _dispatch_verify(self, tokens, pos, tables=None, logits=False):
         """One speculative verify dispatch (ISSUE 17): tokens/pos are
         [S, K+1] (row 0 the slot's pending last token, rows 1..k its
-        draft; pad rows/slots at the layout's pad position), logits come
-        back [S, K+1, V]. KV for all fed positions is written inside the
-        program; acceptance and rollback happen host-side after."""
+        draft; pad rows/slots at the layout's pad position), the target
+        argmax ids come back [S, K+1] (beams never draft, so the
+        scheduler never asks for the [S, K+1, V] logits). KV for all fed
+        positions is written inside the program; acceptance and rollback
+        happen host-side after."""
         feed = {'tokens': tokens, 'pos': pos}
         if tables is not None:
             feed['block_tables'] = tables
@@ -1300,9 +1339,11 @@ class DecodingPredictor(object):
         self._state = list(new_state)
         with self.stats._lock:
             self.stats.verify_steps += 1
-        return self._to_host(fetches[0], 'verify')      # [S, K+1, V] sync
+        return self._to_host(fetches, 'verify', logits)    # sync
 
-    def _dispatch_prefill(self, bucket, padded, plen, slot):
+    def _dispatch_prefill(self, bucket, padded, plen, slot, logits=False):
+        """One whole-prompt prefill: the first token's id and, if
+        `logits`, the [V] row it is the argmax of (else None)."""
         feed = {'prompt_ids': padded,
                 'prompt_len': np.full((1, 1), plen, np.int32),
                 'slot': np.full((1, 1), slot, np.int32)}
@@ -1313,13 +1354,16 @@ class DecodingPredictor(object):
         self._state = list(new_state)
         with self.stats._lock:
             self.stats.prefills += 1
-        return self._to_host(fetches[0],                   # [V] sync
-                             self._prefill_mods[bucket].name)[0]
+        return _one_row(*self._to_host(
+            fetches, self._prefill_mods[bucket].name, logits))     # sync
 
-    def _dispatch_chunk(self, size, ids, start, take, table_row):
+    def _dispatch_chunk(self, size, ids, start, take, table_row,
+                        logits=False):
         """One chunked-prefill slice: `take` real rows of one prompt at
         absolute positions start..start+take-1 (the rest of the `size`
-        rows are pad) write through `table_row` [1, max_blocks]."""
+        rows are pad) write through `table_row` [1, max_blocks]. Returns
+        the id the slice's last real position chose and, if `logits`,
+        its [V] row (else None)."""
         feed = {'chunk_ids': ids,
                 'start': np.full((1, 1), start, np.int32),
                 'chunk_len': np.full((1, 1), take, np.int32),
@@ -1332,8 +1376,8 @@ class DecodingPredictor(object):
         with self.stats._lock:
             self.stats.prefills += 1
             self.stats.chunk_slices += 1
-        return self._to_host(fetches[0],                   # [V] sync
-                             self._chunk_mods[size].name)[0]
+        return _one_row(*self._to_host(
+            fetches, self._chunk_mods[size].name, logits))         # sync
 
     def _dispatch_blockcopy(self, pairs):
         """One block-copy dispatch: every (dst, src) PHYSICAL-BLOCK pair
@@ -1583,24 +1627,25 @@ class DecodingPredictor(object):
                    take=plen, start=0):
             padded = np.zeros((1, bucket), np.int64)
             padded[0, :plen] = req.prompt
-            logits = self._dispatch_prefill(bucket, padded, plen,
-                                            req.slots[0])
+            tok, logits = self._dispatch_prefill(
+                bucket, padded, plen, req.slots[0],
+                logits=req.beam is not None)
             for i, s in enumerate(req.slots):
                 self._slots[s] = (req, i)
             with _req_span('decode/first_token', req):
-                self._first_token(req, logits)
+                self._first_token(req, tok, logits)
 
-    def _first_token(self, req, logits):
-        """Emit a request's first token from its prompt logits: greedy
-        argmax, or the top-W DISTINCT tokens seeding a beam group (the
-        standard first-expansion; a naive W*V step over identical beams
+    def _first_token(self, req, tok, logits):
+        """Emit a request's first token: greedy, `tok` — the id its
+        prompt's last position chose on the device; a beam, from that
+        position's `logits` the top-W DISTINCT tokens seeding the group
+        (the standard first-expansion; a naive W*V step over identical beams
         would collapse onto one token). Beam history fan-out: the slot
         layout replicates slot 0's cache rows through the reorder
         program; the block layout FORKS the prompt's block table — a
         host-side copy + incref, zero device work."""
         now = time.perf_counter()
         if req.beam is None:
-            tok = int(np.argmax(logits))
             req.last_tokens = [tok]
             req.tokens = [tok]
             req.produced = 1
@@ -1716,17 +1761,22 @@ class DecodingPredictor(object):
     def _prefill_slice(self, req, size, take):
         ids = np.zeros((1, size), np.int64)
         ids[0, :take] = req.prompt[req.next_start:req.next_start + take]
-        logits = self._dispatch_chunk(size, ids, req.next_start, take,
-                                      self._table_row(req.tables[0]))
+        last = req.next_start + take >= int(req.prompt.size)
+        # a beam's LAST slice is the one dispatch of a prompt whose whole
+        # logits row the host reads
+        tok, logits = self._dispatch_chunk(
+            size, ids, req.next_start, take,
+            self._table_row(req.tables[0]),
+            logits=last and req.beam is not None)
         req.next_start += take
-        if req.next_start < int(req.prompt.size):
+        if not last:
             return
         req.prefilling = False
         # publish the prompt's FULL blocks for prefix reuse (the
         # partial tail stays private: decode writes land there)
         self._blocks.register_prefix(req.prompt, req.tables[0])
         with _req_span('decode/first_token', req):
-            self._first_token(req, logits)
+            self._first_token(req, tok, logits)
 
     def _live_rows(self, skip=()):
         """(request, beam index, write position) for every slot that
@@ -1836,8 +1886,8 @@ class DecodingPredictor(object):
             if drafted:
                 self._verify_block(drafted, waiting)
             with _span('decode/build_feed'):
-                tokens, pos, tables, cow, active = self._step_feed_block(
-                    waiting, drafted)
+                tokens, pos, tables, cow, active, beam = \
+                    self._step_feed_block(waiting, drafted)
             sp.set_metadata(active=active)
             if not active:
                 return   # every live stream drafted (or shed): no plain step
@@ -1846,15 +1896,17 @@ class DecodingPredictor(object):
                 self.stats.slot_steps += self._S
             if cow:
                 self._dispatch_blockcopy(cow)
-            logits = self._dispatch_step(tokens, pos, tables=tables)
+            ids, logits = self._dispatch_step(tokens, pos, tables=tables,
+                                              logits=beam)
             with _span('decode/advance', rows=active):
-                self._advance_block(logits, drafted)
+                self._advance_block(ids, logits, drafted)
 
     def _step_feed_block(self, waiting, drafted):
         """The plain step's feed over the block pool: reserve and make
         writable every block this step writes, then fill tokens / pos /
         tables for the live undrafted rows. Returns them with the CoW
-        pairs to copy first and the number of live rows."""
+        pairs to copy first, the number of live rows, and whether one of
+        them is a beam's (the step's logits are then wanted)."""
         tokens = np.zeros((self._S, 1), np.int64)
         pos = np.zeros((self._S, 1), np.int32)
         tables = np.full((self._S, self._maxb), self._trash, np.int32)
@@ -1864,26 +1916,30 @@ class DecodingPredictor(object):
                              in self._live_rows(skip=drafted)])
         cow = []
         active = 0
+        beam = False
         for req, bi, p in self._live_rows(skip=drafted):
             self._ensure_writable(req, bi, p, cow)
             s = req.slots[bi]
             active += 1
+            beam = beam or req.beam is not None
             tokens[s, 0] = req.last_tokens[bi]
             pos[s, 0] = p
             table = req.tables[bi]
             tables[s, :len(table)] = table
-        return tokens, pos, tables, cow, active
+        return tokens, pos, tables, cow, active, beam
 
-    def _advance_block(self, logits, drafted):
-        """After the step: argmax / beam scoring over the fetched
-        logits, emit to the streams, finish what ended. Argmax and emit
-        interleave per request, so one span holds both."""
+    def _advance_block(self, ids, logits, drafted):
+        """After the step: emit the ids the program chose to the greedy
+        streams, score beams over the fetched logits (there iff a beam
+        row was live), finish what ended."""
         now = time.perf_counter()
-        for req in self._active_requests():
-            if req.prefilling or req in drafted:
-                continue
+        reqs = [r for r in self._active_requests()
+                if not (r.prefilling or r in drafted)]
+        toks = ids.tolist()     # once a step, not once a row
+        self._meter_greedy(reqs, now)
+        for req in reqs:
             if req.beam is None:
-                self._advance_greedy(req, logits, now)
+                self._advance_greedy(req, toks[req.slots[0]])
                 continue
             # shared beam scoring; the history move is the block
             # layout's own — table permutation instead of a slot-row
@@ -1904,14 +1960,22 @@ class DecodingPredictor(object):
             if all(req.finished) or req.produced >= req.max_new:
                 self._finish_beam(req)
 
-    def _advance_greedy(self, req, logits, now):
-        """Shared slot/block greedy advance: emit the argmax token,
+    def _meter_greedy(self, reqs, now):
+        """Every greedy request of `reqs` metered for the token this
+        step is about to give it (_advance_greedy), under ONE hold of
+        the stats lock a step instead of one a row."""
+        with self.stats._lock:
+            for req in reqs:
+                if req.beam is None:
+                    self._count_emit(req, now)
+
+    def _advance_greedy(self, req, tok):
+        """Shared slot/block greedy advance: emit the token the program
+        chose for the request's slot (already metered: _meter_greedy),
         finish on eos/max_new."""
-        tok = int(np.argmax(logits[req.slots[0]]))
         req.last_tokens[0] = tok
         req.tokens.append(tok)
         req.produced += 1
-        self._record_emit(req, now)
         req.stream._push(tok)
         if tok == self._eos or req.produced >= req.max_new:
             self._finish_greedy(req)
@@ -1966,19 +2030,20 @@ class DecodingPredictor(object):
                 drafted[req] = toks
         return drafted
 
-    def _advance_spec(self, req, draft, row_logits, now):
+    def _advance_spec(self, req, draft, row_ids, now):
         """Longest-prefix acceptance against the target argmax: row i
-        of `row_logits` [K+1, V] was computed with rows < i's tokens in
-        context, so its logits equal the plain step's EXACTLY while the
-        draft prefix matches. Emitting greedily row by row until the
-        draft diverges (the diverging row still contributes its
+        of `row_ids` [K+1] (the verify program's own argmax) was
+        computed with rows < i's tokens in context, so it equals the
+        plain step's id EXACTLY while the draft prefix matches. Emitting
+        greedily row by row until the draft diverges (the diverging row
+        still contributes its
         CORRECTED token; full acceptance adds the K+1'th bonus token),
         or eos / max_new truncates, reproduces the plain greedy
         transcript bit-for-bit. Returns the emitted token list."""
         k = len(draft)
         emitted = []
         for i in range(k + 1):
-            g = int(np.argmax(row_logits[i]))
+            g = row_ids[i]
             emitted.append(g)
             if g == self._eos \
                     or req.produced + len(emitted) >= req.max_new:
@@ -2031,11 +2096,12 @@ class DecodingPredictor(object):
         with self.stats._lock:
             self.stats.active_slot_steps += len(rows)
             self.stats.slot_steps += self._S
-        logits = self._dispatch_verify(tokens, pos)
+        ids, _ = self._dispatch_verify(tokens, pos)
         with _span('decode/advance', rows=len(rows)):
             now = time.perf_counter()
+            ids = ids.tolist()
             for req, draft in rows:
-                self._advance_spec(req, draft, logits[req.slots[0]], now)
+                self._advance_spec(req, draft, ids[req.slots[0]], now)
 
     def _verify_block(self, drafted, waiting):
         """Verify tick, block layout: preflight/extend/CoW every block
@@ -2081,12 +2147,13 @@ class DecodingPredictor(object):
         # blockcopy dispatch's S pairs: chunk
         for i in range(0, len(cow), self._S):
             self._dispatch_blockcopy(cow[i:i + self._S])
-        logits = self._dispatch_verify(tokens, pos, tables=tables)
+        ids, _ = self._dispatch_verify(tokens, pos, tables=tables)
         with _span('decode/advance', rows=len(rows)):
             now = time.perf_counter()
+            ids = ids.tolist()
             for req, bi, p, span in rows:
                 s = req.slots[0]
-                self._advance_spec(req, drafted[req], logits[s], now)
+                self._advance_spec(req, drafted[req], ids[s], now)
                 if self._slots[s] is not None \
                         and self._slots[s][0] is req:
                     # still decoding: positions 0..plen+produced-2 hold
@@ -2125,20 +2192,24 @@ class DecodingPredictor(object):
 
     def _record_emit(self, req, now, count=1, events=None):
         with self.stats._lock:
-            self.stats.tokens += count
-            # advance accounting (ISSUE 17): `events` defaults to
-            # `count` (greedy step / beam step / prefill first token
-            # all deliver count tokens over count per-row advances), so
-            # plain serving meters tokens_per_dispatch exactly 1.0; a
-            # verify tick passes events=1 for its multi-token advance
-            self.stats.adv_tokens += count
-            self.stats.adv_events += (count if events is None
-                                      else events)
-            if req.t_first is None:
-                req.t_first = now
-                self.stats._ttft.append(now - req.t_submit)
-            else:
-                self.stats._itl.append(now - req.t_last)
+            self._count_emit(req, now, count, events)
+
+    def _count_emit(self, req, now, count=1, events=None):
+        """Meter one delivery; the caller holds stats._lock."""
+        self.stats.tokens += count
+        # advance accounting (ISSUE 17): `events` defaults to
+        # `count` (greedy step / beam step / prefill first token
+        # all deliver count tokens over count per-row advances), so
+        # plain serving meters tokens_per_dispatch exactly 1.0; a
+        # verify tick passes events=1 for its multi-token advance
+        self.stats.adv_tokens += count
+        self.stats.adv_events += (count if events is None
+                                  else events)
+        if req.t_first is None:
+            req.t_first = now
+            self.stats._ttft.append(now - req.t_submit)
+        else:
+            self.stats._itl.append(now - req.t_last)
         req.t_last = now
 
     def _finish_greedy(self, req):
@@ -2172,21 +2243,22 @@ class DecodingPredictor(object):
             if drafted:
                 self._verify_slot(drafted)
             with _span('decode/build_feed'):
-                tokens, pos, active = self._step_feed_slot(drafted)
+                tokens, pos, active, beam = self._step_feed_slot(drafted)
             sp.set_metadata(active=active)
             if not active:
                 return   # every live stream drafted: no plain step
             with self.stats._lock:
                 self.stats.active_slot_steps += active
                 self.stats.slot_steps += self._S
-            logits = self._dispatch_step(tokens, pos)
+            ids, logits = self._dispatch_step(tokens, pos, logits=beam)
             with _span('decode/advance', rows=active):
-                self._advance_slot(logits, drafted)
+                self._advance_slot(ids, logits, drafted)
 
     def _step_feed_slot(self, drafted):
         tokens = np.zeros((self._S, 1), np.int64)
         pos = np.zeros((self._S, 1), np.int32)
         active = 0
+        beam = False    # a beam row live: the step's logits are wanted
         for s, entry in enumerate(self._slots):
             if entry is None:
                 continue
@@ -2195,19 +2267,21 @@ class DecodingPredictor(object):
                 pos[s, 0] = self._T - 1   # advanced via verify this tick
                 continue
             active += 1
+            beam = beam or req.beam is not None
             tokens[s, 0] = req.last_tokens[bi]
             # this token writes at position len(prompt) + produced - 1
             pos[s, 0] = req.prompt.size + req.produced - 1
-        return tokens, pos, active
+        return tokens, pos, active, beam
 
-    def _advance_slot(self, logits, drafted):
+    def _advance_slot(self, ids, logits, drafted):
         now = time.perf_counter()
         src = np.arange(self._S, dtype=np.int32)
-        for req in self._active_requests():
-            if req in drafted:
-                continue
+        reqs = [r for r in self._active_requests() if r not in drafted]
+        toks = ids.tolist()     # once a step, not once a row
+        self._meter_greedy(reqs, now)
+        for req in reqs:
             if req.beam is None:
-                self._advance_greedy(req, logits, now)
+                self._advance_greedy(req, toks[req.slots[0]])
                 continue
             # shared beam scoring; the history move is the slot
             # layout's own — a slot-row gather

@@ -401,19 +401,29 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
       step         {'program', 'feeds', 'samples', 'fetches'}: the
                    decode-step program. Feeds must be named exactly
                    'tokens' [max_slots, 1] int64 and 'pos'
-                   [max_slots, 1] int32; fetch 0 is the per-slot logits
-                   [max_slots, vocab].
+                   [max_slots, 1] int32; 'fetches' names ONE var, the
+                   per-slot float32 logits [max_slots, vocab].
       prefill      {bucket_len: {...}}: one prefill program per prompt-
                    length bucket. Feeds must be named 'prompt_ids'
                    [1, bucket] int64, 'prompt_len' [1, 1] int32, 'slot'
-                   [1, 1] int32; fetch 0 is the last-real-position
+                   [1, 1] int32; 'fetches' names the last-real-position
                    logits [1, vocab].
       cache_vars   persistable KV-cache state vars present in every
                    program ([max_slots, max_cache_len, ...]).
       max_slots / max_cache_len / eos_id / vocab.
 
     Every program is traced ONCE as fn(params, state, feeds) ->
-    (fetches, new_state). `params` is every persistable any program
+    (fetches, new_state). The exported program returns TWO fetches
+    (signature 'fetches': ['ids', <the logits var>]): fetch 0 is `ids`,
+    int32, the argmax of the logits over the vocabulary — [max_slots]
+    for the step, [max_slots, K+1] for verify, [1] for a prefill or a
+    chunk — and fetch 1 is the float32 logits the spec names, untouched.
+    The argmax is appended HERE, at the one place every program of every
+    model is traced (_export_decode_program), over the same float32
+    values and with np.argmax's tie rule (the lowest index), so the
+    scheduler copies max_slots x 4 bytes a step and chooses no token on
+    the host; it copies the logits only for a dispatch in which a beam
+    row is live. `params` is every persistable any program
     reads, in one sorted list that is the same for all of them,
     UNDONATED: no module holds a weight as a constant, the artifact
     holds one copy of the weights (decode_weights.bin: raw bytes, mapped
@@ -427,8 +437,9 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     per-program AOT warm-start sidecars, the model's programs compiled
     WITH state donation (the paged cache updates in place; the loader
     passes only XLA-owned buffers, the executor's round-10 ownership
-    discipline). The signature is version 4 for this convention; the
-    loader refuses an older artifact by name.
+    discipline). The signature is version 5: weights as arguments
+    (since 4) and `ids` as fetch 0 beside the logits (5); the loader
+    refuses an older artifact by name.
 
     Artifact layout (out_dir/):
       decode_signature.json   shapes, buckets, params and state specs,
@@ -558,10 +569,9 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     sigs = {}
     for d, e in entries.items():
         with _span('export/program', program=d, params=len(param_sig)):
-            sigs[d] = dict(_export_decode_program(
+            sigs[d] = _export_decode_program(
                 e, programs[d], param_args, param_specs, state_names,
-                state_specs, os.path.join(out_dir, d), shard=shard),
-                fetches=list(e['fetches']))
+                state_specs, os.path.join(out_dir, d), shard=shard)
     if layout == 'block':
         _export_decode_blockcopy(
             state_specs, int(spec['max_slots']),
@@ -577,8 +587,9 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                    for s in state_specs]
     # version 4: the weights are ARGUMENTS of every program (one
     # decode_weights.bin, listed under 'params'); up to version 3 each
-    # program's module held them as constants
-    sig = {'version': 4, 'kind': 'decode',
+    # program's module held them as constants. Version 5: every program
+    # returns the argmax ids as fetch 0 beside its logits
+    sig = {'version': _decoding._SIG_VERSION, 'kind': 'decode',
            'layout': layout,
            'max_slots': int(spec['max_slots']),
            'max_cache_len': int(spec['max_cache_len']),
@@ -753,7 +764,11 @@ def _export_decode_program(entry, program, param_args, param_specs,
     shardings (so the weights genuinely partition across the mesh), the
     KV state threads through mp-sharded input->output (fixed-point
     pinned), and feeds/fetches stay replicated (the host scheduler sees
-    full arrays). Returns the program's signature entries: its 'feeds',
+    full arrays). The program's fetches are `ids` — int32, the argmax
+    over the last axis of the logits (the spec's first fetch), lowest
+    index on ties — and then what the spec fetches: the one site where
+    greedy token selection enters every program of every model. Returns
+    the program's signature entries: its 'feeds', its 'fetches' by name,
     and under 'attention' the body each of its kv_*attention* ops holds
     where the module is compiled for a TPU, by op type and counted
     ({'kv_block_attention': {'kernel': 6}}) — 'kernel' is the paged
@@ -762,6 +777,7 @@ def _export_decode_program(entry, program, param_args, param_specs,
     every body on another platform, is the 'jnp' expression over the
     gathered view."""
     import jax
+    import jax.numpy as jnp
     from ..core.lowering import Tracer
 
     feed_names = list(entry['feeds'])
@@ -790,8 +806,10 @@ def _export_decode_program(entry, program, param_args, param_specs,
         tracer.env.update(dict(zip(feed_names, feed_list)))
         tracer.run_block(program.global_block())
         lowered[:] = tracer.lowered_bodies
-        return ([tracer.env[n] for n in fetch_names],
-                [tracer.env[n] for n in state_names])
+        fetched = [tracer.env[n] for n in fetch_names]
+        with jax.named_scope('greedy_ids'):
+            ids = jnp.argmax(fetched[0], axis=-1).astype(jnp.int32)
+        return ([ids] + fetched, [tracer.env[n] for n in state_names])
 
     lowered = []     # (op type, body) as the export's trace lowered them
     feed_specs = [jax.ShapeDtypeStruct(samples[n].shape, samples[n].dtype)
@@ -800,7 +818,7 @@ def _export_decode_program(entry, program, param_args, param_specs,
     if shard is not None:
         in_sh = (list(shard['param_ns']), list(shard['state_ns']),
                  [shard['rep']] * len(feed_names))
-        out_sh = ([shard['rep']] * len(fetch_names),
+        out_sh = ([shard['rep']] * (1 + len(fetch_names)),
                   list(shard['state_ns']))
     _export_serialize(fn, (param_specs, state_specs, feed_specs), out_dir,
                       shard=shard, in_shardings=in_sh, out_shardings=out_sh)
@@ -816,6 +834,7 @@ def _export_decode_program(entry, program, param_args, param_specs,
         by_body[body] = by_body.get(body, 0) + 1
     return {'feeds': [{'name': n, 'shape': list(samples[n].shape),
                        'dtype': samples[n].dtype.name} for n in feed_names],
+            'fetches': ['ids'] + fetch_names,
             'attention': attention}
 
 

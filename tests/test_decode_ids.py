@@ -1,0 +1,297 @@
+"""Greedy token selection inside the decode programs (signature version 5):
+every token-emitting program returns `ids`, the int32 argmax of its logits,
+as fetch 0 beside the float32 logits, and the scheduler copies the ids — the
+logits only for a dispatch in which a beam row is live.
+
+On any platform the ids equal np.argmax of the logits fetch of the SAME
+dispatch, bit for bit, lowest index on ties."""
+import json
+import os
+import shutil
+
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu.inference import DecodingPredictor, decoding, export_decode
+from paddle_tpu.testing.decode_logits import served_logits
+
+VOCAB, SLOTS, K = 97, 4, 3
+_OLMOE = dict(vocab=128, d_model=64, n_head=4, n_layer=2, n_expert=8,
+              d_expert=32, top_k=2, max_slots=SLOTS, max_cache_len=64,
+              block_size=8, chunk_sizes=(8, 16))
+# the output projection of each builder: [d_model, vocab]
+_HEAD = {'block': 'out_w', 'slot': 'out_w', 'olmoe': 'lm_head_w'}
+
+
+def _tie_head(w):
+    """A head under which EVERY logits row has two equal maxima away from
+    an all-equal row: columns 0 and 1 hold +v, columns 2 and 3 hold -v,
+    every other column zero. h.v > 0 ties columns {0, 1}, h.v < 0 ties
+    {2, 3} (both pairs sit in one vector lane of the matmul), h.v == 0
+    ties every column."""
+    v = np.asarray(w[:, 5], np.float32)
+    out = np.zeros(w.shape, np.float32)
+    out[:, 0] = out[:, 1] = v
+    out[:, 2] = out[:, 3] = -v
+    return out.astype(w.dtype)
+
+
+def _export(tmp, name, tie=False):
+    art = str(tmp / (name + ('_tie' if tie else '')))
+    scope = fluid.core.Scope()
+    with fluid.scope_guard(scope), fluid.unique_name.guard():
+        if name == 'olmoe':
+            from models.olmoe import build_decode_spec
+            spec = build_decode_spec(weights_dtype='float32',
+                                     kv_cache_dtype='float32', **_OLMOE)
+        else:
+            from models.transformer import build_decode_spec
+            spec = build_decode_spec(
+                vocab=VOCAB, d_model=32, n_head=4, n_layer=2, d_ff=64,
+                max_slots=SLOTS, max_cache_len=48, eos_id=1,
+                prompt_buckets=(8, 16), draft_k=K,
+                **({'block_size': 4} if name == 'block' else {}))
+        spec['startup'].random_seed = 11
+        fluid.Executor(fluid.CPUPlace()).run(spec['startup'], scope=scope)
+        if tie:
+            head = np.asarray(scope.get(_HEAD[name]))
+            scope.set(_HEAD[name], _tie_head(head))
+        export_decode(spec, art, scope=scope)
+    return art
+
+
+@pytest.fixture(scope='module')
+def arts(tmp_path_factory):
+    tmp = tmp_path_factory.mktemp('ids')
+    made = {}
+
+    def get(name, tie=False):
+        if (name, tie) not in made:
+            made[name, tie] = _export(tmp, name, tie)
+        return made[name, tie]
+    return get
+
+
+def _prompts(vocab):
+    rng = np.random.RandomState(5)
+    return [rng.randint(2, vocab, n) for n in (3, 7, 13, 16, 5, 9)]
+
+
+# -- (a) ids == argmax of the same dispatch's logits -------------------------
+
+def _dispatches(pred, program):
+    """[(ids, logits)] of a few dispatches of `program` made through the
+    predictor's own dispatch functions, logits asked for: three prompts
+    prefilled into slots 0..2, then the program under test."""
+    S, vocab = pred.max_slots, pred._vocab
+    prompts = _prompts(vocab)[:3]
+    block = pred.layout == 'block'
+    out = {'prefill': [], 'chunk': [], 'step': [], 'verify': []}
+    tables = None
+    if block:
+        maxb = pred._maxb
+        tables = np.full((S, maxb), pred._trash, np.int32)
+        for i in range(len(prompts)):
+            tables[i] = 1 + i * maxb + np.arange(maxb)
+    last = []
+    for i, prompt in enumerate(prompts):
+        if block:
+            start = 0
+            while start < len(prompt):
+                left = len(prompt) - start
+                size = next((c for c in pred._chunks if c >= left),
+                            pred._chunks[-1])
+                take = min(size, left)
+                ids = np.zeros((1, size), np.int64)
+                ids[0, :take] = prompt[start:start + take]
+                tok, row = pred._dispatch_chunk(
+                    size, ids, start, take, tables[i:i + 1], logits=True)
+                out['chunk'].append((np.asarray([tok], np.int32), row[None]))
+                start += take
+        else:
+            bucket = decoding.select_bucket(pred._buckets, len(prompt))
+            padded = np.zeros((1, bucket), np.int64)
+            padded[0, :len(prompt)] = prompt
+            tok, row = pred._dispatch_prefill(bucket, padded, len(prompt),
+                                              i, logits=True)
+            out['prefill'].append((np.asarray([tok], np.int32), row[None]))
+        last.append(tok)
+    kw = {'tables': tables} if block else {}
+    if program == 'verify':
+        R = K + 1
+        tok = np.zeros((S, R), np.int64)
+        pos = np.full((S, R), pred._maxb * pred._bs if block else pred._T,
+                      np.int32)
+        for i, prompt in enumerate(prompts):
+            tok[i] = [last[i], 7, 9, 11]
+            pos[i] = len(prompt) + np.arange(R)
+        out['verify'].append(pred._dispatch_verify(tok, pos, logits=True,
+                                                   **kw))
+    elif program == 'step':
+        for j in range(3):
+            tok = np.zeros((S, 1), np.int64)
+            pos = np.zeros((S, 1), np.int32)
+            for i, prompt in enumerate(prompts):
+                tok[i, 0] = last[i]
+                pos[i, 0] = len(prompt) + j
+            ids, logits = pred._dispatch_step(tok, pos, logits=True, **kw)
+            out['step'].append((ids, logits))
+            last = ids.tolist()
+    return out[program]
+
+
+@pytest.mark.parametrize('tie', [False, True], ids=['seeded', 'tied'])
+@pytest.mark.parametrize('name,program', [
+    ('block', 'step'), ('block', 'chunk'), ('block', 'verify'),
+    ('slot', 'step'), ('slot', 'prefill'), ('slot', 'verify'),
+    ('olmoe', 'step'), ('olmoe', 'chunk')])
+def test_ids_are_the_argmax_of_the_same_dispatch(arts, name, program, tie):
+    with DecodingPredictor(arts(name, tie)) as pred:
+        got = _dispatches(pred, program)
+        assert pred.stats.snapshot()['logits_fetches'] >= len(got)
+    assert got
+    for ids, logits in got:
+        assert ids.dtype == np.int32 and logits.dtype == np.float32
+        assert ids.shape == logits.shape[:-1]
+        assert np.array_equal(ids, np.argmax(logits, axis=-1))
+        if tie:
+            # every row holds its maximum at least twice, and the lowest
+            # index won
+            rows = logits.reshape(-1, logits.shape[-1])
+            assert ((rows == rows.max(-1, keepdims=True)).sum(-1) >= 2).all()
+            assert set(ids.reshape(-1).tolist()) <= {0, 2}
+
+
+@pytest.mark.parametrize('name', ['block', 'slot', 'olmoe'])
+def test_signature_names_both_fetches(arts, name):
+    with open(os.path.join(arts(name), decoding._DECODE_SIGNATURE)) as f:
+        sig = json.load(f)
+    assert sig['version'] == decoding._SIG_VERSION == 5
+    entries = [sig['step']] + list(sig.get('chunk', {}).values()) \
+        + list(sig.get('prefill', {}).values()) \
+        + ([sig['verify']] if 'verify' in sig else [])
+    assert len(entries) >= 3
+    for e in entries:
+        assert len(e['fetches']) == 2 and e['fetches'][0] == 'ids'
+
+
+# -- (b) what the scheduler copies -------------------------------------------
+
+class _Copies(object):
+    """Every (program, fetch, bytes) the scheduler's one copy site moved:
+    the stats of its decode/d2h spans, read where they are made."""
+
+    def __init__(self, monkeypatch):
+        self.seen = []
+        real = decoding._span
+
+        def span(name, **stats):
+            if name == 'decode/d2h':
+                self.seen.append((stats['program'], stats['fetch'],
+                                  stats['bytes']))
+            return real(name, **stats)
+        monkeypatch.setattr(decoding, '_span', span)
+
+
+@pytest.mark.parametrize('name', ['block', 'slot', 'olmoe'])
+def test_greedy_serving_copies_ids_only(arts, name, monkeypatch):
+    copies = _Copies(monkeypatch)
+    with DecodingPredictor(arts(name)) as pred:
+        streams = [pred.submit(p, max_new_tokens=6)
+                   for p in _prompts(pred._vocab)]
+        assert all(len(s.result(120)) >= 1 for s in streams)
+        snap = pred.stats.snapshot()
+    assert snap['logits_fetches'] == 0 and snap['steps'] > 0
+    assert {f for _, f, _ in copies.seen} == {'ids'}
+    assert {b for p, _, b in copies.seen if p == 'step'} == {SLOTS * 4}
+    assert {b for p, _, b in copies.seen if p != 'step'} == {4}
+
+
+# what the parent commit (PR 26: host argmax over the copied logits) served
+# for these requests on this spec
+_PARENT_GREEDY = [[80, 80, 80, 81, 54, 81, 54, 80],
+                  [88, 60, 83, 81, 88, 60, 81, 88]]
+_PARENT_BEAM_IDS = [[54, 81, 88, 60, 81, 81, 81, 81],
+                    [54, 81, 88, 60, 83, 81, 81, 81]]
+_PARENT_BEAM_SCORES = [-25.31855396037914, -25.322833602904396]
+_PARENT_SPEC = [[81, 88, 23, 54, 82, 65, 81, 81, 88, 54, 62, 88],
+                [81, 88, 54, 81, 81, 54, 81, 88, 54, 81, 88, 60]]
+
+
+@pytest.mark.parametrize('name', ['block', 'slot'])
+def test_a_live_beam_fetches_logits_and_serves_the_parents_beam(
+        arts, name, monkeypatch):
+    copies = _Copies(monkeypatch)
+    prompts = _prompts(VOCAB)
+    with DecodingPredictor(arts(name)) as pred:
+        g1 = pred.submit(prompts[0], max_new_tokens=8)
+        beam = pred.submit(prompts[2], max_new_tokens=8, beam=2)
+        g2 = pred.submit(prompts[1], max_new_tokens=8)
+        ids, scores = beam.result(120)
+        greedy = [[int(t) for t in s.result(120)] for s in (g1, g2)]
+        snap = pred.stats.snapshot()
+    assert np.asarray(ids).tolist() == _PARENT_BEAM_IDS
+    assert [float(x) for x in scores] == _PARENT_BEAM_SCORES
+    assert greedy == _PARENT_GREEDY
+    # the beam's last prompt slice and every step it rode copied logits
+    # (ids beside them); the greedy requests' prompts copied ids
+    logits = [(p, b) for p, f, b in copies.seen if f == 'logits']
+    assert snap['logits_fetches'] == len(logits) == 8
+    assert {b for p, b in logits if p == 'step'} == {SLOTS * (VOCAB + 1) * 4}
+    assert {b for p, b in logits if p != 'step'} == {(VOCAB + 1) * 4}
+    assert len(logits) < len(copies.seen)
+
+
+@pytest.mark.parametrize('name', ['block', 'slot'])
+def test_speculative_serving_reads_the_verify_ids(arts, name, monkeypatch):
+    copies = _Copies(monkeypatch)
+    prompts = _prompts(VOCAB)
+    with DecodingPredictor(arts(name), draft='ngram') as pred:
+        got = [[int(t) for t in
+                pred.submit(p, max_new_tokens=12).result(120)]
+               for p in (np.tile(prompts[1][:4], 4), prompts[3])]
+        snap = pred.stats.snapshot()
+    assert got == _PARENT_SPEC
+    assert snap['verify_steps'] > 0 and snap['logits_fetches'] == 0
+    assert {(f, b) for p, f, b in copies.seen if p == 'verify'} \
+        == {('ids', SLOTS * (K + 1) * 4)}
+
+
+# -- (c) an older artifact is refused by name --------------------------------
+
+def test_a_version_4_artifact_is_refused_by_name(arts, tmp_path):
+    art = str(tmp_path / 'v4')
+    shutil.copytree(arts('block'), art)
+    path = os.path.join(art, decoding._DECODE_SIGNATURE)
+    with open(path) as f:
+        sig = json.load(f)
+    sig['version'] = 4
+    with open(path, 'w') as f:
+        json.dump(sig, f)
+    with pytest.raises(ValueError, match='logits alone.*export_decode'):
+        DecodingPredictor(art)
+    with pytest.raises(ValueError, match='export it again'):
+        decoding.precompile_decode_artifact(art)
+
+
+# -- (d) the logits are still there for who asks -----------------------------
+
+@pytest.mark.parametrize('name', ['block', 'olmoe'])
+def test_served_logits_still_returns_rows(arts, name):
+    n_new = 5
+    with DecodingPredictor(arts(name)) as pred:
+        vocab = pred._vocab
+        prompts = _prompts(vocab)[:3]
+        tokens, logits = served_logits(pred, prompts, n_new)
+        assert pred.stats.snapshot()['logits_fetches'] == 0    # reset after
+        served = [[int(t) for t in
+                   pred.submit(p, max_new_tokens=n_new).result(120)]
+                  for p in prompts]
+    for toks, rows in zip(tokens, logits):
+        assert rows.shape == (n_new, vocab) and rows.dtype == np.float32
+        assert toks == np.argmax(rows, -1).tolist()
+    # the scheduler, reading ids, serves the tokens those rows choose
+    # (until eos ends a stream early)
+    for toks, got in zip(tokens, served):
+        assert got == toks[:len(got)]

@@ -289,7 +289,7 @@ def test_artifact_holds_one_copy_of_the_weights(olmoe_art):
     art, weights = olmoe_art
     with open(os.path.join(art, decoding._DECODE_SIGNATURE)) as f:
         sig = json.load(f)
-    assert sig['version'] == decoding._SIG_VERSION == 4
+    assert sig['version'] == decoding._SIG_VERSION == 5
     assert sorted(e['name'] for e in sig['params']) == sorted(weights)
     # the 9 float32 norm vectors ride in one argument, each matrix alone
     packs = [a for a in sig['param_args'] if len(a) > 1]
@@ -432,7 +432,7 @@ def test_transformer_transcripts_are_the_parents(tmp_path, name):
     assert got == _PARENT[name]
     with open(os.path.join(art, decoding._DECODE_SIGNATURE)) as f:
         sig = json.load(f)
-    assert sig['version'] == 4 and sig['params']
+    assert sig['version'] == 5 and sig['params']
     with open(os.path.join(art, decoding._STEP_DIR, serve._MODULE),
               'rb') as f:
         assert len(f.read()) < sig['weight_bytes']

@@ -183,11 +183,18 @@ def test_one_request_stat_joins_a_requests_spans(decode_trace):
     assert {s[4]['request'] for s in tagged} == {seqs[1]}
 
 
-def test_step_d2h_bytes_is_slots_x_vocab_x_4(decode_trace):
+def test_step_d2h_bytes_is_the_ids(decode_trace):
+    """A greedy step copies its [max_slots] int32 ids and nothing else;
+    every d2h span says which fetch it moved."""
     step = [s for s in decode_trace.named('decode/d2h')
             if s[4]['program'] == 'step']
     assert step
-    assert {s[4]['bytes'] for s in step} == {SLOTS * VOCAB * 4}
+    assert {s[4]['bytes'] for s in step} == {SLOTS * 4}
+    assert {s[4]['fetch'] for s in decode_trace.named('decode/d2h')} \
+        == {'ids'}
+    # a prefill slice's copy is its one id
+    assert {s[4]['bytes'] for s in decode_trace.named('decode/d2h')
+            if s[4]['program'].startswith('chunk_')} == {4}
     programs = {s[4]['program'] for s in decode_trace.named('decode/dispatch')}
     assert {'step', 'chunk_4', 'chunk_8', 'zeros'} <= programs
     ticks = [s[4]['tick'] for s in decode_trace.named('decode/tick')]
