@@ -761,9 +761,9 @@ def bench_decode_serving():
 
     Env knobs (PTPU_BENCH_DECODE_*): REQS, MAX_NEW, SLOTS, RATE_X
     (offered load as a multiple of sequential capacity), DMODEL, LAYERS,
-    BLOCK (ISSUE 13: block-paged layout with this block_size — chunked
-    prefill + prefix sharing; 0/unset = slot layout; the metric line
-    then carries the block-cache gauges).
+    BLOCK (the block-paged pool's block_size, default 16 — chunked
+    prefill + prefix sharing; the metric line carries the block-cache
+    gauges).
     """
     import tempfile
     import paddle_tpu as fluid
@@ -776,7 +776,7 @@ def bench_decode_serving():
     rate_x = float(os.environ.get('PTPU_BENCH_DECODE_RATE_X', '8'))
     d_model = int(os.environ.get('PTPU_BENCH_DECODE_DMODEL', '64'))
     n_layer = int(os.environ.get('PTPU_BENCH_DECODE_LAYERS', '2'))
-    block = int(os.environ.get('PTPU_BENCH_DECODE_BLOCK', '0'))
+    block = int(os.environ.get('PTPU_BENCH_DECODE_BLOCK', '16'))
     vocab, buckets, cache = 512, (8, 16), 64
 
     scope = fluid.core.Scope()
@@ -785,8 +785,8 @@ def bench_decode_serving():
         spec = build_decode_spec(vocab=vocab, d_model=d_model, n_head=4,
                                  n_layer=n_layer, d_ff=4 * d_model,
                                  max_slots=slots, max_cache_len=cache,
-                                 prompt_buckets=buckets, eos_id=1,
-                                 block_size=block or None)
+                                 chunk_sizes=buckets, eos_id=1,
+                                 block_size=block)
         exe, _ = _device()
         exe.run(spec['startup'], scope=scope)
         export_decode(spec, art, scope=scope)
@@ -965,7 +965,8 @@ def bench_decode_serving_int8():
             spec = build_decode_spec(
                 vocab=vocab, d_model=d_model, n_head=4, n_layer=n_layer,
                 d_ff=4 * d_model, max_slots=s, max_cache_len=cache,
-                prompt_buckets=buckets, eos_id=1, kv_cache_dtype=kv)
+                chunk_sizes=buckets, block_size=16, eos_id=1,
+                kv_cache_dtype=kv)
             exe, _ = _device()
             exe.run(spec['startup'], scope=scope)
         return spec, scope
@@ -1374,7 +1375,8 @@ def bench_fleet_serving():
         spec = build_decode_spec(vocab=211, d_model=48, n_head=4,
                                  n_layer=2, d_ff=96, max_slots=4,
                                  max_cache_len=max_new + 10,
-                                 prompt_buckets=(4, 8), eos_id=1)
+                                 chunk_sizes=(4, 8), block_size=16,
+                                 eos_id=1)
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(spec['startup'])
         export_decode(spec, art, scope=scope)

@@ -36,10 +36,10 @@ def build_decode_spec(vocab=128, d_model=64, n_head=4, n_layer=2,
                       rope_theta=10000.0, norm_topk_prob=False,
                       init_std=0.02):
     """The block-paged decode program set of an OLMoE model (defaults: a
-    toy for the cpu tests). The spec has models/transformer.py's block
-    layout keys: 'startup', 'step', 'chunk' {size: entry}, 'cache_vars',
-    'layout' 'block', 'block_size', 'num_blocks' (default: full capacity
-    plus the trash block), 'max_blocks_per_slot', 'max_slots',
+    toy for the cpu tests). The spec has models/transformer.py's keys:
+    'startup', 'step', 'chunk' {size: entry}, 'cache_vars',
+    'block_size', 'num_blocks' (default: full capacity plus the trash
+    block), 'max_blocks_per_slot', 'max_slots',
     'max_cache_len', 'eos_id', 'vocab', 'kv_cache_dtype'.
 
     Weights draw from N(0, init_std) (the family's initializer_range);
@@ -192,7 +192,6 @@ def build_decode_spec(vocab=128, d_model=64, n_head=4, n_layer=2,
             'fetches': [chunk_logits.name]}
 
     return {'startup': startup,
-            'layout': 'block',
             'block_size': BS, 'num_blocks': NB,
             'max_blocks_per_slot': MAXB,
             'step': {'program': step_p,

@@ -198,20 +198,20 @@ def build_transformer_train(src_vocab=32000, trg_vocab=32000, max_len=256,
 
 # ---------------------------------------------------------------------------
 # Continuous-decode serving programs (ISSUE 8): a decoder-only LM expressed
-# as the TWO fixed-shape programs the decode-serving tier compiles once and
-# reuses forever — a PREFILL program per prompt-length bucket (one request,
-# causal self-attention over the bucket, K/V rows written into one slot of
-# the paged cache) and a DECODE-STEP program (max_slots requests, one token
-# per slot per step, cache-aware attention via ops/decode_ops.py). All
+# as the fixed-shape programs the decode-serving tier compiles once and
+# reuses forever — a chunked-PREFILL program per chunk size (one slice of
+# one request's prompt, K/V rows written through its block table into the
+# paged pool) and a DECODE-STEP program (max_slots requests, one token per
+# slot per step), both attending the cache through ops/decode_ops.py. All
 # parameters are shared by name across every program, the reference's
 # train-program/infer-program pattern (tests/test_book.py NMT).
 # ---------------------------------------------------------------------------
 
 def _pe_table(max_len, d_model):
     """Sinusoid position-encoding table [max_len, d_model] precomputed in
-    float32 host numpy: prefill (full-prompt slice) and decode step
-    (per-position gather) read the SAME table, so positional values agree
-    bit-for-bit across the two programs."""
+    float32 host numpy: chunked prefill and decode step gather from the
+    SAME table by position, so positional values agree bit-for-bit
+    across the programs."""
     import numpy as np
     half = d_model // 2
     pos = np.arange(max_len, dtype=np.float32)[:, None]
@@ -222,67 +222,63 @@ def _pe_table(max_len, d_model):
 
 
 def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
-                      max_slots=8, max_cache_len=48, prompt_buckets=(8, 16),
-                      eos_id=1, kv_cache_dtype='float32', block_size=None,
-                      num_blocks=None, chunk_sizes=None, mp_shard=0,
-                      draft_k=0):
+                      max_slots=8, max_cache_len=48, chunk_sizes=(8, 16),
+                      eos_id=1, kv_cache_dtype='float32', block_size=16,
+                      num_blocks=None, mp_shard=0, draft_k=0):
     """Build the decode-serving program set for a decoder-only transformer
     LM. Returns the spec dict `inference.export_decode` consumes:
 
       {'startup': Program,           # run ONCE to init shared params
        'step':    {'program', 'feeds', 'samples', 'fetches'},
-       'prefill': {bucket_len: {'program', 'feeds', 'samples', 'fetches'}},
-       'cache_vars': [names],        # paged KV state [S, T, d_model]
+       'chunk':   {chunk_size: {'program', 'feeds', 'samples', 'fetches'}},
+       'cache_vars': [names],        # the KV pool [NB, block_size, d_model]
+       'block_size', 'num_blocks', 'max_blocks_per_slot',
        'max_slots', 'max_cache_len', 'eos_id', 'vocab'}
 
     The KV cache is per-layer persistable state shared by name between the
-    programs; export_decode threads it as donated input->output state
-    while baking every other parameter as constants.
+    programs: a BLOCK POOL [num_blocks, block_size, D] addressed through
+    per-slot block tables the serving tier feeds each dispatch
+    (inference/kv_blocks.py owns refcounts/CoW/prefix sharing);
+    export_decode threads it as donated input->output state and passes
+    every other parameter as an argument. Prefill is CHUNKED: one chunk
+    program per size in `chunk_sizes` admits a prompt in fixed slices
+    interleaved with decode steps (attending earlier chunks / shared
+    prefix blocks through the table). num_blocks defaults to full
+    capacity (max_slots * ceil(max_cache_len / block_size) + 1 trash
+    block); size it SMALLER to oversubscribe on prefix sharing.
 
-    kv_cache_dtype='bfloat16': the float cache holds bfloat16 rows (K and
+    kv_cache_dtype='bfloat16': the float pool holds bfloat16 rows (K and
     V round once, at the write; attention reads them as they are) —
     half the bytes of the float32 pool, and what the TPU's paged kernel
     reads with block_size % 16 == 0.
 
-    kv_cache_dtype='int8' (ISSUE 11): the paged cache stores int8 rows
-    with one f32 scale per slot-page (kv_ks_<i>/kv_vs_<i> [S, T] ride
-    the cache_vars state next to the int8 [S, T, D] pages) and the
-    programs use the quantized write/prefill/attention kernels
+    kv_cache_dtype='int8' (ISSUE 11): the pool stores int8 rows with one
+    f32 scale per cache position (kv_ks_<i>/kv_vs_<i> [num_blocks,
+    block_size] ride the cache_vars state next to the int8 pages) and
+    the programs use the quantized write/attention ops
     (ops/decode_ops.py) — ~(1+4/D)/2 the cache bytes of the f32 form,
     so the same cache-HBM budget holds ~2x the slots.
 
-    block_size=N (ISSUE 13): BLOCK-PAGED layout. The cache becomes a
-    pool [num_blocks, block_size, D] addressed through per-slot block
-    tables the serving tier feeds each dispatch (inference/kv_blocks.py
-    owns refcounts/CoW/prefix sharing), and prefill becomes CHUNKED:
-    one chunk program per size in `chunk_sizes` (default: the
-    prompt_buckets) admits a prompt in fixed slices interleaved with
-    decode steps. num_blocks defaults to full capacity
-    (max_slots * ceil(max_cache_len / block_size) + 1 trash block);
-    size it SMALLER to oversubscribe on prefix sharing. Composes with
-    kv_cache_dtype='int8' (int8 block pages + [num_blocks, block_size]
-    page scales).
-
-    mp_shard=k (ISSUE 13, block layout only): annotate every weight
-    (and the D axis of the KV block pool) for k-way tensor-model
-    sharding over the 'mp' mesh axis (parallel/api.shard_parameter) and
-    insert sharding_hint replicate points at contraction boundaries so
-    every reduction stays full-width — export_decode traces the
-    programs over the mesh and the sharded artifact's transcripts are
-    BIT-IDENTICAL to the single-chip one. Requires k | n_head, k | d_ff.
+    mp_shard=k (ISSUE 13): annotate every weight (and the D axis of the
+    KV block pool) for k-way tensor-model sharding over the 'mp' mesh
+    axis (parallel/api.shard_parameter) and insert sharding_hint
+    replicate points at contraction boundaries so every reduction stays
+    full-width — export_decode traces the programs over the mesh and
+    the sharded artifact's transcripts are BIT-IDENTICAL to the
+    single-chip one. Requires k | n_head, k | d_ff.
 
     draft_k=K (ISSUE 17): add a third, VERIFY program for speculative
     decoding — [S, K+1] token/position rows score in ONE dispatch over
     the same paged cache (KV written speculatively for every fed row,
     row i attending j <= pos[s, i], so row i's logits match the plain
     step's at the same accepted prefix). The verify program is built
-    LAST and shares every weight by name, so the step/prefill programs
+    LAST and shares every weight by name, so the step/chunk programs
     (and the weights the per-op rng streams draw) are byte-for-byte
-    what a draft_k=0 build produces. Works in all four tier
-    combinations (slot/block x fp/int8). The serving tier drafts
-    host-side and rolls rejected rows back (inference/decoding.py).
+    what a draft_k=0 build produces. The serving tier drafts host-side
+    and rolls rejected rows back (inference/decoding.py).
     """
     import numpy as np
+    from paddle_tpu.parallel import shard_parameter
     PA = fluid.ParamAttr
     if kv_cache_dtype not in ('float32', 'bfloat16', 'int8'):
         raise ValueError("kv_cache_dtype must be 'float32', 'bfloat16' "
@@ -290,286 +286,6 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
     if not 0 <= int(draft_k) <= int(max_cache_len) - 2:
         raise ValueError('draft_k must be in [0, max_cache_len - 2], '
                          'got %r' % (draft_k,))
-    if block_size is not None:
-        return _build_block_decode_spec(
-            vocab=vocab, d_model=d_model, n_head=n_head, n_layer=n_layer,
-            d_ff=d_ff, max_slots=max_slots, max_cache_len=max_cache_len,
-            chunk_sizes=tuple(chunk_sizes or prompt_buckets),
-            eos_id=eos_id, kv_cache_dtype=kv_cache_dtype,
-            block_size=int(block_size), num_blocks=num_blocks,
-            mp_shard=int(mp_shard or 0), draft_k=int(draft_k))
-    if mp_shard:
-        raise ValueError(
-            'mp_shard requires the block-paged layout — pass '
-            'block_size= as well (the sharded decode tier addresses '
-            'the cache through block tables)')
-    kv_int8 = kv_cache_dtype == 'int8'
-    S, T, D = int(max_slots), int(max_cache_len), int(d_model)
-    if D % n_head or D % 2:
-        raise ValueError("d_model must be even and divisible by n_head")
-    buckets = sorted({int(b) for b in prompt_buckets})
-    if not buckets or buckets[0] < 1 or buckets[-1] > T:
-        raise ValueError("prompt_buckets must be in [1, max_cache_len]")
-    dh = D // n_head
-    startup = fluid.Program()
-    pe = _pe_table(T, D)
-    cache_vars = []
-    for i in range(n_layer):
-        cache_vars += ['kv_k_%d' % i, 'kv_v_%d' % i]
-        if kv_int8:
-            cache_vars += ['kv_ks_%d' % i, 'kv_vs_%d' % i]
-
-    def const_param(name, shape, init, dtype='float32'):
-        return fluid.layers.create_parameter(
-            shape, dtype, attr=PA(name=name, trainable=False),
-            default_initializer=init)
-
-    def caches(i):
-        zero = fluid.initializer.ConstantInitializer(0.0)
-        dt = 'int8' if kv_int8 else kv_cache_dtype
-        k = const_param('kv_k_%d' % i, [S, T, D], zero, dt)
-        v = const_param('kv_v_%d' % i, [S, T, D], zero, dt)
-        if not kv_int8:
-            return k, v
-        # per-slot-page dequant scales; 1.0 keeps never-written pages
-        # dequantizing to exact zero rows without a 0-divide
-        one = fluid.initializer.ConstantInitializer(1.0)
-        return (k, v, const_param('kv_ks_%d' % i, [S, T], one),
-                const_param('kv_vs_%d' % i, [S, T], one))
-
-    def pe_param():
-        return const_param(
-            'pos_enc_w', [T, D], fluid.initializer.NumpyArrayInitializer(pe))
-
-    def qkv(x, i, nfd):
-        def proj(tag):
-            return fluid.layers.fc(
-                x, D, num_flatten_dims=nfd,
-                param_attr=PA(name='l%d_%s_w' % (i, tag)), bias_attr=False)
-        return proj('q'), proj('k'), proj('v')
-
-    def block_tail(x, a, i, nfd):
-        """Shared residual+LN+FFN tail; `nfd` = 1 (step, [S, D]) or 2
-        (prefill, [1, L, D]) — same [D]-shaped params either way."""
-        x = fluid.layers.layer_norm(
-            x + fluid.layers.fc(a, D, num_flatten_dims=nfd,
-                                param_attr=PA(name='l%d_o_w' % i),
-                                bias_attr=False),
-            begin_norm_axis=nfd, param_attr=PA(name='l%d_ln1_s' % i),
-            bias_attr=PA(name='l%d_ln1_b' % i))
-        h = fluid.layers.fc(x, d_ff, num_flatten_dims=nfd, act='relu',
-                            param_attr=PA(name='l%d_f1_w' % i),
-                            bias_attr=PA(name='l%d_f1_b' % i))
-        f = fluid.layers.fc(h, D, num_flatten_dims=nfd,
-                            param_attr=PA(name='l%d_f2_w' % i),
-                            bias_attr=PA(name='l%d_f2_b' % i))
-        return fluid.layers.layer_norm(
-            x + f, begin_norm_axis=nfd, param_attr=PA(name='l%d_ln2_s' % i),
-            bias_attr=PA(name='l%d_ln2_b' % i))
-
-    def embed(ids):
-        x = fluid.layers.embedding(ids, size=[vocab, D],
-                                   param_attr=PA(name='dec_emb_w'))
-        return fluid.layers.scale(x, scale=float(D ** 0.5))
-
-    def out_logits(x, nfd=1):
-        return fluid.layers.fc(x, vocab, num_flatten_dims=nfd,
-                               param_attr=PA(name='out_w'), bias_attr=False)
-
-    # ---- decode-step program: [S] slots advance one token ----------------
-    # shapes are fully static (append_batch_size=False): the decode tier
-    # compiles ONE shape per program and reuses it forever
-    step_p = fluid.Program()
-    with fluid.program_guard(step_p, startup):
-        tokens = fluid.layers.data(name='tokens', shape=[S, 1],
-                                   append_batch_size=False, dtype='int64')
-        pos = fluid.layers.data(name='pos', shape=[S, 1],
-                                append_batch_size=False, dtype='int32')
-        table = pe_param()
-        x = embed(tokens)                                       # [S, D]
-        x = fluid.layers.elementwise_add(x,
-                                         fluid.layers.gather(table, pos))
-        for i in range(n_layer):
-            # cache params FIRST, then qkv — the op-creation order seeds
-            # the per-op rng streams, and the fp path must draw the same
-            # weights it always did (bit-compat with pre-int8 artifacts)
-            if kv_int8:
-                kcache, vcache, kscale, vscale = caches(i)
-                q, k, v = qkv(x, i, 1)
-                kcache, kscale = fluid.layers.kv_cache_write_quant(
-                    kcache, kscale, k, pos)
-                vcache, vscale = fluid.layers.kv_cache_write_quant(
-                    vcache, vscale, v, pos)
-                a = fluid.layers.kv_cache_attention_quant(
-                    q, kcache, kscale, vcache, vscale, pos, n_head)
-            else:
-                kcache, vcache = caches(i)
-                q, k, v = qkv(x, i, 1)
-                kcache = fluid.layers.kv_cache_write(kcache, k, pos)
-                vcache = fluid.layers.kv_cache_write(vcache, v, pos)
-                a = fluid.layers.kv_cache_attention(q, kcache, vcache,
-                                                    pos, n_head)
-            x = block_tail(x, a, i, 1)
-        step_logits = out_logits(x)                             # [S, V]
-
-    # ---- prefill programs: one request, bucketed by prompt length --------
-    prefills = {}
-    for L in buckets:
-        pp = fluid.Program()
-        with fluid.program_guard(pp, startup):
-            prompt = fluid.layers.data(name='prompt_ids', shape=[1, L],
-                                       append_batch_size=False,
-                                       dtype='int64')
-            plen = fluid.layers.data(name='prompt_len', shape=[1, 1],
-                                     append_batch_size=False, dtype='int32')
-            slot = fluid.layers.data(name='slot', shape=[1, 1],
-                                     append_batch_size=False, dtype='int32')
-            table = pe_param()
-            x = embed(prompt)                                   # [1, L, D]
-            pe_l = fluid.layers.slice(table, axes=[0], starts=[0],
-                                      ends=[L])
-            x = fluid.layers.elementwise_add(
-                x, fluid.layers.reshape(pe_l, shape=[1, L, D]))
-            pidx = fluid.layers.range(0, L, 1, 'int32')
-            above = fluid.layers.cast(fluid.layers.greater_than(
-                fluid.layers.reshape(pidx, shape=[1, L]),
-                fluid.layers.reshape(pidx, shape=[L, 1])), 'float32')
-            mask = above * -1e9                                 # [L, L]
-
-            def heads(z):
-                return fluid.layers.transpose(
-                    fluid.layers.reshape(z, shape=[1, L, n_head, dh]),
-                    perm=[0, 2, 1, 3])
-            for i in range(n_layer):
-                if kv_int8:
-                    kcache, vcache, kscale, vscale = caches(i)
-                    q, k, v = qkv(x, i, 2)
-                    kcache, kscale = \
-                        fluid.layers.kv_cache_prefill_write_quant(
-                            kcache, kscale, k, slot)
-                    vcache, vscale = \
-                        fluid.layers.kv_cache_prefill_write_quant(
-                            vcache, vscale, v, slot)
-                else:
-                    kcache, vcache = caches(i)
-                    q, k, v = qkv(x, i, 2)
-                    kcache = fluid.layers.kv_cache_prefill_write(
-                        kcache, k, slot)
-                    vcache = fluid.layers.kv_cache_prefill_write(
-                        vcache, v, slot)
-                scores = fluid.layers.matmul(heads(q), heads(k),
-                                             transpose_y=True,
-                                             alpha=dh ** -0.5)
-                w = fluid.layers.softmax(scores + mask)
-                ctxv = fluid.layers.matmul(w, heads(v))
-                a = fluid.layers.reshape(
-                    fluid.layers.transpose(ctxv, perm=[0, 2, 1, 3]),
-                    shape=[1, L, D])
-                x = block_tail(x, a, i, 2)
-            # logits at the LAST REAL prompt position (padded rows beyond
-            # prompt_len feed garbage the decode step overwrites before
-            # ever attending it)
-            flat = fluid.layers.reshape(x, shape=[L, D])
-            last = fluid.layers.gather(
-                flat, fluid.layers.elementwise_sub(
-                    plen, fluid.layers.fill_constant([1], 'int32', 1)))
-            pre_logits = out_logits(last)                       # [1, V]
-        prefills[L] = {
-            'program': pp,
-            'feeds': ['prompt_ids', 'prompt_len', 'slot'],
-            'samples': {'prompt_ids': np.zeros((1, L), np.int64),
-                        'prompt_len': np.ones((1, 1), np.int32),
-                        'slot': np.zeros((1, 1), np.int32)},
-            'fetches': [pre_logits.name]}
-
-    # ---- verify program (ISSUE 17, built LAST so the op-creation rng
-    # order of step/prefill — and thus the weights — is untouched):
-    # [S, R] rows (R = draft_k + 1) score in one dispatch; pad rows
-    # carry pos = T (out-of-bounds scatter writes drop) -----------------
-    verify = None
-    if draft_k:
-        R = int(draft_k) + 1
-        vp = fluid.Program()
-        with fluid.program_guard(vp, startup):
-            vtok = fluid.layers.data(name='tokens', shape=[S, R],
-                                     append_batch_size=False,
-                                     dtype='int64')
-            vpos = fluid.layers.data(name='pos', shape=[S, R],
-                                     append_batch_size=False,
-                                     dtype='int32')
-            table = pe_param()
-            x = embed(vtok)                                 # [S, R, D]
-            # pad rows carry pos = T, past the PE table: clamp the
-            # GATHER index only (write positions keep the pad encoding
-            # — the OOB scatter is what drops them). An unclamped OOB
-            # gather is NaN-filled under jnp.take, and a NaN row would
-            # poison the whole batch through 0 * NaN in masked
-            # attention if it ever reached the cache
-            pe_idx = fluid.layers.clip(vpos, 0, T - 1)
-            pe_r = fluid.layers.gather(table, pe_idx)       # [S*R, D]
-            x = fluid.layers.elementwise_add(
-                x, fluid.layers.reshape(pe_r, shape=[S, R, D]))
-            for i in range(n_layer):
-                if kv_int8:
-                    kcache, vcache, kscale, vscale = caches(i)
-                    q, k, v = qkv(x, i, 2)
-                    kcache, kscale = \
-                        fluid.layers.kv_cache_verify_write_quant(
-                            kcache, kscale, k, vpos)
-                    vcache, vscale = \
-                        fluid.layers.kv_cache_verify_write_quant(
-                            vcache, vscale, v, vpos)
-                    a = fluid.layers.kv_cache_verify_attention_quant(
-                        q, kcache, kscale, vcache, vscale, vpos, n_head)
-                else:
-                    kcache, vcache = caches(i)
-                    q, k, v = qkv(x, i, 2)
-                    kcache = fluid.layers.kv_cache_verify_write(
-                        kcache, k, vpos)
-                    vcache = fluid.layers.kv_cache_verify_write(
-                        vcache, v, vpos)
-                    a = fluid.layers.kv_cache_verify_attention(
-                        q, kcache, vcache, vpos, n_head)
-                x = block_tail(x, a, i, 2)
-            verify_logits = out_logits(x, nfd=2)            # [S, R, V]
-        verify = {'program': vp,
-                  'feeds': ['tokens', 'pos'],
-                  'samples': {'tokens': np.zeros((S, R), np.int64),
-                              'pos': np.full((S, R), T, np.int32)},
-                  'fetches': [verify_logits.name]}
-
-    spec = {'startup': startup,
-            'step': {'program': step_p,
-                     'feeds': ['tokens', 'pos'],
-                     'samples': {'tokens': np.zeros((S, 1), np.int64),
-                                 'pos': np.zeros((S, 1), np.int32)},
-                     'fetches': [step_logits.name]},
-            'prefill': prefills,
-            'cache_vars': list(cache_vars),
-            'max_slots': S, 'max_cache_len': T,
-            'eos_id': int(eos_id), 'vocab': int(vocab),
-            'kv_cache_dtype': kv_cache_dtype}
-    if verify is not None:
-        spec['verify'] = verify
-        spec['draft_k'] = int(draft_k)
-    return spec
-
-
-def _build_block_decode_spec(vocab, d_model, n_head, n_layer, d_ff,
-                             max_slots, max_cache_len, chunk_sizes,
-                             eos_id, kv_cache_dtype, block_size,
-                             num_blocks, mp_shard, draft_k=0):
-    """Block-paged decode spec (ISSUE 13; see build_decode_spec): the
-    KV cache is a pool [num_blocks, block_size, D] addressed through
-    block tables fed at dispatch time, prefill is CHUNKED (one program
-    per chunk size, attending earlier chunks / shared prefix blocks
-    through the table), and with mp_shard=k every weight + the cache's
-    D axis annotate for k-way 'mp' tensor sharding with replicate
-    hints at contraction boundaries (bit-identity with the single-chip
-    trace — ops/decode_ops.py sharding_hint)."""
-    import numpy as np
-    from paddle_tpu.parallel import shard_parameter
-    PA = fluid.ParamAttr
     kv_int8 = kv_cache_dtype == 'int8'
     S, T, D = int(max_slots), int(max_cache_len), int(d_model)
     BS = int(block_size)
@@ -656,10 +372,11 @@ def _build_block_decode_spec(vocab, d_model, n_head, n_layer, d_ff,
         return q, k, v
 
     def block_tail(x, a, i, nfd):
-        """Residual+LN+FFN tail (the slot-paged builder's, plus the mp
-        replicate hints: attention context gathers before the o
-        projection, h before f2, and each projection output before its
-        LN — every contraction stays full-width)."""
+        """Shared residual+LN+FFN tail; `nfd` = 1 (step, [S, D]) or 2
+        (chunk / verify, [1, C, D] / [S, R, D]) — same [D]-shaped params
+        either way. The mp replicate hints: attention context gathers
+        before the o projection, h before f2, and each projection output
+        before its LN — every contraction stays full-width."""
         a = _hint(a)
         o = fluid.layers.fc(a, D, num_flatten_dims=nfd,
                             param_attr=PA(name='l%d_o_w' % i),
@@ -814,10 +531,12 @@ def _build_block_decode_spec(vocab, d_model, n_head, n_layer, d_ff,
                         'block_table': np.zeros((1, MAXB), np.int32)},
             'fetches': [chunk_logits.name]}
 
-    # ---- verify program (ISSUE 17, built LAST — see the slot builder;
-    # pad rows carry pos = MAXB * BS, the span guard's trash route, so
-    # a pad row can never land in a SHARED full prefix block the way
-    # pos = T could when T is not block-aligned) -----------------------
+    # ---- verify program (ISSUE 17, built LAST so the op-creation rng
+    # order of step/chunk — and thus the weights — is untouched):
+    # [S, R] rows (R = draft_k + 1) score in one dispatch; pad rows
+    # carry pos = MAXB * BS, the span guard's trash route, so a pad row
+    # can never land in a SHARED full prefix block the way pos = T
+    # could when T is not block-aligned --------------------------------
     verify = None
     if draft_k:
         R = int(draft_k) + 1
@@ -879,7 +598,6 @@ def _build_block_decode_spec(vocab, d_model, n_head, n_layer, d_ff,
                   'fetches': [verify_logits.name]}
 
     spec = {'startup': startup,
-            'layout': 'block',
             'block_size': BS, 'num_blocks': NB,
             'max_blocks_per_slot': MAXB,
             'step': {'program': step_p,

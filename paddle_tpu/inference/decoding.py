@@ -4,16 +4,20 @@
 streaming endpoint, the stateful sibling of `BatchingPredictor`'s
 stateless request coalescing — the technique behind modern high-
 throughput LLM servers (Orca-style iteration-level scheduling over a
-vLLM-style preallocated, slot-paged KV cache):
+vLLM-style preallocated, block-paged KV cache):
 
-1. **Two compiled programs, fixed shapes forever** — a PREFILL program
-   per prompt-length bucket (one request: writes the prompt's K/V rows
-   into one cache slot and returns first-token logits) and ONE
-   DECODE-STEP program ([max_slots] requests advance one token each).
+1. **A few compiled programs, fixed shapes forever** — a chunked-PREFILL
+   program per chunk size (one slice of one request's prompt: writes
+   its K/V rows through the request's block table and returns the
+   logits of its last position) and ONE DECODE-STEP program
+   ([max_slots] requests advance one token each). The cache is a pool
+   of fixed-size blocks addressed through per-slot block tables the
+   scheduler feeds each dispatch (kv_blocks.py owns refcounts,
+   copy-on-write and prefix sharing).
    Every token-emitting program returns TWO fetches (signature version
    5): fetch 0 `ids`, int32, the argmax of its logits over the
    vocabulary (lowest index on ties, np.argmax's rule) — [max_slots],
-   [max_slots, K+1] for verify, [1] for a prefill or chunk — and fetch
+   [max_slots, K+1] for verify, [1] for a chunk — and fetch
    1 the float32 logits. The scheduler copies the ids; the logits stay
    on the device unless a beam row is live in that dispatch.
    Idle slots are masked by each slot's own attention window, so a
@@ -21,8 +25,9 @@ vLLM-style preallocated, slot-paged KV cache):
    in steady state, and zero compiles at all in a warm fresh process
    (AOT sidecars per program, `tools/cache_ctl.py prewarm`).
 2. **Iteration-level scheduling** — new requests join the running batch
-   at step boundaries (one prefill dispatch, then their slot decodes
-   with everyone else); finished sequences (eos / max_new_tokens) free
+   at step boundaries (one prefill slice a tick, interleaved with the
+   running batch's steps, then their slot decodes with everyone else);
+   finished sequences (eos / max_new_tokens) free
    their slot immediately for the next waiting request.
 3. **Weights as arguments, donated paged KV state** — every program is
    `fn(params, state, feeds)`: the weights are loaded once from the
@@ -56,7 +61,7 @@ greedy transcripts BIT-IDENTICAL to plain decode while advancing up to
 K+1 tokens per dispatch. Slots without drafts ride the plain step in
 the same scheduler tick; beams never draft. Rejected speculative cache
 rows sit strictly above each slot's accepted frontier (rolled-back
-`pos` masks them; the block layout also trims over-extended tables), so
+`pos` masks them, and over-extended tables are trimmed), so
 they are overwritten before any attention window admits them. Zero
 steady-state recompiles: variable per-slot acceptance lives inside the
 fixed [max_slots, K+1] compiled shape as masked pad rows.
@@ -113,10 +118,8 @@ class MidStreamEvicted(ServerOverloaded):
 # -- artifact layout (export.py export_decode writes exactly this) ----------
 _DECODE_SIGNATURE = 'decode_signature.json'
 _STEP_DIR = 'decode_step'
-_PREFILL_DIR = 'prefill_%05d'   # % prompt-length bucket
-_REORDER_DIR = 'decode_reorder'
-# block-paged layout (ISSUE 13): chunked-prefill programs + the
-# block-copy program (beam CoW moves diverged BLOCKS, not slot rows)
+# chunked-prefill programs + the block-copy program (beam CoW moves
+# diverged BLOCKS)
 _CHUNK_DIR = 'prefill_chunk_%05d'   # % chunk size
 _BLOCKCOPY_DIR = 'decode_blockcopy'
 # speculative decoding (ISSUE 17): the [S, K+1] -> [S, K+1, V] verify
@@ -259,14 +262,14 @@ class DecodeStats(object):
         # dispatches whose LOGITS were copied to the host (a beam row
         # was live, or a caller asked); every other one copied its ids
         self.logits_fetches = 0
-        self.reorders = 0        # slot-gather dispatches (beam/replicate)
+        self.reorders = 0        # beam history moves (table permutations)
         self.active_slot_steps = 0
         self.slot_steps = 0
         self.shed = 0
         self.expired = 0
         self.drained = 0         # shed by drain(): queued at scale-in
         self.busy_s = 0.0        # wall time with >= 1 active slot
-        # block-paged layout (ISSUE 13); zero/absent on slot artifacts.
+        # set by the predictor that owns the pool (_reset_state):
         # block_source is the BlockManager.stats callable (pool gauges +
         # prefix-share accounting merge into snapshot()); block_reset
         # its reset_counters, so reset() covers the merged counters too
@@ -362,12 +365,12 @@ class DecodeStats(object):
                     'tokens_per_dispatch':
                         round(self.adv_tokens / self.adv_events, 4)
                         if self.adv_events else 1.0,
-                    'recent_failures': list(self._failures)}
-            if self.block_source is None:
+                    'recent_failures': list(self._failures),
+                    'cow_blocks': int(self.cow_blocks),
+                    'blockcopies': int(self.blockcopies),
+                    'chunk_slices': int(self.chunk_slices)}
+            if self.block_source is None:    # not wired to a pool yet
                 return snap
-            snap['cow_blocks'] = int(self.cow_blocks)
-            snap['blockcopies'] = int(self.blockcopies)
-            snap['chunk_slices'] = int(self.chunk_slices)
         # outside the stats lock: the BlockManager takes its own
         bs = self.block_source()
         snap['blocks_in_use'] = int(bs['blocks_in_use'])
@@ -568,7 +571,7 @@ class _Request(object):
         self.hyps = []                    # per beam token lists
         self.t_first = None
         self.t_last = None
-        # block layout (ISSUE 13)
+        # block tables and chunked prefill (ISSUE 13)
         self.tables = []                  # per beam: logical block ids
         self.next_start = 0               # next chunked-prefill position
         self.prefilling = False           # still admitting via chunks
@@ -597,7 +600,7 @@ def _fail(req, exc):
 class _DecodeModule(object):
     """One exported decode program: lazy StableHLO deserialize, AOT
     warm-start sidecar (zero compiles when present), fresh bookkept jit
-    fallback — donated state for step/prefill (jax's own donation
+    fallback — donated state for step/chunk/verify (jax's own donation
     bookkeeping guards the cold path; the sidecar carries certified
     aliasing for the warm path)."""
 
@@ -610,7 +613,7 @@ class _DecodeModule(object):
             sp.set_metadata(bytes=len(self._module_bytes))
         # which argument is the donated cache state: 1 for the model's
         # programs (params, state, feeds), 0 for blockcopy (state, ...),
-        # None for the undonated reorder and zeros programs
+        # None for the zeros program
         self._donate = donate
         self._platform = aot_tag.split('_')[0] if aot_tag \
             else _serve._aot_platform(device)
@@ -642,8 +645,7 @@ class _DecodeModule(object):
 
     def call(self, *args):
         """THE one dispatch site of the decode programs (step, verify,
-        chunk, prefill, blockcopy, reorder, zeros): returns once the call
-        is enqueued."""
+        chunk, blockcopy, zeros): returns once the call is enqueued."""
         fn = self._aot if self._aot is not None else self._jitted()
         with _span('decode/dispatch', program=self.name), \
                 warnings.catch_warnings():
@@ -661,7 +663,7 @@ def _precompile_decode_dir(d, arg_specs, donate=None, platform=None,
     (under a mesh, with the shardings already on the specs); `donate` is
     the index of the cache state among them — the model's programs and
     blockcopy update the paged cache in place on warm replicas — or None
-    (reorder, zeros). A sharded artifact's sidecar writes under the MESH
+    (zeros). A sharded artifact's sidecar writes under the MESH
     TAG (aot_<platform>_<axes>.jaxexec)."""
     import jax
     from jax import export as jexport
@@ -700,6 +702,13 @@ def _load_signature(artifact_dir):
                'hold the weights as constants' if version < 4
                else 'return their logits alone',
                _SIG_VERSION, _serve._DECODE_WEIGHTS))
+    if sig.get('layout') != 'block':
+        raise ValueError(
+            'decode artifact %s has the slot layout (contiguous '
+            '[max_slots, max_cache_len] cache rows, bucketed prefill): '
+            'slot-layout artifacts are no longer served; export again '
+            '(every build_decode_spec builds the block-paged pool)'
+            % artifact_dir)
     return sig
 
 
@@ -730,15 +739,14 @@ def _sig_mesh_ctx(sig, platform=None):
 
 def precompile_decode_artifact(artifact_dir, platform=None):
     """Prewarm a continuous-decode artifact: AOT-compile the decode-step
-    program, EVERY prefill bucket (slot layout) or chunked-prefill size
-    plus the block-copy program (block layout), the reorder and the zeros
-    program,
+    program, the verify program where there is one, EVERY
+    chunked-prefill size, the block-copy and the zeros program,
     writing warm-start sidecars — a replica that loads the artifact
     afterwards answers with zero traces and zero XLA compiles. Sharded
     artifacts (signature carries a mesh) prewarm over the recorded mesh
     and write MESH-TAGGED sidecars; the host must see the full device
     count. Driven by `tools/cache_ctl.py prewarm`
-    (serve.precompile_artifact detects the decode layout). Returns the
+    (serve.precompile_artifact detects a decode artifact). Returns the
     sidecar paths written."""
     import jax
     sig = _load_signature(artifact_dir)
@@ -777,19 +785,11 @@ def precompile_decode_artifact(artifact_dir, platform=None):
         # speculative artifacts (ISSUE 17): the verify program warm-
         # starts exactly like the step it rides beside
         written.append(model(_VERIFY_DIR, sig['verify']))
-    if sig.get('layout', 'slot') == 'block':
-        for c in sig['chunk_buckets']:
-            written.append(model(_CHUNK_DIR % int(c), sig['chunk'][str(c)]))
-        pairs = index_spec(sig['max_slots'])
-        written.append(dir_(_BLOCKCOPY_DIR, [state_specs, pairs, pairs],
-                            donate=0))
-        reorder_n = int(sig['block']['num_blocks'])
-    else:
-        for b in sig['prompt_buckets']:
-            written.append(model(_PREFILL_DIR % int(b),
-                                 sig['prefill'][str(b)]))
-        reorder_n = int(sig['max_slots'])
-    written.append(dir_(_REORDER_DIR, [state_specs, index_spec(reorder_n)]))
+    for c in sig['chunk_buckets']:
+        written.append(model(_CHUNK_DIR % int(c), sig['chunk'][str(c)]))
+    pairs = index_spec(sig['max_slots'])
+    written.append(dir_(_BLOCKCOPY_DIR, [state_specs, pairs, pairs],
+                        donate=0))
     written.append(dir_(_ZEROS_DIR, [index_spec(1)]))
     return written
 
@@ -809,9 +809,9 @@ class DecodingPredictor(object):
                                              and in-flight requests fail
                                              with RuntimeError
 
-    `prompt_ids`: 1-D int sequence, 1 <= len <= the largest prompt
-    bucket. `beam=` runs fixed-width beam search (the request occupies
-    `beam` slots); default greedy. Admission is strict FIFO: a beam
+    `prompt_ids`: 1-D int sequence, 1 <= len <= max_cache_len (chunked
+    prefill admits a prompt in fixed slices). `beam=` runs fixed-width
+    beam search (the request occupies `beam` slots); default greedy. Admission is strict FIFO: a beam
     request at the head waits for enough free slots.
 
     Speculative decoding (ISSUE 17): on an artifact exported with
@@ -841,7 +841,6 @@ class DecodingPredictor(object):
         self._T = int(self._sig['max_cache_len'])
         self._eos = int(self._sig['eos_id'])
         self._vocab = int(self._sig['vocab'])
-        self._layout = self._sig.get('layout', 'slot')
         self._default_max_new = int(default_max_new_tokens)
         self._max_queue = int(max_queue) if max_queue else None
         # sharded artifact (ISSUE 13): rebuild the export mesh; state
@@ -857,9 +856,6 @@ class DecodingPredictor(object):
         self._step_mod = _DecodeModule(
             os.path.join(artifact_dir, _STEP_DIR), donate=1,
             device=self._device, aot_tag=aot_tag, name='step')
-        self._reorder_mod = _DecodeModule(
-            os.path.join(artifact_dir, _REORDER_DIR),
-            device=self._device, aot_tag=aot_tag, name='reorder')
         self._zeros_mod = _DecodeModule(
             os.path.join(artifact_dir, _ZEROS_DIR),
             device=self._device, aot_tag=aot_tag, name='zeros')
@@ -895,49 +891,29 @@ class DecodingPredictor(object):
                         'draft_k must be in [1, %d] (the exported '
                         'verify width)' % self._K)
                 self._draft_k = int(draft_k)
-        if self._layout == 'block':
-            blk = self._sig['block']
-            self._bs = int(blk['block_size'])
-            self._nb = int(blk['num_blocks'])
-            self._maxb = int(blk['max_blocks_per_slot'])
-            self._trash = TRASH_BLOCK
-            # the block allocator itself is built (and wired into
-            # stats.block_source) by _reset_state — the single owner
-            # chunked prefill: prompts admit in fixed slices, so the
-            # prompt ceiling is the CACHE length, not a prefill bucket
-            self._chunks = sorted(int(c) for c in
-                                  self._sig['chunk_buckets'])
-            self._max_prompt = self._T
-            self._chunk_mods = {
-                c: _DecodeModule(
-                    os.path.join(artifact_dir, _CHUNK_DIR % c),
-                    donate=1, device=self._device,
-                    aot_tag=aot_tag, name='chunk_%d' % c)
-                for c in self._chunks}
-            self._chunk_feeds = {
-                c: [e['name'] for e in self._sig['chunk'][str(c)]['feeds']]
-                for c in self._chunks}
-            self._blockcopy_mod = _DecodeModule(
-                os.path.join(artifact_dir, _BLOCKCOPY_DIR),
-                donate=0, device=self._device, aot_tag=aot_tag,
-                name='blockcopy')
-            self._buckets = list(self._chunks)
-        else:
-            # sorted once at load: select_bucket prefers the smallest
-            # fitting bucket deterministically (batching.py discipline)
-            self._buckets = sorted(int(b)
-                                   for b in self._sig['prompt_buckets'])
-            self._max_prompt = self._buckets[-1]
-            self._prefill_mods = {
-                b: _DecodeModule(
-                    os.path.join(artifact_dir, _PREFILL_DIR % b),
-                    donate=1, device=self._device,
-                    aot_tag=aot_tag, name='prefill_%d' % b)
-                for b in self._buckets}
-            self._prefill_feeds = {
-                b: [e['name']
-                    for e in self._sig['prefill'][str(b)]['feeds']]
-                for b in self._buckets}
+        blk = self._sig['block']
+        self._bs = int(blk['block_size'])
+        self._nb = int(blk['num_blocks'])
+        self._maxb = int(blk['max_blocks_per_slot'])
+        self._trash = TRASH_BLOCK
+        # the block allocator itself is built (and wired into
+        # stats.block_source) by _reset_state — the single owner.
+        # Chunked prefill: prompts admit in fixed slices, so the prompt
+        # ceiling is the CACHE length, not a chunk size
+        self._chunks = sorted(int(c) for c in self._sig['chunk_buckets'])
+        self._chunk_mods = {
+            c: _DecodeModule(
+                os.path.join(artifact_dir, _CHUNK_DIR % c),
+                donate=1, device=self._device,
+                aot_tag=aot_tag, name='chunk_%d' % c)
+            for c in self._chunks}
+        self._chunk_feeds = {
+            c: [e['name'] for e in self._sig['chunk'][str(c)]['feeds']]
+            for c in self._chunks}
+        self._blockcopy_mod = _DecodeModule(
+            os.path.join(artifact_dir, _BLOCKCOPY_DIR),
+            donate=0, device=self._device, aot_tag=aot_tag,
+            name='blockcopy')
         self._state = None
         self._slots = [None] * self._S    # slot -> (request, beam index)
         self._closed = False
@@ -973,17 +949,16 @@ class DecodingPredictor(object):
     @property
     def attention_bodies(self):
         """{program: {op type: {body: count}}} for the kv_*attention* ops
-        of the loaded programs ('step', 'verify', 'chunk_<C>',
-        'prefill_<L>') as THIS platform runs them: what export_decode
+        of the loaded programs ('step', 'verify', 'chunk_<C>') as THIS
+        platform runs them: what export_decode
         wrote into the signature, with 'kernel' — the body a module
         holds for a TPU — read as 'jnp' anywhere else. Empty for an
         artifact exported before the signature carried it."""
         import jax
         sig = self._sig
         progs = {'step': sig['step'], 'verify': sig.get('verify', {})}
-        for kind in ('chunk', 'prefill'):
-            for size, entry in sig.get(kind, {}).items():
-                progs['%s_%s' % (kind, size)] = entry
+        for size, entry in sig['chunk'].items():
+            progs['chunk_%s' % size] = entry
         platform = (self._mesh_ctx['platform'] if self._mesh_ctx is not None
                     else (self._device or jax.devices()[0]).platform)
         out = {}
@@ -1002,16 +977,6 @@ class DecodingPredictor(object):
         return self._S
 
     @property
-    def prompt_buckets(self):
-        return list(self._buckets)
-
-    @property
-    def layout(self):
-        """'slot' (contiguous rows, bucketed prefill) or 'block'
-        (block-paged cache, chunked prefill — ISSUE 13)."""
-        return self._layout
-
-    @property
     def mesh_tag(self):
         """Mesh tag of a sharded artifact (e.g. 'tpu_mp2'); None for
         single-chip artifacts."""
@@ -1020,10 +985,9 @@ class DecodingPredictor(object):
 
     @property
     def block_manager(self):
-        """The live BlockManager of a block-layout artifact (None on
-        slot artifacts): stats()/peak accounting for tooling, and
-        evict_all_prefixes() for an explicit prefix-cache clear."""
-        return self._blocks if self._layout == 'block' else None
+        """The live BlockManager: stats()/peak accounting for tooling,
+        and evict_all_prefixes() for an explicit prefix-cache clear."""
+        return self._blocks
 
     def submit(self, prompt_ids, max_new_tokens=None, beam=None,
                deadline_ms=None, request_id=None):
@@ -1070,15 +1034,11 @@ class DecodingPredictor(object):
             prompt = np.asarray(prompt_ids, np.int64).reshape(-1).copy()
             if not prompt.size:
                 raise ValueError('empty prompt')
-            if prompt.size > self._max_prompt:
+            if prompt.size > self._T:
                 raise ValueError(
-                    'prompt of %d tokens exceeds %s' % (
-                        prompt.size,
-                        'max_cache_len %d (chunked prefill admits up to '
-                        'the cache length)' % self._max_prompt
-                        if self._layout == 'block' else
-                        'the largest compiled prompt bucket %d'
-                        % self._max_prompt))
+                    'prompt of %d tokens exceeds max_cache_len %d (chunked '
+                    'prefill admits up to the cache length)'
+                    % (prompt.size, self._T))
             max_new = int(max_new_tokens if max_new_tokens is not None
                           else self._default_max_new)
             # cache capacity: the last generated token writes position
@@ -1122,8 +1082,8 @@ class DecodingPredictor(object):
 
     def warmup(self):
         """Compile every program ahead of traffic (a no-op dispatch per
-        prefill bucket, one decode step, one all-pad verify tick on
-        speculative artifacts, one reorder); state is re-zeroed
+        prefill chunk size, one decode step, one block copy, one all-pad
+        verify tick on speculative artifacts); state is re-zeroed
         afterwards. With AOT sidecars loaded this costs a handful of
         dispatches and zero compiles. Must run BEFORE any submit(): it dispatches on
         the scheduler's donated state from this thread, so it refuses
@@ -1134,34 +1094,22 @@ class DecodingPredictor(object):
                 'warmup() must run before traffic: requests are queued or '
                 'decoding, and a caller-thread dispatch would race the '
                 "scheduler over the donated cache state")
-        if self._layout == 'block':
-            trash_tables = np.full((self._S, self._maxb), self._trash,
-                                   np.int32)
-            for c in self._chunks:
-                self._dispatch_chunk(c, np.zeros((1, c), np.int64), 0, 1,
-                                     trash_tables[:1])
-            self._dispatch_step(np.zeros((self._S, 1), np.int64),
-                                np.zeros((self._S, 1), np.int32),
-                                tables=trash_tables)
-            self._dispatch_blockcopy([])      # identity (trash-to-trash)
-        else:
-            for b in self._buckets:
-                self._dispatch_prefill(b, np.zeros((1, b), np.int64), 1, 0)
-            self._dispatch_step(np.zeros((self._S, 1), np.int64),
-                                np.zeros((self._S, 1), np.int32))
+        trash_tables = np.full((self._S, self._maxb), self._trash, np.int32)
+        for c in self._chunks:
+            self._dispatch_chunk(c, np.zeros((1, c), np.int64), 0, 1,
+                                 trash_tables[:1])
+        self._dispatch_step(np.zeros((self._S, 1), np.int64),
+                            np.zeros((self._S, 1), np.int32), trash_tables)
+        self._dispatch_blockcopy([])      # identity (trash-to-trash)
         if self._verify_mod is not None:
             # all-pad verify dispatch (ISSUE 17): every row at the pad
-            # position, so the scatter drops (slot) / routes to the
-            # trash block (block) and the dispatch is pure compile-warm
+            # position, so the scatter routes to the trash block and the
+            # dispatch is pure compile-warm
             R = self._K + 1
-            pad = (self._maxb * self._bs if self._layout == 'block'
-                   else self._T)
             self._dispatch_verify(
                 np.zeros((self._S, R), np.int64),
-                np.full((self._S, R), pad, np.int32),
-                tables=(np.full((self._S, self._maxb), self._trash,
-                                np.int32)
-                        if self._layout == 'block' else None))
+                np.full((self._S, R), self._maxb * self._bs, np.int32),
+                trash_tables)
         self._reset_state()
         self.stats.reset()   # warmup dispatches must not count as traffic
         return self
@@ -1261,23 +1209,22 @@ class DecodingPredictor(object):
         """(Re)zero the paged KV cache: the old state is dropped first and
         the new one is the OUTPUT of the artifact's zeros program (its one
         argument only says where), so the pool is held once, no host copy of it is ever
-        made, and every leaf handed to the donated step/prefill
+        made, and every leaf handed to the donated step/chunk
         executables is an XLA-owned buffer (a reloaded donating
         executable honors its baked-in aliasing without jax's
         external-buffer guard — round-8/10 cliff). Sharded artifacts get
         each leaf in its recorded mesh sharding (the program's output
-        shardings); block artifacts also rebuild the block allocator
-        (every table is dead by the time this runs)."""
+        shardings). The block allocator is rebuilt with it (every table
+        is dead by the time this runs)."""
         self._state = None
         with self._dev_ctx():
             self._state = list(self._zeros_mod.call(
                 self._feed(np.zeros((1,), np.int32))))
-        if self._layout == 'block':
-            self._blocks = BlockManager(self._nb, self._bs)
-            # block-cache gauges + prefix-share accounting merge into
-            # stats.snapshot() (serving_report's block columns)
-            self.stats.block_source = self._blocks.stats
-            self.stats.block_reset = self._blocks.reset_counters
+        self._blocks = BlockManager(self._nb, self._bs)
+        # block-cache gauges + prefix-share accounting merge into
+        # stats.snapshot() (serving_report's block columns)
+        self.stats.block_source = self._blocks.stats
+        self.stats.block_reset = self._blocks.reset_counters
 
     def _to_host(self, fetches, program, logits):
         """(ids, logits) of one dispatch as host arrays, in two spans:
@@ -1305,12 +1252,10 @@ class DecodingPredictor(object):
                 self.stats.logits_fetches += 1
         return host[0], host[1] if logits else None
 
-    def _dispatch_step(self, tokens, pos, tables=None, logits=False):
+    def _dispatch_step(self, tokens, pos, tables, logits=False):
         """One decode step: ids [S] int32 and, if `logits`, the [S, V]
         float32 rows they are the argmax of (else None)."""
-        feed = {'tokens': tokens, 'pos': pos}
-        if tables is not None:
-            feed['block_tables'] = tables
+        feed = {'tokens': tokens, 'pos': pos, 'block_tables': tables}
         args = [self._feed(feed[n])
                 for n in self._step_feeds]  # signature feed order
         with self._dev_ctx():
@@ -1321,17 +1266,15 @@ class DecodingPredictor(object):
             self.stats.steps += 1
         return self._to_host(fetches, 'step', logits)      # sync
 
-    def _dispatch_verify(self, tokens, pos, tables=None, logits=False):
+    def _dispatch_verify(self, tokens, pos, tables, logits=False):
         """One speculative verify dispatch (ISSUE 17): tokens/pos are
         [S, K+1] (row 0 the slot's pending last token, rows 1..k its
-        draft; pad rows/slots at the layout's pad position), the target
+        draft; pad rows/slots at the pad position), the target
         argmax ids come back [S, K+1] (beams never draft, so the
         scheduler never asks for the [S, K+1, V] logits). KV for all fed
         positions is written inside the program; acceptance and rollback
         happen host-side after."""
-        feed = {'tokens': tokens, 'pos': pos}
-        if tables is not None:
-            feed['block_tables'] = tables
+        feed = {'tokens': tokens, 'pos': pos, 'block_tables': tables}
         args = [self._feed(feed[n]) for n in self._verify_feeds]
         with self._dev_ctx():
             fetches, new_state = self._verify_mod.call(
@@ -1340,22 +1283,6 @@ class DecodingPredictor(object):
         with self.stats._lock:
             self.stats.verify_steps += 1
         return self._to_host(fetches, 'verify', logits)    # sync
-
-    def _dispatch_prefill(self, bucket, padded, plen, slot, logits=False):
-        """One whole-prompt prefill: the first token's id and, if
-        `logits`, the [V] row it is the argmax of (else None)."""
-        feed = {'prompt_ids': padded,
-                'prompt_len': np.full((1, 1), plen, np.int32),
-                'slot': np.full((1, 1), slot, np.int32)}
-        args = [self._feed(feed[n]) for n in self._prefill_feeds[bucket]]
-        with self._dev_ctx():
-            fetches, new_state = self._prefill_mods[bucket].call(
-                self._params, self._state, args)
-        self._state = list(new_state)
-        with self.stats._lock:
-            self.stats.prefills += 1
-        return _one_row(*self._to_host(
-            fetches, self._prefill_mods[bucket].name, logits))     # sync
 
     def _dispatch_chunk(self, size, ids, start, take, table_row,
                         logits=False):
@@ -1384,7 +1311,7 @@ class DecodingPredictor(object):
         copies pool-wide (all layers' K/V (+scale) vars). Unused pairs
         pad with (trash, trash) — a self-copy of the write-only trash
         block. This is the CoW device half: dispatch bytes scale with
-        len(pairs) x block bytes, not with slot rows."""
+        len(pairs) x block bytes."""
         dst = np.full((self._S,), self._trash, np.int32)
         src = np.full((self._S,), self._trash, np.int32)
         for i, (d, s) in enumerate(pairs):
@@ -1397,13 +1324,6 @@ class DecodingPredictor(object):
         with self.stats._lock:
             self.stats.blockcopies += 1
             self.stats.cow_blocks += len(pairs)
-
-    def _dispatch_reorder(self, src):
-        with self._dev_ctx():
-            self._state = list(self._reorder_mod.call(
-                self._state, self._feed(np.asarray(src, np.int32))))
-        with self.stats._lock:
-            self.stats.reorders += 1
 
     # -- scheduler ---------------------------------------------------------
     def _active_requests(self):
@@ -1419,13 +1339,12 @@ class DecodingPredictor(object):
     def _release(self, req):
         for s in req.slots:
             self._slots[s] = None
-        if self._layout == 'block':
-            # refcount-to-zero blocks return to the pool; blocks a
-            # prefix entry (or another request) still references live on
-            for t in req.tables:
-                self._blocks.decref(t)
-            req.tables = []
-            self._drop_match(req)
+        # refcount-to-zero blocks return to the pool; blocks a prefix
+        # entry (or another request) still references live on
+        for t in req.tables:
+            self._blocks.decref(t)
+        req.tables = []
+        self._drop_match(req)
 
     def _drop_match(self, req):
         """Release a waiting request's cached prefix-match refs (held
@@ -1480,21 +1399,16 @@ class DecodingPredictor(object):
             self._expire(waiting)
         if not self._draining:
             with _span('decode/admit') as sp:
-                sp.set_metadata(admitted=(
-                    self._admit_block(waiting) if self._layout == 'block'
-                    else self._admit(waiting)))
+                sp.set_metadata(admitted=self._admit(waiting))
         if any(s is not None for s in self._slots):
             try:
-                if self._layout == 'block':
-                    # one prefill slice per admitting request, then one
-                    # step for the running batch: a long prompt
-                    # interleaves instead of stalling every stream
-                    self._prefill_tick()
-                    if any(e is not None and not e[0].prefilling
-                           for e in self._slots):
-                        self._step_block(waiting)
-                else:
-                    self._step()
+                # one prefill slice per admitting request, then one
+                # step for the running batch: a long prompt
+                # interleaves instead of stalling every stream
+                self._prefill_tick()
+                if any(e is not None and not e[0].prefilling
+                       for e in self._slots):
+                    self._step(waiting)
             except Exception as e:
                 self._fail_all(e, waiting)
             with self.stats._lock:
@@ -1582,36 +1496,6 @@ class DecodingPredictor(object):
                            ' (request %s)' % req.request_id
                            if req.request_id else '')))
 
-    def _admit(self, waiting):
-        """Strict-FIFO admission at the step boundary: one prefill
-        dispatch per admitted request; beam requests wait for enough
-        free slots. Returns how many it admitted."""
-        admitted = 0
-        while waiting:
-            req = waiting[0]
-            need = req.beam or 1
-            free = self._free_slots()
-            if len(free) < need:
-                return admitted
-            with self._admit_span(req, 0):
-                waiting.popleft()
-                with self.stats._lock:
-                    self.stats.queue_depth -= 1
-                req.slots = free[:need]
-            admitted += 1
-            try:
-                self._prefill(req)
-            except Exception as e:
-                # the donated prefill dispatch may have consumed the
-                # state even though it raised: this is the same hazard
-                # as a step failure, so recover the same way (fail the
-                # co-resident requests loudly, rebuild zero state)
-                self._release(req)
-                _fail(req, e)
-                self._fail_all(e, waiting)
-                return admitted
-        return admitted
-
     def _admit_span(self, req, covered):
         """The marker of one admission: how long the request queued."""
         return _span('decode/admit_request', request=req.seq,
@@ -1620,30 +1504,14 @@ class DecodingPredictor(object):
                      prompt_len=int(req.prompt.size),
                      prefix_covered=int(covered))
 
-    def _prefill(self, req):
-        plen = int(req.prompt.size)
-        bucket = select_bucket(self._buckets, plen)
-        with _span('decode/prefill_slice', request=req.seq, size=bucket,
-                   take=plen, start=0):
-            padded = np.zeros((1, bucket), np.int64)
-            padded[0, :plen] = req.prompt
-            tok, logits = self._dispatch_prefill(
-                bucket, padded, plen, req.slots[0],
-                logits=req.beam is not None)
-            for i, s in enumerate(req.slots):
-                self._slots[s] = (req, i)
-            with _req_span('decode/first_token', req):
-                self._first_token(req, tok, logits)
-
     def _first_token(self, req, tok, logits):
         """Emit a request's first token: greedy, `tok` — the id its
         prompt's last position chose on the device; a beam, from that
         position's `logits` the top-W DISTINCT tokens seeding the group
         (the standard first-expansion; a naive W*V step over identical beams
-        would collapse onto one token). Beam history fan-out: the slot
-        layout replicates slot 0's cache rows through the reorder
-        program; the block layout FORKS the prompt's block table — a
-        host-side copy + incref, zero device work."""
+        would collapse onto one token). Beam history fan-out FORKS the
+        prompt's block table — a host-side copy + incref, zero device
+        work."""
         now = time.perf_counter()
         if req.beam is None:
             req.last_tokens = [tok]
@@ -1655,17 +1523,10 @@ class DecodingPredictor(object):
                 self._finish_greedy(req)
             return
         if len(req.slots) > 1:
-            if self._layout == 'block':
-                base = req.tables[0]
-                req.tables = [base] + [list(base)
-                                       for _ in req.slots[1:]]
-                for t in req.tables[1:]:
-                    self._blocks.incref(t)
-            else:
-                src = np.arange(self._S, dtype=np.int32)
-                for s in req.slots[1:]:
-                    src[s] = req.slots[0]
-                self._dispatch_reorder(src)
+            base = req.tables[0]
+            req.tables = [base] + [list(base) for _ in req.slots[1:]]
+            for t in req.tables[1:]:
+                self._blocks.incref(t)
         lp = _log_softmax(logits)
         order = np.argsort(-lp, kind='stable')[:req.beam]
         req.last_tokens = [int(t) for t in order]
@@ -1677,10 +1538,11 @@ class DecodingPredictor(object):
         if all(req.finished) or req.produced >= req.max_new:
             self._finish_beam(req)
 
-    # -- block-layout scheduling (ISSUE 13) --------------------------------
-    def _admit_block(self, waiting):
-        """Strict-FIFO block-layout admission: a request admits when a
-        slot group AND blocks for its whole prompt span are available.
+    # -- admission, prefill slices, the step (ISSUE 13) --------------------
+    def _admit(self, waiting):
+        """Strict-FIFO admission at the step boundary: a request admits
+        when a slot group AND blocks for its whole prompt span are
+        available.
         A prefix-cache hit maps the shared blocks into the table and
         skips allocating (and later prefilling) the covered span; the
         match is cached on the request across attempts, so its refs pin
@@ -1870,7 +1732,7 @@ class DecodingPredictor(object):
             self._blocks.decref([b])
             table[lblk] = nb
 
-    def _step_block(self, waiting):
+    def _step(self, waiting):
         """One iteration of the continuous batch over the block pool:
         CoW copies dispatch first (one block-copy for ALL diverged
         blocks), then every live slot advances one token through the
@@ -1884,10 +1746,10 @@ class DecodingPredictor(object):
             with _span('decode/build_feed'):
                 drafted = self._collect_drafts()
             if drafted:
-                self._verify_block(drafted, waiting)
+                self._verify(drafted, waiting)
             with _span('decode/build_feed'):
                 tokens, pos, tables, cow, active, beam = \
-                    self._step_feed_block(waiting, drafted)
+                    self._step_feed(waiting, drafted)
             sp.set_metadata(active=active)
             if not active:
                 return   # every live stream drafted (or shed): no plain step
@@ -1896,12 +1758,12 @@ class DecodingPredictor(object):
                 self.stats.slot_steps += self._S
             if cow:
                 self._dispatch_blockcopy(cow)
-            ids, logits = self._dispatch_step(tokens, pos, tables=tables,
+            ids, logits = self._dispatch_step(tokens, pos, tables,
                                               logits=beam)
             with _span('decode/advance', rows=active):
-                self._advance_block(ids, logits, drafted)
+                self._advance(ids, logits, drafted)
 
-    def _step_feed_block(self, waiting, drafted):
+    def _step_feed(self, waiting, drafted):
         """The plain step's feed over the block pool: reserve and make
         writable every block this step writes, then fill tokens / pos /
         tables for the live undrafted rows. Returns them with the CoW
@@ -1928,7 +1790,7 @@ class DecodingPredictor(object):
             tables[s, :len(table)] = table
         return tokens, pos, tables, cow, active, beam
 
-    def _advance_block(self, ids, logits, drafted):
+    def _advance(self, ids, logits, drafted):
         """After the step: emit the ids the program chose to the greedy
         streams, score beams over the fetched logits (there iff a beam
         row was live), finish what ended."""
@@ -1941,9 +1803,7 @@ class DecodingPredictor(object):
             if req.beam is None:
                 self._advance_greedy(req, toks[req.slots[0]])
                 continue
-            # shared beam scoring; the history move is the block
-            # layout's own — table permutation instead of a slot-row
-            # gather
+            # the history move is a table permutation on the host
             parents = self._score_beam(req, logits)
             if any(int(p) != i for i, p in enumerate(parents)):
                 old = req.tables
@@ -1970,7 +1830,7 @@ class DecodingPredictor(object):
                     self._count_emit(req, now)
 
     def _advance_greedy(self, req, tok):
-        """Shared slot/block greedy advance: emit the token the program
+        """Greedy advance: emit the token the program
         chose for the request's slot (already metered: _meter_greedy),
         finish on eos/max_new."""
         req.last_tokens[0] = tok
@@ -2069,42 +1929,8 @@ class DecodingPredictor(object):
             self._finish_greedy(req)
         return emitted
 
-    def _verify_slot(self, drafted):
-        """Verify tick, slot layout: ONE [S, K+1] dispatch scores every
-        drafted slot's pending token + draft. Undrafted rows ride at
-        pos = max_cache_len — the cache scatter DROPS out-of-bounds
-        rows, so they neither write nor perturb anyone. Rejected
-        speculative rows land strictly above the accepted frontier
-        (req.produced rolls the next write position back), where the
-        write-before-attend program order overwrites them before any
-        mask admits them."""
-        R = self._K + 1
-        with _span('decode/build_feed'):
-            tokens = np.zeros((self._S, R), np.int64)
-            pos = np.full((self._S, R), self._T, np.int32)
-            live = self._active_requests()
-            rows = [(req, d) for req, d in drafted.items() if req in live]
-            for req, draft in rows:
-                s = req.slots[0]
-                p = int(req.prompt.size) + req.produced - 1
-                k = len(draft)
-                tokens[s, 0] = req.last_tokens[0]
-                tokens[s, 1:1 + k] = draft
-                pos[s, :k + 1] = p + np.arange(k + 1, dtype=np.int32)
-        if not rows:
-            return
-        with self.stats._lock:
-            self.stats.active_slot_steps += len(rows)
-            self.stats.slot_steps += self._S
-        ids, _ = self._dispatch_verify(tokens, pos)
-        with _span('decode/advance', rows=len(rows)):
-            now = time.perf_counter()
-            ids = ids.tolist()
-            for req, draft in rows:
-                self._advance_spec(req, draft, ids[req.slots[0]], now)
-
-    def _verify_block(self, drafted, waiting):
-        """Verify tick, block layout: preflight/extend/CoW every block
+    def _verify(self, drafted, waiting):
+        """Verify tick: preflight/extend/CoW every block
         in each drafted slot's speculative span, dispatch ONE verify
         program (undrafted rows ride as all-pad trash-table rows), then
         ROLL each table BACK to the accepted frontier — blocks covering
@@ -2147,7 +1973,7 @@ class DecodingPredictor(object):
         # blockcopy dispatch's S pairs: chunk
         for i in range(0, len(cow), self._S):
             self._dispatch_blockcopy(cow[i:i + self._S])
-        ids, _ = self._dispatch_verify(tokens, pos, tables=tables)
+        ids, _ = self._dispatch_verify(tokens, pos, tables)
         with _span('decode/advance', rows=len(rows)):
             now = time.perf_counter()
             ids = ids.tolist()
@@ -2167,10 +1993,8 @@ class DecodingPredictor(object):
         """Fixed-width beam candidate scoring (finished beams
         contribute one frozen eos candidate — ops/decode_ops.py
         beam_search discipline): updates scores/hyps/finished/
-        last_tokens and returns `parents` for the layout's own history
-        move (slot-row gather vs block-table permutation). ONE copy, so
-        the two layouts can never drift out of the bit-identity the
-        cross-tier tests and rollout 'bit' promotion depend on."""
+        last_tokens and returns `parents` for the history move (a
+        block-table permutation)."""
         W, V = req.beam, self._vocab
         cand = np.full((W, V), -np.inf, np.float64)
         for i in range(W):
@@ -2227,77 +2051,6 @@ class DecodingPredictor(object):
             ids = np.asarray(req.hyps, np.int64)
             scores = np.asarray(req.scores, np.float64)
             req.stream._finish((ids, scores))
-
-    def _step(self):
-        """One iteration of the continuous batch: every active slot
-        advances one token through ONE fixed-shape dispatch. With a
-        drafter attached, slots holding drafts ride ONE verify dispatch
-        first (ISSUE 17); in the plain step they idle at the TOP cache
-        position — always strictly above an active slot's frontier, so
-        the garbage row is overwritten by a real write before any
-        attention mask admits it — and a fully-drafted batch skips the
-        plain dispatch entirely."""
-        with _span('decode/step') as sp:
-            with _span('decode/build_feed'):
-                drafted = self._collect_drafts()
-            if drafted:
-                self._verify_slot(drafted)
-            with _span('decode/build_feed'):
-                tokens, pos, active, beam = self._step_feed_slot(drafted)
-            sp.set_metadata(active=active)
-            if not active:
-                return   # every live stream drafted: no plain step
-            with self.stats._lock:
-                self.stats.active_slot_steps += active
-                self.stats.slot_steps += self._S
-            ids, logits = self._dispatch_step(tokens, pos, logits=beam)
-            with _span('decode/advance', rows=active):
-                self._advance_slot(ids, logits, drafted)
-
-    def _step_feed_slot(self, drafted):
-        tokens = np.zeros((self._S, 1), np.int64)
-        pos = np.zeros((self._S, 1), np.int32)
-        active = 0
-        beam = False    # a beam row live: the step's logits are wanted
-        for s, entry in enumerate(self._slots):
-            if entry is None:
-                continue
-            req, bi = entry
-            if req in drafted:
-                pos[s, 0] = self._T - 1   # advanced via verify this tick
-                continue
-            active += 1
-            beam = beam or req.beam is not None
-            tokens[s, 0] = req.last_tokens[bi]
-            # this token writes at position len(prompt) + produced - 1
-            pos[s, 0] = req.prompt.size + req.produced - 1
-        return tokens, pos, active, beam
-
-    def _advance_slot(self, ids, logits, drafted):
-        now = time.perf_counter()
-        src = np.arange(self._S, dtype=np.int32)
-        reqs = [r for r in self._active_requests() if r not in drafted]
-        toks = ids.tolist()     # once a step, not once a row
-        self._meter_greedy(reqs, now)
-        for req in reqs:
-            if req.beam is None:
-                self._advance_greedy(req, toks[req.slots[0]])
-                continue
-            # shared beam scoring; the history move is the slot
-            # layout's own — a slot-row gather
-            parents = self._score_beam(req, logits)
-            for i in range(req.beam):
-                src[req.slots[i]] = req.slots[parents[i]]
-            req.produced += 1
-            self._record_emit(req, now, count=req.beam)
-            if all(req.finished) or req.produced >= req.max_new:
-                self._finish_beam(req)
-                for s in req.slots:   # a finished group never reorders
-                    src[s] = s
-        if not np.array_equal(src, np.arange(self._S, dtype=np.int32)):
-            # one slot-gather for every surviving beam group: each beam's
-            # cache follows its parent before the next step writes
-            self._dispatch_reorder(src)
 
     def _fail_all(self, exc, waiting=()):
         """A dispatch failure mid-step may have consumed the donated

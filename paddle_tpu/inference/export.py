@@ -390,7 +390,8 @@ def _param_args(params, sharded):
 
 def export_decode(spec, out_dir, scope=None, precompile=None,
                   kv_cache_dtype=None):
-    """Export a TWO-PROGRAM continuous-decode serving artifact (ISSUE 8).
+    """Export a continuous-decode serving artifact (ISSUE 8) over the
+    block-paged KV cache (ISSUE 13).
 
     `spec` is the dict a decode model builder produces (e.g.
     models/transformer.build_decode_spec):
@@ -400,24 +401,29 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                    exporting.
       step         {'program', 'feeds', 'samples', 'fetches'}: the
                    decode-step program. Feeds must be named exactly
-                   'tokens' [max_slots, 1] int64 and 'pos'
-                   [max_slots, 1] int32; 'fetches' names ONE var, the
-                   per-slot float32 logits [max_slots, vocab].
-      prefill      {bucket_len: {...}}: one prefill program per prompt-
-                   length bucket. Feeds must be named 'prompt_ids'
-                   [1, bucket] int64, 'prompt_len' [1, 1] int32, 'slot'
-                   [1, 1] int32; 'fetches' names the last-real-position
-                   logits [1, vocab].
+                   'tokens' [max_slots, 1] int64, 'pos' [max_slots, 1]
+                   int32 and 'block_tables' [max_slots, max_blocks]
+                   int32; 'fetches' names ONE var, the per-slot float32
+                   logits [max_slots, vocab].
+      chunk        {chunk_size: {...}}: one chunked-prefill program per
+                   chunk size. Feeds must be named 'chunk_ids'
+                   [1, chunk] int64, 'start' [1, 1] int32, 'chunk_len'
+                   [1, 1] int32, 'block_table' [1, max_blocks] int32;
+                   'fetches' names the last-real-position logits
+                   [1, vocab].
       cache_vars   persistable KV-cache state vars present in every
-                   program ([max_slots, max_cache_len, ...]).
+                   program: the pool ([num_blocks, block_size, ...]),
+                   addressed through the block tables fed at dispatch
+                   time.
+      block_size / num_blocks / max_blocks_per_slot /
       max_slots / max_cache_len / eos_id / vocab.
 
     Every program is traced ONCE as fn(params, state, feeds) ->
     (fetches, new_state). The exported program returns TWO fetches
     (signature 'fetches': ['ids', <the logits var>]): fetch 0 is `ids`,
     int32, the argmax of the logits over the vocabulary — [max_slots]
-    for the step, [max_slots, K+1] for verify, [1] for a prefill or a
-    chunk — and fetch 1 is the float32 logits the spec names, untouched.
+    for the step, [max_slots, K+1] for verify, [1] for a chunk — and
+    fetch 1 is the float32 logits the spec names, untouched.
     The argmax is appended HERE, at the one place every program of every
     model is traced (_export_decode_program), over the same float32
     values and with np.argmax's tie rule (the lowest index), so the
@@ -428,42 +434,35 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     UNDONATED: no module holds a weight as a constant, the artifact
     holds one copy of the weights (decode_weights.bin: raw bytes, mapped
     by the signature's 'params' entries {name, shape, dtype, offset,
-    nbytes}) and the loader one set of device buffers that step, chunk,
-    prefill and verify share. The cache state threads through as
-    donated inputs/outputs. The artifact also carries a REORDER program
-    (state gathered by a per-slot source index — beam reordering, cache
-    replication), a ZEROS program (the cache state born on the device:
-    XLA-owned buffers, the pool held once, no host copy) and
-    per-program AOT warm-start sidecars, the model's programs compiled
-    WITH state donation (the paged cache updates in place; the loader
+    nbytes}) and the loader one set of device buffers that step, chunk
+    and verify share. The cache state threads through as
+    donated inputs/outputs. The artifact also carries a BLOCKCOPY
+    program (up to max_slots (dst, src) block pairs copy per dispatch —
+    beam copy-on-write moves diverged BLOCKS; a beam's history move
+    itself is a table permutation on the host), a ZEROS program (the
+    cache state born on the device: XLA-owned buffers, the pool held
+    once, no host copy) and per-program AOT warm-start sidecars, the
+    model's programs compiled WITH state donation (the paged cache updates in place; the loader
     passes only XLA-owned buffers, the executor's round-10 ownership
     discipline). The signature is version 5: weights as arguments
     (since 4) and `ids` as fetch 0 beside the logits (5); the loader
     refuses an older artifact by name.
 
     Artifact layout (out_dir/):
-      decode_signature.json   shapes, buckets, params and state specs,
-                              eos/vocab
+      decode_signature.json   shapes, chunk sizes, params and state
+                              specs, the pool's geometry, eos/vocab
       decode_weights.bin      the one copy of the weights
       decode_step/            module.jaxexport (+ aot_<platform>.jaxexec)
       decode_zeros/           the state's birth
-      prefill_<bucket>/       one per prompt bucket
-      decode_reorder/         slot-gather program (undonated)
+      prefill_chunk_<C>/      one per chunk size
+      decode_blockcopy/       block-pair copy program (CoW)
 
     kv_cache_dtype='int8' (ISSUE 11): assert-and-record that the spec
     was built with the quantized paged cache (build_decode_spec's
-    kv_cache_dtype) — the int8 pages + per-slot-page f32 scales thread
+    kv_cache_dtype) — the int8 pages + per-position f32 scales thread
     through as state like any other cache var, halving cache HBM so the
     same budget serves ~2x max_slots. The signature records the dtype
     and the per-state byte accounting for capacity planning.
-
-    Block-paged specs (ISSUE 13, build_decode_spec(block_size=...))
-    export the BLOCK layout: the cache pool is addressed through block
-    tables fed at dispatch time, prefill is chunked (prefill_chunk_<C>/
-    one program per chunk size), and the artifact carries a BLOCKCOPY
-    program (decode_blockcopy/: up to max_slots (dst, src) block pairs
-    copy per dispatch — beam copy-on-write moves diverged BLOCKS, not
-    slot rows) next to the reorder program (which gathers over blocks).
 
     Specs annotated for tensor-model sharding (build_decode_spec
     mp_shard=k) trace every program over the composed mesh: the
@@ -500,7 +499,6 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
             "requested cache dtype (build_decode_spec(kv_cache_dtype=...))"
             % (kv_cache_dtype, spec_kv))
     scope = scope if scope is not None else global_scope()
-    layout = spec.get('layout', 'slot')
     state_names = list(spec['cache_vars'])
     state_specs = []
     for n in state_names:
@@ -512,8 +510,7 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
         # shape and dtype only: the pool itself never leaves the device
         state_specs.append(jax.ShapeDtypeStruct(np.shape(val), val.dtype))
     step = spec['step']
-    step_want = (['block_tables', 'pos', 'tokens'] if layout == 'block'
-                 else ['pos', 'tokens'])
+    step_want = ['block_tables', 'pos', 'tokens']
     if sorted(step['feeds']) != step_want:
         raise ValueError("decode-step feeds must be %r, got %r"
                          % (step_want, step['feeds']))
@@ -527,33 +524,17 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
             raise ValueError("decode-verify feeds must be %r, got %r"
                              % (step_want, verify['feeds']))
         entries[_decoding._VERIFY_DIR] = verify
-    if layout == 'block':
-        chunks = sorted(int(c) for c in spec['chunk'])
-        if not chunks:
-            raise ValueError("block-layout export needs at least one "
-                             "chunk size")
-        for C in chunks:
-            p = spec['chunk'][C]
-            if sorted(p['feeds']) != ['block_table', 'chunk_ids',
-                                      'chunk_len', 'start']:
-                raise ValueError(
-                    "chunk feeds must be ['chunk_ids', 'start', "
-                    "'chunk_len', 'block_table'], got %r" % (p['feeds'],))
-            entries[_decoding._CHUNK_DIR % C] = p
-        reorder_n = int(spec['num_blocks'])
-    else:
-        buckets = sorted(int(b) for b in spec['prefill'])
-        if not buckets:
-            raise ValueError("export_decode needs at least one prompt "
-                             "bucket")
-        for L in buckets:
-            p = spec['prefill'][L]
-            if sorted(p['feeds']) != ['prompt_ids', 'prompt_len', 'slot']:
-                raise ValueError(
-                    "prefill feeds must be ['prompt_ids', 'prompt_len', "
-                    "'slot'], got %r" % (p['feeds'],))
-            entries[_decoding._PREFILL_DIR % L] = p
-        reorder_n = int(spec['max_slots'])
+    chunks = sorted(int(c) for c in spec['chunk'])
+    if not chunks:
+        raise ValueError("export_decode needs at least one chunk size")
+    for C in chunks:
+        p = spec['chunk'][C]
+        if sorted(p['feeds']) != ['block_table', 'chunk_ids',
+                                  'chunk_len', 'start']:
+            raise ValueError(
+                "chunk feeds must be ['chunk_ids', 'start', "
+                "'chunk_len', 'block_table'], got %r" % (p['feeds'],))
+        entries[_decoding._CHUNK_DIR % C] = p
     programs = {d: _optimize_decode_program(e, state_names)
                 for d, e in entries.items()}
     # the ONE parameter list every program takes, in this order
@@ -572,13 +553,9 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
             sigs[d] = _export_decode_program(
                 e, programs[d], param_args, param_specs, state_names,
                 state_specs, os.path.join(out_dir, d), shard=shard)
-    if layout == 'block':
-        _export_decode_blockcopy(
-            state_specs, int(spec['max_slots']),
-            os.path.join(out_dir, _decoding._BLOCKCOPY_DIR), shard=shard)
-    _export_decode_reorder(state_specs, reorder_n,
-                           os.path.join(out_dir, _decoding._REORDER_DIR),
-                           shard=shard)
+    _export_decode_blockcopy(
+        state_specs, int(spec['max_slots']),
+        os.path.join(out_dir, _decoding._BLOCKCOPY_DIR), shard=shard)
     _export_decode_zeros(state_specs,
                          os.path.join(out_dir, _decoding._ZEROS_DIR),
                          shard=shard)
@@ -588,9 +565,10 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     # version 4: the weights are ARGUMENTS of every program (one
     # decode_weights.bin, listed under 'params'); up to version 3 each
     # program's module held them as constants. Version 5: every program
-    # returns the argmax ids as fetch 0 beside its logits
+    # returns the argmax ids as fetch 0 beside its logits. 'layout' is
+    # what the loader refuses an artifact without (the slot tier's)
     sig = {'version': _decoding._SIG_VERSION, 'kind': 'decode',
-           'layout': layout,
+           'layout': 'block',
            'max_slots': int(spec['max_slots']),
            'max_cache_len': int(spec['max_cache_len']),
            'eos_id': int(spec['eos_id']), 'vocab': int(spec['vocab']),
@@ -604,22 +582,17 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
            'params': param_sig,
            'param_args': param_args,
            'weight_bytes': int(sum(e['nbytes'] for e in param_sig)),
-           'step': sigs[_decoding._STEP_DIR]}
+           'step': sigs[_decoding._STEP_DIR],
+           'block': {'block_size': int(spec['block_size']),
+                     'num_blocks': int(spec['num_blocks']),
+                     'max_blocks_per_slot':
+                         int(spec['max_blocks_per_slot'])},
+           'chunk_buckets': chunks,
+           'chunk': {str(C): sigs[_decoding._CHUNK_DIR % C]
+                     for C in chunks}}
     if verify is not None:
         sig['verify'] = dict(sigs[_decoding._VERIFY_DIR],
                              draft_k=int(spec['draft_k']))
-    if layout == 'block':
-        sig['block'] = {'block_size': int(spec['block_size']),
-                        'num_blocks': int(spec['num_blocks']),
-                        'max_blocks_per_slot':
-                            int(spec['max_blocks_per_slot'])}
-        sig['chunk_buckets'] = chunks
-        sig['chunk'] = {str(C): sigs[_decoding._CHUNK_DIR % C]
-                        for C in chunks}
-    else:
-        sig['prompt_buckets'] = buckets
-        sig['prefill'] = {str(L): sigs[_decoding._PREFILL_DIR % L]
-                          for L in buckets}
     if shard is not None:
         sig['mesh'] = {'axes': {a: int(n) for a, n in
                                 shard['axes'].items()},
@@ -838,33 +811,6 @@ def _export_decode_program(entry, program, param_args, param_specs,
             'attention': attention}
 
 
-def _state_program_shardings(shard, n_index_args):
-    """(in_shardings, out_shardings) of a program over the state list and
-    `n_index_args` replicated index vectors; (None, None) unsharded."""
-    if shard is None:
-        return None, None
-    state_ns = list(shard['state_ns'])
-    return (state_ns,) + (shard['rep'],) * n_index_args, state_ns
-
-
-def _export_decode_reorder(state_specs, n_rows, out_dir, shard=None):
-    """Serialize the axis-0 gather program: new_state[i] = state[i][src]
-    per cache var (src [n_rows] int32 — slot rows in the slot layout,
-    PHYSICAL BLOCKS in the block layout). Pure structural jax — no
-    Program IR needed. Undonated: beam reordering reads rows it also
-    writes."""
-    import jax
-    import jax.numpy as jnp
-
-    def fn(state_list, src):
-        return [jnp.take(s, src, axis=0) for s in state_list]
-
-    src_spec = jax.ShapeDtypeStruct((n_rows,), np.int32)
-    in_sh, out_sh = _state_program_shardings(shard, 1)
-    _export_serialize(fn, (state_specs, src_spec), out_dir, shard=shard,
-                      in_shardings=in_sh, out_shardings=out_sh)
-
-
 def _export_decode_zeros(state_specs, out_dir, shard=None):
     """Serialize the program that GIVES BIRTH to the cache state: one
     zero array per cache var, made on the device. The loader's state
@@ -886,23 +832,26 @@ def _export_decode_zeros(state_specs, out_dir, shard=None):
 
 
 def _export_decode_blockcopy(state_specs, max_pairs, out_dir, shard=None):
-    """Serialize the block-copy program (block layout only): up to
-    `max_pairs` (dst, src) PHYSICAL-BLOCK pairs copy per dispatch —
+    """Serialize the block-copy program: up to `max_pairs` (dst, src)
+    PHYSICAL-BLOCK pairs copy per dispatch —
     new_state[i] = state[i].at[dst].set(state[i][src]) for every pool
     var. This is beam copy-on-write's device half: the scheduler copies
     only the DIVERGED partial tail blocks of a reordered beam group (and
     pads unused pairs with (0, 0) — a trash-to-trash self-copy), so
-    reorder dispatch bytes scale with diverged blocks instead of whole
-    slot rows. Donated at load (in-place on the live pool)."""
+    the bytes a reorder moves scale with diverged blocks. Donated at
+    load (in-place on the live pool)."""
     import jax
 
     def fn(state_list, dst, src):
         return [s.at[dst].set(s[src]) for s in state_list]
 
     idx_spec = jax.ShapeDtypeStruct((max_pairs,), np.int32)
-    in_sh, out_sh = _state_program_shardings(shard, 2)
+    state_ns = shard and list(shard['state_ns'])
     _export_serialize(fn, (state_specs, idx_spec, idx_spec), out_dir,
-                      shard=shard, in_shardings=in_sh, out_shardings=out_sh)
+                      shard=shard,
+                      in_shardings=shard and (state_ns, shard['rep'],
+                                              shard['rep']),
+                      out_shardings=state_ns)
 
 
 def _optimize_for_export(predictor):

@@ -287,9 +287,8 @@ class _Replica(object):
                              or self.spec.get('artifact')),
                 'tier': self.hello.get('tier', self.spec.get('tier')
                                        or 'bf16'),
-                # decode artifacts: cache layout + mesh tag the worker
-                # actually loaded (ISSUE 13 block/sharded tiers)
-                'layout': self.hello.get('layout'),
+                # decode artifacts: the mesh tag the worker actually
+                # loaded (ISSUE 13 sharded tiers)
                 'mesh': self.hello.get('mesh'),
                 'outstanding': len(self.outstanding),
                 'pending': len(self.pending),

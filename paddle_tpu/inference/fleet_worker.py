@@ -22,10 +22,10 @@ Frames handled: infer / decode (per-request), drain (predictor drain()
 hook: stop admitting, finish in-flight, shed the queue re-routably),
 stop. Replies: result (ok or etype/error/requeue), tok (greedy decode
 streaming), drained, bye. The hello frame carries the artifact tier the
-endpoint ACTUALLY serves plus — for decode artifacts — the cache layout
-('slot' or 'block') and mesh tag ('cpu_mp2', None unsharded), so
-block-paged and mp-sharded decode tiers (ISSUE 13) route through the
-same protocol with the router able to audit what each replica loaded.
+endpoint ACTUALLY serves plus — for decode artifacts — the mesh tag
+('cpu_mp2', None unsharded), so mp-sharded decode tiers (ISSUE 13) route
+through the same protocol with the router able to audit what each
+replica loaded.
 """
 import json
 import os
@@ -166,11 +166,10 @@ class _DecodingEndpoint(object):
         if opts.get('warmup', True):
             self.pred.warmup()
         self.tier = self.pred.stats.tier
-        # ISSUE 13: block-paged and mp-sharded decode artifacts load
-        # through the same endpoint (DecodingPredictor reads the layout
-        # and mesh from the signature); surface both so the router and
-        # fleet_ctl can audit which tier a replica actually serves
-        self.layout = self.pred.layout
+        # ISSUE 13: mp-sharded decode artifacts load through the same
+        # endpoint (DecodingPredictor reads the mesh from the
+        # signature); surface it so the router and fleet_ctl can audit
+        # which tier a replica actually serves
         self.mesh = self.pred.mesh_tag
         self.draining = False
 
@@ -440,7 +439,6 @@ def main():
     conn.send({'op': 'hello', 'replica': rid, 'pid': os.getpid(),
                'artifact': artifact,
                'kind': kind, 'tier': endpoint.tier,
-               'layout': getattr(endpoint, 'layout', None),
                'mesh': getattr(endpoint, 'mesh', None),
                'compiles': compiles[0],
                'framework_free': 'paddle_tpu' not in sys.modules})

@@ -1,13 +1,13 @@
 """Block-granular KV-cache management (ISSUE 13 tentpole).
 
-The slot-paged decode cache (rounds 11/14) reserves one contiguous
-`[max_cache_len, d]` row per slot, which makes two per-request costs
-structural: beam reorder gathers WHOLE slot rows (the only way to move a
-beam's history under contiguous addressing), and two requests with the
-same prompt prefix — system prompts, the production common case — store
-and recompute that prefix once EACH. This module is the vLLM-style fix:
-the cache becomes a pool of fixed-size BLOCKS `[num_blocks, block_size,
-d]`, each slot addresses it through a per-slot BLOCK TABLE (logical
+A decode cache that reserves one contiguous `[max_cache_len, d]` row per
+slot makes two per-request costs structural: beam reorder gathers WHOLE
+slot rows (the only way to move a beam's history under contiguous
+addressing), and two requests with the same prompt prefix — system
+prompts, the production common case — store and recompute that prefix
+once EACH. This module is the vLLM-style answer, and the serving tier's
+one cache layout: the cache is a pool of fixed-size BLOCKS
+`[num_blocks, block_size, d]`, each slot addresses it through a per-slot BLOCK TABLE (logical
 position p lives at `cache[table[p // bs], p % bs]`), and blocks are
 refcounted so histories are SHARED instead of copied:
 

@@ -360,9 +360,9 @@ def precompile_artifact(artifact_dir, platform=None):
     the train module when present) for this process's platform, writing
     warm-start sidecars — a replica that loads the artifact afterwards
     performs zero traces and zero XLA compiles before its first answer.
-    Continuous-decode artifacts (export_decode's two-program layout)
-    prewarm BOTH tiers: every prompt-length prefill bucket plus the
-    decode-step and reorder programs. The engine behind
+    Continuous-decode artifacts (export_decode's, decode_signature.json)
+    prewarm every program they hold: each chunked-prefill size, the
+    decode-step, verify, block-copy and zeros programs. The engine behind
     `tools/cache_ctl.py prewarm`. Returns the sidecar paths written."""
     import shutil
     written = []

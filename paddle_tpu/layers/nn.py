@@ -1636,99 +1636,6 @@ def pipelined_ffn_stack(input, num_layers, d_ff, num_microbatches=0,
     return out
 
 
-def kv_cache_write(cache, kv, pos):
-    """Continuous-decode primitive: write this step's K or V rows
-    [max_slots, d] into the persistable slot-paged `cache`
-    [max_slots, max_cache_len, d] at each slot's `pos` (int32
-    [max_slots] or [max_slots, 1]). Updates `cache` IN PLACE (output
-    aliases the input var, the optimizer ParamOut==Param discipline) and
-    returns it, so downstream kv_cache_attention reads the post-write
-    binding. Serving-only (no gradient)."""
-    helper = LayerHelper('kv_cache_write')
-    helper.append_op(type='kv_cache_write',
-                     inputs={'Cache': cache, 'KV': kv, 'Pos': pos},
-                     outputs={'Out': cache}, attrs={})
-    return cache
-
-
-def kv_cache_prefill_write(cache, kv, slot):
-    """Continuous-decode primitive: write a whole prompt's K or V rows
-    [1, bucket_len, d] into ONE slot of the paged `cache`
-    [max_slots, max_cache_len, d] (int32 `slot`, shape [1] or [1, 1]).
-    In-place on `cache`, like kv_cache_write."""
-    helper = LayerHelper('kv_cache_prefill_write')
-    helper.append_op(type='kv_cache_prefill_write',
-                     inputs={'Cache': cache, 'KV': kv, 'Slot': slot},
-                     outputs={'Out': cache}, attrs={})
-    return cache
-
-
-def kv_cache_attention(query, k_cache, v_cache, pos, n_head, scale=None):
-    """One-token-per-slot attention over the slot-paged KV cache:
-    `query` [max_slots, d] attends rows j <= pos of its own slot in
-    k_cache/v_cache [max_slots, max_cache_len, d]; heads split inside
-    the op. Returns the merged context [max_slots, d]. Masked rows get
-    exactly-zero softmax weight, so inactive/stale slots never perturb
-    active ones (the continuous-batching bit-identity contract;
-    ops/decode_ops.py)."""
-    helper = LayerHelper('kv_cache_attention')
-    out = helper.create_variable_for_type_inference(query.dtype)
-    helper.append_op(type='kv_cache_attention',
-                     inputs={'Q': query, 'KCache': k_cache,
-                             'VCache': v_cache, 'Pos': pos},
-                     outputs={'Out': out},
-                     attrs={'n_head': int(n_head),
-                            'scale': float(scale or 0.0)})
-    out.stop_gradient = True
-    return out
-
-
-def kv_cache_write_quant(cache, cache_scale, kv, pos):
-    """kv_cache_write over the INT8 paged cache (ISSUE 11): `cache` is
-    int8 [max_slots, max_cache_len, d] with one f32 scale per slot-page
-    in `cache_scale` [max_slots, max_cache_len]. Each slot's f32 row
-    quantizes at its own abs-max page scale at write time. In-place on
-    the (cache, cache_scale) pair; returns both post-write bindings."""
-    helper = LayerHelper('kv_cache_write_quant')
-    helper.append_op(type='kv_cache_write_quant',
-                     inputs={'Cache': cache, 'Scale': cache_scale,
-                             'KV': kv, 'Pos': pos},
-                     outputs={'Out': cache, 'OutScale': cache_scale},
-                     attrs={})
-    return cache, cache_scale
-
-
-def kv_cache_prefill_write_quant(cache, cache_scale, kv, slot):
-    """kv_cache_prefill_write over the INT8 paged cache: a whole
-    prompt's [1, bucket_len, d] f32 rows quantize per position and blit
-    into ONE slot. In-place, like kv_cache_write_quant."""
-    helper = LayerHelper('kv_cache_prefill_write_quant')
-    helper.append_op(type='kv_cache_prefill_write_quant',
-                     inputs={'Cache': cache, 'Scale': cache_scale,
-                             'KV': kv, 'Slot': slot},
-                     outputs={'Out': cache, 'OutScale': cache_scale},
-                     attrs={})
-    return cache, cache_scale
-
-
-def kv_cache_attention_quant(query, k_cache, k_scale, v_cache, v_scale,
-                             pos, n_head, scale=None):
-    """kv_cache_attention over the INT8 paged cache: K/V rows dequantize
-    (int8 x per-page scale) INSIDE the attention body — no f32 cache
-    copy materializes. Same masked-window semantics as the fp op."""
-    helper = LayerHelper('kv_cache_attention_quant')
-    out = helper.create_variable_for_type_inference(query.dtype)
-    helper.append_op(type='kv_cache_attention_quant',
-                     inputs={'Q': query, 'KCache': k_cache,
-                             'KScale': k_scale, 'VCache': v_cache,
-                             'VScale': v_scale, 'Pos': pos},
-                     outputs={'Out': out},
-                     attrs={'n_head': int(n_head),
-                            'scale': float(scale or 0.0)})
-    out.stop_gradient = True
-    return out
-
-
 def sharding_hint(x, spec=()):
     """Constrain `x` to a GSPMD partition spec (mesh axis name per dim,
     None/'' to replicate a dim; empty spec = fully replicated) on the
@@ -1772,9 +1679,8 @@ def kv_block_attention(query, k_cache, v_cache, pos, block_table,
     an active slot, and a slot's output depends only on its own pages
     and `pos` (the block form of the continuous-batching contract: a
     stream is bit-identical to serving the request alone). The op has
-    two lowerings (ops/decode_ops.py): the gathered view through
-    kv_cache_attention's own expression on every platform but a TPU,
-    where block-paged therefore equals slot-paged bit for bit; and,
+    two lowerings (ops/decode_ops.py): the masked-attention expression
+    over the gathered view on every platform but a TPU; and,
     compiled for a TPU with a float32 / bfloat16 pool of whole-tile
     pages, a Pallas kernel that reads pages 0 .. pos // block_size only
     and rounds differently from the gathered body (float32 throughout)."""
@@ -1897,73 +1803,11 @@ def kv_block_chunk_attention_quant(query, k_cache, k_scale, v_cache,
     return out
 
 
-def kv_cache_verify_write(cache, kv, pos):
-    """Speculative-decode primitive (ISSUE 17): write R = draft_k + 1
-    speculative K or V rows per slot ([max_slots, R, d]) into the
-    slot-paged `cache` at per-row positions `pos` [max_slots, R] int32.
-    Pad rows carry pos = max_cache_len (out-of-bounds scatter rows
-    drop — no write). In-place on `cache`, like kv_cache_write."""
-    helper = LayerHelper('kv_cache_verify_write')
-    helper.append_op(type='kv_cache_verify_write',
-                     inputs={'Cache': cache, 'KV': kv, 'Pos': pos},
-                     outputs={'Out': cache}, attrs={})
-    return cache
-
-
-def kv_cache_verify_attention(query, k_cache, v_cache, pos, n_head,
-                              scale=None):
-    """Verify attention over the slot-paged cache: `query`
-    [max_slots, R, d] row i attends its slot's cache rows
-    j <= pos[s, i] — a per-row frontier, so one dispatch scores every
-    drafted continuation length at once. Row-wise the body is exactly
-    kv_cache_attention's expression (bit-comparable to the plain step;
-    ops/decode_ops.py)."""
-    helper = LayerHelper('kv_cache_verify_attention')
-    out = helper.create_variable_for_type_inference(query.dtype)
-    helper.append_op(type='kv_cache_verify_attention',
-                     inputs={'Q': query, 'KCache': k_cache,
-                             'VCache': v_cache, 'Pos': pos},
-                     outputs={'Out': out},
-                     attrs={'n_head': int(n_head),
-                            'scale': float(scale or 0.0)})
-    out.stop_gradient = True
-    return out
-
-
-def kv_cache_verify_write_quant(cache, cache_scale, kv, pos):
-    """kv_cache_verify_write over the INT8 paged cache: each
-    speculative row quantizes at its own abs-max page scale; pad rows
-    drop both row and scale. In-place on the (cache, scale) pair."""
-    helper = LayerHelper('kv_cache_verify_write_quant')
-    helper.append_op(type='kv_cache_verify_write_quant',
-                     inputs={'Cache': cache, 'Scale': cache_scale,
-                             'KV': kv, 'Pos': pos},
-                     outputs={'Out': cache, 'OutScale': cache_scale},
-                     attrs={})
-    return cache, cache_scale
-
-
-def kv_cache_verify_attention_quant(query, k_cache, k_scale, v_cache,
-                                    v_scale, pos, n_head, scale=None):
-    """kv_cache_verify_attention over the INT8 paged cache: K/V rows
-    dequantize inside the body, then the exact fp verify expression."""
-    helper = LayerHelper('kv_cache_verify_attention_quant')
-    out = helper.create_variable_for_type_inference(query.dtype)
-    helper.append_op(type='kv_cache_verify_attention_quant',
-                     inputs={'Q': query, 'KCache': k_cache,
-                             'KScale': k_scale, 'VCache': v_cache,
-                             'VScale': v_scale, 'Pos': pos},
-                     outputs={'Out': out},
-                     attrs={'n_head': int(n_head),
-                            'scale': float(scale or 0.0)})
-    out.stop_gradient = True
-    return out
-
-
 def kv_block_verify_write(cache, kv, pos, block_table):
-    """kv_cache_verify_write over the BLOCK pool: R speculative rows
-    per slot scatter through the slot's `block_table` row (broadcast
-    over its R rows). Pad rows carry pos = max_blocks * block_size,
+    """Speculative-decode verify write (ISSUE 17): R = draft_k + 1 K or
+    V rows per slot [max_slots, R, d] at `pos` [max_slots, R] scatter
+    through the slot's `block_table` row (broadcast over its R rows).
+    Pad rows carry pos = max_blocks * block_size,
     which the scatter's span guard forces to the trash block — never a
     shared prefix block. In-place on `cache`."""
     helper = LayerHelper('kv_block_verify_write')
@@ -1976,9 +1820,10 @@ def kv_block_verify_write(cache, kv, pos, block_table):
 
 def kv_block_verify_attention(query, k_cache, v_cache, pos, block_table,
                               n_head, scale=None):
-    """kv_cache_verify_attention over the block pool: per-slot logical
-    views gather through `block_table`, row i masks at j <= pos[s, i].
-    Foreign blocks and trash garbage get exactly-zero weight."""
+    """Verify attention over the block pool: `query` [max_slots, R, d],
+    per-slot logical views gather through `block_table`, row i masks at
+    j <= pos[s, i]. Foreign blocks and trash garbage get exactly-zero
+    weight."""
     helper = LayerHelper('kv_block_verify_attention')
     out = helper.create_variable_for_type_inference(query.dtype)
     helper.append_op(type='kv_block_verify_attention',

@@ -445,45 +445,17 @@ def _chunk_eval(ctx, ins):
 # KV-cache decode steps (continuous in-flight batching, ISSUE 8)
 #
 # The decode-serving tier (inference/decoding.py) runs autoregressive
-# models as TWO fixed-shape programs over a preallocated slot-paged KV
-# cache [max_slots, max_cache_len, d_model] held as persistable state:
-# a bucketed PREFILL program writes a whole prompt's K/V rows into one
-# slot, and a DECODE-STEP program advances every slot by one token.
-# These ops are the cache-aware attention primitives both programs use.
-# Per-slot math never mixes rows, so a slot's outputs are bit-identical
-# regardless of which other requests co-reside in the batch — the
-# continuous-batching determinism contract.
+# models as fixed-shape programs over a preallocated block-paged KV pool
+# held as persistable state: chunked-PREFILL programs write one slice of
+# one prompt's K/V rows through its slot's block table, and a
+# DECODE-STEP program advances every slot by one token. The kv_block_*
+# ops below are the cache-aware primitives those programs use; the
+# bodies here are the masked-attention expressions they share, over a
+# slot's gathered logical view [T', D]. Per-slot math never mixes rows,
+# so a slot's outputs are bit-identical regardless of which other
+# requests co-reside in the batch — the continuous-batching determinism
+# contract.
 # ---------------------------------------------------------------------------
-
-@register('kv_cache_write', no_grad=True, lod='none')
-def _kv_cache_write(ctx, ins):
-    """Write one decode step's K or V row into the slot-paged cache:
-    Cache [S, T, D], KV [S, D], Pos [S] int32 (each slot's write
-    position). Out aliases Cache (in-place update of the persistable
-    buffer, the sgd ParamOut==Param discipline)."""
-    cache = ins['Cache'][0]
-    kv = ins['KV'][0]
-    pos = ins['Pos'][0].reshape(-1).astype(jnp.int32)
-
-    def upd(c, k, p):
-        return jax.lax.dynamic_update_slice(c, k[None, :], (p, 0))
-
-    return {'Out': [jax.vmap(upd)(cache, kv.astype(cache.dtype), pos)]}
-
-
-@register('kv_cache_prefill_write', no_grad=True, lod='none')
-def _kv_cache_prefill_write(ctx, ins):
-    """Write a whole prompt's K/V rows into ONE slot of the paged cache:
-    Cache [S, T, D], KV [1, L, D] (prefill batch is one request), Slot
-    [1] int32. Rows beyond the true prompt length carry pad garbage;
-    the decode step overwrites position p before any step attends it
-    (mask j <= pos), so stale rows are never read."""
-    cache = ins['Cache'][0]
-    kv = ins['KV'][0]
-    slot = ins['Slot'][0].reshape(-1).astype(jnp.int32)[0]
-    return {'Out': [jax.lax.dynamic_update_slice(
-        cache, kv.astype(cache.dtype), (slot, 0, 0))]}
-
 
 def _paged_attention_body(ctx, q, kc, vc, pos):
     """The shared heads-inside masked attention body: Q [S, D] attends
@@ -506,25 +478,10 @@ def _paged_attention_body(ctx, q, kc, vc, pos):
     return ctxv.reshape(s, d).astype(q.dtype)
 
 
-@register('kv_cache_attention', no_grad=True, lod='none')
-def _kv_cache_attention(ctx, ins):
-    """One-token-per-slot attention over the paged cache: Q [S, D],
-    KCache/VCache [S, T, D], Pos [S] int32. Each slot attends its own
-    cache rows j <= pos (already written this step), heads split
-    inside the op (attr n_head); masked rows get exactly-zero weight
-    (-inf before softmax), so stale finite cache garbage in masked or
-    foreign rows can never perturb an active slot's output."""
-    q = ins['Q'][0]
-    kc = ins['KCache'][0]
-    vc = ins['VCache'][0]
-    pos = ins['Pos'][0].reshape(-1).astype(jnp.int32)
-    return {'Out': [_paged_attention_body(ctx, q, kc, vc, pos)]}
-
-
 # ---------------------------------------------------------------------------
-# int8-quantized paged KV cache (ISSUE 11): the cache stores int8 rows
-# plus ONE f32 scale per slot-page (cache position) — [S, T] scales next
-# to the [S, T, D] int8 cache, ~(1 + 4/D)/2 the bytes of a bf16 cache —
+# int8-quantized paged KV cache (ISSUE 11): the pool stores int8 rows
+# plus ONE f32 scale per cache position — [NB, BS] scales next to the
+# [NB, BS, D] int8 pool, ~(1 + 4/D)/2 the bytes of a bf16 cache —
 # so a fixed cache-HBM budget holds 2x the slots, the direct
 # occupancy -> throughput win for DecodingPredictor. Quantization
 # happens at WRITE time (each K/V row is seen exactly once); attention
@@ -540,67 +497,11 @@ _KV_SCALE_EPS = 1e-30
 
 def _quantize_kv_rows(kv):
     """[..., D] f32 -> (int8 [..., D], f32 scale [...]) with one
-    symmetric abs-max scale per row (= per slot-page once written)."""
+    symmetric abs-max scale per row (= per cache position once written)."""
     s = jnp.max(jnp.abs(kv), axis=-1) / _KV_QMAX
     s = jnp.maximum(s, _KV_SCALE_EPS)
     q = jnp.clip(jnp.round(kv / s[..., None]), -_KV_QMAX, _KV_QMAX)
     return q.astype(jnp.int8), s.astype(jnp.float32)
-
-
-@register('kv_cache_write_quant', no_grad=True, lod='none')
-def _kv_cache_write_quant(ctx, ins):
-    """kv_cache_write over the int8 cache: Cache int8 [S, T, D], Scale
-    f32 [S, T], KV f32 [S, D], Pos [S] int32. Each slot's row quantizes
-    at its own abs-max page scale; Out/OutScale alias Cache/Scale
-    (in-place on the persistable pair)."""
-    cache = ins['Cache'][0]
-    cscale = ins['Scale'][0]
-    kv = ins['KV'][0]
-    pos = ins['Pos'][0].reshape(-1).astype(jnp.int32)
-    q, s = _quantize_kv_rows(kv.astype(jnp.float32))
-
-    def upd(c, sc, qrow, srow, p):
-        c = jax.lax.dynamic_update_slice(c, qrow[None, :], (p, 0))
-        sc = jax.lax.dynamic_update_slice(sc, srow[None], (p,))
-        return c, sc
-
-    cache, cscale = jax.vmap(upd)(cache, cscale, q, s, pos)
-    return {'Out': [cache], 'OutScale': [cscale]}
-
-
-@register('kv_cache_prefill_write_quant', no_grad=True, lod='none')
-def _kv_cache_prefill_write_quant(ctx, ins):
-    """kv_cache_prefill_write over the int8 cache: KV [1, L, D] f32
-    quantizes per position (per slot-page) and blits into ONE slot of
-    Cache int8 [S, T, D] / Scale f32 [S, T]. Rows beyond the true
-    prompt length carry pad garbage the decode step overwrites before
-    any step attends them (the fp op's contract)."""
-    cache = ins['Cache'][0]
-    cscale = ins['Scale'][0]
-    kv = ins['KV'][0]
-    slot = ins['Slot'][0].reshape(-1).astype(jnp.int32)[0]
-    q, s = _quantize_kv_rows(kv.astype(jnp.float32))    # [1,L,D], [1,L]
-    cache = jax.lax.dynamic_update_slice(cache, q, (slot, 0, 0))
-    cscale = jax.lax.dynamic_update_slice(cscale, s, (slot, 0))
-    return {'Out': [cache], 'OutScale': [cscale]}
-
-
-@register('kv_cache_attention_quant', no_grad=True, lod='none')
-def _kv_cache_attention_quant(ctx, ins):
-    """kv_cache_attention over the int8 cache: dequantizes K/V INSIDE
-    the attention body (int8 row x its page scale), then runs the exact
-    fp masked-attention expression. Q stays f32; only cache STORAGE is
-    quantized, so transcripts track the fp-KV reference within the
-    per-page quantization step (~1/254 relative per row)."""
-    q = ins['Q'][0]
-    kc = ins['KCache'][0]
-    ks = ins['KScale'][0]
-    vc = ins['VCache'][0]
-    vs = ins['VScale'][0]
-    pos = ins['Pos'][0].reshape(-1).astype(jnp.int32)
-    kf = kc.astype(jnp.float32) * ks[:, :, None]
-    vf = vc.astype(jnp.float32) * vs[:, :, None]
-    return {'Out': [_paged_attention_body(ctx, q, kf, vf, pos)]}
 
 
 # ---------------------------------------------------------------------------
@@ -610,24 +511,24 @@ def _kv_cache_attention_quant(ctx, ins):
 # cache[table[p // bs], p % bs]. Tables are host state the scheduler
 # feeds every dispatch (inference/kv_blocks.py owns the refcounts), so
 # beam reorder is a table permutation + copy-on-write of the partial
-# tail block instead of a whole-slot-row gather, and requests with a
+# tail block, and requests with a
 # common prompt prefix SHARE the prefix's blocks. Physical block 0 is
 # the reserved trash block: idle/padded rows scatter there and no table
 # maps it into an attention window, so its (possibly write-racy, but
-# never read) bits cannot perturb any active slot — the same masked-
-# idle-slot determinism contract as the slot-paged ops above.
+# never read) bits cannot perturb any active slot — the masked-
+# idle-slot determinism contract.
 #
 # What reads the pool how (ISSUE 25). Every op here gathers a slot's
 # whole logical view [MAXB * BS, D] through _block_view and runs the
-# slot-paged jnp expression over it, masking rows past pos — on those
-# bodies block-paged equals slot-paged BIT FOR BIT, which the cpu tests
-# pin. ONE op has a second body: where a program is compiled for a TPU,
+# jnp expression above over it, masking rows past pos — on those bodies
+# a slot's output is the same BIT FOR BIT however its history is paged.
+# ONE op has a second body: where a program is compiled for a TPU,
 # the step's kv_block_attention is the Pallas kernel of
 # pallas_paged_attention.py, which copies pages 0 .. pos // BS of each
 # slot straight from the pool through its table row and never sees a
 # row past pos. Same function, float32 throughout, another summation
 # order (online softmax): on a TPU the step rounds differently from the
-# chunk / verify / _quant / slot-paged bodies, which keep the gathered
+# chunk / verify / _quant bodies, which keep the gathered
 # view and stay the reference the kernel is tested against. The choice
 # is made from what the lowering sees — the platform the program is
 # compiled for, the pool's dtype and page shape (ppa.supports), a trace
@@ -712,9 +613,9 @@ def _kv_block_write(ctx, ins):
 
 def _kv_block_attention_jnp(ctx, q, kc, vc, pos, table):
     """The reference body: gather each slot's logical view through its
-    table row, then the slot-paged op's masked attention — ONE
-    expression with kv_cache_attention, so on this body a slot's output
-    is bit-identical however its history is paged."""
+    table row, then _paged_attention_body's masked attention — on this
+    body a slot's output is bit-identical however its history is
+    paged."""
     kv_view = jax.vmap(lambda r: _block_view(kc, r))(table)  # [S, T', D]
     vv_view = jax.vmap(lambda r: _block_view(vc, r))(table)
     return _paged_attention_body(ctx, q, kv_view, vv_view, pos)
@@ -722,16 +623,17 @@ def _kv_block_attention_jnp(ctx, q, kc, vc, pos, table):
 
 @register('kv_block_attention', no_grad=True, lod='none')
 def _kv_block_attention(ctx, ins):
-    """kv_cache_attention over the block pool: Q [S, D], KCache/VCache
-    [NB, BS, D], Pos [S] int32, BlockTable [S, MAXB] int32. Each slot
+    """One-token-per-slot attention over the block pool: Q [S, D],
+    KCache/VCache [NB, BS, D], Pos [S] int32, BlockTable [S, MAXB] int32;
+    heads split inside the op (attr n_head). Each slot
     attends its own table's logical view rows j <= pos; rows beyond get
     exactly-zero weight, so foreign blocks and trash garbage can never
     perturb an active slot.
 
     Two bodies (the comment block above). The jnp one — every platform
     but a TPU, and on a TPU a pool the kernel cannot read or a sharded
-    trace — is the slot-paged op's expression over the gathered view,
-    bit-identical to kv_cache_attention. Compiled for a TPU, the Pallas
+    trace — is _paged_attention_body over the gathered view. Compiled
+    for a TPU, the Pallas
     kernel reads pages 0 .. pos // BS through the table instead."""
     from ..parallel.mesh import current_trace_mesh
     from . import pallas_paged_attention as ppa
@@ -887,9 +789,9 @@ def _kv_block_chunk_attention_quant(ctx, ins):
     """kv_block_chunk_attention over the int8 block pool. The CURRENT
     chunk's rows attend at FULL precision: K/V carry the fresh f32
     projections ([1, C, D], the same arrays the write op quantized) and
-    splice over the view's span [start, start + C) — the slot tier's
-    int8 prefill semantics (attend fresh f32, store int8), so a
-    single-chunk prompt is bit-identical to the slot tier. Earlier
+    splice over the view's span [start, start + C) — attend fresh f32,
+    store int8, so a single-chunk prompt's prefill sees no quantization
+    at all. Earlier
     chunks and shared prefix blocks exist only as int8 pages and
     dequantize — the unavoidable (and vLLM-standard) chunked-prefill
     quantization boundary."""
@@ -935,13 +837,11 @@ def _kv_block_chunk_attention_quant(ctx, ins):
 # rejected rows' cache garbage sits strictly above the attended
 # frontier and is overwritten by the next real write before any mask
 # ever admits it. Per-row positions encode the variable part inside the
-# fixed [S, R] shape: slot-layout pad rows carry pos = T (out-of-bounds
-# scatter rows DROP — no write at all), block-layout pad rows carry
-# pos = MAXB * BS (forced to the trash block by _block_scatter_idx's
-# span guard — pos = T would hit a SHARED full prefix block at offset
-# T % BS when T is not block-aligned). Either way an unfed row writes
-# nothing an attention mask can reach and its logits row is garbage the
-# host never reads.
+# fixed [S, R] shape: pad rows carry pos = MAXB * BS (forced to the
+# trash block by _block_scatter_idx's span guard — pos = T would hit a
+# SHARED full prefix block at offset T % BS when T is not
+# block-aligned). An unfed row writes nothing an attention mask can
+# reach and its logits row is garbage the host never reads.
 # ---------------------------------------------------------------------------
 
 def _verify_attention_body(ctx, q, kc, vc, pos):
@@ -968,78 +868,11 @@ def _verify_attention_body(ctx, q, kc, vc, pos):
     return ctxv.reshape(s, r, d).astype(q.dtype)
 
 
-@register('kv_cache_verify_write', no_grad=True, lod='none')
-def _kv_cache_verify_write(ctx, ins):
-    """Write R = K+1 speculative K or V rows per slot into the
-    slot-paged cache: Cache [S, T, D], KV [S, R, D], Pos [S, R] int32.
-    Row (s, i) scatters to cache[s, pos[s, i]]; pad rows carry
-    pos = T, an out-of-bounds scatter index XLA DROPS — a pad row
-    writes nothing. Real rows of one slot have distinct consecutive
-    positions, so indices never collide. Out aliases Cache."""
-    cache = ins['Cache'][0]
-    kv = ins['KV'][0]
-    pos = ins['Pos'][0].astype(jnp.int32)
-    s, r = pos.shape
-    sidx = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[:, None],
-                            (s, r)).reshape(-1)
-    pflat = pos.reshape(-1)
-    return {'Out': [cache.at[sidx, pflat].set(
-        kv.reshape(s * r, -1).astype(cache.dtype))]}
-
-
-@register('kv_cache_verify_attention', no_grad=True, lod='none')
-def _kv_cache_verify_attention(ctx, ins):
-    """Verify attention over the slot-paged cache: Q [S, R, D],
-    KCache/VCache [S, T, D], Pos [S, R] int32. Row i of a slot attends
-    its own cache rows j <= pos[s, i] — the speculative rows written
-    this dispatch included, so row i's window is exactly the plain
-    step's window after accepting the i drafted tokens before it."""
-    q = ins['Q'][0]
-    kc = ins['KCache'][0]
-    vc = ins['VCache'][0]
-    pos = ins['Pos'][0].astype(jnp.int32)
-    return {'Out': [_verify_attention_body(ctx, q, kc, vc, pos)]}
-
-
-@register('kv_cache_verify_write_quant', no_grad=True, lod='none')
-def _kv_cache_verify_write_quant(ctx, ins):
-    """kv_cache_verify_write over the int8 cache: each speculative row
-    quantizes at its own abs-max page scale (the write-time contract of
-    kv_cache_write_quant); pad rows (pos = T) drop both the row and its
-    scale scatter. Out/OutScale alias Cache/Scale."""
-    cache = ins['Cache'][0]
-    cscale = ins['Scale'][0]
-    kv = ins['KV'][0]
-    pos = ins['Pos'][0].astype(jnp.int32)
-    s, r = pos.shape
-    q, sc = _quantize_kv_rows(kv.astype(jnp.float32))   # [S,R,D], [S,R]
-    sidx = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[:, None],
-                            (s, r)).reshape(-1)
-    pflat = pos.reshape(-1)
-    return {'Out': [cache.at[sidx, pflat].set(q.reshape(s * r, -1))],
-            'OutScale': [cscale.at[sidx, pflat].set(sc.reshape(-1))]}
-
-
-@register('kv_cache_verify_attention_quant', no_grad=True, lod='none')
-def _kv_cache_verify_attention_quant(ctx, ins):
-    """kv_cache_verify_attention over the int8 cache: dequantize inside
-    the body (int8 row x its page scale), then the exact fp verify
-    expression."""
-    q = ins['Q'][0]
-    kc = ins['KCache'][0]
-    ks = ins['KScale'][0]
-    vc = ins['VCache'][0]
-    vs = ins['VScale'][0]
-    pos = ins['Pos'][0].astype(jnp.int32)
-    kf = kc.astype(jnp.float32) * ks[:, :, None]
-    vf = vc.astype(jnp.float32) * vs[:, :, None]
-    return {'Out': [_verify_attention_body(ctx, q, kf, vf, pos)]}
-
-
 @register('kv_block_verify_write', no_grad=True, lod='none')
 def _kv_block_verify_write(ctx, ins):
-    """kv_cache_verify_write over the BLOCK pool: Cache [NB, BS, D],
-    KV [S, R, D], Pos [S, R] int32, BlockTable [S, MAXB] int32. Each
+    """Write R = K+1 speculative K or V rows per slot into the block
+    pool: Cache [NB, BS, D], KV [S, R, D], Pos [S, R] int32, BlockTable
+    [S, MAXB] int32. Each
     slot's table broadcasts over its R rows; pad rows carry
     pos = MAXB * BS, which _block_scatter_idx forces to the trash block
     (colliding trash scatters are write-racy but never read — the
@@ -1061,9 +894,12 @@ def _kv_block_verify_write(ctx, ins):
 
 @register('kv_block_verify_attention', no_grad=True, lod='none')
 def _kv_block_verify_attention(ctx, ins):
-    """kv_cache_verify_attention over the block pool: per-slot logical
-    views gather through the table, then the shared verify body masks
-    row i at j <= pos[s, i]. Masked rows get exactly-zero weight, so
+    """Verify attention over the block pool: Q [S, R, D], Pos [S, R]
+    int32. Per-slot logical views gather through the table, then the
+    shared verify body masks row i at j <= pos[s, i] — the speculative
+    rows written this dispatch included, so row i's window is exactly
+    the plain step's window after accepting the i drafted tokens before
+    it. Masked rows get exactly-zero weight, so
     foreign blocks, trash garbage, and rejected speculative rows above
     a frontier can never perturb an accepted row's output."""
     q = ins['Q'][0]

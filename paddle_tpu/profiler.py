@@ -193,8 +193,8 @@ def serving_report():
                    s.get('expired', 0), s.get('p50_ms', 0.0),
                    s.get('p95_ms', 0.0), s.get('p99_ms', 0.0)))
     if decode_rows:
-        # block-cache columns render only when some source serves the
-        # block-paged layout; slot-layout-only fleets keep the old width
+        # block-cache columns render only when some source reports its
+        # pool (a DecodeStats not yet wired to one does not)
         blocks = any('blocks_in_use' in s for _, s in decode_rows)
         hdr = ("%-26s %5s %5s %6s %7s %8s %8s %6s %5s %5s %5s %6s %10s "
                "%10s %9s %9s" %

@@ -1,4 +1,4 @@
-"""The LOGITS a block-layout DecodingPredictor's programs compute for given
+"""The LOGITS a DecodingPredictor's programs compute for given
 prompts — chunked prefill, then greedy decode steps through the block
 cache — taken through the predictor's own dispatch functions (fetch 1 of
 the chunk and the step programs, asked for with `logits=True`; the
@@ -20,8 +20,6 @@ def served_logits(pred, prompts, n_new):
     (tokens, logits): per prompt n_new greedy tokens and the [n_new, vocab]
     float32 rows that chose them — row 0 from the prompt's last chunk
     (scoring position len(prompt)), row j from decode step j."""
-    if pred.layout != 'block':
-        raise ValueError('served_logits reads block-layout artifacts')
     if len(prompts) > pred.max_slots:
         raise ValueError('more prompts than slots')
     S, maxb, chunks = pred.max_slots, pred._maxb, pred._chunks

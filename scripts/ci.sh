@@ -65,7 +65,7 @@ JAX_PLATFORMS=cpu python bench.py
 echo "== serving bench smoke (serve.py bench on a tiny artifact) =="
 python scripts/serve_bench_smoke.py
 
-echo "== decode serving smoke (continuous in-flight batching: Poisson A/B >=3x tokens/s vs sequential decode, bit-identical transcripts, 0-compile warm replica; block tier: prefix-share A/B >=1.5x effective capacity at fixed cache HBM, beam reorder >=10x fewer dispatch bytes block-level, chunked prefill >=2x below the monolithic-prefill stall) =="
+echo "== decode serving smoke (continuous in-flight batching: Poisson A/B >=3x tokens/s vs sequential decode, bit-identical transcripts, 0-compile warm replica; block tier: prefix-share A/B >=1.5x effective capacity at fixed cache HBM, beam reorder >=10x fewer dispatch bytes block-level) =="
 JAX_PLATFORMS=cpu python scripts/decode_serve_smoke.py
 
 echo "== speculative decode smoke (draft-and-verify over the block-paged cache: bit-identical transcripts across plain/ngram/adversarial arms, >=1.5x tokens/s on the screened repetitive-suffix workload, zero-acceptance arm <=1.15x via acceptance-aware backoff) =="

@@ -22,11 +22,7 @@ Block-paged tier (ISSUE 13):
     must drop >= 1.5x (the effective-slot-capacity multiplier at fixed
     cache bytes), transcripts bit-identical to the no-sharing serve;
   * beam reorder measured BLOCK-level: copy-on-write dispatch bytes
-    must undercut the slot tier's whole-state reorder gathers >= 10x;
-  * chunked prefill: while a max-length prompt admits, the running
-    streams' worst inter-token gap must stay >= 2x below the measured
-    stall the slot tier's monolithic prefill inflicts, with the long
-    prompt's transcript bit-identical across both tiers.
+    must undercut a whole-state reorder gather >= 10x.
 Exits non-zero on any failed bar.
 """
 import json
@@ -65,7 +61,7 @@ def _export(art_dir, **kw):
     with fluid.scope_guard(scope), fluid.unique_name.guard():
         cfg = dict(vocab=VOCAB, d_model=16, n_head=2, n_layer=2,
                    d_ff=32, max_slots=SLOTS, max_cache_len=48,
-                   prompt_buckets=(4, 8), eos_id=1)
+                   chunk_sizes=(4, 8), block_size=16, eos_id=1)
         cfg.update(kw)
         spec = build_decode_spec(**cfg)
         exe = fluid.Executor(fluid.CPUPlace())
@@ -88,7 +84,7 @@ def _prefix_share_ab(d):
     workload with unique prefixes, on one block-paged artifact. Returns
     the result dict; raises AssertionError on a failed bar."""
     art = os.path.join(d, 'block_art')
-    _export(art, max_cache_len=64, block_size=8, prompt_buckets=(8, 16))
+    _export(art, max_cache_len=64, block_size=8, chunk_sizes=(8, 16))
     rng = np.random.RandomState(9)
     system = rng.randint(2, VOCAB, 32)           # 4 full blocks
     n = 16
@@ -150,9 +146,9 @@ def _prefix_share_ab(d):
         bsnap = pred.stats.snapshot()
     finally:
         pred.close()
-    # one slot-layout reorder gathers the WHOLE cache state (S rows x
-    # max_cache_len x d_model, K+V per layer); the block tier dispatches
-    # only the diverged blocks' copy pairs
+    # a reorder by whole-slot-row gather would move the WHOLE cache state
+    # (S rows x max_cache_len x d_model, K+V per layer); the block tier
+    # dispatches only the diverged blocks' copy pairs
     slot_bytes = bsnap['reorders'] * SLOTS * 64 * 16 * 4 * (2 * 2)
     cow_bytes = bsnap['cow_blocks'] * blk_bytes
     ratio = slot_bytes / max(cow_bytes, 1)
@@ -170,96 +166,6 @@ def _prefix_share_ab(d):
             'peak_blocks_unique': snap_u['blocks_peak'],
             'prefix_hits': snap_s['prefix_hits'],
             'reorder_bytes_x': round(ratio, 1)}
-
-
-def _chunked_prefill_itl(d):
-    """ISSUE 13 part C: p99 ITL of running streams while a max-length
-    prompt admits — chunked prefill (block tier) vs the monolithic
-    prefill stall (slot tier). Returns the result dict; raises
-    AssertionError on a failed bar."""
-    import threading
-    # big enough that the monolithic prefill stall is unmistakable on
-    # the CPU proxy (a 1000-token causal prefill at d_model 128), small
-    # enough to export in seconds
-    cfg = dict(d_model=128, n_head=8, n_layer=2, d_ff=256, max_slots=4,
-               max_cache_len=1088)
-    slot_art = os.path.join(d, 'itl_slot')
-    blk_art = os.path.join(d, 'itl_block')
-    _export(slot_art, prompt_buckets=(8, 1024), **cfg)
-    _export(blk_art, prompt_buckets=(8, 32), block_size=32, **cfg)
-    rng = np.random.RandomState(11)
-    bg_prompts = [rng.randint(2, VOCAB, 6) for _ in range(3)]
-    long_prompt = rng.randint(2, VOCAB, 1000)
-
-    def trial(art):
-        pred = DecodingPredictor(art)
-        try:
-            pred.warmup()
-            stamps = [[] for _ in bg_prompts]
-            threads = []
-            bgs = []
-            for p, ts in zip(bg_prompts, stamps):
-                s = pred.submit(p, max_new_tokens=160)
-                bgs.append(s)
-                t = threading.Thread(target=_consume, args=(s, ts),
-                                     daemon=True)
-                t.start()
-                threads.append(t)
-            while any(len(ts) < 12 for ts in stamps):
-                time.sleep(0.005)
-            t_admit = time.perf_counter()
-            long_s = pred.submit(long_prompt, max_new_tokens=8)
-            long_out = long_s.result(600)
-            t_done = time.perf_counter()
-            for t in threads:
-                t.join(300)
-            base, stall = [], 0.0
-            for ts in stamps:
-                gaps = np.diff([t for t in ts if t <= t_admit])
-                base.extend(gaps.tolist())
-                w = [t for t in ts if t_admit - 0.05 <= t <= t_done]
-                if len(w) >= 2:
-                    stall = max(stall, float(np.max(np.diff(w))))
-                # a stream that emitted NOTHING across the window
-                # stalled for the whole admission
-                inside = [t for t in ts if t_admit <= t <= t_done]
-                if not inside and ts and ts[-1] > t_done:
-                    stall = max(stall, t_done - t_admit)
-            return (long_out, float(np.percentile(base, 99)) * 1e3,
-                    stall * 1e3)
-        finally:
-            pred.close()
-
-    def run(art, trials=3):
-        # the stall statistic is a one-shot MAX gap: scheduler jitter,
-        # GC, or a slow consumer wakeup can only inflate it, never
-        # shrink it — so the MIN across trials is the tightest estimate
-        # of the true admission stall (and what the 2x bar compares)
-        outs, bases, stalls = [], [], []
-        for _ in range(trials):
-            o, b, s = trial(art)
-            outs.append(o)
-            bases.append(b)
-            stalls.append(s)
-        assert all(o == outs[0] for o in outs[1:]), \
-            'long-prompt transcript varied across trials'
-        return outs[0], float(np.median(bases)), float(min(stalls))
-
-    long_slot, base_slot, stall_slot = run(slot_art)
-    long_blk, base_blk, stall_blk = run(blk_art)
-    assert long_slot == long_blk, \
-        'chunked prefill changed the long prompt transcript'
-    print('chunked prefill: worst running-stream gap while a %d-token '
-          'prompt admits: slot %.1f ms (baseline itl p99 %.1f) vs '
-          'block %.1f ms (baseline %.1f)'
-          % (len(long_prompt), stall_slot, base_slot, stall_blk,
-             base_blk))
-    assert stall_slot >= 2.0 * stall_blk, \
-        'monolithic prefill stall %.1f ms not >= 2x chunked %.1f ms' \
-        % (stall_slot, stall_blk)
-    return {'stall_slot_ms': round(stall_slot, 1),
-            'stall_block_ms': round(stall_blk, 1),
-            'itl_p99_base_ms': round(base_blk, 1)}
 
 
 def main():
@@ -358,17 +264,14 @@ def main():
         # -- ISSUE 13: block-paged tier bars -----------------------------
         try:
             share = _prefix_share_ab(d)
-            itl = _chunked_prefill_itl(d)
         except AssertionError as e:
             print('FAIL: %s' % e, file=sys.stderr)
             return 1
-        print(json.dumps(dict(share, **itl)))
+        print(json.dumps(share))
         print('decode smoke OK: %.2fx tokens/s, bit-identical '
               'transcripts, 0 warm compiles; prefix share %.2fx '
-              'capacity, reorder bytes %.0fx down, chunked-prefill '
-              'stall %.1f -> %.1f ms'
-              % (speedup, share['capacity_x'], share['reorder_bytes_x'],
-                 itl['stall_slot_ms'], itl['stall_block_ms']))
+              'capacity, reorder bytes %.0fx down'
+              % (speedup, share['capacity_x'], share['reorder_bytes_x']))
     return 0
 
 

@@ -58,7 +58,8 @@ def _export_decode_artifact(art):
     with fluid.scope_guard(scope), fluid.unique_name.guard():
         spec = build_decode_spec(vocab=VOCAB, d_model=48, n_head=4,
                                  n_layer=2, d_ff=96, max_slots=4,
-                                 max_cache_len=128, prompt_buckets=(4, 8),
+                                 max_cache_len=128, chunk_sizes=(4, 8),
+                                 block_size=16,
                                  eos_id=1)
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(spec['startup'])

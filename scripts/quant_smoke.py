@@ -154,7 +154,8 @@ def _build_decode(kv, slots):
         # role is played by the fixed per-dispatch cost at serving batch)
         spec = build_decode_spec(vocab=251, d_model=32, n_head=4,
                                  n_layer=2, d_ff=64, max_slots=slots,
-                                 max_cache_len=48, prompt_buckets=(4, 8),
+                                 max_cache_len=48, chunk_sizes=(4, 8),
+                                 block_size=16,
                                  eos_id=1, kv_cache_dtype=kv)
         # seeded init: the transcript-agreement bar must measure the
         # quantization step, not a fresh weight draw per run

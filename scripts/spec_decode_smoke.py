@@ -70,7 +70,7 @@ def _export(art_dir):
     with fluid.scope_guard(scope), fluid.unique_name.guard():
         spec = build_decode_spec(
             vocab=VOCAB, d_model=16, n_head=2, n_layer=2, d_ff=32,
-            max_slots=SLOTS, max_cache_len=128, prompt_buckets=(8, 16),
+            max_slots=SLOTS, max_cache_len=128, chunk_sizes=(8, 16),
             block_size=8, eos_id=1, draft_k=K)
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(spec['startup'])

@@ -37,7 +37,7 @@ def main():
 
     with decoding.DecodingPredictor(artifact) as pred:
         vocab = pred._vocab
-        big = max(pred.prompt_buckets)
+        big = max(pred._chunks)
         rng = np.random.RandomState(seed)
         prompts = [rng.randint(2, vocab, rng.randint(2, big + 1))
                    for _ in range(n)]

@@ -39,7 +39,7 @@ def main():
 
     with decoding.DecodingPredictor(artifact, draft='ngram') as pred:
         vocab = pred._vocab
-        big = max(pred.prompt_buckets or [8])
+        big = max(pred._chunks)
         rng = np.random.RandomState(seed)
         # self-repetitive prompts so the n-gram drafter actually fires
         # (verify dispatches happen regardless of acceptance)

@@ -21,7 +21,7 @@ _OLMOE = dict(vocab=128, d_model=64, n_head=4, n_layer=2, n_expert=8,
               d_expert=32, top_k=2, max_slots=SLOTS, max_cache_len=64,
               block_size=8, chunk_sizes=(8, 16))
 # the output projection of each builder: [d_model, vocab]
-_HEAD = {'block': 'out_w', 'slot': 'out_w', 'olmoe': 'lm_head_w'}
+_HEAD = {'block': 'out_w', 'block_int8': 'out_w', 'olmoe': 'lm_head_w'}
 
 
 def _tie_head(w):
@@ -50,8 +50,9 @@ def _export(tmp, name, tie=False):
             spec = build_decode_spec(
                 vocab=VOCAB, d_model=32, n_head=4, n_layer=2, d_ff=64,
                 max_slots=SLOTS, max_cache_len=48, eos_id=1,
-                prompt_buckets=(8, 16), draft_k=K,
-                **({'block_size': 4} if name == 'block' else {}))
+                chunk_sizes=(8, 16), draft_k=K, block_size=4,
+                kv_cache_dtype='int8' if name == 'block_int8'
+                else 'float32')
         spec['startup'].random_seed = 11
         fluid.Executor(fluid.CPUPlace()).run(spec['startup'], scope=scope)
         if tie:
@@ -86,48 +87,35 @@ def _dispatches(pred, program):
     prefilled into slots 0..2, then the program under test."""
     S, vocab = pred.max_slots, pred._vocab
     prompts = _prompts(vocab)[:3]
-    block = pred.layout == 'block'
-    out = {'prefill': [], 'chunk': [], 'step': [], 'verify': []}
-    tables = None
-    if block:
-        maxb = pred._maxb
-        tables = np.full((S, maxb), pred._trash, np.int32)
-        for i in range(len(prompts)):
-            tables[i] = 1 + i * maxb + np.arange(maxb)
+    out = {'chunk': [], 'step': [], 'verify': []}
+    maxb = pred._maxb
+    tables = np.full((S, maxb), pred._trash, np.int32)
+    for i in range(len(prompts)):
+        tables[i] = 1 + i * maxb + np.arange(maxb)
     last = []
     for i, prompt in enumerate(prompts):
-        if block:
-            start = 0
-            while start < len(prompt):
-                left = len(prompt) - start
-                size = next((c for c in pred._chunks if c >= left),
-                            pred._chunks[-1])
-                take = min(size, left)
-                ids = np.zeros((1, size), np.int64)
-                ids[0, :take] = prompt[start:start + take]
-                tok, row = pred._dispatch_chunk(
-                    size, ids, start, take, tables[i:i + 1], logits=True)
-                out['chunk'].append((np.asarray([tok], np.int32), row[None]))
-                start += take
-        else:
-            bucket = decoding.select_bucket(pred._buckets, len(prompt))
-            padded = np.zeros((1, bucket), np.int64)
-            padded[0, :len(prompt)] = prompt
-            tok, row = pred._dispatch_prefill(bucket, padded, len(prompt),
-                                              i, logits=True)
-            out['prefill'].append((np.asarray([tok], np.int32), row[None]))
+        start = 0
+        while start < len(prompt):
+            left = len(prompt) - start
+            size = next((c for c in pred._chunks if c >= left),
+                        pred._chunks[-1])
+            take = min(size, left)
+            ids = np.zeros((1, size), np.int64)
+            ids[0, :take] = prompt[start:start + take]
+            tok, row = pred._dispatch_chunk(
+                size, ids, start, take, tables[i:i + 1], logits=True)
+            out['chunk'].append((np.asarray([tok], np.int32), row[None]))
+            start += take
         last.append(tok)
-    kw = {'tables': tables} if block else {}
     if program == 'verify':
         R = K + 1
         tok = np.zeros((S, R), np.int64)
-        pos = np.full((S, R), pred._maxb * pred._bs if block else pred._T,
-                      np.int32)
+        pos = np.full((S, R), pred._maxb * pred._bs, np.int32)
         for i, prompt in enumerate(prompts):
             tok[i] = [last[i], 7, 9, 11]
             pos[i] = len(prompt) + np.arange(R)
-        out['verify'].append(pred._dispatch_verify(tok, pos, logits=True,
-                                                   **kw))
+        out['verify'].append(pred._dispatch_verify(tok, pos, tables,
+                                                   logits=True))
     elif program == 'step':
         for j in range(3):
             tok = np.zeros((S, 1), np.int64)
@@ -135,7 +123,7 @@ def _dispatches(pred, program):
             for i, prompt in enumerate(prompts):
                 tok[i, 0] = last[i]
                 pos[i, 0] = len(prompt) + j
-            ids, logits = pred._dispatch_step(tok, pos, logits=True, **kw)
+            ids, logits = pred._dispatch_step(tok, pos, tables, logits=True)
             out['step'].append((ids, logits))
             last = ids.tolist()
     return out[program]
@@ -144,7 +132,8 @@ def _dispatches(pred, program):
 @pytest.mark.parametrize('tie', [False, True], ids=['seeded', 'tied'])
 @pytest.mark.parametrize('name,program', [
     ('block', 'step'), ('block', 'chunk'), ('block', 'verify'),
-    ('slot', 'step'), ('slot', 'prefill'), ('slot', 'verify'),
+    ('block_int8', 'step'), ('block_int8', 'chunk'),
+    ('block_int8', 'verify'),
     ('olmoe', 'step'), ('olmoe', 'chunk')])
 def test_ids_are_the_argmax_of_the_same_dispatch(arts, name, program, tie):
     with DecodingPredictor(arts(name, tie)) as pred:
@@ -163,13 +152,12 @@ def test_ids_are_the_argmax_of_the_same_dispatch(arts, name, program, tie):
             assert set(ids.reshape(-1).tolist()) <= {0, 2}
 
 
-@pytest.mark.parametrize('name', ['block', 'slot', 'olmoe'])
+@pytest.mark.parametrize('name', ['block', 'block_int8', 'olmoe'])
 def test_signature_names_both_fetches(arts, name):
     with open(os.path.join(arts(name), decoding._DECODE_SIGNATURE)) as f:
         sig = json.load(f)
     assert sig['version'] == decoding._SIG_VERSION == 5
-    entries = [sig['step']] + list(sig.get('chunk', {}).values()) \
-        + list(sig.get('prefill', {}).values()) \
+    entries = [sig['step']] + list(sig['chunk'].values()) \
         + ([sig['verify']] if 'verify' in sig else [])
     assert len(entries) >= 3
     for e in entries:
@@ -194,7 +182,7 @@ class _Copies(object):
         monkeypatch.setattr(decoding, '_span', span)
 
 
-@pytest.mark.parametrize('name', ['block', 'slot', 'olmoe'])
+@pytest.mark.parametrize('name', ['block', 'block_int8', 'olmoe'])
 def test_greedy_serving_copies_ids_only(arts, name, monkeypatch):
     copies = _Copies(monkeypatch)
     with DecodingPredictor(arts(name)) as pred:
@@ -208,18 +196,29 @@ def test_greedy_serving_copies_ids_only(arts, name, monkeypatch):
     assert {b for p, _, b in copies.seen if p != 'step'} == {4}
 
 
-# what the parent commit (PR 26: host argmax over the copied logits) served
-# for these requests on this spec
-_PARENT_GREEDY = [[80, 80, 80, 81, 54, 81, 54, 80],
-                  [88, 60, 83, 81, 88, 60, 81, 88]]
-_PARENT_BEAM_IDS = [[54, 81, 88, 60, 81, 81, 81, 81],
-                    [54, 81, 88, 60, 83, 81, 81, 81]]
-_PARENT_BEAM_SCORES = [-25.31855396037914, -25.322833602904396]
-_PARENT_SPEC = [[81, 88, 23, 54, 82, 65, 81, 81, 88, 54, 62, 88],
-                [81, 88, 54, 81, 81, 54, 81, 88, 54, 81, 88, 60]]
+# what the parent commit served for these requests on this spec: 'block' at
+# PR 26 (host argmax over the copied logits), 'block_int8' at PR 27 (its
+# block artifact; nothing checked the quantized programs' ids before)
+_PARENT_GREEDY = {
+    'block': [[80, 80, 80, 81, 54, 81, 54, 80],
+              [88, 60, 83, 81, 88, 60, 81, 88]],
+    'block_int8': [[25, 42, 42, 42, 23, 42, 23, 42], [7, 91, 91, 1]]}
+_PARENT_BEAM_IDS = {
+    'block': [[54, 81, 88, 60, 81, 81, 81, 81],
+              [54, 81, 88, 60, 83, 81, 81, 81]],
+    'block_int8': [[96, 52, 2, 2, 2, 2, 2, 2],
+                   [96, 7, 2, 2, 2, 2, 2, 2]]}
+_PARENT_BEAM_SCORES = {
+    'block': [-25.31855396037914, -25.322833602904396],
+    'block_int8': [-24.096582432523846, -24.30078494080831]}
+_PARENT_SPEC = {
+    'block': [[81, 88, 23, 54, 82, 65, 81, 81, 88, 54, 62, 88],
+              [81, 88, 54, 81, 81, 54, 81, 88, 54, 81, 88, 60]],
+    'block_int8': [[2, 2, 2, 2, 37, 51, 2, 2, 2, 37, 2, 2],
+                   [2, 52, 2, 37, 51, 2, 96, 96, 96, 96, 96, 96]]}
 
 
-@pytest.mark.parametrize('name', ['block', 'slot'])
+@pytest.mark.parametrize('name', ['block', 'block_int8'])
 def test_a_live_beam_fetches_logits_and_serves_the_parents_beam(
         arts, name, monkeypatch):
     copies = _Copies(monkeypatch)
@@ -231,9 +230,9 @@ def test_a_live_beam_fetches_logits_and_serves_the_parents_beam(
         ids, scores = beam.result(120)
         greedy = [[int(t) for t in s.result(120)] for s in (g1, g2)]
         snap = pred.stats.snapshot()
-    assert np.asarray(ids).tolist() == _PARENT_BEAM_IDS
-    assert [float(x) for x in scores] == _PARENT_BEAM_SCORES
-    assert greedy == _PARENT_GREEDY
+    assert np.asarray(ids).tolist() == _PARENT_BEAM_IDS[name]
+    assert [float(x) for x in scores] == _PARENT_BEAM_SCORES[name]
+    assert greedy == _PARENT_GREEDY[name]
     # the beam's last prompt slice and every step it rode copied logits
     # (ids beside them); the greedy requests' prompts copied ids
     logits = [(p, b) for p, f, b in copies.seen if f == 'logits']
@@ -243,7 +242,7 @@ def test_a_live_beam_fetches_logits_and_serves_the_parents_beam(
     assert len(logits) < len(copies.seen)
 
 
-@pytest.mark.parametrize('name', ['block', 'slot'])
+@pytest.mark.parametrize('name', ['block', 'block_int8'])
 def test_speculative_serving_reads_the_verify_ids(arts, name, monkeypatch):
     copies = _Copies(monkeypatch)
     prompts = _prompts(VOCAB)
@@ -252,7 +251,7 @@ def test_speculative_serving_reads_the_verify_ids(arts, name, monkeypatch):
                 pred.submit(p, max_new_tokens=12).result(120)]
                for p in (np.tile(prompts[1][:4], 4), prompts[3])]
         snap = pred.stats.snapshot()
-    assert got == _PARENT_SPEC
+    assert got == _PARENT_SPEC[name]
     assert snap['verify_steps'] > 0 and snap['logits_fetches'] == 0
     assert {(f, b) for p, f, b in copies.seen if p == 'verify'} \
         == {('ids', SLOTS * (K + 1) * 4)}

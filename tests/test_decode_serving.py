@@ -16,13 +16,14 @@ import paddle_tpu as fluid
 from paddle_tpu.inference import (DecodingPredictor, export_decode,
                                   ServerOverloaded, DeadlineExceeded)
 
-VOCAB, SLOTS, CACHE, BUCKETS = 37, 4, 64, (4, 8)
+VOCAB, SLOTS, CACHE, CHUNKS, BLOCK = 37, 4, 64, (4, 8), 4
 
 
 @pytest.fixture(scope='module')
 def artifact(tmp_path_factory):
     """One tiny decoder-LM artifact per module: 2 layers, 4 slots,
-    prompt buckets (4, 8), AOT sidecars on (export default)."""
+    prefill chunks (4, 8), pages of 4 rows, AOT sidecars on (export
+    default)."""
     from models.transformer import build_decode_spec
     out = str(tmp_path_factory.mktemp('decode') / 'art')
     main, startup = fluid.Program(), fluid.Program()
@@ -34,7 +35,7 @@ def artifact(tmp_path_factory):
             spec = build_decode_spec(
                 vocab=VOCAB, d_model=16, n_head=2, n_layer=2, d_ff=32,
                 max_slots=SLOTS, max_cache_len=CACHE,
-                prompt_buckets=BUCKETS, eos_id=1)
+                chunk_sizes=CHUNKS, block_size=BLOCK, eos_id=1)
             exe = fluid.Executor(fluid.CPUPlace())
             exe.run(spec['startup'])
             export_decode(spec, out, scope=scope)
@@ -56,12 +57,14 @@ def test_artifact_layout(artifact):
         sig = json.load(f)
     assert sig['kind'] == 'decode'
     assert sig['max_slots'] == SLOTS
-    assert sig['prompt_buckets'] == sorted(BUCKETS)
+    assert sig['layout'] == 'block'
+    assert sig['chunk_buckets'] == sorted(CHUNKS)
     assert len(sig['state']) == 4  # 2 layers x K/V
     for e in sig['state']:
-        assert e['shape'][:2] == [SLOTS, CACHE]
-    for d in ([decoding._STEP_DIR, decoding._REORDER_DIR] +
-              [decoding._PREFILL_DIR % b for b in BUCKETS]):
+        assert e['shape'][:2] == [SLOTS * (CACHE // BLOCK) + 1, BLOCK]
+    for d in ([decoding._STEP_DIR, decoding._ZEROS_DIR,
+               decoding._BLOCKCOPY_DIR] +
+              [decoding._CHUNK_DIR % c for c in CHUNKS]):
         assert os.path.exists(os.path.join(artifact, d, 'module.jaxexport'))
         # export-time AOT warm-start sidecar per program
         assert os.path.exists(os.path.join(artifact, d, 'aot_cpu.jaxexec'))
@@ -133,8 +136,8 @@ def test_token_streaming(artifact):
 
 
 def test_prefill_step_cache_consistency(artifact):
-    """Teacher-forcing the generated tokens back through the (bucketed)
-    prefill program reproduces the decode-step choices: the two programs
+    """Teacher-forcing the generated tokens back through the chunked
+    prefill programs reproduces the decode-step choices: the two programs
     agree on the cache contents."""
     prompt = _prompts(15, 1)[0][:3]
     with DecodingPredictor(artifact) as pred:
@@ -157,13 +160,24 @@ def test_deadline_expires_in_queue(artifact):
 def test_deadline_expiry_mid_decode_frees_slot(artifact):
     """A deadline elapsing DURING decode resolves the stream with
     DeadlineExceeded at the next step boundary and frees the slot —
-    follow-up traffic is unaffected."""
+    follow-up traffic is unaffected. Counted, not timed: the deadline
+    moves into the past on the scheduler's own thread at the first tick
+    that finds three tokens emitted."""
     prompts = _prompts(17, 3)
     with DecodingPredictor(artifact) as pred:
         want = pred.generate(prompts[1], max_new_tokens=5)
-        s = pred.submit(prompts[0], max_new_tokens=57, deadline_ms=3.0)
-        with pytest.raises(DeadlineExceeded):
+        run_tick = pred._run_tick
+
+        def tick(waiting):
+            for req in pred._active_requests():
+                if req.produced >= 3:
+                    req.deadline = 0.0
+            run_tick(waiting)
+        pred._run_tick = tick
+        s = pred.submit(prompts[0], max_new_tokens=57, deadline_ms=3.6e6)
+        with pytest.raises(DeadlineExceeded, match='after 3 token'):
             s.result(120)
+        pred._run_tick = run_tick
         assert pred.stats.snapshot()['expired'] == 1
         # every slot is free again and serving continues bit-identically
         assert pred._free_slots() == list(range(SLOTS))
@@ -192,8 +206,9 @@ def test_submit_validation(artifact):
     with DecodingPredictor(artifact) as pred:
         with pytest.raises(ValueError):
             pred.submit([], max_new_tokens=4).result(10)
-        with pytest.raises(ValueError):  # longer than the largest bucket
-            pred.submit(np.arange(2, 12), max_new_tokens=4).result(10)
+        with pytest.raises(ValueError):  # longer than the cache
+            pred.submit(np.arange(2, CACHE + 3) % VOCAB,
+                        max_new_tokens=4).result(10)
         with pytest.raises(ValueError):  # beam wider than the slot pool
             pred.submit([3, 4], beam=SLOTS + 1).result(10)
     with pytest.raises(RuntimeError):
@@ -233,7 +248,7 @@ def test_warm_fresh_subprocess_zero_compiles(artifact):
     assert payload['compiles'] == 0, payload
     # replicate the worker's prompts in-process and compare transcripts
     rng = np.random.RandomState(23)
-    prompts = [rng.randint(2, VOCAB, rng.randint(2, max(BUCKETS) + 1))
+    prompts = [rng.randint(2, VOCAB, rng.randint(2, max(CHUNKS) + 1))
                for _ in range(5)]
     with DecodingPredictor(artifact) as pred:
         want = [pred.submit(p, max_new_tokens=7) for p in prompts]

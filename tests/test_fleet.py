@@ -83,26 +83,7 @@ def decode_art(tmp_path_factory):
     with fluid.scope_guard(scope), fluid.unique_name.guard():
         spec = build_decode_spec(vocab=VOCAB, d_model=8, n_head=2,
                                  n_layer=1, d_ff=16, max_slots=4,
-                                 max_cache_len=40, prompt_buckets=(4,),
-                                 eos_id=1)
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(spec['startup'])
-        export_decode(spec, art, scope=scope)
-    return art
-
-
-@pytest.fixture(scope='module')
-def block_art(tmp_path_factory):
-    """Block-paged decode artifact (ISSUE 13): same model as decode_art
-    but with the cache as a block pool + chunked prefill."""
-    tmp = str(tmp_path_factory.mktemp('fleet_block'))
-    art = os.path.join(tmp, 'block')
-    from models.transformer import build_decode_spec
-    scope = fluid.core.Scope()
-    with fluid.scope_guard(scope), fluid.unique_name.guard():
-        spec = build_decode_spec(vocab=VOCAB, d_model=8, n_head=2,
-                                 n_layer=1, d_ff=16, max_slots=4,
-                                 max_cache_len=40, prompt_buckets=(4,),
+                                 max_cache_len=40, chunk_sizes=(4,),
                                  eos_id=1, block_size=4)
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(spec['startup'])
@@ -460,21 +441,21 @@ def test_mid_stream_eviction_is_not_requeueable():
     assert not fw._stream_requeueable(RuntimeError('dispatch failed'))
 
 
-def test_fleet_block_paged_artifact_unchanged_protocol(block_art):
+def test_fleet_block_paged_artifact_unchanged_protocol(decode_art):
     """ISSUE 13: a block-paged decode artifact routes through
     FleetRouter/fleet_worker UNCHANGED — detect_kind sees the decode
-    signature, the worker's DecodingPredictor reads the layout, and
-    transcripts stay bit-identical to a direct in-process serve. The
-    hello frame surfaces layout='block' so fleet_ctl can audit the
-    tier, and replica heartbeats carry the block-cache gauges."""
+    signature and transcripts stay bit-identical to a direct in-process
+    serve. The hello frame surfaces the mesh tag (None here) so
+    fleet_ctl can audit the tier, and replica heartbeats carry the
+    block-cache gauges."""
     prompts = _prompts(12, seed=21)
-    with DecodingPredictor(block_art, platform='cpu') as ref:
-        assert ref.layout == 'block' and ref.mesh_tag is None
+    with DecodingPredictor(decode_art, platform='cpu') as ref:
+        assert ref.block_manager is not None and ref.mesh_tag is None
         want = [ref.generate(p, max_new_tokens=12) for p in prompts]
         want_beam = ref.generate(prompts[0], max_new_tokens=8, beam=3)
     with warnings.catch_warnings():
         warnings.simplefilter('ignore')
-        with _patient(FleetRouter(block_art, replicas=2,
+        with _patient(FleetRouter(decode_art, replicas=2,
                                   platform='cpu')) as router:
             assert router.kind == 'decoding'
             futs = [router.submit(p, max_new_tokens=12)
@@ -487,7 +468,7 @@ def test_fleet_block_paged_artifact_unchanged_protocol(block_art):
             np.testing.assert_array_equal(scores, want_beam[1])
             st = router.status()
             for s in st['replicas'].values():
-                assert s['layout'] == 'block'
+                assert 'layout' not in s
                 assert s['mesh'] is None
             # worker heartbeats surface the block-cache gauges
             # (serving_report's columns work fleet-wide)

@@ -72,7 +72,8 @@ def decode_art(tmp_path_factory):
     with fluid.scope_guard(scope), fluid.unique_name.guard():
         spec = build_decode_spec(vocab=VOCAB, d_model=8, n_head=2,
                                  n_layer=1, d_ff=16, max_slots=4,
-                                 max_cache_len=40, prompt_buckets=(4,),
+                                 max_cache_len=40, chunk_sizes=(4,),
+                                 block_size=4,
                                  eos_id=1)
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(spec['startup'])
@@ -435,23 +436,35 @@ def test_deadline_expires_mid_decode_slot_freed(direct_pred):
     """Site 3: the budget survives admission + first tokens but not the
     full decode — DeadlineExceeded names the mid-decode site and the
     request id, the slot frees, the expired counter increments, and
-    follow-up traffic is unaffected."""
+    follow-up traffic is unaffected. Counted, not timed: the request's
+    deadline moves into the past on the scheduler's own thread at the
+    first tick that finds five of its tokens emitted."""
     prompt = _prompts(4, seed=13)[0]
-    t0 = time.perf_counter()
     want = [int(t) for t in
             direct_pred.submit(prompt, max_new_tokens=30).result(300)]
-    full_ms = (time.perf_counter() - t0) * 1e3
+    assert len(want) > 5
     before = direct_pred.stats.snapshot()['expired']
+    run_tick = direct_pred._run_tick
+
+    def tick(waiting):
+        for req in direct_pred._active_requests():
+            if req.request_id == 'mid-1' and req.produced >= 5:
+                req.deadline = 0.0
+        run_tick(waiting)
+    direct_pred._run_tick = tick
     with Gateway(direct_pred) as gw:
-        code, _, raw = _req(gw.url, '/v1/decode',
-                            {'prompt': [int(p) for p in prompt],
-                             'max_new_tokens': 30,
-                             'deadline_ms': full_ms * 0.4},
-                            rid='mid-1')
+        try:
+            code, _, raw = _req(gw.url, '/v1/decode',
+                                {'prompt': [int(p) for p in prompt],
+                                 'max_new_tokens': 30,
+                                 'deadline_ms': 3.6e6},
+                                rid='mid-1')
+        finally:
+            direct_pred._run_tick = run_tick
         toks, done, err = _sse_tokens(raw)
-        assert done is None
+        assert done is None and toks == want[:5]
         assert err is not None and err['code'] == 504
-        assert 'mid-decode' in err['error']
+        assert 'mid-decode after 5 token' in err['error']
         assert '(request mid-1)' in err['error']
         assert err['request_id'] == 'mid-1'
         assert direct_pred.stats.snapshot()['expired'] == before + 1

@@ -1,9 +1,10 @@
 """Block-granular KV-cache management (ISSUE 13): BlockManager edge
 cases — refcount-to-zero frees, copy-on-write ownership, prefix-hash
 collision safety, LRU eviction under pressure — plus the served block
-tier: bit-identity with the slot layout (greedy, beam, chunked prefill,
-int8 pages), CoW under beam divergence at block boundaries, and prefix
-sharing's capacity effect."""
+tier: what the parent's block artifacts served (greedy, beam, chunked
+prefill, int8 pages), the float tier's logits against the plain
+reference's full forward pass, CoW under beam divergence at block
+boundaries, and prefix sharing's capacity effect."""
 import json
 import os
 
@@ -193,32 +194,40 @@ def test_doomed_alloc_does_not_wipe_prefix_cache():
 
 # -- served block tier -------------------------------------------------------
 
-def _build(tmp, **kw):
+_MODEL = dict(vocab=VOCAB, d_model=16, n_head=2, n_layer=2, d_ff=32)
+
+
+def _build(tmp, weights=None, **kw):
+    """Export one artifact; `weights` (a dict) receives the scope's
+    parameters by name, for the plain reference."""
     from models.transformer import build_decode_spec
     scope = fluid.core.Scope()
     with fluid.scope_guard(scope), fluid.unique_name.guard():
         spec = build_decode_spec(
-            vocab=VOCAB, d_model=16, n_head=2, n_layer=2, d_ff=32,
-            max_slots=SLOTS, max_cache_len=CACHE, eos_id=1, **kw)
+            max_slots=SLOTS, max_cache_len=CACHE, eos_id=1,
+            **dict(_MODEL, **kw))
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(spec['startup'])
+        if weights is not None:
+            weights.update((n, np.asarray(scope.get(n)))
+                           for n in scope.local_var_names()
+                           if n not in spec['cache_vars'])
         export_decode(spec, tmp, scope=scope)
     return tmp
 
 
 @pytest.fixture(scope='module')
 def arts(tmp_path_factory):
-    """Slot/block artifact pairs (f32 and int8 tiers) of the same tiny
-    LM: the slot tier is the bit-identity reference."""
+    """The f32 and the int8 tier of the same tiny LM, and the f32
+    tier's weights by name (the plain reference's input)."""
     t = tmp_path_factory.mktemp('kvblocks')
+    weights = {}
     return {
-        'slot': _build(str(t / 'slot'), prompt_buckets=(4, 8)),
-        'block': _build(str(t / 'block'), prompt_buckets=(4, 8),
-                        block_size=4),
-        'slot8': _build(str(t / 'slot8'), prompt_buckets=(4, 8),
-                        kv_cache_dtype='int8'),
-        'block8': _build(str(t / 'block8'), prompt_buckets=(4, 8),
+        'block': _build(str(t / 'block'), weights=weights,
+                        chunk_sizes=(4, 8), block_size=4),
+        'block8': _build(str(t / 'block8'), chunk_sizes=(4, 8),
                          block_size=4, kv_cache_dtype='int8'),
+        'weights': weights,
     }
 
 
@@ -226,6 +235,57 @@ def _prompts(seed, n, lo=2):
     rng = np.random.RandomState(seed)
     return [rng.randint(lo, VOCAB, int(rng.randint(2, 9)))
             for _ in range(n)]
+
+
+# What the PARENT commit's block artifacts (PR 27, the last tree with a
+# slot tier, where each of these equalled the slot tier bit for bit)
+# served for the requests below: greedy transcripts, and per beam request
+# (ids [beam, n], scores [beam]).
+_PARENT_GREEDY = [[40, 40, 40, 40, 40, 40, 40, 40, 40, 40],
+                  [29, 29, 17, 16, 16, 40, 16, 40, 16, 16],
+                  [23, 40, 23, 23, 16, 40, 40, 40, 40, 23],
+                  [40, 40, 40, 23, 40, 40, 40, 40, 40, 40],
+                  [29, 29, 29, 29, 29, 29, 29, 29, 29, 29],
+                  [29, 16, 16, 16, 16, 16, 16, 16, 16, 16],
+                  [16, 16, 16, 16, 16, 16, 16, 16, 16, 16],
+                  [17, 40, 40, 40, 40, 40, 23, 40, 40, 40]]
+_PARENT_BEAM = [([[40, 40, 40, 40, 40, 40, 40, 40],
+                  [10, 40, 40, 40, 40, 40, 40, 40],
+                  [40, 40, 40, 40, 40, 23, 40, 40]],
+                 [-12.456114752830457, -13.189863539932261, -13.579984728514328]),
+                ([[1, 1, 1, 1, 1, 1, 1, 1],
+                  [16, 40, 16, 16, 16, 16, 40, 23],
+                  [16, 40, 16, 16, 16, 16, 40, 16]],
+                 [-2.932869733489811, -17.69957593421799, -17.77937570552689]),
+                ([[23, 40, 23, 23, 16, 40, 40, 40],
+                  [23, 40, 23, 16, 40, 23, 40, 40],
+                  [23, 40, 23, 16, 40, 40, 40, 40]],
+                 [-17.11012598352386, -17.181376874652884, -17.47598545129889])]
+_PARENT_COW = [([[16, 16, 16, 16, 16, 16, 16, 16, 16, 16],
+                 [16, 16, 16, 16, 16, 16, 16, 16, 16, 23],
+                 [16, 16, 16, 16, 16, 16, 16, 16, 23, 16]],
+                [-20.296402014762137, -20.41649977114794, -20.572828420454236]),
+               ([[40, 23, 40, 23, 40, 40, 40, 40, 23, 23],
+                 [40, 23, 40, 23, 40, 40, 40, 23, 11, 26],
+                 [40, 40, 23, 40, 40, 40, 40, 23, 11, 26]],
+                [-20.952905869109905, -20.985735012385927, -20.993592360285092])]
+_PARENT_INT8_GREEDY = [[20, 20, 20, 20, 20, 20, 20, 20, 20, 20],
+                       [31, 23, 18, 18, 31, 31, 31, 31, 31, 31],
+                       [20, 20, 20, 20, 20, 20, 20, 20, 20, 20],
+                       [27, 18, 20, 21, 31, 31, 31, 31, 20, 20],
+                       [18, 31, 23, 7, 21, 31, 20, 20, 20, 20],
+                       [7, 31, 20, 20, 20, 20, 20, 20, 20, 20]]
+_PARENT_INT8_BEAM = [([[20, 20, 20, 20, 20, 20, 20, 20],
+                       [31, 20, 20, 20, 20, 20, 20, 20],
+                       [20, 20, 20, 20, 20, 20, 20, 31]],
+                      [-17.066645254289973, -17.794964544832048, -17.975224365389217])]
+
+
+def _assert_beams(got, want):
+    assert len(got) == len(want)
+    for (ids, scores), (want_ids, want_scores) in zip(got, want):
+        np.testing.assert_array_equal(ids, want_ids)
+        np.testing.assert_array_equal(scores, want_scores)
 
 
 def test_block_artifact_layout(arts):
@@ -240,7 +300,7 @@ def test_block_artifact_layout(arts):
     assert blk['num_blocks'] == SLOTS * (CACHE // 4) + 1
     for e in sig['state']:
         assert e['shape'][:2] == [blk['num_blocks'], 4]
-    for d in ([decoding._STEP_DIR, decoding._REORDER_DIR,
+    for d in ([decoding._STEP_DIR, decoding._ZEROS_DIR,
                decoding._BLOCKCOPY_DIR] +
               [decoding._CHUNK_DIR % c for c in sig['chunk_buckets']]):
         assert os.path.exists(os.path.join(arts['block'], d,
@@ -249,42 +309,128 @@ def test_block_artifact_layout(arts):
                                            'aot_cpu.jaxexec'))
 
 
-def test_block_greedy_and_beam_bit_identical_to_slot(arts):
+# -- one cache layout --------------------------------------------------------
+
+def test_op_registry_holds_the_block_ops_alone():
+    """12 = {fp, quant} x {step, chunk, verify} x {write, attention}, and
+    no op or layer of the slot tier."""
+    from paddle_tpu.core import registry
+    kv = sorted(n for n in registry.registered_ops() if n.startswith('kv_'))
+    assert len(kv) == 12 and all(n.startswith('kv_block_') for n in kv)
+    assert not [n for n in dir(fluid.layers) if n.startswith('kv_cache_')]
+
+
+def test_builder_default_is_a_pool_of_16_row_pages():
+    from models.transformer import build_decode_spec
+    with fluid.scope_guard(fluid.core.Scope()), fluid.unique_name.guard():
+        spec = build_decode_spec()
+    assert spec['block_size'] == 16 and 'layout' not in spec
+    maxb = spec['max_blocks_per_slot']
+    assert maxb == -(-spec['max_cache_len'] // 16)
+    assert spec['num_blocks'] == spec['max_slots'] * maxb + 1
+    assert sorted(spec['chunk']) == [8, 16] and 'prefill' not in spec
+    pool = spec['step']['program'].global_block().var('kv_k_0')
+    assert list(pool.shape)[:2] == [spec['num_blocks'], 16]
+    with pytest.raises(TypeError):
+        build_decode_spec(prompt_buckets=(8, 16))
+
+
+@pytest.mark.parametrize('layout', [None, 'slot'])
+def test_a_slot_artifact_is_refused_by_name(arts, tmp_path, layout):
+    """A signature without 'layout' is what the slot tier wrote."""
+    import shutil
+    from paddle_tpu.inference import decoding
+    art = str(tmp_path / 'slot')
+    shutil.copytree(arts['block'], art)
+    path = os.path.join(art, decoding._DECODE_SIGNATURE)
+    with open(path) as f:
+        sig = json.load(f)
+    if layout is None:
+        del sig['layout']
+    else:
+        sig['layout'] = layout
+    with open(path, 'w') as f:
+        json.dump(sig, f)
+    with pytest.raises(ValueError, match='slot-layout artifacts are no '
+                                         'longer served; export again'):
+        DecodingPredictor(art)
+    with pytest.raises(ValueError, match='no longer served'):
+        decoding.precompile_decode_artifact(art)
+
+
+def test_no_reorder_program_and_a_parents_artifact_still_loads(arts,
+                                                               tmp_path):
+    """The block scheduler never dispatched decode_reorder/: an artifact
+    holds none, and one the parent exported — the same programs plus that
+    directory — loads (the directory ignored) and serves the same
+    transcripts and beams."""
+    import shutil
+    assert not os.path.exists(os.path.join(arts['block'], 'decode_reorder'))
+    assert sorted(d for d in os.listdir(arts['block'])
+                  if os.path.isdir(os.path.join(arts['block'], d))) == [
+        'decode_blockcopy', 'decode_step', 'decode_zeros',
+        'prefill_chunk_00004', 'prefill_chunk_00008']
+    old = str(tmp_path / 'parent')
+    shutil.copytree(arts['block'], old)
+    shutil.copytree(os.path.join(old, 'decode_blockcopy'),
+                    os.path.join(old, 'decode_reorder'))
     prompts = _prompts(31, 8)
-    with DecodingPredictor(arts['slot']) as ps:
-        g_ref = [ps.generate(p, max_new_tokens=10) for p in prompts]
-        b_ref = [ps.generate(p, max_new_tokens=8, beam=3)
-                 for p in prompts[:3]]
+    with DecodingPredictor(old) as pb:
+        assert not hasattr(pb, '_reorder_mod')
+        g = [pb.generate(p, max_new_tokens=10) for p in prompts]
+        b = [pb.generate(p, max_new_tokens=8, beam=3) for p in prompts[:3]]
+    assert g == _PARENT_GREEDY
+    _assert_beams(b, _PARENT_BEAM)
+
+
+def test_block_greedy_and_beam_are_the_parents(arts):
+    prompts = _prompts(31, 8)
     with DecodingPredictor(arts['block']) as pb:
-        assert pb.layout == 'block'
         g = [pb.generate(p, max_new_tokens=10) for p in prompts]
         b = [pb.generate(p, max_new_tokens=8, beam=3)
              for p in prompts[:3]]
         snap = pb.stats.snapshot()
-    assert g == g_ref
-    for (i1, s1), (i2, s2) in zip(b_ref, b):
-        np.testing.assert_array_equal(i1, i2)
-        np.testing.assert_array_equal(s1, s2)
+    assert g == _PARENT_GREEDY
+    _assert_beams(b, _PARENT_BEAM)
     # beam history moves were table permutations + block CoW — and the
-    # copies dispatched blocks, not slot rows
+    # copies dispatched blocks
     assert snap['cow_blocks'] > 0
     assert snap['blockcopies'] <= snap['cow_blocks']
 
 
-def test_block_int8_pages_bit_identical_to_slot_int8(arts):
+def test_block_logits_match_the_plain_reference(arts):
+    """An independent reference, which a second cache layout never was:
+    chunked prefill (prompts longer than the largest chunk among them)
+    and paged decode give the LOGITS of benchmark/reference/decoder_lm.py's
+    full forward pass over the same tokens, to float32 summation order."""
+    from benchmark.reference import decoder_lm
+    from paddle_tpu.testing.decode_logits import served_logits
+    rng = np.random.RandomState(38)
+    prompts = [rng.randint(2, VOCAB, n) for n in (3, 8, 13, 23)]
+    n_new = 10
+    with DecodingPredictor(arts['block']) as pb:
+        tokens, logits = served_logits(pb, prompts, n_new)
+    for prompt, toks, rows in zip(prompts, tokens, logits):
+        seq = np.concatenate([prompt, toks[:-1]])
+        ref = np.asarray(decoder_lm.logits(
+            arts['weights'], seq, n_head=_MODEL['n_head'],
+            n_layer=_MODEL['n_layer']))[len(prompt) - 1:]
+        assert ref.shape == rows.shape == (n_new, VOCAB)
+        np.testing.assert_allclose(rows, ref, rtol=0, atol=2e-5)
+
+
+def test_block_int8_pages_serve_the_parents_transcripts(arts):
     """int8 KV pages compose with block paging (round-14 x ISSUE 13):
     per-page scales ride the pool and, with a COLD prefix cache,
-    transcripts AND beam scores match the int8 slot tier exactly (the
-    chunk op attends the current chunk's fresh f32 rows — the slot
-    tier's int8 prefill semantics). Once prefix sharing engages, a hit
+    transcripts AND beam scores are exactly the parent's (the chunk op
+    attends the current chunk's fresh f32 rows — attend f32, store
+    int8). Once prefix sharing engages, a hit
     attends the covered span via its int8 pages where a cold prefill
     recomputes it at f32: token ids stay identical, scores track within
     the quantization step — the (vLLM-standard) int8 prefix-cache
     boundary."""
     prompts = _prompts(32, 6)
-    with DecodingPredictor(arts['slot8']) as ps:
-        ref = [ps.generate(p, max_new_tokens=10) for p in prompts]
-        b_ref = ps.generate(prompts[0], max_new_tokens=8, beam=3)
+    ref, (b_ref,) = _PARENT_INT8_GREEDY, _PARENT_INT8_BEAM
     with DecodingPredictor(arts['block8']) as pb:
         assert pb.stats.tier == 'int8'
         b_cold = pb.generate(prompts[0], max_new_tokens=8, beam=3)
@@ -309,21 +455,16 @@ def test_beam_divergence_cow_at_block_boundary(arts):
     """Force beam CoW exactly where it is subtle: a prompt whose length
     is a multiple of block_size (the fork point is a BLOCK BOUNDARY, so
     the first divergent write extends into a fresh block — no copy) and
-    one mid-block (the shared partial tail must CoW). Both must match
-    the slot tier bit-for-bit."""
+    one mid-block (the shared partial tail must CoW). Both must serve
+    what the parent's block artifact served, bit for bit."""
     rng = np.random.RandomState(33)
     at_boundary = rng.randint(2, VOCAB, 8)    # 8 % 4 == 0
     mid_block = rng.randint(2, VOCAB, 6)      # 6 % 4 != 0
-    with DecodingPredictor(arts['slot']) as ps:
-        ref = [ps.generate(p, max_new_tokens=10, beam=3)
-               for p in (at_boundary, mid_block)]
     with DecodingPredictor(arts['block']) as pb:
         got = [pb.generate(p, max_new_tokens=10, beam=3)
                for p in (at_boundary, mid_block)]
         snap = pb.stats.snapshot()
-    for (i1, s1), (i2, s2) in zip(ref, got):
-        np.testing.assert_array_equal(i1, i2)
-        np.testing.assert_array_equal(s1, s2)
+    _assert_beams(got, _PARENT_COW)
     assert snap['cow_blocks'] > 0
 
 
@@ -348,22 +489,20 @@ def test_prefix_sharing_skips_compute_and_storage(arts):
 
 
 def test_chunked_prefill_admits_beyond_largest_chunk(arts):
-    """A prompt longer than the largest chunk size admits in slices (the
-    slot tier would reject it: no bucket fits) and its transcript
-    matches a short-prompt continuation computed the long way around:
-    greedy decode is deterministic, so serving the same prompt twice on
-    the block tier across chunk boundaries must agree."""
+    """A prompt longer than the largest chunk size admits in slices —
+    the ceiling is the cache length, and a prompt past THAT is refused
+    by name. Greedy decode is deterministic, so serving the same prompt
+    twice across chunk boundaries must agree."""
     rng = np.random.RandomState(35)
     long_prompt = rng.randint(2, VOCAB, 23)    # > max chunk (8)
     with DecodingPredictor(arts['block']) as pb:
         one = pb.generate(long_prompt, max_new_tokens=12)
         s = pb.stats.snapshot()
         two = pb.generate(long_prompt, max_new_tokens=12)
+        with pytest.raises(ValueError, match='exceeds max_cache_len'):
+            pb.generate(rng.randint(2, VOCAB, CACHE + 1), max_new_tokens=4)
     assert one == two
     assert s['chunk_slices'] >= 3              # 23 tokens over 8-chunks
-    with DecodingPredictor(arts['slot']) as ps:
-        with pytest.raises(ValueError, match='exceeds'):
-            ps.generate(long_prompt, max_new_tokens=4)
 
 
 def test_mp_sharded_decode_transcripts_match_single_chip(arts,
@@ -375,7 +514,7 @@ def test_mp_sharded_decode_transcripts_match_single_chip(arts,
     agree to within local-fusion ulps — accumulated float beam scores
     may differ in the last ~1e-6 (the standard the sharded serving
     systems hold); ids must not."""
-    mp2 = _build(str(tmp_path / 'mp2'), prompt_buckets=(4, 8),
+    mp2 = _build(str(tmp_path / 'mp2'), chunk_sizes=(4, 8),
                  block_size=4, mp_shard=2)
     with open(os.path.join(mp2, 'decode_signature.json')) as f:
         sig = json.load(f)
@@ -384,7 +523,7 @@ def test_mp_sharded_decode_transcripts_match_single_chip(arts,
     # mesh-tagged sidecars: a sharded executable can never load into an
     # unsharded serve (or another mesh shape)
     from paddle_tpu.inference import decoding
-    for d in (decoding._STEP_DIR, decoding._REORDER_DIR,
+    for d in (decoding._STEP_DIR, decoding._ZEROS_DIR,
               decoding._BLOCKCOPY_DIR):
         assert os.path.exists(os.path.join(mp2, d,
                                            'aot_cpu_mp2.jaxexec'))
@@ -413,7 +552,7 @@ def test_mp_sharded_warm_replica_zero_compiles(arts, tmp_path):
     way — the full ISSUE 13 sharded-serve acceptance bar."""
     import subprocess
     import sys as _sys
-    mp2 = _build(str(tmp_path / 'mp2w'), prompt_buckets=(4, 8),
+    mp2 = _build(str(tmp_path / 'mp2w'), chunk_sizes=(4, 8),
                  block_size=4, mp_shard=2)
     here = os.path.dirname(os.path.abspath(__file__))
     outs = []
@@ -447,10 +586,9 @@ def test_chunk_pad_overflow_lands_in_trash_block():
     agree."""
     import tempfile
     t = tempfile.mkdtemp()
-    big = _build(os.path.join(t, 'big'), prompt_buckets=(8,),
-                 block_size=8, chunk_sizes=(48,))
-    small = _build(os.path.join(t, 'small'), prompt_buckets=(8,),
-                   block_size=8, chunk_sizes=(8,))
+    big = _build(os.path.join(t, 'big'), block_size=8, chunk_sizes=(48,))
+    small = _build(os.path.join(t, 'small'), block_size=8,
+                   chunk_sizes=(8,))
     rng = np.random.RandomState(36)
     prompt = rng.randint(2, VOCAB, CACHE - 1)  # 63 tokens: table full
     with DecodingPredictor(small) as ps:
@@ -468,7 +606,7 @@ def test_waiting_request_rematches_published_prefix():
     shared prefix while it waited. A cached miss holds no refs, so
     only a cached HIT may pin across attempts."""
     import tempfile
-    art = _build(tempfile.mkdtemp() + '/rematch', prompt_buckets=(4, 8),
+    art = _build(tempfile.mkdtemp() + '/rematch', chunk_sizes=(4, 8),
                  block_size=4, num_blocks=5)   # 4 usable blocks
     rng = np.random.RandomState(37)
     prompt = rng.randint(2, VOCAB, 12)         # 3 blocks at admission
@@ -491,7 +629,7 @@ def test_pool_exhaustion_sheds_loudly(arts):
     request with ServerOverloaded instead of deadlocking."""
     from paddle_tpu.inference import ServerOverloaded
     import tempfile
-    small = _build(tempfile.mkdtemp() + '/tiny', prompt_buckets=(4, 8),
+    small = _build(tempfile.mkdtemp() + '/tiny', chunk_sizes=(4, 8),
                    block_size=4, num_blocks=3)  # 2 usable blocks
     with DecodingPredictor(small) as pb:
         ok = pb.generate(np.asarray([3, 4, 5]), max_new_tokens=4)

@@ -377,8 +377,10 @@ def test_a_version_3_artifact_is_refused_by_name(olmoe_art, tmp_path):
 
 # -- transformer_base_lm-shaped programs through the same path ---------------
 
-# greedy transcripts of this spec at the parent commit (PR 25, weights
-# baked into each module), six prompts served together
+# greedy transcripts of this spec at the parent commit ('block' and
+# 'block_int8' at PR 25, weights baked into each module; 'block_bf16' at
+# PR 27, the last tree that had a second cache layout), six prompts served
+# together
 _PARENT = {
     'block': [[80, 80, 80, 81, 54, 81, 54, 80, 81, 88, 54, 80],
               [88, 60, 83, 81, 88, 60, 81, 88, 60, 81, 88, 60],
@@ -392,9 +394,14 @@ _PARENT = {
                    [2, 52, 2, 37, 51, 2, 96, 96, 96, 96, 96, 96],
                    [51, 91, 42, 94, 80, 91, 80, 42, 94, 80, 91, 11],
                    [93, 2, 2, 2, 2, 2, 2, 2, 2, 2, 2, 2]],
+    'block_bf16': [[80, 65, 80, 81, 54, 81, 81, 81, 88, 65, 80, 81],
+                   [88, 60, 83, 81, 88, 60, 81, 88, 60, 81, 88, 60],
+                   [81, 88, 60, 81, 81, 81, 81, 81, 54, 81, 88, 65],
+                   [81, 88, 54, 81, 81, 54, 81, 88, 54, 81, 88, 60],
+                   [75, 68, 88, 60, 81, 88, 60, 81, 88, 60, 81, 88],
+                   [54, 81, 88, 60, 81, 88, 60, 81, 88, 60, 81, 81]],
 }
-_PARENT['slot'] = _PARENT['block']
-_TRANSFORMER_KW = {'block': dict(block_size=4), 'slot': dict(),
+_TRANSFORMER_KW = {'block': dict(block_size=4),
                    'block_int8': dict(block_size=4, kv_cache_dtype='int8'),
                    'block_bf16': dict(block_size=4,
                                       kv_cache_dtype='bfloat16')}
@@ -407,7 +414,7 @@ def _transformer_art(tmp, name):
     with fluid.scope_guard(scope), fluid.unique_name.guard():
         spec = build_decode_spec(
             vocab=97, d_model=32, n_head=4, n_layer=2, d_ff=64, max_slots=4,
-            max_cache_len=48, eos_id=1, prompt_buckets=(8, 16),
+            max_cache_len=48, eos_id=1, chunk_sizes=(8, 16),
             **_TRANSFORMER_KW[name])
         spec['startup'].random_seed = 11
         fluid.Executor(fluid.CPUPlace()).run(spec['startup'], scope=scope)
@@ -420,7 +427,7 @@ def _transformer_prompts():
     return [rng.randint(2, 97, n) for n in (3, 7, 13, 16, 5, 9)]
 
 
-@pytest.mark.parametrize('name', ['block', 'slot', 'block_int8'])
+@pytest.mark.parametrize('name', ['block', 'block_bf16', 'block_int8'])
 def test_transformer_transcripts_are_the_parents(tmp_path, name):
     """Weights as arguments change no served token: bit-identical to what
     the parent commit's baked-constant artifact served."""

@@ -308,7 +308,7 @@ def _export(tmp, d_model, **kw):
         spec = build_decode_spec(
             vocab=VOCAB, d_model=d_model, n_head=2, n_layer=2, d_ff=32,
             max_slots=SLOTS, max_cache_len=CACHE, eos_id=1,
-            prompt_buckets=(8, 16), **kw)
+            chunk_sizes=(8, 16), **kw)
         fluid.Executor(fluid.CPUPlace()).run(spec['startup'])
         export_decode(spec, tmp, scope=scope)
     return tmp
@@ -317,8 +317,7 @@ def _export(tmp, d_model, **kw):
 @pytest.fixture(scope='module')
 def arts(tmp_path_factory):
     t = tmp_path_factory.mktemp('paged')
-    return {'slot128': _export(str(t / 'slot128'), 128),
-            'block128': _export(str(t / 'block128'), 128, block_size=8),
+    return {'block128': _export(str(t / 'block128'), 128, block_size=8),
             'block32': _export(str(t / 'block32'), 32, block_size=8)}
 
 
@@ -345,19 +344,28 @@ def test_signature_names_the_body_each_attention_op_holds(arts, art, body):
             assert b'tpu_custom_call' not in f.read()
 
 
-def test_slot_artifact_names_the_slot_ops_body(arts):
-    sig = _signature(arts['slot128'])
-    assert sig['step']['attention'] == {'kv_cache_attention': {'jnp': 2}}
+@pytest.mark.parametrize('art', ['block128', 'block32'])
+def test_a_loaded_artifact_names_only_block_attention_ops(arts, art):
+    """One cache layout: every attention op of every program a predictor
+    loaded is a kv_block_* op."""
+    with DecodingPredictor(arts[art]) as pred:
+        bodies = pred.attention_bodies
+    assert set(bodies) == {'step', 'chunk_8', 'chunk_16'}
+    for by_op in bodies.values():
+        assert by_op and all(op.startswith('kv_block_') for op in by_op)
 
 
 def test_on_the_cpu_the_kernels_artifact_serves_the_jnp_body(arts):
     """An artifact whose step holds the kernel for a TPU runs the jnp
-    body here: the predictor says so, and block-paged equals slot-paged
-    bit for bit, as before."""
+    body here: the predictor says so, and it serves what the parent's
+    block artifact served (PR 27, where that equalled the slot tier bit
+    for bit)."""
     rng = np.random.RandomState(5)
     prompts = [rng.randint(2, VOCAB, n) for n in (3, 9, 14, 6)]
-    with DecodingPredictor(arts['slot128']) as ps:
-        want = [ps.generate(p, max_new_tokens=7) for p in prompts]
+    want = [[30, 4, 30, 4, 4, 30, 4],
+            [30, 20, 16, 26, 11, 16, 26],
+            [4, 4, 4, 4, 4, 4, 4],
+            [39, 10, 26, 11, 2, 26, 11]]
     with DecodingPredictor(arts['block128']) as pb:
         assert pb.attention_bodies['step'] == {
             'kv_block_attention': {'jnp': 2}}

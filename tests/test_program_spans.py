@@ -73,7 +73,7 @@ def _traced(tmp, body):
 
 @pytest.fixture(scope='module')
 def decode_art(tmp_path_factory):
-    """A small block-layout decode artifact, built as
+    """A small decode artifact, built as
     tests/test_kv_blocks.py builds its own."""
     from models.transformer import build_decode_spec
     art = str(tmp_path_factory.mktemp('spans') / 'art')
@@ -82,7 +82,7 @@ def decode_art(tmp_path_factory):
         spec = build_decode_spec(
             vocab=VOCAB, d_model=16, n_head=2, n_layer=2, d_ff=32,
             max_slots=SLOTS, max_cache_len=CACHE, eos_id=1,
-            prompt_buckets=(4, 8), block_size=4)
+            chunk_sizes=(4, 8), block_size=4)
         fluid.Executor(fluid.CPUPlace()).run(spec['startup'])
         export_decode(spec, art, scope=scope)
     return art
@@ -204,7 +204,7 @@ def test_step_d2h_bytes_is_the_ids(decode_trace):
 @pytest.mark.parametrize('sub,name', [
     ('decode_step', 'decode_step'), ('prefill_chunk_00004', 'prefill_chunk_4'),
     ('prefill_chunk_00008', 'prefill_chunk_8'),
-    ('decode_reorder', 'decode_reorder'), ('decode_zeros', 'decode_zeros'),
+    ('decode_zeros', 'decode_zeros'),
     ('decode_blockcopy', 'decode_blockcopy')])
 def test_exported_decode_programs_have_stable_names(decode_art, sub, name):
     """What 'XLA Modules' prints as jit_<name> for an AOT-loaded program."""

@@ -391,8 +391,9 @@ def _decode_spec(kv, slots, scope):
     with fluid.scope_guard(scope):
         spec = build_decode_spec(vocab=41, d_model=16, n_head=2,
                                  n_layer=2, d_ff=32, max_slots=slots,
-                                 max_cache_len=24, prompt_buckets=(4,),
-                                 eos_id=1, kv_cache_dtype=kv)
+                                 max_cache_len=24, chunk_sizes=(4,),
+                                 block_size=4, eos_id=1,
+                                 kv_cache_dtype=kv)
         exe = fluid.Executor(fluid.CPUPlace())
         exe.run(spec['startup'], scope=scope)
     return spec
@@ -464,27 +465,37 @@ def test_kv_quant_ops_roundtrip():
             return self.attrs.get(n, d)
 
     rng = np.random.RandomState(0)
-    S, T, D = 3, 8, 8
+    S, BS, MAXB, D = 3, 4, 2, 8            # slot s owns blocks 1+2s, 2+2s
+    NB = 1 + S * MAXB
+    table = (1 + MAXB * np.arange(S)[:, None]
+             + np.arange(MAXB)[None, :]).astype(np.int32)
     kv = rng.randn(S, D).astype(np.float32)
     pos = np.full((S, 1), 2, np.int32)
-    cache = np.zeros((S, T, D), np.int8)
-    cscale = np.ones((S, T), np.float32)
-    out = get('kv_cache_write_quant').lower(Ctx(), {
+    cache = np.zeros((NB, BS, D), np.int8)
+    cscale = np.ones((NB, BS), np.float32)
+    out = get('kv_block_write_quant').lower(Ctx(), {
         'Cache': [jnp.asarray(cache)], 'Scale': [jnp.asarray(cscale)],
-        'KV': [jnp.asarray(kv)], 'Pos': [jnp.asarray(pos)]})
+        'KV': [jnp.asarray(kv)], 'Pos': [jnp.asarray(pos)],
+        'BlockTable': [jnp.asarray(table)]})
     c2, s2 = np.asarray(out['Out'][0]), np.asarray(out['OutScale'][0])
-    deq = c2[:, 2, :].astype(np.float32) * s2[:, 2, None]
+    deq = c2[table[:, 0], 2, :].astype(np.float32) \
+        * s2[table[:, 0], 2, None]
     assert np.abs(deq - kv).max() <= np.abs(kv).max() / 127.0 * 0.51
-    # attention: garbage in rows > pos must not perturb the result
+    # attention: garbage in rows > pos (the rest of each slot's first
+    # page, all of its second, the trash block) must not perturb the
+    # result
     q = rng.randn(S, D).astype(np.float32)
     kc = c2.copy()
-    kc[:, 3:, :] = 77                      # stale garbage beyond pos
+    kc[table[:, 0], 3:, :] = 77
+    kc[table[:, 1]] = 77
+    kc[0] = 77
     args = lambda k: {'Q': [jnp.asarray(q)], 'KCache': [jnp.asarray(k)],
                       'KScale': [jnp.asarray(s2)],
                       'VCache': [jnp.asarray(c2)],
                       'VScale': [jnp.asarray(s2)],
-                      'Pos': [jnp.asarray(pos)]}
-    att = get('kv_cache_attention_quant')
+                      'Pos': [jnp.asarray(pos)],
+                      'BlockTable': [jnp.asarray(table)]}
+    att = get('kv_block_attention_quant')
     o1 = np.asarray(att.lower(Ctx(n_head=2), args(c2))['Out'][0])
     o2 = np.asarray(att.lower(Ctx(n_head=2), args(kc))['Out'][0])
     assert np.array_equal(o1, o2)

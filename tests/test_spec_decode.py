@@ -1,6 +1,6 @@
 """Speculative decoding (ISSUE 17): greedy bit-identity of
-draft-and-verify decode vs plain decode across the slot/block and
-f32/int8 tiers, mixed draft/no-draft/beam ticks, rejected-tail cache
+draft-and-verify decode vs plain decode across the f32 / bf16 / int8
+KV tiers, mixed draft/no-draft/beam ticks, rejected-tail cache
 invisibility and block rollback, EOS/max_new truncation inside the
 draft window, acceptance stats, drafter units, and fresh-subprocess
 warm start with zero XLA compiles over the verify sidecar."""
@@ -36,18 +36,22 @@ def _build(tmp, **kw):
 
 @pytest.fixture(scope='module')
 def arts(tmp_path_factory):
-    """draft_k=K artifacts of the same tiny LM across all four KV
-    tiers, plus one verify-less artifact for the negative tests."""
+    """draft_k=K artifacts of the same tiny LM across the KV tiers
+    (bfloat16 at two page sizes: 16 rows is what the TPU's paged kernel
+    reads), plus one verify-less artifact for the negative tests."""
     t = tmp_path_factory.mktemp('spec')
     return {
-        'slot': _build(str(t / 'slot'), prompt_buckets=(4, 8), draft_k=K),
-        'block': _build(str(t / 'block'), prompt_buckets=(4, 8),
+        'block': _build(str(t / 'block'), chunk_sizes=(4, 8),
                         block_size=4, draft_k=K),
-        'slot8': _build(str(t / 'slot8'), prompt_buckets=(4, 8),
-                        kv_cache_dtype='int8', draft_k=K),
-        'block8': _build(str(t / 'block8'), prompt_buckets=(4, 8),
+        'block_bf16': _build(str(t / 'block_bf16'), chunk_sizes=(4, 8),
+                             block_size=4, kv_cache_dtype='bfloat16',
+                             draft_k=K),
+        'block_bf16_p16': _build(str(t / 'block_bf16_p16'),
+                                 chunk_sizes=(4, 8), block_size=16,
+                                 kv_cache_dtype='bfloat16', draft_k=K),
+        'block8': _build(str(t / 'block8'), chunk_sizes=(4, 8),
                          block_size=4, kv_cache_dtype='int8', draft_k=K),
-        'plain': _build(str(t / 'plain'), prompt_buckets=(4,)),
+        'plain': _build(str(t / 'plain'), chunk_sizes=(4,), block_size=4),
     }
 
 
@@ -100,7 +104,7 @@ class _OracleDrafter(object):
 
 def test_verify_artifact_layout(arts):
     from paddle_tpu.inference import decoding
-    for name in ('slot', 'block', 'slot8', 'block8'):
+    for name in ('block', 'block_bf16', 'block_bf16_p16', 'block8'):
         with open(os.path.join(arts[name],
                                decoding._DECODE_SIGNATURE)) as f:
             sig = json.load(f)
@@ -123,7 +127,8 @@ def test_verify_artifact_layout(arts):
 
 # -- greedy bit-identity -----------------------------------------------------
 
-@pytest.mark.parametrize('name', ['slot', 'block', 'slot8', 'block8'])
+@pytest.mark.parametrize('name', ['block', 'block_bf16',
+                                  'block_bf16_p16', 'block8'])
 def test_spec_bit_identity_all_tiers(arts, name):
     """The ISSUE 17 bar: speculative greedy transcripts are
     BIT-IDENTICAL to plain decode on every KV tier, with real
@@ -145,11 +150,11 @@ def test_mixed_draft_nodraft_and_beam_tick(arts):
     step, and a beam request (never drafted) decodes alongside — all in
     the same scheduler loop, all bit-identical to plain serving."""
     prompts = _prompts(23, 8)
-    with DecodingPredictor(arts['slot']) as pp:
+    with DecodingPredictor(arts['block']) as pp:
         want = [pp.generate(p, max_new_tokens=10) for p in prompts]
         want_ids, want_scores = pp.generate(prompts[1],
                                             max_new_tokens=8, beam=3)
-    with DecodingPredictor(arts['slot'], draft='ngram') as ps:
+    with DecodingPredictor(arts['block'], draft='ngram') as ps:
         ps.stats.reset()
         streams = [ps.submit(p, max_new_tokens=10) for p in prompts]
         got = [s.result(120) for s in streams]
@@ -194,8 +199,8 @@ def test_truncation_inside_draft_window(arts):
     exactly where plain decode stops, never overshooting on accepted
     draft tokens."""
     prompts = _prompts(31, 6)
-    with DecodingPredictor(arts['slot']) as pp, \
-            DecodingPredictor(arts['slot'], draft='ngram') as ps:
+    with DecodingPredictor(arts['block']) as pp, \
+            DecodingPredictor(arts['block'], draft='ngram') as ps:
         for max_new in (1, 2, 3):
             want = [pp.generate(p, max_new_tokens=max_new)
                     for p in prompts]
@@ -211,11 +216,11 @@ def test_eos_semantics_match_plain(arts):
     actually emits, then spec — including an oracle drafter that
     PROPOSES the EOS mid-window — must stop exactly where plain does."""
     prompts = _prompts(43, 8)
-    with DecodingPredictor(arts['slot']) as pp:
+    with DecodingPredictor(arts['block']) as pp:
         base = [pp.generate(p, max_new_tokens=12) for p in prompts]
     toks = [t for w in base for t in w]
     eos = max(set(toks), key=toks.count)
-    with DecodingPredictor(arts['slot']) as pp:
+    with DecodingPredictor(arts['block']) as pp:
         pp._eos = eos
         want = [pp.generate(p, max_new_tokens=12) for p in prompts]
     assert any(len(w) < 12 and w[-1] == eos for w in want), \
@@ -224,7 +229,7 @@ def test_eos_semantics_match_plain(arts):
     for p, w in zip(prompts, want):
         oracle.remember(p, w)
     for drafter in ('ngram', oracle):
-        with DecodingPredictor(arts['slot'], draft=drafter) as ps:
+        with DecodingPredictor(arts['block'], draft=drafter) as ps:
             ps._eos = eos
             got = [ps.generate(p, max_new_tokens=12) for p in prompts]
         assert got == want
@@ -235,7 +240,7 @@ def test_eos_semantics_match_plain(arts):
 def test_acceptance_stats(arts):
     oracle = _OracleDrafter()
     prompts = _prompts(37, 4)
-    with DecodingPredictor(arts['slot']) as pp:
+    with DecodingPredictor(arts['block']) as pp:
         pp.stats.reset()
         want = [pp.generate(p, max_new_tokens=10) for p in prompts]
         plain_snap = pp.stats.snapshot()
@@ -245,7 +250,7 @@ def test_acceptance_stats(arts):
     assert plain_snap['drafted'] == 0 and plain_snap['accepted'] == 0
     assert plain_snap['acc_rate'] == 1.0
     assert plain_snap['tokens_per_dispatch'] == 1.0
-    with DecodingPredictor(arts['slot'], draft=oracle) as ps:
+    with DecodingPredictor(arts['block'], draft=oracle) as ps:
         ps.stats.reset()
         got = [ps.generate(p, max_new_tokens=10) for p in prompts]
         snap = ps.stats.snapshot()
@@ -263,7 +268,7 @@ def test_acceptance_stats(arts):
 
 def test_serving_report_spec_columns(arts, capsys):
     from paddle_tpu import profiler
-    with DecodingPredictor(arts['slot'], draft='ngram') as ps:
+    with DecodingPredictor(arts['block'], draft='ngram') as ps:
         ps.generate(np.tile([5, 9], 4), max_new_tokens=8)
         out = profiler.serving_report()
         name = [k for k in out if k.startswith('decode:')]
@@ -282,14 +287,14 @@ def test_tokenstream_batches_coalesce(arts):
     the stream; plain decode delivers singletons."""
     oracle = _OracleDrafter()
     prompt = np.asarray([3, 4, 5, 6], np.int64)
-    with DecodingPredictor(arts['slot']) as pp:
+    with DecodingPredictor(arts['block']) as pp:
         want = pp.generate(prompt, max_new_tokens=10)
         st = pp.submit(prompt, max_new_tokens=10)
         plain_batches = list(st.batches())
     oracle.remember(prompt, want)
     assert all(len(b) == 1 for b in plain_batches)
     assert [t for b in plain_batches for t in b] == want
-    with DecodingPredictor(arts['slot'], draft=oracle) as ps:
+    with DecodingPredictor(arts['block'], draft=oracle) as ps:
         st = ps.submit(prompt, max_new_tokens=10)
         batches = list(st.batches())
     assert [t for b in batches for t in b] == want
@@ -344,13 +349,13 @@ def test_draft_validation(arts):
         DecodingPredictor(arts['plain'], draft='ngram')
     for bad_k in (0, K + 1):
         with pytest.raises(ValueError):
-            DecodingPredictor(arts['slot'], draft='ngram',
+            DecodingPredictor(arts['block'], draft='ngram',
                               draft_k=bad_k)
     # draft_k below the artifact's K narrows the window
-    with DecodingPredictor(arts['slot'], draft='ngram',
+    with DecodingPredictor(arts['block'], draft='ngram',
                            draft_k=2) as ps:
         out = ps.generate(np.tile([5, 9], 4), max_new_tokens=8)
-    with DecodingPredictor(arts['slot']) as pp:
+    with DecodingPredictor(arts['block']) as pp:
         assert pp.generate(np.tile([5, 9], 4), max_new_tokens=8) == out
 
 
@@ -377,7 +382,7 @@ def test_warm_fresh_subprocess_zero_compiles(arts, tmp_path):
     serving process must perform ZERO XLA compiles and match the
     in-process transcripts."""
     art = str(tmp_path / 'art')
-    shutil.copytree(arts['slot'], art)
+    shutil.copytree(arts['block'], art)
     stripped = 0
     for root, _dirs, files in os.walk(art):
         for f in files:
@@ -414,7 +419,7 @@ def test_warm_fresh_subprocess_zero_compiles(arts, tmp_path):
         pat = rng.randint(2, VOCAB, 2)
         plen = int(rng.randint(4, 9))
         prompts.append(np.tile(pat, plen)[:plen])
-    with DecodingPredictor(arts['slot'], draft='ngram') as ps:
+    with DecodingPredictor(arts['block'], draft='ngram') as ps:
         want = [ps.submit(p, max_new_tokens=8) for p in prompts]
         want = [s.result(120) for s in want]
     assert payload['greedy'] == want

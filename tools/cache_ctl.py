@@ -14,11 +14,12 @@ PTPU_COMPILE_CACHE_MAX_MB), or clears everything with --all.
 warm-start sidecars — run it on a new replica image ahead of first
 traffic, and CompiledPredictor/BatchingPredictor/CompiledTrainer load
 with zero traces and zero XLA compiles. Continuous-decode artifacts
-(export_decode's two-program layout, decode_signature.json) prewarm BOTH
-tiers: every prompt-length prefill bucket plus the decode-step and
-reorder programs — and, on speculative-decode artifacts, the verify
-program (see below) — so DecodingPredictor replicas answer their first
-token with zero compiles.
+(export_decode's, decode_signature.json) prewarm every program they
+hold: the chunked-prefill programs (prefill_chunk_<C>/, one per chunk
+size), the decode-step, block-copy (decode_blockcopy/) and zeros
+programs — and, on speculative-decode artifacts, the verify program
+(see below) — so DecodingPredictor replicas answer their first token
+with zero compiles.
 
 Quantized artifact tiers (ISSUE 11, export_compiled(quantize='int8')):
 an artifact carrying an int8/ tier subdir (its own bucket tree +
@@ -26,14 +27,11 @@ signature) prewarms BOTH tiers automatically — every bf16 bucket, every
 int8 bucket, and the int8 top mirror — so a replica serving either tier
 (CompiledPredictor/BatchingPredictor tier='int8') starts with zero
 compiles. Int8-KV decode artifacts (export_decode of a
-kv_cache_dtype='int8' spec) prewarm through the standard decode layout:
+kv_cache_dtype='int8' spec) prewarm like any other decode artifact:
 the quantized cache is ordinary program state.
 
-Block-paged / mp-sharded decode artifacts (ISSUE 13,
-build_decode_spec(block_size=..., mp_shard=k)): a block-layout artifact
-prewarms its chunked-prefill programs (prefill_chunk_<C>/, one per chunk
-size) and the block-copy program (decode_blockcopy/) in place of the
-prompt-bucket prefill tree. An artifact whose signature carries a mesh
+mp-sharded decode artifacts (ISSUE 13, build_decode_spec(mp_shard=k)):
+an artifact whose signature carries a mesh
 block prewarms over that mesh — the host must see prod(mesh axes)
 devices of the artifact's platform or prewarm fails with exit 1 — and
 writes MESH-TAGGED sidecars (aot_<platform>_<axes>.jaxexec, e.g.
@@ -47,7 +45,7 @@ a decode artifact whose signature carries a `verify` block (signature
 version 3) ships a THIRD program, decode_verify/ — the [S, K+1] ->
 [S, K+1, V] draft-scoring dispatch. Prewarm learns it exactly like the
 step program it rides beside, across every tier and mesh tag the
-artifact carries: slot and block layouts, bf16 and int8/ KV tiers, and
+artifact carries: bf16 and int8/ KV tiers, and
 mesh-tagged sidecars for mp-sharded artifacts. A replica serving with a
 drafter attached (DecodingPredictor(draft=...)) then reaches its first
 verify tick — not just its first token — with zero compiles.

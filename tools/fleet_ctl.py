@@ -108,14 +108,14 @@ def cmd_status(args):
           % (c.get('scale_out', 0), c.get('scale_in', 0),
              c.get('replica_deaths', 0),
              c.get('rollout', {}).get('state', 'idle')))
-    # layout/mesh columns (ISSUE 13): which decode cache layout and
-    # mesh each replica ACTUALLY loaded — a rolling rollout to the
-    # block-paged or mp-sharded tier is auditable mid-flight.
+    # mesh column (ISSUE 13): which mesh each replica ACTUALLY loaded
+    # — a rolling rollout to the mp-sharded tier is auditable
+    # mid-flight.
     # pid/artifact (ISSUE 19): the WORKER-reported identity from
     # hello/heartbeats, so a wedged row maps to a process + artifact
     # dir even when the router-side view is stale
-    print('%-8s %-9s %5s %6s %8s %7s %8s %8s %5s %9s %8s %s' %
-          ('replica', 'state', 'tier', 'layout', 'mesh', 'pid',
+    print('%-8s %-9s %5s %8s %7s %8s %8s %5s %9s %8s %s' %
+          ('replica', 'state', 'tier', 'mesh', 'pid',
            'backlog', 'requests', 'occ', 'hb-age(s)', 'compiles',
            'artifact'))
     reps = st.get('replicas', {})
@@ -127,9 +127,9 @@ def cmd_status(args):
         # double-count frames already inside the worker's queue)
         backlog = s.get('pending', 0) + s.get('queue_depth', 0)
         artifact = hb.get('artifact') or s.get('artifact') or '-'
-        print('%-8s %-9s %5s %6s %8s %7s %8d %8d %5.2f %9s %8s %s' %
+        print('%-8s %-9s %5s %8s %7s %8d %8d %5.2f %9s %8s %s' %
               (rid, s.get('state', '?')[:9], s.get('tier', 'bf16'),
-               s.get('layout') or '-', s.get('mesh') or '-',
+               s.get('mesh') or '-',
                hb.get('pid') or s.get('pid') or '-',
                backlog, s.get('requests', 0),
                s.get('occupancy', 0.0),
