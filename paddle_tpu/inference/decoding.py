@@ -278,6 +278,9 @@ class DecodeStats(object):
         self.cow_blocks = 0      # blocks copied for beam copy-on-write
         self.blockcopies = 0     # block-copy dispatches
         self.chunk_slices = 0    # chunked-prefill slice dispatches
+        # slices whose result the host read: the prompts' last ones.
+        # 1 - slice_reads / chunk_slices of the slices cost no wait
+        self.slice_reads = 0
         # speculative decoding (ISSUE 17). adv_* meter tokens delivered
         # per request-advancing dispatch (prefill first token, plain
         # step, beam step, verify tick) — tokens_per_dispatch is
@@ -310,6 +313,7 @@ class DecodeStats(object):
             self.cow_blocks = 0
             self.blockcopies = 0
             self.chunk_slices = 0
+            self.slice_reads = 0
             self.verify_steps = 0
             self.drafted = 0
             self.accepted = 0
@@ -368,7 +372,8 @@ class DecodeStats(object):
                     'recent_failures': list(self._failures),
                     'cow_blocks': int(self.cow_blocks),
                     'blockcopies': int(self.blockcopies),
-                    'chunk_slices': int(self.chunk_slices)}
+                    'chunk_slices': int(self.chunk_slices),
+                    'slice_reads': int(self.slice_reads)}
             if self.block_source is None:    # not wired to a pool yet
                 return snap
         # outside the stats lock: the BlockManager takes its own
@@ -1096,20 +1101,21 @@ class DecodingPredictor(object):
                 "scheduler over the donated cache state")
         trash_tables = np.full((self._S, self._maxb), self._trash, np.int32)
         for c in self._chunks:
-            self._dispatch_chunk(c, np.zeros((1, c), np.int64), 0, 1,
-                                 trash_tables[:1])
-        self._dispatch_step(np.zeros((self._S, 1), np.int64),
-                            np.zeros((self._S, 1), np.int32), trash_tables)
+            self._to_host(self._dispatch_chunk(
+                c, np.zeros((1, c), np.int64), 0, 1, trash_tables[:1]))
+        self._to_host(self._dispatch_step(
+            np.zeros((self._S, 1), np.int64),
+            np.zeros((self._S, 1), np.int32), trash_tables))
         self._dispatch_blockcopy([])      # identity (trash-to-trash)
         if self._verify_mod is not None:
             # all-pad verify dispatch (ISSUE 17): every row at the pad
             # position, so the scatter routes to the trash block and the
             # dispatch is pure compile-warm
             R = self._K + 1
-            self._dispatch_verify(
+            self._to_host(self._dispatch_verify(
                 np.zeros((self._S, R), np.int64),
                 np.full((self._S, R), self._maxb * self._bs, np.int32),
-                trash_tables)
+                trash_tables))
         self._reset_state()
         self.stats.reset()   # warmup dispatches must not count as traffic
         return self
@@ -1226,21 +1232,33 @@ class DecodingPredictor(object):
         self.stats.block_source = self._blocks.stats
         self.stats.block_reset = self._blocks.reset_counters
 
-    def _to_host(self, fetches, program, logits):
-        """(ids, logits) of one dispatch as host arrays, in two spans:
-        the wait for the device to finish the program (launch latency
-        and the program's own time sit here), then what is left of the
-        device-to-host copy. The ids (fetch 0) always come; the logits
-        (fetch 1) stay a device array, and None here, unless `logits` —
-        a beam row live in this dispatch — asks for them. The copy is
-        queued behind the program FIRST, as a bare np.asarray would
-        queue it: waiting for the program before asking for the copy
-        would put a host wake-up between the two (measured: +2 % on the
-        inter-token gap)."""
-        import jax
+    def _ask(self, fetches, program, logits):
+        """Ask for the device-to-host copy of one dispatch's ids (fetch
+        0) and, if `logits` — a beam row live in this dispatch — of its
+        logits (fetch 1) too, and return the READ still to be made:
+        (program, the arrays asked for), which _to_host turns into host
+        arrays. The copy is queued behind the program HERE, at the
+        dispatch, as a bare np.asarray would queue it: waiting for the
+        program before asking for the copy would put a host wake-up
+        between the two (measured: +2 % on the inter-token gap). Nothing
+        waits: the scheduler dispatches the tick's prefill slices
+        between this and the read."""
         copied = fetches[:2] if logits else fetches[:1]
         for f in copied:
             f.copy_to_host_async()
+        return program, copied
+
+    def _to_host(self, read):
+        """(ids, logits) of one dispatch as host arrays — the read that
+        _ask left to be made — in two spans: the wait for the device to
+        finish the program (what is left of launch latency and of the
+        program's own time sits here; nothing, once the host had other
+        work to do since the dispatch), then what is left of the
+        device-to-host copy. The logits stayed a device array, and are
+        None here, unless they were asked for."""
+        import jax
+        program, copied = read
+        logits = len(copied) > 1
         with _span('decode/device_wait', program=program):
             jax.block_until_ready(copied)
         with _span('decode/d2h', program=program,
@@ -1253,8 +1271,10 @@ class DecodingPredictor(object):
         return host[0], host[1] if logits else None
 
     def _dispatch_step(self, tokens, pos, tables, logits=False):
-        """One decode step: ids [S] int32 and, if `logits`, the [S, V]
-        float32 rows they are the argmax of (else None)."""
+        """Dispatch one decode step and ask for its ids [S] int32 and,
+        if `logits`, the [S, V] float32 rows they are the argmax of.
+        Returns the read (_to_host), unmade: the call is enqueued, the
+        device may not have started."""
         feed = {'tokens': tokens, 'pos': pos, 'block_tables': tables}
         args = [self._feed(feed[n])
                 for n in self._step_feeds]  # signature feed order
@@ -1264,7 +1284,7 @@ class DecodingPredictor(object):
         self._state = list(new_state)
         with self.stats._lock:
             self.stats.steps += 1
-        return self._to_host(fetches, 'step', logits)      # sync
+        return self._ask(fetches, 'step', logits)
 
     def _dispatch_verify(self, tokens, pos, tables, logits=False):
         """One speculative verify dispatch (ISSUE 17): tokens/pos are
@@ -1273,7 +1293,7 @@ class DecodingPredictor(object):
         argmax ids come back [S, K+1] (beams never draft, so the
         scheduler never asks for the [S, K+1, V] logits). KV for all fed
         positions is written inside the program; acceptance and rollback
-        happen host-side after."""
+        happen host-side after. Returns the read (_to_host), unmade."""
         feed = {'tokens': tokens, 'pos': pos, 'block_tables': tables}
         args = [self._feed(feed[n]) for n in self._verify_feeds]
         with self._dev_ctx():
@@ -1282,15 +1302,20 @@ class DecodingPredictor(object):
         self._state = list(new_state)
         with self.stats._lock:
             self.stats.verify_steps += 1
-        return self._to_host(fetches, 'verify', logits)    # sync
+        return self._ask(fetches, 'verify', logits)
 
     def _dispatch_chunk(self, size, ids, start, take, table_row,
-                        logits=False):
-        """One chunked-prefill slice: `take` real rows of one prompt at
-        absolute positions start..start+take-1 (the rest of the `size`
-        rows are pad) write through `table_row` [1, max_blocks]. Returns
-        the id the slice's last real position chose and, if `logits`,
-        its [V] row (else None)."""
+                        logits=False, read=True):
+        """Dispatch one chunked-prefill slice: `take` real rows of one
+        prompt at absolute positions start..start+take-1 (the rest of
+        the `size` rows are pad) write through `table_row` [1,
+        max_blocks]. With `read` — the slice is its prompt's last —
+        asks for the id its last real position chose and, if `logits`,
+        that position's [V] row, and returns the read (_to_host gives
+        them with a leading axis of 1: _one_row), unmade. Without, it
+        keeps nothing of the fetches and returns None: the slice wrote
+        its K/V rows into the pool the next dispatch takes, and nobody
+        reads the id of a position inside a prompt."""
         feed = {'chunk_ids': ids,
                 'start': np.full((1, 1), start, np.int32),
                 'chunk_len': np.full((1, 1), take, np.int32),
@@ -1303,8 +1328,9 @@ class DecodingPredictor(object):
         with self.stats._lock:
             self.stats.prefills += 1
             self.stats.chunk_slices += 1
-        return _one_row(*self._to_host(
-            fetches, self._chunk_mods[size].name, logits))         # sync
+        if not read:
+            return None
+        return self._ask(fetches, self._chunk_mods[size].name, logits)
 
     def _dispatch_blockcopy(self, pairs):
         """One block-copy dispatch: every (dst, src) PHYSICAL-BLOCK pair
@@ -1387,8 +1413,24 @@ class DecodingPredictor(object):
 
     def _run_tick(self, waiting):
         """One scheduler iteration with work to look at — the interval
-        stats.busy_s times: expire, admit, one prefill slice per
-        admitting request, one step of the running batch."""
+        stats.busy_s times. The step FIRST, and everything else of the
+        tick in its shadow: after expiry the running batch's step is
+        dispatched (_step); behind it the waiting requests admit, and
+        one prefill slice per admitting request is dispatched onto the
+        device's queue with nothing waited for or copied (_prefill_tick)
+        — the donated pool threads step, slice, slice, … in order while
+        the host builds the next feed. Only then the device is read,
+        once: the step's ids, emitted before any slice's result is
+        touched so that no stream's token waits for another request's
+        prompt (_read_step), then the ids of the slices that were a
+        prompt's last (_read_slice). Such a request decodes from the
+        NEXT tick's step on, and that step reserves its blocks before
+        the next admission can take them — as when step followed slice
+        within one tick. With no row decoding the tick is admission,
+        slices and their reads. Nothing stays unread across ticks, so
+        expiry, cancel, drain and close see settled states; an error the
+        device raises in a slice nobody read surfaces at the next read,
+        inside a tick's try."""
         t0 = time.perf_counter()
         with _span('decode/expire'):
             if self._draining:
@@ -1397,18 +1439,23 @@ class DecodingPredictor(object):
                 # stepping to completion below
                 self._shed_waiting(waiting)
             self._expire(waiting)
+        step = None
+        try:
+            if any(e is not None and not e[0].prefilling
+                   for e in self._slots):
+                step = self._step(waiting)
+        except Exception as e:
+            self._fail_all(e, waiting)
         if not self._draining:
             with _span('decode/admit') as sp:
                 sp.set_metadata(admitted=self._admit(waiting))
         if any(s is not None for s in self._slots):
             try:
-                # one prefill slice per admitting request, then one
-                # step for the running batch: a long prompt
-                # interleaves instead of stalling every stream
-                self._prefill_tick()
-                if any(e is not None and not e[0].prefilling
-                       for e in self._slots):
-                    self._step(waiting)
+                lasts = self._prefill_tick()
+                if step is not None:
+                    self._read_step(*step)
+                for req, read in lasts:
+                    self._read_slice(req, read)
             except Exception as e:
                 self._fail_all(e, waiting)
             with self.stats._lock:
@@ -1602,12 +1649,15 @@ class DecodingPredictor(object):
         return admitted
 
     def _prefill_tick(self):
-        """One chunked-prefill slice per ADMITTING request: the
-        uncovered prompt span (a prefix hit skips the covered span's
-        compute AND storage) admits in fixed-size slices, one per
-        scheduler iteration, interleaved with the running batch's decode
-        steps — a max-length prompt no longer stalls every stream's
-        inter-token latency for its whole prefill."""
+        """One chunked-prefill slice per ADMITTING request, dispatched
+        and not waited for: the uncovered prompt span (a prefix hit
+        skips the covered span's compute AND storage) admits in
+        fixed-size slices, one per scheduler iteration, interleaved with
+        the running batch's decode steps — a max-length prompt never
+        stalls every stream's inter-token latency for its whole prefill.
+        Returns [(request, read)] of the slices that were their prompt's
+        last, for _read_slice once the step's tokens are out."""
+        lasts = []
         for req in self._active_requests():
             if not req.prefilling:
                 continue
@@ -1616,23 +1666,37 @@ class DecodingPredictor(object):
             size = select_bucket(self._chunks,
                                  min(remaining, self._chunks[-1]))
             take = min(size, remaining)
+            last = take >= remaining
             with _span('decode/prefill_slice', request=req.seq, size=size,
-                       take=take, start=req.next_start):
-                self._prefill_slice(req, size, take)
+                       take=take, start=req.next_start, last=int(last)):
+                read = self._prefill_slice(req, size, take, last)
+            if last:
+                lasts.append((req, read))
+        return lasts
 
-    def _prefill_slice(self, req, size, take):
+    def _prefill_slice(self, req, size, take, last):
+        """Dispatch one slice of `req`'s prompt. What it keeps depends
+        on what it can see: nothing unless the slice is the prompt's
+        `last` — then the read of its id, and of its logits row where
+        the request is a beam (the one dispatch of a prompt whose whole
+        logits row the host reads)."""
         ids = np.zeros((1, size), np.int64)
         ids[0, :take] = req.prompt[req.next_start:req.next_start + take]
-        last = req.next_start + take >= int(req.prompt.size)
-        # a beam's LAST slice is the one dispatch of a prompt whose whole
-        # logits row the host reads
-        tok, logits = self._dispatch_chunk(
+        read = self._dispatch_chunk(
             size, ids, req.next_start, take,
             self._table_row(req.tables[0]),
-            logits=last and req.beam is not None)
+            logits=last and req.beam is not None, read=last)
         req.next_start += take
-        if not last:
-            return
+        return read
+
+    def _read_slice(self, req, read):
+        """The end of `req`'s prefill: read the id (a beam: the logits
+        row) its prompt's last position chose, publish the prompt's
+        blocks, emit the first token. The request is a decoding row from
+        here on — of the next tick's step."""
+        tok, logits = _one_row(*self._to_host(read))
+        with self.stats._lock:
+            self.stats.slice_reads += 1
         req.prefilling = False
         # publish the prompt's FULL blocks for prefix reuse (the
         # partial tail stays private: decode writes land there)
@@ -1733,15 +1797,16 @@ class DecodingPredictor(object):
             table[lblk] = nb
 
     def _step(self, waiting):
-        """One iteration of the continuous batch over the block pool:
-        CoW copies dispatch first (one block-copy for ALL diverged
-        blocks), then every live slot advances one token through the
-        fixed-shape step; beam reorder afterwards is pure block-table
-        permutation (incref/decref, zero device work until the next
-        write diverges a shared tail block). With a drafter attached,
-        slots holding drafts ride ONE verify dispatch first (ISSUE 17)
-        and the plain step below covers only the undrafted remainder —
-        a fully-drafted batch skips the plain dispatch entirely."""
+        """The dispatch half of one iteration of the continuous batch
+        over the block pool: CoW copies dispatch first (one block-copy
+        for ALL diverged blocks), then the fixed-shape step that
+        advances every live slot one token. With a drafter attached,
+        slots holding drafts ride ONE verify tick first, read and
+        advanced as before (ISSUE 17), and the plain step covers only
+        the undrafted remainder. Returns what _read_step needs — the
+        step's read, unmade, the drafted set and the live row count — or
+        None where a fully-drafted (or shed) batch needs no plain
+        dispatch."""
         with _span('decode/step') as sp:
             with _span('decode/build_feed'):
                 drafted = self._collect_drafts()
@@ -1752,14 +1817,22 @@ class DecodingPredictor(object):
                     self._step_feed(waiting, drafted)
             sp.set_metadata(active=active)
             if not active:
-                return   # every live stream drafted (or shed): no plain step
+                return None
             with self.stats._lock:
                 self.stats.active_slot_steps += active
                 self.stats.slot_steps += self._S
             if cow:
                 self._dispatch_blockcopy(cow)
-            ids, logits = self._dispatch_step(tokens, pos, tables,
-                                              logits=beam)
+            return (self._dispatch_step(tokens, pos, tables, logits=beam),
+                    drafted, active)
+
+    def _read_step(self, read, drafted, active):
+        """The read half: wait for what is left of the step, copy its
+        ids (its logits where a beam row was live), emit. Beam reorder
+        is pure block-table permutation (incref/decref, zero device work
+        until the next write diverges a shared tail block)."""
+        with _span('decode/step', active=active):
+            ids, logits = self._to_host(read)
             with _span('decode/advance', rows=active):
                 self._advance(ids, logits, drafted)
 
@@ -1973,7 +2046,7 @@ class DecodingPredictor(object):
         # blockcopy dispatch's S pairs: chunk
         for i in range(0, len(cow), self._S):
             self._dispatch_blockcopy(cow[i:i + self._S])
-        ids, _ = self._dispatch_verify(tokens, pos, tables)
+        ids, _ = self._to_host(self._dispatch_verify(tokens, pos, tables))
         with _span('decode/advance', rows=len(rows)):
             now = time.perf_counter()
             ids = ids.tolist()

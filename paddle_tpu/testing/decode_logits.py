@@ -1,7 +1,8 @@
 """The LOGITS a DecodingPredictor's programs compute for given
 prompts — chunked prefill, then greedy decode steps through the block
 cache — taken through the predictor's own dispatch functions (fetch 1 of
-the chunk and the step programs, asked for with `logits=True`; the
+the chunk and the step programs, asked for with `logits=True` and read
+with `_to_host`; the
 scheduler itself copies only fetch 0, the ids), for comparison with a
 plain reference's
 full forward pass. Transcripts alone cannot carry that comparison: with
@@ -36,8 +37,9 @@ def served_logits(pred, prompts, n_new):
             take = min(size, left)
             ids = np.zeros((1, size), np.int64)
             ids[0, :take] = prompt[start:start + take]
-            _, lg = pred._dispatch_chunk(size, ids, start, take,
-                                         tables[i:i + 1], logits=True)
+            read = pred._dispatch_chunk(size, ids, start, take,
+                                        tables[i:i + 1], logits=True)
+            lg = pred._to_host(read)[1][0]
             start += take
         rows.append([np.array(lg, np.float32)])
     for j in range(1, n_new):
@@ -46,7 +48,8 @@ def served_logits(pred, prompts, n_new):
         for i, prompt in enumerate(prompts):
             tok[i, 0] = int(np.argmax(rows[i][-1]))
             pos[i, 0] = len(prompt) + j - 1
-        _, lg = pred._dispatch_step(tok, pos, tables=tables, logits=True)
+        _, lg = pred._to_host(
+            pred._dispatch_step(tok, pos, tables=tables, logits=True))
         for i in range(len(prompts)):
             rows[i].append(np.array(lg[i], np.float32))
     pred._reset_state()

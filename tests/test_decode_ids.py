@@ -102,8 +102,9 @@ def _dispatches(pred, program):
             take = min(size, left)
             ids = np.zeros((1, size), np.int64)
             ids[0, :take] = prompt[start:start + take]
-            tok, row = pred._dispatch_chunk(
-                size, ids, start, take, tables[i:i + 1], logits=True)
+            tok, row = decoding._one_row(*pred._to_host(
+                pred._dispatch_chunk(size, ids, start, take,
+                                     tables[i:i + 1], logits=True)))
             out['chunk'].append((np.asarray([tok], np.int32), row[None]))
             start += take
         last.append(tok)
@@ -114,8 +115,8 @@ def _dispatches(pred, program):
         for i, prompt in enumerate(prompts):
             tok[i] = [last[i], 7, 9, 11]
             pos[i] = len(prompt) + np.arange(R)
-        out['verify'].append(pred._dispatch_verify(tok, pos, tables,
-                                                   logits=True))
+        out['verify'].append(pred._to_host(
+            pred._dispatch_verify(tok, pos, tables, logits=True)))
     elif program == 'step':
         for j in range(3):
             tok = np.zeros((S, 1), np.int64)
@@ -123,7 +124,8 @@ def _dispatches(pred, program):
             for i, prompt in enumerate(prompts):
                 tok[i, 0] = last[i]
                 pos[i, 0] = len(prompt) + j
-            ids, logits = pred._dispatch_step(tok, pos, tables, logits=True)
+            ids, logits = pred._to_host(
+                pred._dispatch_step(tok, pos, tables, logits=True))
             out['step'].append((ids, logits))
             last = ids.tolist()
     return out[program]

@@ -184,6 +184,75 @@ def test_deadline_expiry_mid_decode_frees_slot(artifact):
         assert pred.generate(prompts[1], max_new_tokens=5) == want
 
 
+def test_second_token_comes_from_the_tick_after_the_first(artifact):
+    """A prompt's last slice is read at the END of its tick, behind the
+    running batch's step: the request emits its first token there and
+    joins the step of the NEXT tick — one token a tick from then on, the
+    first delivered before the second."""
+    rng = np.random.RandomState(20)
+    prompt = rng.randint(2, VOCAB, 11)      # chunks (4, 8): two slices
+    with DecodingPredictor(artifact) as pred:
+        run_tick, seen = pred._run_tick, []
+
+        def tick(waiting):
+            run_tick(waiting)
+            seen.extend((r.next_start, r.prefilling, r.produced)
+                        for r in pred._active_requests())
+        pred._run_tick = tick
+        stream = pred.submit(prompt, max_new_tokens=5)
+        got = list(stream)
+        pred._run_tick = run_tick
+        want = pred.generate(prompt, max_new_tokens=5)
+    assert got == want == stream.result(10)
+    # after each tick: (prompt tokens prefilled, still prefilling, emitted);
+    # the tick that emits the fifth token finishes the request
+    assert seen == [(8, True, 0), (11, False, 1), (11, False, 2),
+                    (11, False, 3), (11, False, 4)]
+
+
+def test_a_failing_chunk_program_fails_every_request_loudly(artifact):
+    """A slice's dispatch that raises reaches _fail_all inside the tick:
+    the stream that was decoding (its step was dispatched in front of the
+    slices and is never read), the requests admitting and, as they admit
+    in turn, the ones that waited all resolve with the error — none
+    hangs — and the endpoint serves again once the program does. Counted,
+    not timed: the scheduler's own thread holds the tick after the first
+    token until the others are queued, and breaks the chunk programs
+    before any of them has a slice dispatched."""
+    import threading
+    # prompts[0] decodes past three tokens (the mid-decode deadline test's)
+    prompts = _prompts(17, 1) + _prompts(21, SLOTS + 2)
+    queued = threading.Event()
+
+    def boom(*args):
+        raise RuntimeError('chunk program broke')
+
+    with DecodingPredictor(artifact) as pred:
+        want = pred.generate(prompts[0], max_new_tokens=6)
+        calls = {c: m.call for c, m in pred._chunk_mods.items()}
+        run_tick = pred._run_tick
+
+        def tick(waiting):
+            if any(r.produced for r in pred._active_requests()):
+                assert queued.wait(60)
+                for m in pred._chunk_mods.values():
+                    m.call = boom
+            run_tick(waiting)
+        pred._run_tick = tick
+        streams = [pred.submit(prompts[0], max_new_tokens=57)]
+        assert next(iter(streams[0])) == want[0]    # it holds a slot
+        streams += [pred.submit(p, max_new_tokens=6) for p in prompts[1:]]
+        queued.set()
+        for s in streams:
+            with pytest.raises(RuntimeError, match='chunk program broke'):
+                s.result(60)
+        pred._run_tick = run_tick
+        for c, m in pred._chunk_mods.items():
+            m.call = calls[c]
+        assert pred._free_slots() == list(range(SLOTS))
+        assert pred.generate(prompts[0], max_new_tokens=6) == want
+
+
 def test_max_queue_shedding(artifact):
     """Submissions beyond max_queue waiting requests fast-fail with
     ServerOverloaded before any device work; admitted requests finish."""

@@ -505,6 +505,164 @@ def test_chunked_prefill_admits_beyond_largest_chunk(arts):
     assert s['chunk_slices'] >= 3              # 23 tokens over 8-chunks
 
 
+# -- the tick's order (ISSUE 29): the step first, the slices behind it,
+# one read of the device a tick — the same tokens whatever tick a row
+# joins in -------------------------------------------------------------
+
+# What the PARENT commit (PR 28, aa11165) served for _mixed_prompts() from
+# draft_k=3 artifacts of the three pools: six greedy transcripts and the
+# beam-3 hypotheses and scores of the 13-token prompt — alone or together,
+# the parent served the same.
+_PARENT_TOGETHER = {
+    'f32': {
+        'greedy': [[19, 29, 29, 29, 29, 29, 29, 17, 16, 16],
+                   [40, 40, 40, 40, 40, 40, 40, 40, 40, 40],
+                   [16, 16, 16, 16, 16, 16, 16, 16, 23, 16],
+                   [40, 40, 40, 23, 17, 9, 23, 40, 23, 23],
+                   [40, 16, 40, 16, 40, 23, 40, 23, 16, 16],
+                   [16, 16, 16, 16, 16, 16, 40, 23, 16, 23]],
+        'beam': ([[16, 16, 16, 16, 23, 16, 16, 16],
+                  [16, 16, 16, 16, 40, 23, 16, 16],
+                  [16, 16, 16, 16, 40, 16, 16, 16]],
+                 [-16.60764288641112,
+                  -16.704687913167376,
+                  -16.80076966118413])},
+    'bf16': {
+        'greedy': [[19, 29, 29, 29, 29, 29, 29, 17, 16, 16],
+                   [40, 40, 40, 40, 40, 40, 40, 40, 40, 40],
+                   [16, 16, 16, 16, 16, 16, 16, 16, 23, 16],
+                   [40, 40, 40, 23, 17, 9, 23, 40, 23, 23],
+                   [40, 16, 40, 16, 40, 23, 40, 23, 16, 16],
+                   [16, 16, 16, 16, 16, 16, 40, 23, 16, 23]],
+        'beam': ([[16, 16, 16, 16, 23, 16, 16, 16],
+                  [16, 16, 16, 16, 40, 23, 16, 16],
+                  [16, 16, 16, 16, 40, 16, 16, 16]],
+                 [-16.60848130747962,
+                  -16.703123644847444,
+                  -16.799922178149544])},
+    'int8': {
+        'greedy': [[31, 31, 31, 18, 20, 7, 14, 20, 20, 20],
+                   [31, 31, 31, 31, 31, 38, 21, 31, 31, 31],
+                   [31, 31, 31, 31, 31, 31, 31, 31, 31, 31],
+                   [31, 31, 31, 31, 31, 31, 31, 31, 31, 31],
+                   [20, 20, 20, 20, 20, 20, 20, 20, 20, 20],
+                   [31, 20, 20, 20, 31, 31, 20, 20, 20, 20]],
+        'beam': ([[0, 31, 31, 31, 31, 31, 31, 31],
+                  [0, 31, 39, 31, 31, 31, 31, 31],
+                  [0, 31, 31, 31, 31, 31, 18, 0]],
+                 [-19.928895083176876,
+                  -20.055627483755494,
+                  -20.150544523072558])},
+}
+_POOLS = {'f32': {}, 'bf16': {'kv_cache_dtype': 'bfloat16'},
+          'int8': {'kv_cache_dtype': 'int8'}}
+
+
+def _mixed_prompts():
+    """3 to 23 tokens over chunks of 4 and 8: one to three slices each."""
+    rng = np.random.RandomState(39)
+    return [rng.randint(2, VOCAB, n) for n in (3, 8, 13, 23, 5, 11)]
+
+
+@pytest.fixture(scope='module')
+def k3_arts(tmp_path_factory):
+    t = tmp_path_factory.mktemp('kvblocks_k3')
+    made = {}
+
+    def get(pool):
+        if pool not in made:
+            made[pool] = _build(str(t / pool), chunk_sizes=(4, 8),
+                                block_size=4, draft_k=3, **_POOLS[pool])
+        return made[pool]
+    return get
+
+
+@pytest.mark.parametrize('mode', ['together', 'prefix_hit', 'draft'])
+@pytest.mark.parametrize('pool', ['f32', 'bf16', 'int8'])
+def test_served_together_is_what_the_parent_served(k3_arts, pool, mode):
+    """Seven requests over four slots, prompts of one to three slices,
+    a beam among the greedy rows: every transcript and beam score is the
+    parent's, bit for bit — served cold, again over the prefix cache the
+    first serve filled, and with the n-gram drafter's verify ticks in
+    front of the step. (An int8 prefix hit attends the covered span
+    through its quantized pages: ids as the parent's, scores within the
+    quantization step, as test_block_int8_pages_... pins.)"""
+    prompts = _mixed_prompts()
+    want = _PARENT_TOGETHER[pool]
+    kw = {'draft': 'ngram'} if mode == 'draft' else {}
+
+    def serve(pred):
+        streams = [pred.submit(p, max_new_tokens=10) for p in prompts[:3]]
+        beam = pred.submit(prompts[2], max_new_tokens=8, beam=3)
+        streams += [pred.submit(p, max_new_tokens=10) for p in prompts[3:]]
+        return [s.result(120) for s in streams], beam.result(120)
+
+    with DecodingPredictor(k3_arts(pool), **kw) as pred:
+        greedy, beam = serve(pred)
+        if mode == 'prefix_hit':
+            cold = pred.stats.snapshot()
+            greedy, beam = serve(pred)
+        snap = pred.stats.snapshot()
+    assert greedy == want['greedy']
+    np.testing.assert_array_equal(beam[0], want['beam'][0])
+    if mode == 'prefix_hit':
+        assert snap['prefix_hits'] > cold['prefix_hits']
+        assert (snap['chunk_slices'] - cold['chunk_slices']
+                < cold['chunk_slices'])
+    if mode == 'prefix_hit' and pool == 'int8':
+        np.testing.assert_allclose(beam[1], want['beam'][1], atol=0.05)
+    else:
+        np.testing.assert_array_equal(beam[1], want['beam'][1])
+    if mode == 'draft':
+        assert snap['verify_steps'] > 0
+    # a slice is read iff it was its prompt's last
+    assert snap['slice_reads'] == snap['requests'] == \
+        (14 if mode == 'prefix_hit' else 7)
+    assert snap['chunk_slices'] >= snap['slice_reads']
+
+
+def test_served_together_is_the_plain_references_argmax(arts):
+    """The same mixed prompts through the scheduler, all in flight at
+    once: every served token is the argmax of
+    benchmark/reference/decoder_lm.py's full forward pass over the tokens
+    before it, wherever the reference's top two are further apart than
+    float32 summation order can move them."""
+    from benchmark.reference import decoder_lm
+    prompts = _mixed_prompts()
+    with DecodingPredictor(arts['block']) as pb:
+        streams = [pb.submit(p, max_new_tokens=10) for p in prompts]
+        served = [s.result(120) for s in streams]
+    compared = 0
+    for prompt, toks in zip(prompts, served):
+        seq = np.concatenate([prompt, toks[:-1]])
+        ref = np.asarray(decoder_lm.logits(
+            arts['weights'], seq, n_head=_MODEL['n_head'],
+            n_layer=_MODEL['n_layer']))[len(prompt) - 1:]
+        top = np.sort(ref, axis=-1)
+        for row, margin, tok in zip(ref, top[:, -1] - top[:, -2], toks):
+            if margin > 1e-4:
+                compared += 1
+                assert tok == int(np.argmax(row))
+    assert compared >= 40
+
+
+def test_slice_reads_counts_the_prompts_last_slices(arts):
+    """chunk_slices counts every slice dispatched, slice_reads the ones
+    whose id the host waited for and copied: one a prompt. reset() zeroes
+    both."""
+    prompts = _mixed_prompts()
+    # chunks (4, 8): 3 -> 1 slice, 8 -> 1, 13 -> 2, 23 -> 3, 5 -> 1, 11 -> 2
+    with DecodingPredictor(arts['block']) as pb:
+        for p in prompts:
+            pb.generate(p, max_new_tokens=3)
+        snap = pb.stats.snapshot()
+        pb.stats.reset()
+        zero = pb.stats.snapshot()
+    assert snap['chunk_slices'] == 10 and snap['prefills'] == 10
+    assert snap['slice_reads'] == 6
+    assert zero['chunk_slices'] == zero['slice_reads'] == 0
+
+
 def test_mp_sharded_decode_transcripts_match_single_chip(arts,
                                                          tmp_path):
     """ISSUE 13 acceptance: the 2-chip mp-sharded decode artifact's

@@ -55,6 +55,14 @@ class _Trace(object):
                    and p[1] <= s + _TOL_NS and e <= p[2] + _TOL_NS]
         return max(holders, key=lambda p: p[1]) if holders else None
 
+    def inside(self, holder, name, program=''):
+        """The spans called `name` (whose 'program' stat starts with
+        `program`) that `holder` holds on its own thread."""
+        return [s for s in self.named(name)
+                if s[3] == holder[3] and holder[1] <= s[1] + _TOL_NS
+                and s[2] <= holder[2] + _TOL_NS
+                and s[4].get('program', '').startswith(program)]
+
 
 def _traced(tmp, body):
     import jax
@@ -132,9 +140,12 @@ def test_decode_span_is_in_the_trace(decode_trace, name):
     ('decode/build_feed', ('decode/step',)),
     ('decode/advance', ('decode/step',)),
     ('decode/dispatch', ('decode/step', 'decode/prefill_slice')),
-    ('decode/device_wait', ('decode/step', 'decode/prefill_slice')),
-    ('decode/d2h', ('decode/step', 'decode/prefill_slice')),
-    ('decode/first_token', ('decode/prefill_slice',)),
+    # a slice is dispatched inside its span and never waited for there;
+    # a prompt's last slice is read at the end of the tick, in no span
+    # but the tick's
+    ('decode/device_wait', ('decode/step', 'decode/tick')),
+    ('decode/d2h', ('decode/step', 'decode/tick')),
+    ('decode/first_token', ('decode/tick',)),
     ('decode/finish', ('decode/advance', 'decode/first_token')),
 ])
 def test_decode_children_lie_inside_their_parents(decode_trace, child,
@@ -199,6 +210,45 @@ def test_step_d2h_bytes_is_the_ids(decode_trace):
     assert {'step', 'chunk_4', 'chunk_8', 'zeros'} <= programs
     ticks = [s[4]['tick'] for s in decode_trace.named('decode/tick')]
     assert ticks == sorted(ticks) and len(set(ticks)) == len(ticks)
+
+
+def test_a_slice_is_dispatched_and_only_a_prompts_last_is_read(decode_trace):
+    """Inside its span a slice is a dispatch and nothing else — no wait,
+    no copy; `last` says whether the tick will read it, and the reads of
+    the trace are exactly the prompts' last slices."""
+    slices = decode_trace.named('decode/prefill_slice')
+    assert {s[4]['last'] for s in slices} == {0, 1}
+    for sl in slices:
+        assert len(decode_trace.inside(sl, 'decode/dispatch')) == 1
+        assert not decode_trace.inside(sl, 'decode/device_wait')
+        assert not decode_trace.inside(sl, 'decode/d2h')
+    reads = [s for s in decode_trace.named('decode/d2h')
+             if s[4]['program'].startswith('chunk_')]
+    assert len(reads) == sum(s[4]['last'] for s in slices) == 3
+    assert len(decode_trace.named('decode/first_token')) == 3
+
+
+def test_the_step_goes_first_and_is_read_before_any_slice(decode_trace):
+    """In a tick that holds both, the step is dispatched before the first
+    slice, every slice is dispatched before the step is waited for, and
+    the step's tokens are out (decode/advance ends) before the first
+    slice's result is touched."""
+    both = 0
+    for tick in decode_trace.named('decode/tick'):
+        step = decode_trace.inside(tick, 'decode/dispatch', 'step')
+        chunks = decode_trace.inside(tick, 'decode/dispatch', 'chunk_')
+        if not step or not chunks:
+            continue
+        both += 1
+        assert len(step) == 1
+        assert step[0][2] <= chunks[0][1] + _TOL_NS
+        wait, = decode_trace.inside(tick, 'decode/device_wait', 'step')
+        assert chunks[-1][2] <= wait[1] + _TOL_NS
+        advance, = decode_trace.inside(tick, 'decode/advance')
+        for read in decode_trace.inside(tick, 'decode/device_wait',
+                                        'chunk_'):
+            assert advance[2] <= read[1] + _TOL_NS
+    assert both >= 1
 
 
 @pytest.mark.parametrize('sub,name', [
