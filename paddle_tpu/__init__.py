@@ -30,7 +30,7 @@ from . import ops as _ops  # registers all op lowerings
 
 from .framework import (Program, Block, Operator, Variable, Parameter,
                         default_main_program, default_startup_program,
-                        program_guard, switch_main_program,
+                        program_guard, name_scope, switch_main_program,
                         switch_startup_program, convert_dtype,
                         CPUPlace, TPUPlace, CUDAPlace, CUDAPinnedPlace)
 from .executor import Executor, global_scope, scope_guard, Scope

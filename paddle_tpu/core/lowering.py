@@ -151,7 +151,9 @@ class Tracer(object):
         # its op_name metadata ('jit(train_step)/.../conv2d/...'), so a
         # device trace's ops can be traced back to the op that made them;
         # trace-time only, nothing at run time
-        with jax.named_scope(t):
+        # (under fluid.name_scope: '<scope>/<op type>/...')
+        scope = op.attrs.get('op_namescope')
+        with jax.named_scope('%s/%s' % (scope, t) if scope else t):
             return self._lower_op(op, block)
 
     def _lower_op(self, op, block):

@@ -194,6 +194,8 @@ class Operator(object):
             program = block.program
             program._op_uid_counter += 1
             self.attrs['_op_uid'] = program._op_uid_counter
+        if _name_scopes and 'op_namescope' not in self.attrs:
+            self.attrs['op_namescope'] = '/'.join(_name_scopes)
 
     def input(self, slot):
         return self.inputs.get(slot, [])
@@ -453,6 +455,24 @@ def switch_startup_program(program):
     global _startup_program_
     prev, _startup_program_ = _startup_program_, program
     return prev
+
+
+_name_scopes = []
+
+
+@contextlib.contextmanager
+def name_scope(prefix):
+    """Ops built inside carry the scope in attr 'op_namescope' (nested
+    scopes joined by '/'; ref: fluid/framework.py name_scope), and their
+    lowering runs under jax.named_scope of it (core/lowering.py run_op):
+    in a device trace the op_name of what they emit reads
+    '.../<prefix>/<op type>/...', so a sub-layer built from generic ops
+    (a shared expert out of mul + swiglu) can be told apart."""
+    _name_scopes.append(str(prefix))
+    try:
+        yield
+    finally:
+        _name_scopes.pop()
 
 
 @contextlib.contextmanager

@@ -85,12 +85,14 @@ import numpy as np
 try:
     from . import serve as _serve
     from . import batching as _batching
-    from .kv_blocks import BlockManager, BlockPoolExhausted, TRASH_BLOCK
+    from .kv_blocks import (BlockManager, BlockPoolExhausted, TRASH_BLOCK,
+                            WindowTable)
 except ImportError:  # imported by file path: siblings sit alongside
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
     import serve as _serve
     import batching as _batching
-    from kv_blocks import BlockManager, BlockPoolExhausted, TRASH_BLOCK
+    from kv_blocks import (BlockManager, BlockPoolExhausted, TRASH_BLOCK,
+                           WindowTable)
 
 _STOP = object()
 _WAKE = object()   # no-op queue item: rouse an idle scheduler (drain)
@@ -385,6 +387,11 @@ class DecodeStats(object):
         snap['prefix_hit_rate'] = float(bs['prefix_hit_rate'])
         snap['prefix_tokens_reused'] = int(bs['prefix_tokens_reused'])
         snap['block_evictions'] = int(bs['evictions'])
+        # the window layers' pool, where the artifact has one
+        for k in ('window_blocks_in_use', 'window_blocks_peak',
+                  'window_blocks_released'):
+            if k in bs:
+                snap[k] = int(bs[k])
         return snap
 
 
@@ -552,7 +559,7 @@ class _Request(object):
     __slots__ = ('prompt', 'max_new', 'beam', 'stream', 't_submit',
                  'deadline', 'slots', 'produced', 'tokens', 'last_tokens',
                  'scores', 'finished', 'hyps', 't_first', 't_last',
-                 'tables', 'next_start', 'prefilling', 'match',
+                 'tables', 'wtable', 'next_start', 'prefilling', 'match',
                  'match_epoch', 'draft_strikes', 'draft_cooldown',
                  'request_id', 'seq')
 
@@ -578,6 +585,7 @@ class _Request(object):
         self.t_last = None
         # block tables and chunked prefill (ISSUE 13)
         self.tables = []                  # per beam: logical block ids
+        self.wtable = None                # window layers' (WindowTable)
         self.next_start = 0               # next chunked-prefill position
         self.prefilling = False           # still admitting via chunks
         self.match = None                 # cached (shared blocks, covered)
@@ -901,6 +909,12 @@ class DecodingPredictor(object):
         self._nb = int(blk['num_blocks'])
         self._maxb = int(blk['max_blocks_per_slot'])
         self._trash = TRASH_BLOCK
+        # window layers (ISSUE 30): a pool and a table of their own,
+        # which keep only what is still inside a window; the programs
+        # then take a second table feed
+        win = blk.get('window')
+        self._window = int(win['length']) if win else 0
+        self._wnb = int(win['num_blocks']) if win else 0
         # the block allocator itself is built (and wired into
         # stats.block_source) by _reset_state — the single owner.
         # Chunked prefill: prompts admit in fixed slices, so the prompt
@@ -1053,6 +1067,11 @@ class DecodingPredictor(object):
                 raise ValueError(
                     'beam width %d not in [1, max_slots=%d]'
                     % (beam, self._S))
+            if beam is not None and self._window:
+                raise ValueError(
+                    'beam search is refused on an artifact with window '
+                    'layers: their blocks are not shared, so a beam '
+                    'cannot fork its history there')
         except Exception as e:
             stream._fail(e)
             return stream
@@ -1100,12 +1119,15 @@ class DecodingPredictor(object):
                 'decoding, and a caller-thread dispatch would race the '
                 "scheduler over the donated cache state")
         trash_tables = np.full((self._S, self._maxb), self._trash, np.int32)
+        wtables = trash_tables if self._window else None
         for c in self._chunks:
             self._to_host(self._dispatch_chunk(
-                c, np.zeros((1, c), np.int64), 0, 1, trash_tables[:1]))
+                c, np.zeros((1, c), np.int64), 0, 1, trash_tables[:1],
+                window_row=None if wtables is None else wtables[:1]))
         self._to_host(self._dispatch_step(
             np.zeros((self._S, 1), np.int64),
-            np.zeros((self._S, 1), np.int32), trash_tables))
+            np.zeros((self._S, 1), np.int32), trash_tables,
+            wtables=wtables))
         self._dispatch_blockcopy([])      # identity (trash-to-trash)
         if self._verify_mod is not None:
             # all-pad verify dispatch (ISSUE 17): every row at the pad
@@ -1226,7 +1248,9 @@ class DecodingPredictor(object):
         with self._dev_ctx():
             self._state = list(self._zeros_mod.call(
                 self._feed(np.zeros((1,), np.int32))))
-        self._blocks = BlockManager(self._nb, self._bs)
+        self._blocks = BlockManager(
+            self._nb, self._bs,
+            window=(self._wnb, self._window) if self._window else None)
         # block-cache gauges + prefix-share accounting merge into
         # stats.snapshot() (serving_report's block columns)
         self.stats.block_source = self._blocks.stats
@@ -1270,12 +1294,15 @@ class DecodingPredictor(object):
                 self.stats.logits_fetches += 1
         return host[0], host[1] if logits else None
 
-    def _dispatch_step(self, tokens, pos, tables, logits=False):
+    def _dispatch_step(self, tokens, pos, tables, logits=False,
+                       wtables=None):
         """Dispatch one decode step and ask for its ids [S] int32 and,
         if `logits`, the [S, V] float32 rows they are the argmax of.
-        Returns the read (_to_host), unmade: the call is enqueued, the
-        device may not have started."""
-        feed = {'tokens': tokens, 'pos': pos, 'block_tables': tables}
+        `wtables`: the window layers' tables, on an artifact that has
+        such layers. Returns the read (_to_host), unmade: the call is
+        enqueued, the device may not have started."""
+        feed = {'tokens': tokens, 'pos': pos, 'block_tables': tables,
+                'window_tables': wtables}
         args = [self._feed(feed[n])
                 for n in self._step_feeds]  # signature feed order
         with self._dev_ctx():
@@ -1305,11 +1332,13 @@ class DecodingPredictor(object):
         return self._ask(fetches, 'verify', logits)
 
     def _dispatch_chunk(self, size, ids, start, take, table_row,
-                        logits=False, read=True):
+                        logits=False, read=True, window_row=None):
         """Dispatch one chunked-prefill slice: `take` real rows of one
         prompt at absolute positions start..start+take-1 (the rest of
         the `size` rows are pad) write through `table_row` [1,
-        max_blocks]. With `read` — the slice is its prompt's last —
+        max_blocks] (and `window_row`, the window layers' table, where
+        the artifact has such layers). With `read` — the slice is its
+        prompt's last —
         asks for the id its last real position chose and, if `logits`,
         that position's [V] row, and returns the read (_to_host gives
         them with a leading axis of 1: _one_row), unmade. Without, it
@@ -1319,7 +1348,8 @@ class DecodingPredictor(object):
         feed = {'chunk_ids': ids,
                 'start': np.full((1, 1), start, np.int32),
                 'chunk_len': np.full((1, 1), take, np.int32),
-                'block_table': np.asarray(table_row, np.int32)}
+                'block_table': np.asarray(table_row, np.int32),
+                'window_table': window_row}
         args = [self._feed(feed[n]) for n in self._chunk_feeds[size]]
         with self._dev_ctx():
             fetches, new_state = self._chunk_mods[size].call(
@@ -1370,6 +1400,9 @@ class DecodingPredictor(object):
         for t in req.tables:
             self._blocks.decref(t)
         req.tables = []
+        if req.wtable is not None:
+            self._blocks.window_free(req.wtable)
+            req.wtable = None
         self._drop_match(req)
 
     def _drop_match(self, req):
@@ -1387,6 +1420,24 @@ class DecodingPredictor(object):
         row = np.full((1, self._maxb), self._trash, np.int32)
         row[0, :len(table)] = table
         return row
+
+    def _window_advance(self, rows):
+        """Before a dispatch: for every (request, first query position,
+        one past the last position written) of `rows`, give back the
+        window-layer blocks no live window reaches any more and add
+        those the dispatch writes (BlockManager.window_advance)."""
+        with _span('decode/window_release', slots=len(rows)) as sp:
+            released = sum(
+                self._blocks.window_advance(
+                    req.wtable, first - self._window + 1, end)
+                for req, first, end in rows)
+            sp.set_metadata(blocks=released)
+
+    def _window_row(self, req):
+        """One request's window-layer table row [1, max_blocks], trash
+        wherever no block is held."""
+        return req.wtable.fill(
+            np.full(self._maxb, self._trash, np.int32))[None]
 
     def _sched_loop(self):
         waiting = deque()
@@ -1614,7 +1665,10 @@ class DecodingPredictor(object):
                 # re-hashing its prompt (and counting a fresh miss)
                 # every scheduler tick
                 req.match_epoch = self._blocks.prefix_epoch
-                req.match = self._blocks.match_prefix(req.prompt)
+                # window layers keep no prefix: nothing to look up (the
+                # manager refuses by name)
+                req.match = (([], 0) if self._window
+                             else self._blocks.match_prefix(req.prompt))
             shared, covered = req.match
             try:
                 fresh = self._blocks.alloc(
@@ -1640,6 +1694,8 @@ class DecodingPredictor(object):
                 with self.stats._lock:
                     self.stats.queue_depth -= 1
                 req.tables = [list(shared) + list(fresh)]
+                if self._window:
+                    req.wtable = WindowTable()
                 req.next_start = int(covered)
                 req.prefilling = True
                 req.slots = free[:need]
@@ -1682,10 +1738,16 @@ class DecodingPredictor(object):
         logits row the host reads)."""
         ids = np.zeros((1, size), np.int64)
         ids[0, :take] = req.prompt[req.next_start:req.next_start + take]
+        window_row = None
+        if self._window:
+            self._window_advance(
+                [(req, req.next_start, req.next_start + take)])
+            window_row = self._window_row(req)
         read = self._dispatch_chunk(
             size, ids, req.next_start, take,
             self._table_row(req.tables[0]),
-            logits=last and req.beam is not None, read=last)
+            logits=last and req.beam is not None, read=last,
+            window_row=window_row)
         req.next_start += take
         return read
 
@@ -1698,9 +1760,10 @@ class DecodingPredictor(object):
         with self.stats._lock:
             self.stats.slice_reads += 1
         req.prefilling = False
-        # publish the prompt's FULL blocks for prefix reuse (the
-        # partial tail stays private: decode writes land there)
-        self._blocks.register_prefix(req.prompt, req.tables[0])
+        if not self._window:
+            # publish the prompt's FULL blocks for prefix reuse (the
+            # partial tail stays private: decode writes land there)
+            self._blocks.register_prefix(req.prompt, req.tables[0])
         with _req_span('decode/first_token', req):
             self._first_token(req, tok, logits)
 
@@ -1813,7 +1876,7 @@ class DecodingPredictor(object):
             if drafted:
                 self._verify(drafted, waiting)
             with _span('decode/build_feed'):
-                tokens, pos, tables, cow, active, beam = \
+                tokens, pos, tables, wtables, cow, active, beam = \
                     self._step_feed(waiting, drafted)
             sp.set_metadata(active=active)
             if not active:
@@ -1823,7 +1886,8 @@ class DecodingPredictor(object):
                 self.stats.slot_steps += self._S
             if cow:
                 self._dispatch_blockcopy(cow)
-            return (self._dispatch_step(tokens, pos, tables, logits=beam),
+            return (self._dispatch_step(tokens, pos, tables, logits=beam,
+                                        wtables=wtables),
                     drafted, active)
 
     def _read_step(self, read, drafted, active):
@@ -1839,12 +1903,17 @@ class DecodingPredictor(object):
     def _step_feed(self, waiting, drafted):
         """The plain step's feed over the block pool: reserve and make
         writable every block this step writes, then fill tokens / pos /
-        tables for the live undrafted rows. Returns them with the CoW
-        pairs to copy first, the number of live rows, and whether one of
-        them is a beam's (the step's logits are then wanted)."""
+        tables for the live undrafted rows — the window layers' too,
+        where the artifact has such layers (None otherwise), after
+        giving back the blocks each row's window has passed. Returns
+        them with the CoW pairs to copy first, the number of live rows,
+        and whether one of them is a beam's (the step's logits are then
+        wanted)."""
         tokens = np.zeros((self._S, 1), np.int64)
         pos = np.zeros((self._S, 1), np.int32)
         tables = np.full((self._S, self._maxb), self._trash, np.int32)
+        wtables = (np.full((self._S, self._maxb), self._trash, np.int32)
+                   if self._window else None)
         self._preflight_blocks(
             waiting,
             rows_fn=lambda: [(r, b, p, 1) for r, b, p
@@ -1852,7 +1921,10 @@ class DecodingPredictor(object):
         cow = []
         active = 0
         beam = False
-        for req, bi, p in self._live_rows(skip=drafted):
+        rows = self._live_rows(skip=drafted)
+        if self._window:
+            self._window_advance([(req, p, p + 1) for req, _, p in rows])
+        for req, bi, p in rows:
             self._ensure_writable(req, bi, p, cow)
             s = req.slots[bi]
             active += 1
@@ -1861,7 +1933,9 @@ class DecodingPredictor(object):
             pos[s, 0] = p
             table = req.tables[bi]
             tables[s, :len(table)] = table
-        return tokens, pos, tables, cow, active, beam
+            if self._window:
+                req.wtable.fill(wtables[s])
+        return tokens, pos, tables, wtables, cow, active, beam
 
     def _advance(self, ids, logits, drafted):
         """After the step: emit the ids the program chose to the greedy

@@ -417,6 +417,19 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                    time.
       block_size / num_blocks / max_blocks_per_slot /
       max_slots / max_cache_len / eos_id / vocab.
+      window       (optional) {'length', 'num_blocks', 'cache_vars'}:
+                   the cache vars of sliding-window layers, which attend
+                   the last `length` positions only. They form a pool of
+                   their own ([num_blocks, block_size, ...], at least
+                   full capacity: max_slots x kv_blocks.
+                   window_blocks_per_slot + the trash block) addressed
+                   through a SECOND table the scheduler keeps per
+                   request and from which it drops what the window has
+                   passed: the step then also feeds 'window_tables'
+                   [max_slots, max_blocks] and a chunk 'window_table'
+                   [1, max_blocks]. Every other cache var is a full
+                   layer's. Such a spec has no verify program, and the
+                   loader refuses beams and prefix reuse on it by name.
 
     Every program is traced ONCE as fn(params, state, feeds) ->
     (fetches, new_state). The exported program returns TWO fetches
@@ -510,7 +523,13 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
         # shape and dtype only: the pool itself never leaves the device
         state_specs.append(jax.ShapeDtypeStruct(np.shape(val), val.dtype))
     step = spec['step']
+    window = spec.get('window')
     step_want = ['block_tables', 'pos', 'tokens']
+    chunk_want = ['block_table', 'chunk_ids', 'chunk_len', 'start']
+    if window is not None:
+        _check_window(spec, state_names, state_specs)
+        step_want = sorted(step_want + ['window_tables'])
+        chunk_want = sorted(chunk_want + ['window_table'])
     if sorted(step['feeds']) != step_want:
         raise ValueError("decode-step feeds must be %r, got %r"
                          % (step_want, step['feeds']))
@@ -529,11 +548,9 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
         raise ValueError("export_decode needs at least one chunk size")
     for C in chunks:
         p = spec['chunk'][C]
-        if sorted(p['feeds']) != ['block_table', 'chunk_ids',
-                                  'chunk_len', 'start']:
-            raise ValueError(
-                "chunk feeds must be ['chunk_ids', 'start', "
-                "'chunk_len', 'block_table'], got %r" % (p['feeds'],))
+        if sorted(p['feeds']) != chunk_want:
+            raise ValueError("chunk feeds must be %r, got %r"
+                             % (chunk_want, p['feeds']))
         entries[_decoding._CHUNK_DIR % C] = p
     programs = {d: _optimize_decode_program(e, state_names)
                 for d, e in entries.items()}
@@ -555,7 +572,9 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                 state_specs, os.path.join(out_dir, d), shard=shard)
     _export_decode_blockcopy(
         state_specs, int(spec['max_slots']),
-        os.path.join(out_dir, _decoding._BLOCKCOPY_DIR), shard=shard)
+        os.path.join(out_dir, _decoding._BLOCKCOPY_DIR), shard=shard,
+        copied=[n not in (window or {}).get('cache_vars', ())
+                for n in state_names])
     _export_decode_zeros(state_specs,
                          os.path.join(out_dir, _decoding._ZEROS_DIR),
                          shard=shard)
@@ -593,6 +612,11 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     if verify is not None:
         sig['verify'] = dict(sigs[_decoding._VERIFY_DIR],
                              draft_k=int(spec['draft_k']))
+    if window is not None:
+        sig['block']['window'] = {
+            'length': int(window['length']),
+            'num_blocks': int(window['num_blocks']),
+            'cache_vars': list(window['cache_vars'])}
     if shard is not None:
         sig['mesh'] = {'axes': {a: int(n) for a, n in
                                 shard['axes'].items()},
@@ -831,19 +855,55 @@ def _export_decode_zeros(state_specs, out_dir, shard=None):
                       out_shardings=shard and list(shard['state_ns']))
 
 
-def _export_decode_blockcopy(state_specs, max_pairs, out_dir, shard=None):
+def _check_window(spec, state_names, state_specs):
+    """A spec with window layers: its window vars are cache vars of one
+    pool shape, the pool holds every slot's worst case (so the scheduler
+    never has to shed for it), and nothing the window tables do not
+    support rides along."""
+    from .kv_blocks import window_blocks_per_slot
+    window = spec['window']
+    names = list(window['cache_vars'])
+    unknown = [n for n in names if n not in state_names]
+    if unknown or not names:
+        raise ValueError("spec['window']['cache_vars'] must name cache "
+                         "vars, got %r" % (names,))
+    if spec.get('verify') is not None:
+        raise ValueError('a spec with window layers has no speculative '
+                         'verify program: kv_block_verify_* know no '
+                         'window')
+    nbw = int(window['num_blocks'])
+    for n in names:
+        shape = state_specs[state_names.index(n)].shape
+        if shape[0] != nbw or shape[1] != int(spec['block_size']):
+            raise ValueError(
+                'window cache var %r is %r, not [%d, %d, ...]'
+                % (n, tuple(shape), nbw, int(spec['block_size'])))
+    need = int(spec['max_slots']) * window_blocks_per_slot(
+        window['length'], max(int(c) for c in spec['chunk']),
+        spec['block_size']) + 1
+    if nbw < need:
+        raise ValueError(
+            'window pool of %d blocks is under full capacity (%d): the '
+            'scheduler does not shed for the window layers' % (nbw, need))
+
+
+def _export_decode_blockcopy(state_specs, max_pairs, out_dir, copied,
+                             shard=None):
     """Serialize the block-copy program: up to `max_pairs` (dst, src)
     PHYSICAL-BLOCK pairs copy per dispatch —
     new_state[i] = state[i].at[dst].set(state[i][src]) for every pool
-    var. This is beam copy-on-write's device half: the scheduler copies
-    only the DIVERGED partial tail blocks of a reordered beam group (and
+    var that `copied` marks: all but the window layers', whose blocks
+    are never shared (the pairs index the full layers' pool). This is
+    beam copy-on-write's device half: the scheduler copies only the
+    DIVERGED partial tail blocks of a reordered beam group (and
     pads unused pairs with (0, 0) — a trash-to-trash self-copy), so
     the bytes a reorder moves scale with diverged blocks. Donated at
     load (in-place on the live pool)."""
     import jax
 
     def fn(state_list, dst, src):
-        return [s.at[dst].set(s[src]) for s in state_list]
+        return [s.at[dst].set(s[src]) if copy else s
+                for s, copy in zip(state_list, copied)]
 
     idx_spec = jax.ShapeDtypeStruct((max_pairs,), np.int32)
     state_ns = shard and list(shard['state_ns'])

@@ -26,6 +26,21 @@ refcounted so histories are SHARED instead of copied:
                      under pressure the LRU prefix entries evict first
                      (eviction accounting in `stats`)
 
+Two KINDS of layer (ISSUE 30). A full-attention layer reads every
+position of a request, so its pool keeps a request's blocks until the
+request ends — everything above. A sliding-window layer reads the last
+`window` positions only, so holding every position there would size its
+pool by the cache length for rows nobody reads again. Window layers
+therefore have a pool of their own (its own num_blocks, its own trash
+block 0) and a `WindowTable` per request, which names the blocks of the
+positions still inside some live window and nothing else:
+`window_advance` gives back every block the window has passed and adds
+the blocks the next dispatch writes. No sharing there — no fork, no
+prefix entry: a manager with a window pool refuses `match_prefix` /
+`register_prefix` by name, because a prefix hit would have to bring the
+last `window - 1` positions of the prefix for those layers too, and
+does not yet.
+
 `BlockManager` is pure host bookkeeping — stdlib only, framework-free —
 and deliberately knows nothing about devices: the scheduler
 (inference/decoding.py) owns the numpy block tables it feeds the
@@ -39,7 +54,8 @@ import hashlib
 import threading
 from collections import OrderedDict, deque
 
-__all__ = ['BlockManager', 'BlockPoolExhausted', 'TRASH_BLOCK']
+__all__ = ['BlockManager', 'BlockPoolExhausted', 'TRASH_BLOCK',
+           'WindowTable', 'window_blocks_per_slot']
 
 # physical block 0: write target for idle/padded rows, never allocated,
 # never read (attention masks it out and no table maps it)
@@ -69,6 +85,31 @@ class _PrefixEntry(object):
         #   collision guard costs O(L) tokens per prompt, not O(L^2)
 
 
+class WindowTable(object):
+    """One request's blocks in the window pool: `blocks[i]` backs logical
+    block `first + i`; logical blocks below `first` were given back."""
+    __slots__ = ('first', 'blocks')
+
+    def __init__(self):
+        self.first = 0
+        self.blocks = []
+
+    def fill(self, row):
+        """Write the table into `row` (one int32 row of max_blocks
+        columns, trash elsewhere); returns it."""
+        row[self.first:self.first + len(self.blocks)] = self.blocks
+        return row
+
+
+def window_blocks_per_slot(window, max_slice, block_size):
+    """The most window-pool blocks one request holds at any time: the
+    blocks of window - 1 + max_slice consecutive positions, wherever
+    they start (a prefill slice of max_slice rows attends window - 1
+    rows below its first) — at most ceil((window + max_slice) /
+    block_size) + 1."""
+    return -(-(int(window) + int(max_slice)) // int(block_size)) + 1
+
+
 class BlockManager(object):
     """Refcounted allocator over `num_blocks` physical cache blocks of
     `block_size` token positions each (block 0 reserved as trash).
@@ -90,7 +131,7 @@ class BlockManager(object):
     """
 
     def __init__(self, num_blocks, block_size, hash_fn=None,
-                 max_prefix_entries=1024):
+                 max_prefix_entries=1024, window=None):
         if num_blocks < 2:
             raise ValueError('need >= 2 blocks (block 0 is reserved), '
                              'got %d' % num_blocks)
@@ -118,6 +159,18 @@ class BlockManager(object):
         self.prefix_misses = 0
         self.prefix_tokens_reused = 0
         self.evictions = 0
+        # the window layers' pool: `window` = (num_blocks, length)
+        self.window = None
+        if window is not None:
+            wnb, length = int(window[0]), int(window[1])
+            if wnb < 2 or length < 1:
+                raise ValueError('window pool needs >= 2 blocks and a '
+                                 'length >= 1, got %r' % (window,))
+            self.window = length
+            self._wfree = deque(range(1, wnb))
+            self._wcap = wnb - 1
+            self._wpeak = 0
+            self.window_released = 0
 
     # -- allocation --------------------------------------------------------
     def capacity(self):
@@ -254,6 +307,53 @@ class BlockManager(object):
         with self._lock:
             return self._ref[block] == 1
 
+    # -- window layers ----------------------------------------------------
+    def window_advance(self, table, lo, hi):
+        """Make `table` (a WindowTable) hold the blocks of positions
+        [lo, hi) and of nothing below: every block whose last row lies
+        under `lo` returns to the window pool, and blocks are added up
+        to the one that holds hi - 1. The scheduler calls it before each
+        dispatch with lo = (first query position) - window + 1 and hi =
+        one past the last position written, so a block goes back only
+        when no row of it is inside a live window any more. Returns how
+        many blocks it gave back."""
+        bs = self.block_size
+        lo_blk = max(int(lo), 0) // bs
+        hi_blk = (int(hi) - 1) // bs
+        with self._lock:
+            passed = min(max(lo_blk - table.first, 0), len(table.blocks))
+            if passed:
+                self._wfree.extend(table.blocks[:passed])
+                del table.blocks[:passed]
+                self.window_released += passed
+            table.first += passed
+            if not table.blocks:      # everything passed: start at lo
+                table.first = max(table.first, lo_blk)
+            need = hi_blk + 1 - table.first - len(table.blocks)
+            if need > len(self._wfree):
+                raise BlockPoolExhausted(
+                    'window pool: need %d block(s), %d free of %d'
+                    % (need, len(self._wfree), self._wcap))
+            for _ in range(need):
+                table.blocks.append(self._wfree.popleft())
+            self._wpeak = max(self._wpeak, self._wcap - len(self._wfree))
+        return passed
+
+    def window_free(self, table):
+        """Return all of a finished request's window blocks."""
+        with self._lock:
+            self._wfree.extend(table.blocks)
+            table.first += len(table.blocks)
+            del table.blocks[:]
+
+    def _refuse_prefix(self):
+        if self.window is not None:
+            raise ValueError(
+                'prefix reuse is refused on a cache with window layers: '
+                'a hit would have to bring the last %d positions of the '
+                'prefix for them, which nothing keeps'
+                % (self.window - 1))
+
     # -- prefix sharing ----------------------------------------------------
     def _block_keys(self, tokens, n_full):
         """Chained per-block keys: keys[m-1] identifies tokens[:m*bs]
@@ -280,6 +380,7 @@ class BlockManager(object):
         compute something to produce its first-token logits. Hash hits
         verify exact token equality (collision safety): a colliding key
         whose stored tokens differ is a miss, never an alias."""
+        self._refuse_prefix()
         bs = self.block_size
         tokens = [int(t) for t in tokens]
         # cap below len(tokens): never cover the whole prompt
@@ -338,6 +439,7 @@ class BlockManager(object):
         also hit); each entry holds one cache reference per block,
         released on eviction. Idempotent for already-registered
         prefixes."""
+        self._refuse_prefix()
         bs = self.block_size
         tokens = [int(t) for t in tokens]
         n_full = min(len(blocks), len(tokens) // bs)
@@ -409,12 +511,23 @@ class BlockManager(object):
             self.prefix_misses = 0
             self.prefix_tokens_reused = 0
             self.evictions = 0
+            if self.window is not None:
+                self._wpeak = self._wcap - len(self._wfree)
+                self.window_released = 0
 
     # -- accounting --------------------------------------------------------
     def stats(self):
         with self._lock:
             looked = self.prefix_hits + self.prefix_misses
-            return {
+            per_kind = {}
+            if self.window is not None:
+                # the window layers' pool, beside the full layers'
+                per_kind = {
+                    'window_num_blocks': self._wcap,
+                    'window_blocks_in_use': self._wcap - len(self._wfree),
+                    'window_blocks_peak': self._wpeak,
+                    'window_blocks_released': self.window_released}
+            return dict(per_kind, **{
                 'num_blocks': self.capacity(),
                 'block_size': self.block_size,
                 'blocks_in_use': self.in_use_locked(),
@@ -429,4 +542,4 @@ class BlockManager(object):
                                     if looked else 0.0),
                 'prefix_tokens_reused': self.prefix_tokens_reused,
                 'evictions': self.evictions,
-            }
+            })

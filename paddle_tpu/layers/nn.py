@@ -1571,33 +1571,59 @@ def swiglu(gate, up):
 
 
 def moe_topk_ffn(input, num_experts, d_ff, k, norm_topk_prob=False,
-                 dtype=None, param_attr=None, name=None):
+                 dtype=None, param_attr=None, name=None,
+                 scoring='softmax', router_bias=False,
+                 routed_scaling_factor=1.0, num_held=None,
+                 expert_offset=0):
     """Dropless top-k mixture of SwiGLU experts over the last axis of
-    `input` (ops/moe_ops.py moe_topk_ffn): a float32 softmax router
-    [D, num_experts], each token's `k` best experts (weights
-    renormalised only with norm_topk_prob), and per expert gate / up
-    [D, d_ff] and down [d_ff, D] matrices stacked [num_experts, ...].
-    Every (token, expert) pair is computed: there is no capacity.
-    Weights are created in `dtype` (default: input's) under
-    param_attr's name + '_router' / '_gate' / '_up' / '_down'; float32
-    out."""
+    `input` (ops/moe_ops.py moe_topk_ffn): a float32 router
+    [D, num_experts] scored by `scoring` ('softmax' | 'sigmoid'), each
+    token's `k` best experts (weights renormalised only with
+    norm_topk_prob, then times routed_scaling_factor), and per expert
+    gate / up [D, d_ff] and down [d_ff, D] matrices stacked
+    [num_held, ...]. `router_bias` (True, or a ParamAttr of its own)
+    adds a float32 [num_experts] parameter that moves the CHOICE of
+    experts only (a selection bias; the weights stay the scores).
+    `num_held` (default: all) experts starting at `expert_offset` live
+    in this op — one chip's share of an expert-parallel layer: it routes
+    over all num_experts and returns its own experts' part. Every (token, held expert) pair is computed:
+    there is no capacity. Weights are created in `dtype` (default:
+    input's) under param_attr's name + '_router' / '_gate' / '_up' /
+    '_down' (/ '_router_bias'); float32 out."""
+    if scoring not in ('softmax', 'sigmoid'):
+        raise ValueError("scoring must be 'softmax' or 'sigmoid', got %r"
+                         % (scoring,))
+    held = int(num_experts if num_held is None else num_held)
+    if not 0 <= int(expert_offset) <= int(num_experts) - held:
+        raise ValueError(
+            'experts [%d, %d) are not among the %d the router scores'
+            % (expert_offset, int(expert_offset) + held, num_experts))
     helper = LayerHelper('moe_topk_ffn', name=name)
     d = int(input.shape[-1])
     dtype = dtype or input.dtype
     shapes = {'RouterW': ('_router', [d, num_experts]),
-              'WGate': ('_gate', [num_experts, d, d_ff]),
-              'WUp': ('_up', [num_experts, d, d_ff]),
-              'WDown': ('_down', [num_experts, d_ff, d])}
+              'WGate': ('_gate', [held, d, d_ff]),
+              'WUp': ('_up', [held, d, d_ff]),
+              'WDown': ('_down', [held, d_ff, d])}
     inputs = {'X': input}
     for slot, (suffix, shape) in shapes.items():
         inputs[slot] = helper.create_parameter(
             attr=_suffixed_attr(param_attr, suffix), shape=shape,
             dtype=dtype)
+    if router_bias:
+        inputs['RouterBias'] = helper.create_parameter(
+            attr=(_suffixed_attr(param_attr, '_router_bias')
+                  if router_bias is True else router_bias),
+            shape=[num_experts], dtype='float32')
     out = helper.create_variable_for_type_inference('float32')
     helper.append_op(type='moe_topk_ffn', inputs=inputs,
                      outputs={'Out': out},
                      attrs={'k': int(k),
-                            'norm_topk_prob': bool(norm_topk_prob)})
+                            'norm_topk_prob': bool(norm_topk_prob),
+                            'scoring': scoring,
+                            'routed_scaling_factor':
+                                float(routed_scaling_factor),
+                            'expert_offset': int(expert_offset)})
     return out
 
 
@@ -1671,7 +1697,7 @@ def kv_block_write(cache, kv, pos, block_table):
 
 
 def kv_block_attention(query, k_cache, v_cache, pos, block_table,
-                       n_head, scale=None):
+                       n_head, scale=None, n_kv_head=None, window=0):
     """One-token-per-slot attention over the block-paged cache: `query`
     [max_slots, d] attends its own slot's logically-ordered block view
     (rows j <= pos) through `block_table`. Rows beyond get exactly-zero
@@ -1683,7 +1709,12 @@ def kv_block_attention(query, k_cache, v_cache, pos, block_table,
     over the gathered view on every platform but a TPU; and,
     compiled for a TPU with a float32 / bfloat16 pool of whole-tile
     pages, a Pallas kernel that reads pages 0 .. pos // block_size only
-    and rounds differently from the gathered body (float32 throughout)."""
+    and rounds differently from the gathered body (float32 throughout).
+    `n_kv_head` (default n_head) K/V heads: the cache is n_kv_head *
+    d_head wide, `query` n_head * d_head, and query head h reads K/V
+    head h // (n_head // n_kv_head). `window` w > 0 attends only the
+    rows pos - w < j <= pos — the table then needs to name the slot's
+    own blocks only from the one that holds pos - w + 1 on."""
     helper = LayerHelper('kv_block_attention')
     out = helper.create_variable_for_type_inference(query.dtype)
     helper.append_op(type='kv_block_attention',
@@ -1692,6 +1723,8 @@ def kv_block_attention(query, k_cache, v_cache, pos, block_table,
                              'BlockTable': block_table},
                      outputs={'Out': out},
                      attrs={'n_head': int(n_head),
+                            'n_kv_head': int(n_kv_head or n_head),
+                            'window': int(window),
                             'scale': float(scale or 0.0)})
     out.stop_gradient = True
     return out
@@ -1711,12 +1744,16 @@ def kv_block_chunk_write(cache, kv, start, block_table):
 
 
 def kv_block_chunk_attention(query, k_cache, v_cache, start, block_table,
-                             n_head, scale=None):
+                             n_head, scale=None, n_kv_head=None, window=0):
     """Chunked-prefill attention: chunk row i ([1, chunk, d] `query`)
     attends the slot's block view rows j <= start + i — causal within
     the chunk AND over every previously written position (earlier
     chunks, shared prefix blocks), which is what lets a prefix-cache
-    hit skip recomputing the shared span."""
+    hit skip recomputing the shared span. `n_kv_head` and `window` as
+    kv_block_attention's. Where heads are grouped, a window is set or
+    the [chunk, n_head, max_blocks * block_size] scores of the whole
+    view would be too large to hold, the lowering reads the slot's pages
+    a block of positions at a time under an online softmax instead."""
     helper = LayerHelper('kv_block_chunk_attention')
     out = helper.create_variable_for_type_inference(query.dtype)
     helper.append_op(type='kv_block_chunk_attention',
@@ -1725,6 +1762,8 @@ def kv_block_chunk_attention(query, k_cache, v_cache, start, block_table,
                              'BlockTable': block_table},
                      outputs={'Out': out},
                      attrs={'n_head': int(n_head),
+                            'n_kv_head': int(n_kv_head or n_head),
+                            'window': int(window),
                             'scale': float(scale or 0.0)})
     out.stop_gradient = True
     return out

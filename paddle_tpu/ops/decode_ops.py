@@ -457,16 +457,52 @@ def _chunk_eval(ctx, ins):
 # contract.
 # ---------------------------------------------------------------------------
 
+def _head_attrs(ctx, d):
+    """(n_head, n_kv_head, d_head, scale, window) of an attention op over
+    a cache of width d = n_kv_head * d_head: attr n_kv_head (default:
+    n_head) K/V heads, query head h reading K/V head h // (n_head //
+    n_kv_head); attr window w > 0 keeps the rows pos - w < j <= pos (0:
+    every row j <= pos)."""
+    n_head = int(ctx.attr('n_head', 1))
+    n_kv = int(ctx.attr('n_kv_head', 0) or 0) or n_head
+    if n_head % n_kv:
+        raise ValueError('n_head %d is not a multiple of n_kv_head %d'
+                         % (n_head, n_kv))
+    dh = d // n_kv
+    scale = float(ctx.attr('scale', 0.0) or 0.0) or dh ** -0.5
+    return n_head, n_kv, dh, scale, int(ctx.attr('window', 0) or 0)
+
+
+def _in_window(j, pos, window):
+    """Which cache rows j a query at position pos attends."""
+    valid = j <= pos
+    return valid & (j > pos - window) if window else valid
+
+
 def _paged_attention_body(ctx, q, kc, vc, pos):
-    """The shared heads-inside masked attention body: Q [S, D] attends
-    its own slot's cache rows j <= pos. Used by the fp and the int8-
+    """The shared heads-inside masked attention body: Q [S, n_head *
+    d_head] attends its own slot's cache rows j <= pos (inside attr
+    window, if one is set: _head_attrs). Used by the fp and the int8-
     dequantizing attention ops — ONE expression, so the fp path's
     bit-identity contract is untouched and the quantized path differs
     only by the dequant of its operands."""
-    n_head = int(ctx.attr('n_head', 1))
     s, t, d = kc.shape
-    dh = d // n_head
-    scale = float(ctx.attr('scale', 0.0) or 0.0) or dh ** -0.5
+    n_head, n_kv, dh, scale, window = _head_attrs(ctx, d)
+    if n_kv != n_head or window:
+        g = n_head // n_kv
+        qh = q.reshape(s, n_kv, g, dh)
+        kh = kc.reshape(s, t, n_kv, dh)
+        vh = vc.reshape(s, t, n_kv, dh)
+        scores = jnp.einsum('skgd,stkd->skgt', qh, kh) * scale
+        valid = _in_window(jnp.arange(t, dtype=jnp.int32)[None, :],
+                           pos[:, None], window)
+        scores = jnp.where(valid[:, None, None, :], scores, -jnp.inf)
+        w = jax.nn.softmax(scores, axis=-1)
+        # with a window the table names pages the slot gave back: their
+        # V must not reach the sum even at weight zero (the kernel's rule)
+        vh = jnp.where(valid[:, :, None, None], vh, 0)
+        ctxv = jnp.einsum('skgt,stkd->skgd', w, vh)
+        return ctxv.reshape(s, n_head * dh).astype(q.dtype)
     qh = q.reshape(s, n_head, dh)
     kh = kc.reshape(s, t, n_head, dh)
     vh = vc.reshape(s, t, n_head, dh)
@@ -623,10 +659,15 @@ def _kv_block_attention_jnp(ctx, q, kc, vc, pos, table):
 
 @register('kv_block_attention', no_grad=True, lod='none')
 def _kv_block_attention(ctx, ins):
-    """One-token-per-slot attention over the block pool: Q [S, D],
-    KCache/VCache [NB, BS, D], Pos [S] int32, BlockTable [S, MAXB] int32;
-    heads split inside the op (attr n_head). Each slot
-    attends its own table's logical view rows j <= pos; rows beyond get
+    """One-token-per-slot attention over the block pool: Q [S, n_head *
+    d_head], KCache/VCache [NB, BS, D = n_kv_head * d_head], Pos [S]
+    int32, BlockTable [S, MAXB] int32; heads split inside the op (attr
+    n_head; attr n_kv_head, default n_head, K/V heads each read by
+    n_head / n_kv_head query heads). Each slot
+    attends its own table's logical view rows j <= pos — with attr
+    window w > 0 only pos - w < j <= pos, and then only the pages that
+    hold those rows need be the slot's own: the table may name anything
+    below them; rows beyond get
     exactly-zero weight, so foreign blocks and trash garbage can never
     perturb an active slot.
 
@@ -642,36 +683,39 @@ def _kv_block_attention(ctx, ins):
     vc = ins['VCache'][0]
     pos = ins['Pos'][0].reshape(-1).astype(jnp.int32)
     table = ins['BlockTable'][0].astype(jnp.int32)
-    n_head = int(ctx.attr('n_head', 1))
+    n_head, n_kv, _, scale, window = _head_attrs(ctx, kc.shape[2])
     jnp_body = functools.partial(_kv_block_attention_jnp, ctx)
     if ctx.abstract:        # shape inference: no Tracer, and any body will do
         return {'Out': [jnp_body(q, kc, vc, pos, table)]}
     kernel = (current_trace_mesh() is None
-              and ppa.supports(q, kc, vc, n_head))
+              and ppa.supports(q, kc, vc, n_head, n_kv))
     ctx.tracer.lowered_bodies.append(
         ('kv_block_attention', 'kernel' if kernel else 'jnp'))
     if not kernel:
         return {'Out': [jnp_body(q, kc, vc, pos, table)]}
-    scale = (float(ctx.attr('scale', 0.0) or 0.0)
-             or (kc.shape[2] // n_head) ** -0.5)
     return {'Out': [ppa.tpu_or_default(
         q, kc, vc, pos, table, default=jnp_body,
         tpu=functools.partial(ppa.paged_attention, n_head=n_head,
+                              n_kv_head=n_kv, window=window,
                               scale=scale))]}
 
 
 def _chunk_attention_body(ctx, q, kview, vview, start, d):
-    """Chunked-prefill attention for ONE slot: q [1, C, D] (chunk rows at
-    absolute positions start + i), kview/vview [T', D] the slot's
-    logical cache view. Row i attends j <= start + i — causal within
-    the chunk AND over every previously written position (earlier
-    chunks, shared prefix blocks). Heads inside; exactly-zero masked
-    weights (the step op's contract)."""
-    n_head = int(ctx.attr('n_head', 1))
+    """Chunked-prefill attention for ONE slot: q [1, C, D] (chunk rows
+    at absolute positions start + i), kview/vview [T', D] the slot's
+    logical cache view. Row i attends j <= start + i — causal within the
+    chunk AND over every previously written position (earlier chunks,
+    shared prefix blocks). Heads inside (attr n_head: as many K/V heads
+    as query heads, and no window — _chunk_attention_blocked has
+    those); exactly-zero masked weights (the step op's contract)."""
+    n_head, n_kv, dh, scale, window = _head_attrs(ctx, d)
+    if n_kv != n_head or window:
+        raise NotImplementedError(
+            'chunk attention over a gathered view has neither grouped '
+            'K/V heads (n_kv_head=%d of n_head=%d) nor a window (%d)'
+            % (n_kv, n_head, window))
     c = q.shape[1]
     t = kview.shape[0]
-    dh = d // n_head
-    scale = float(ctx.attr('scale', 0.0) or 0.0) or dh ** -0.5
     qh = q.reshape(c, n_head, dh)
     kh = kview.reshape(t, n_head, dh)
     vh = vview.reshape(t, n_head, dh)
@@ -683,6 +727,75 @@ def _chunk_attention_body(ctx, q, kview, vview, start, d):
     w = jax.nn.softmax(scores, axis=-1)
     ctxv = jnp.einsum('cht,thd->chd', w, vh)
     return ctxv.reshape(1, c, d).astype(q.dtype)
+
+
+# What the chunk op decides from the shapes it is given
+# (_kv_block_chunk_attention): the float32 [C, n_head, T'] scores of the
+# gathered view may take this many bytes and no more, and the blocked
+# body reads this many positions at a time.
+_CHUNK_SCORES_BYTES = 256 << 20
+_CHUNK_KEY_BLOCK = 512
+
+
+def _chunk_attention_blocked(ctx, q, kc, vc, start, table):
+    """_chunk_attention_body's function — with grouped K/V heads and a
+    window (_head_attrs) — without its [C, n_head, T'] scores: the
+    slot's pages are gathered _CHUNK_KEY_BLOCK positions at a time, from
+    the block that holds the first row any chunk row attends (position
+    0, or start - window + 1) to the one that holds start + C - 1, under
+    an online softmax — what a long cache needs (at C = 512, 64 heads
+    and 12,288 positions the whole scores are 1.6 GB of float32). Both
+    products at float32 'highest', as the step's kernel has them. A row
+    with nothing to attend (a pad row further past the cache's end than
+    the window is long) gives zeros."""
+    bs, d = kc.shape[1], kc.shape[2]
+    n_head, n_kv, dh, scale, window = _head_attrs(ctx, d)
+    c = q.shape[1]
+    pages = min(max(_CHUNK_KEY_BLOCK // bs, 1), table.shape[0])
+    key_block = pages * bs
+    g = n_head // n_kv
+    high = jax.lax.Precision.HIGHEST
+    qh = q.reshape(c, n_kv, g, dh).astype(jnp.float32)
+    start = start.reshape(()).astype(jnp.int32)
+    rows = start + jnp.arange(c, dtype=jnp.int32)[:, None]      # [C, 1]
+    first = (jnp.maximum(start - window + 1, 0) // key_block if window
+             else jnp.int32(0))
+
+    def block(i, carry):
+        m, l, acc = carry
+        # a page index past the table clamps to its last column: those
+        # positions lie above every row's own and are masked below
+        page = jnp.take(table, jnp.minimum(
+            i * pages + jnp.arange(pages, dtype=jnp.int32),
+            table.shape[0] - 1))
+        kh = jnp.take(kc, page, axis=0).reshape(key_block, n_kv, dh)
+        vh = jnp.take(vc, page, axis=0).reshape(key_block, n_kv, dh)
+        j = i * key_block + jnp.arange(key_block, dtype=jnp.int32)[None, :]
+        sc = jnp.einsum('ckgd,tkd->ckgt', qh, kh.astype(jnp.float32),
+                        precision=high) * scale
+        seen = _in_window(j, rows, window)                      # [C, kb]
+        sc = jnp.where(seen[:, None, None, :], sc, -jnp.inf)
+        # a position no row of the chunk attends (a page the window has
+        # passed, the tail past the chunk) may hold anything: its V must
+        # not reach the sum, even at weight zero
+        vh = jnp.where(jnp.any(seen, axis=0)[:, None, None],
+                       vh.astype(jnp.float32), 0.0)
+        m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
+        base = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
+        alpha = jnp.exp(m - base)
+        p = jnp.exp(sc - base)
+        l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        acc = alpha * acc + jnp.einsum('ckgt,tkd->ckgd', p, vh,
+                                       precision=high)
+        return m_new, l, acc
+
+    init = (jnp.full((c, n_kv, g, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((c, n_kv, g, 1), jnp.float32),
+            jnp.zeros((c, n_kv, g, dh), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(first, (start + c - 1) // key_block + 1,
+                                  block, init)
+    out = acc / jnp.where(l == 0.0, 1.0, l)
+    return out.reshape(1, c, n_head * dh).astype(q.dtype)
 
 
 @register('kv_block_chunk_write', no_grad=True, lod='none')
@@ -713,12 +826,25 @@ def _kv_block_chunk_attention(ctx, ins):
     attend the slot's logical view (KCache/VCache [NB, BS, D] through
     BlockTable [1, MAXB]) rows j <= Start + i — causal in the chunk and
     across everything already written (earlier chunks, SHARED prefix
-    blocks, which is what lets a prefix hit skip recompute)."""
+    blocks, which is what lets a prefix hit skip recompute). Attrs
+    n_kv_head and window as the step op's (_head_attrs).
+
+    Two bodies, chosen from what the lowering sees and never from a
+    knob: the gathered view under one softmax (_chunk_attention_body)
+    where query and K/V heads are as many, nothing is windowed and the
+    view's [C, n_head, T'] float32 scores fit _CHUNK_SCORES_BYTES; else
+    the pages a block of positions at a time under an online softmax
+    (_chunk_attention_blocked): another summation order."""
     q = ins['Q'][0]
     kc = ins['KCache'][0]
     vc = ins['VCache'][0]
     start = ins['Start'][0]
     table = ins['BlockTable'][0].astype(jnp.int32)[0]
+    n_head, n_kv, _, _, window = _head_attrs(ctx, kc.shape[2])
+    scores = 4 * q.shape[1] * n_head * table.shape[0] * kc.shape[1]
+    if n_kv != n_head or window or scores > _CHUNK_SCORES_BYTES:
+        return {'Out': [_chunk_attention_blocked(ctx, q, kc, vc, start,
+                                                 table)]}
     kview = _block_view(kc, table)
     vview = _block_view(vc, table)
     return {'Out': [_chunk_attention_body(ctx, q, kview, vview, start,
@@ -851,11 +977,14 @@ def _verify_attention_body(ctx, q, kc, vc, pos):
     expression (same einsum contraction order, same -inf mask, same
     softmax), which is what makes a verify row's output bit-comparable
     to the plain step's output at the same prefix."""
-    n_head = int(ctx.attr('n_head', 1))
     s, t, d = kc.shape
     r = q.shape[1]
-    dh = d // n_head
-    scale = float(ctx.attr('scale', 0.0) or 0.0) or dh ** -0.5
+    n_head, n_kv, dh, scale, window = _head_attrs(ctx, d)
+    if n_kv != n_head or window:
+        raise NotImplementedError(
+            'the speculative verify attention has neither grouped K/V '
+            'heads (n_kv_head=%d of n_head=%d) nor a window (%d)'
+            % (n_kv, n_head, window))
     qh = q.reshape(s, r, n_head, dh)
     kh = kc.reshape(s, t, n_head, dh)
     vh = vc.reshape(s, t, n_head, dh)

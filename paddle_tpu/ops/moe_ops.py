@@ -73,36 +73,68 @@ def _switch_moe_ffn(ctx, ins):
                                        'WDown'))
 def _moe_topk_ffn(ctx, ins):
     """Dropless top-k routed SwiGLU FFN: X [..., D], RouterW [D, E],
-    WGate / WUp [E, D, F], WDown [E, F, D] -> Out [..., D] float32.
+    WGate / WUp [H, D, F], WDown [H, F, D] -> Out [..., D] float32.
 
-    p = softmax(X RouterW) over the E experts in float32 (the product at
-    'highest': a router that rounds its input picks other experts); the
-    top-k values and indices (ties to the lower index, lax.top_k's
-    rule), renormalised only when attr norm_topk_prob; out = sum_k p_k *
+    s = softmax(X RouterW) over the E experts in float32 (the product at
+    'highest': a router that rounds its input picks other experts), or,
+    with attr scoring 'sigmoid', the logits' sigmoid; the top-k values
+    and indices (ties to the lower index, lax.top_k's rule) — chosen by
+    s + RouterBias [E] where that input is given, the VALUES always s's
+    own; renormalised only when attr norm_topk_prob (a sigmoid router's
+    sum takes the family's + 1e-20: its scores can all underflow), then
+    times attr routed_scaling_factor; out = sum_k s_k *
     WDown_e(silu(WGate_e x) * WUp_e x). No capacity and no
     [tokens, experts, capacity] tensor: the N * k (token, expert) pairs
     are sorted by expert and every expert multiplies its own contiguous
     rows (lax.ragged_dot, operands in the weights' dtype, float32
     accumulation), so no pair is dropped however uneven the routing.
     Each token's k partial results are summed in its own top-k order, so
-    a row's output does not depend on what else is in the batch."""
+    a row's output does not depend on what else is in the batch.
+
+    The op HOLDS the H = WGate.shape[0] experts [expert_offset,
+    expert_offset + H) of the E the router scores (one chip's share of
+    an expert-parallel layer; H = E and offset 0: all of them). Routing
+    is over all E; a pair whose expert is not held sorts behind the held
+    groups, multiplies nothing and adds exactly zero — the op returns
+    its own experts' part of the layer, and nothing stands in for the
+    rest."""
     from .llm_ops import swiglu
     x_in = ins['X'][0]
     router_w, w_gate, w_up, w_down = (ins[n][0] for n in
                                       ('RouterW', 'WGate', 'WUp', 'WDown'))
+    bias = (ins.get('RouterBias') or [None])[0]
     k = int(ctx.attr('k'))
+    sigmoid = ctx.attr('scoring', 'softmax') == 'sigmoid'
+    scaling = float(ctx.attr('routed_scaling_factor', 1.0))
+    offset = int(ctx.attr('expert_offset', 0))
     e, d, _ = w_gate.shape
+    partial = offset != 0 or e != router_w.shape[1]
     x = x_in.reshape(-1, d)
     n = x.shape[0]
     with jax.named_scope('router'):
         logits = jnp.matmul(x.astype(jnp.float32),
                             router_w.astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)
-        vals, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        scores = (jax.nn.sigmoid(logits) if sigmoid
+                  else jax.nn.softmax(logits, axis=-1))
+        if bias is None:
+            vals, idx = jax.lax.top_k(scores, k)
+        else:
+            _, idx = jax.lax.top_k(
+                scores + bias.astype(jnp.float32).reshape(1, -1), k)
+            vals = jnp.take_along_axis(scores, idx, axis=-1)
         if ctx.attr('norm_topk_prob', False):
-            vals = vals / jnp.sum(vals, axis=-1, keepdims=True)
+            total = jnp.sum(vals, axis=-1, keepdims=True)
+            vals = vals / (total + 1e-20 if sigmoid else total)
+        if scaling != 1.0:
+            vals = vals * scaling
     with jax.named_scope('dispatch'):
         expert = idx.reshape(-1)                       # [N * k]
+        if partial:
+            # pairs of experts that live elsewhere: group `e`, behind
+            # every held group, outside the grouped matmuls' sizes
+            held = (expert >= offset) & (expert < offset + e)
+            expert = jnp.where(held, expert - offset, e)
         order = jnp.argsort(expert, stable=True)
         rows = x.astype(w_gate.dtype)[order // k]      # sorted by expert
         sizes = jnp.bincount(expert, length=e).astype(jnp.int32)
@@ -113,6 +145,10 @@ def _moe_topk_ffn(ctx, ins):
         h = swiglu(grouped(rows, w_gate), grouped(rows, w_up))
         y = grouped(h.astype(w_down.dtype), w_down)    # [N * k, D]
     with jax.named_scope('combine'):
+        if partial:
+            # what the grouped matmuls leave in the rows past their
+            # sizes is not the op's to read
+            y = jnp.where(held[order][:, None], y, 0.0)
         y = y[jnp.argsort(order)].reshape(n, k, d)     # back to top-k order
         out = jnp.sum(y * vals[..., None], axis=1)
     return {'Out': [out.reshape(x_in.shape)]}

@@ -13,6 +13,8 @@ caller's thread: use it on a predictor that has served nothing yet (or is
 idle); the cache is re-zeroed afterwards."""
 import numpy as np
 
+from ..inference.kv_blocks import WindowTable
+
 
 def served_logits(pred, prompts, n_new):
     """Each of `prompts` (at most max_slots) is prefilled into a slot of
@@ -27,6 +29,16 @@ def served_logits(pred, prompts, n_new):
     tables = np.full((S, maxb), pred._trash, np.int32)
     for i in range(len(prompts)):       # full capacity: a private span
         tables[i] = 1 + i * maxb + np.arange(maxb)
+    # window layers: the tables the scheduler itself would keep, from
+    # the predictor's own block manager — blocks the window has passed
+    # are given back (and handed to the next prompt) as it goes
+    window = pred._window
+    wrows = [WindowTable() for _ in prompts] if window else None
+
+    def wtable(i, first, end):
+        pred._blocks.window_advance(wrows[i], first - window + 1, end)
+        return wrows[i].fill(np.full(maxb, pred._trash, np.int32))[None]
+
     rows = []
     for i, prompt in enumerate(prompts):
         prompt = np.asarray(prompt, np.int64)
@@ -37,8 +49,10 @@ def served_logits(pred, prompts, n_new):
             take = min(size, left)
             ids = np.zeros((1, size), np.int64)
             ids[0, :take] = prompt[start:start + take]
-            read = pred._dispatch_chunk(size, ids, start, take,
-                                        tables[i:i + 1], logits=True)
+            read = pred._dispatch_chunk(
+                size, ids, start, take, tables[i:i + 1], logits=True,
+                window_row=wtable(i, start, start + take) if window
+                else None)
             lg = pred._to_host(read)[1][0]
             start += take
         rows.append([np.array(lg, np.float32)])
@@ -48,8 +62,15 @@ def served_logits(pred, prompts, n_new):
         for i, prompt in enumerate(prompts):
             tok[i, 0] = int(np.argmax(rows[i][-1]))
             pos[i, 0] = len(prompt) + j - 1
+        wtables = None
+        if window:
+            wtables = np.concatenate(
+                [wtable(i, int(pos[i, 0]), int(pos[i, 0]) + 1)
+                 for i in range(len(prompts))]
+                + [np.full((S - len(prompts), maxb), pred._trash, np.int32)])
         _, lg = pred._to_host(
-            pred._dispatch_step(tok, pos, tables=tables, logits=True))
+            pred._dispatch_step(tok, pos, tables=tables, logits=True,
+                                wtables=wtables))
         for i in range(len(prompts)):
             rows[i].append(np.array(lg[i], np.float32))
     pred._reset_state()

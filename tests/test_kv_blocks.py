@@ -15,7 +15,8 @@ import paddle_tpu as fluid
 from paddle_tpu.inference import DecodingPredictor, export_decode
 from paddle_tpu.inference.kv_blocks import (BlockManager,
                                             BlockPoolExhausted,
-                                            TRASH_BLOCK)
+                                            TRASH_BLOCK, WindowTable,
+                                            window_blocks_per_slot)
 
 VOCAB, SLOTS, CACHE = 41, 4, 64
 
@@ -171,6 +172,80 @@ def test_evict_all_and_stats_keys():
               'prefix_hits', 'prefix_misses', 'prefix_hit_rate',
               'prefix_tokens_reused', 'evictions'):
         assert k in st, k
+
+
+# -- the window layers' pool (ISSUE 30) ---------------------------------------
+
+@pytest.mark.parametrize('window,chunk,bs,plen,new', [
+    (128, 512, 16, 6000, 300), (128, 512, 16, 24, 200), (16, 16, 8, 70, 40),
+    (128, 128, 16, 127, 20), (100, 48, 16, 1000, 150)])
+def test_window_table_holds_its_window_and_nothing_below(window, chunk, bs,
+                                                         plen, new):
+    """A request prefilled in slices and then decoded, as the scheduler
+    drives it: before every dispatch the table names the blocks of every
+    position a query of that dispatch attends, and never more than
+    ceil((window + C) / BS) + 1 blocks; whatever it gave back is free for
+    another request at once."""
+    bound = window_blocks_per_slot(window, chunk, bs)
+    assert bound == -(-(window + chunk) // bs) + 1
+    m = BlockManager(8, bs, window=(2 * bound + 1, window))
+    t, other = WindowTable(), WindowTable()
+    seen_peak = 0
+
+    def dispatch(first, end):
+        nonlocal seen_peak
+        m.window_advance(t, first - window + 1, end)
+        lo = max(first - window + 1, 0) // bs
+        assert t.first <= lo and t.first + len(t.blocks) == (end - 1) // bs + 1
+        assert len(t.blocks) <= bound
+        assert TRASH_BLOCK not in t.blocks
+        assert len(set(t.blocks) | set(other.blocks)) \
+            == len(t.blocks) + len(other.blocks)
+        seen_peak = max(seen_peak, len(t.blocks))
+        row = np.zeros(4096, np.int32)
+        t.fill(row)
+        assert list(row[t.first:t.first + len(t.blocks)]) == t.blocks
+        assert not row[:t.first].any()
+
+    start = 0
+    while start < plen:
+        take = min(chunk, plen - start)
+        dispatch(start, start + take)
+        start += take
+        # a second request lives in what is free meanwhile
+        m.window_advance(other, start - window + 1, start + 1)
+    for p in range(plen, plen + new):
+        dispatch(p, p + 1)
+    if plen >= chunk + window:
+        assert seen_peak >= bound - 1           # the bound is reached
+    st = m.stats()
+    assert st['window_blocks_in_use'] == len(t.blocks) + len(other.blocks)
+    assert st['window_blocks_peak'] <= 2 * bound
+    m.window_free(t)
+    m.window_free(other)
+    st = m.stats()
+    assert st['window_blocks_in_use'] == 0
+    assert st['window_blocks_released'] > 0 or plen + new <= window
+    # the full layers' pool never moved
+    assert st['blocks_in_use'] == 0 and st['allocs'] == 0
+
+
+def test_window_pool_is_apart_from_the_full_pool_and_refuses_prefixes():
+    m = BlockManager(6, 4, window=(4, 8))
+    full = m.alloc(5)
+    t = WindowTable()
+    m.window_advance(t, 0, 12)                   # 3 blocks: the whole pool
+    assert m.stats()['window_blocks_in_use'] == 3
+    assert m.stats()['blocks_in_use'] == 5
+    with pytest.raises(BlockPoolExhausted, match='window pool'):
+        m.window_advance(WindowTable(), 0, 1)
+    with pytest.raises(ValueError, match='prefix reuse is refused'):
+        m.match_prefix(list(range(20)))
+    with pytest.raises(ValueError, match='prefix reuse is refused'):
+        m.register_prefix(list(range(20)), full)
+    m.reset_counters()
+    assert m.stats()['window_blocks_peak'] == 3
+    assert 'window_blocks_in_use' not in BlockManager(6, 4).stats()
 
 
 def test_doomed_alloc_does_not_wipe_prefix_cache():

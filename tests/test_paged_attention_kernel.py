@@ -33,11 +33,12 @@ from paddle_tpu.ops import pallas_paged_attention as ppa
 TOL = dict(rtol=1e-5, atol=1e-5)
 
 
-def _attrs(n_head):
+def _attrs(n_head, **more):
     """What an op lowering is handed (core/lowering.py OpCtx), as far as
     these ops ask: attrs, and the Tracer's record of chosen bodies."""
+    attrs = dict(more, n_head=n_head)
     return types.SimpleNamespace(
-        attr=lambda name, default=None: {'n_head': n_head}.get(name, default),
+        attr=lambda name, default=None: attrs.get(name, default),
         abstract=False,
         tracer=types.SimpleNamespace(lowered_bodies=[]))
 
@@ -236,6 +237,93 @@ def test_a_slots_output_is_its_own():
     assert not np.array_equal(a[2], b[2])
 
 
+# -- grouped K/V heads and the window (ISSUE 30) -------------------------------
+
+@functools.partial(jax.jit, static_argnames=('n_head', 'n_kv_head', 'window'))
+def _kernel_gw(q, kc, vc, pos, table, n_head, n_kv_head, window):
+    return ppa.paged_attention(
+        q, kc, vc, pos, table, n_head=n_head, n_kv_head=n_kv_head,
+        window=window, scale=(kc.shape[2] // n_kv_head) ** -0.5,
+        interpret=True)
+
+
+@functools.partial(jax.jit, static_argnames=('n_head', 'n_kv_head', 'window'))
+def _body_gw(q, kc, vc, pos, table, n_head, n_kv_head, window):
+    return decode_ops._kv_block_attention_jnp(
+        _attrs(n_head, n_kv_head=n_kv_head, window=window), q, kc, vc, pos,
+        table)
+
+
+# positions on both sides of a page edge (16), of the window's length
+# (128: the first position that drops row 0), of the kernel's compute
+# block (256) and of a window that starts inside a page
+_WINDOW_POS = (0, 15, 16, 127, 128, 129, 143, 144, 255, 256, 300, 383)
+
+
+@pytest.mark.parametrize('window', [0, 128])
+@pytest.mark.parametrize('heads,dtype', [((8, 2), np.float32),
+                                         ((8, 2), jnp.bfloat16),
+                                         ((4, 1), np.float32),
+                                         ((2, 2), jnp.bfloat16)])
+def test_grouped_heads_and_window_match_jnp_body(heads, dtype, window):
+    """n_kv_head < n_head (the query wider than the pool) and a window:
+    the kernel against the jnp body, on a pool that is NaN wherever the
+    slot does not attend — rows past pos, and with a window every row
+    under pos - 127 and every page under the window's first, which the
+    table may no longer name (the scheduler gave them back)."""
+    n_head, n_kv = heads
+    dh, bs, maxb = 128, 16, 24
+    s, d = len(_WINDOW_POS), n_kv * dh
+    rng = np.random.RandomState(13)
+    nb = s * maxb + 2
+    q = rng.randn(s, n_head * dh).astype(np.float32)
+    pos = np.asarray(_WINDOW_POS, np.int32)
+    table = rng.permutation(np.arange(1, nb - 1)).reshape(s, maxb) \
+        .astype(np.int32)
+    attended = np.zeros((nb, bs), bool)
+    for i, p in enumerate(pos):
+        lo = max(p - window + 1, 0) if window else 0
+        if window:
+            table[i, :lo // bs] = nb - 1          # given back: nobody's
+        for j in range(lo, p + 1):
+            attended[table[i, j // bs], j % bs] = True
+    pools = []
+    for _ in 'kv':
+        clean = rng.randn(nb, bs, d).astype(np.float32)
+        pools.append((np.where(attended[..., None], clean, np.nan), clean))
+    args = lambda which: (jnp.asarray(q),
+                          jnp.asarray(pools[0][which], dtype),
+                          jnp.asarray(pools[1][which], dtype),
+                          jnp.asarray(pos), jnp.asarray(table))
+    kw = dict(n_head=n_head, n_kv_head=n_kv, window=window)
+    got = np.asarray(_kernel_gw(*args(0), **kw))
+    want = np.asarray(_body_gw(*args(1), **kw))
+    assert got.shape == (s, n_head * dh) and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, **TOL)
+
+
+def test_window_drops_exactly_the_rows_it_passed():
+    """Row pos - 128 carries a huge V: inside no window, it must not move
+    the output; row pos - 127 is the window's first and must."""
+    rng = np.random.RandomState(17)
+    bs, d, maxb = 16, 256, 16
+    q = rng.randn(1, 512).astype(np.float32)
+    kc = rng.randn(maxb + 1, bs, d).astype(np.float32)
+    vc = rng.randn(maxb + 1, bs, d).astype(np.float32)
+    pos = np.asarray([200], np.int32)
+    table = np.arange(1, maxb + 1, dtype=np.int32)[None]
+    kw = dict(n_head=4, n_kv_head=2, window=128)
+    base = np.asarray(_kernel_gw(q, kc, vc, pos, table, **kw))
+    for row, moves in ((200 - 128, False), (200 - 127, True)):
+        v2 = vc.copy()
+        v2[table[0, row // bs], row % bs] += 1e4
+        out = np.asarray(_kernel_gw(q, kc, jnp.asarray(v2), pos, table, **kw))
+        assert (not np.array_equal(out, base)) == moves, row
+        np.testing.assert_allclose(
+            out, np.asarray(_body_gw(q, kc, jnp.asarray(v2), pos, table,
+                                     **kw)), rtol=1e-4, atol=1e-2)
+
+
 # -- which body ---------------------------------------------------------------
 
 def _sds(shape, dtype):
@@ -260,6 +348,27 @@ def _sds(shape, dtype):
 ])
 def test_shape_rule(why, q, pool, n_head, want):
     assert ppa.supports(q, pool, pool, n_head) is want, why
+
+
+@pytest.mark.parametrize('why,q,pool,n_head,n_kv,want', [
+    ('k_exaone: 64 query heads over 8 K/V heads of 128',
+     _sds((64, 8192), np.float32), _sds((49153, 16, 1024), jnp.bfloat16),
+     64, 8, True),
+    ('grouped heads of 64 lanes are not whole tiles',
+     _sds((8, 256), np.float32), _sds((65, 16, 128), np.float32), 4, 2,
+     False),
+    ('a query that is not n_head heads of the pool\'s size',
+     _sds((8, 384), np.float32), _sds((65, 16, 256), np.float32), 4, 2,
+     False),
+    ('n_head not a multiple of n_kv_head',
+     _sds((8, 768), np.float32), _sds((65, 16, 512), np.float32), 6, 4,
+     False),
+    ('n_kv_head = n_head is the ungrouped rule',
+     _sds((8, 128), np.float32), _sds((65, 16, 128), np.float32), 4, 4,
+     True),
+])
+def test_shape_rule_for_grouped_heads(why, q, pool, n_head, n_kv, want):
+    assert ppa.supports(q, pool, pool, n_head, n_kv) is want, why
 
 
 def _op_args(d, bs=8, s=3, maxb=6):
@@ -394,13 +503,19 @@ def one_chip():
     (128, 16385, 16, 512, 8, 128, np.float32),     # the benchmark's
     (8, 257, 16, 512, 8, 32, np.float32),          # chip_smoke phase B's
     (8, 257, 16, 128, 2, 32, jnp.bfloat16),
-    (8, 257, 8, 128, 1, 32, np.float32)])
+    (8, 257, 8, 128, 1, 32, np.float32),
+    # k_exaone_236b_a23b: 64 query / 8 K/V heads, a full and a window layer
+    (64, 49153, 16, 1024, (64, 8, 0), 768, jnp.bfloat16),
+    (64, 2625, 16, 1024, (64, 8, 128), 768, jnp.bfloat16)])
 def test_kernel_compiles_for_v5e(one_chip, s, nb, bs, d, h, maxb, dtype):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    h, n_kv, window = h if isinstance(h, tuple) else (h, h, 0)
     fn = jax.jit(functools.partial(ppa.paged_attention, n_head=h,
-                                   scale=(d // h) ** -0.5))
-    compiled = fn.lower(sds((s, d), np.float32), sds((nb, bs, d), dtype),
+                                   n_kv_head=n_kv, window=window,
+                                   scale=(d // n_kv) ** -0.5))
+    compiled = fn.lower(sds((s, h * (d // n_kv)), np.float32),
+                        sds((nb, bs, d), dtype),
                         sds((nb, bs, d), dtype), sds((s,), np.int32),
                         sds((s, maxb), np.int32)).compile()
     assert 'tpu_custom_call' in compiled.as_text()
