@@ -28,7 +28,14 @@ vLLM-style preallocated, block-paged KV cache):
    at step boundaries (one prefill slice a tick, interleaved with the
    running batch's steps, then their slot decodes with everyone else);
    finished sequences (eos / max_new_tokens) free
-   their slot immediately for the next waiting request.
+   their slot immediately for the next waiting request. The scheduler
+   runs ONE STEP AHEAD of its reads: a tick dispatches the next step
+   before it reads what the tick before dispatched (its step, and the
+   last slice of each prompt that ended there), each row's token handed from step to step — and from a
+   prompt's last slice to its first step — in the programs' state, on
+   the device (`_run_tick`; signature version 6), so the device works
+   while the host emits. Where the host must see a result first (a live
+   beam, a drafter) the same code reads before it dispatches.
 3. **Weights as arguments, donated paged KV state** — every program is
    `fn(params, state, feeds)`: the weights are loaded once from the
    artifact's one weights file into one set of device buffers that step,
@@ -133,8 +140,12 @@ _ZEROS_DIR = 'decode_zeros'
 # signature version 4: weights are arguments of every program, loaded
 # once from serve._DECODE_WEIGHTS; up to 3 they were module constants.
 # Version 5: fetch 0 of every token-emitting program is the argmax ids,
-# fetch 1 the logits; up to 4 the logits were the one fetch
-_SIG_VERSION = 5
+# fetch 1 the logits; up to 4 the logits were the one fetch. Version 6:
+# the state's last entry is the [max_slots] ids row (export.py _IDS_ROW):
+# the step takes a row's token from it where the `tokens` feed is
+# negative, a chunk takes a `slot` feed and writes its id there; up to 5
+# every token came from the host
+_SIG_VERSION = 6
 
 
 def _dtype(name):
@@ -283,6 +294,17 @@ class DecodeStats(object):
         # slices whose result the host read: the prompts' last ones.
         # 1 - slice_reads / chunk_slices of the slices cost no wait
         self.slice_reads = 0
+        # steps dispatched while what the previous tick dispatched was
+        # still unread (its step, or the last slice a row of this step
+        # takes its first token from): the host built their feed without
+        # the ids before them. 0 of `steps` while a beam row is live or a
+        # drafter is attached
+        self.steps_ahead = 0
+        # rows of a step whose id the read dropped because their request
+        # had ended by then: an eos seen a tick late (the row rode ONE
+        # more step), or a cancel, expiry or shed between the dispatch
+        # and its read
+        self.wasted_rows = 0
         # speculative decoding (ISSUE 17). adv_* meter tokens delivered
         # per request-advancing dispatch (prefill first token, plain
         # step, beam step, verify tick) — tokens_per_dispatch is
@@ -316,6 +338,8 @@ class DecodeStats(object):
             self.blockcopies = 0
             self.chunk_slices = 0
             self.slice_reads = 0
+            self.steps_ahead = 0
+            self.wasted_rows = 0
             self.verify_steps = 0
             self.drafted = 0
             self.accepted = 0
@@ -375,7 +399,9 @@ class DecodeStats(object):
                     'cow_blocks': int(self.cow_blocks),
                     'blockcopies': int(self.blockcopies),
                     'chunk_slices': int(self.chunk_slices),
-                    'slice_reads': int(self.slice_reads)}
+                    'slice_reads': int(self.slice_reads),
+                    'steps_ahead': int(self.steps_ahead),
+                    'wasted_rows': int(self.wasted_rows)}
             if self.block_source is None:    # not wired to a pool yet
                 return snap
         # outside the stats lock: the BlockManager takes its own
@@ -557,7 +583,8 @@ class DraftModelDrafter(object):
 
 class _Request(object):
     __slots__ = ('prompt', 'max_new', 'beam', 'stream', 't_submit',
-                 'deadline', 'slots', 'produced', 'tokens', 'last_tokens',
+                 'deadline', 'slots', 'produced', 'dispatched', 'tokens',
+                 'last_tokens',
                  'scores', 'finished', 'hyps', 't_first', 't_last',
                  'tables', 'wtable', 'next_start', 'prefilling', 'match',
                  'match_epoch', 'draft_strikes', 'draft_cooldown',
@@ -575,7 +602,11 @@ class _Request(object):
         self.deadline = (self.t_submit + deadline_ms / 1e3
                          if deadline_ms is not None else None)
         self.slots = []                   # slot indices, beam order
-        self.produced = 0                 # tokens generated so far
+        self.produced = 0                 # tokens the host has read
+        # tokens whose program is dispatched (the first by the prompt's
+        # last slice, then one a step): what a feed is built from —
+        # positions, tables, max_new — while `produced` runs a read behind
+        self.dispatched = 0
         self.tokens = []                  # greedy transcript
         self.last_tokens = []             # per beam: next step's input
         self.scores = []                  # per beam accumulated logprob
@@ -709,11 +740,13 @@ def _load_signature(artifact_dir):
         raise ValueError(
             'decode artifact %s has signature version %s: its programs '
             '%s. Version %d programs take the weights as arguments (one '
-            '%s) and return the argmax ids as fetch 0 beside the logits '
-            '— export it again with this export_decode'
+            '%s), return the argmax ids as fetch 0 beside the logits and '
+            'hand each slot\'s last id from dispatch to dispatch in their '
+            'state — export it again with this export_decode'
             % (artifact_dir, sig.get('version'),
                'hold the weights as constants' if version < 4
-               else 'return their logits alone',
+               else 'return their logits alone' if version < 5
+               else 'take every token from the host',
                _SIG_VERSION, _serve._DECODE_WEIGHTS))
     if sig.get('layout') != 'block':
         raise ValueError(
@@ -935,6 +968,10 @@ class DecodingPredictor(object):
             name='blockcopy')
         self._state = None
         self._slots = [None] * self._S    # slot -> (request, beam index)
+        # what the last tick dispatched and nobody has read yet: (the
+        # step's read and rows or None, [(request, read)] of the slices
+        # that were their prompt's last), or None
+        self._unread = None
         self._closed = False
         self._draining = False
         self._idle_evt = threading.Event()
@@ -1112,8 +1149,8 @@ class DecodingPredictor(object):
         dispatches and zero compiles. Must run BEFORE any submit(): it dispatches on
         the scheduler's donated state from this thread, so it refuses
         loudly once traffic has started."""
-        if self.stats.queue_depth or any(s is not None
-                                         for s in self._slots):
+        if self.stats.queue_depth or self._unread is not None \
+                or any(s is not None for s in self._slots):
             raise RuntimeError(
                 'warmup() must run before traffic: requests are queued or '
                 'decoding, and a caller-thread dispatch would race the '
@@ -1265,8 +1302,9 @@ class DecodingPredictor(object):
         dispatch, as a bare np.asarray would queue it: waiting for the
         program before asking for the copy would put a host wake-up
         between the two (measured: +2 % on the inter-token gap). Nothing
-        waits: the scheduler dispatches the tick's prefill slices
-        between this and the read."""
+        waits: the scheduler makes the read a tick later, behind the
+        next tick's dispatches (_run_tick), so the copy has landed by
+        then unless the device is the slower side."""
         copied = fetches[:2] if logits else fetches[:1]
         for f in copied:
             f.copy_to_host_async()
@@ -1275,9 +1313,10 @@ class DecodingPredictor(object):
     def _to_host(self, read):
         """(ids, logits) of one dispatch as host arrays — the read that
         _ask left to be made — in two spans: the wait for the device to
-        finish the program (what is left of launch latency and of the
-        program's own time sits here; nothing, once the host had other
-        work to do since the dispatch), then what is left of the
+        finish the program (nothing where the read is made a tick after
+        the dispatch and the host is the slower side; where the device
+        is, this wait IS the pipeline: the next step is already queued
+        behind the one waited for), then what is left of the
         device-to-host copy. The logits stayed a device array, and are
         None here, unless they were asked for."""
         import jax
@@ -1298,8 +1337,11 @@ class DecodingPredictor(object):
                        wtables=None):
         """Dispatch one decode step and ask for its ids [S] int32 and,
         if `logits`, the [S, V] float32 rows they are the argmax of.
-        `wtables`: the window layers' tables, on an artifact that has
-        such layers. Returns the read (_to_host), unmade: the call is
+        `tokens` [S, 1]: a row's input token, or a negative value where
+        the row takes the id the device holds for its slot (the state's
+        ids row: what the last step chose there, or the last slice of
+        the slot's prompt). `wtables`: the window layers' tables, on an
+        artifact that has such layers. Returns the read (_to_host), unmade: the call is
         enqueued, the device may not have started."""
         feed = {'tokens': tokens, 'pos': pos, 'block_tables': tables,
                 'window_tables': wtables}
@@ -1332,14 +1374,17 @@ class DecodingPredictor(object):
         return self._ask(fetches, 'verify', logits)
 
     def _dispatch_chunk(self, size, ids, start, take, table_row,
-                        logits=False, read=True, window_row=None):
+                        logits=False, read=True, window_row=None, slot=-1):
         """Dispatch one chunked-prefill slice: `take` real rows of one
         prompt at absolute positions start..start+take-1 (the rest of
         the `size` rows are pad) write through `table_row` [1,
         max_blocks] (and `window_row`, the window layers' table, where
-        the artifact has such layers). With `read` — the slice is its
-        prompt's last —
-        asks for the id its last real position chose and, if `logits`,
+        the artifact has such layers). `slot`: where the slice is the
+        last of a greedy prompt, the request's slot — the program writes
+        the id its last real position chose into that entry of the
+        state's ids row, from which the next step takes it; negative,
+        nothing is written. With `read` — the slice is its prompt's
+        last — asks for the id its last real position chose and, if `logits`,
         that position's [V] row, and returns the read (_to_host gives
         them with a leading axis of 1: _one_row), unmade. Without, it
         keeps nothing of the fetches and returns None: the slice wrote
@@ -1349,7 +1394,8 @@ class DecodingPredictor(object):
                 'start': np.full((1, 1), start, np.int32),
                 'chunk_len': np.full((1, 1), take, np.int32),
                 'block_table': np.asarray(table_row, np.int32),
-                'window_table': window_row}
+                'window_table': window_row,
+                'slot': np.full((1, 1), slot, np.int32)}
         args = [self._feed(feed[n]) for n in self._chunk_feeds[size]]
         with self._dev_ctx():
             fetches, new_state = self._chunk_mods[size].call(
@@ -1383,11 +1429,16 @@ class DecodingPredictor(object):
 
     # -- scheduler ---------------------------------------------------------
     def _active_requests(self):
-        seen = []
-        for entry in self._slots:
-            if entry is not None and entry[0] not in seen:
-                seen.append(entry[0])
-        return seen
+        """The requests that hold a slot, in slot order, each once."""
+        return list(dict.fromkeys(
+            e[0] for e in self._slots if e is not None))
+
+    def _holds(self, slot, req):
+        """Whether `slot` is still `req`'s: a read carries the rows it
+        was dispatched for, and an id is given to a slot's request only — never to one that has ended since (finished,
+        cancelled, expired, shed) or to the slot's next tenant."""
+        entry = self._slots[slot]
+        return entry is not None and entry[0] is req
 
     def _free_slots(self):
         return [i for i, s in enumerate(self._slots) if s is None]
@@ -1442,7 +1493,10 @@ class DecodingPredictor(object):
     def _sched_loop(self):
         waiting = deque()
         while True:
-            have_work = waiting or any(s is not None for s in self._slots)
+            # an outstanding read is work: the loop does not go idle with
+            # tokens on the device that no stream has seen
+            have_work = (waiting or self._unread is not None
+                         or any(s is not None for s in self._slots))
             try:
                 item = self._queue.get(block=not have_work)
             except queue.Empty:
@@ -1458,31 +1512,53 @@ class DecodingPredictor(object):
             self._tick += 1
             with _span('decode/tick', tick=self._tick):
                 self._run_tick(waiting)
-            if self._draining and not waiting \
+            if self._draining and not waiting and self._unread is None \
                     and not any(s is not None for s in self._slots):
                 self._idle_evt.set()
 
     def _run_tick(self, waiting):
         """One scheduler iteration with work to look at — the interval
-        stats.busy_s times. The step FIRST, and everything else of the
-        tick in its shadow: after expiry the running batch's step is
-        dispatched (_step); behind it the waiting requests admit, and
-        one prefill slice per admitting request is dispatched onto the
-        device's queue with nothing waited for or copied (_prefill_tick)
-        — the donated pool threads step, slice, slice, … in order while
-        the host builds the next feed. Only then the device is read,
-        once: the step's ids, emitted before any slice's result is
-        touched so that no stream's token waits for another request's
-        prompt (_read_step), then the ids of the slices that were a
-        prompt's last (_read_slice). Such a request decodes from the
-        NEXT tick's step on, and that step reserves its blocks before
-        the next admission can take them — as when step followed slice
-        within one tick. With no row decoding the tick is admission,
-        slices and their reads. Nothing stays unread across ticks, so
-        expiry, cancel, drain and close see settled states; an error the
-        device raises in a slice nobody read surfaces at the next read,
-        inside a tick's try."""
+        stats.busy_s times. ONE rule orders it: what a tick dispatches
+        is read in the NEXT tick, behind that tick's step. After expiry
+        the running batch's next step is dispatched (_step) from what
+        the host knows without the ids of the step before it —
+        positions, tables and block demand are functions of each
+        request's `dispatched` count, and a row's input token is handed
+        from step to step (and from a prompt's last slice to its first
+        step) in the programs' state, on the device. With that step on
+        the device's queue the host reads what the PREVIOUS tick
+        dispatched (_settle): its step's ids, emitted before any slice's
+        result is touched so that no stream's token waits for another
+        request's prompt, then the first tokens of the slices that were
+        a prompt's last. Those copies were asked for a tick ago: the
+        wait is nothing where the host is the slower side, and where the
+        device is, the wait is the pipeline — step k+1 is queued behind
+        the step k waited for. Then the waiting requests admit — into
+        the slots that read has just freed — and one prefill slice per
+        admitting request is dispatched (_prefill_tick); the donated
+        state threads step, slice, slice, step, ... in order. A tick is
+        max(host work, device work).
+
+        What follows from reading a tick late. A request that reaches
+        max_new is known at dispatch and is not fed again. An eos is seen
+        a tick late: the row rides ONE more step, whose id the read drops
+        (stats.wasted_rows; its K/V write lands in its own tail block,
+        and freeing that block is safe because the device runs
+        dispatches in order and every later writer is dispatched later).
+        A request cancelled, expired or shed with a read outstanding is
+        released at once and its rows are dropped from the read
+        (_holds). An error the device raises in a program nobody has
+        read yet surfaces at the next read, inside a tick's try.
+
+        Where the host must see a result before it can build the next
+        feed — a beam row live (it scores the logits), a drafter attached
+        (it reads the tokens): _results_first — the tick reads what it
+        dispatched before it ends, and the next step is dispatched with
+        nothing unread (stats.steps_ahead stays 0): the same code with
+        the read taken before the dispatch. _fail_all, close and the
+        loop going idle settle the outstanding read first."""
         t0 = time.perf_counter()
+        busy = self._unread is not None
         with _span('decode/expire'):
             if self._draining:
                 # scale-in drain: shed the waiting queue loudly (safe to
@@ -1495,22 +1571,48 @@ class DecodingPredictor(object):
             if any(e is not None and not e[0].prefilling
                    for e in self._slots):
                 step = self._step(waiting)
+            self._settle()
         except Exception as e:
             self._fail_all(e, waiting)
+            step = None     # dispatched on the state that failed
         if not self._draining:
             with _span('decode/admit') as sp:
                 sp.set_metadata(admitted=self._admit(waiting))
-        if any(s is not None for s in self._slots):
-            try:
-                lasts = self._prefill_tick()
-                if step is not None:
-                    self._read_step(*step)
-                for req, read in lasts:
-                    self._read_slice(req, read)
-            except Exception as e:
-                self._fail_all(e, waiting)
+        busy = busy or any(s is not None for s in self._slots)
+        try:
+            lasts = self._prefill_tick()
+            if step is not None or lasts:
+                if self._results_first():
+                    self._read(step, lasts)
+                else:
+                    self._unread = (step, lasts)
+        except Exception as e:
+            self._fail_all(e, waiting)
+        if busy:
             with self.stats._lock:
                 self.stats.busy_s += time.perf_counter() - t0
+
+    def _results_first(self):
+        """Whether the host must see what a tick dispatched before it can
+        build the next step's feed: a drafter reads each request's
+        tokens, and a beam's next tokens are scored on the host from the
+        logits. Told from what the scheduler holds, tick by tick."""
+        return self._drafter is not None or any(
+            e is not None and e[0].beam is not None for e in self._slots)
+
+    def _settle(self):
+        """Read what the last tick left unread, if anything."""
+        unread, self._unread = self._unread, None
+        if unread is not None:
+            self._read(*unread)
+
+    def _read(self, step, lasts):
+        """Read what one tick dispatched: the step's ids first (emit),
+        then the ids of the slices that were a prompt's last."""
+        if step is not None:
+            self._read_step(*step)
+        for req, read in lasts:
+            self._read_slice(req, read)
 
     def _shed_waiting(self, waiting):
         """drain() in progress: fail every WAITING request with
@@ -1529,8 +1631,19 @@ class DecodingPredictor(object):
                 % (' (request %s)' % req.request_id
                    if req.request_id else '')))
 
+    def _settle_quietly(self):
+        """Before every in-flight request is failed (close, a dispatch
+        failure): read what is outstanding, so that a stream whose last
+        token is already on the device ends with it; an error in that
+        read changes nothing — the requests fail with the caller's."""
+        try:
+            self._settle()
+        except Exception:
+            pass
+
     def _drain_on_close(self, waiting):
         err = RuntimeError('DecodingPredictor closed')
+        self._settle_quietly()
         for req in self._active_requests():
             self._release(req)
             _fail(req, err)
@@ -1712,7 +1825,8 @@ class DecodingPredictor(object):
         the running batch's decode steps — a max-length prompt never
         stalls every stream's inter-token latency for its whole prefill.
         Returns [(request, read)] of the slices that were their prompt's
-        last, for _read_slice once the step's tokens are out."""
+        last, for _read_slice a tick later, behind the next step's
+        dispatch and once the tokens of this tick's step are out."""
         lasts = []
         for req in self._active_requests():
             if not req.prefilling:
@@ -1735,7 +1849,10 @@ class DecodingPredictor(object):
         on what it can see: nothing unless the slice is the prompt's
         `last` — then the read of its id, and of its logits row where
         the request is a beam (the one dispatch of a prompt whose whole
-        logits row the host reads)."""
+        logits row the host reads). Behind its last slice the request is
+        a decoding row — of the next tick's step, which takes a greedy
+        request's first token from the device: the slice writes it into
+        the request's slot of the ids row."""
         ids = np.zeros((1, size), np.int64)
         ids[0, :take] = req.prompt[req.next_start:req.next_start + take]
         window_row = None
@@ -1747,19 +1864,26 @@ class DecodingPredictor(object):
             size, ids, req.next_start, take,
             self._table_row(req.tables[0]),
             logits=last and req.beam is not None, read=last,
-            window_row=window_row)
+            window_row=window_row,
+            slot=req.slots[0] if last and req.beam is None else -1)
         req.next_start += take
+        if last:
+            req.prefilling = False
+            req.dispatched = 1
         return read
 
     def _read_slice(self, req, read):
-        """The end of `req`'s prefill: read the id (a beam: the logits
-        row) its prompt's last position chose, publish the prompt's
-        blocks, emit the first token. The request is a decoding row from
-        here on — of the next tick's step."""
+        """The end of `req`'s prefill, read a tick after its last slice
+        was dispatched: the id (a beam: the logits row) its prompt's
+        last position chose, the prompt's blocks published, the first
+        token emitted. The request has been a decoding row since that
+        dispatch; one that ended in between (cancelled, expired, shed)
+        has nothing read."""
+        if not self._holds(req.slots[0], req):
+            return
         tok, logits = _one_row(*self._to_host(read))
         with self.stats._lock:
             self.stats.slice_reads += 1
-        req.prefilling = False
         if not self._window:
             # publish the prompt's FULL blocks for prefix reuse (the
             # partial tail stays private: decode writes land there)
@@ -1773,15 +1897,19 @@ class DecodingPredictor(object):
         beams idle (trash row) — their frozen candidate needs no cache
         writes, and skipping them avoids spurious CoW/extension.
         Requests in `skip` (this tick's drafted set — they advance via
-        the verify dispatch instead) are excluded."""
+        the verify dispatch instead) are excluded, and so is a request
+        whose last token (max_new) is dispatched already: it only waits
+        for its read. All from the `dispatched` count: the ids of the
+        step before need not have been read."""
         rows = []
         for req in self._active_requests():
-            if req.prefilling or req in skip:
+            if req.prefilling or req in skip \
+                    or req.dispatched >= req.max_new:
                 continue
             for bi in range(len(req.slots)):
                 if req.beam is not None and req.finished[bi]:
                     continue
-                p = int(req.prompt.size) + req.produced - 1
+                p = int(req.prompt.size) + req.dispatched - 1
                 rows.append((req, bi, p))
         return rows
 
@@ -1865,49 +1993,64 @@ class DecodingPredictor(object):
         for ALL diverged blocks), then the fixed-shape step that
         advances every live slot one token. With a drafter attached,
         slots holding drafts ride ONE verify tick first, read and
-        advanced as before (ISSUE 17), and the plain step covers only
+        advanced at once (ISSUE 17), and the plain step covers only
         the undrafted remainder. Returns what _read_step needs — the
-        step's read, unmade, the drafted set and the live row count — or
-        None where a fully-drafted (or shed) batch needs no plain
-        dispatch."""
+        step's read, unmade, and the (request, beam index, position)
+        rows it was dispatched for — or None where a fully-drafted (or shed)
+        batch needs no plain dispatch. The span's `ahead` stat: whether
+        the step was dispatched with the previous tick's programs
+        unread (stats.steps_ahead)."""
         with _span('decode/step') as sp:
             with _span('decode/build_feed'):
                 drafted = self._collect_drafts()
             if drafted:
                 self._verify(drafted, waiting)
             with _span('decode/build_feed'):
-                tokens, pos, tables, wtables, cow, active, beam = \
+                tokens, pos, tables, wtables, cow, rows, beam = \
                     self._step_feed(waiting, drafted)
-            sp.set_metadata(active=active)
-            if not active:
+            if not rows:
+                sp.set_metadata(active=0)
                 return None
+            ahead = int(self._unread is not None)
+            sp.set_metadata(active=len(rows), ahead=ahead)
             with self.stats._lock:
-                self.stats.active_slot_steps += active
+                self.stats.active_slot_steps += len(rows)
                 self.stats.slot_steps += self._S
+                self.stats.steps_ahead += ahead
             if cow:
                 self._dispatch_blockcopy(cow)
-            return (self._dispatch_step(tokens, pos, tables, logits=beam,
-                                        wtables=wtables),
-                    drafted, active)
+            read = self._dispatch_step(tokens, pos, tables, logits=beam,
+                                       wtables=wtables)
+            for req in dict.fromkeys(r[0] for r in rows):
+                req.dispatched += 1
+            return read, rows
 
-    def _read_step(self, read, drafted, active):
-        """The read half: wait for what is left of the step, copy its
-        ids (its logits where a beam row was live), emit. Beam reorder
-        is pure block-table permutation (incref/decref, zero device work
-        until the next write diverges a shared tail block)."""
-        with _span('decode/step', active=active):
+    def _read_step(self, read, rows):
+        """The read half, a tick after the dispatch (the same tick's end
+        where _results_first): wait for what is left of the step, copy
+        its ids (its logits where a beam row was live), emit. Beam
+        reorder is pure block-table permutation (incref/decref, zero
+        device work until the next write diverges a shared tail
+        block)."""
+        with _span('decode/step', active=len(rows)):
             ids, logits = self._to_host(read)
-            with _span('decode/advance', rows=active):
-                self._advance(ids, logits, drafted)
+            with _span('decode/advance', rows=len(rows)):
+                self._advance(ids, logits, rows)
 
     def _step_feed(self, waiting, drafted):
         """The plain step's feed over the block pool: reserve and make
         writable every block this step writes, then fill tokens / pos /
         tables for the live undrafted rows — the window layers' too,
         where the artifact has such layers (None otherwise), after
-        giving back the blocks each row's window has passed. Returns
-        them with the CoW pairs to copy first, the number of live rows,
-        and whether one of them is a beam's (the step's logits are then
+        giving back the blocks each row's window has passed. A greedy
+        row's token is -1: the program takes the id the device holds for
+        the slot (what the last step chose there, or the prompt's last
+        slice), which the host may not have read yet. The host supplies
+        the token only where it alone knows it: a beam's, and every row
+        while a drafter is attached (a verify tick moves a request past
+        what the device row holds). Returns them with the CoW pairs to
+        copy first, the (request, beam index, position) rows, and
+        whether one of them is a beam's (the step's logits are then
         wanted)."""
         tokens = np.zeros((self._S, 1), np.int64)
         pos = np.zeros((self._S, 1), np.int32)
@@ -1919,7 +2062,6 @@ class DecodingPredictor(object):
             rows_fn=lambda: [(r, b, p, 1) for r, b, p
                              in self._live_rows(skip=drafted)])
         cow = []
-        active = 0
         beam = False
         rows = self._live_rows(skip=drafted)
         if self._window:
@@ -1927,25 +2069,28 @@ class DecodingPredictor(object):
         for req, bi, p in rows:
             self._ensure_writable(req, bi, p, cow)
             s = req.slots[bi]
-            active += 1
             beam = beam or req.beam is not None
-            tokens[s, 0] = req.last_tokens[bi]
+            from_host = req.beam is not None or self._drafter is not None
+            tokens[s, 0] = req.last_tokens[bi] if from_host else -1
             pos[s, 0] = p
             table = req.tables[bi]
             tables[s, :len(table)] = table
             if self._window:
                 req.wtable.fill(wtables[s])
-        return tokens, pos, tables, wtables, cow, active, beam
+        return tokens, pos, tables, wtables, cow, rows, beam
 
-    def _advance(self, ids, logits, drafted):
-        """After the step: emit the ids the program chose to the greedy
-        streams, score beams over the fetched logits (there iff a beam
-        row was live), finish what ended."""
+    def _advance(self, ids, logits, rows):
+        """After the step's read: emit the ids the program chose to the
+        greedy streams of `rows` (the rows the step was dispatched for),
+        score beams over the fetched logits (there iff a beam row was
+        live), finish what ended. A row whose request no longer holds
+        its slot is dropped and counted (stats.wasted_rows)."""
         now = time.perf_counter()
-        reqs = [r for r in self._active_requests()
-                if not (r.prefilling or r in drafted)]
+        live = [req for req, bi, _ in rows
+                if self._holds(req.slots[bi], req)]
+        reqs = list(dict.fromkeys(live))    # a beam request: once
         toks = ids.tolist()     # once a step, not once a row
-        self._meter_greedy(reqs, now)
+        self._meter_greedy(reqs, now, len(rows) - len(live))
         for req in reqs:
             if req.beam is None:
                 self._advance_greedy(req, toks[req.slots[0]])
@@ -1967,11 +2112,13 @@ class DecodingPredictor(object):
             if all(req.finished) or req.produced >= req.max_new:
                 self._finish_beam(req)
 
-    def _meter_greedy(self, reqs, now):
+    def _meter_greedy(self, reqs, now, wasted):
         """Every greedy request of `reqs` metered for the token this
-        step is about to give it (_advance_greedy), under ONE hold of
-        the stats lock a step instead of one a row."""
+        step is about to give it (_advance_greedy), and the step's
+        dropped rows counted, under ONE hold of the stats lock a step
+        instead of one a row."""
         with self.stats._lock:
+            self.stats.wasted_rows += wasted
             for req in reqs:
                 if req.beam is None:
                     self._count_emit(req, now)
@@ -2067,6 +2214,7 @@ class DecodingPredictor(object):
         req.last_tokens[0] = emitted[-1]
         req.tokens.extend(emitted)
         req.produced += len(emitted)
+        req.dispatched = req.produced     # nothing of it is unread
         with self.stats._lock:
             self.stats.drafted += k
             self.stats.accepted += accepted
@@ -2205,7 +2353,9 @@ class DecodingPredictor(object):
         zero state so the endpoint keeps serving. If even the rebuild
         dispatch fails (wedged backend), the endpoint closes itself —
         queued and future requests fail fast instead of hanging on a
-        dead scheduler."""
+        dead scheduler. What the last tick left unread is read first
+        (its programs ran before the failure)."""
+        self._settle_quietly()
         for req in self._active_requests():
             self._release(req)
             _fail(req, exc)

@@ -457,9 +457,15 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     once, no host copy) and per-program AOT warm-start sidecars, the
     model's programs compiled WITH state donation (the paged cache updates in place; the loader
     passes only XLA-owned buffers, the executor's round-10 ownership
-    discipline). The signature is version 5: weights as arguments
-    (since 4) and `ids` as fetch 0 beside the logits (5); the loader
-    refuses an older artifact by name.
+    discipline). Every program's state ends in the IDS ROW, [max_slots]
+    int32 and no program variable: the step writes its ids there and
+    takes a row's input token from it where the `tokens` feed is
+    negative, a chunk takes a `slot` feed beside the spec's and writes
+    its id into that entry where the slice is its prompt's last
+    (_export_decode_program) — the scheduler dispatches a step before it
+    has read the one before. The signature is version 6: weights as
+    arguments (since 4), `ids` as fetch 0 beside the logits (5), the ids
+    row (6); the loader refuses an older artifact by name.
 
     Artifact layout (out_dir/):
       decode_signature.json   shapes, chunk sizes, params and state
@@ -535,6 +541,7 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                          % (step_want, step['feeds']))
     # every program of the artifact, by its directory
     entries = {_decoding._STEP_DIR: step}
+    roles = {_decoding._STEP_DIR: 'step'}
     verify = spec.get('verify')
     if verify is not None:
         # ISSUE 17: third program — same feed NAMES as the step (the
@@ -543,6 +550,7 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
             raise ValueError("decode-verify feeds must be %r, got %r"
                              % (step_want, verify['feeds']))
         entries[_decoding._VERIFY_DIR] = verify
+        roles[_decoding._VERIFY_DIR] = 'verify'
     chunks = sorted(int(c) for c in spec['chunk'])
     if not chunks:
         raise ValueError("export_decode needs at least one chunk size")
@@ -552,29 +560,39 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
             raise ValueError("chunk feeds must be %r, got %r"
                              % (chunk_want, p['feeds']))
         entries[_decoding._CHUNK_DIR % C] = p
+        roles[_decoding._CHUNK_DIR % C] = 'chunk'
     programs = {d: _optimize_decode_program(e, state_names)
                 for d, e in entries.items()}
     # the ONE parameter list every program takes, in this order
     params = _decode_params(programs.values(), state_names, scope)
     param_args = _param_args(params, set(spec.get('param_shardings') or ()))
-    shard = _decode_shard_ctx(spec, state_names, params, param_args)
+    # (the ids row, named by no sharding, is replicated on a mesh)
+    shard = _decode_shard_ctx(spec, state_names + [_IDS_ROW], params,
+                              param_args)
     os.makedirs(out_dir, exist_ok=True)
     param_sig = _write_decode_weights(
         os.path.join(out_dir, _DECODE_WEIGHTS), param_args, params)
     param_specs = _decoding.param_arg_specs(param_sig, param_args)
     del params
+    # the ids row rides LAST in every program's state: no program
+    # variable, copied by no block pair
+    copied = [n not in (window or {}).get('cache_vars', ())
+              for n in state_names] + [False]
+    state_names = state_names + [_IDS_ROW]
+    state_specs = state_specs + [jax.ShapeDtypeStruct(
+        (int(spec['max_slots']),), np.int32)]
 
     sigs = {}
     for d, e in entries.items():
         with _span('export/program', program=d, params=len(param_sig)):
             sigs[d] = _export_decode_program(
                 e, programs[d], param_args, param_specs, state_names,
-                state_specs, os.path.join(out_dir, d), shard=shard)
+                state_specs, os.path.join(out_dir, d), shard=shard,
+                role=roles[d])
     _export_decode_blockcopy(
         state_specs, int(spec['max_slots']),
         os.path.join(out_dir, _decoding._BLOCKCOPY_DIR), shard=shard,
-        copied=[n not in (window or {}).get('cache_vars', ())
-                for n in state_names])
+        copied=copied)
     _export_decode_zeros(state_specs,
                          os.path.join(out_dir, _decoding._ZEROS_DIR),
                          shard=shard)
@@ -584,7 +602,8 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     # version 4: the weights are ARGUMENTS of every program (one
     # decode_weights.bin, listed under 'params'); up to version 3 each
     # program's module held them as constants. Version 5: every program
-    # returns the argmax ids as fetch 0 beside its logits. 'layout' is
+    # returns the argmax ids as fetch 0 beside its logits. Version 6: the
+    # state's last entry is the ids row. 'layout' is
     # what the loader refuses an artifact without (the slot tier's)
     sig = {'version': _decoding._SIG_VERSION, 'kind': 'decode',
            'layout': 'block',
@@ -745,8 +764,14 @@ def _write_decode_weights(path, param_args, params):
     return out
 
 
+# the last entry of every decode program's state: [max_slots] int32, the
+# id each slot's row chose last (see _export_decode_program)
+_IDS_ROW = 'decode_ids_row'
+
+
 def _export_decode_program(entry, program, param_args, param_specs,
-                           state_names, state_specs, out_dir, shard=None):
+                           state_names, state_specs, out_dir, shard=None,
+                           role='step'):
     """Trace one decode program as fn(params, state, feeds) -> (fetches,
     new_state) — export_train_step's state-threading convention minus
     the rng (decode programs draw no randomness) — and serialize it.
@@ -764,8 +789,25 @@ def _export_decode_program(entry, program, param_args, param_specs,
     full arrays). The program's fetches are `ids` — int32, the argmax
     over the last axis of the logits (the spec's first fetch), lowest
     index on ties — and then what the spec fetches: the one site where
-    greedy token selection enters every program of every model. Returns
-    the program's signature entries: its 'feeds', its 'fetches' by name,
+    greedy token selection enters every program of every model.
+
+    The same site hands each row's token from dispatch to dispatch ON THE
+    DEVICE. The state's last entry (_IDS_ROW, [max_slots] int32; no
+    program variable, replicated on a mesh) holds the id each slot chose
+    last, and `role` says what a program does with it. The 'step' writes
+    its ids there and, in front of the embedding, takes a row's input
+    token from it wherever the `tokens` feed holds a NEGATIVE value —
+    the feed's sign is the per-row mask: a token >= 0 is one only the
+    host knows (a beam's, an accepted draft's, a caller's own). A
+    'chunk' takes one feed more, `slot` [1, 1] int32, and writes the id
+    its last real position chose into that slot's entry — the slice is
+    its prompt's last and the request decodes from the next step without
+    the host having seen its first token — or nothing where `slot` is
+    negative. 'verify' threads the row through (its rows' tokens are the
+    host's).
+
+    Returns the program's signature entries: its 'feeds' (a chunk's
+    with `slot`), its 'fetches' by name,
     and under 'attention' the body each of its kv_*attention* ops holds
     where the module is compiled for a TPU, by op type and counted
     ({'kv_block_attention': {'kernel': 6}}) — 'kernel' is the paged
@@ -780,6 +822,9 @@ def _export_decode_program(entry, program, param_args, param_specs,
     feed_names = list(entry['feeds'])
     fetch_names = list(entry['fetches'])
     samples = {n: np.asarray(entry['samples'][n]) for n in feed_names}
+    if role == 'chunk':
+        feed_names.append('slot')     # fed to fn, no program variable
+        samples['slot'] = np.full((1, 1), -1, np.int32)
     rng = jax.random.key(0)  # decode programs draw no randomness
     constrain = shard['constrain'] if shard is not None else {}
     sizes = {v.name: int(np.prod(v.shape)) for v in program.list_vars()
@@ -799,14 +844,28 @@ def _export_decode_program(entry, program, param_args, param_specs,
         for n, ns in constrain.items():
             tracer.env[n] = jax.lax.with_sharding_constraint(
                 tracer.env[n], ns)
-        tracer.env.update(dict(zip(state_names, state_list)))
-        tracer.env.update(dict(zip(feed_names, feed_list)))
+        feeds = dict(zip(feed_names, feed_list))
+        row = state_list[-1]
+        if role == 'step':
+            with jax.named_scope('device_tokens'):
+                host = feeds['tokens']
+                feeds['tokens'] = jnp.where(
+                    host < 0, row[:, None].astype(host.dtype), host)
+        slot = feeds.pop('slot', None)
+        tracer.env.update(dict(zip(state_names[:-1], state_list)))
+        tracer.env.update(feeds)
         tracer.run_block(program.global_block())
         lowered[:] = tracer.lowered_bodies
         fetched = [tracer.env[n] for n in fetch_names]
         with jax.named_scope('greedy_ids'):
             ids = jnp.argmax(fetched[0], axis=-1).astype(jnp.int32)
-        return ([ids] + fetched, [tracer.env[n] for n in state_names])
+            if role == 'step':
+                row = ids
+            elif role == 'chunk':
+                row = jnp.where(jnp.arange(row.shape[0]) == slot[0, 0],
+                                ids[0], row)
+        return ([ids] + fetched,
+                [tracer.env[n] for n in state_names[:-1]] + [row])
 
     lowered = []     # (op type, body) as the export's trace lowered them
     feed_specs = [jax.ShapeDtypeStruct(samples[n].shape, samples[n].dtype)

@@ -1,7 +1,10 @@
 """Greedy token selection inside the decode programs (signature version 5):
 every token-emitting program returns `ids`, the int32 argmax of its logits,
 as fetch 0 beside the float32 logits, and the scheduler copies the ids — the
-logits only for a dispatch in which a beam row is live.
+logits only for a dispatch in which a beam row is live. Since version 6 the
+programs also hand each slot's last id from dispatch to dispatch in their
+state (the ids row), so that the scheduler dispatches a step before it has
+read the one before: section (e).
 
 On any platform the ids equal np.argmax of the logits fetch of the SAME
 dispatch, bit for bit, lowest index on ties."""
@@ -158,7 +161,7 @@ def test_ids_are_the_argmax_of_the_same_dispatch(arts, name, program, tie):
 def test_signature_names_both_fetches(arts, name):
     with open(os.path.join(arts(name), decoding._DECODE_SIGNATURE)) as f:
         sig = json.load(f)
-    assert sig['version'] == decoding._SIG_VERSION == 5
+    assert sig['version'] == decoding._SIG_VERSION == 6
     entries = [sig['step']] + list(sig['chunk'].values()) \
         + ([sig['verify']] if 'verify' in sig else [])
     assert len(entries) >= 3
@@ -296,3 +299,138 @@ def test_served_logits_still_returns_rows(arts, name):
     # (until eos ends a stream early)
     for toks, got in zip(tokens, served):
         assert got == toks[:len(got)]
+
+
+# -- (e) the ids row: a row's token handed on, on the device -----------------
+
+def _row(pred):
+    return np.asarray(pred._state[-1]).tolist()
+
+
+@pytest.mark.parametrize('name', ['block', 'block_int8', 'olmoe'])
+def test_a_slice_writes_its_slot_and_the_step_takes_it_from_there(arts, name):
+    """A chunk dispatched with `slot` writes the id its last real
+    position chose into that entry of the state's last array and no
+    other (a negative slot: nothing); a step fed -1 takes that entry as
+    the row's token — the same logits, bit for bit, as the step fed the
+    token by the host — and leaves its own ids there."""
+    with DecodingPredictor(arts(name)) as pred:
+        S, maxb = pred.max_slots, pred._maxb
+        prompts = _prompts(pred._vocab)[:3]
+        tables = np.full((S, maxb), pred._trash, np.int32)
+        first = []
+        for i, prompt in enumerate(prompts):
+            tables[i] = 1 + i * maxb + np.arange(maxb)
+            size = pred._chunks[-1]
+            ids = np.zeros((1, size), np.int64)
+            ids[0, :len(prompt)] = prompt
+            before = _row(pred)
+            # not the prompt's last slice as far as the program knows
+            tok, _ = decoding._one_row(*pred._to_host(pred._dispatch_chunk(
+                size, ids, 0, len(prompt), tables[i:i + 1])))
+            assert _row(pred) == before
+            again, _ = decoding._one_row(*pred._to_host(pred._dispatch_chunk(
+                size, ids, 0, len(prompt), tables[i:i + 1], slot=i)))
+            assert again == tok
+            assert _row(pred) == before[:i] + [tok] + before[i + 1:]
+            first.append(tok)
+        pos = np.zeros((S, 1), np.int32)
+        host = np.zeros((S, 1), np.int64)
+        for i, prompt in enumerate(prompts):
+            pos[i, 0] = len(prompt)
+            host[i, 0] = first[i]
+        want = pred._to_host(pred._dispatch_step(host, pos, tables,
+                                                 logits=True))
+        assert _row(pred) == want[0].tolist()
+        # put the slices' ids back (the step above overwrote them), then
+        # let the device supply rows 0..2 and the host row 3
+        for i, prompt in enumerate(prompts):
+            ids = np.zeros((1, pred._chunks[-1]), np.int64)
+            ids[0, :len(prompt)] = prompt
+            pred._dispatch_chunk(pred._chunks[-1], ids, 0, len(prompt),
+                                 tables[i:i + 1], read=False, slot=i)
+        dev = np.full((S, 1), -1, np.int64)
+        dev[3, 0] = 0
+        got = pred._to_host(pred._dispatch_step(dev, pos, tables,
+                                                logits=True))
+        assert np.array_equal(got[0], want[0])
+        assert np.array_equal(got[1], want[1])
+        # the next step, fed nothing by the host, continues from the ids
+        # the last one left in the row
+        nxt_host = pred._to_host(pred._dispatch_step(
+            np.asarray(want[0], np.int64)[:, None], pos + 1, tables,
+            logits=True))
+        for i, prompt in enumerate(prompts):    # rewind the row
+            pred._state[-1] = pred._state[-1].at[i].set(int(want[0][i]))
+        nxt_dev = pred._to_host(pred._dispatch_step(
+            np.full((S, 1), -1, np.int64), pos + 1, tables, logits=True))
+        assert np.array_equal(nxt_dev[1][:3], nxt_host[1][:3])
+
+
+def test_verify_and_blockcopy_thread_the_row_through(arts):
+    with DecodingPredictor(arts('block')) as pred:
+        S = pred.max_slots
+        pred._state[-1] = pred._state[-1].at[:].set(
+            np.arange(5, 5 + S, dtype=np.int32))
+        tables = np.full((S, pred._maxb), pred._trash, np.int32)
+        R = K + 1
+        pred._to_host(pred._dispatch_verify(
+            np.zeros((S, R), np.int64),
+            np.full((S, R), pred._maxb * pred._bs, np.int32), tables))
+        pred._dispatch_blockcopy([])
+        assert _row(pred) == list(range(5, 5 + S))
+        pred._reset_state()
+        assert _row(pred) == [0] * S
+
+
+class _StepEvents(object):
+    """'D' for each dispatch of the step program and 'H' for each copy of
+    a step's ids to the host, in the order the scheduler made them."""
+
+    def __init__(self, monkeypatch):
+        self.seen = []
+        real = decoding._span
+
+        def span(name, **stats):
+            if stats.get('program') == 'step' and name in (
+                    'decode/dispatch', 'decode/d2h'):
+                self.seen.append('D' if name == 'decode/dispatch' else 'H')
+            return real(name, **stats)
+        monkeypatch.setattr(decoding, '_span', span)
+
+
+@pytest.mark.parametrize('name', ['block', 'olmoe'])
+def test_step_k_plus_1_is_dispatched_before_step_k_is_read(arts, name,
+                                                            monkeypatch):
+    events = _StepEvents(monkeypatch)
+    with DecodingPredictor(arts(name)) as pred:
+        pred._eos = -1          # run to max_new: 1 token of the slice + 5
+        toks = pred.submit(_prompts(pred._vocab)[1],
+                           max_new_tokens=6).result(120)
+        snap = pred.stats.snapshot()
+    assert len(toks) == 6
+    # dispatch(1) dispatch(2) read(1) dispatch(3) read(2) ... read(5)
+    assert ''.join(events.seen) == 'D' + 'DH' * 4 + 'H'
+    assert snap['steps'] == snap['steps_ahead'] == 5
+    assert snap['wasted_rows'] == 0
+
+
+@pytest.mark.parametrize('how', ['beam', 'drafter'])
+def test_a_beam_row_or_a_drafter_takes_the_settled_order(arts, how,
+                                                         monkeypatch):
+    """Where the host must see a step's result before it can build the
+    next feed, every step is read before the next is dispatched."""
+    events = _StepEvents(monkeypatch)
+    prompts = _prompts(VOCAB)
+    kw = {'draft': 'ngram'} if how == 'drafter' else {}
+    with DecodingPredictor(arts('block'), **kw) as pred:
+        if how == 'beam':
+            pred.submit(prompts[2], max_new_tokens=8, beam=2).result(120)
+        else:
+            for p in (np.tile(prompts[1][:4], 4), prompts[3]):
+                pred.submit(p, max_new_tokens=12).result(120)
+        snap = pred.stats.snapshot()
+        assert pred._unread is None
+    assert snap['steps'] > 0 and snap['steps_ahead'] == 0
+    assert snap['wasted_rows'] == 0
+    assert ''.join(events.seen) == 'DH' * snap['steps']

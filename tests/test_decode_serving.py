@@ -59,9 +59,15 @@ def test_artifact_layout(artifact):
     assert sig['max_slots'] == SLOTS
     assert sig['layout'] == 'block'
     assert sig['chunk_buckets'] == sorted(CHUNKS)
-    assert len(sig['state']) == 4  # 2 layers x K/V
-    for e in sig['state']:
+    # 2 layers x K/V, then the ids row (each slot's last id, handed
+    # from dispatch to dispatch on the device)
+    assert len(sig['state']) == 5
+    for e in sig['state'][:-1]:
         assert e['shape'][:2] == [SLOTS * (CACHE // BLOCK) + 1, BLOCK]
+    assert sig['state'][-1] == {'name': 'decode_ids_row', 'shape': [SLOTS],
+                                'dtype': 'int32'}
+    assert [e['name'] for e in sig['chunk'][str(CHUNKS[0])]['feeds']][-1] \
+        == 'slot'
     for d in ([decoding._STEP_DIR, decoding._ZEROS_DIR,
                decoding._BLOCKCOPY_DIR] +
               [decoding._CHUNK_DIR % c for c in CHUNKS]):
@@ -185,10 +191,11 @@ def test_deadline_expiry_mid_decode_frees_slot(artifact):
 
 
 def test_second_token_comes_from_the_tick_after_the_first(artifact):
-    """A prompt's last slice is read at the END of its tick, behind the
-    running batch's step: the request emits its first token there and
-    joins the step of the NEXT tick — one token a tick from then on, the
-    first delivered before the second."""
+    """A prompt's last slice is read a tick after its dispatch, behind
+    the step that already decodes the request (its first token went from
+    slice to step on the device): the first token is emitted there, the
+    second a tick later — one token a tick from then on, the first
+    delivered before the second."""
     rng = np.random.RandomState(20)
     prompt = rng.randint(2, VOCAB, 11)      # chunks (4, 8): two slices
     with DecodingPredictor(artifact) as pred:
@@ -196,18 +203,24 @@ def test_second_token_comes_from_the_tick_after_the_first(artifact):
 
         def tick(waiting):
             run_tick(waiting)
-            seen.extend((r.next_start, r.prefilling, r.produced)
-                        for r in pred._active_requests())
+            seen.extend((r.next_start, r.prefilling, r.dispatched,
+                         r.produced) for r in pred._active_requests())
         pred._run_tick = tick
         stream = pred.submit(prompt, max_new_tokens=5)
         got = list(stream)
         pred._run_tick = run_tick
         want = pred.generate(prompt, max_new_tokens=5)
+        snap = pred.stats.snapshot()
     assert got == want == stream.result(10)
-    # after each tick: (prompt tokens prefilled, still prefilling, emitted);
-    # the tick that emits the fifth token finishes the request
-    assert seen == [(8, True, 0), (11, False, 1), (11, False, 2),
-                    (11, False, 3), (11, False, 4)]
+    # after each tick: (prompt tokens prefilled, still prefilling, tokens
+    # dispatched, tokens emitted): emitted runs one tick behind
+    # dispatched, the fifth token is dispatched in the tick that emits
+    # the third, and the tick that emits the fifth finishes the request
+    assert seen == [(8, True, 0, 0), (11, False, 1, 0), (11, False, 2, 1),
+                    (11, False, 3, 2), (11, False, 4, 3), (11, False, 5, 4)]
+    # every step of both requests was dispatched with a read outstanding
+    assert snap['steps'] == snap['steps_ahead'] == 8
+    assert snap['wasted_rows'] == 0
 
 
 def test_a_failing_chunk_program_fails_every_request_loudly(artifact):
@@ -327,3 +340,176 @@ def test_warm_fresh_subprocess_zero_compiles(artifact):
     np.testing.assert_array_equal(np.asarray(payload['beam_ids']), ids)
     np.testing.assert_array_equal(np.asarray(payload['beam_scores']),
                                   scores)
+
+
+# -- one step ahead (ISSUE 31): a tick reads what the tick before dispatched --
+
+def _gated(pred, before=None):
+    """Run `before(pred)` on the scheduler's own thread in front of
+    every tick, and hold the FIRST tick until the test has queued
+    its whole batch: tick 1 then admits the first request alone and tick
+    2 finds every other one waiting — admissions staggered, and the same
+    in every run."""
+    import threading
+    run_tick, gate = pred._run_tick, threading.Event()
+
+    def tick(waiting):
+        assert gate.wait(60)
+        if before is not None:
+            before(pred)
+        run_tick(waiting)
+    pred._run_tick = tick
+    return gate
+
+
+def _mixed_batch(seed=31):
+    """Seven prompts over 4 slots: one of 19 tokens (three slices of the
+    (4, 8) chunks), short ones beside it, three more than there are
+    slots (a freed slot is re-admitted)."""
+    rng = np.random.RandomState(seed)
+    lens = (5, 19, 3, 7, 2, 6, 4)
+    return [rng.randint(2, VOCAB, n) for n in lens], (9, 6, 12, 5, 12, 7, 8)
+
+
+def _serve_batch(pred, prompts, max_new, settled):
+    if settled:
+        # the synchronous order: the same code, the read taken before
+        # the next dispatch — what a live beam or a drafter asks for
+        pred._results_first = lambda: True
+    pred.stats.reset()
+    gate = _gated(pred)
+    streams = [pred.submit(p, max_new_tokens=n)
+               for p, n in zip(prompts, max_new)]
+    gate.set()
+    got = [list(s.result(120)) for s in streams]
+    return got, pred.stats.snapshot()
+
+
+def test_ahead_and_settled_orders_serve_the_same_transcripts(artifact):
+    """A mixed batch — staggered admissions, a 3-slice prompt, a row that
+    ends by eos mid-batch with its slot re-admitted, rows that end by
+    max_new — decodes to the same tokens whether each step is dispatched
+    before the previous one is read (the scheduler's order for greedy
+    rows) or after it (forced here, on the instance), and to what each
+    request decodes to alone. The counters are exact."""
+    prompts, max_new = _mixed_batch()
+    with DecodingPredictor(artifact) as pred:
+        solo = [pred.generate(p, max_new_tokens=n)
+                for p, n in zip(prompts, max_new)]
+    # eos is the host's to see: take the artifact's where a transcript
+    # ends by it mid-batch while another runs to max_new, else name a
+    # token that does (told to the instance, like the order)
+
+    def cut(eos):
+        return [t[:t.index(eos) + 1] if eos in t else t for t in solo]
+
+    def mixed(eos):
+        ends = [len(t) < n for t, n in zip(cut(eos), max_new)]
+        return any(ends) and not all(ends)
+    eos = next(e for e in [1] + sorted({t for row in solo for t in row})
+               if mixed(e))
+    want = cut(eos)
+    early = sum(1 for t, n in zip(want, max_new) if len(t) < n)
+    snaps = {}
+    for settled in (False, True):
+        with DecodingPredictor(artifact) as pred:
+            pred._eos = eos
+            got, snaps[settled] = _serve_batch(pred, prompts, max_new,
+                                               settled)
+            assert _pool_is_empty(pred)
+        assert got == want, settled
+    ahead, settled = snaps[False], snaps[True]
+    tokens = sum(len(t) for t in want)
+    for snap in (ahead, settled):
+        assert snap['tokens'] == tokens and snap['requests'] == len(want)
+        assert snap['slice_reads'] == len(want)
+        assert snap['chunk_slices'] == len(want) + 2      # 19 = 8 + 8 + 3
+    # every greedy step is dispatched with the tick before it unread; a
+    # row whose eos is seen a tick late rides ONE more step
+    assert ahead['steps_ahead'] == ahead['steps'] > 0
+    assert ahead['wasted_rows'] == early
+    assert settled['steps_ahead'] == settled['wasted_rows'] == 0
+    # a request's first token comes from its slice, every other from a
+    # step row; the wasted rows are the only rows that gave none
+    for snap in (ahead, settled):
+        rows = int(round(snap['occupancy'] * snap['steps'] * SLOTS))
+        assert rows == tokens - len(want) + snap['wasted_rows']
+
+
+def _pool_is_empty(pred):
+    pred.block_manager.evict_all_prefixes()
+    return pred.block_manager.stats()['blocks_in_use'] == 0 \
+        and pred._free_slots() == list(range(SLOTS))
+
+
+@pytest.mark.parametrize('what', ['cancel', 'deadline', 'drain', 'close',
+                                  'shed'])
+def test_an_outstanding_read_strands_no_stream_and_leaks_no_block(
+        artifact, what):
+    """cancel, an expired deadline, drain, close and a block-pool shed,
+    each arriving while the last tick's step is unread (on the
+    scheduler's own thread, at a tick that finds a read outstanding and
+    two tokens out): every stream ends — with its tokens or its error —
+    and every block comes back to the pool."""
+    prompts, max_new = _mixed_batch(32)
+    fired = []
+
+    def fire(pred):
+        target = next((r for r in pred._active_requests()
+                       if r.produced >= 2 and r.dispatched < r.max_new),
+                      None)
+        if fired or pred._unread is None or pred._unread[0] is None \
+                or target is None:
+            return
+        fired.append(target)
+        if what == 'cancel':
+            target.stream.cancel()
+        elif what == 'deadline':
+            target.deadline = 0.0
+        elif what == 'drain':
+            pred._draining = True       # what drain() sets before it waits
+        elif what == 'close':
+            pred.close()                # on the scheduler's thread: no join
+        elif what == 'shed':
+            reserve = pred._blocks.reserve
+
+            def once(n):
+                pred._blocks.reserve = reserve
+                return False            # pressure: the youngest row goes
+            pred._blocks.reserve = once
+
+    with DecodingPredictor(artifact) as pred:
+        want = [pred.generate(p, max_new_tokens=n)
+                for p, n in zip(prompts, max_new)]
+        gate = _gated(pred, before=fire)
+        streams = [pred.submit(p, max_new_tokens=n, deadline_ms=3.6e6)
+                   for p, n in zip(prompts, max_new)]
+        gate.set()
+        ends = []
+        for s in streams:
+            try:
+                ends.append(list(s.result(120)))
+            except Exception as e:
+                ends.append(e)
+        assert fired and all(s.done() for s in streams)
+        errors = [e for e in ends if isinstance(e, Exception)]
+        # who finished has the tokens it decodes to alone
+        assert all(e == w for e, w in zip(ends, want)
+                   if not isinstance(e, Exception))
+        if what in ('cancel', 'deadline', 'shed'):
+            assert len(errors) == 1
+            assert type(errors[0]).__name__ == {
+                'cancel': 'RuntimeError', 'deadline': 'DeadlineExceeded',
+                'shed': 'MidStreamEvicted'}[what]
+        elif what == 'drain':
+            # active streams finish; the waiting ones are shed at the door
+            assert errors and all(isinstance(e, ServerOverloaded)
+                                  for e in errors)
+            assert pred.drain(60)
+        else:
+            assert errors and all('closed' in str(e) for e in errors)
+        assert pred._unread is None
+        assert _pool_is_empty(pred)
+        if what != 'close':
+            pred._draining = False
+            assert pred.generate(prompts[0], max_new_tokens=9) == want[0]

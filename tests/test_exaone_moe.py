@@ -388,7 +388,8 @@ def test_signature_and_feeds_name_the_window_pool(served):
     assert [e['name'] for e in sig['step']['feeds']] == [
         'tokens', 'pos', 'block_tables', 'window_tables']
     assert [e['name'] for e in sig['chunk']['16']['feeds']] == [
-        'chunk_ids', 'start', 'chunk_len', 'block_table', 'window_table']
+        'chunk_ids', 'start', 'chunk_len', 'block_table', 'window_table',
+        'slot']
 
 
 def test_window_artifact_refuses_beams_and_prefix_reuse_by_name(served):

@@ -373,8 +373,10 @@ def test_block_artifact_layout(arts):
     assert blk['block_size'] == 4
     assert blk['max_blocks_per_slot'] == CACHE // 4
     assert blk['num_blocks'] == SLOTS * (CACHE // 4) + 1
-    for e in sig['state']:
+    for e in sig['state'][:-1]:
         assert e['shape'][:2] == [blk['num_blocks'], 4]
+    # the last entry is no pool: each slot's last id (version 6)
+    assert sig['state'][-1]['shape'] == [SLOTS]
     for d in ([decoding._STEP_DIR, decoding._ZEROS_DIR,
                decoding._BLOCKCOPY_DIR] +
               [decoding._CHUNK_DIR % c for c in sig['chunk_buckets']]):

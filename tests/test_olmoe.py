@@ -289,7 +289,7 @@ def test_artifact_holds_one_copy_of_the_weights(olmoe_art):
     art, weights = olmoe_art
     with open(os.path.join(art, decoding._DECODE_SIGNATURE)) as f:
         sig = json.load(f)
-    assert sig['version'] == decoding._SIG_VERSION == 5
+    assert sig['version'] == decoding._SIG_VERSION == 6
     assert sorted(e['name'] for e in sig['params']) == sorted(weights)
     # the 9 float32 norm vectors ride in one argument, each matrix alone
     packs = [a for a in sig['param_args'] if len(a) > 1]
@@ -335,7 +335,9 @@ def test_programs_share_one_set_of_device_buffers(olmoe_art):
         assert [p.unsafe_buffer_pointer() for p in pred._params] == before
         assert all(not p.is_deleted() for p in pred._params)
         # the pool was born on the device in its signature's dtype
-        assert {str(s.dtype) for s in pred._state} == {'bfloat16'}
+        # (the state's last entry is the ids row, not a pool)
+        assert {str(s.dtype) for s in pred._state[:-1]} == {'bfloat16'}
+        assert str(pred._state[-1].dtype) == 'int32'
 
 
 def test_warm_fresh_process_loads_sidecars_with_zero_compiles(olmoe_art):
@@ -439,7 +441,7 @@ def test_transformer_transcripts_are_the_parents(tmp_path, name):
     assert got == _PARENT[name]
     with open(os.path.join(art, decoding._DECODE_SIGNATURE)) as f:
         sig = json.load(f)
-    assert sig['version'] == 5 and sig['params']
+    assert sig['version'] == 6 and sig['params']
     with open(os.path.join(art, decoding._STEP_DIR, serve._MODULE),
               'rb') as f:
         assert len(f.read()) < sig['weight_bytes']
@@ -453,7 +455,9 @@ def test_transformer_builder_makes_a_bfloat16_pool(tmp_path):
     art = _transformer_art(tmp_path, 'block_bf16')
     prompts = _transformer_prompts()
     with DecodingPredictor(art) as pred:
-        assert {str(s.dtype) for s in pred._state} == {'bfloat16'}
+        # (the state's last entry is the ids row, not a pool)
+        assert {str(s.dtype) for s in pred._state[:-1]} == {'bfloat16'}
+        assert str(pred._state[-1].dtype) == 'int32'
         together = [s.result(120) for s in
                     [pred.submit(p, max_new_tokens=8) for p in prompts]]
         pred.block_manager.evict_all_prefixes()

@@ -141,8 +141,8 @@ def test_decode_span_is_in_the_trace(decode_trace, name):
     ('decode/advance', ('decode/step',)),
     ('decode/dispatch', ('decode/step', 'decode/prefill_slice')),
     # a slice is dispatched inside its span and never waited for there;
-    # a prompt's last slice is read at the end of the tick, in no span
-    # but the tick's
+    # a prompt's last slice is read at the end of the NEXT tick, in no
+    # span but the tick's
     ('decode/device_wait', ('decode/step', 'decode/tick')),
     ('decode/d2h', ('decode/step', 'decode/tick')),
     ('decode/first_token', ('decode/tick',)),
@@ -228,27 +228,55 @@ def test_a_slice_is_dispatched_and_only_a_prompts_last_is_read(decode_trace):
     assert len(decode_trace.named('decode/first_token')) == 3
 
 
-def test_the_step_goes_first_and_is_read_before_any_slice(decode_trace):
-    """In a tick that holds both, the step is dispatched before the first
-    slice, every slice is dispatched before the step is waited for, and
-    the step's tokens are out (decode/advance ends) before the first
-    slice's result is touched."""
-    both = 0
+def test_the_step_goes_first_and_the_last_tick_is_read_behind_it(
+        decode_trace):
+    """In a tick the next step is dispatched first, then what the
+    PREVIOUS tick dispatched is read — dispatch(step k+1) ends before
+    d2h(step k) begins, and the step's tokens are out (decode/advance
+    ends) before a slice's result is touched — and only then the tick's
+    own slices are dispatched, behind every read."""
+    ahead = slices = 0
     for tick in decode_trace.named('decode/tick'):
         step = decode_trace.inside(tick, 'decode/dispatch', 'step')
         chunks = decode_trace.inside(tick, 'decode/dispatch', 'chunk_')
-        if not step or not chunks:
-            continue
-        both += 1
-        assert len(step) == 1
-        assert step[0][2] <= chunks[0][1] + _TOL_NS
-        wait, = decode_trace.inside(tick, 'decode/device_wait', 'step')
-        assert chunks[-1][2] <= wait[1] + _TOL_NS
-        advance, = decode_trace.inside(tick, 'decode/advance')
-        for read in decode_trace.inside(tick, 'decode/device_wait',
-                                        'chunk_'):
-            assert advance[2] <= read[1] + _TOL_NS
-    assert both >= 1
+        waits = decode_trace.inside(tick, 'decode/device_wait', 'step')
+        copies = decode_trace.inside(tick, 'decode/d2h', 'step')
+        assert len(step) <= 1 and len(waits) == len(copies) <= 1
+        if step and chunks:
+            assert step[0][2] <= chunks[0][1] + _TOL_NS
+        if step and copies:
+            ahead += 1
+            assert step[0][2] <= waits[0][1] + _TOL_NS
+            assert step[0][2] <= copies[0][1] + _TOL_NS
+        firsts = decode_trace.inside(tick, 'decode/device_wait', 'chunk_')
+        advance = decode_trace.inside(tick, 'decode/advance')
+        for read in firsts:
+            assert all(a[2] <= read[1] + _TOL_NS for a in advance)
+        reads = decode_trace.inside(tick, 'decode/d2h')
+        if chunks and reads:
+            slices += 1
+            assert max(r[2] for r in reads) <= chunks[0][1] + _TOL_NS
+    assert ahead >= 1 and slices >= 1
+
+
+def test_the_dispatch_half_of_a_step_says_whether_it_ran_ahead(decode_trace):
+    """decode/step opens twice for one step: the dispatch half carries
+    `ahead` (1: the previous tick's programs were unread when it was
+    dispatched), the read half, a tick later, does not. Greedy traffic
+    runs every step ahead."""
+    halves = decode_trace.named('decode/step')
+    dispatch = [s for s in halves if 'ahead' in s[4]]
+    read = [s for s in halves if 'ahead' not in s[4] and s[4]['active']]
+    assert len(dispatch) == len(read) >= 3
+    assert {s[4]['ahead'] for s in dispatch} == {1}
+    for d, r in zip(dispatch, read):
+        assert d[4]['active'] == r[4]['active']
+        assert decode_trace.inside(d, 'decode/dispatch', 'step')
+        assert not decode_trace.inside(d, 'decode/d2h')
+        assert decode_trace.inside(r, 'decode/d2h', 'step')
+        # the read half lies in a LATER tick than its dispatch half
+        assert decode_trace.parent_of(d, ('decode/tick',))[4]['tick'] \
+            < decode_trace.parent_of(r, ('decode/tick',))[4]['tick']
 
 
 @pytest.mark.parametrize('sub,name', [

@@ -108,7 +108,7 @@ def test_verify_artifact_layout(arts):
         with open(os.path.join(arts[name],
                                decoding._DECODE_SIGNATURE)) as f:
             sig = json.load(f)
-        assert sig['version'] == decoding._SIG_VERSION == 5
+        assert sig['version'] == decoding._SIG_VERSION == 6
         ver = sig['verify']
         assert ver['draft_k'] == K
         assert (sorted(e['name'] for e in ver['feeds']) ==
