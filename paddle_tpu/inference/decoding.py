@@ -36,6 +36,9 @@ vLLM-style preallocated, block-paged KV cache):
    the device (`_run_tick`; signature version 6), so the device works
    while the host emits. Where the host must see a result first (a live
    beam, a drafter) the same code reads before it dispatches.
+   The step's feed and the live rows are kept between ticks and
+   re-written at events (`_step_feed`): a tick's host work follows the
+   rows that changed, not the rows that are live.
 3. **Weights as arguments, donated paged KV state** — every program is
    `fn(params, state, feeds)`: the weights are loaded once from the
    artifact's one weights file into one set of device buffers that step,
@@ -305,6 +308,15 @@ class DecodeStats(object):
         # more step), or a cancel, expiry or shed between the dispatch
         # and its read
         self.wasted_rows = 0
+        # the step's feed is kept between ticks: of the rows live in the
+        # steps dispatched (feed_rows_live), those the host re-wrote for
+        # that step (feed_rows_touched) — a row's first step, a position
+        # that opens a block or moves a window, and every step of a row
+        # the host alone can advance (a beam's, a drafter's, one admitted
+        # on a prefix hit). The rest rode the arrays as the step before
+        # left them, one position on
+        self.feed_rows_touched = 0
+        self.feed_rows_live = 0
         # speculative decoding (ISSUE 17). adv_* meter tokens delivered
         # per request-advancing dispatch (prefill first token, plain
         # step, beam step, verify tick) — tokens_per_dispatch is
@@ -340,6 +352,8 @@ class DecodeStats(object):
             self.slice_reads = 0
             self.steps_ahead = 0
             self.wasted_rows = 0
+            self.feed_rows_touched = 0
+            self.feed_rows_live = 0
             self.verify_steps = 0
             self.drafted = 0
             self.accepted = 0
@@ -401,7 +415,9 @@ class DecodeStats(object):
                     'chunk_slices': int(self.chunk_slices),
                     'slice_reads': int(self.slice_reads),
                     'steps_ahead': int(self.steps_ahead),
-                    'wasted_rows': int(self.wasted_rows)}
+                    'wasted_rows': int(self.wasted_rows),
+                    'feed_rows_touched': int(self.feed_rows_touched),
+                    'feed_rows_live': int(self.feed_rows_live)}
             if self.block_source is None:    # not wired to a pool yet
                 return snap
         # outside the stats lock: the BlockManager takes its own
@@ -433,11 +449,16 @@ class TokenStream(object):
     are queued as ONE batch. `__iter__` still yields token-at-a-time
     (order preserved), `batches()` yields one list per delivery event —
     the fleet wire protocol iterates batches so a verify tick costs one
-    frame, not K+1."""
+    frame, not K+1.
+
+    The queue is a `queue.SimpleQueue`: a delivery is ONE C call on the
+    scheduler's thread (no python Lock + Condition per token), and a
+    consumer blocked in `get` wakes without running python-level lock
+    code under the GIL."""
 
     def __init__(self, beam=None):
         self.beam = beam
-        self._q = queue.Queue()
+        self._q = queue.SimpleQueue()
         self._fut = Future()
         self._cancelled = False
 
@@ -585,8 +606,9 @@ class _Request(object):
     __slots__ = ('prompt', 'max_new', 'beam', 'stream', 't_submit',
                  'deadline', 'slots', 'produced', 'dispatched', 'tokens',
                  'last_tokens',
-                 'scores', 'finished', 'hyps', 't_first', 't_last',
-                 'tables', 'wtable', 'next_start', 'prefilling', 'match',
+                 'scores', 'finished', 'hyps', 't_first',
+                 'tables', 'wtable', 'next_start', 'prefilling', 'shared',
+                 'match',
                  'match_epoch', 'draft_strikes', 'draft_cooldown',
                  'request_id', 'seq')
 
@@ -608,17 +630,21 @@ class _Request(object):
         # positions, tables, max_new — while `produced` runs a read behind
         self.dispatched = 0
         self.tokens = []                  # greedy transcript
-        self.last_tokens = []             # per beam: next step's input
+        # per beam: next step's input (a greedy request's is tokens[-1])
+        self.last_tokens = []
         self.scores = []                  # per beam accumulated logprob
         self.finished = []                # per beam: emitted eos
         self.hyps = []                    # per beam token lists
+        # first delivery; the last one is the scheduler's, per slot
+        # (DecodingPredictor._t_last)
         self.t_first = None
-        self.t_last = None
         # block tables and chunked prefill (ISSUE 13)
         self.tables = []                  # per beam: logical block ids
         self.wtable = None                # window layers' (WindowTable)
         self.next_start = 0               # next chunked-prefill position
         self.prefilling = False           # still admitting via chunks
+        # admitted on a prefix hit: its table holds blocks it shares
+        self.shared = False
         self.match = None                 # cached (shared blocks, covered)
         self.match_epoch = -1             # prefix_epoch the match saw
         # speculative decoding (ISSUE 17): acceptance-aware backoff
@@ -968,6 +994,28 @@ class DecodingPredictor(object):
             name='blockcopy')
         self._state = None
         self._slots = [None] * self._S    # slot -> (request, beam index)
+        # THE STEP'S FEED, KEPT BETWEEN TICKS: one row a slot, born in
+        # the idle row's state (token 0, position 0, trash table) and
+        # re-written only at an event of its row (_step_feed has the
+        # list); every other step a live row rides them as they are, one
+        # position on (_step adds the live mask to the positions)
+        S, maxb = self._S, self._maxb
+        self._feed_tokens = np.zeros((S, 1), np.int64)
+        self._feed_pos = np.zeros((S, 1), np.int32)
+        self._feed_live = np.zeros((S, 1), np.int32)     # 1: the row steps
+        self._feed_tables = np.full((S, maxb), self._trash, np.int32)
+        self._feed_wtables = (np.full((S, maxb), self._trash, np.int32)
+                              if self._window else None)
+        # when each slot's stream last had a delivery (perf_counter): the
+        # inter-token samples of a whole step come from one subtraction
+        self._t_last = np.zeros(S, np.float64)
+        # requests whose rows the next step's feed re-writes from the
+        # request itself, in the order they became decoding rows: every
+        # request for its first step, and for every step those the
+        # arrays cannot advance blindly (_blind)
+        self._rewrite = {}
+        # _active_requests()'s list, kept until a slot changes hands
+        self._active = None
         # what the last tick dispatched and nobody has read yet: (the
         # step's read and rows or None, [(request, read)] of the slices
         # that were their prompt's last), or None
@@ -1429,9 +1477,14 @@ class DecodingPredictor(object):
 
     # -- scheduler ---------------------------------------------------------
     def _active_requests(self):
-        """The requests that hold a slot, in slot order, each once."""
-        return list(dict.fromkeys(
-            e[0] for e in self._slots if e is not None))
+        """The requests that hold a slot, in slot order, each once. The
+        list is kept between ticks and made anew only after a slot has
+        changed hands (_admit, _release): callers read it, none writes
+        it."""
+        if self._active is None:
+            self._active = list(dict.fromkeys(
+                e[0] for e in self._slots if e is not None))
+        return self._active
 
     def _holds(self, slot, req):
         """Whether `slot` is still `req`'s: a read carries the rows it
@@ -1444,8 +1497,14 @@ class DecodingPredictor(object):
         return [i for i, s in enumerate(self._slots) if s is None]
 
     def _release(self, req):
+        """The end of a request's tenancy, whatever ended it (finished,
+        cancelled, expired, shed, failed, closed): its slots are free and
+        their rows of the kept feed idle again, its blocks go back."""
         for s in req.slots:
             self._slots[s] = None
+        self._idle_rows(req)
+        self._active = None
+        self._rewrite.pop(req, None)
         # refcount-to-zero blocks return to the pool; blocks a prefix
         # entry (or another request) still references live on
         for t in req.tables:
@@ -1725,7 +1784,6 @@ class DecodingPredictor(object):
         work."""
         now = time.perf_counter()
         if req.beam is None:
-            req.last_tokens = [tok]
             req.tokens = [tok]
             req.produced = 1
             self._record_emit(req, now)
@@ -1807,6 +1865,7 @@ class DecodingPredictor(object):
                 with self.stats._lock:
                     self.stats.queue_depth -= 1
                 req.tables = [list(shared) + list(fresh)]
+                req.shared = bool(shared)
                 if self._window:
                     req.wtable = WindowTable()
                 req.next_start = int(covered)
@@ -1814,6 +1873,7 @@ class DecodingPredictor(object):
                 req.slots = free[:need]
                 for i, s in enumerate(req.slots):
                     self._slots[s] = (req, i)
+                self._active = None
             admitted += 1
         return admitted
 
@@ -1870,6 +1930,7 @@ class DecodingPredictor(object):
         if last:
             req.prefilling = False
             req.dispatched = 1
+            self._rewrite[req] = None   # the next step's feed writes it
         return read
 
     def _read_slice(self, req, read):
@@ -1891,65 +1952,157 @@ class DecodingPredictor(object):
         with _req_span('decode/first_token', req):
             self._first_token(req, tok, logits)
 
-    def _live_rows(self, skip=()):
-        """(request, beam index, write position) for every slot that
-        writes this step: decoding requests' unfinished beams. Finished
-        beams idle (trash row) — their frozen candidate needs no cache
-        writes, and skipping them avoids spurious CoW/extension.
-        Requests in `skip` (this tick's drafted set — they advance via
-        the verify dispatch instead) are excluded, and so is a request
-        whose last token (max_new) is dispatched already: it only waits
-        for its read. All from the `dispatched` count: the ids of the
-        step before need not have been read."""
+    def _blind(self, req):
+        """Whether the kept feed advances `req`'s rows without the host
+        looking at them: a greedy request's position moves one a step,
+        its token comes from the device's ids row, and its table changes
+        only where its position opens a block. The host alone can
+        advance a beam's rows (the tokens it scored, the tables it
+        permuted, the beams that finished), any row while a drafter is
+        attached (a verify tick moves a request several positions and
+        trims its table) and a request admitted on a prefix hit (the one
+        greedy request whose table holds shared blocks: the shared-block
+        walk is for those that can hold one). Told from what the
+        scheduler holds; such a request stays in _rewrite while it
+        decodes."""
+        return (req.beam is None and self._drafter is None
+                and not req.shared)
+
+    def _idle_row(self, s):
+        """Slot `s`'s row of the kept feed back in the idle row's state:
+        it writes the trash block at position 0 and reads nothing."""
+        self._feed_tokens[s, 0] = 0
+        self._feed_pos[s, 0] = 0
+        self._feed_live[s, 0] = 0
+        self._feed_tables[s] = self._trash
+        if self._window:
+            self._feed_wtables[s] = self._trash
+
+    def _idle_rows(self, req):
+        """Every row of `req` that still steps idles."""
+        for s in req.slots:
+            if self._feed_live[s, 0]:
+                self._idle_row(s)
+
+    def _live_rows(self):
+        """The (request, beam index) entry of every slot that writes the
+        next step, in slot order: decoding requests' unfinished beams.
+        Finished beams idle (trash row) — their frozen candidate needs
+        no cache writes, and skipping them avoids spurious
+        CoW/extension — and so does a request whose last token
+        (max_new) is dispatched already: it only waits for its read.
+        Kept between ticks, re-written at events: this reads the kept
+        live mask, which the events of _step_feed's list keep, and
+        returns the slot table's own tuples (what _advance tells a
+        slot's tenant by)."""
+        slots = self._slots
+        return [slots[s] for s in np.flatnonzero(self._feed_live).tolist()]
+
+    def _host_rows(self, skip):
+        """The rows of the next step that the host places itself: for
+        each request of _rewrite, token, position and live mask of its
+        rows from the request alone, as the `dispatched` count gives
+        them (the ids of the step before need not have been read). A
+        greedy row's token is -1 — the program takes the id the device
+        holds for the slot — unless the host alone knows it: a beam's,
+        and every row while a drafter is attached (a verify tick moves a
+        request past what the device row holds). Rows that do not step
+        idle: a finished beam, a request in `skip` (this tick's drafted
+        set: they advance via the verify dispatch instead), one whose
+        last token is dispatched. A request the arrays can advance from
+        here on (_blind) leaves _rewrite. Returns the stepping rows as
+        (request, beam index, position, 1); their tables are written
+        once the step's blocks are reserved (_step_feed)."""
         rows = []
-        for req in self._active_requests():
-            if req.prefilling or req in skip \
-                    or req.dispatched >= req.max_new:
-                continue
-            for bi in range(len(req.slots)):
-                if req.beam is not None and req.finished[bi]:
-                    continue
-                p = int(req.prompt.size) + req.dispatched - 1
-                rows.append((req, bi, p))
+        for req in list(self._rewrite):
+            p = int(req.prompt.size) + req.dispatched - 1
+            steps = req not in skip and req.dispatched < req.max_new
+            for bi, s in enumerate(req.slots):
+                if steps and not (req.beam is not None
+                                  and req.finished[bi]):
+                    self._feed_tokens[s, 0] = (
+                        req.last_tokens[bi] if req.beam is not None
+                        else req.tokens[-1] if self._drafter is not None
+                        else -1)
+                    self._feed_pos[s, 0] = p
+                    self._feed_live[s, 0] = 1
+                    rows.append((req, bi, p, 1))
+                elif self._feed_live[s, 0]:
+                    self._idle_row(s)
+            if self._blind(req):
+                del self._rewrite[req]
         return rows
 
-    def _preflight_blocks(self, waiting=(), rows_fn=None):
-        """Reserve this step's exact fresh-block demand (one per block
-        that must extend or copy-on-write across each row's write SPAN)
-        BEFORE building the dispatch. Pressure resolves in severity
-        order: first un-pin WAITING requests' cached prefix matches
-        (their refs can make prefix entries non-evictable; a queued
-        request simply re-matches at its next admission attempt), only
-        then shed the YOUNGEST decoding request — never kill an
-        in-flight stream for a pin a queued request can re-acquire.
-        All-or-nothing, so row building never unwinds a half-planned
-        step. `rows_fn` yields (req, bi, p, span) rows — the default is
-        this step's live rows with span 1; the speculative verify tick
-        passes its drafted rows with span draft+1 (ISSUE 17). It is a
+    def _touched_rows(self, hosted):
+        """The rows of the next step whose tables the host must look at,
+        as (request, beam index, position, 1): those of `hosted` that
+        still hold their slot, and of every other live row those whose
+        position is the first of a block (the table may need one more)
+        or, with window layers, the one whose window leaves a block
+        behind — found in the kept positions at once, not row by row.
+        Every other live row keeps the tables it has."""
+        live = self._feed_live[:, 0]
+        pos = self._feed_pos[:, 0]
+        rows, mine = [], set()
+        for r in hosted:
+            s = r[0].slots[r[1]]
+            if live[s]:
+                rows.append(r)
+                mine.add(s)
+        at = pos % self._bs == 0
+        if self._window:
+            at |= (pos - self._window + 1) % self._bs == 0
+        at &= live != 0
+        for s in np.flatnonzero(at).tolist():
+            if s not in mine:
+                req, bi = self._slots[s]
+                rows.append((req, bi, int(pos[s]), 1))
+        return rows
+
+    def _block_demand(self, rows):
+        """Fresh blocks the writes of `rows` — (request, beam index,
+        first position, span) — need: one per block that must extend or
+        copy-on-write across each row's write SPAN."""
+        need = 0
+        shared = {}
+        for req, bi, p, span in rows:
+            table = req.tables[bi]
+            for lblk in range(p // self._bs,
+                              (p + span - 1) // self._bs + 1):
+                if lblk >= len(table):
+                    need += 1        # extension: always a fresh block
+                elif not self._blocks.writable(table[lblk]):
+                    b = table[lblk]
+                    shared[b] = shared.get(b, 0) + 1
+        for b, k in shared.items():
+            # k rows CoW the same block in table order; each CoW
+            # decrefs it, so the LAST sharer writes in place when no
+            # reference beyond this step's k tables remains
+            need += k if self._blocks.refcount(b) > k else k - 1
+        return need
+
+    def _preflight_blocks(self, waiting, rows_fn):
+        """Reserve a dispatch's exact fresh-block demand (_block_demand
+        of the rows `rows_fn` yields) BEFORE building it, and return
+        those rows. Pressure resolves in severity order: first un-pin
+        WAITING requests' cached prefix matches (their refs can make
+        prefix entries non-evictable; a queued request simply re-matches
+        at its next admission attempt), only then shed the YOUNGEST
+        decoding request — never kill an in-flight stream for a pin a
+        queued request can re-acquire. All-or-nothing, so row building
+        never unwinds a half-planned step. The plain step passes the
+        rows whose tables can change this step (_touched_rows: the
+        demand is the live rows at a block boundary beyond their table,
+        plus the shared-block walk for the requests that can hold a
+        shared block — kept between ticks, so nothing walks the rows
+        that cannot need a block); the speculative verify tick passes
+        its drafted rows with span draft+1 (ISSUE 17). `rows_fn` is a
         CALLABLE because shedding a victim must drop its rows from the
         re-count."""
-        if rows_fn is None:
-            rows_fn = lambda: [(r, b, p, 1)
-                               for r, b, p in self._live_rows()]
         while True:
-            need = 0
-            shared = {}
-            for req, bi, p, span in rows_fn():
-                table = req.tables[bi]
-                for lblk in range(p // self._bs,
-                                  (p + span - 1) // self._bs + 1):
-                    if lblk >= len(table):
-                        need += 1        # extension: always a fresh block
-                    elif not self._blocks.writable(table[lblk]):
-                        b = table[lblk]
-                        shared[b] = shared.get(b, 0) + 1
-            for b, k in shared.items():
-                # k rows CoW the same block in table order; each CoW
-                # decrefs it, so the LAST sharer writes in place when no
-                # reference beyond this step's k tables remains
-                need += k if self._blocks.refcount(b) > k else k - 1
-            if self._blocks.reserve(need):
-                return
+            rows = rows_fn()
+            if self._blocks.reserve(self._block_demand(rows)):
+                return rows
             dropped = False
             for req in waiting:
                 if req.match is not None and req.match[0]:
@@ -1960,7 +2113,7 @@ class DecodingPredictor(object):
             victims = [r for r in self._active_requests()
                        if not r.prefilling]
             if not victims:
-                return
+                return rows
             victim = max(victims, key=lambda r: r.t_submit)
             self._release(victim)
             with self.stats._lock:
@@ -1995,19 +2148,23 @@ class DecodingPredictor(object):
         slots holding drafts ride ONE verify tick first, read and
         advanced at once (ISSUE 17), and the plain step covers only
         the undrafted remainder. Returns what _read_step needs — the
-        step's read, unmade, and the (request, beam index, position)
-        rows it was dispatched for — or None where a fully-drafted (or shed)
+        step's read, unmade, and the (request, beam index) rows it was
+        dispatched for — or None where a fully-drafted (or shed)
         batch needs no plain dispatch. The span's `ahead` stat: whether
         the step was dispatched with the previous tick's programs
-        unread (stats.steps_ahead)."""
+        unread (stats.steps_ahead). Behind the dispatch the kept feed
+        moves on: every live row's position by one (one add over the
+        live mask), and a row whose last token (max_new) this step
+        dispatched idles."""
         with _span('decode/step') as sp:
             with _span('decode/build_feed'):
                 drafted = self._collect_drafts()
             if drafted:
                 self._verify(drafted, waiting)
-            with _span('decode/build_feed'):
-                tokens, pos, tables, wtables, cow, rows, beam = \
-                    self._step_feed(waiting, drafted)
+            with _span('decode/build_feed') as fsp:
+                cow, rows, beam, touched = self._step_feed(waiting,
+                                                           drafted)
+                fsp.set_metadata(active=len(rows), touched=touched)
             if not rows:
                 sp.set_metadata(active=0)
                 return None
@@ -2017,12 +2174,24 @@ class DecodingPredictor(object):
                 self.stats.active_slot_steps += len(rows)
                 self.stats.slot_steps += self._S
                 self.stats.steps_ahead += ahead
+                self.stats.feed_rows_live += len(rows)
+                self.stats.feed_rows_touched += touched
             if cow:
                 self._dispatch_blockcopy(cow)
-            read = self._dispatch_step(tokens, pos, tables, logits=beam,
-                                       wtables=wtables)
+            # the program gets COPIES (64 KB a table): the kept arrays
+            # are written again for step k+1 while the runtime may still
+            # read step k's arguments — a TPU transfer that has not
+            # completed, a cpu buffer that aliases an aligned numpy array
+            read = self._dispatch_step(
+                self._feed_tokens.copy(), self._feed_pos.copy(),
+                self._feed_tables.copy(), logits=beam,
+                wtables=(self._feed_wtables.copy() if self._window
+                         else None))
+            self._feed_pos += self._feed_live
             for req in dict.fromkeys(r[0] for r in rows):
                 req.dispatched += 1
+                if req.dispatched >= req.max_new:
+                    self._idle_rows(req)
             return read, rows
 
     def _read_step(self, read, rows):
@@ -2038,63 +2207,106 @@ class DecodingPredictor(object):
                 self._advance(ids, logits, rows)
 
     def _step_feed(self, waiting, drafted):
-        """The plain step's feed over the block pool: reserve and make
-        writable every block this step writes, then fill tokens / pos /
-        tables for the live undrafted rows — the window layers' too,
-        where the artifact has such layers (None otherwise), after
-        giving back the blocks each row's window has passed. A greedy
-        row's token is -1: the program takes the id the device holds for
-        the slot (what the last step chose there, or the prompt's last
-        slice), which the host may not have read yet. The host supplies
-        the token only where it alone knows it: a beam's, and every row
-        while a drafter is attached (a verify tick moves a request past
-        what the device row holds). Returns them with the CoW pairs to
-        copy first, the (request, beam index, position) rows, and
-        whether one of them is a beam's (the step's logits are then
-        wanted)."""
-        tokens = np.zeros((self._S, 1), np.int64)
-        pos = np.zeros((self._S, 1), np.int32)
-        tables = np.full((self._S, self._maxb), self._trash, np.int32)
-        wtables = (np.full((self._S, self._maxb), self._trash, np.int32)
-                   if self._window else None)
-        self._preflight_blocks(
-            waiting,
-            rows_fn=lambda: [(r, b, p, 1) for r, b, p
-                             in self._live_rows(skip=drafted)])
+        """The plain step's feed over the block pool, KEPT BETWEEN TICKS
+        and re-written at events: tokens / pos / block_tables (and the
+        window layers' tables, where the artifact has such layers) live
+        in the predictor, and a step costs host work for the rows that
+        CHANGED since the step before, not for the rows that are live.
+        The events that re-write a row:
+
+        * it becomes a decoding row (its prompt's last slice was
+          dispatched): token -1 — the program takes the id the device
+          holds for the slot, which the host may not have read yet —
+          position, whole table, window table (_host_rows);
+        * its position opens a block: _ensure_writable extends the
+          table, or copies a shared block on write, and the row's table
+          is written again; with window layers also where its window
+          leaves a block behind (window_advance gives it back, the
+          window table is written again);
+        * every step, for the rows the arrays cannot advance blindly
+          (_blind): a beam's (tokens, permuted tables, finished beams),
+          a drafter's (host tokens, positions moved and tables trimmed
+          by a verify tick; this tick's `drafted` requests idle), a
+          prefix hit's (the shared-block walk);
+        * it ends — max_new dispatched (_step), finished, cancelled,
+          expired, shed, failed (_release): the idle row again.
+
+        Between events a row rides the arrays as they are: _step adds
+        the live mask to the positions behind each dispatch. Blocks are
+        reserved all-or-nothing (_preflight_blocks) before any table
+        changes. Returns the CoW pairs to copy first, the (request, beam
+        index) rows of the step (_live_rows), whether one of them is a
+        beam's (the step's logits are then wanted), and how many rows
+        were re-written (stats.feed_rows_touched)."""
+        hosted = self._host_rows(drafted)
+        touched = self._preflight_blocks(
+            waiting, lambda: self._touched_rows(hosted))
         cow = []
-        beam = False
-        rows = self._live_rows(skip=drafted)
         if self._window:
-            self._window_advance([(req, p, p + 1) for req, _, p in rows])
-        for req, bi, p in rows:
+            self._window_advance([(req, p, p + 1)
+                                  for req, _, p, _ in touched])
+        for req, bi, p, _ in touched:
             self._ensure_writable(req, bi, p, cow)
             s = req.slots[bi]
-            beam = beam or req.beam is not None
-            from_host = req.beam is not None or self._drafter is not None
-            tokens[s, 0] = req.last_tokens[bi] if from_host else -1
-            pos[s, 0] = p
             table = req.tables[bi]
-            tables[s, :len(table)] = table
+            row = self._feed_tables[s]
+            row[:len(table)] = table
+            row[len(table):] = self._trash
             if self._window:
-                req.wtable.fill(wtables[s])
-        return tokens, pos, tables, wtables, cow, rows, beam
+                wrow = self._feed_wtables[s]
+                wrow[:] = self._trash
+                req.wtable.fill(wrow)
+        beam = any(r[0].beam is not None for r in touched)
+        return cow, self._live_rows(), beam, len(touched)
 
     def _advance(self, ids, logits, rows):
         """After the step's read: emit the ids the program chose to the
         greedy streams of `rows` (the rows the step was dispatched for),
         score beams over the fetched logits (there iff a beam row was
-        live), finish what ended. A row whose request no longer holds
-        its slot is dropped and counted (stats.wasted_rows)."""
+        live), finish what ended. A row whose slot has changed hands
+        since the dispatch — its request finished, was cancelled,
+        expired or shed, the slot maybe let again — is dropped and
+        counted (stats.wasted_rows): a row IS the slot table's tuple,
+        so one identity test tells. Greedy rows cost one `tolist()`, one
+        hold of the stats lock in which the whole step's inter-token
+        samples come from the kept last-delivery times, then per row an
+        append, the stream's put (one C call) and the finish check —
+        nothing else."""
         now = time.perf_counter()
-        live = [req for req, bi, _ in rows
-                if self._holds(req.slots[bi], req)]
-        reqs = list(dict.fromkeys(live))    # a beam request: once
         toks = ids.tolist()     # once a step, not once a row
-        self._meter_greedy(reqs, now, len(rows) - len(live))
-        for req in reqs:
-            if req.beam is None:
-                self._advance_greedy(req, toks[req.slots[0]])
+        slots = self._slots
+        greedy, at, beams = [], [], {}
+        held = 0
+        for row in rows:
+            req, bi = row
+            s = req.slots[bi]
+            if slots[s] is not row:
                 continue
+            held += 1
+            if req.beam is None:
+                greedy.append(req)
+                at.append(s)
+            else:
+                beams[req] = None    # a beam request: once
+        stats, n = self.stats, len(greedy)
+        with stats._lock:
+            stats.wasted_rows += len(rows) - held
+            # a greedy request's first token came from its prompt's
+            # last slice (_first_token): every delivery here is a gap
+            stats.tokens += n
+            stats.adv_tokens += n
+            stats.adv_events += n
+            stats._itl.extend((now - self._t_last[at]).tolist())
+            self._t_last[at] = now
+        eos = self._eos
+        for req, s in zip(greedy, at):
+            tok = toks[s]
+            req.tokens.append(tok)
+            req.produced += 1
+            req.stream._push(tok)
+            if tok == eos or req.produced >= req.max_new:
+                self._finish_greedy(req)
+        for req in beams:
             # the history move is a table permutation on the host
             parents = self._score_beam(req, logits)
             if any(int(p) != i for i, p in enumerate(parents)):
@@ -2111,28 +2323,6 @@ class DecodingPredictor(object):
             self._record_emit(req, now, count=req.beam)
             if all(req.finished) or req.produced >= req.max_new:
                 self._finish_beam(req)
-
-    def _meter_greedy(self, reqs, now, wasted):
-        """Every greedy request of `reqs` metered for the token this
-        step is about to give it (_advance_greedy), and the step's
-        dropped rows counted, under ONE hold of the stats lock a step
-        instead of one a row."""
-        with self.stats._lock:
-            self.stats.wasted_rows += wasted
-            for req in reqs:
-                if req.beam is None:
-                    self._count_emit(req, now)
-
-    def _advance_greedy(self, req, tok):
-        """Greedy advance: emit the token the program
-        chose for the request's slot (already metered: _meter_greedy),
-        finish on eos/max_new."""
-        req.last_tokens[0] = tok
-        req.tokens.append(tok)
-        req.produced += 1
-        req.stream._push(tok)
-        if tok == self._eos or req.produced >= req.max_new:
-            self._finish_greedy(req)
 
     # -- speculative decoding (ISSUE 17) -----------------------------------
     def _collect_drafts(self):
@@ -2211,7 +2401,6 @@ class DecodingPredictor(object):
             req.draft_cooldown = 1 << min(req.draft_strikes, 6)
         else:
             req.draft_strikes = 0
-        req.last_tokens[0] = emitted[-1]
         req.tokens.extend(emitted)
         req.produced += len(emitted)
         req.dispatched = req.produced     # nothing of it is unread
@@ -2242,8 +2431,7 @@ class DecodingPredictor(object):
                     for req, d in drafted.items() if req in live]
 
         with _span('decode/build_feed'):
-            self._preflight_blocks(waiting, rows_fn=rows_fn)
-            rows = rows_fn()
+            rows = self._preflight_blocks(waiting, rows_fn)
             cow = []
             tokens = np.zeros((self._S, R), np.int64)
             pos = np.full((self._S, R), pad_pos, np.int32)
@@ -2254,7 +2442,7 @@ class DecodingPredictor(object):
                 draft = drafted[req]
                 s = req.slots[0]
                 k = len(draft)
-                tokens[s, 0] = req.last_tokens[0]
+                tokens[s, 0] = req.tokens[-1]
                 tokens[s, 1:1 + k] = draft
                 pos[s, :k + 1] = p + np.arange(k + 1, dtype=np.int32)
                 table = req.tables[0]
@@ -2324,12 +2512,13 @@ class DecodingPredictor(object):
         self.stats.adv_tokens += count
         self.stats.adv_events += (count if events is None
                                   else events)
+        s = req.slots[0]
         if req.t_first is None:
             req.t_first = now
             self.stats._ttft.append(now - req.t_submit)
         else:
-            self.stats._itl.append(now - req.t_last)
-        req.t_last = now
+            self.stats._itl.append(now - float(self._t_last[s]))
+        self._t_last[s] = now
 
     def _finish_greedy(self, req):
         with _req_span('decode/finish', req, outcome='done'):

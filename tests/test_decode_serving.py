@@ -15,6 +15,9 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu.inference import (DecodingPredictor, export_decode,
                                   ServerOverloaded, DeadlineExceeded)
+from paddle_tpu.inference.decoding import TokenStream
+
+from decode_feed_check import watch_feed
 
 VOCAB, SLOTS, CACHE, CHUNKS, BLOCK = 37, 4, 64, (4, 8), 4
 
@@ -377,12 +380,13 @@ def _serve_batch(pred, prompts, max_new, settled):
         # the next dispatch — what a live beam or a drafter asks for
         pred._results_first = lambda: True
     pred.stats.reset()
+    watch = watch_feed(pred)
     gate = _gated(pred)
     streams = [pred.submit(p, max_new_tokens=n)
                for p, n in zip(prompts, max_new)]
     gate.set()
     got = [list(s.result(120)) for s in streams]
-    return got, pred.stats.snapshot()
+    return got, pred.stats.snapshot(), watch
 
 
 def test_ahead_and_settled_orders_serve_the_same_transcripts(artifact):
@@ -414,10 +418,17 @@ def test_ahead_and_settled_orders_serve_the_same_transcripts(artifact):
     for settled in (False, True):
         with DecodingPredictor(artifact) as pred:
             pred._eos = eos
-            got, snaps[settled] = _serve_batch(pred, prompts, max_new,
-                                               settled)
+            got, snaps[settled], watch = _serve_batch(
+                pred, prompts, max_new, settled)
             assert _pool_is_empty(pred)
         assert got == want, settled
+        # the step's feed is kept between ticks: at every step it was
+        # what a rebuild from the requests gives (watch_feed), and the
+        # rows the scheduler re-wrote are exactly those with an event
+        snap = snaps[settled]
+        assert snap['feed_rows_live'] == watch.live > 0
+        assert snap['feed_rows_touched'] == watch.events
+        assert 0 < watch.events < watch.live
     ahead, settled = snaps[False], snaps[True]
     tokens = sum(len(t) for t in want)
     for snap in (ahead, settled):
@@ -481,6 +492,7 @@ def test_an_outstanding_read_strands_no_stream_and_leaks_no_block(
     with DecodingPredictor(artifact) as pred:
         want = [pred.generate(p, max_new_tokens=n)
                 for p, n in zip(prompts, max_new)]
+        watch = watch_feed(pred)
         gate = _gated(pred, before=fire)
         streams = [pred.submit(p, max_new_tokens=n, deadline_ms=3.6e6)
                    for p, n in zip(prompts, max_new)]
@@ -510,6 +522,147 @@ def test_an_outstanding_read_strands_no_stream_and_leaks_no_block(
             assert errors and all('closed' in str(e) for e in errors)
         assert pred._unread is None
         assert _pool_is_empty(pred)
+        # every feed was what a rebuild from the requests gives, and
+        # every row of the kept feed is idle again
+        assert watch.steps > 0 and not pred._feed_live.any()
+        assert not pred._feed_pos.any() and not pred._feed_tokens.any()
+        assert (pred._feed_tables == pred._trash).all()
         if what != 'close':
             pred._draining = False
             assert pred.generate(prompts[0], max_new_tokens=9) == want[0]
+
+
+# -- the step's feed kept between ticks (ISSUE 35) ----------------------------
+
+def test_kept_feed_counts_the_rows_it_rewrites(artifact):
+    """Three greedy requests over pages of 4 rows, counted by hand: a row
+    is re-written in its first step and where its position opens a page,
+    and rides the kept arrays otherwise."""
+    rng = np.random.RandomState(35)
+    prompts = [rng.randint(2, VOCAB, n) for n in (5, 8, 3)]
+    with DecodingPredictor(artifact) as pred:
+        pred._eos = -1                      # every row runs to max_new
+        watch = watch_feed(pred)
+        gate = _gated(pred)
+        streams = [pred.submit(p, max_new_tokens=9) for p in prompts]
+        gate.set()
+        assert all(len(s.result(120)) == 9 for s in streams)
+        snap = pred.stats.snapshot()
+        assert _pool_is_empty(pred)
+    # a request's first token comes from its slice, the other 8 from steps
+    assert snap['feed_rows_live'] == watch.live == 3 * 8
+    # positions written by steps: 5..12, 8..15, 3..10 — the first of each
+    # (its first step) and every multiple of 4 behind it
+    assert snap['feed_rows_touched'] == watch.events == (1 + 2) + 2 + (1 + 2)
+    assert snap['wasted_rows'] == 0
+
+
+def test_kept_feed_under_a_prefix_hit_and_a_beam(artifact):
+    """The rows the arrays cannot advance blindly take the per-row route
+    through the same arrays: a request admitted on a prefix hit (its
+    table holds shared blocks), a beam (reorders: tables permuted, blocks
+    copied on write, finished beams idle) beside greedy rows — every feed
+    equals the rebuild, transcripts are the solo ones, every block comes
+    back."""
+    rng = np.random.RandomState(36)
+    prompt = rng.randint(2, VOCAB, 9)           # 2 full pages + 1
+    other = rng.randint(2, VOCAB, 6)
+    with DecodingPredictor(artifact) as pred:
+        solo = pred.generate(prompt, max_new_tokens=10)
+        solo_other = pred.generate(other, max_new_tokens=7)
+        ids, scores = pred.generate(other, max_new_tokens=8, beam=3)
+        pred.stats.reset()
+        watch = watch_feed(pred)
+        hit = pred.submit(prompt, max_new_tokens=10)   # the pages are shared
+        assert hit.result(120) == solo
+        snap = pred.stats.snapshot()
+        assert snap['prefix_hits'] == 1
+        # the one request is re-written every step, and its shared pages
+        # are never written: nothing is copied
+        assert snap['feed_rows_touched'] == snap['feed_rows_live'] == 9
+        assert snap['cow_blocks'] == 0
+        beam = pred.submit(other, max_new_tokens=8, beam=3)
+        greedy = pred.submit(other, max_new_tokens=7)
+        got_ids, got_scores = beam.result(120)
+        assert greedy.result(120) == solo_other
+        snap = pred.stats.snapshot()
+        assert watch.steps > 9 and snap['feed_rows_live'] == watch.live
+        assert snap['feed_rows_touched'] == watch.events
+        assert snap['cow_blocks'] > 0 and snap['reorders'] > 0
+        assert _pool_is_empty(pred)
+    np.testing.assert_array_equal(got_ids, ids)
+    np.testing.assert_array_equal(got_scores, scores)
+
+
+# -- TokenStream: a delivery is one C call (queue.SimpleQueue) -----------------
+
+def test_tokenstream_batches_keep_order_and_multi_token_pushes():
+    s = TokenStream()
+    s._push(5)
+    s._push_many([6, 7, 8])
+    s._push(9)
+    s._finish([5, 6, 7, 8, 9])
+    assert list(s.batches()) == [[5], [6, 7, 8], [9]]
+    assert s.result(1) == [5, 6, 7, 8, 9] and s.done()
+    t = TokenStream()
+    t._push_many([1, 2])
+    t._finish([1, 2])
+    assert list(t) == [1, 2]
+
+
+@pytest.mark.parametrize('how', ['finish', 'fail'])
+def test_tokenstream_end_wakes_a_blocked_consumer(how):
+    import threading
+    s, seen = TokenStream(), []
+
+    def consume():
+        try:
+            seen.extend(s)
+            seen.append('end')
+        except KeyError as e:
+            seen.append(e)
+    t = threading.Thread(target=consume, daemon=True)
+    t.start()
+    s._push(3)
+    time.sleep(0.05)            # the consumer blocks in get() again
+    s._finish([3]) if how == 'finish' else s._fail(KeyError('boom'))
+    t.join(30)
+    assert not t.is_alive()
+    assert seen[0] == 3 and len(seen) == 2
+    if how == 'finish':
+        assert seen[1] == 'end' and s.result(1) == [3]
+    else:
+        assert isinstance(seen[1], KeyError)
+        assert isinstance(s.exception(1), KeyError)
+
+
+def test_tokenstream_64_consumers_lose_no_token():
+    """One producer, 64 consumer threads, 200 tokens a stream, under a
+    shortened switch interval: every consumer sees its own tokens, all of
+    them, in order."""
+    import threading
+    n_streams, n_tokens = 64, 200
+    streams = [TokenStream() for _ in range(n_streams)]
+    got = [[] for _ in streams]
+
+    def consume(i):
+        got[i].extend(streams[i])
+    threads = [threading.Thread(target=consume, args=(i,), daemon=True)
+               for i in range(n_streams)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for k in range(n_tokens):           # a step: one token a stream
+            for i, s in enumerate(streams):
+                s._push(i * n_tokens + k)
+        for i, s in enumerate(streams):
+            s._finish(None)
+        for t in threads:
+            t.join(60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads)
+    for i in range(n_streams):
+        assert got[i] == list(range(i * n_tokens, (i + 1) * n_tokens))

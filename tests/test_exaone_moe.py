@@ -23,6 +23,8 @@ from paddle_tpu.testing.decode_logits import served_logits
 from benchmark.reference import exaone_moe as ref
 from models.exaone_moe import build_decode_spec, layer_types
 
+from decode_feed_check import watch_feed
+
 TOY = dict(vocab=128, d_model=64, n_head=4, n_kv_head=2, d_head=16,
            n_layer=5, window=16, d_dense=96, n_expert=16, n_held=4,
            expert_offset=4, d_expert=32, top_k=4, max_slots=4,
@@ -371,6 +373,53 @@ def test_a_released_block_serves_the_next_request_unchanged(served):
         together = [list(s.result(120)) for s in streams]
         assert pred.stats.snapshot()['window_blocks_released'] > 20
     assert together == alone
+
+
+def test_kept_feed_on_window_layers_is_the_rebuilt_feed(served):
+    """The step's feed is kept between ticks (ISSUE 35), the window
+    layers' tables with it: nine requests over 4 slots — prompts shorter
+    and longer than the window, slots let again, rows that end by
+    max_new, one cancelled mid-decode — and at every step tokens, pos,
+    block_tables and window_tables equal what a rebuild from the requests
+    gives, a row re-written only in its first step, where its position
+    opens a page and where its window leaves one behind; both pools end
+    empty."""
+    art, _, _, _ = served
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(2, TOY['vocab'], n)
+               for n in (33, 5, 60, 18, 47, 26, 70, 9, 52)]
+    with DecodingPredictor(art) as pred:
+        alone = [list(pred.generate(p, max_new_tokens=21, timeout=120))
+                 for p in prompts]
+        pred.stats.reset()
+        watch = watch_feed(pred)
+        run_tick = pred._run_tick
+
+        def tick(waiting):      # on the scheduler's own thread
+            for req in pred._active_requests():
+                if req.prompt.size == 18 and req.produced >= 4:
+                    req.stream.cancel()
+            run_tick(waiting)
+        pred._run_tick = tick
+        streams = [pred.submit(p, max_new_tokens=21) for p in prompts]
+        together = []
+        for s in streams:
+            try:
+                together.append(list(s.result(120)))
+            except RuntimeError as e:
+                together.append(str(e))
+        snap = pred.stats.snapshot()
+        stats = pred.block_manager.stats()
+        assert not pred._feed_live.any()
+        assert (pred._feed_wtables == pred._trash).all()
+    assert together[3] == 'request cancelled'
+    assert together[:3] + together[4:] == alone[:3] + alone[4:]
+    assert snap['feed_rows_live'] == watch.live > 100
+    # pages of 8 rows, a window of 16: two events in eight positions,
+    # and each row's first step
+    assert snap['feed_rows_touched'] == watch.events
+    assert 0.25 * watch.live <= watch.events < 0.4 * watch.live
+    assert stats['window_blocks_in_use'] == 0 and stats['blocks_in_use'] == 0
 
 
 def test_signature_and_feeds_name_the_window_pool(served):

@@ -18,6 +18,8 @@ from paddle_tpu.inference import (DecodingPredictor, DraftModelDrafter,
                                   NgramDrafter, export_decode)
 from paddle_tpu.inference.kv_blocks import BlockManager
 
+from decode_feed_check import watch_feed
+
 VOCAB, SLOTS, CACHE, K = 37, 4, 64, 4
 
 
@@ -156,6 +158,12 @@ def test_mixed_draft_nodraft_and_beam_tick(arts):
                                             max_new_tokens=8, beam=3)
     with DecodingPredictor(arts['block'], draft='ngram') as ps:
         ps.stats.reset()
+        # the plain step's feed is kept between ticks (ISSUE 35): under
+        # a drafter every row is the host's to write — its token, the
+        # position a verify tick moved, the table it trimmed, this
+        # tick's drafted rows idle — through the same arrays, and each
+        # feed equals a rebuild from the requests
+        watch = watch_feed(ps)
         streams = [ps.submit(p, max_new_tokens=10) for p in prompts]
         got = [s.result(120) for s in streams]
         ids, scores = ps.generate(prompts[1], max_new_tokens=8, beam=3)
@@ -164,6 +172,8 @@ def test_mixed_draft_nodraft_and_beam_tick(arts):
     np.testing.assert_array_equal(ids, want_ids)
     np.testing.assert_array_equal(scores, want_scores)
     assert snap['drafted'] > 0
+    assert watch.steps > 0
+    assert snap['feed_rows_touched'] == snap['feed_rows_live'] == watch.live
 
 
 # -- rejection path ----------------------------------------------------------
