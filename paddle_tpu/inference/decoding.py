@@ -232,7 +232,7 @@ def _state_shardings_ns(mesh, spec_map, names):
 
 
 def _percentiles(values, qs):
-    if not values:
+    if not len(values):
         return [0.0 for _ in qs]
     arr = np.asarray(values, np.float64) * 1e3
     return [round(float(p), 3) for p in np.percentile(arr, qs)]
@@ -257,10 +257,45 @@ class DecodeStats(object):
     dispatch totals, slot occupancy, and sliding windows of TTFT and
     inter-token latency for percentile reporting. `snapshot()` is the
     profiler serving-source contract (kind='decode' rows render in
-    `profiler.serving_report()`'s decode table)."""
+    `profiler.serving_report()`'s decode table).
+
+    The tick log (`tick_log()`) holds one row for every scheduler tick
+    that `busy_s` counts, with tracing off: was the scheduler's thread
+    working or waiting in it, and for what."""
+
+    # one row a tick: `tick` its number (the 'tick' stat of its
+    # 'decode/tick' span: the row and the span are joined on it); `t0`
+    # its start on time.perf_counter(); `wall_s` what busy_s gained;
+    # `wait_s` seconds in _to_host's
+    # block_until_ready (waiting for the device, by design); `gc_s`
+    # seconds python's collector ran on ANY thread (it holds the GIL);
+    # `dispatches` steps + verify ticks + prefill slices dispatched;
+    # `rows` tokens emitted. The CPU clock of a thread is a system call
+    # (0.3 us on a plain kernel; on a sandboxed one 6 us alone, tens
+    # beside busy threads, and the clock moves in steps of 10 ms), so
+    # it is READ at the end of a tick only where CPU_EVERY_S have passed
+    # since it was last read: that row holds in `cpu_s` the CPU time the
+    # scheduler's thread used since the reading before (time.thread_time())
+    # and in `cpu_wall_s` the busy seconds that reading spans — its own
+    # wall_s and that of the rows since; the rows in between hold NaN in
+    # both. Over many rows, sum(cpu_s) / sum(cpu_wall_s) is the share of
+    # its busy time the thread was on a CPU, and 1 - that - the share in
+    # `wait_s` the share it was neither running nor waiting for the device
+    TICK_ROW = np.dtype([(k, np.float64) for k in (
+        't0', 'wall_s', 'cpu_s', 'wait_s', 'gc_s', 'dispatches', 'rows',
+        'cpu_wall_s', 'tick')])
+    TICK_RING = 1 << 16
+    CPU_EVERY_S = 0.02
 
     def __init__(self, window=8192):
         self._lock = threading.Lock()
+        self._ticks = np.zeros(self.TICK_RING, self.TICK_ROW)
+        self._n_ticks = 0        # ticks logged since reset()
+        # the scheduler's thread alone: its CPU clock where it was last
+        # read, when that was, and the busy seconds logged since
+        self._cpu_at = None
+        self._cpu_read_t = 0.0
+        self._cpu_wall = 0.0
         self._ttft = deque(maxlen=window)
         self._itl = deque(maxlen=window)
         # tagged-request failure trace (shed/expired for requests that
@@ -359,11 +394,55 @@ class DecodeStats(object):
             self.accepted = 0
             self.adv_tokens = 0
             self.adv_events = 0
+            self._n_ticks = 0
+            # the next reading of the CPU clock starts a new span of
+            # busy time: none reaches back across a reset
+            self._cpu_at = None
+            self._cpu_wall = 0.0
             if self.block_reset is not None:
                 # the BlockManager-sourced counters merge into
                 # snapshot(): a reset-then-measure window must not
                 # report pre-reset prefix hits / peaks
                 self.block_reset()
+
+    def log_tick(self, tick, t0, wall_s, wait_s, gc_s, dispatches, rows):
+        """One tick the scheduler was busy in, logged from its thread at
+        the tick's end: its wall time into busy_s and its TICK_ROW into
+        the ring, under the one hold of the lock the tick has always
+        ended with. Reads the thread's CPU clock where it is due."""
+        cpu = span = float('nan')
+        self._cpu_wall += wall_s
+        if t0 + wall_s - self._cpu_read_t >= self.CPU_EVERY_S:
+            at = time.thread_time()
+            if self._cpu_at is not None:
+                cpu, span = at - self._cpu_at, self._cpu_wall
+            self._cpu_at, self._cpu_read_t = at, t0 + wall_s
+            self._cpu_wall = 0.0
+        with self._lock:
+            self.busy_s += wall_s
+            self._ticks[self._n_ticks & (self.TICK_RING - 1)] = (
+                t0, wall_s, cpu, wait_s, gc_s, dispatches, rows, span, tick)
+            self._n_ticks += 1
+
+    def _last_rows(self, last):
+        # with the lock held: VIEWS of the last `last` logged rows in time
+        # order — two where they lie across the ring's seam
+        n, ring = self._n_ticks, self.TICK_RING
+        k = min(n, ring, last)
+        a = (n - k) & (ring - 1)
+        if a + k <= ring:
+            return [self._ticks[a:a + k]]
+        return [self._ticks[a:], self._ticks[:a + k - ring]]
+
+    def tick_log(self, since=None):
+        """A copy of the logged ticks (TICK_ROW records, at most the
+        last TICK_RING of them) in time order; `since`: those that
+        began at or after that time.perf_counter() instant. It copies
+        the ring (4.7 MB once it is full) with the stats lock held:
+        for a reader after a run, not for a poller."""
+        with self._lock:
+            log = np.concatenate(self._last_rows(self.TICK_RING))
+        return log if since is None else log[log['t0'] >= since]
 
     def record_failure(self, request_id, kind):
         """One tagged request's shed/expiry: lands in the bounded
@@ -376,6 +455,19 @@ class DecodeStats(object):
                                    'time': time.time()})
 
     def snapshot(self):
+        # the tick columns look at the last `window` ticks, as the
+        # latency percentiles do: a poller copies four columns of them
+        # under the lock (~20 us), never the ring
+        with self._lock:
+            rows = self._last_rows(self._itl.maxlen)
+            log = {k: np.concatenate([r[k] for r in rows])
+                   for k in ('wall_s', 'cpu_s', 'wait_s', 'cpu_wall_s')}
+        wall = log['wall_s']
+        tick50, tick99, tick_max = _percentiles(wall, [50, 99, 100])
+        read = float(np.nansum(log['cpu_wall_s']))
+        offcpu = (1.0 - float(np.nansum(log['cpu_s'])) / read
+                  - float(log['wait_s'].sum() / wall.sum())
+                  if read else 0.0)
         with self._lock:
             ttft50, ttft99 = _percentiles(list(self._ttft), [50, 99])
             itl50, itl99 = _percentiles(list(self._itl), [50, 99])
@@ -417,7 +509,13 @@ class DecodeStats(object):
                     'steps_ahead': int(self.steps_ahead),
                     'wasted_rows': int(self.wasted_rows),
                     'feed_rows_touched': int(self.feed_rows_touched),
-                    'feed_rows_live': int(self.feed_rows_live)}
+                    'feed_rows_live': int(self.feed_rows_live),
+                    # the tick log: a tick's wall time, and the share of
+                    # it the scheduler's thread neither ran nor waited
+                    # for the device (the GIL, a lock, the run queue)
+                    'tick_p50_ms': tick50, 'tick_p99_ms': tick99,
+                    'tick_max_ms': tick_max,
+                    'tick_offcpu_share': round(offcpu, 4)}
             if self.block_source is None:    # not wired to a pool yet
                 return snap
         # outside the stats lock: the BlockManager takes its own
@@ -717,7 +815,17 @@ class _DecodeModule(object):
         """THE one dispatch site of the decode programs (step, verify,
         chunk, blockcopy, zeros): returns once the call is enqueued."""
         fn = self._aot if self._aot is not None else self._jitted()
-        with _span('decode/dispatch', program=self.name), \
+        sized = {}
+        if _serve.tracing():
+            # what sizes the call's work on the host: the arrays it hands
+            # over that are not on the device yet — a model program's
+            # feeds (its last argument), or the call's own. Counted in
+            # front of the span: its length is the call's alone
+            feeds = args[-1] if isinstance(args[-1], list) else args
+            feeds = [a for a in feeds if isinstance(a, np.ndarray)]
+            sized = {'feeds': len(feeds),
+                     'feed_bytes': sum(a.nbytes for a in feeds)}
+        with _span('decode/dispatch', program=self.name, **sized), \
                 warnings.catch_warnings():
             # backends without donation support (XLA:CPU) warn per call;
             # the fallback is a copy, not a correctness issue
@@ -1020,12 +1128,16 @@ class DecodingPredictor(object):
         # step's read and rows or None, [(request, read)] of the slices
         # that were their prompt's last), or None
         self._unread = None
+        # seconds inside _to_host's block_until_ready, ever: the tick
+        # log's `wait_s` is its gain over a tick
+        self._wait_s = 0.0
         self._closed = False
         self._draining = False
         self._idle_evt = threading.Event()
         self._lifecycle = threading.Lock()
         self._queue = queue.Queue()
         self.stats = DecodeStats(stats_window)
+        _serve.install_gc_hook()
         # int8 paged-KV artifacts serve through the same scheduler; the
         # tier rides the stats into serving_report's tier column
         self.stats.tier = ('int8' if self._sig.get('kv_cache_dtype')
@@ -1371,7 +1483,9 @@ class DecodingPredictor(object):
         program, copied = read
         logits = len(copied) > 1
         with _span('decode/device_wait', program=program):
+            t0 = time.perf_counter()
             jax.block_until_ready(copied)
+            self._wait_s += time.perf_counter() - t0
         with _span('decode/d2h', program=program,
                    bytes=sum(int(f.nbytes) for f in copied),
                    fetch='logits' if logits else 'ids'):
@@ -1616,7 +1730,11 @@ class DecodingPredictor(object):
         nothing unread (stats.steps_ahead stays 0): the same code with
         the read taken before the dispatch. _fail_all, close and the
         loop going idle settle the outstanding read first."""
+        stats = self.stats
         t0 = time.perf_counter()
+        wait0, gc0 = self._wait_s, _serve.gc_seconds()
+        made0 = stats.steps + stats.verify_steps + stats.chunk_slices
+        rows0 = stats.tokens
         busy = self._unread is not None
         with _span('decode/expire'):
             if self._draining:
@@ -1648,8 +1766,11 @@ class DecodingPredictor(object):
         except Exception as e:
             self._fail_all(e, waiting)
         if busy:
-            with self.stats._lock:
-                self.stats.busy_s += time.perf_counter() - t0
+            stats.log_tick(
+                self._tick, t0, time.perf_counter() - t0,
+                self._wait_s - wait0, _serve.gc_seconds() - gc0,
+                stats.steps + stats.verify_steps + stats.chunk_slices
+                - made0, stats.tokens - rows0)
 
     def _results_first(self):
         """Whether the host must see what a tick dispatched before it can
@@ -1948,7 +2069,9 @@ class DecodingPredictor(object):
         if not self._window:
             # publish the prompt's FULL blocks for prefix reuse (the
             # partial tail stays private: decode writes land there)
-            self._blocks.register_prefix(req.prompt, req.tables[0])
+            with _span('decode/publish_prefix',
+                       blocks=len(req.prompt) // self._bs):
+                self._blocks.register_prefix(req.prompt, req.tables[0])
         with _req_span('decode/first_token', req):
             self._first_token(req, tok, logits)
 

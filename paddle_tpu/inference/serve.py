@@ -22,6 +22,7 @@ import itertools
 import json
 import os
 import sys
+import threading
 import time
 import warnings
 
@@ -55,6 +56,55 @@ def span(name, **stats):
         return prof.span(name, **stats)
     from jax.profiler import TraceAnnotation
     return TraceAnnotation(name, **stats)
+
+
+def tracing():
+    """Whether a jax profiler trace is running: a stat that costs more
+    than a name at hand is worked out only then."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation.is_enabled()
+
+
+# python's cyclic collector, seen from the program: a collection runs on
+# whichever thread's allocation tripped it and holds the GIL from start to
+# stop, so EVERY python thread of the process stands still for it
+_gc_lock = threading.Lock()
+_gc_s = [0.0]           # seconds collecting, ever
+_gc_open = [0.0, None]  # the running collection's start and its span
+
+
+def _gc_event(phase, info):
+    if phase == 'start':
+        sp = None
+        if tracing():
+            sp = span('py/gc', generation=info['generation'])
+            sp.__enter__()
+        _gc_open[:] = time.perf_counter(), sp
+        return
+    t0, sp = _gc_open
+    if sp is not None:
+        sp.__exit__(None, None, None)
+    if t0:
+        _gc_s[0] += time.perf_counter() - t0
+    _gc_open[:] = 0.0, None
+
+
+def install_gc_hook():
+    """Put ONE `gc.callbacks` hook into the process, however often it is
+    asked for (the first DecodingPredictor asks): it times every
+    collection for `gc_seconds()` and, while a jax profiler trace runs,
+    opens a 'py/gc' span (`generation`) from the collection's start to
+    its stop on the thread that collects. It tunes nothing."""
+    import gc
+    with _gc_lock:
+        if _gc_event not in gc.callbacks:
+            gc.callbacks.append(_gc_event)
+
+
+def gc_seconds():
+    """Seconds python's collector ran since install_gc_hook():
+    process-wide, on any thread."""
+    return _gc_s[0]
 
 
 def _np_threefry_fold(seed, step):

@@ -162,7 +162,11 @@ def serving_report():
     decode source: `acc` is the draft acceptance rate and `tok/d` the
     tokens delivered per request-advancing dispatch — both identically
     1.00 for plain (non-drafting) decode, so mixed spec/non-spec fleets
-    line up in one table."""
+    line up in one table. The tick columns come from the source's tick
+    log (`DecodeStats.tick_log`), its last 8,192 ticks: a scheduler
+    tick's p50 / p99 / longest wall time and `offcpu`, the share of the
+    ticks' time the scheduler's thread was neither on a CPU nor waiting
+    for the device."""
     out = {}
     rows = []
     decode_rows = []
@@ -202,6 +206,10 @@ def serving_report():
                 'tok/s', 'prefills', 'steps', 'occ', 'shed',
                 'acc', 'tok/d',
                 'ttftp50(ms)', 'ttftp99(ms)', 'itlp50(ms)', 'itlp99(ms)'))
+        # the scheduler's tick over its tick log: wall time, and the
+        # share of it its thread neither ran nor waited for the device
+        hdr += " %11s %11s %11s %6s" % ('tickp50(ms)', 'tickp99(ms)',
+                                        'tickmax(ms)', 'offcpu')
         if blocks:
             hdr += " %11s %6s %6s %6s" % ('blocks', 'pfxhit', 'cow',
                                           'slices')
@@ -219,6 +227,9 @@ def serving_report():
                     s.get('tokens_per_dispatch', 1.0),
                     s.get('ttft_p50_ms', 0.0), s.get('ttft_p99_ms', 0.0),
                     s.get('itl_p50_ms', 0.0), s.get('itl_p99_ms', 0.0)))
+            row += " %11.2f %11.2f %11.2f %6.2f" % (
+                s.get('tick_p50_ms', 0.0), s.get('tick_p99_ms', 0.0),
+                s.get('tick_max_ms', 0.0), s.get('tick_offcpu_share', 0.0))
             if blocks:
                 if 'blocks_in_use' in s:
                     row += " %11s %6.2f %6d %6d" % (
@@ -655,6 +666,13 @@ class span(_TraceAnnotation):
     plain ints/strs already at hand; a stat known only once the work is
     done is added inside the block with `.set_metadata(k=v)`. With no
     trace running it costs about a microsecond and records nothing.
+    While a trace runs every span also carries `cpu_us`, the CPU time
+    its thread used inside it (CLOCK_THREAD_CPUTIME_ID): its wall time
+    minus that is the time the thread was NOT running — waiting for the
+    GIL, a lock, the run queue or a blocking runtime call. A holder's
+    `cpu_us` includes its children's; a reader subtracts them. The clock
+    is the kernel's: where it moves in steps (10 ms on some machines) one
+    span's `cpu_us` is a sample, and sums over many spans are the reading.
     While `is_profiling()` it also keeps (name, start, dur, tid) for the
     host-event report and `export_chrome_tracing`."""
 
@@ -662,13 +680,19 @@ class span(_TraceAnnotation):
         super().__init__(name, **stats)
         self._name = name
         self._t0 = None
+        self._cpu0 = None
 
     def __enter__(self):
         if _active:
             self._t0 = time.perf_counter()
+        if _TraceAnnotation.is_enabled():
+            self._cpu0 = time.thread_time_ns()
         return super().__enter__()
 
     def __exit__(self, *exc):
+        if self._cpu0 is not None:
+            self.set_metadata(
+                cpu_us=(time.thread_time_ns() - self._cpu0) / 1e3)
         super().__exit__(*exc)
         if self._t0 is not None:
             _events.append((self._name, self._t0 - _EPOCH,

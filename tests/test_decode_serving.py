@@ -666,3 +666,196 @@ def test_tokenstream_64_consumers_lose_no_token():
     assert not any(t.is_alive() for t in threads)
     for i in range(n_streams):
         assert got[i] == list(range(i * n_tokens, (i + 1) * n_tokens))
+
+
+# -- the tick log: every busy tick, with tracing off --------------------------
+
+@pytest.fixture(scope='module')
+def ticked(artifact):
+    """Six requests served with nothing tracing; what the scheduler's
+    ticks added to busy_s, one entry a tick that added anything."""
+    from paddle_tpu.inference.decoding import DecodeStats
+    added = []
+    with DecodingPredictor(artifact) as pred:
+        pred.generate(_prompts(31, 1)[0], max_new_tokens=2)    # warm
+        # the warm-up's last token is out before its tick has ended: a
+        # reset() inside that tick would be followed by the tick's row
+        seen = None
+        while seen != pred.stats.busy_s:
+            seen = pred.stats.busy_s
+            time.sleep(0.03)
+        pred.stats.reset()
+        # a reset forgets the CPU clock's last reading: the next one is a
+        # baseline and the one after it the first logged — a run of some
+        # tens of milliseconds wants them closer together than 20 ms
+        pred.stats.CPU_EVERY_S = 0.004
+        emptied = len(pred.stats.tick_log())
+        run_tick = pred._run_tick
+
+        def counted(waiting):
+            before = pred.stats.busy_s
+            run_tick(waiting)
+            if pred.stats.busy_s != before:
+                added.append(pred.stats.busy_s - before)
+        pred._run_tick = counted
+        t_first = time.perf_counter()
+        streams = [pred.submit(p, max_new_tokens=6)
+                   for p in _prompts(32, 6)]
+        tokens = [s.result(120) for s in streams]
+        pred.close()
+        log, snap = pred.stats.tick_log(), pred.stats.snapshot()
+        assert isinstance(pred.stats, DecodeStats)
+    return {'log': log, 'snap': snap, 'added': added, 'tokens': tokens,
+            'busy_s': pred.stats.busy_s, 'emptied': emptied,
+            't_first': t_first, 'stats': pred.stats}
+
+
+def test_tick_log_has_one_row_a_busy_tick(ticked):
+    log = ticked['log']
+    assert ticked['emptied'] == 0           # reset() emptied the warm-up's
+    assert len(log) == len(ticked['added']) > 3
+    assert list(log.dtype.names) == ['t0', 'wall_s', 'cpu_s', 'wait_s',
+                                     'gc_s', 'dispatches', 'rows',
+                                     'cpu_wall_s', 'tick']
+    # a row carries its tick's number, the 'tick' stat of its span: an
+    # idle tick has a span and no row, so the numbers may skip
+    assert np.all(np.diff(log['tick']) >= 1)
+    assert np.all(np.diff(log['t0']) > 0) and log['t0'][0] >= ticked['t_first']
+    np.testing.assert_allclose(log['wall_s'], ticked['added'], rtol=0,
+                               atol=1e-9)
+    assert log['wall_s'].sum() == pytest.approx(ticked['busy_s'], rel=1e-9)
+
+
+def test_tick_log_rows_add_up(ticked):
+    """Waiting for the device is a part of the tick; every dispatch and
+    every token the steps emitted is in some row. The thread's CPU clock
+    is read where CPU_EVERY_S have passed since its last reading: such a
+    row holds the CPU time since then and the busy seconds that spans —
+    its own and those of the rows since — and the others hold neither."""
+    log, snap = ticked['log'], ticked['snap']
+    assert np.all(log['wait_s'] >= 0) and np.all(log['gc_s'] >= 0)
+    assert np.all(log['wait_s'] <= log['wall_s'] + 1e-6)
+    assert log['dispatches'].sum() == snap['steps'] + snap['chunk_slices']
+    assert log['rows'].sum() == snap['tokens'] \
+        == sum(len(t) for t in ticked['tokens'])
+    read = ~np.isnan(log['cpu_s'])
+    assert np.array_equal(read, ~np.isnan(log['cpu_wall_s']))
+    assert 1 <= read.sum() <= len(log)
+    # between two readings at least CPU_EVERY_S passed
+    ends = (log['t0'] + log['wall_s'])[read]
+    assert np.all(np.diff(ends) >= ticked['stats'].CPU_EVERY_S)
+    # a reading spans its own tick and the unread ticks before it
+    spans = np.split(log['wall_s'], np.flatnonzero(read) + 1)[:read.sum()]
+    np.testing.assert_allclose(log['cpu_wall_s'][read][1:],
+                               [s.sum() for s in spans][1:], atol=1e-9)
+    assert np.all(log['cpu_wall_s'][read] >= log['wall_s'][read] - 1e-9)
+    # on a CPU for no longer than the time between the two readings (the
+    # loop between two ticks is CPU time and not busy time, so a reading
+    # of this toy's 0.2 ms ticks may pass the busy seconds it spans)
+    assert np.all(log['cpu_s'][read] >= 0)
+    assert np.all(log['cpu_s'][read][1:] <= np.diff(ends) + 1e-4)
+
+
+def test_the_cpu_clock_is_read_every_tick_where_it_is_always_due(artifact):
+    """CPU_EVERY_S = 0 on the instance: every row carries a reading that
+    spans that tick alone, and working + waiting for the device fill no
+    more than the tick."""
+    with DecodingPredictor(artifact) as pred:
+        pred.stats.CPU_EVERY_S = 0
+        pred.generate(_prompts(33, 1)[0], max_new_tokens=8)
+        log = pred.stats.tick_log()
+    assert len(log) > 3 and not np.isnan(log['cpu_s'][1:]).any()
+    np.testing.assert_allclose(log['cpu_wall_s'][1:], log['wall_s'][1:],
+                               atol=1e-9)
+    assert np.all(log['cpu_s'][1:] <= log['wall_s'][1:] + 1e-3)
+
+
+def test_snapshot_reads_the_tick_log(ticked):
+    log, snap = ticked['log'], ticked['snap']
+    assert snap['tick_max_ms'] == pytest.approx(log['wall_s'].max() * 1e3,
+                                                abs=1e-3)
+    assert 0 < snap['tick_p50_ms'] <= snap['tick_p99_ms'] \
+        <= snap['tick_max_ms']
+    share = 1 - np.nansum(log['cpu_s']) / np.nansum(log['cpu_wall_s']) \
+        - log['wait_s'].sum() / log['wall_s'].sum()
+    assert snap['tick_offcpu_share'] == pytest.approx(share, abs=1e-4)
+    assert snap['tick_offcpu_share'] < 1
+
+
+def test_tick_log_since_and_copy(ticked):
+    stats, log = ticked['stats'], ticked['log']
+    mid = log['t0'][len(log) // 2]
+    assert len(stats.tick_log(since=mid)) == len(log) - len(log) // 2
+    stats.tick_log()['wall_s'][:] = 0       # a copy: the ring is untouched
+    assert stats.tick_log()['wall_s'].sum() == pytest.approx(
+        log['wall_s'].sum())
+
+
+def test_tick_ring_wraps_without_growing():
+    from paddle_tpu.inference.decoding import DecodeStats
+    stats = DecodeStats()
+    ring = stats.TICK_RING
+    held = stats._ticks
+    for k in range(ring + 1000):
+        stats.log_tick(k, float(k), 1e-3, 1e-4, 0.0, 2, 7)
+    log = stats.tick_log()
+    assert stats._ticks is held and len(held) == ring == len(log)
+    assert log['t0'][0] == 1000.0 and log['t0'][-1] == ring + 999.0
+    assert np.all(np.diff(log['t0']) == 1)
+    assert stats.busy_s == pytest.approx((ring + 1000) * 1e-3)
+    assert log['tick'][-1] == ring + 999
+    assert len(stats.tick_log(since=ring)) == 1000
+    stats.reset()
+    assert len(stats.tick_log()) == 0 and stats.snapshot()['tick_max_ms'] == 0
+
+
+def test_snapshot_looks_at_the_last_window_of_ticks():
+    """A poller's snapshot() copies the last `window` rows, across the
+    ring's seam too, and never the ring."""
+    from paddle_tpu.inference.decoding import DecodeStats
+    stats = DecodeStats(window=64)
+    for k in range(stats.TICK_RING - 43):
+        stats.log_tick(k, float(k), (1 + k % 7) * 1e-3, 0.0, 0.0, 1, 1)
+    stats.log_tick(0, 0.0, 0.5, 0.0, 0.0, 1, 1)
+    for k in range(62):
+        stats.log_tick(k, float(k), 2e-3, 0.0, 0.0, 1, 1)
+    with stats._lock:
+        last = np.concatenate(stats._last_rows(64))
+    assert len(last) == 64 and last['wall_s'][1] == 0.5
+    assert len(stats.tick_log()) == stats.TICK_RING     # 20 past the seam
+    for column in ('t0', 'wall_s', 'tick'):
+        assert np.array_equal(last[column], stats.tick_log()[column][-64:])
+    snap = stats.snapshot()
+    assert snap['tick_max_ms'] == 500.0 and snap['tick_p50_ms'] == 2.0
+    stats.log_tick(0, 0.0, 2e-3, 0.0, 0.0, 1, 1)
+    stats.log_tick(0, 0.0, 2e-3, 0.0, 0.0, 1, 1)
+    assert stats.snapshot()['tick_max_ms'] == 2.0   # the stop left the window
+
+
+def test_reset_starts_the_cpu_clocks_readings_anew():
+    """No reading of the thread's CPU clock reaches back across a
+    reset(): the first one after it spans nothing and is not logged."""
+    from paddle_tpu.inference.decoding import DecodeStats
+    stats = DecodeStats()
+    stats.CPU_EVERY_S = 0
+    stats.log_tick(1, 1.0, 1e-3, 0.0, 0.0, 1, 1)
+    stats.log_tick(2, 2.0, 1e-3, 0.0, 0.0, 1, 1)
+    assert not np.isnan(stats.tick_log()['cpu_s'][1])
+    stats.reset()
+    stats.log_tick(3, 3.0, 1e-3, 0.0, 0.0, 1, 1)
+    stats.log_tick(4, 4.0, 1e-3, 0.0, 0.0, 1, 1)
+    log = stats.tick_log()
+    assert np.isnan(log['cpu_s'][0]) and log['cpu_wall_s'][1] == 1e-3
+
+
+def test_the_collector_hook_is_installed_once(artifact):
+    """However many predictors a process builds, gc.callbacks holds ONE
+    hook; the seconds it counts only grow."""
+    import gc
+    from paddle_tpu.inference import serve
+    before = serve.gc_seconds()
+    with DecodingPredictor(artifact), DecodingPredictor(artifact):
+        assert gc.callbacks.count(serve._gc_event) == 1
+        gc.collect()
+    assert gc.callbacks.count(serve._gc_event) == 1
+    assert serve.gc_seconds() > before

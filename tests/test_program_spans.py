@@ -6,6 +6,7 @@ op types in op_name metadata, stable names on the jitted programs).
 
 On the cpu backend, read back with jax.profiler.ProfileData — the same way
 benchmark/layer_metrics/_spans.py reads a chip trace."""
+import gc
 import glob
 import os
 import statistics
@@ -23,7 +24,9 @@ _TOL_NS = 1000      # an event's end is start + duration, both rounded
 
 
 class _Trace(object):
-    """Host spans of one .xplane.pb: (name, start, end, thread, stats)."""
+    """Host spans of one .xplane.pb: (name, start, end, thread, stats).
+    Every python thread's line has the process's name: a thread is its
+    line's name and number."""
 
     def __init__(self, trace_dir):
         from jax.profiler import ProfileData
@@ -34,17 +37,27 @@ class _Trace(object):
         for plane in ProfileData.from_file(path).planes:
             if not plane.name.startswith('/host:CPU'):
                 continue
-            for line in plane.lines:
+            for k, line in enumerate(plane.lines):
                 for e in line.events:
                     if '/' in e.name and e.name.split('/')[0] in (
-                            'decode', 'exe', 'load', 'compile', 'pass'):
+                            'decode', 'exe', 'load', 'compile', 'pass',
+                            'py'):
                         self.spans.append(
                             (e.name, e.start_ns, e.start_ns + e.duration_ns,
-                             line.name, dict(e.stats)))
+                             '%s#%d' % (line.name, k), dict(e.stats)))
         self.spans.sort(key=lambda s: s[1])
 
     def named(self, name):
         return [s for s in self.spans if s[0] == name]
+
+    def cpu_slack_us(self):
+        """How far a span's cpu_us may pass its wall time: the two clocks
+        differ by a few microseconds, and a thread's CPU clock may move in
+        steps (10 ms under a sandboxed kernel) — the smallest movement any
+        span of the trace saw is at least one step."""
+        moved = [s[4]['cpu_us'] for s in self.spans
+                 if s[4].get('cpu_us', 0) > 0]
+        return max(20.0, min(moved, default=0.0))
 
     def parent_of(self, span, names):
         """The innermost span named one of `names` that holds `span` on
@@ -106,6 +119,14 @@ def decode_trace(decode_art, tmp_path_factory):
 
     def serve():
         with DecodingPredictor(decode_art) as pred:
+            # one collection on the scheduler's thread, inside a tick
+            admit, once = pred._admit, [gc.collect]
+
+            def admit_after_a_collection(waiting):
+                while once:
+                    once.pop()()
+                return admit(waiting)
+            pred._admit = admit_after_a_collection
             streams = [pred.submit(p, max_new_tokens=4,
                                    request_id='gw-7' if i == 1 else None)
                        for i, p in enumerate(prompts)]
@@ -121,6 +142,7 @@ _DECODE_SPANS = ('decode/submit', 'decode/tick', 'decode/expire',
                  'decode/prefill_slice', 'decode/step', 'decode/build_feed',
                  'decode/dispatch', 'decode/device_wait', 'decode/d2h',
                  'decode/advance', 'decode/first_token', 'decode/finish',
+                 'decode/publish_prefix', 'py/gc',
                  'load/read', 'load/weights', 'load/reset_state')
 
 
@@ -147,6 +169,8 @@ def test_decode_span_is_in_the_trace(decode_trace, name):
     ('decode/d2h', ('decode/step', 'decode/tick')),
     ('decode/first_token', ('decode/tick',)),
     ('decode/finish', ('decode/advance', 'decode/first_token')),
+    # a prompt's full blocks are published where its last slice is read
+    ('decode/publish_prefix', ('decode/tick',)),
 ])
 def test_decode_children_lie_inside_their_parents(decode_trace, child,
                                                   parents):
@@ -279,6 +303,70 @@ def test_the_dispatch_half_of_a_step_says_whether_it_ran_ahead(decode_trace):
             < decode_trace.parent_of(r, ('decode/tick',))[4]['tick']
 
 
+def test_the_collector_is_a_span_on_the_thread_that_collects(decode_trace):
+    """The one forced collection ran in the scheduler's _admit: its py/gc
+    span (generation 2) lies inside that tick's decode/admit, on the
+    scheduler's thread."""
+    full = [s for s in decode_trace.named('py/gc')
+            if s[4]['generation'] == 2]
+    assert full
+    held = [decode_trace.parent_of(s, ('decode/admit',)) for s in full]
+    held = [h for h in held if h is not None]
+    assert len(held) == 1
+    assert decode_trace.parent_of(held[0], ('decode/tick',)) is not None
+
+
+def test_publish_prefix_says_how_many_blocks(decode_trace):
+    """One span a prompt, between the read of its last slice and its first
+    token: the prompt's full blocks (pages of 4 rows; prompts of 3, 11
+    and 6 tokens)."""
+    spans = decode_trace.named('decode/publish_prefix')
+    assert sorted(s[4]['blocks'] for s in spans) == [0, 1, 2]
+    for s in spans:
+        assert decode_trace.parent_of(
+            s, ('decode/first_token', 'decode/step')) is None
+
+
+def test_a_dispatch_says_what_it_handed_over(decode_trace):
+    """decode/dispatch carries the host arrays the call was given: a step
+    its tokens, positions and table rows, a slice five feeds, the state's
+    birth one."""
+    by_program = {}
+    for s in decode_trace.named('decode/dispatch'):
+        by_program.setdefault(s[4]['program'], set()).add(
+            (s[4]['feeds'], s[4]['feed_bytes']))
+    (step,) = by_program['step']
+    assert step[0] == 3 and step[1] > SLOTS * (CACHE // 4) * 4
+    assert by_program['zeros'] == {(1, 4)}
+    assert {n for n, _ in by_program['chunk_8']} == {5}
+
+
+@pytest.mark.parametrize('name', ['decode/tick', 'decode/dispatch',
+                                  'decode/device_wait', 'decode/advance',
+                                  'decode/submit', 'load/weights', 'py/gc'])
+def test_every_span_carries_its_threads_cpu_time(decode_trace, name):
+    """cpu_us: the CPU time the span's thread used inside it, never more
+    than the span lasted (two clocks: a few microseconds of slack, or one
+    step of a CPU clock that moves in steps), and a holder's is at least
+    its children's."""
+    spans = decode_trace.named(name)
+    assert spans
+    slack = decode_trace.cpu_slack_us()
+    for s in spans:
+        assert 0 <= s[4]['cpu_us'] <= (s[2] - s[1]) / 1e3 + slack, s
+    if name == 'decode/tick':
+        for tick in spans:
+            inside = sum(s[4]['cpu_us'] for s in decode_trace.spans
+                         if s is not tick and s[3] == tick[3]
+                         and decode_trace.parent_of(s, _HOLDERS) is tick)
+            assert inside <= tick[4]['cpu_us'] + slack
+
+
+_HOLDERS = ('decode/tick', 'decode/step', 'decode/prefill_slice',
+            'decode/admit', 'decode/advance', 'decode/first_token',
+            'decode/build_feed', 'decode/admit_request')
+
+
 @pytest.mark.parametrize('sub,name', [
     ('decode_step', 'decode_step'), ('prefill_chunk_00004', 'prefill_chunk_4'),
     ('prefill_chunk_00008', 'prefill_chunk_8'),
@@ -340,6 +428,46 @@ def test_inactive_spans_record_nothing_and_cost_microseconds():
             pass
         costs.append(time.perf_counter() - t0)
     assert statistics.median(costs) < 25e-6
+
+
+# -- (c') working or waiting: cpu_us while a trace runs -----------------------
+
+_SPUN_S = 0.1       # the CPU time the spinning span uses, by its own clock
+
+
+@pytest.fixture(scope='module')
+def sleep_and_spin(tmp_path_factory):
+    """One span that sleeps 0.1 s, one that spins until its thread has
+    used 0.1 s of CPU — however long a loaded machine takes to give it
+    that much."""
+    def body():
+        with profiler.span('pass/sleeps'):
+            time.sleep(0.1)
+        with profiler.span('pass/spins'):
+            t0 = time.thread_time()
+            while time.thread_time() - t0 < _SPUN_S:
+                pass
+    return _traced(tmp_path_factory.mktemp('cpu_us'), body)[0]
+
+
+@pytest.mark.parametrize('name,cpu_s', [
+    ('pass/sleeps', (0.0, 0.02)),           # waiting: hardly any CPU time
+    ('pass/spins', (_SPUN_S, 1.1 * _SPUN_S)),   # working: what it spun for
+])
+def test_cpu_us_tells_working_from_waiting(sleep_and_spin, name, cpu_s):
+    (span,) = sleep_and_spin.named(name)
+    wall_us = (span[2] - span[1]) / 1e3
+    assert wall_us >= 100e3
+    slack = sleep_and_spin.cpu_slack_us()
+    assert cpu_s[0] * 1e6 - slack <= span[4]['cpu_us'] \
+        <= min(cpu_s[1] * 1e6, wall_us) + slack, span
+
+
+def test_a_span_outside_a_trace_takes_no_cpu_clock():
+    """No trace running: the span reads no clock and adds no stat."""
+    with profiler.span('pass/none') as sp:
+        pass
+    assert sp._cpu0 is None
 
 
 # -- (d) the device side: op types in op_name, names on the programs ---------
