@@ -16,10 +16,66 @@ passed. A model with full layers only has neither the pool nor the feed.
 """
 from __future__ import annotations
 
+import re
+
 import numpy as np
 
 import paddle_tpu as fluid
 from paddle_tpu.inference.kv_blocks import window_blocks_per_slot
+from paddle_tpu.ops.decode_ops import chunk_row_program
+
+
+def chunk_row_shape(chunk_progs, view_len):
+    """(C, R) of the ONE row program a spec with these chunked-prefill
+    programs ({size: entry}) holds beside them — its largest chunk built
+    once more at [R, C], slices of R different prompts in one dispatch —
+    or None: ops/decode_ops.chunk_row_program's rule, given what the
+    largest chunk program's attention ops ARE (type, heads, window) and
+    the `view_len` positions a slot's table spans. Every decode spec
+    builder asks here; nothing is asked of a caller."""
+    ops = chunk_progs[max(chunk_progs)]['program'].global_block().ops
+    return chunk_row_program(
+        list(chunk_progs),
+        [(op.type, op.attr('n_head'), op.attr('n_kv_head'),
+          op.attr('window')) for op in ops
+         if re.fullmatch(r'kv_\w*attention\w*', op.type)],
+        view_len)
+
+
+def chunk_positions(start, C, R):
+    """The positions `start[r] + i` of a chunk program's rows: [R, C],
+    and at R = 1 the [C] expression every one-row program has held (its
+    StableHLO is pinned, tests/test_decode_ids.py)."""
+    L = fluid.layers
+    cidx = L.range(0, C, 1, 'int32')
+    if R == 1:
+        return L.elementwise_add(cidx, L.reshape(start, shape=[1]))
+    return L.elementwise_add(start, cidx)
+
+
+def last_logits(x, clen, C, R, D, logits_fn):
+    """[R, V] logits of a chunk program's rows: `logits_fn` of `x`
+    [R, C, D] at each row's LAST VALID position, `clen[r] - 1` (the
+    scheduler reads them only from a prompt's final chunk; a pad row's,
+    chunk_len 0, are the position before its first: unread).
+
+    EACH ROW THROUGH THE ONE-ROW EXPRESSION, from the slice of `x` on: a
+    request's first token is the argmax of these and must not tell who
+    admitted beside it. On a TPU an [R, D] x [D, V] product is compiled
+    to another algorithm than a [1, D] one (PERF.md section 6, PR 39:
+    2e-3 apart in logits of 0.7, while every K/V row the [R * C, D]
+    products wrote was equal to the bit)."""
+    L = fluid.layers
+
+    def row(x_r, clen_r):       # [1, C, D], [1, 1]: the one-row program's
+        return logits_fn(L.gather(
+            L.reshape(x_r, shape=[C, D]),
+            L.elementwise_sub(clen_r, L.fill_constant([1], 'int32', 1))))
+    if R == 1:
+        return row(x, clen)
+    return L.concat([row(L.slice(x, axes=[0], starts=[r], ends=[r + 1]),
+                         L.slice(clen, axes=[0], starts=[r], ends=[r + 1]))
+                     for r in range(R)], axis=0)
 
 
 class DecodeSpecBuilder(object):
@@ -150,51 +206,50 @@ class DecodeSpecBuilder(object):
         step_feeds = (['tokens', 'pos', 'block_tables']
                       + ['window_tables'] * windowed)
 
-        # ---- chunked prefill: one CHUNK of one prompt ------------------
-        chunk_progs = {}
-        for C in self.chunks:
+        # ---- chunked prefill: one CHUNK of one prompt a row; every
+        # chunk size at ONE row and, where the shapes give one
+        # (chunk_row_shape), the largest once more at R rows
+        def chunk_program(C, R=1):
             cp = fluid.Program()
             with fluid.program_guard(cp, self.startup):
-                chunk_ids = L.data(name='chunk_ids', shape=[1, C],
+                chunk_ids = L.data(name='chunk_ids', shape=[R, C],
                                    append_batch_size=False, dtype='int64')
-                start = L.data(name='start', shape=[1, 1],
+                start = L.data(name='start', shape=[R, 1],
                                append_batch_size=False, dtype='int32')
-                clen = L.data(name='chunk_len', shape=[1, 1],
+                clen = L.data(name='chunk_len', shape=[R, 1],
                               append_batch_size=False, dtype='int32')
-                btabs = [table_feed('block_table', 1)]
+                btabs = [table_feed('block_table', R)]
                 if windowed:
-                    btabs.append(table_feed('window_table', 1))
+                    btabs.append(table_feed('window_table', R))
                 self._io = {
                     'write': lambda c, kv, kind: L.kv_block_chunk_write(
                         c, kv, start, btabs[kind]),
                     'attend': lambda q, kc, vc, kind, **kw:
                         L.kv_block_chunk_attention(
                             q, kc, vc, start, btabs[kind], **kw)}
-                x = self.embed(chunk_ids)                       # [1, C, D]
-                posv = L.elementwise_add(
-                    L.range(0, C, 1, 'int32'),
-                    L.reshape(start, shape=[1]))                 # [C]
+                x = self.embed(chunk_ids)                       # [R, C, D]
+                posv = chunk_positions(start, C, R)          # [C] / [R, C]
                 for i in range(self.n_layer):
                     x = block(self, x, i, 2, posv)
-                # logits at the chunk's LAST VALID row (the scheduler
-                # reads them only from a prompt's final chunk)
-                last = L.gather(
-                    L.reshape(x, shape=[C, D]),
-                    L.elementwise_sub(
-                        clen, L.fill_constant([1], 'int32', 1)))
-                chunk_logits = logits(self, last)                # [1, V]
-            samples = {'chunk_ids': np.zeros((1, C), np.int64),
-                       'start': np.zeros((1, 1), np.int32),
-                       'chunk_len': np.ones((1, 1), np.int32),
-                       'block_table': np.zeros((1, MAXB), np.int32)}
+                chunk_logits = last_logits(
+                    x, clen, C, R, D, lambda row: logits(self, row))
+            samples = {'chunk_ids': np.zeros((R, C), np.int64),
+                       'start': np.zeros((R, 1), np.int32),
+                       'chunk_len': np.ones((R, 1), np.int32),
+                       'block_table': np.zeros((R, MAXB), np.int32)}
             if windowed:
-                samples['window_table'] = np.zeros((1, MAXB), np.int32)
-            chunk_progs[C] = {
+                samples['window_table'] = np.zeros((R, MAXB), np.int32)
+            return {
                 'program': cp,
                 'feeds': (['chunk_ids', 'start', 'chunk_len', 'block_table']
                           + ['window_table'] * windowed),
                 'samples': samples,
                 'fetches': [chunk_logits.name]}
+
+        chunk_progs = {C: chunk_program(C) for C in self.chunks}
+        rows = chunk_row_shape(chunk_progs, MAXB * self.BS)
+        chunk_rows = (dict(chunk_program(*rows), size=rows[0], rows=rows[1])
+                      if rows is not None else None)
         self._io = None
 
         samples = {'tokens': np.zeros((S, 1), np.int64),
@@ -214,6 +269,8 @@ class DecodeSpecBuilder(object):
                 'max_slots': S, 'max_cache_len': self.T,
                 'eos_id': self.eos_id, 'vocab': self.vocab,
                 'kv_cache_dtype': self.kv_cache_dtype}
+        if chunk_rows is not None:
+            spec['chunk_rows'] = chunk_rows
         if windowed:
             spec['window'] = {
                 'length': self.window, 'num_blocks': self.NBW,

@@ -231,6 +231,8 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
       {'startup': Program,           # run ONCE to init shared params
        'step':    {'program', 'feeds', 'samples', 'fetches'},
        'chunk':   {chunk_size: {'program', 'feeds', 'samples', 'fetches'}},
+       'chunk_rows': {..., 'size': C, 'rows': R},   # where the shapes
+                                     # give one (decode_spec.chunk_row_shape)
        'cache_vars': [names],        # the KV pool [NB, block_size, d_model]
        'block_size', 'num_blocks', 'max_blocks_per_slot',
        'max_slots', 'max_cache_len', 'eos_id', 'vocab'}
@@ -279,6 +281,8 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
     """
     import numpy as np
     from paddle_tpu.parallel import shard_parameter
+    from .decode_spec import (chunk_positions, chunk_row_shape,
+                              last_logits)
     PA = fluid.ParamAttr
     if kv_cache_dtype not in ('float32', 'bfloat16', 'int8'):
         raise ValueError("kv_cache_dtype must be 'float32', 'bfloat16' "
@@ -466,31 +470,31 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
             x = block_tail(x, a, i, 1)
         step_logits = out_logits(x)                             # [S, V]
 
-    # ---- chunked-prefill programs: one CHUNK of one prompt ---------------
-    chunk_progs = {}
-    for C in chunks:
+    # ---- chunked-prefill programs: one CHUNK of one prompt a row; every
+    # chunk size at ONE row, and where the shapes allow it (chunk_rows,
+    # below) the largest once more at R rows: slices of R different
+    # prompts in one dispatch ------------------------------------------
+    def chunk_program(C, R=1):
         cp = fluid.Program()
         with fluid.program_guard(cp, startup):
-            chunk_ids = fluid.layers.data(name='chunk_ids', shape=[1, C],
+            chunk_ids = fluid.layers.data(name='chunk_ids', shape=[R, C],
                                           append_batch_size=False,
                                           dtype='int64')
-            start = fluid.layers.data(name='start', shape=[1, 1],
+            start = fluid.layers.data(name='start', shape=[R, 1],
                                       append_batch_size=False,
                                       dtype='int32')
-            clen = fluid.layers.data(name='chunk_len', shape=[1, 1],
+            clen = fluid.layers.data(name='chunk_len', shape=[R, 1],
                                      append_batch_size=False,
                                      dtype='int32')
-            btab = fluid.layers.data(name='block_table', shape=[1, MAXB],
+            btab = fluid.layers.data(name='block_table', shape=[R, MAXB],
                                      append_batch_size=False,
                                      dtype='int32')
             table = pe_param()
-            x = embed(chunk_ids)                               # [1, C, D]
-            cidx = fluid.layers.range(0, C, 1, 'int32')        # [C]
-            posv = fluid.layers.elementwise_add(
-                cidx, fluid.layers.reshape(start, shape=[1]))
-            pe_c = fluid.layers.gather(table, posv)            # [C, D]
+            x = embed(chunk_ids)                               # [R, C, D]
+            posv = chunk_positions(start, C, R)              # [C] / [R, C]
+            pe_c = fluid.layers.gather(table, posv)            # [R*C, D]
             x = fluid.layers.elementwise_add(
-                x, fluid.layers.reshape(pe_c, shape=[1, C, D]))
+                x, fluid.layers.reshape(pe_c, shape=[R, C, D]))
             x = _hint(x)
             for i in range(n_layer):
                 if kv_int8:
@@ -515,21 +519,18 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
                     a = fluid.layers.kv_block_chunk_attention(
                         q, kcache, vcache, start, btab, n_head)
                 x = block_tail(x, a, i, 2)
-            # logits at the chunk's LAST VALID row (the scheduler reads
-            # them only from a prompt's FINAL chunk)
-            flat = fluid.layers.reshape(x, shape=[C, D])
-            last = fluid.layers.gather(
-                flat, fluid.layers.elementwise_sub(
-                    clen, fluid.layers.fill_constant([1], 'int32', 1)))
-            chunk_logits = out_logits(last)                    # [1, V]
-        chunk_progs[C] = {
+            chunk_logits = last_logits(x, clen, C, R, D,
+                                       out_logits)             # [R, V]
+        return {
             'program': cp,
             'feeds': ['chunk_ids', 'start', 'chunk_len', 'block_table'],
-            'samples': {'chunk_ids': np.zeros((1, C), np.int64),
-                        'start': np.zeros((1, 1), np.int32),
-                        'chunk_len': np.ones((1, 1), np.int32),
-                        'block_table': np.zeros((1, MAXB), np.int32)},
+            'samples': {'chunk_ids': np.zeros((R, C), np.int64),
+                        'start': np.zeros((R, 1), np.int32),
+                        'chunk_len': np.ones((R, 1), np.int32),
+                        'block_table': np.zeros((R, MAXB), np.int32)},
             'fetches': [chunk_logits.name]}
+
+    chunk_progs = {C: chunk_program(C) for C in chunks}
 
     # ---- verify program (ISSUE 17, built LAST so the op-creation rng
     # order of step/chunk — and thus the weights — is untouched):
@@ -597,6 +598,11 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
                                                        np.int32)},
                   'fetches': [verify_logits.name]}
 
+    # ---- the row program, after everything else for the same reason: the
+    # largest chunk once more at [R, C], where the shapes give one
+    # (decode_spec.chunk_row_shape: chunks (32, 128) -> 128 x 4) ----------
+    rows = chunk_row_shape(chunk_progs, MAXB * BS)
+
     spec = {'startup': startup,
             'block_size': BS, 'num_blocks': NB,
             'max_blocks_per_slot': MAXB,
@@ -612,6 +618,9 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
             'max_slots': S, 'max_cache_len': T,
             'eos_id': int(eos_id), 'vocab': int(vocab),
             'kv_cache_dtype': kv_cache_dtype}
+    if rows is not None:
+        spec['chunk_rows'] = dict(chunk_program(*rows), size=rows[0],
+                                  rows=rows[1])
     if verify is not None:
         spec['verify'] = verify
         spec['draft_k'] = int(draft_k)

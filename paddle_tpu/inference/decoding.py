@@ -9,7 +9,9 @@ vLLM-style preallocated, block-paged KV cache):
 1. **A few compiled programs, fixed shapes forever** — a chunked-PREFILL
    program per chunk size (one slice of one request's prompt: writes
    its K/V rows through the request's block table and returns the
-   logits of its last position) and ONE DECODE-STEP program
+   logits of its last position), where the artifact has one the ROW
+   program (the largest chunk with a leading dimension of R: one slice
+   each of up to R different prompts) and ONE DECODE-STEP program
    ([max_slots] requests advance one token each). The cache is a pool
    of fixed-size blocks addressed through per-slot block tables the
    scheduler feeds each dispatch (kv_blocks.py owns refcounts,
@@ -26,7 +28,9 @@ vLLM-style preallocated, block-paged KV cache):
    (AOT sidecars per program, `tools/cache_ctl.py prewarm`).
 2. **Iteration-level scheduling** — new requests join the running batch
    at step boundaries (one prefill slice a tick, interleaved with the
-   running batch's steps, then their slot decodes with everyone else);
+   running batch's steps — the largest bucket's slices that several
+   admitting requests have due in one tick in ONE dispatch of the row
+   program — then their slot decodes with everyone else);
    finished sequences (eos / max_new_tokens) free
    their slot immediately for the next waiting request. The scheduler
    runs ONE STEP AHEAD of its reads: a tick dispatches the next step
@@ -59,7 +63,11 @@ it decodes alone or co-resident with any other requests — every per-slot
 computation is row-independent and masked rows carry exactly-zero
 attention weight (ops/decode_ops.py). Greedy decoding reads the ids the
 program chose on the device; fixed-width beam search runs host-side over
-the fetched logits with deterministic tie-breaking.
+the fetched logits with deterministic tie-breaking. Which chunk program
+takes a slice is chosen so that this holds (_prefill_tick has the rule
+and what the chip read): a beam's prompt keeps the one-row programs
+whoever admits beside it — its scores are those it has alone, to the
+bit — and a greedy slice rides the row program only in its own bucket.
 
 Speculative decoding (ISSUE 17): artifacts exported with a VERIFY
 program (build_decode_spec(draft_k=K)) can serve greedy streams
@@ -133,6 +141,9 @@ _STEP_DIR = 'decode_step'
 # chunked-prefill programs + the block-copy program (beam CoW moves
 # diverged BLOCKS)
 _CHUNK_DIR = 'prefill_chunk_%05d'   # % chunk size
+# the largest chunk with a leading ROW dimension, where the spec had one
+# (signature key 'chunk_rows'): slices of R different prompts a dispatch
+_CHUNK_ROWS_DIR = 'prefill_chunk_%05dx%d'   # % (chunk size, rows)
 _BLOCKCOPY_DIR = 'decode_blockcopy'
 # speculative decoding (ISSUE 17): the [S, K+1] -> [S, K+1, V] verify
 # program, present iff the spec was built with draft_k > 0
@@ -238,10 +249,10 @@ def _percentiles(values, qs):
     return [round(float(p), 3) for p in np.percentile(arr, qs)]
 
 
-def _one_row(ids, logits):
-    """(token, [V] logits or None) of a one-request program's [1] ids
-    and [1, V] logits."""
-    return int(ids[0]), None if logits is None else logits[0]
+def _one_row(ids, logits, row=0):
+    """(token, [V] logits or None) of row `row` of a chunk program's [R]
+    ids and [R, V] logits (R = 1: a one-request program's)."""
+    return int(ids[row]), None if logits is None else logits[row]
 
 
 def _log_softmax(row):
@@ -269,7 +280,8 @@ class DecodeStats(object):
     # `wait_s` seconds in _to_host's
     # block_until_ready (waiting for the device, by design); `gc_s`
     # seconds python's collector ran on ANY thread (it holds the GIL);
-    # `dispatches` steps + verify ticks + prefill slices dispatched;
+    # `dispatches` CALLS: steps + verify ticks + chunk-program calls
+    # (stats.chunk_dispatches: one for all the slices a row program took);
     # `rows` tokens emitted. The CPU clock of a thread is a system call
     # (0.3 us on a plain kernel; on a sandboxed one 6 us alone, tens
     # beside busy threads, and the clock moves in steps of 10 ms), so
@@ -328,7 +340,14 @@ class DecodeStats(object):
         self.block_reset = None
         self.cow_blocks = 0      # blocks copied for beam copy-on-write
         self.blockcopies = 0     # block-copy dispatches
-        self.chunk_slices = 0    # chunked-prefill slice dispatches
+        # chunked-prefill slices: one per slice of ONE request's prompt,
+        # however it reached the device (what the benchmark's tick_*_ms
+        # divide by, beside `steps`)
+        self.chunk_slices = 0
+        # calls of a chunk program: the slices that several admitting
+        # requests have due in one tick ride ONE call of the row program
+        # where the artifact has one, so chunk_dispatches <= chunk_slices
+        self.chunk_dispatches = 0
         # slices whose result the host read: the prompts' last ones.
         # 1 - slice_reads / chunk_slices of the slices cost no wait
         self.slice_reads = 0
@@ -384,6 +403,7 @@ class DecodeStats(object):
             self.cow_blocks = 0
             self.blockcopies = 0
             self.chunk_slices = 0
+            self.chunk_dispatches = 0
             self.slice_reads = 0
             self.steps_ahead = 0
             self.wasted_rows = 0
@@ -505,6 +525,7 @@ class DecodeStats(object):
                     'cow_blocks': int(self.cow_blocks),
                     'blockcopies': int(self.blockcopies),
                     'chunk_slices': int(self.chunk_slices),
+                    'chunk_dispatches': int(self.chunk_dispatches),
                     'slice_reads': int(self.slice_reads),
                     'steps_ahead': int(self.steps_ahead),
                     'wasted_rows': int(self.wasted_rows),
@@ -811,9 +832,11 @@ class _DecodeModule(object):
                 compiler_options=_compile_options(self._platform), **kw)
         return self._fn
 
-    def call(self, *args):
+    def call(self, *args, rows=None):
         """THE one dispatch site of the decode programs (step, verify,
-        chunk, blockcopy, zeros): returns once the call is enqueued."""
+        chunk, blockcopy, zeros): returns once the call is enqueued.
+        `rows`: a chunk program's real rows in this call (the prompt
+        slices it carries), the span's `rows` stat while a trace runs."""
         fn = self._aot if self._aot is not None else self._jitted()
         sized = {}
         if _serve.tracing():
@@ -825,6 +848,8 @@ class _DecodeModule(object):
             feeds = [a for a in feeds if isinstance(a, np.ndarray)]
             sized = {'feeds': len(feeds),
                      'feed_bytes': sum(a.nbytes for a in feeds)}
+            if rows is not None:
+                sized['rows'] = rows
         with _span('decode/dispatch', program=self.name, **sized), \
                 warnings.catch_warnings():
             # backends without donation support (XLA:CPU) warn per call;
@@ -967,6 +992,10 @@ def precompile_decode_artifact(artifact_dir, platform=None):
         written.append(model(_VERIFY_DIR, sig['verify']))
     for c in sig['chunk_buckets']:
         written.append(model(_CHUNK_DIR % int(c), sig['chunk'][str(c)]))
+    rows = sig.get('chunk_rows')
+    if rows is not None:
+        written.append(model(
+            _CHUNK_ROWS_DIR % (int(rows['size']), int(rows['rows'])), rows))
     pairs = index_spec(sig['max_slots'])
     written.append(dir_(_BLOCKCOPY_DIR, [state_specs, pairs, pairs],
                         donate=0))
@@ -1096,6 +1125,27 @@ class DecodingPredictor(object):
         self._chunk_feeds = {
             c: [e['name'] for e in self._sig['chunk'][str(c)]['feeds']]
             for c in self._chunks}
+        # the ROW program, where the artifact has one: the largest chunk
+        # with a leading dimension of R — the slices that several
+        # admitting requests have due in one tick go to the device in
+        # one dispatch (_prefill_tick). Its feed is KEPT, every row in
+        # the pad row's state between dispatches (no tokens, the trash
+        # table, no slot): a dispatch writes its real rows, hands over
+        # copies (_step has why) and puts the pad rows back
+        self._row_mod = self._row_feed = None
+        self._rows = 1
+        rsig = self._sig.get('chunk_rows')
+        if rsig is not None:
+            size, self._rows = int(rsig['size']), int(rsig['rows'])
+            self._row_mod = _DecodeModule(
+                os.path.join(artifact_dir,
+                             _CHUNK_ROWS_DIR % (size, self._rows)),
+                donate=1, device=self._device, aot_tag=aot_tag,
+                name='chunk_%dx%d' % (size, self._rows))
+            self._row_feed = {
+                e['name']: np.zeros(e['shape'], _dtype(e['dtype']))
+                for e in rsig['feeds']}
+            self._pad_rows(self._rows)
         self._blockcopy_mod = _DecodeModule(
             os.path.join(artifact_dir, _BLOCKCOPY_DIR),
             donate=0, device=self._device, aot_tag=aot_tag,
@@ -1125,8 +1175,8 @@ class DecodingPredictor(object):
         # _active_requests()'s list, kept until a slot changes hands
         self._active = None
         # what the last tick dispatched and nobody has read yet: (the
-        # step's read and rows or None, [(request, read)] of the slices
-        # that were their prompt's last), or None
+        # step's read and rows or None, [(read, [(request, row)])] of
+        # the chunk calls that held a prompt's last slice), or None
         self._unread = None
         # seconds inside _to_host's block_until_ready, ever: the tick
         # log's `wait_s` is its gain over a tick
@@ -1165,7 +1215,8 @@ class DecodingPredictor(object):
     @property
     def attention_bodies(self):
         """{program: {op type: {body: count}}} for the kv_*attention* ops
-        of the loaded programs ('step', 'verify', 'chunk_<C>') as THIS
+        of the loaded programs ('step', 'verify', 'chunk_<C>',
+        'chunk_<C>x<R>') as THIS
         platform runs them: what export_decode
         wrote into the signature, with 'kernel' — the body a module
         holds for a TPU — read as 'jnp' anywhere else. Empty for an
@@ -1175,6 +1226,8 @@ class DecodingPredictor(object):
         progs = {'step': sig['step'], 'verify': sig.get('verify', {})}
         for size, entry in sig['chunk'].items():
             progs['chunk_%s' % size] = entry
+        if self._row_mod is not None:
+            progs[self._row_mod.name] = sig['chunk_rows']
         platform = (self._mesh_ctx['platform'] if self._mesh_ctx is not None
                     else (self._device or jax.devices()[0]).platform)
         out = {}
@@ -1303,7 +1356,8 @@ class DecodingPredictor(object):
 
     def warmup(self):
         """Compile every program ahead of traffic (a no-op dispatch per
-        prefill chunk size, one decode step, one block copy, one all-pad
+        prefill chunk size and an all-pad one of the row program, one
+        decode step, one block copy, one all-pad
         verify tick on speculative artifacts); state is re-zeroed
         afterwards. With AOT sidecars loaded this costs a handful of
         dispatches and zero compiles. Must run BEFORE any submit(): it dispatches on
@@ -1321,6 +1375,8 @@ class DecodingPredictor(object):
             self._to_host(self._dispatch_chunk(
                 c, np.zeros((1, c), np.int64), 0, 1, trash_tables[:1],
                 window_row=None if wtables is None else wtables[:1]))
+        if self._row_mod is not None:
+            self._to_host(self._dispatch_rows(0, read=True))  # all pad
         self._to_host(self._dispatch_step(
             np.zeros((self._S, 1), np.int64),
             np.zeros((self._S, 1), np.int32), trash_tables,
@@ -1559,16 +1615,59 @@ class DecodingPredictor(object):
                 'window_table': window_row,
                 'slot': np.full((1, 1), slot, np.int32)}
         args = [self._feed(feed[n]) for n in self._chunk_feeds[size]]
+        return self._call_chunk(self._chunk_mods[size], args, 1, logits,
+                                read)
+
+    def _call_chunk(self, mod, args, n, logits, read):
+        """One call of a chunk program that carries `n` prompt slices;
+        the read of its ids (and logits) if `read`, else None."""
         with self._dev_ctx():
-            fetches, new_state = self._chunk_mods[size].call(
-                self._params, self._state, args)
+            fetches, new_state = mod.call(self._params, self._state, args,
+                                          rows=n)
         self._state = list(new_state)
         with self.stats._lock:
-            self.stats.prefills += 1
-            self.stats.chunk_slices += 1
-        if not read:
-            return None
-        return self._ask(fetches, self._chunk_mods[size].name, logits)
+            self.stats.prefills += n
+            self.stats.chunk_slices += n
+            self.stats.chunk_dispatches += 1
+        return self._ask(fetches, mod.name, logits) if read else None
+
+    def _pad_rows(self, n):
+        """The first `n` rows of the row program's kept feed in the pad
+        row's state: no real position (chunk_len 0), the trash table —
+        the row writes the trash block, which nothing reads — and no
+        slot, so no entry of the ids row is written."""
+        feed = self._row_feed
+        feed['chunk_ids'][:n] = 0
+        feed['start'][:n] = 0
+        feed['chunk_len'][:n] = 0
+        feed['block_table'][:n] = self._trash
+        feed['slot'][:n] = -1
+
+    def _write_row(self, k, req, take, last):
+        """Row `k` of the row program's kept feed: `take` tokens of
+        `req`'s prompt from its next_start on, through its table;
+        `slot` as _dispatch_chunk's."""
+        feed = self._row_feed
+        at = req.next_start
+        feed['chunk_ids'][k, :take] = req.prompt[at:at + take]
+        feed['start'][k, 0] = at
+        feed['chunk_len'][k, 0] = take
+        table = req.tables[0]
+        feed['block_table'][k, :len(table)] = table
+        feed['slot'][k, 0] = req.slots[0] if last else -1
+
+    def _dispatch_rows(self, n, read=False):
+        """Dispatch the row program on the first `n` rows of its kept
+        feed (_write_row; the rest are pad rows): one slice each of `n`
+        DIFFERENT greedy prompts in one call. With `read` — some row is
+        its prompt's last slice — asks for the [R] ids and returns the
+        ONE read the rows share (_to_host once, _one_row by row index);
+        else None, as _dispatch_chunk. Nobody reads its [R, V] logits:
+        a beam's slices take the one-row programs."""
+        # the kept feed holds the arrays in the signature's feed order
+        args = [self._feed(a.copy()) for a in self._row_feed.values()]
+        self._pad_rows(n)
+        return self._call_chunk(self._row_mod, args, n, False, read)
 
     def _dispatch_blockcopy(self, pairs):
         """One block-copy dispatch: every (dst, src) PHYSICAL-BLOCK pair
@@ -1708,8 +1807,10 @@ class DecodingPredictor(object):
         device is, the wait is the pipeline — step k+1 is queued behind
         the step k waited for. Then the waiting requests admit — into
         the slots that read has just freed — and one prefill slice per
-        admitting request is dispatched (_prefill_tick); the donated
-        state threads step, slice, slice, step, ... in order. A tick is
+        admitting request is dispatched (_prefill_tick: the slices of
+        several requests in ONE call where the artifact has a row
+        program); the donated
+        state threads step, slices, step, ... in order. A tick is
         max(host work, device work).
 
         What follows from reading a tick late. A request that reaches
@@ -1733,7 +1834,7 @@ class DecodingPredictor(object):
         stats = self.stats
         t0 = time.perf_counter()
         wait0, gc0 = self._wait_s, _serve.gc_seconds()
-        made0 = stats.steps + stats.verify_steps + stats.chunk_slices
+        made0 = stats.steps + stats.verify_steps + stats.chunk_dispatches
         rows0 = stats.tokens
         busy = self._unread is not None
         with _span('decode/expire'):
@@ -1769,7 +1870,7 @@ class DecodingPredictor(object):
             stats.log_tick(
                 self._tick, t0, time.perf_counter() - t0,
                 self._wait_s - wait0, _serve.gc_seconds() - gc0,
-                stats.steps + stats.verify_steps + stats.chunk_slices
+                stats.steps + stats.verify_steps + stats.chunk_dispatches
                 - made0, stats.tokens - rows0)
 
     def _results_first(self):
@@ -1788,11 +1889,12 @@ class DecodingPredictor(object):
 
     def _read(self, step, lasts):
         """Read what one tick dispatched: the step's ids first (emit),
-        then the ids of the slices that were a prompt's last."""
+        then the ids of the slices that were a prompt's last, a chunk
+        call at a time."""
         if step is not None:
             self._read_step(*step)
-        for req, read in lasts:
-            self._read_slice(req, read)
+        for read, rows in lasts:
+            self._read_slices(read, rows)
 
     def _shed_waiting(self, waiting):
         """drain() in progress: fail every WAITING request with
@@ -2005,35 +2107,76 @@ class DecodingPredictor(object):
         fixed-size slices, one per scheduler iteration, interleaved with
         the running batch's decode steps — a max-length prompt never
         stalls every stream's inter-token latency for its whole prefill.
-        Returns [(request, read)] of the slices that were their prompt's
-        last, for _read_slice a tick later, behind the next step's
-        dispatch and once the tokens of this tick's step are out."""
-        lasts = []
+
+        COLLECT, THEN DISPATCH. Each slice due — in slot order, under
+        its own 'decode/prefill_slice' span — is its request's next
+        `take` tokens, in the bucket it takes alone. Which route follows
+        from what the scheduler sees. A slice RIDES THE ROW PROGRAM
+        where the artifact has one, the request is greedy, the slice's
+        own bucket is the largest — the row program's — and another
+        such slice is due: groups of up to R rows, one call a group, the
+        span holding the row's bookkeeping only (_write_row, _sliced)
+        and the call following the group's last span. Every other slice
+        takes its own bucket's one-row program, dispatched inside its
+        span (_prefill_slice) — also a group of one that is left over
+        (R + 1 riders due): R rows of device work for one slice buy
+        nothing. Several slices of ONE request never share a call.
+
+        Why only those. A row of the row program is the function its
+        bucket's one-row program computes, under another shape, and
+        what a request is served must not tell who admitted beside it.
+        On the TPU the row program writes the K/V rows of chunk_<C>, its
+        own bucket, to the bit, and its logits within one unit in the
+        last place (PERF.md section 6, PR 39); a SMALLER bucket's
+        program rounds otherwise (its K/V rows read 7e-3 apart), so a
+        slice that alone takes a smaller bucket keeps it. A beam's
+        logits are read and scored by the host: it keeps the one-row
+        programs, and its scores are those it has alone, to the bit.
+
+        Returns [(read, [(request, row)])]: for each call that held a
+        prompt's last slice, its read and those rows — for _read_slices
+        a tick later, behind the next step's dispatch and once the
+        tokens of this tick's step are out."""
+        R = self._rows      # 1 on an artifact without a row program
+        largest = self._chunks[-1]
+        due = []            # (request, bucket, take, last, rides)
         for req in self._active_requests():
-            if not req.prefilling:
-                continue
-            plen = int(req.prompt.size)
-            remaining = plen - req.next_start
-            size = select_bucket(self._chunks,
-                                 min(remaining, self._chunks[-1]))
-            take = min(size, remaining)
-            last = take >= remaining
+            if req.prefilling:
+                remaining = int(req.prompt.size) - req.next_start
+                size = select_bucket(self._chunks, min(remaining, largest))
+                due.append((req, size, min(size, remaining),
+                            size >= remaining,
+                            R > 1 and size == largest and req.beam is None))
+        rowed = sum(rides for *_, rides in due)
+        rowed -= rowed % R == 1
+        lasts, group, taken = [], [], 0
+        for req, size, take, last, rides in due:
             with _span('decode/prefill_slice', request=req.seq, size=size,
                        take=take, start=req.next_start, last=int(last)):
-                read = self._prefill_slice(req, size, take, last)
-            if last:
-                lasts.append((req, read))
+                if rides and taken < rowed:
+                    self._write_row(len(group), req, take, last)
+                    self._sliced(req, take, last)
+                    group.append((req, last))
+                    taken += 1
+                else:
+                    read = self._prefill_slice(req, size, take, last)
+                    if last:
+                        lasts.append((read, [(req, 0)]))
+            if group and (len(group) == R or taken == rowed):
+                rows = [(r, k) for k, (r, end) in enumerate(group) if end]
+                read = self._dispatch_rows(len(group), read=bool(rows))
+                if rows:
+                    lasts.append((read, rows))
+                group = []
         return lasts
 
     def _prefill_slice(self, req, size, take, last):
-        """Dispatch one slice of `req`'s prompt. What it keeps depends
+        """Dispatch one slice of `req`'s prompt through the one-row
+        program of its bucket. What it keeps depends
         on what it can see: nothing unless the slice is the prompt's
         `last` — then the read of its id, and of its logits row where
         the request is a beam (the one dispatch of a prompt whose whole
-        logits row the host reads). Behind its last slice the request is
-        a decoding row — of the next tick's step, which takes a greedy
-        request's first token from the device: the slice writes it into
-        the request's slot of the ids row."""
+        logits row the host reads)."""
         ids = np.zeros((1, size), np.int64)
         ids[0, :take] = req.prompt[req.next_start:req.next_start + take]
         window_row = None
@@ -2047,33 +2190,47 @@ class DecodingPredictor(object):
             logits=last and req.beam is not None, read=last,
             window_row=window_row,
             slot=req.slots[0] if last and req.beam is None else -1)
+        self._sliced(req, take, last)
+        return read
+
+    def _sliced(self, req, take, last):
+        """`take` more tokens of `req`'s prompt are on their way to the
+        device. Behind its last slice the request is
+        a decoding row — of the next tick's step, which takes a greedy
+        request's first token from the device: the slice writes it into
+        the request's slot of the ids row."""
         req.next_start += take
         if last:
             req.prefilling = False
             req.dispatched = 1
             self._rewrite[req] = None   # the next step's feed writes it
-        return read
 
-    def _read_slice(self, req, read):
-        """The end of `req`'s prefill, read a tick after its last slice
-        was dispatched: the id (a beam: the logits row) its prompt's
+    def _read_slices(self, read, rows):
+        """The end of a prefill, read a tick after the chunk call that
+        held the prompt's last slice: for each (request, row of the
+        call) of `rows`, the id (a beam: the logits row) its prompt's
         last position chose, the prompt's blocks published, the first
-        token emitted. The request has been a decoding row since that
-        dispatch; one that ended in between (cancelled, expired, shed)
-        has nothing read."""
-        if not self._holds(req.slots[0], req):
+        token emitted. The call is read ONCE for all its rows. A request
+        has been a decoding row since that dispatch; one that ended in
+        between (cancelled, expired, shed) has nothing read."""
+        rows = [(req, k) for req, k in rows
+                if self._holds(req.slots[0], req)]
+        if not rows:
             return
-        tok, logits = _one_row(*self._to_host(read))
+        ids, logits = self._to_host(read)
         with self.stats._lock:
-            self.stats.slice_reads += 1
-        if not self._window:
-            # publish the prompt's FULL blocks for prefix reuse (the
-            # partial tail stays private: decode writes land there)
-            with _span('decode/publish_prefix',
-                       blocks=len(req.prompt) // self._bs):
-                self._blocks.register_prefix(req.prompt, req.tables[0])
-        with _req_span('decode/first_token', req):
-            self._first_token(req, tok, logits)
+            self.stats.slice_reads += len(rows)
+        for req, k in rows:
+            tok, row_logits = _one_row(ids, logits, k)
+            if not self._window:
+                # publish the prompt's FULL blocks for prefix reuse (the
+                # partial tail stays private: decode writes land there)
+                with _span('decode/publish_prefix',
+                           blocks=len(req.prompt) // self._bs):
+                    self._blocks.register_prefix(req.prompt,
+                                                 req.tables[0])
+            with _req_span('decode/first_token', req):
+                self._first_token(req, tok, row_logits)
 
     def _blind(self, req):
         """Whether the kept feed advances `req`'s rows without the host

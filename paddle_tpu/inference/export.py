@@ -411,6 +411,18 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                    [1, 1] int32, 'block_table' [1, max_blocks] int32;
                    'fetches' names the last-real-position logits
                    [1, vocab].
+      chunk_rows   (optional) {..., 'size': C, 'rows': R}: the LARGEST
+                   chunk once more with a leading row dimension — the
+                   same feeds at [R, C] / [R, 1] / [R, max_blocks],
+                   logits [R, vocab] — so that the slices R DIFFERENT
+                   admitting requests have due in one scheduler tick go
+                   to the device in one dispatch. A builder adds it
+                   where the shapes give one (models/decode_spec.
+                   chunk_row_shape: at most 512 prompt tokens and 4 rows
+                   a dispatch, the chunk attention over the gathered
+                   view — as many K/V heads as query heads, no window —
+                   with the rows' scores in its budget: chunks (32, 128)
+                   -> 128 x 4, a largest chunk of 512 -> none).
       cache_vars   persistable KV-cache state vars present in every
                    program: the pool ([num_blocks, block_size, ...]),
                    addressed through the block tables fed at dispatch
@@ -435,7 +447,8 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     (fetches, new_state). The exported program returns TWO fetches
     (signature 'fetches': ['ids', <the logits var>]): fetch 0 is `ids`,
     int32, the argmax of the logits over the vocabulary — [max_slots]
-    for the step, [max_slots, K+1] for verify, [1] for a chunk — and
+    for the step, [max_slots, K+1] for verify, [1] for a chunk ([R] for
+    the row program) — and
     fetch 1 is the float32 logits the spec names, untouched.
     The argmax is appended HERE, at the one place every program of every
     model is traced (_export_decode_program), over the same float32
@@ -474,6 +487,11 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
       decode_step/            module.jaxexport (+ aot_<platform>.jaxexec)
       decode_zeros/           the state's birth
       prefill_chunk_<C>/      one per chunk size
+      prefill_chunk_<C>x<R>/  the row program, where the spec has one:
+                              `slot` [R, 1], ids [R], logits [R, V];
+                              listed under the signature's 'chunk_rows'
+                              (an artifact without it serves one slice a
+                              dispatch, as every artifact did)
       decode_blockcopy/       block-pair copy program (CoW)
 
     kv_cache_dtype='int8' (ISSUE 11): assert-and-record that the spec
@@ -561,6 +579,20 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                              % (chunk_want, p['feeds']))
         entries[_decoding._CHUNK_DIR % C] = p
         roles[_decoding._CHUNK_DIR % C] = 'chunk'
+    rows = spec.get('chunk_rows')
+    if rows is not None:
+        # the one chunk program with a row dimension: its largest chunk
+        # at [R, C], the same feed names
+        if sorted(rows['feeds']) != chunk_want \
+                or int(rows['size']) != chunks[-1]:
+            raise ValueError(
+                "spec['chunk_rows'] must be the largest chunk (%d) with "
+                'feeds %r, got size %r, feeds %r'
+                % (chunks[-1], chunk_want, rows['size'], rows['feeds']))
+        rows_dir = _decoding._CHUNK_ROWS_DIR % (int(rows['size']),
+                                                int(rows['rows']))
+        entries[rows_dir] = rows
+        roles[rows_dir] = 'chunk'
     programs = {d: _optimize_decode_program(e, state_names)
                 for d, e in entries.items()}
     # the ONE parameter list every program takes, in this order
@@ -628,6 +660,11 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
            'chunk_buckets': chunks,
            'chunk': {str(C): sigs[_decoding._CHUNK_DIR % C]
                      for C in chunks}}
+    if rows is not None:
+        # a key of its own: a loader from before the row program reads
+        # 'chunk_buckets' / 'chunk' and serves one slice a dispatch
+        sig['chunk_rows'] = dict(sigs[rows_dir], size=int(rows['size']),
+                                 rows=int(rows['rows']))
     if verify is not None:
         sig['verify'] = dict(sigs[_decoding._VERIFY_DIR],
                              draft_k=int(spec['draft_k']))
@@ -803,8 +840,9 @@ def _export_decode_program(entry, program, param_args, param_specs,
     its last real position chose into that slot's entry — the slice is
     its prompt's last and the request decodes from the next step without
     the host having seen its first token — or nothing where `slot` is
-    negative. 'verify' threads the row through (its rows' tokens are the
-    host's).
+    negative; the row program's `slot` is [R, 1] and the write a scatter
+    over the rows whose slot is not negative. 'verify' threads the row
+    through (its rows' tokens are the host's).
 
     Returns the program's signature entries: its 'feeds' (a chunk's
     with `slot`), its 'fetches' by name,
@@ -822,9 +860,11 @@ def _export_decode_program(entry, program, param_args, param_specs,
     feed_names = list(entry['feeds'])
     fetch_names = list(entry['fetches'])
     samples = {n: np.asarray(entry['samples'][n]) for n in feed_names}
+    n_rows = 0      # of a chunk program: 1, or the row program's R
     if role == 'chunk':
         feed_names.append('slot')     # fed to fn, no program variable
-        samples['slot'] = np.full((1, 1), -1, np.int32)
+        n_rows = samples['chunk_ids'].shape[0]
+        samples['slot'] = np.full((n_rows, 1), -1, np.int32)
     rng = jax.random.key(0)  # decode programs draw no randomness
     constrain = shard['constrain'] if shard is not None else {}
     sizes = {v.name: int(np.prod(v.shape)) for v in program.list_vars()
@@ -861,9 +901,15 @@ def _export_decode_program(entry, program, param_args, param_specs,
             ids = jnp.argmax(fetched[0], axis=-1).astype(jnp.int32)
             if role == 'step':
                 row = ids
-            elif role == 'chunk':
+            elif n_rows == 1:
                 row = jnp.where(jnp.arange(row.shape[0]) == slot[0, 0],
                                 ids[0], row)
+            elif n_rows:
+                # a scatter over the rows whose slot >= 0: the others
+                # (a slice inside a prompt, a pad row) aim
+                # past the row's end and are dropped
+                at = jnp.where(slot[:, 0] >= 0, slot[:, 0], row.shape[0])
+                row = row.at[at].set(ids, mode='drop')
         return ([ids] + fetched,
                 [tracer.env[n] for n in state_names[:-1]] + [row])
 

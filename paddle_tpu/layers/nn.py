@@ -1734,7 +1734,10 @@ def kv_block_chunk_write(cache, kv, start, block_table):
     """Chunked-prefill write (ISSUE 13): one chunk's K or V rows
     [1, chunk, d] for absolute positions start..start+chunk-1 of ONE
     slot scatter into the block pool through the slot's `block_table`
-    row [1, max_blocks]. In-place on `cache`."""
+    row [1, max_blocks]. The leading dimension may be R rows — `kv`
+    [R, chunk, d], `start` [R, 1], `block_table` [R, max_blocks] — each
+    row one slot's chunk through its own table row (a pad row: the
+    trash table). In-place on `cache`."""
     helper = LayerHelper('kv_block_chunk_write')
     helper.append_op(type='kv_block_chunk_write',
                      inputs={'Cache': cache, 'KV': kv, 'Start': start,
@@ -1753,7 +1756,11 @@ def kv_block_chunk_attention(query, k_cache, v_cache, start, block_table,
     kv_block_attention's. Where heads are grouped, a window is set or
     the [chunk, n_head, max_blocks * block_size] scores of the whole
     view would be too large to hold, the lowering reads the slot's pages
-    a block of positions at a time under an online softmax instead."""
+    a block of positions at a time under an online softmax instead.
+    Over the gathered view the leading dimension may be R rows (`query`
+    [R, chunk, d], `start` [R, 1], `block_table` [R, max_blocks]): row r
+    is one slot's chunk, attended through table row r — the same
+    function per row; the other body takes one row."""
     helper = LayerHelper('kv_block_chunk_attention')
     out = helper.create_variable_for_type_inference(query.dtype)
     helper.append_op(type='kv_block_chunk_attention',

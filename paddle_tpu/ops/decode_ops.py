@@ -731,10 +731,58 @@ def _chunk_attention_body(ctx, q, kview, vview, start, d):
 
 # What the chunk op decides from the shapes it is given
 # (_kv_block_chunk_attention): the float32 [C, n_head, T'] scores of the
-# gathered view may take this many bytes and no more, and the blocked
-# body reads this many positions at a time.
+# gathered view — of all its R rows together — may take this many bytes
+# and no more, and the blocked body reads this many positions at a time.
 _CHUNK_SCORES_BYTES = 256 << 20
 _CHUNK_KEY_BLOCK = 512
+# The ONE row program of a decode spec (chunk_row_program): a dispatch
+# carries at most this many prompt tokens — the largest chunk of the MoE
+# configurations, i.e. how much prefill may sit in front of the other
+# streams' next token — in at most this many rows (slices of DIFFERENT
+# admitting requests). PERF.md section 6 (PR 39) has the chip's reading
+# of a 4-row call's host and device time.
+_CHUNK_ROW_TOKENS = 512
+_CHUNK_ROWS = 4
+
+
+def _gathered_view_fits(rows, c, n_head, n_kv, window, view_len):
+    """Whether `rows` chunk rows of c positions take the gathered-view
+    body (_chunk_attention_body): as many K/V heads as query heads,
+    nothing windowed, and the rows' float32 [c, n_head, view_len] scores
+    within _CHUNK_SCORES_BYTES together."""
+    return (n_kv == n_head and not window
+            and 4 * rows * c * n_head * view_len <= _CHUNK_SCORES_BYTES)
+
+
+def _one_row_only(op, x):
+    """The chunk forms that keep R = 1 (the int8 pool's) say so by name
+    when handed more."""
+    if x.shape[0] != 1:
+        raise NotImplementedError(
+            '%s takes one chunk row, got %d: only kv_block_chunk_write / '
+            'kv_block_chunk_attention over the gathered view have a row '
+            'dimension' % (op, x.shape[0]))
+
+
+def chunk_row_program(chunk_sizes, attentions, view_len):
+    """(C, R) of the one chunked-prefill program a decode spec builds
+    with a leading ROW dimension — R slices of different prompts in one
+    dispatch — or None. From shapes alone: C is the spec's largest
+    chunk, R = min(_CHUNK_ROWS, _CHUNK_ROW_TOKENS // C); there is one
+    where R > 1 and every attention of the chunk program — `attentions`:
+    (op type, n_head, n_kv_head, window) of each — is a
+    kv_block_chunk_attention that takes its gathered-view body at
+    [R, C] over a view of `view_len` positions. The int8 pool's _quant
+    form, grouped heads and a window keep R = 1."""
+    c = max(int(x) for x in chunk_sizes)
+    rows = min(_CHUNK_ROWS, _CHUNK_ROW_TOKENS // c)
+    if rows > 1 and attentions and all(
+            op == 'kv_block_chunk_attention'
+            and _gathered_view_fits(rows, c, n_head, n_kv or n_head,
+                                    window, view_len)
+            for op, n_head, n_kv, window in attentions):
+        return c, rows
+    return None
 
 
 def _chunk_attention_blocked(ctx, q, kc, vc, start, table):
@@ -800,55 +848,85 @@ def _chunk_attention_blocked(ctx, q, kc, vc, start, table):
 
 @register('kv_block_chunk_write', no_grad=True, lod='none')
 def _kv_block_chunk_write(ctx, ins):
-    """Chunked-prefill write: KV [1, C, D] rows for chunk positions
-    start..start+C-1 of ONE slot scatter into the block pool through
-    the slot's table (Cache [NB, BS, D], Start [1, 1] int32, BlockTable
-    [1, MAXB] int32). Rows beyond the chunk's true length carry pad
-    garbage into the slot's own tail block (or the trash block past the
-    allocated span) — never attended before a decode step overwrites
-    them, the prefill contract in block form. Out aliases Cache."""
+    """Chunked-prefill write: KV [R, C, D] — row r the K or V rows of
+    chunk positions start[r]..start[r]+C-1 of ONE slot — scatter into
+    the block pool through that slot's table row (Cache [NB, BS, D],
+    Start [R, 1] int32, BlockTable [R, MAXB] int32). Positions beyond a
+    chunk's true length carry pad garbage into the slot's own tail block
+    (or the trash block past the allocated span) — never attended before
+    a decode step overwrites them, the prefill contract in block form;
+    a pad ROW (the trash table) writes the trash block only. With R = 1
+    the expression is the one-slot one, unchanged. Out aliases Cache."""
     cache = ins['Cache'][0]
     kv = ins['KV'][0]
-    start = ins['Start'][0].reshape(()).astype(jnp.int32)
     table = ins['BlockTable'][0]
-    c = kv.shape[1]
-    pos = start + jnp.arange(c, dtype=jnp.int32)
+    r, c = kv.shape[0], kv.shape[1]
+    if r == 1:
+        start = ins['Start'][0].reshape(()).astype(jnp.int32)
+        pos = start + jnp.arange(c, dtype=jnp.int32)
+        bidx, boff = _block_scatter_idx(
+            jnp.broadcast_to(table[0], (c, table.shape[1])), pos,
+            cache.shape[1])
+        return {'Out': [cache.at[bidx, boff].set(
+            kv[0].astype(cache.dtype))]}
+    start = ins['Start'][0].reshape(r, 1).astype(jnp.int32)
+    pos = start + jnp.arange(c, dtype=jnp.int32)[None, :]       # [R, C]
     bidx, boff = _block_scatter_idx(
-        jnp.broadcast_to(table[0], (c, table.shape[1])), pos,
-        cache.shape[1])
+        jnp.repeat(table, c, axis=0), pos.reshape(-1), cache.shape[1])
     return {'Out': [cache.at[bidx, boff].set(
-        kv[0].astype(cache.dtype))]}
+        kv.reshape(r * c, -1).astype(cache.dtype))]}
 
 
 @register('kv_block_chunk_attention', no_grad=True, lod='none')
 def _kv_block_chunk_attention(ctx, ins):
-    """Chunked-prefill attention: Q [1, C, D] chunk rows of one slot
-    attend the slot's logical view (KCache/VCache [NB, BS, D] through
-    BlockTable [1, MAXB]) rows j <= Start + i — causal in the chunk and
-    across everything already written (earlier chunks, SHARED prefix
-    blocks, which is what lets a prefix hit skip recompute). Attrs
-    n_kv_head and window as the step op's (_head_attrs).
+    """Chunked-prefill attention: Q [R, C, D] — row r the chunk rows of
+    one slot — attend that slot's logical view (KCache/VCache
+    [NB, BS, D] through BlockTable row r of [R, MAXB]) rows
+    j <= Start[r] + i — causal in the chunk and across everything
+    already written (earlier chunks, SHARED prefix blocks, which is what
+    lets a prefix hit skip recompute). Attrs n_kv_head and window as the
+    step op's (_head_attrs).
 
     Two bodies, chosen from what the lowering sees and never from a
     knob: the gathered view under one softmax (_chunk_attention_body)
     where query and K/V heads are as many, nothing is windowed and the
-    view's [C, n_head, T'] float32 scores fit _CHUNK_SCORES_BYTES; else
-    the pages a block of positions at a time under an online softmax
-    (_chunk_attention_blocked): another summation order."""
+    view's [R, C, n_head, T'] float32 scores fit _CHUNK_SCORES_BYTES
+    (_gathered_view_fits); else the pages a block of positions at a time
+    under an online softmax (_chunk_attention_blocked): another
+    summation order. Only the gathered view has rows: with R = 1 it is
+    the one-slot expression, unchanged, with more it is that function
+    per row (one vmap); the blocked body's trip count depends on
+    `start`, so it keeps R = 1 and refuses more by name."""
     q = ins['Q'][0]
     kc = ins['KCache'][0]
     vc = ins['VCache'][0]
     start = ins['Start'][0]
-    table = ins['BlockTable'][0].astype(jnp.int32)[0]
-    n_head, n_kv, _, _, window = _head_attrs(ctx, kc.shape[2])
-    scores = 4 * q.shape[1] * n_head * table.shape[0] * kc.shape[1]
-    if n_kv != n_head or window or scores > _CHUNK_SCORES_BYTES:
-        return {'Out': [_chunk_attention_blocked(ctx, q, kc, vc, start,
-                                                 table)]}
-    kview = _block_view(kc, table)
-    vview = _block_view(vc, table)
-    return {'Out': [_chunk_attention_body(ctx, q, kview, vview, start,
-                                          kc.shape[2])]}
+    tables = ins['BlockTable'][0].astype(jnp.int32)
+    r, d = q.shape[0], kc.shape[2]
+    n_head, n_kv, _, _, window = _head_attrs(ctx, d)
+    gathered = _gathered_view_fits(r, q.shape[1], n_head, n_kv, window,
+                                   tables.shape[1] * kc.shape[1])
+    if r == 1:
+        table = tables[0]
+        if not gathered:
+            return {'Out': [_chunk_attention_blocked(ctx, q, kc, vc,
+                                                     start, table)]}
+        kview = _block_view(kc, table)
+        vview = _block_view(vc, table)
+        return {'Out': [_chunk_attention_body(ctx, q, kview, vview, start,
+                                              d)]}
+    if not gathered:
+        raise NotImplementedError(
+            'kv_block_chunk_attention has rows (%d) over the gathered '
+            'view only: _chunk_attention_blocked (grouped K/V heads, a '
+            'window, scores past _CHUNK_SCORES_BYTES) keeps R = 1'
+            % r)
+
+    def row(q_r, start_r, table_r):
+        return _chunk_attention_body(
+            ctx, q_r[None], _block_view(kc, table_r),
+            _block_view(vc, table_r), start_r, d)[0]
+    return {'Out': [jax.vmap(row)(q, start.reshape(r), tables)]}
 
 
 @register('kv_block_write_quant', no_grad=True, lod='none')
@@ -898,6 +976,7 @@ def _kv_block_chunk_write_quant(ctx, ins):
     cache = ins['Cache'][0]
     cscale = ins['Scale'][0]
     kv = ins['KV'][0]
+    _one_row_only('kv_block_chunk_write_quant', kv)
     start = ins['Start'][0].reshape(()).astype(jnp.int32)
     table = ins['BlockTable'][0]
     c = kv.shape[1]
@@ -928,6 +1007,7 @@ def _kv_block_chunk_attention_quant(ctx, ins):
     vs = ins['VScale'][0]
     k_f = ins['K'][0]
     v_f = ins['V'][0]
+    _one_row_only('kv_block_chunk_attention_quant', q)
     start = ins['Start'][0].reshape(()).astype(jnp.int32)
     table = ins['BlockTable'][0].astype(jnp.int32)[0]
 

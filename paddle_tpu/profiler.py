@@ -211,8 +211,10 @@ def serving_report():
         hdr += " %11s %11s %11s %6s" % ('tickp50(ms)', 'tickp99(ms)',
                                         'tickmax(ms)', 'offcpu')
         if blocks:
-            hdr += " %11s %6s %6s %6s" % ('blocks', 'pfxhit', 'cow',
-                                          'slices')
+            # prefill slices, and the chunk-program calls that carried
+            # them (fewer where slices rode the row program together)
+            hdr += " %11s %6s %6s %6s %6s" % ('blocks', 'pfxhit', 'cow',
+                                              'slices', 'calls')
         print(hdr)
         for name, s in decode_rows:
             row = ("%-26s %5s %5d %6d %7d %8.1f %8d %6d %5.2f %5d %5.2f "
@@ -232,14 +234,16 @@ def serving_report():
                 s.get('tick_max_ms', 0.0), s.get('tick_offcpu_share', 0.0))
             if blocks:
                 if 'blocks_in_use' in s:
-                    row += " %11s %6.2f %6d %6d" % (
+                    row += " %11s %6.2f %6d %6d %6d" % (
                         '%d/%d' % (s['blocks_in_use'],
                                    s.get('blocks_total', 0)),
                         s.get('prefix_hit_rate', 0.0),
                         s.get('cow_blocks', 0),
-                        s.get('chunk_slices', 0))
+                        s.get('chunk_slices', 0),
+                        s.get('chunk_dispatches', 0))
                 else:
-                    row += " %11s %6s %6s %6s" % ('-', '-', '-', '-')
+                    row += " %11s %6s %6s %6s %6s" % ('-', '-', '-', '-',
+                                                      '-')
             print(row)
     return out
 

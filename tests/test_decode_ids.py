@@ -194,11 +194,15 @@ def test_greedy_serving_copies_ids_only(arts, name, monkeypatch):
         streams = [pred.submit(p, max_new_tokens=6)
                    for p in _prompts(pred._vocab)]
         assert all(len(s.result(120)) >= 1 for s in streams)
-        snap = pred.stats.snapshot()
+        snap, rows = pred.stats.snapshot(), pred._rows
     assert snap['logits_fetches'] == 0 and snap['steps'] > 0
     assert {f for _, f, _ in copies.seen} == {'ids'}
     assert {b for p, _, b in copies.seen if p == 'step'} == {SLOTS * 4}
-    assert {b for p, _, b in copies.seen if p != 'step'} == {4}
+    # a slice's copy is its one id; a call of the row program (the float
+    # pools' artifacts hold one: six prompts at once ride it) copies R
+    assert rows == (1 if name == 'block_int8' else 4)
+    assert {4 * rows} <= {b for p, _, b in copies.seen if p != 'step'} \
+        <= {4, 4 * rows}
 
 
 # what the parent commit served for these requests on this spec: 'block' at
@@ -238,8 +242,9 @@ def test_a_live_beam_fetches_logits_and_serves_the_parents_beam(
     assert np.asarray(ids).tolist() == _PARENT_BEAM_IDS[name]
     assert [float(x) for x in scores] == _PARENT_BEAM_SCORES[name]
     assert greedy == _PARENT_GREEDY[name]
-    # the beam's last prompt slice and every step it rode copied logits
-    # (ids beside them); the greedy requests' prompts copied ids
+    # the beam's last prompt slice — a call of its bucket's one-row
+    # program, whoever admits beside it — and every step it rode copied
+    # logits (ids beside them); the greedy requests' prompts copied ids
     logits = [(p, b) for p, f, b in copies.seen if f == 'logits']
     assert snap['logits_fetches'] == len(logits) == 8
     assert {b for p, b in logits if p == 'step'} == {SLOTS * (VOCAB + 1) * 4}
@@ -434,3 +439,150 @@ def test_a_beam_row_or_a_drafter_takes_the_settled_order(arts, how,
     assert snap['steps'] > 0 and snap['steps_ahead'] == 0
     assert snap['wasted_rows'] == 0
     assert ''.join(events.seen) == 'DH' * snap['steps']
+
+
+# -- (f) the chunk program's row dimension: every R = 1 program is what it
+# was, and which spec holds a row program follows from its shapes ------------
+
+# sha256 (16 hex digits) of the location-free StableHLO text of every module
+# the parent of PR 39 exported for these three specs: a chunk op with a
+# leading dimension of 1 lowers to the expression it always had
+_PARENT_STABLEHLO = {
+    'transformer_base_lm/decode_blockcopy': '981e930e2bdfa797',
+    'transformer_base_lm/decode_step': '4a90334ca35afd6e',
+    'transformer_base_lm/decode_zeros': 'a07c8904ffbd40c2',
+    'transformer_base_lm/prefill_chunk_00008': '5c076e84cced5158',
+    'transformer_base_lm/prefill_chunk_00016': '1754ec5d1c3d3932',
+    'olmoe_1b_7b/decode_blockcopy': 'ccd86492049dc682',
+    'olmoe_1b_7b/decode_step': '4790b3c3458b86db',
+    'olmoe_1b_7b/decode_zeros': 'd1f3ab8996686fba',
+    'olmoe_1b_7b/prefill_chunk_00008': 'd1b14246625acd6b',
+    'olmoe_1b_7b/prefill_chunk_00016': 'dbf53268fad550ac',
+    'k_exaone_236b_a23b/decode_blockcopy': '149381b1d6f6401a',
+    'k_exaone_236b_a23b/decode_step': '48308d8960781fb1',
+    'k_exaone_236b_a23b/decode_zeros': '8650ecbf8a90380f',
+    'k_exaone_236b_a23b/prefill_chunk_00008': 'f0e3486c93c5f369',
+    'k_exaone_236b_a23b/prefill_chunk_00016': '2cf6d1530b0afb36'}
+# the one module each spec gained: its largest chunk at four rows
+_ROW_MODULES = {'transformer_base_lm': ['prefill_chunk_00016x4'],
+                'olmoe_1b_7b': ['prefill_chunk_00016x4'],
+                'k_exaone_236b_a23b': []}
+
+
+def _rehearsal_spec(config, **kw):
+    """The three decode configurations at toy widths, the rehearsal's
+    chunks (8, 16) unless `kw` says otherwise."""
+    if config == 'transformer_base_lm':
+        from models.transformer import build_decode_spec
+        args = dict(vocab=97, d_model=32, n_head=4, n_layer=2, d_ff=64,
+                    max_slots=4, max_cache_len=48, eos_id=1,
+                    chunk_sizes=(8, 16), block_size=4)
+    elif config == 'olmoe_1b_7b':
+        from models.olmoe import build_decode_spec
+        args = dict(_OLMOE)
+    else:
+        from models.exaone_moe import build_decode_spec
+        args = {}
+    args.update(kw)
+    return build_decode_spec(**args)
+
+
+def _location_free(text):
+    """StableHLO text without its `loc(...)` marks: they name export.py's
+    lines, which move with every edit of that file."""
+    out = []
+    for line in text.splitlines():
+        if line.startswith('#loc'):
+            continue
+        while ' loc(' in line:
+            i = line.index(' loc(')
+            depth, j = 0, i + 4
+            while True:
+                depth += {'(': 1, ')': -1}.get(line[j], 0)
+                j += 1
+                if depth == 0:
+                    break
+            line = line[:i] + line[j:]
+        out.append(line)
+    return '\n'.join(out)
+
+
+@pytest.fixture(scope='module')
+def stablehlo(tmp_path_factory):
+    """{module directory: hash of its text} of one spec's export, made
+    once a configuration."""
+    import hashlib
+    from jax import export as jexport
+    tmp = tmp_path_factory.mktemp('stablehlo')
+    made = {}
+
+    def get(config):
+        if config not in made:
+            art = str(tmp / config)
+            scope = fluid.core.Scope()
+            with fluid.scope_guard(scope), fluid.unique_name.guard():
+                spec = _rehearsal_spec(config)
+                spec['startup'].random_seed = 11
+                fluid.Executor(fluid.CPUPlace()).run(spec['startup'],
+                                                     scope=scope)
+                export_decode(spec, art, scope=scope, precompile=False)
+            made[config] = {}
+            for d in sorted(os.listdir(art)):
+                path = os.path.join(art, d, 'module.jaxexport')
+                if os.path.exists(path):
+                    with open(path, 'rb') as f:
+                        text = jexport.deserialize(f.read()).mlir_module()
+                    made[config][d] = hashlib.sha256(
+                        _location_free(text).encode()).hexdigest()[:16]
+        return made[config]
+    return get
+
+
+@pytest.mark.parametrize('module', sorted(_PARENT_STABLEHLO))
+def test_every_one_row_program_is_the_parents_stablehlo(stablehlo, module):
+    config, d = module.split('/')
+    assert stablehlo(config)[d] == _PARENT_STABLEHLO[module]
+
+
+@pytest.mark.parametrize('config', sorted(_ROW_MODULES))
+def test_a_spec_gains_its_row_program_and_nothing_else(stablehlo, config):
+    mine = {m.split('/')[1] for m in _PARENT_STABLEHLO
+            if m.startswith(config + '/')}
+    assert sorted(set(stablehlo(config)) - mine) == _ROW_MODULES[config]
+
+
+@pytest.mark.parametrize('config,kw,want', [
+    ('transformer_base_lm', dict(chunk_sizes=(32, 128), max_cache_len=256,
+                                 block_size=16), (128, 4)),
+    ('transformer_base_lm', {}, (16, 4)),
+    ('transformer_base_lm', dict(kv_cache_dtype='int8'), None),
+    ('olmoe_1b_7b', dict(chunk_sizes=(32, 128, 512), max_cache_len=512),
+     None),
+    ('olmoe_1b_7b', {}, (16, 4)),
+    ('k_exaone_236b_a23b', dict(chunk_sizes=(128, 512), max_cache_len=512),
+     None),
+    ('k_exaone_236b_a23b', {}, None),
+], ids=['base_32_128', 'base_8_16', 'base_int8', 'olmoe_published',
+        'olmoe_8_16', 'exaone_published', 'exaone_8_16'])
+def test_which_spec_holds_a_row_program_follows_from_its_shapes(config, kw,
+                                                                want):
+    """At most 512 prompt tokens and four rows a dispatch, over the
+    gathered view only: transformer_base_lm's chunks (32, 128) give
+    128 x 4, the MoE configurations' published chunks (largest 512)
+    none, the rehearsal's (8, 16) 16 x 4 — so the routed layer sees
+    [R, C, D] in tier-1 — and grouped heads, a window or the int8 pool
+    none at any size. The row program's feeds are the chunk's with R
+    rows."""
+    with fluid.unique_name.guard():
+        spec = _rehearsal_spec(config, **kw)
+    rows = spec.get('chunk_rows')
+    if want is None:
+        assert rows is None
+        return
+    assert (rows['size'], rows['rows']) == want
+    assert rows['size'] == max(spec['chunk'])
+    one = spec['chunk'][rows['size']]
+    assert rows['feeds'] == one['feeds']
+    for name in one['feeds']:
+        assert rows['samples'][name].shape \
+            == (want[1],) + one['samples'][name].shape[1:]

@@ -485,3 +485,146 @@ def test_beam_search_decode_backtrace():
     # apply end-id freezing as the op does
     np.testing.assert_array_equal(ids_mat[:, :T], want)
     np.testing.assert_allclose(scores_mat[:, 0], cur_scores, rtol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# the chunked-prefill ops' ROW dimension: KV / Q [R, C, D], Start [R, 1],
+# BlockTable [R, MAXB] — row r is one slot's chunk, through table row r
+# ---------------------------------------------------------------------------
+
+_NB, _BS, _D, _MAXB, _HEADS = 21, 4, 16, 5, 2
+
+
+def _chunk_ops(attrs=None):
+    import types
+    from paddle_tpu.ops import decode_ops
+    attrs = dict(attrs or {'n_head': _HEADS})
+    ctx = types.SimpleNamespace(attr=lambda n, d=None: attrs.get(n, d))
+
+    def write(cache, kv, start, tables):
+        return decode_ops._kv_block_chunk_write(ctx, {
+            'Cache': [cache], 'KV': [kv], 'Start': [start],
+            'BlockTable': [tables]})['Out'][0]
+
+    def attend(q, kc, vc, start, tables):
+        return decode_ops._kv_block_chunk_attention(ctx, {
+            'Q': [q], 'KCache': [kc], 'VCache': [vc], 'Start': [start],
+            'BlockTable': [tables]})['Out'][0]
+    return write, attend
+
+
+def _rows_case(rng, rows, c, starts):
+    """`rows` slots with tables of their own over one pool that already
+    holds every slot's history, the last rows pad rows (the trash table,
+    start 0)."""
+    import jax.numpy as jnp
+    pool = rng.randn(_NB, _BS, _D).astype(np.float32)
+    tables = np.zeros((rows, _MAXB), np.int32)        # trash: block 0
+    for r in range(len(starts)):
+        tables[r] = 1 + r * _MAXB + np.arange(_MAXB)
+    start = np.zeros((rows, 1), np.int32)
+    start[:len(starts), 0] = starts
+    kv = rng.randn(rows, c, _D).astype(np.float32)
+    q = rng.randn(rows, c, _D).astype(np.float32)
+    return tuple(jnp.asarray(a) for a in (pool, kv, q, start, tables))
+
+
+@pytest.mark.parametrize('rows,starts', [(4, [0, 3, 8, 5]), (4, [2, 9]),
+                                         (3, [7]), (2, [0, 11])])
+def test_chunk_ops_with_rows_are_the_one_row_ops_row_by_row(rows, starts):
+    """kv_block_chunk_write / kv_block_chunk_attention at [R, C, D] equal
+    R calls at [1, C, D], row by row: the same blocks written with the
+    same rows, the same attention output to the bit. A pad row (the trash
+    table) writes the trash block and nothing else."""
+    write, attend = _chunk_ops()
+    c = 6
+    pool, kv, q, start, tables = _rows_case(np.random.RandomState(7), rows,
+                                            c, starts)
+    many = write(pool, kv, start, tables)
+    one = pool
+    for r in range(rows):
+        one = write(one, kv[r:r + 1], start[r:r + 1], tables[r:r + 1])
+    many, one = np.asarray(many), np.asarray(one)
+    # block 0 is the trash block: every pad row lands there, in whatever
+    # order; every other block is what the R one-row writes left
+    np.testing.assert_array_equal(many[1:], one[1:])
+    changed = np.flatnonzero((many != np.asarray(pool)).any(axis=(1, 2)))
+    real = {int(b) for r in range(len(starts))
+            for b in np.asarray(tables)[r, [int(starts[r] + i) // _BS
+                                            for i in range(c)]]}
+    assert set(changed) - {0} == real
+    assert (0 in changed) == (len(starts) < rows)
+    out = np.asarray(attend(q, many, many, start, tables))
+    assert out.shape == (rows, c, _D)
+    for r in range(len(starts)):
+        np.testing.assert_array_equal(
+            out[r:r + 1],
+            np.asarray(attend(q[r:r + 1], many, many, start[r:r + 1],
+                              tables[r:r + 1])))
+    assert np.isfinite(out).all()
+
+
+def test_chunk_rows_exist_over_the_gathered_view_only(monkeypatch):
+    """Grouped heads, a window or scores past the budget send ONE row to
+    the blocked body; more rows are refused by name, as they are by the
+    int8 pool's forms."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import decode_ops
+    pool, kv, q, start, tables = _rows_case(np.random.RandomState(8), 2, 4,
+                                            [0, 4])
+    for attrs in ({'n_head': 4, 'n_kv_head': 2}, {'n_head': 2, 'window': 8}):
+        _, attend = _chunk_ops(attrs)
+        wide = jnp.concatenate([q, q], -1) if attrs['n_head'] == 4 else q
+        with pytest.raises(NotImplementedError, match='gathered'):
+            attend(wide, pool, pool, start, tables)
+        assert attend(wide[:1], pool, pool, start[:1],
+                      tables[:1]).shape == (1,) + wide.shape[1:]
+    _, attend = _chunk_ops()
+    # the budget counts the rows' scores together
+    one_row = 4 * 4 * _HEADS * _MAXB * _BS
+    monkeypatch.setattr(decode_ops, '_CHUNK_SCORES_BYTES', one_row)
+    assert attend(q[:1], pool, pool, start[:1], tables[:1]).shape \
+        == (1, 4, _D)
+    with pytest.raises(NotImplementedError, match='R = 1'):
+        attend(q, pool, pool, start, tables)
+    with pytest.raises(NotImplementedError, match='one chunk row, got 2'):
+        decode_ops._one_row_only('kv_block_chunk_write_quant', kv)
+
+
+@pytest.mark.parametrize('chunks,heads,want', [
+    ((32, 128), (8, 8, 0), (128, 4)),       # transformer_base_lm
+    ((32, 128, 512), (16, 16, 0), None),    # olmoe_1b_7b as published
+    ((128, 512), (64, 8, 128), None),       # k_exaone_236b_a23b
+    ((8, 16), (4, 4, 0), (16, 4)),          # the rehearsal's chunks
+    ((8, 16), (4, 2, 0), None),             # grouped heads
+    ((8, 16), (4, 4, 16), None),            # a window
+    ((256,), (8, 8, 0), (256, 2)),          # 512 tokens a dispatch
+    ((300,), (8, 8, 0), None),
+    ((64,), (8, 8, 0), (64, 4)),            # at most four rows
+])
+def test_the_row_programs_shape_follows_from_shapes(chunks, heads, want):
+    from paddle_tpu.ops.decode_ops import chunk_row_program
+    n_head, n_kv, window = heads
+    ops = [('kv_block_chunk_attention', n_head, n_kv, window)] * 3
+    assert chunk_row_program(chunks, ops, 2048) == want
+    # one layer that cannot take rows, and none can: the int8 pool's form,
+    # a window layer among full ones
+    assert chunk_row_program(
+        chunks, ops + [('kv_block_chunk_attention_quant',) + heads],
+        2048) is None
+    assert chunk_row_program(
+        chunks, ops + [('kv_block_chunk_attention', n_head, n_kv, 128)],
+        2048) is None
+    assert chunk_row_program(chunks, [], 2048) is None
+
+
+def test_the_rows_scores_stay_inside_the_budget(monkeypatch):
+    """R x the [C, n_head, T'] float32 scores <= _CHUNK_SCORES_BYTES, or
+    the spec holds no row program."""
+    from paddle_tpu.ops import decode_ops
+    ops = [('kv_block_chunk_attention', 8, 8, 0)]
+    four = 4 * 4 * 128 * 8 * 2048
+    monkeypatch.setattr(decode_ops, '_CHUNK_SCORES_BYTES', four)
+    assert decode_ops.chunk_row_program((32, 128), ops, 2048) == (128, 4)
+    monkeypatch.setattr(decode_ops, '_CHUNK_SCORES_BYTES', four - 1)
+    assert decode_ops.chunk_row_program((32, 128), ops, 2048) is None

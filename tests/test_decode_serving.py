@@ -240,18 +240,21 @@ def test_a_failing_chunk_program_fails_every_request_loudly(artifact):
     prompts = _prompts(17, 1) + _prompts(21, SLOTS + 2)
     queued = threading.Event()
 
-    def boom(*args):
+    def boom(*args, **kw):
         raise RuntimeError('chunk program broke')
 
     with DecodingPredictor(artifact) as pred:
         want = pred.generate(prompts[0], max_new_tokens=6)
-        calls = {c: m.call for c, m in pred._chunk_mods.items()}
+        # every chunk program: a slice alone takes its bucket's, the
+        # slices of several admitting requests the row program
+        mods = list(pred._chunk_mods.values()) + [pred._row_mod]
+        calls = [m.call for m in mods]
         run_tick = pred._run_tick
 
         def tick(waiting):
             if any(r.produced for r in pred._active_requests()):
                 assert queued.wait(60)
-                for m in pred._chunk_mods.values():
+                for m in mods:
                     m.call = boom
             run_tick(waiting)
         pred._run_tick = tick
@@ -263,8 +266,8 @@ def test_a_failing_chunk_program_fails_every_request_loudly(artifact):
             with pytest.raises(RuntimeError, match='chunk program broke'):
                 s.result(60)
         pred._run_tick = run_tick
-        for c, m in pred._chunk_mods.items():
-            m.call = calls[c]
+        for m, call in zip(mods, calls):
+            m.call = call
         assert pred._free_slots() == list(range(SLOTS))
         assert pred.generate(prompts[0], max_new_tokens=6) == want
 
@@ -594,6 +597,291 @@ def test_kept_feed_under_a_prefix_hit_and_a_beam(artifact):
     np.testing.assert_array_equal(got_scores, scores)
 
 
+# -- one dispatch for a tick's prefill slices (ISSUE 39): the row program ----
+
+def _export_wide(out, rows=True):
+    """The module's spec with eight slots; `rows` False: its row program
+    taken out before the export."""
+    from models.transformer import build_decode_spec
+    scope = fluid.core.Scope()
+    with fluid.scope_guard(scope), fluid.unique_name.guard():
+        spec = build_decode_spec(
+            vocab=VOCAB, d_model=16, n_head=2, n_layer=2, d_ff=32,
+            max_slots=8, max_cache_len=CACHE, chunk_sizes=CHUNKS,
+            block_size=BLOCK, eos_id=1)
+        spec['startup'].random_seed = 3
+        fluid.Executor(fluid.CPUPlace()).run(spec['startup'], scope=scope)
+        assert (spec['chunk_rows']['size'], spec['chunk_rows']['rows']) \
+            == (8, 4)
+        if not rows:
+            del spec['chunk_rows']
+        export_decode(spec, out, scope=scope)
+    return out
+
+
+@pytest.fixture(scope='module')
+def wide(tmp_path_factory):
+    """Eight slots: room for more admitting requests in one tick than the
+    row program (8 x 4) has rows."""
+    return _export_wide(str(tmp_path_factory.mktemp('decode_wide') / 'art'))
+
+
+@pytest.fixture(scope='module')
+def rowless(tmp_path_factory):
+    """The same spec exported WITHOUT its row program: what every artifact
+    from before the row program is."""
+    return _export_wide(
+        str(tmp_path_factory.mktemp('decode_rowless') / 'art'), rows=False)
+
+
+def _row_prompts(n, seed=41):
+    """Prompts of one to three slices of the (4, 8) chunks."""
+    rng = np.random.RandomState(seed)
+    lens = (3, 11, 6, 8, 19, 5, 9, 2, 14, 7)[:n]
+    return [rng.randint(2, VOCAB, k) for k in lens]
+
+
+def _held_behind(pred, prompt, max_new, before=None):
+    """Submit `prompt`, wait until it decodes, then hold the scheduler
+    (_gated): what the test queues before it sets the gate is all found
+    waiting by ONE tick, beside a running batch of one. Returns (the
+    stream, its first token, the gate)."""
+    stream = pred.submit(prompt, max_new_tokens=max_new)
+    tokens = iter(stream)
+    head = next(tokens)
+    return stream, head, _gated(pred, before)
+
+
+def _together(pred, prompts, max_new=7):
+    """The prompts served together: the first decodes, all the others
+    admit in one tick (as many as there are slots)."""
+    pred.block_manager.evict_all_prefixes()
+    pred.stats.reset()
+    watch = watch_feed(pred)
+    first, head, gate = _held_behind(pred, prompts[0], max_new)
+    streams = [pred.submit(p, max_new_tokens=max_new) for p in prompts[1:]]
+    gate.set()
+    got = [[head] + list(first)] + [list(s.result(120)) for s in streams]
+    return got, pred.stats.snapshot(), watch
+
+
+@pytest.mark.parametrize('n', [3, 5, 8, 10])
+def test_slices_due_together_ride_one_call_and_change_no_token(wide, n):
+    """N requests submitted together give the transcripts of the same
+    requests served one slice per dispatch (the same artifact, told on
+    the instance that it has one row a call) and of each served alone, in fewer
+    calls than slices; every kept feed equals its rebuild."""
+    prompts = _row_prompts(n)
+    with DecodingPredictor(wide) as pred:
+        assert pred._rows == 4 and pred._row_mod.name == 'chunk_8x4'
+        solo = [pred.generate(p, max_new_tokens=7) for p in prompts]
+        alone = pred.stats.snapshot()
+        rows, snap, watch = _together(pred, prompts)
+        assert watch.steps > 0
+        pred._rows = 1      # as an artifact without a row program
+        pred._run_tick = type(pred)._run_tick.__get__(pred)
+        pred._step_feed = type(pred)._step_feed.__get__(pred)
+        single, snap1, _ = _together(pred, prompts)
+    assert rows == single == solo
+    # a request alone: every slice is a call of its own
+    assert alone['chunk_dispatches'] == alone['chunk_slices'] > n
+    assert snap1['chunk_dispatches'] == snap1['chunk_slices'] \
+        == snap['chunk_slices']
+    assert snap['chunk_dispatches'] < snap['chunk_slices']
+    assert snap['slice_reads'] == snap['requests'] == n
+    assert snap['prefills'] == snap['chunk_slices']
+
+
+def test_a_mixed_tick_of_rows(wide):
+    """One tick with seven slices due: six of the largest bucket —
+    greedy rows, a row admitted on a prefix hit (start > 0), a row whose
+    request is cancelled between its dispatch and its read (the read
+    drops it: _holds) — in two calls of the row program, four rows and
+    two, and one of the small bucket in a call of its own program. Every
+    other transcript is the solo one, every feed equals its rebuild,
+    every block comes back."""
+    rng = np.random.RandomState(43)
+    shared = rng.randint(2, VOCAB, 8)                   # two full pages
+    hit = np.concatenate([shared[:4], rng.randint(2, VOCAB, 5)])
+    prompts = [rng.randint(2, VOCAB, k) for k in (5, 7, 13, 6, 8)] \
+        + [hit, rng.randint(2, VOCAB, 3)]
+    calls = []
+    with DecodingPredictor(wide) as pred:
+        solo = [pred.generate(p, max_new_tokens=6) for p in prompts]
+        pred.block_manager.evict_all_prefixes()
+        pred.generate(shared, max_new_tokens=2)         # publishes its pages
+        pred.stats.reset()
+        watch = watch_feed(pred)
+        dispatch_rows = pred._dispatch_rows
+
+        def counted(n, **kw):
+            calls.append((n, pred._row_feed['start'][:n, 0].tolist()))
+            return dispatch_rows(n, **kw)
+        pred._dispatch_rows = counted
+        streams = []
+
+        def cancel_behind_the_dispatch(pred):
+            # on the scheduler's thread, in front of the tick that reads
+            # what the last one dispatched
+            if streams and pred._unread is not None and pred._unread[1]:
+                streams[3].cancel()
+        first, _, gate = _held_behind(pred, shared[:3], 40,
+                                      before=cancel_behind_the_dispatch)
+        streams += [pred.submit(p, max_new_tokens=6) for p in prompts]
+        gate.set()
+        got = []
+        for k, stream in enumerate(streams):
+            if k == 3:
+                with pytest.raises(RuntimeError, match='cancelled'):
+                    stream.result(120)
+                got.append(None)
+            else:
+                got.append(list(stream.result(120)))
+        first.result(120)
+        snap = pred.stats.snapshot()
+        assert watch.steps > 0 and _pool_is_empty_of(pred, 8)
+    assert [g for k, g in enumerate(got) if k != 3] \
+        == [s for k, s in enumerate(solo) if k != 3]
+    # six slices of the largest bucket due in one tick: four rows, then
+    # two; the hit's row starts behind its shared page. The 3-token
+    # prompt's slice, the first request's and the 13-token prompt's
+    # second (alone in its tick) are calls of their own
+    assert calls == [(4, [0, 0, 0, 0]), (2, [0, 4])]
+    assert snap['prefix_hits'] == 1
+    assert snap['chunk_slices'] == 9 and snap['chunk_dispatches'] == 5
+    assert snap['steps_ahead'] == snap['steps']     # no beam: a tick late
+    # the cancelled row was dispatched and never read
+    assert snap['slice_reads'] == 7
+
+
+def _pool_is_empty_of(pred, slots):
+    pred.block_manager.evict_all_prefixes()
+    return pred.block_manager.stats()['blocks_in_use'] == 0 \
+        and pred._free_slots() == list(range(slots))
+
+
+def test_a_beam_among_greedy_rows_keeps_the_one_row_program(wide):
+    """A beam admitted in one tick with four greedy prompts: the greedy
+    slices are one call of the row program, read once and ids only; the
+    beam's slice is a call of its own bucket's one-row program, its
+    [1, V] logits row copied — hypotheses AND scores as served alone, to
+    the bit; the greedy transcripts the solo ones."""
+    prompts = _row_prompts(5, seed=47)
+    with DecodingPredictor(wide) as pred:
+        solo = [pred.generate(p, max_new_tokens=6) for p in prompts]
+        ids, scores = pred.generate(prompts[2], max_new_tokens=6, beam=3)
+        pred.block_manager.evict_all_prefixes()
+        pred.stats.reset()
+        watch = watch_feed(pred)
+        reads = []
+        to_host = pred._to_host
+
+        def seen(read):
+            out = to_host(read)
+            reads.append((read[0], None if out[1] is None
+                          else out[1].shape))
+            return out
+        pred._to_host = seen
+        first, head, gate = _held_behind(pred, prompts[0], 6)
+        greedy = [pred.submit(p, max_new_tokens=6) for p in prompts[1:3]]
+        beam = pred.submit(prompts[2], max_new_tokens=6, beam=3)
+        greedy += [pred.submit(p, max_new_tokens=6) for p in prompts[3:]]
+        gate.set()
+        got_ids, got_scores = beam.result(120)
+        assert [[head] + list(first)] \
+            + [list(s.result(120)) for s in greedy] == solo
+        snap = pred.stats.snapshot()
+        assert watch.steps > 0 and _pool_is_empty_of(pred, 8)
+    np.testing.assert_array_equal(got_ids, ids)
+    np.testing.assert_array_equal(got_scores, scores)
+    # five slices due in one tick: the four greedy ones are one call of
+    # the row program, read without its logits; the beam's is its own,
+    # and the only chunk call whose logits anybody reads
+    chunk_reads = [r for r in reads if r[0].startswith('chunk')]
+    assert ('chunk_8x4', None) in chunk_reads
+    assert [r for r in chunk_reads if r[1] is not None] \
+        == [('chunk_8', (1, VOCAB))]
+    assert snap['chunk_dispatches'] <= snap['chunk_slices'] - 3
+
+
+def test_an_artifact_without_a_row_program_serves_as_it_did(rowless, wide):
+    """No 'chunk_rows' in the signature (an artifact exported before the
+    row program, or a spec without one): it loads, every slice is a call
+    of its own, and the transcripts are those of the artifact that has
+    one."""
+    import json
+    from paddle_tpu.inference import decoding
+    with open(os.path.join(rowless, decoding._DECODE_SIGNATURE)) as f:
+        assert 'chunk_rows' not in json.load(f)
+    assert not os.path.exists(os.path.join(rowless, 'prefill_chunk_00008x4'))
+    assert os.path.exists(os.path.join(
+        wide, 'prefill_chunk_00008x4', 'aot_cpu.jaxexec'))
+    prompts = _row_prompts(6)
+    with DecodingPredictor(wide) as pred:
+        want, with_rows, _ = _together(pred, prompts)
+        assert 'chunk_8x4' in pred.attention_bodies
+    with DecodingPredictor(rowless) as pred:
+        assert pred._row_mod is None and pred._rows == 1
+        got, snap, _ = _together(pred, prompts)
+        assert 'chunk_8x4' not in pred.attention_bodies
+        pred.warmup()
+    assert got == want
+    assert snap['chunk_dispatches'] == snap['chunk_slices'] \
+        == with_rows['chunk_slices'] > with_rows['chunk_dispatches']
+
+
+def test_warmup_runs_the_row_program_on_pad_rows(wide):
+    """warmup() touches every program, the row program by a call of pad
+    rows: it writes the trash block, no slot's id, and counts for
+    nothing."""
+    with DecodingPredictor(wide) as pred:
+        seen = []
+        call = pred._row_mod.call
+
+        def watched(*args, **kw):
+            seen.append((kw['rows'], [np.asarray(a).copy()
+                                      for a in args[-1]]))
+            return call(*args, **kw)
+        pred._row_mod.call = watched
+        pred.warmup()
+        (rows, feeds), = seen
+        by_name = dict(zip(pred._row_feed, feeds))
+        assert rows == 0 and not by_name['chunk_len'].any()
+        assert (by_name['slot'] == -1).all()
+        assert (by_name['block_table'] == pred._trash).all()
+        snap = pred.stats.snapshot()
+        assert snap['chunk_dispatches'] == snap['chunk_slices'] == 0
+        assert pred.generate(_row_prompts(1)[0], max_new_tokens=4)
+
+
+def test_a_window_artifact_never_batches(tmp_path):
+    """Window layers (and grouped heads) keep every chunk op on the body
+    that has no rows: the spec holds no row program, and several
+    admissions in one tick are a call each."""
+    from models.exaone_moe import build_decode_spec
+    art = str(tmp_path / 'window')
+    scope = fluid.core.Scope()
+    with fluid.scope_guard(scope), fluid.unique_name.guard():
+        spec = build_decode_spec(n_layer=2, kv_cache_dtype='float32',
+                                 weights_dtype='float32')
+        assert 'window' in spec and 'chunk_rows' not in spec
+        fluid.Executor(fluid.CPUPlace()).run(spec['startup'], scope=scope)
+        export_decode(spec, art, scope=scope, precompile=False)
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(2, 120, k) for k in (5, 20, 9)]
+    with DecodingPredictor(art) as pred:
+        assert pred._row_mod is None
+        solo = [pred.generate(p, max_new_tokens=4) for p in prompts]
+        pred.stats.reset()
+        first, head, gate = _held_behind(pred, prompts[0], 4)
+        streams = [pred.submit(p, max_new_tokens=4) for p in prompts[1:]]
+        gate.set()
+        assert [[head] + list(first)] \
+            + [list(s.result(120)) for s in streams] == solo
+        snap = pred.stats.snapshot()
+    assert snap['chunk_dispatches'] == snap['chunk_slices'] == 4
+
+
 # -- TokenStream: a delivery is one C call (queue.SimpleQueue) -----------------
 
 def test_tokenstream_batches_keep_order_and_multi_token_pushes():
@@ -735,7 +1023,10 @@ def test_tick_log_rows_add_up(ticked):
     log, snap = ticked['log'], ticked['snap']
     assert np.all(log['wait_s'] >= 0) and np.all(log['gc_s'] >= 0)
     assert np.all(log['wait_s'] <= log['wall_s'] + 1e-6)
-    assert log['dispatches'].sum() == snap['steps'] + snap['chunk_slices']
+    # calls, not slices: six prompts at once ride the row program
+    assert log['dispatches'].sum() \
+        == snap['steps'] + snap['chunk_dispatches']
+    assert snap['chunk_dispatches'] < snap['chunk_slices']
     assert log['rows'].sum() == snap['tokens'] \
         == sum(len(t) for t in ticked['tokens'])
     read = ~np.isnan(log['cpu_s'])

@@ -446,7 +446,8 @@ def test_no_reorder_program_and_a_parents_artifact_still_loads(arts,
     assert sorted(d for d in os.listdir(arts['block'])
                   if os.path.isdir(os.path.join(arts['block'], d))) == [
         'decode_blockcopy', 'decode_step', 'decode_zeros',
-        'prefill_chunk_00004', 'prefill_chunk_00008']
+        'prefill_chunk_00004', 'prefill_chunk_00008',
+        'prefill_chunk_00008x4']
     old = str(tmp_path / 'parent')
     shutil.copytree(arts['block'], old)
     shutil.copytree(os.path.join(old, 'decode_blockcopy'),

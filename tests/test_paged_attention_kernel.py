@@ -459,7 +459,7 @@ def test_a_loaded_artifact_names_only_block_attention_ops(arts, art):
     loaded is a kv_block_* op."""
     with DecodingPredictor(arts[art]) as pred:
         bodies = pred.attention_bodies
-    assert set(bodies) == {'step', 'chunk_8', 'chunk_16'}
+    assert set(bodies) == {'step', 'chunk_8', 'chunk_16', 'chunk_16x4'}
     for by_op in bodies.values():
         assert by_op and all(op.startswith('kv_block_') for op in by_op)
 

@@ -161,7 +161,10 @@ def test_decode_span_is_in_the_trace(decode_trace, name):
     ('decode/step', ('decode/tick',)),
     ('decode/build_feed', ('decode/step',)),
     ('decode/advance', ('decode/step',)),
-    ('decode/dispatch', ('decode/step', 'decode/prefill_slice')),
+    # the slices several requests have due in one tick ride ONE call of
+    # the row program, which follows their spans: in the tick's own
+    ('decode/dispatch', ('decode/step', 'decode/prefill_slice',
+                         'decode/tick')),
     # a slice is dispatched inside its span and never waited for there;
     # a prompt's last slice is read at the end of the NEXT tick, in no
     # span but the tick's
@@ -227,25 +230,38 @@ def test_step_d2h_bytes_is_the_ids(decode_trace):
     assert {s[4]['bytes'] for s in step} == {SLOTS * 4}
     assert {s[4]['fetch'] for s in decode_trace.named('decode/d2h')} \
         == {'ids'}
-    # a prefill slice's copy is its one id
-    assert {s[4]['bytes'] for s in decode_trace.named('decode/d2h')
-            if s[4]['program'].startswith('chunk_')} == {4}
+    # a prefill slice's copy is its one id, the row program's its four
+    assert {(s[4]['program'], s[4]['bytes'])
+            for s in decode_trace.named('decode/d2h')
+            if s[4]['program'].startswith('chunk_')} \
+        == {('chunk_4', 4), ('chunk_8x4', 16)}
     programs = {s[4]['program'] for s in decode_trace.named('decode/dispatch')}
-    assert {'step', 'chunk_4', 'chunk_8', 'zeros'} <= programs
+    assert {'step', 'chunk_4', 'chunk_8x4', 'zeros'} <= programs
     ticks = [s[4]['tick'] for s in decode_trace.named('decode/tick')]
     assert ticks == sorted(ticks) and len(set(ticks)) == len(ticks)
 
 
 def test_a_slice_is_dispatched_and_only_a_prompts_last_is_read(decode_trace):
     """Inside its span a slice is a dispatch and nothing else — no wait,
-    no copy; `last` says whether the tick will read it, and the reads of
-    the trace are exactly the prompts' last slices."""
+    no copy — or, where the tick has several slices of the largest bucket
+    due and they ride one call of the row program, its bookkeeping alone:
+    the short prompt's slice (the small bucket's) is a call of its own
+    inside its span, the two others' first slices are one call of two
+    rows behind their spans, the long prompt's second slice a call of its
+    own again. `last` says whether the tick will read it, and the reads
+    of the trace are exactly the calls that held a prompt's last slice."""
     slices = decode_trace.named('decode/prefill_slice')
     assert {s[4]['last'] for s in slices} == {0, 1}
+    held = []
     for sl in slices:
-        assert len(decode_trace.inside(sl, 'decode/dispatch')) == 1
+        held.append(len(decode_trace.inside(sl, 'decode/dispatch')))
         assert not decode_trace.inside(sl, 'decode/device_wait')
         assert not decode_trace.inside(sl, 'decode/d2h')
+    assert held == [1, 0, 0, 1]
+    calls = [s[4] for s in decode_trace.named('decode/dispatch')
+             if s[4]['program'].startswith('chunk_')]
+    assert [(c['program'], c['rows']) for c in calls] \
+        == [('chunk_4', 1), ('chunk_8x4', 2), ('chunk_4', 1)]
     reads = [s for s in decode_trace.named('decode/d2h')
              if s[4]['program'].startswith('chunk_')]
     assert len(reads) == sum(s[4]['last'] for s in slices) == 3
@@ -338,7 +354,8 @@ def test_a_dispatch_says_what_it_handed_over(decode_trace):
     (step,) = by_program['step']
     assert step[0] == 3 and step[1] > SLOTS * (CACHE // 4) * 4
     assert by_program['zeros'] == {(1, 4)}
-    assert {n for n, _ in by_program['chunk_8']} == {5}
+    assert {n for n, _ in by_program['chunk_4']} == {5}
+    assert {n for n, _ in by_program['chunk_8x4']} == {5}
 
 
 @pytest.mark.parametrize('name', ['decode/tick', 'decode/dispatch',
