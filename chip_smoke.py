@@ -42,6 +42,17 @@ this one process — a chip belongs to one process at a time):
               step's attention the paged kernel with grouped heads and the
               window, the window layers on their own block table.
 
+  J  latent   JoyAI-LLM-Flash at its published widths (hidden 2048, 32 heads
+              of 128 + 64 rotary, q_lora_rank 1536, kv_lora_rank 512, a
+              dense leading layer of 7,168, 32 of 256 experts of 768 top-8
+              + the shared expert, 16,160 vocabulary rows; 5 layers, 4
+              slots of 2048 positions): the same comparison against
+              benchmark/reference/joyai_llm_flash.py, whose attention is
+              the EXPANDED form while the programs absorb; the step's
+              attention the latent paged kernel (one 640-wide pool a
+              layer), which is also run against its jnp body at the
+              benchmark's shape (128 slots, 288 pages a slot, bfloat16).
+
 Weights and data are random from fixed seeds; depth is what the builders
 give. One JSON line per phase (platform, device_kind, device count, cache
 directory, XLA compiles and cache hits inside the phase, seconds), non-zero
@@ -93,6 +104,15 @@ FULL = {
                    chunk_sizes=(128, 512)),
     'exaone_prompts': (100, 1500), 'exaone_new': 64,
     'exaone_seeds': (30, 31),
+    # JoyAI-LLM-Flash, the benchmark configuration's share and widths
+    'joyai': dict(vocab=16160, d_model=2048, n_head=32, q_lora_rank=1536,
+                  kv_lora_rank=512, d_nope=128, d_rope=64, d_v=128,
+                  n_layer=5, d_dense=7168, n_expert=256, n_held=32,
+                  expert_offset=0, d_expert=768, top_k=8, max_slots=4,
+                  max_cache_len=2048, block_size=16, chunk_sizes=(128, 512)),
+    'joyai_prompts': (100, 1500), 'joyai_new': 64, 'joyai_seeds': (40, 41),
+    'latent_paged': dict(slots=128, num_blocks=36865, block_size=16,
+                         width=640, v_width=512, n_head=32, max_blocks=288),
 }
 TOY = {
     'resnet': dict(dshape=(3, 32, 32), class_dim=10, depth=50, batch=8),
@@ -112,6 +132,14 @@ TOY = {
                    expert_offset=4, d_expert=32, top_k=4, max_slots=4,
                    max_cache_len=96, block_size=8, chunk_sizes=(8, 16)),
     'exaone_prompts': (5, 60), 'exaone_new': 8, 'exaone_seeds': (30,),
+    'joyai': dict(vocab=128, d_model=64, n_head=4, q_lora_rank=24,
+                  kv_lora_rank=32, d_nope=16, d_rope=8, d_v=16, n_layer=3,
+                  d_dense=96, n_expert=16, n_held=4, expert_offset=4,
+                  d_expert=32, top_k=4, max_slots=4, max_cache_len=96,
+                  block_size=8, chunk_sizes=(8, 16)),
+    'joyai_prompts': (5, 60), 'joyai_new': 8, 'joyai_seeds': (40,),
+    'latent_paged': dict(slots=16, num_blocks=321, block_size=16, width=256,
+                         v_width=128, n_head=4, max_blocks=20),
 }
 # Phase M's bound, at published widths on the chip, on the MEDIAN over the
 # compared rows of a row's largest |served logit - reference logit|. The
@@ -138,6 +166,16 @@ OLMOE_LOGIT_TOL = 0.027
 # expert among its eight, so that expert's whole term comes or goes under
 # the post-norm.
 EXAONE_LOGIT_TOL = 0.040
+# Phase J's bound, the same quantity for the JoyAI-LLM-Flash share at
+# published widths (5 layers, 32 of 256 experts, 16,160 of 129,280 rows; my
+# chip run, PR 40, 512 rows over two seeds, logits of standard deviation
+# 0.91): the served programs — the attention ABSORBED, both its products on
+# bfloat16 operands, against a reference that expands — give 0.0245 and
+# 0.0245, the reference one precision down 0.0476 and 0.0484, which has to
+# fail; the bound is their geometric mean. The median again: 12-19 rows in
+# 256 are off by 0.39-0.52 in BOTH precisions, a held expert's term coming
+# or going at a near tie (32 of 256 experts held, four routed layers).
+JOYAI_LOGIT_TOL = 0.034
 # a phase that warns one of these did not run the path it claims to prove
 FALLBACK = re.compile(r'fall(ing|s)? back|fallback|unusable|unavailable',
                       re.I)
@@ -399,6 +437,14 @@ class Smoke(object):
         sigmoid router over the held experts, the shared expert."""
         return self._logit_phase('exaone', EXAONE_LOGIT_TOL)
 
+    def phase_j(self):
+        """JoyAI-LLM-Flash at published widths: the absorbed latent
+        attention over one pool a layer against the reference's expanded
+        form, and the latent paged kernel against its jnp body."""
+        out = self._logit_phase('joyai', JOYAI_LOGIT_TOL)
+        out['latent_paged_attention'] = self._latent_paged()
+        return out
+
     def _logit_phase(self, model, bound):
         out = {'bound': bound, 'seeds': []}
         for seed in self.cfg[model + '_seeds']:
@@ -431,6 +477,12 @@ class Smoke(object):
             from models.olmoe import build_decode_spec
             kw = dict(n_head=d['n_head'], n_layer=d['n_layer'],
                       top_k=d['top_k'])
+        elif model == 'joyai':
+            from benchmark.reference import joyai_llm_flash as reference
+            from models.joyai_llm_flash import build_decode_spec
+            kw = dict({k: d[k] for k in ('n_head', 'd_nope', 'd_rope', 'd_v',
+                                         'n_layer', 'top_k',
+                                         'expert_offset')}, first_dense=1)
         else:
             from benchmark.reference import exaone_moe as reference
             from models.exaone_moe import build_decode_spec, layer_types
@@ -471,13 +523,14 @@ class Smoke(object):
             attention = pred.stats.snapshot()['attention']
             tokens, logits = served_logits(pred, prompts,
                                            self.cfg[model + '_new'])
-        if self.cfg is FULL and attention != 'kernel':
+        if self.cfg is FULL and attention != (
+                'latent_kernel' if model == 'joyai' else 'kernel'):
             raise AssertionError('the step serves the %s attention body, '
                                  'not the paged kernel' % attention)
         want, low, gaps = [], [], []
         for p, t in zip(prompts, tokens):
             seq = np.concatenate([p, np.asarray(t[:-1], np.int64)])
-            if model == 'exaone':
+            if model in ('exaone', 'joyai'):
                 lg, gap = reference_logits(weights, seq, routing_gaps=True)
                 gaps.append(np.asarray(gap)[len(p) - 1:])
             else:
@@ -622,12 +675,7 @@ class Smoke(object):
         last = MAXB * BS - 1
         live = [0, last, BS - 1, BS, BS + 1, 255, 256] + [
             int(x) for x in rng.randint(0, last + 1, S // 2 - 7)]
-        pos = np.zeros(S, np.int32)
-        table = np.zeros((S, MAXB), np.int32)
-        free = iter(rng.permutation(np.arange(1, NB)))
-        for s, p in zip(rng.permutation(S)[:len(live)], live):
-            pos[s] = p
-            table[s, :p // BS + 1] = [next(free) for _ in range(p // BS + 1)]
+        pos, table = _ragged_slots(rng, live, S, NB, BS, MAXB)
         args = (q, kc, vc, jnp.asarray(pos), jnp.asarray(table))
         ctx = types.SimpleNamespace(         # core/lowering.py OpCtx
             attr=lambda name, default=None: {'n_head': H}.get(name, default),
@@ -666,14 +714,94 @@ class Smoke(object):
                 'max_abs_err': err, 'max_rel_err': rel}
 
 
+    def _latent_paged(self):
+        """kv_block_attention over a LATENT pool (attr v_width, K and V
+        the same bfloat16 pages), the op as a program lowers it — on a
+        TPU the compiled program must hold the latent paged kernel —
+        against its jnp body in float32 over the same bfloat16 rows.
+        Slots and pages as _paged has them."""
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        from paddle_tpu.ops import decode_ops
+        from paddle_tpu.ops import pallas_paged_attention as ppa
+        c = self.cfg['latent_paged']
+        S, NB, BS, W, DV, H, MAXB = (c[k] for k in (
+            'slots', 'num_blocks', 'block_size', 'width', 'v_width',
+            'n_head', 'max_blocks'))
+        keys = jax.random.split(jax.random.key(40), 2)
+        pool = jax.random.normal(keys[0], (NB, BS, W), jnp.bfloat16)
+        q = jax.random.normal(keys[1], (S, H * W), jnp.float32)
+        rng = np.random.RandomState(40)
+        last = MAXB * BS - 1
+        live = [0, last, BS - 1, BS, 255, 256] + [
+            int(x) for x in rng.randint(0, last + 1, S // 2 - 6)]
+        pos, table = _ragged_slots(rng, live, S, NB, BS, MAXB)
+        args = (q, pool, jnp.asarray(pos), jnp.asarray(table))
+        attrs = {'n_head': H, 'n_kv_head': 1, 'v_width': DV,
+                 'scale': 192 ** -0.5}
+        ctx = types.SimpleNamespace(
+            attr=lambda name, default=None: attrs.get(name, default),
+            abstract=False,
+            tracer=types.SimpleNamespace(lowered_bodies=[]))
+
+        def op(q, pool, pos, table):
+            return decode_ops._kv_block_attention(
+                ctx, {'Q': [q], 'KCache': [pool], 'VCache': [pool],
+                      'Pos': [pos], 'BlockTable': [table]})['Out'][0]
+
+        if self.dev.platform == 'tpu':
+            kernel = jax.jit(op).lower(*args).compile()
+            if 'kv_block_latent_paged_attention' not in kernel.as_text():
+                raise AssertionError('the op compiled for the TPU does not '
+                                     'hold the latent paged kernel')
+        else:
+            kernel = jax.jit(lambda *a: ppa.latent_paged_attention(
+                *a, n_head=H, v_width=DV, scale=attrs['scale'],
+                interpret=True))
+        got = np.asarray(kernel(*args))
+        with jax.default_matmul_precision('highest'):
+            want = np.asarray(jax.jit(
+                lambda q, pool, pos, table:
+                decode_ops._kv_block_attention_jnp(ctx, q, pool, pool, pos,
+                                                   table))(*args))
+        if not np.isfinite(got).all():
+            raise AssertionError('latent paged kernel: non-finite output')
+        err = float(np.abs(got - want).max())
+        rel = err / float(np.abs(want).max())
+        # the kernel rounds the query and the softmax weights to bfloat16
+        # (2^-9 each) under float32 sums; a dropped page or one row too
+        # many moves a slot's output by far more
+        if rel > 2e-2:
+            raise AssertionError('latent paged kernel vs jnp body: max abs '
+                                 '%.3g, relative %.3g' % (err, rel))
+        return {'shape': [S, NB, BS, W, DV, H, MAXB],
+                'live_slots': len(live), 'max_abs_err': err,
+                'max_rel_err': rel}
+
+
+def _ragged_slots(rng, live, S, NB, BS, MAXB):
+    """(pos [S], table [S, MAXB]): the positions `live` on as many slots
+    chosen at random, each with its pages drawn from the shuffled pool;
+    the other slots idle at position 0 on the trash block."""
+    import numpy as np
+    pos = np.zeros(S, np.int32)
+    table = np.zeros((S, MAXB), np.int32)
+    free = iter(rng.permutation(np.arange(1, NB)))
+    for s, p in zip(rng.permutation(S)[:len(live)], live):
+        pos[s] = p
+        table[s, :p // BS + 1] = [next(free) for _ in range(p // BS + 1)]
+    return pos, table
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.split('\n\n')[0])
     ap.add_argument('--out', default=os.path.join(HERE, 'chip_smoke_out'),
                     help='directory for artifacts and lines.jsonl')
     ap.add_argument('--cpu-rehearsal', action='store_true',
                     help='toy sizes on the host cpu; never a chip pass')
-    ap.add_argument('--phases', default='ACBMXK',
-                    help='the phases to run, of A C B M X K (C needs 4 '
+    ap.add_argument('--phases', default='ACBMXJK',
+                    help='the phases to run, of A C B M X J K (C needs 4 '
                     'chips)')
     args = ap.parse_args(argv)
     if args.cpu_rehearsal:
@@ -707,7 +835,7 @@ def main(argv=None):
 
     smoke = Smoke(TOY if args.cpu_rehearsal else FULL, args.out, devs[0],
                   len(devs))
-    for name in 'ACBMXK':
+    for name in 'ACBMXJK':
         if name in args.phases.upper() and (name != 'C' or len(devs) >= 4):
             smoke.phase(name, getattr(smoke, 'phase_' + name.lower()))
     result = {'ok': not args.cpu_rehearsal, 'phases': args.phases.upper(),

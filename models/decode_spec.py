@@ -13,6 +13,13 @@ in, a sliding-window layer's (`window_layers`, `window`) in a pool of
 their own, addressed through a second table feed ('window_tables' /
 'window_table') from which the scheduler drops what the window has
 passed. A model with full layers only has neither the pool nor the feed.
+
+What a layer caches is said ONCE, by `DecodeSpecBuilder.cache_names`: a K
+pool and a V pool, each `kv_width` wide, or — with `v_width` — ONE pool
+whose `kv_width`-wide row is key and value both (a latent row: the value is
+its first `v_width` channels; models/joyai_llm_flash.py). `_caches`,
+`write`, `attend`, the spec's 'cache_vars' and through them the export's
+state programs all follow that list.
 """
 from __future__ import annotations
 
@@ -82,7 +89,7 @@ class DecodeSpecBuilder(object):
     def __init__(self, vocab, d_model, kv_width, n_layer, max_slots,
                  max_cache_len, block_size, chunk_sizes, num_blocks, eos_id,
                  kv_cache_dtype, weights_dtype, rms_eps, init_std,
-                 window_layers=(), window=0):
+                 window_layers=(), window=0, v_width=0):
         if kv_cache_dtype not in ('float32', 'bfloat16'):
             raise ValueError("kv_cache_dtype must be 'float32' or "
                              "'bfloat16', got %r" % (kv_cache_dtype,))
@@ -105,6 +112,9 @@ class DecodeSpecBuilder(object):
         self.eos_id = int(eos_id)
         self.kv_cache_dtype, self.weights_dtype = kv_cache_dtype, weights_dtype
         self.rms_eps, self.init_std = float(rms_eps), float(init_std)
+        self.v_width = int(v_width)
+        if not 0 <= self.v_width <= self.kv_width:
+            raise ValueError('v_width must be in [0, kv_width]')
         self.window_layers = frozenset(int(i) for i in window_layers)
         self.window = int(window) if self.window_layers else 0
         if self.window_layers and self.window < 1:
@@ -146,6 +156,9 @@ class DecodeSpecBuilder(object):
         return fluid.layers.cast(x, 'float32')
 
     def cache_names(self, i):
+        """Layer i's pools, in the order `write` takes their rows."""
+        if self.v_width:
+            return ['kv_c_%d' % i]
         return ['kv_k_%d' % i, 'kv_v_%d' % i]
 
     def _caches(self, i):
@@ -157,21 +170,30 @@ class DecodeSpecBuilder(object):
             default_initializer=zero) for name in self.cache_names(i))
 
     # -- the cache ops of the program being built ------------------------
-    def write(self, i, k, v):
-        """Layer i's pool with this program's K and V rows written:
-        (kcache, vcache)."""
-        kcache, vcache = self._caches(i)
+    def write(self, i, *rows):
+        """Layer i's pools with this program's rows written, one tensor
+        of rows a pool (cache_names' order: K and V, or the one latent
+        row): the pools, a tuple as long."""
+        caches = self._caches(i)
+        if len(rows) != len(caches):
+            raise ValueError('layer %d keeps %d pool(s) %r, got %d tensors '
+                             'of rows' % (i, len(caches),
+                                          self.cache_names(i), len(rows)))
         write = self._io['write']
         kind = i in self.window_layers
-        return write(kcache, k, kind), write(vcache, v, kind)
+        return tuple(write(c, r, kind) for c, r in zip(caches, rows))
 
-    def attend(self, i, q, kcache, vcache, n_head, n_kv_head=None):
-        """Layer i's attention over its pool (inside its window, if it
-        is a window layer)."""
+    def attend(self, i, q, kcache, vcache, n_head, n_kv_head=None,
+               scale=None):
+        """Layer i's attention over its pools (inside its window, if it
+        is a window layer); over a latent pool — `kcache` and `vcache`
+        the same pool — the op is told where in the row the value
+        lies."""
         kind = i in self.window_layers
+        latent = {'v_width': self.v_width} if self.v_width else {}
         return self._io['attend'](
             q, kcache, vcache, kind, n_head=n_head, n_kv_head=n_kv_head,
-            window=self.window if kind else 0)
+            window=self.window if kind else 0, scale=scale, **latent)
 
     # -- the programs ----------------------------------------------------
     def build(self, block, logits):
@@ -269,6 +291,8 @@ class DecodeSpecBuilder(object):
                 'max_slots': S, 'max_cache_len': self.T,
                 'eos_id': self.eos_id, 'vocab': self.vocab,
                 'kv_cache_dtype': self.kv_cache_dtype}
+        if self.v_width:
+            spec['cache_kind'] = 'latent'
         if chunk_rows is not None:
             spec['chunk_rows'] = chunk_rows
         if windowed:

@@ -338,6 +338,11 @@ class DecodeStats(object):
         # its reset_counters, so reset() covers the merged counters too
         self.block_source = None
         self.block_reset = None
+        # what the pools hold (pool_facts of the loaded signature): bytes
+        # one cached position takes over all layers, and the pools' bytes
+        # by kind ('kv', 'latent', 'window')
+        self.cache_row_bytes = 0
+        self.pool_bytes = {}
         self.cow_blocks = 0      # blocks copied for beam copy-on-write
         self.blockcopies = 0     # block-copy dispatches
         # chunked-prefill slices: one per slice of ONE request's prompt,
@@ -541,6 +546,8 @@ class DecodeStats(object):
                 return snap
         # outside the stats lock: the BlockManager takes its own
         bs = self.block_source()
+        snap['cache_row_bytes'] = int(self.cache_row_bytes)
+        snap['pool_bytes'] = dict(self.pool_bytes)
         snap['blocks_in_use'] = int(bs['blocks_in_use'])
         snap['blocks_peak'] = int(bs['blocks_peak'])
         snap['blocks_total'] = int(bs['num_blocks'])
@@ -554,6 +561,26 @@ class DecodeStats(object):
             if k in bs:
                 snap[k] = int(bs[k])
         return snap
+
+
+def pool_facts(sig):
+    """(bytes one cached position takes over all of a decode artifact's
+    pools, {kind: the pools' bytes}) from its signature's state list —
+    every entry but the ids row is a pool [blocks, block_size, (width)].
+    Kinds: 'window' for the sliding-window layers' pools, else what the
+    export wrote as the block's 'cache_kind' ('latent': one row a
+    position that is key and value both), else 'kv'."""
+    block = sig.get('block', {})
+    window = set((block.get('window') or {}).get('cache_vars', ()))
+    kind = block.get('cache_kind', 'kv')
+    row, pools = 0, {}
+    for e in sig['state'][:-1]:
+        shape = [int(n) for n in e['shape']]
+        cell = np.dtype(e['dtype']).itemsize * int(np.prod(shape[2:]))
+        row += cell
+        k = 'window' if e['name'] in window else kind
+        pools[k] = pools.get(k, 0) + cell * shape[0] * shape[1]
+    return row, pools
 
 
 class TokenStream(object):
@@ -1193,8 +1220,9 @@ class DecodingPredictor(object):
         self.stats.tier = ('int8' if self._sig.get('kv_cache_dtype')
                            == 'int8' else 'bf16')
         step_bodies = self.attention_bodies.get('step', {})
-        if set(step_bodies.get('kv_block_attention', ())) == {'kernel'}:
-            self.stats.attention = 'kernel'
+        kinds = set(step_bodies.get('kv_block_attention', ()))
+        if kinds in ({'kernel'}, {'latent_kernel'}):
+            self.stats.attention = kinds.pop()
         self._params = self._load_weights(artifact_dir)
         with _span('load/reset_state'):
             self._reset_state()
@@ -1219,7 +1247,9 @@ class DecodingPredictor(object):
         'chunk_<C>x<R>') as THIS
         platform runs them: what export_decode
         wrote into the signature, with 'kernel' — the body a module
-        holds for a TPU — read as 'jnp' anywhere else. Empty for an
+        holds for a TPU — read as 'jnp' anywhere else (over a latent
+        pool, one row a position with the values inside it:
+        'latent_kernel' / 'latent_jnp'). Empty for an
         artifact exported before the signature carried it."""
         import jax
         sig = self._sig
@@ -1236,8 +1266,13 @@ class DecodingPredictor(object):
             if not bodies:
                 continue
             if platform != 'tpu':
-                bodies = {op: {'jnp': sum(by_body.values())}
-                          for op, by_body in bodies.items()}
+                merged = {}
+                for op, by_body in bodies.items():
+                    here = merged.setdefault(op, {})
+                    for body, n in by_body.items():
+                        body = body.replace('kernel', 'jnp')
+                        here[body] = here.get(body, 0) + n
+                bodies = merged
             out[name] = bodies
         return out
 
@@ -1508,6 +1543,8 @@ class DecodingPredictor(object):
         # stats.snapshot() (serving_report's block columns)
         self.stats.block_source = self._blocks.stats
         self.stats.block_reset = self._blocks.reset_counters
+        self.stats.cache_row_bytes, self.stats.pool_bytes = pool_facts(
+            self._sig)
 
     def _ask(self, fetches, program, logits):
         """Ask for the device-to-host copy of one dispatch's ids (fetch

@@ -427,6 +427,11 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                    program: the pool ([num_blocks, block_size, ...]),
                    addressed through the block tables fed at dispatch
                    time.
+      cache_kind   (optional) what a pool's row is where it is not a K
+                   or a V row: 'latent' (ONE pool a layer whose row is key
+                   and value both; models/decode_spec.py). Written into
+                   the signature's block entry; the loader's stats report
+                   the pools' bytes under it.
       block_size / num_blocks / max_blocks_per_slot /
       max_slots / max_cache_len / eos_id / vocab.
       window       (optional) {'length', 'num_blocks', 'cache_vars'}:
@@ -668,6 +673,9 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     if verify is not None:
         sig['verify'] = dict(sigs[_decoding._VERIFY_DIR],
                              draft_k=int(spec['draft_k']))
+    if spec.get('cache_kind'):
+        # what a pool's row IS, where it is not a K or a V row: 'latent'
+        sig['block']['cache_kind'] = str(spec['cache_kind'])
     if window is not None:
         sig['block']['window'] = {
             'length': int(window['length']),

@@ -1547,17 +1547,22 @@ def rms_norm(input, epsilon=1e-5, param_attr=None, name=None):
     return out
 
 
-def rotary_embedding(input, pos, n_head, theta=10000.0):
+def rotary_embedding(input, pos, n_head, theta=10000.0, interleave=False):
     """Rotate-half rotary position embedding of `input` [..., n_head *
     d_head] at the FED positions `pos` (one per row of input's leading
     axes, any integer shape of that many elements): within each head,
     channel i of the first half pairs with channel i of the second and
-    the pair turns by pos * theta^(-2i / d_head). float32 out."""
+    the pair turns by pos * theta^(-2i / d_head). With `interleave` the
+    pairs are the neighbouring channels (2i, 2i + 1), turned in place.
+    float32 out."""
     helper = LayerHelper('rotary_embedding')
     out = helper.create_variable_for_type_inference('float32')
+    attrs = {'n_head': int(n_head), 'theta': float(theta)}
+    if interleave:      # an op without it is the one every program has held
+        attrs['interleave'] = True
     helper.append_op(type='rotary_embedding',
                      inputs={'X': input, 'Pos': pos}, outputs={'Out': out},
-                     attrs={'n_head': int(n_head), 'theta': float(theta)})
+                     attrs=attrs)
     return out
 
 
@@ -1696,8 +1701,17 @@ def kv_block_write(cache, kv, pos, block_table):
     return cache
 
 
+def _attention_attrs(n_head, n_kv_head, window, scale, v_width):
+    attrs = {'n_head': int(n_head), 'n_kv_head': int(n_kv_head or n_head),
+             'window': int(window), 'scale': float(scale or 0.0)}
+    if v_width:     # an op without it is the one every program has held
+        attrs['v_width'] = int(v_width)
+    return attrs
+
+
 def kv_block_attention(query, k_cache, v_cache, pos, block_table,
-                       n_head, scale=None, n_kv_head=None, window=0):
+                       n_head, scale=None, n_kv_head=None, window=0,
+                       v_width=0):
     """One-token-per-slot attention over the block-paged cache: `query`
     [max_slots, d] attends its own slot's logically-ordered block view
     (rows j <= pos) through `block_table`. Rows beyond get exactly-zero
@@ -1714,7 +1728,13 @@ def kv_block_attention(query, k_cache, v_cache, pos, block_table,
     d_head wide, `query` n_head * d_head, and query head h reads K/V
     head h // (n_head // n_kv_head). `window` w > 0 attends only the
     rows pos - w < j <= pos — the table then needs to name the slot's
-    own blocks only from the one that holds pos - w + 1 on."""
+    own blocks only from the one that holds pos - w + 1 on.
+    `v_width` > 0: a head's VALUE is the first v_width channels of its K
+    row — a LATENT pool, `k_cache` and `v_cache` the same variable,
+    n_kv_head 1: every query head (d_head wide, the key up-projection
+    folded into it) scores the whole row and sums that part of it; the
+    output is n_head * v_width wide. On a TPU such a pool of whole-tile
+    rows is read by a kernel of its own, once a page."""
     helper = LayerHelper('kv_block_attention')
     out = helper.create_variable_for_type_inference(query.dtype)
     helper.append_op(type='kv_block_attention',
@@ -1722,10 +1742,8 @@ def kv_block_attention(query, k_cache, v_cache, pos, block_table,
                              'VCache': v_cache, 'Pos': pos,
                              'BlockTable': block_table},
                      outputs={'Out': out},
-                     attrs={'n_head': int(n_head),
-                            'n_kv_head': int(n_kv_head or n_head),
-                            'window': int(window),
-                            'scale': float(scale or 0.0)})
+                     attrs=_attention_attrs(n_head, n_kv_head, window,
+                                            scale, v_width))
     out.stop_gradient = True
     return out
 
@@ -1747,7 +1765,8 @@ def kv_block_chunk_write(cache, kv, start, block_table):
 
 
 def kv_block_chunk_attention(query, k_cache, v_cache, start, block_table,
-                             n_head, scale=None, n_kv_head=None, window=0):
+                             n_head, scale=None, n_kv_head=None, window=0,
+                             v_width=0):
     """Chunked-prefill attention: chunk row i ([1, chunk, d] `query`)
     attends the slot's block view rows j <= start + i — causal within
     the chunk AND over every previously written position (earlier
@@ -1760,7 +1779,9 @@ def kv_block_chunk_attention(query, k_cache, v_cache, start, block_table,
     Over the gathered view the leading dimension may be R rows (`query`
     [R, chunk, d], `start` [R, 1], `block_table` [R, max_blocks]): row r
     is one slot's chunk, attended through table row r — the same
-    function per row; the other body takes one row."""
+    function per row; the other body takes one row. `v_width` as
+    kv_block_attention's (a latent pool: the paged body, absorbed like
+    the step's)."""
     helper = LayerHelper('kv_block_chunk_attention')
     out = helper.create_variable_for_type_inference(query.dtype)
     helper.append_op(type='kv_block_chunk_attention',
@@ -1768,10 +1789,8 @@ def kv_block_chunk_attention(query, k_cache, v_cache, start, block_table,
                              'VCache': v_cache, 'Start': start,
                              'BlockTable': block_table},
                      outputs={'Out': out},
-                     attrs={'n_head': int(n_head),
-                            'n_kv_head': int(n_kv_head or n_head),
-                            'window': int(window),
-                            'scale': float(scale or 0.0)})
+                     attrs=_attention_attrs(n_head, n_kv_head, window,
+                                            scale, v_width))
     out.stop_gradient = True
     return out
 

@@ -473,6 +473,37 @@ def _head_attrs(ctx, d):
     return n_head, n_kv, dh, scale, int(ctx.attr('window', 0) or 0)
 
 
+def _v_width(ctx, dh):
+    """How many of a K/V head's d_head channels are its VALUE: attr
+    v_width (default: all of them, and then V is a cache of its own). A
+    LATENT pool (one [.., latent + rotary] row a position that every
+    query head reads: n_kv_head 1, KCache and VCache the same pool) scores
+    over the whole row and sums its first v_width channels — attention in
+    the absorbed form, the up-projections folded into the query and the
+    output by the program around the op."""
+    dv = int(ctx.attr('v_width', 0) or 0)
+    if not dv:
+        return dh
+    if not 0 < dv < dh:
+        raise ValueError('v_width %d is not in (0, d_head = %d): a row '
+                         'that is all value is a V pool\'s' % (dv, dh))
+    # the attribute ALONE says the pool is latent (the bodies and the
+    # step's kernel are chosen by it), so what it promises is held here,
+    # by name, and not found out from which body a trace happened to take
+    n_kv, window = (int(ctx.attr(a, 0) or 0) for a in ('n_kv_head', 'window'))
+    if n_kv != 1 or window:
+        raise NotImplementedError(
+            'a latent pool (attr v_width) has ONE K/V head and no window, '
+            'not n_kv_head=%d, window=%d' % (n_kv, window))
+    op = getattr(ctx, 'op', None)
+    if op is not None and op.input('KCache') != op.input('VCache'):
+        raise ValueError(
+            'a latent pool (attr v_width) is key and value both: KCache %r '
+            'and VCache %r are two variables'
+            % (op.input('KCache'), op.input('VCache')))
+    return dv
+
+
 def _in_window(j, pos, window):
     """Which cache rows j a query at position pos attends."""
     valid = j <= pos
@@ -488,11 +519,14 @@ def _paged_attention_body(ctx, q, kc, vc, pos):
     only by the dequant of its operands."""
     s, t, d = kc.shape
     n_head, n_kv, dh, scale, window = _head_attrs(ctx, d)
-    if n_kv != n_head or window:
+    dv = _v_width(ctx, dh)
+    if n_kv != n_head or window or dv != dh:
         g = n_head // n_kv
         qh = q.reshape(s, n_kv, g, dh)
         kh = kc.reshape(s, t, n_kv, dh)
         vh = vc.reshape(s, t, n_kv, dh)
+        if dv != dh:
+            vh = vh[..., :dv]
         scores = jnp.einsum('skgd,stkd->skgt', qh, kh) * scale
         valid = _in_window(jnp.arange(t, dtype=jnp.int32)[None, :],
                            pos[:, None], window)
@@ -502,7 +536,7 @@ def _paged_attention_body(ctx, q, kc, vc, pos):
         # V must not reach the sum even at weight zero (the kernel's rule)
         vh = jnp.where(valid[:, :, None, None], vh, 0)
         ctxv = jnp.einsum('skgt,stkd->skgd', w, vh)
-        return ctxv.reshape(s, n_head * dh).astype(q.dtype)
+        return ctxv.reshape(s, n_head * dv).astype(q.dtype)
     qh = q.reshape(s, n_head, dh)
     kh = kc.reshape(s, t, n_head, dh)
     vh = vc.reshape(s, t, n_head, dh)
@@ -568,7 +602,10 @@ def _quantize_kv_rows(kv):
 # view and stay the reference the kernel is tested against. The choice
 # is made from what the lowering sees — the platform the program is
 # compiled for, the pool's dtype and page shape (ppa.supports), a trace
-# mesh — never from a knob; the op tells its Tracer (lowered_bodies) and
+# mesh — never from a knob. A LATENT pool (ISSUE 40: attr v_width, K and
+# V the same pages, every head reading one row) has a kernel of its own
+# (ppa.latent_paged_attention, ppa.refuses_latent) and, in the chunk
+# form, always the paged jnp body (_chunk_attention_blocked); the op tells its Tracer (lowered_bodies) and
 # export_decode writes it into the signature.
 # ---------------------------------------------------------------------------
 
@@ -675,7 +712,13 @@ def _kv_block_attention(ctx, ins):
     but a TPU, and on a TPU a pool the kernel cannot read or a sharded
     trace — is _paged_attention_body over the gathered view. Compiled
     for a TPU, the Pallas
-    kernel reads pages 0 .. pos // BS through the table instead."""
+    kernel reads pages 0 .. pos // BS through the table instead.
+
+    Attr v_width (_v_width): a LATENT pool — KCache and VCache the same
+    variable, n_kv_head 1, Q n_head rows as wide as the pool's, Out
+    n_head * v_width wide. Its kernel is a second one
+    (ppa.latent_paged_attention: a page copied once, multiplied twice);
+    lowered_bodies says 'latent_kernel' / 'latent_jnp'."""
     from ..parallel.mesh import current_trace_mesh
     from . import pallas_paged_attention as ppa
     q = ins['Q'][0]
@@ -683,21 +726,34 @@ def _kv_block_attention(ctx, ins):
     vc = ins['VCache'][0]
     pos = ins['Pos'][0].reshape(-1).astype(jnp.int32)
     table = ins['BlockTable'][0].astype(jnp.int32)
-    n_head, n_kv, _, scale, window = _head_attrs(ctx, kc.shape[2])
+    n_head, n_kv, dh, scale, window = _head_attrs(ctx, kc.shape[2])
+    dv = _v_width(ctx, dh)
     jnp_body = functools.partial(_kv_block_attention_jnp, ctx)
     if ctx.abstract:        # shape inference: no Tracer, and any body will do
         return {'Out': [jnp_body(q, kc, vc, pos, table)]}
-    kernel = (current_trace_mesh() is None
-              and ppa.supports(q, kc, vc, n_head, n_kv))
+    if dv == dh:
+        body = 'kernel'
+        kernel = ppa.supports(q, kc, vc, n_head, n_kv)
+        tpu = functools.partial(ppa.paged_attention, n_head=n_head,
+                                n_kv_head=n_kv, window=window, scale=scale)
+    else:
+        # the values lie inside the K rows: ONE pool (_v_width has held
+        # the op to it), copied once
+        body = 'latent_kernel'
+        kernel = ppa.refuses_latent(q, kc, n_head, dv) is None
+
+        def tpu(q, kc, vc, pos, table):
+            return ppa.latent_paged_attention(
+                q, kc, pos, table, n_head=n_head, v_width=dv, scale=scale)
+    kernel = kernel and current_trace_mesh() is None
     ctx.tracer.lowered_bodies.append(
-        ('kv_block_attention', 'kernel' if kernel else 'jnp'))
+        ('kv_block_attention',
+         body if kernel else body.replace('kernel', 'jnp')))
     if not kernel:
         return {'Out': [jnp_body(q, kc, vc, pos, table)]}
     return {'Out': [ppa.tpu_or_default(
-        q, kc, vc, pos, table, default=jnp_body,
-        tpu=functools.partial(ppa.paged_attention, n_head=n_head,
-                              n_kv_head=n_kv, window=window,
-                              scale=scale))]}
+        q, kc, vc, pos, table, default=jnp_body, tpu=tpu,
+        out_width=None if dv == dh else n_head * dv)]}
 
 
 def _chunk_attention_body(ctx, q, kview, vview, start, d):
@@ -798,12 +854,21 @@ def _chunk_attention_blocked(ctx, q, kc, vc, start, table):
     the window is long) gives zeros."""
     bs, d = kc.shape[1], kc.shape[2]
     n_head, n_kv, dh, scale, window = _head_attrs(ctx, d)
+    dv = _v_width(ctx, dh)
     c = q.shape[1]
     pages = min(max(_CHUNK_KEY_BLOCK // bs, 1), table.shape[0])
     key_block = pages * bs
     g = n_head // n_kv
     high = jax.lax.Precision.HIGHEST
-    qh = q.reshape(c, n_kv, g, dh).astype(jnp.float32)
+    # what the products multiply: float32 copies at 'highest'; over a
+    # latent pool (v_width: every head reads the whole 4.5-tile row, 4.5
+    # times a K/V row's products) the pool's own dtype, as the step's
+    # latent kernel has them — bfloat16 x bfloat16 with float32 sums
+    # where the pool is bfloat16, one MXU pass and not six
+    mult = jnp.float32 if dv == dh else kc.dtype
+    if mult != jnp.float32:
+        high = None
+    qh = q.reshape(c, n_kv, g, dh).astype(mult)
     start = start.reshape(()).astype(jnp.int32)
     rows = start + jnp.arange(c, dtype=jnp.int32)[:, None]      # [C, 1]
     first = (jnp.maximum(start - window + 1, 0) // key_block if window
@@ -818,32 +883,36 @@ def _chunk_attention_blocked(ctx, q, kc, vc, start, table):
             table.shape[0] - 1))
         kh = jnp.take(kc, page, axis=0).reshape(key_block, n_kv, dh)
         vh = jnp.take(vc, page, axis=0).reshape(key_block, n_kv, dh)
+        if dv != dh:
+            vh = vh[..., :dv]
         j = i * key_block + jnp.arange(key_block, dtype=jnp.int32)[None, :]
-        sc = jnp.einsum('ckgd,tkd->ckgt', qh, kh.astype(jnp.float32),
-                        precision=high) * scale
+        sc = jnp.einsum('ckgd,tkd->ckgt', qh, kh.astype(mult),
+                        precision=high,
+                        preferred_element_type=jnp.float32) * scale
         seen = _in_window(j, rows, window)                      # [C, kb]
         sc = jnp.where(seen[:, None, None, :], sc, -jnp.inf)
         # a position no row of the chunk attends (a page the window has
         # passed, the tail past the chunk) may hold anything: its V must
         # not reach the sum, even at weight zero
         vh = jnp.where(jnp.any(seen, axis=0)[:, None, None],
-                       vh.astype(jnp.float32), 0.0)
+                       vh.astype(mult), 0.0)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
         base = jnp.where(jnp.isneginf(m_new), 0.0, m_new)
         alpha = jnp.exp(m - base)
         p = jnp.exp(sc - base)
         l = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc = alpha * acc + jnp.einsum('ckgt,tkd->ckgd', p, vh,
-                                       precision=high)
+        acc = alpha * acc + jnp.einsum('ckgt,tkd->ckgd', p.astype(mult), vh,
+                                       precision=high,
+                                       preferred_element_type=jnp.float32)
         return m_new, l, acc
 
     init = (jnp.full((c, n_kv, g, 1), -jnp.inf, jnp.float32),
             jnp.zeros((c, n_kv, g, 1), jnp.float32),
-            jnp.zeros((c, n_kv, g, dh), jnp.float32))
+            jnp.zeros((c, n_kv, g, dv), jnp.float32))
     _, l, acc = jax.lax.fori_loop(first, (start + c - 1) // key_block + 1,
                                   block, init)
     out = acc / jnp.where(l == 0.0, 1.0, l)
-    return out.reshape(1, c, n_head * dh).astype(q.dtype)
+    return out.reshape(1, c, n_head * dv).astype(q.dtype)
 
 
 @register('kv_block_chunk_write', no_grad=True, lod='none')
@@ -893,7 +962,9 @@ def _kv_block_chunk_attention(ctx, ins):
     view's [R, C, n_head, T'] float32 scores fit _CHUNK_SCORES_BYTES
     (_gathered_view_fits); else the pages a block of positions at a time
     under an online softmax (_chunk_attention_blocked): another
-    summation order. Only the gathered view has rows: with R = 1 it is
+    summation order; a latent pool (attr v_width) takes the second
+    always, both products on operands of the pool's dtype. Only the
+    gathered view has rows: with R = 1 it is
     the one-slot expression, unchanged, with more it is that function
     per row (one vmap); the blocked body's trip count depends on
     `start`, so it keeps R = 1 and refuses more by name."""
@@ -903,9 +974,9 @@ def _kv_block_chunk_attention(ctx, ins):
     start = ins['Start'][0]
     tables = ins['BlockTable'][0].astype(jnp.int32)
     r, d = q.shape[0], kc.shape[2]
-    n_head, n_kv, _, _, window = _head_attrs(ctx, d)
-    gathered = _gathered_view_fits(r, q.shape[1], n_head, n_kv, window,
-                                   tables.shape[1] * kc.shape[1])
+    n_head, n_kv, dh, _, window = _head_attrs(ctx, d)
+    gathered = (_v_width(ctx, dh) == dh and _gathered_view_fits(
+        r, q.shape[1], n_head, n_kv, window, tables.shape[1] * kc.shape[1]))
     if r == 1:
         table = tables[0]
         if not gathered:

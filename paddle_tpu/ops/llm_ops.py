@@ -39,10 +39,15 @@ def _rotary_embedding(ctx, ins):
     X's leading axes (any shape of that many elements): every head's
     (first half, second half) pairs rotate by pos * theta^(-2i/d_head)
     — the rotate-half convention: out = x * cos + rotate_half(x) * sin
-    with rotate_half(x) = concat(-x2, x1)."""
+    with rotate_half(x) = concat(-x2, x1). Attr interleave: the pairs are
+    the NEIGHBOURS (2i, 2i + 1) instead, each turned in place by the same
+    angle (the latent-attention family's rope_interleave; its files move
+    the pairs to the halves first, the same permutation of q's and k's
+    channels, so every q . k is the same number)."""
     x = ins['X'][0]
     n_head = int(ctx.attr('n_head'))
     theta = float(ctx.attr('theta', 10000.0))
+    interleave = bool(ctx.attr('interleave', False))
     lead, d = x.shape[:-1], x.shape[-1]
     dh = d // n_head
     pos = ins['Pos'][0].reshape(lead).astype(jnp.float32)
@@ -51,6 +56,11 @@ def _rotary_embedding(ctx, ins):
     ang = pos[..., None] * inv_freq                      # [..., dh/2]
     cos = jnp.cos(ang)[..., None, :]
     sin = jnp.sin(ang)[..., None, :]
+    if interleave:
+        xh = x.astype(jnp.float32).reshape(lead + (n_head, dh // 2, 2))
+        x1, x2 = xh[..., 0], xh[..., 1]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return {'Out': [out.reshape(x.shape)]}
     xh = x.astype(jnp.float32).reshape(lead + (n_head, 2, dh // 2))
     x1, x2 = xh[..., 0, :], xh[..., 1, :]
     out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-2)

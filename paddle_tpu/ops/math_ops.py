@@ -246,7 +246,13 @@ def _matmul(ctx, ins):
         x = jnp.swapaxes(x, -1, -2)
     if ty:
         y = jnp.swapaxes(y, -1, -2)
-    out = amp.matmul(x, y)
+    if x.dtype == jnp.float32 and y.dtype == jnp.bfloat16:
+        # mul's rule: a weight stored in bfloat16 under a float32
+        # activation multiplies as the MXU does, on the stored bytes
+        out = amp.matmul(x.astype(jnp.bfloat16), y,
+                         preferred_element_type=jnp.float32)
+    else:
+        out = amp.matmul(x, y)
     if alpha != 1.0:
         out = out * alpha
     if squeeze_out:

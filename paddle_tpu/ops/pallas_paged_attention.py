@@ -25,6 +25,24 @@ the weighted sum, so whatever a page or a stale buffer holds there (NaN
 included) never reaches the result. A slot's output depends only on its
 own pages and `pos` — not on co-resident slots, not on where its pages
 sit in the pool.
+
+A second kernel, `latent_paged_attention` (ISSUE 40), reads a LATENT pool:
+one row a position that is key and value both (every query head scores the
+whole row and sums its first v_width channels — attention in the absorbed
+form), so a page is copied once and multiplied twice, on operands of the
+pool's dtype. Same contract, same double buffering across slots; one grid
+step a slot, because n_head rows of the pool's width a slot make the whole
+batch's query too large to hold at once.
+
+What the paging contract is made of is written ONCE and both kernels call
+it: the rule a pool's pages obey (`_pool_rule`), the clamping of `pos`
+and the table (`_clamped`), a page's async copy through the table
+(`_each_page`), the order in which a block waits for its pages while the
+next ones load (`_load_next_then_wait`) and the online softmax
+(`_softmax_start`, `_softmax_step`). What differs stays in each kernel:
+which rows a slot attends (a window), how the query meets the row
+(block-diagonal heads over K and V pools; every head over one whole
+latent row) and the grid (one step for all slots; a step a slot).
 """
 from __future__ import annotations
 
@@ -48,32 +66,144 @@ _BLOCK_ROWS = 256
 _PRECISION = lax.Precision.HIGHEST
 
 
-def supports(q, k_cache, v_cache, n_head, n_kv_head=None):
-    """The shape rule: what the kernel can read. float32 or bfloat16
-    pools whose pages are whole (sublane, lane) tiles — D a multiple of
-    128 lanes, BS a multiple of the dtype's sublane packing (8 rows of
-    float32, 16 of bfloat16) — a float32 query and heads that divide D.
-    Grouped K/V heads (n_kv_head < n_head, Q wider than the pool) also
-    need a head of whole 128-lane tiles: the query heads of a group are
-    laid side by side at tile boundaries. Anything else runs the jnp
-    body."""
-    if k_cache.ndim != 3 or k_cache.shape != v_cache.shape:
-        return False
-    if k_cache.dtype != v_cache.dtype or q.dtype != jnp.float32:
-        return False
-    sublanes = {jnp.dtype(jnp.float32): 8,
-                jnp.dtype(jnp.bfloat16): 16}.get(jnp.dtype(k_cache.dtype))
+_SUBLANES = {jnp.dtype(jnp.float32): 8, jnp.dtype(jnp.bfloat16): 16}
+
+
+def _pool_rule(cache):
+    """The rule both kernels put to a pool [NB, BS, D], by the name of
+    the first part it breaks, or None: float32 or bfloat16, rows of whole
+    128-lane tiles (a page cannot be copied out of a row that ends
+    inside a tile) and pages of whole sublane tiles that divide a
+    compute block."""
+    sublanes = _SUBLANES.get(jnp.dtype(cache.dtype))
     if sublanes is None:
-        return False
-    _, bs, d = k_cache.shape
+        return 'a pool that is neither float32 nor bfloat16'
+    _, bs, d = cache.shape
+    if d % 128:
+        return 'a row width that is no multiple of 128'
+    if bs % sublanes or _BLOCK_ROWS % bs:
+        return 'pages that are not whole sublane tiles'
+    return None
+
+
+def refuses(q, k_cache, v_cache, n_head, n_kv_head=None):
+    """Why `paged_attention` cannot read these pools — the name of the
+    first rule they break — or None. float32 or bfloat16 pools whose
+    pages are whole (sublane, lane) tiles — D a multiple of 128 lanes, BS
+    a multiple of the dtype's sublane packing (8 rows of float32, 16 of
+    bfloat16) — a float32 query and heads that divide D. Grouped K/V
+    heads (n_kv_head < n_head, Q wider than the pool) also need a head of
+    whole 128-lane tiles: the query heads of a group are laid side by
+    side at tile boundaries. K and V are two pools, each copied: a row
+    that holds both (K = V pages, a latent pool) is
+    `latent_paged_attention`'s, whose rule is `refuses_latent`."""
+    if k_cache.ndim != 3 or k_cache.shape != v_cache.shape:
+        return 'K and V pools of different shapes'
+    if k_cache.dtype != v_cache.dtype or q.dtype != jnp.float32:
+        return 'pools of two dtypes, or a query that is not float32'
+    broken = _pool_rule(k_cache)
+    if broken:
+        return broken
+    d = k_cache.shape[2]
     n_kv = n_kv_head or n_head
     if n_head % n_kv or d % n_kv:
-        return False
+        return 'heads that do not divide the row'
     if n_kv != n_head and (d // n_kv) % 128:
-        return False
-    return (d % 128 == 0 and bs % sublanes == 0
-            and q.shape[-1] == n_head * (d // n_kv)
-            and _BLOCK_ROWS % bs == 0)
+        return 'grouped heads whose width is no multiple of 128'
+    if q.shape[-1] != n_head * (d // n_kv):
+        return 'a query that is not n_head heads wide'
+    return None
+
+
+def supports(q, k_cache, v_cache, n_head, n_kv_head=None):
+    """The shape rule: whether the kernel can read these pools
+    (`refuses` names what it cannot). Anything else runs the jnp
+    body."""
+    return refuses(q, k_cache, v_cache, n_head, n_kv_head) is None
+
+
+def refuses_latent(q, cache, n_head, v_width):
+    """Why `latent_paged_attention` cannot read this pool, or None: a
+    float32 or bfloat16 pool of whole (sublane, lane) tiles — a page
+    cannot be copied out of a row that ends inside a tile: the chip's
+    layout holds a 576-wide row in five 128-lane tiles and Mosaic
+    refuses the 576-of-640 slice, so a latent row is stored padded to
+    640 (models/joyai_llm_flash.py) — a float32 query of n_head rows as
+    wide as the pool's, and values that are the row's first v_width
+    channels, whole 128-lane tiles."""
+    if cache.ndim != 3 or q.dtype != jnp.float32:
+        return 'a pool that is not [blocks, rows, width], or a query that ' \
+               'is not float32'
+    broken = _pool_rule(cache)
+    if broken:
+        return broken
+    d = cache.shape[2]
+    if v_width % 128 or v_width > d:
+        return 'values that are not whole 128-lane tiles of the row'
+    if q.shape[-1] != n_head * d:
+        return 'a query that is not n_head rows of the pool\'s width'
+    return None
+
+
+def _clamped(pos, table, n_block, bs):
+    """(pos, table, pages to a compute block): the kernels index SMEM and
+    HBM with these, so they are clamped as the jnp body's take does — a
+    bad feed reads a wrong page, never past the pool."""
+    maxb = table.shape[1]
+    return (jnp.clip(pos.astype(jnp.int32), 0, maxb * bs - 1),
+            jnp.clip(table.astype(jnp.int32), 0, n_block - 1),
+            min(_BLOCK_ROWS // bs, maxb))
+
+
+def _each_page(tab_ref, first, count, pools, buf, act, bs):
+    """Start, or wait for (`act`), one async copy a page: the `count`
+    pages named by the table from entry `first` on, out of each pool of
+    `pools` — (pool in HBM, its [2, rows, D] buffer, the semaphore of a
+    half) — into rows j * bs of the buffer's half `buf`."""
+    def body(j, carry):
+        page = tab_ref[first + j]
+        for pool, dst, sem_of in pools:
+            copy = pltpu.make_async_copy(
+                pool.at[page],
+                dst.at[buf, pl.ds(pl.multiple_of(j * bs, bs), bs), :],
+                sem_of(buf))
+            getattr(copy, act)()
+        return carry
+    lax.fori_loop(0, count, body, 0)
+
+
+def _load_next_then_wait(each_page, s, i, nblk, n_slot, buf):
+    """Before block i of slot s computes out of half `buf`: what computes
+    next starts loading into the other half — this slot's next block, or
+    after its last the next slot's first — and block i's own copies are
+    waited for."""
+    last = i + 1 == nblk
+    nxt = s + last.astype(jnp.int32)
+
+    @pl.when(nxt < n_slot)
+    def _():
+        each_page(nxt, jnp.where(last, 0, i + 1), 1 - buf, 'start')
+
+    each_page(s, i, buf, 'wait')
+
+
+def _softmax_start(n_head, width):
+    """(running max, running sum, weighted sum [n_head, width]) before a
+    slot's first block."""
+    return (jnp.full((n_head, 1), -jnp.inf, jnp.float32),
+            jnp.zeros((n_head, 1), jnp.float32),
+            jnp.zeros((n_head, width), jnp.float32))
+
+
+def _softmax_step(m, l, acc, sc, weigh):
+    """The online softmax over one more block: sc [n_head, rows] its
+    scores, -inf where a row is not attended (so its weight is exactly
+    zero); weigh(p) the block's values summed under the weights p."""
+    m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+    alpha = jnp.exp(m - m_new)
+    p = jnp.exp(sc - m_new)
+    l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+    return m_new, l, alpha * acc + weigh(p)
 
 
 def _kernel(pos_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
@@ -97,22 +227,15 @@ def _kernel(pos_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
             held = held - first_page(s)
         return jnp.minimum(held - i * pages, pages)
 
+    pools = ((k_hbm, kbuf, lambda buf: sem.at[0, buf]),
+             (v_hbm, vbuf, lambda buf: sem.at[1, buf]))
+
     def each_page(s, i, buf, act):
-        """Start, or wait for, the K and V copies of block i of slot s
-        into buffer `buf`: one async copy per page."""
-        def body(j, carry):
-            page = s * maxb + i * pages + j
-            if window:
-                page = page + first_page(s)
-            page = tab_ref[page]
-            for pool, dst, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
-                copy = pltpu.make_async_copy(
-                    pool.at[page],
-                    dst.at[buf, pl.ds(pl.multiple_of(j * bs, bs), bs), :],
-                    sem.at[which, buf])
-                getattr(copy, act)()
-            return carry
-        lax.fori_loop(0, n_pages(s, i), body, 0)
+        """The K and V copies of block i of slot s into half `buf`."""
+        first = s * maxb + i * pages
+        if window:
+            first = first + first_page(s)
+        _each_page(tab_ref, first, n_pages(s, i), pools, buf, act, bs)
 
     # query head h owns the d_head lanes of K/V head h // group
     kv_head = lax.broadcasted_iota(jnp.int32, (n_head, d), 1) // dh
@@ -148,36 +271,23 @@ def _kernel(pos_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
 
         def block(i, carry):
             m, l, acc, buf = carry
-
-            # what computes next loads meanwhile: this slot's next block,
-            # or after its last the next slot's first
-            last = i + 1 == nblk
-            nxt = s + last.astype(jnp.int32)
-
-            @pl.when(nxt < n_slot)
-            def _():
-                each_page(nxt, jnp.where(last, 0, i + 1), 1 - buf, 'start')
-
-            each_page(s, i, buf, 'wait')
+            _load_next_then_wait(each_page, s, i, nblk, n_slot, buf)
             k = kbuf[buf].astype(jnp.float32)                   # [rows, D]
             sc = lax.dot_general(
                 qbd, k, (((1,), (1,)), ((), ())), precision=_PRECISION,
                 preferred_element_type=jnp.float32) * scale     # [H, rows]
             sc = jnp.where(seen(i, col), sc, -jnp.inf)
-            m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
-            alpha = jnp.exp(m - m_new)
-            p = jnp.exp(sc - m_new)
-            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-            v = jnp.where(seen(i, row), vbuf[buf].astype(jnp.float32), 0.0)
-            acc = alpha * acc + jnp.dot(
-                p, v, precision=_PRECISION,
-                preferred_element_type=jnp.float32)             # [H, D]
-            return m_new, l, acc, 1 - buf
 
-        m0 = jnp.full((n_head, 1), -jnp.inf, jnp.float32)
-        l0 = jnp.zeros((n_head, 1), jnp.float32)
-        acc0 = jnp.zeros((n_head, d), jnp.float32)
-        _, l, acc, buf = lax.fori_loop(0, nblk, block, (m0, l0, acc0, buf))
+            def weigh(p):
+                v = jnp.where(seen(i, row), vbuf[buf].astype(jnp.float32),
+                              0.0)
+                return jnp.dot(p, v, precision=_PRECISION,
+                               preferred_element_type=jnp.float32)  # [H, D]
+
+            return _softmax_step(m, l, acc, sc, weigh) + (1 - buf,)
+
+        _, l, acc, buf = lax.fori_loop(
+            0, nblk, block, _softmax_start(n_head, d) + (buf,))
         kept = jnp.where(own, acc / l, 0.0)
         if group == 1:
             o_ref[pl.ds(s, 1), :] = jnp.sum(
@@ -205,12 +315,8 @@ def paged_attention(q, k_cache, v_cache, pos, table, *, n_head, scale,
     n_block, bs, d = k_cache.shape
     n_kv_head = n_kv_head or n_head
     maxb = table.shape[1]
-    pages = min(_BLOCK_ROWS // bs, maxb)
+    pos, table, pages = _clamped(pos, table, n_block, bs)
     rows = pages * bs
-    # the kernel indexes SMEM and HBM with these: clamp as the jnp body's
-    # take does, so a bad feed reads a wrong page, never past the pool
-    pos = jnp.clip(pos.astype(jnp.int32), 0, maxb * bs - 1)
-    table = jnp.clip(table.astype(jnp.int32), 0, n_block - 1)
     kernel = functools.partial(_kernel, n_head=n_head, n_kv_head=n_kv_head,
                                window=int(window), scale=scale, bs=bs,
                                maxb=maxb, pages=pages)
@@ -240,6 +346,109 @@ def paged_attention(q, k_cache, v_cache, pos, table, *, n_head, scale,
     return out.reshape(n_slot, -1)
 
 
+def _latent_kernel(pos_ref, tab_ref, q_ref, c_hbm, o_ref, cbuf, sem, parity,
+                   *, v_width, scale, bs, maxb, pages):
+    """`_kernel` over ONE pool whose row is both key and value: every
+    query head (the rows of q_ref[0], [n_head, D]) scores the whole
+    D-wide row and sums its first v_width channels, so a page is copied
+    once and multiplied twice. One grid step a slot — n_head rows of D a
+    slot make the whole batch's query too large to hold at once, so the
+    pipeline brings a slot's in while the last one computes — with the
+    page buffers and their parity kept across steps: after a slot's last
+    block the NEXT slot's first is already loading. The products take
+    the pool's dtype as operands with float32 sums (bfloat16 x bfloat16
+    is the MXU's own product: n_head x D x 2 operations a cached row is
+    4.5 times a K/V row's, and six passes of it would be the step); a
+    float32 pool keeps full float32."""
+    s = pl.program_id(0)
+    n_slot = pl.num_programs(0)
+    _, n_head, d = q_ref.shape
+    rows = pages * bs
+    mult = cbuf.dtype
+    precision = _PRECISION if mult == jnp.float32 else None
+
+    pools = ((c_hbm, cbuf, lambda buf: sem.at[buf]),)
+
+    def each_page(s, i, buf, act):
+        """The copies of block i of slot s into half `buf`: its pages
+        that hold a position <= pos."""
+        held = pos_ref[s] // bs + 1
+        _each_page(tab_ref, s * maxb + i * pages,
+                   jnp.minimum(held - i * pages, pages), pools, buf, act, bs)
+
+    @pl.when(s == 0)
+    def _():
+        parity[0] = 0
+        each_page(0, 0, 0, 'start')
+
+    col = lax.broadcasted_iota(jnp.int32, (n_head, rows), 1)
+    row = lax.broadcasted_iota(jnp.int32, (rows, v_width), 0)
+    pos = pos_ref[s]
+    nblk = pos // rows + 1
+    q = (q_ref[0] * scale).astype(mult)                         # [H, D]
+
+    def block(i, carry):
+        m, l, acc, buf = carry
+        _load_next_then_wait(each_page, s, i, nblk, n_slot, buf)
+        c = cbuf[buf]                                           # [rows, D]
+        sc = lax.dot_general(
+            q, c, (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)                 # [H, rows]
+        sc = jnp.where(i * rows + col <= pos, sc, -jnp.inf)
+
+        def weigh(p):
+            v = jnp.where(i * rows + row <= pos, c[:, :v_width],
+                          jnp.zeros((), mult))
+            return jnp.dot(p.astype(mult), v, precision=precision,
+                           preferred_element_type=jnp.float32)  # [H, dv]
+
+        return _softmax_step(m, l, acc, sc, weigh) + (1 - buf,)
+
+    _, l, acc, buf = lax.fori_loop(
+        0, nblk, block, _softmax_start(n_head, v_width) + (parity[0],))
+    parity[0] = buf
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def latent_paged_attention(q, cache, pos, table, *, n_head, v_width, scale,
+                           interpret=False):
+    """Q [S, n_head * D] float32, the latent pool [NB, BS, D], pos [S]
+    int32, table [S, MAXB] int32 -> [S, n_head * v_width]: slot s's
+    n_head query rows each attend positions 0 .. pos[s] of its table's
+    pages, scoring over the whole row and summing its first v_width
+    channels (attention in the absorbed form: the caller has folded the
+    key up-projection into Q and unfolds the value one from the result).
+    `refuses_latent` must give None."""
+    n_slot = q.shape[0]
+    n_block, bs, d = cache.shape
+    maxb = table.shape[1]
+    pos, table, pages = _clamped(pos, table, n_block, bs)
+    rows = pages * bs
+    kernel = functools.partial(_latent_kernel, v_width=int(v_width),
+                               scale=scale, bs=bs, maxb=maxb, pages=pages)
+    q = q.reshape(n_slot, n_head, d)
+    out_shape = (n_slot, n_head, int(v_width))
+    out = pl.pallas_call(
+        kernel,
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n_slot,),
+            in_specs=[pl.BlockSpec((1, n_head, d), lambda i, *_: (i, 0, 0)),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, n_head, int(v_width)),
+                                   lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, rows, d), cache.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('arbitrary',)),
+        name='kv_block_latent_paged_attention',
+        interpret=interpret,
+    )(pos, table.reshape(-1), q, cache)
+    return out.reshape(n_slot, -1)
+
+
 # The platform switch. lax.platform_dependent (ops/quant_ops.py's idiom)
 # cannot carry a pallas_call through the cpu+tpu jax.export the decode
 # artifacts are made with (jax 0.9.0): its cond lowers every kept branch
@@ -250,19 +459,26 @@ def paged_attention(q, k_cache, v_cache, pos, table, *, n_head, scale,
 # platform holds that platform's body only.
 _attend_p = Primitive('kv_block_attention')
 _attend_p.def_abstract_eval(
-    lambda q, *_, **__: jax.core.ShapedArray(q.shape, q.dtype))
+    lambda q, *_, out_width=None, **__: jax.core.ShapedArray(
+        q.shape[:-1] + (out_width or q.shape[-1],), q.dtype))
 _attend_p.def_impl(lambda *args, **params: jax.jit(
     functools.partial(_attend_p.bind, **params))(*args))
 mlir.register_lowering(
-    _attend_p, lambda ctx, *args, tpu, default: mlir.lower_fun(
+    _attend_p, lambda ctx, *args, tpu, default, **_: mlir.lower_fun(
         tpu, multiple_results=False)(ctx, *args), platform='tpu')
 mlir.register_lowering(
-    _attend_p, lambda ctx, *args, tpu, default: mlir.lower_fun(
+    _attend_p, lambda ctx, *args, tpu, default, **_: mlir.lower_fun(
         default, multiple_results=False)(ctx, *args))
 
 
-def tpu_or_default(q, k_cache, v_cache, pos, table, *, tpu, default):
+def tpu_or_default(q, k_cache, v_cache, pos, table, *, tpu, default,
+                   out_width=None):
     """tpu(q, k_cache, v_cache, pos, table) where the program runs on a
-    TPU, default(...) anywhere else; both give [S, D] like q."""
+    TPU, default(...) anywhere else; both give [S, D] like q — or
+    [S, out_width] where the values are narrower than the keys (a latent
+    pool)."""
+    if out_width is None:
+        return _attend_p.bind(q, k_cache, v_cache, pos, table, tpu=tpu,
+                              default=default)
     return _attend_p.bind(q, k_cache, v_cache, pos, table, tpu=tpu,
-                          default=default)
+                          default=default, out_width=int(out_width))

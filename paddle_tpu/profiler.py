@@ -215,6 +215,9 @@ def serving_report():
             # them (fewer where slices rode the row program together)
             hdr += " %11s %6s %6s %6s %6s" % ('blocks', 'pfxhit', 'cow',
                                               'slices', 'calls')
+            # bytes a cached position takes over all layers, and the
+            # pools' bytes by kind (kv, latent, window)
+            hdr += " %7s %s" % ('row(B)', 'pools(MB)')
         print(hdr)
         for name, s in decode_rows:
             row = ("%-26s %5s %5d %6d %7d %8.1f %8d %6d %5.2f %5d %5.2f "
@@ -241,6 +244,10 @@ def serving_report():
                         s.get('cow_blocks', 0),
                         s.get('chunk_slices', 0),
                         s.get('chunk_dispatches', 0))
+                    row += " %7d %s" % (
+                        s.get('cache_row_bytes', 0),
+                        ' '.join('%s:%.0f' % (k, v / 1e6) for k, v in
+                                 sorted(s.get('pool_bytes', {}).items())))
                 else:
                     row += " %11s %6s %6s %6s %6s" % ('-', '-', '-', '-',
                                                       '-')
