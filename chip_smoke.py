@@ -53,6 +53,19 @@ this one process — a chip belongs to one process at a time):
               layer), which is also run against its jnp body at the
               benchmark's shape (128 slots, 288 pages a slot, bfloat16).
 
+  G  grouped  moe_topk_ffn's grouped matmuls (ISSUE 41) at the three MoE
+              cells' real shapes — joyai_llm_flash 32 held experts of
+              2048 x 768, olmoe_1b_7b 64 of 2048 x 1024, k_exaone_236b_a23b
+              16 of 6144 x 2048; a decode step's rows and a 512-token
+              slice's, routed top-8 at random, the pairs of experts held
+              elsewhere behind the sizes: the Pallas weight-streaming
+              kernel (ops/pallas_grouped_matmul.py) against lax.ragged_dot,
+              product by product (the float32-accumulation tolerance) and
+              as one layer's block (gate, up, SwiGLU, down) timed both
+              ways. The line's `us_layer` numbers are host-clock readings
+              of whole blocks: evidence for where pgm.refuses draws its
+              line, not benchmark numbers.
+
 Weights and data are random from fixed seeds; depth is what the builders
 give. One JSON line per phase (platform, device_kind, device count, cache
 directory, XLA compiles and cache hits inside the phase, seconds), non-zero
@@ -113,6 +126,13 @@ FULL = {
     'joyai_prompts': (100, 1500), 'joyai_new': 64, 'joyai_seeds': (40, 41),
     'latent_paged': dict(slots=128, num_blocks=36865, block_size=16,
                          width=640, v_width=512, n_head=32, max_blocks=288),
+    # (tokens, top k, experts routed over, experts held, K, N, layers)
+    'grouped': {'joyai.step': (128, 8, 256, 32, 2048, 768, 4),
+                'joyai.slice': (512, 8, 256, 32, 2048, 768, 4),
+                'olmoe.step': (32, 8, 64, 64, 2048, 1024, 4),
+                'olmoe.slice': (512, 8, 64, 64, 2048, 1024, 4),
+                'exaone.step': (64, 8, 128, 16, 6144, 2048, 4),
+                'exaone.slice': (512, 8, 128, 16, 6144, 2048, 4)},
 }
 TOY = {
     'resnet': dict(dshape=(3, 32, 32), class_dim=10, depth=50, batch=8),
@@ -140,6 +160,8 @@ TOY = {
     'joyai_prompts': (5, 60), 'joyai_new': 8, 'joyai_seeds': (40,),
     'latent_paged': dict(slots=16, num_blocks=321, block_size=16, width=256,
                          v_width=128, n_head=4, max_blocks=20),
+    'grouped': {'toy.step': (16, 2, 8, 4, 128, 256, 2),
+                'toy.slice': (96, 2, 8, 8, 256, 128, 2)},
 }
 # Phase M's bound, at published widths on the chip, on the MEDIAN over the
 # compared rows of a row's largest |served logit - reference logit|. The
@@ -521,12 +543,18 @@ class Smoke(object):
         prompts = [rng.randint(2, d['vocab'], n) for n in lens]
         with DecodingPredictor(art) as pred:
             attention = pred.stats.snapshot()['attention']
+            experts = pred.expert_bodies
             tokens, logits = served_logits(pred, prompts,
                                            self.cfg[model + '_new'])
         if self.cfg is FULL and attention != (
                 'latent_kernel' if model == 'joyai' else 'kernel'):
             raise AssertionError('the step serves the %s attention body, '
                                  'not the paged kernel' % attention)
+        if self.cfg is FULL and any(
+                set(by_op['moe_topk_ffn']) != {'grouped_kernel'}
+                for by_op in experts.values()):
+            raise AssertionError('routed layers multiply with %s, not the '
+                                 'grouped kernel alone' % json.dumps(experts))
         want, low, gaps = [], [], []
         for p, t in zip(prompts, tokens):
             seq = np.concatenate([p, np.asarray(t[:-1], np.int64)])
@@ -580,7 +608,7 @@ class Smoke(object):
                                        for p in (10, 25, 50)],
                 'served': against_reference(np.concatenate(logits)),
                 'lower_precision': against_reference(np.concatenate(low)),
-                'step_attention': attention}
+                'step_attention': attention, 'expert_bodies': experts}
 
     # -- the kernels -------------------------------------------------------
     def phase_k(self):
@@ -648,6 +676,97 @@ class Smoke(object):
                 raise AssertionError('S=%d causal=%s: kernel vs composition '
                                      'rel err %.4g' % (S, causal, worst))
         return {'max_rel_err': errs, 'paged_attention': self._paged()}
+
+    # -- the routed FFN's grouped matmuls -----------------------------------
+    def phase_g(self):
+        """Every case of cfg['grouped']: the kernel's products against
+        lax.ragged_dot's on the rows the sizes hold, and a layer's block
+        timed with each."""
+        import functools
+        import jax
+        import jax.numpy as jnp
+        import numpy as np
+        from paddle_tpu.ops import pallas_grouped_matmul as pgm
+        from paddle_tpu.ops.llm_ops import swiglu
+        on_tpu = self.dev.platform == 'tpu'
+        kernel = (pgm.grouped_matmul if on_tpu else functools.partial(
+            pgm.grouped_matmul, interpret=True))
+
+        def block(grouped, layers):
+            def run(rows, gates, ups, downs, sizes):
+                out = []
+                for gate, up, down in zip(gates, ups, downs):
+                    h = swiglu(grouped(rows, gate, sizes),
+                               grouped(rows, up, sizes))
+                    out.append(grouped(h.astype(down.dtype), down, sizes))
+                return out
+            return jax.jit(run)
+
+        cases = {}
+        rng = np.random.RandomState(41)
+        for name, (tokens, k, routed, held, d, f, layers) in sorted(
+                self.cfg['grouped'].items()):
+            ids = np.stack([rng.permutation(routed)[:k]
+                            for _ in range(tokens)]).reshape(-1)
+            sizes = np.bincount(ids[ids < held], minlength=held)
+            n_held = int(sizes.sum())
+            keys = jax.random.split(jax.random.key(41), 4)
+
+            def weights(key, shape):
+                make = jax.jit(lambda key: jax.random.normal(
+                    key, (held,) + shape, jnp.bfloat16) * shape[0] ** -0.5)
+                return [make(jax.random.fold_in(key, i))
+                        for i in range(layers)]
+            rows = jax.random.normal(keys[0], (tokens * k, d), jnp.bfloat16)
+            hidden = jax.random.normal(keys[0], (tokens * k, f),
+                                       jnp.bfloat16)
+            gates, ups = weights(keys[1], (d, f)), weights(keys[2], (d, f))
+            downs = weights(keys[3], (f, d))
+            sizes = jnp.asarray(sizes, jnp.int32)
+            products = ((rows, gates[0]), (hidden, downs[0]))
+            for a, w in products:
+                why = pgm.refuses(a, w, sizes)
+                if why:
+                    raise AssertionError('%s: the kernel refuses %s'
+                                         % (name, why))
+            if on_tpu:
+                text = jax.jit(pgm.kernel_or_ragged_dot).lower(
+                    rows, gates[0], sizes).compile().as_text()
+                if 'moe_grouped_matmul' not in text:
+                    raise AssertionError('the platform switch compiled '
+                                         'for the TPU holds no kernel')
+            # product by product, on the rows the sizes hold: both sum
+            # bfloat16 x bfloat16 products in float32, in their own order
+            # (K terms: K * 2^-24 relative at worst, 4e-4 at 6,144; a
+            # dropped row or a neighbour's weights move it by O(1))
+            worst = 0.0
+            for a, w in products:
+                got = np.asarray(jax.jit(kernel)(a, w, sizes))[:n_held]
+                want = np.asarray(jax.jit(pgm.ragged_dot)(
+                    a, w, sizes))[:n_held]
+                if not np.isfinite(got).all():
+                    raise AssertionError('%s: non-finite product' % name)
+                if n_held:
+                    worst = max(worst, float(np.abs(got - want).max()
+                                             / np.abs(want).max()))
+            if worst > 1e-4:
+                raise AssertionError(
+                    '%s: kernel vs lax.ragged_dot, relative %.3g'
+                    % (name, worst))
+            args = (rows, gates, ups, downs, sizes)
+            _, t_ragged = _timed(block(pgm.ragged_dot, layers), args)
+            _, t_kernel = _timed(block(kernel, layers), args)
+            groups = int((np.asarray(sizes) > 0).sum())
+            cases[name] = {
+                'rows': tokens * k, 'held_rows': n_held, 'groups': groups,
+                'max_rel_err': worst,
+                'ragged_dot_us_layer': round(t_ragged / layers * 1e6, 1),
+                'kernel_us_layer': round(t_kernel / layers * 1e6, 1),
+                # every held expert's weights, once
+                'bytes_us_layer': round(
+                    held * 3 * d * f * 2 / 819e9 * 1e6, 1)}
+            del rows, hidden, products, gates, ups, downs, args
+        return {'cases': cases}
 
     def _paged(self):
         """kv_block_attention's two bodies on the device, side by side.
@@ -780,6 +899,20 @@ class Smoke(object):
                 'max_rel_err': rel}
 
 
+def _timed(fn, args, reps=6):
+    """(fn's result, median seconds a call): calls in threes, the clock
+    stopped behind the last one's result."""
+    import jax
+    import numpy as np
+    out = jax.block_until_ready(fn(*args))
+    laps = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready([fn(*args) for _ in range(3)])
+        laps.append((time.perf_counter() - t0) / 3)
+    return out, float(np.median(laps))
+
+
 def _ragged_slots(rng, live, S, NB, BS, MAXB):
     """(pos [S], table [S, MAXB]): the positions `live` on as many slots
     chosen at random, each with its pages drawn from the shuffled pool;
@@ -800,8 +933,8 @@ def main(argv=None):
                     help='directory for artifacts and lines.jsonl')
     ap.add_argument('--cpu-rehearsal', action='store_true',
                     help='toy sizes on the host cpu; never a chip pass')
-    ap.add_argument('--phases', default='ACBMXJK',
-                    help='the phases to run, of A C B M X J K (C needs 4 '
+    ap.add_argument('--phases', default='ACBMXJKG',
+                    help='the phases to run, of A C B M X J K G (C needs 4 '
                     'chips)')
     args = ap.parse_args(argv)
     if args.cpu_rehearsal:
@@ -835,7 +968,7 @@ def main(argv=None):
 
     smoke = Smoke(TOY if args.cpu_rehearsal else FULL, args.out, devs[0],
                   len(devs))
-    for name in 'ACBMXJK':
+    for name in 'ACBMXJKG':
         if name in args.phases.upper() and (name != 'C' or len(devs) >= 4):
             smoke.phase(name, getattr(smoke, 'phase_' + name.lower()))
     result = {'ok': not args.cpu_rehearsal, 'phases': args.phases.upper(),

@@ -1251,6 +1251,21 @@ class DecodingPredictor(object):
         pool, one row a position with the values inside it:
         'latent_kernel' / 'latent_jnp'). Empty for an
         artifact exported before the signature carried it."""
+        return self._bodies('attention',
+                            lambda body: body.replace('kernel', 'jnp'))
+
+    @property
+    def expert_bodies(self):
+        """{program: {'moe_topk_ffn': {body: count}}}: what multiplies
+        the routed layers' grouped matmuls as THIS platform runs the
+        loaded programs — 'grouped_kernel' (the Pallas weight-streaming
+        kernel a module holds for a TPU) read as 'ragged_dot' anywhere
+        else. Empty for an artifact without routed layers."""
+        return self._bodies('experts', lambda body: 'ragged_dot')
+
+    def _bodies(self, key, elsewhere):
+        """The signature's `key` entry of every loaded program, each
+        body renamed by `elsewhere` where the platform is not a TPU."""
         import jax
         sig = self._sig
         progs = {'step': sig['step'], 'verify': sig.get('verify', {})}
@@ -1262,7 +1277,7 @@ class DecodingPredictor(object):
                     else (self._device or jax.devices()[0]).platform)
         out = {}
         for name, entry in progs.items():
-            bodies = entry.get('attention')
+            bodies = entry.get(key)
             if not bodies:
                 continue
             if platform != 'tpu':
@@ -1270,7 +1285,7 @@ class DecodingPredictor(object):
                 for op, by_body in bodies.items():
                     here = merged.setdefault(op, {})
                     for body, n in by_body.items():
-                        body = body.replace('kernel', 'jnp')
+                        body = elsewhere(body)
                         here[body] = here.get(body, 0) + n
                 bodies = merged
             out[name] = bodies

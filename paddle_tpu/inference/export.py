@@ -860,7 +860,11 @@ def _export_decode_program(entry, program, param_args, param_specs,
     Pallas kernel, which only kv_block_attention has and reports to its
     Tracer as it lowers (ops/decode_ops.py); every other body, and
     every body on another platform, is the 'jnp' expression over the
-    gathered view."""
+    gathered view. A program with routed layers also has 'experts': the
+    body of each moe_topk_ffn's grouped matmuls where the module is
+    compiled for a TPU ({'moe_topk_ffn': {'grouped_kernel': 10}} — the
+    Pallas weight-streaming kernel, ops/pallas_grouped_matmul.py; or
+    'ragged_dot', which is also what every other platform runs)."""
     import jax
     import jax.numpy as jnp
     from ..core.lowering import Tracer
@@ -935,17 +939,22 @@ def _export_decode_program(entry, program, param_args, param_specs,
     # ops that chose a body said so as they lowered; the other
     # kv_*attention* ops have the one jnp body
     reported = {op_type for op_type, _ in lowered}
-    attention = {}
+    attention, experts = {}, {}
     for op_type, body in lowered + [
             (op.type, 'jnp') for op in program.global_block().ops
             if re.fullmatch(r'kv_\w*attention\w*', op.type)
             and op.type not in reported]:
-        by_body = attention.setdefault(op_type, {})
+        by_body = (experts if op_type == 'moe_topk_ffn'
+                   else attention).setdefault(op_type, {})
         by_body[body] = by_body.get(body, 0) + 1
-    return {'feeds': [{'name': n, 'shape': list(samples[n].shape),
-                       'dtype': samples[n].dtype.name} for n in feed_names],
-            'fetches': ['ids'] + fetch_names,
-            'attention': attention}
+    signature = {'feeds': [{'name': n, 'shape': list(samples[n].shape),
+                            'dtype': samples[n].dtype.name}
+                           for n in feed_names],
+                 'fetches': ['ids'] + fetch_names,
+                 'attention': attention}
+    if experts:
+        signature['experts'] = experts
+    return signature
 
 
 def _export_decode_zeros(state_specs, out_dir, shard=None):

@@ -69,6 +69,28 @@ def _switch_moe_ffn(ctx, ins):
             'AuxLoss': [aux.reshape(1)]}
 
 
+def _grouped_body(ctx, rows, w_in, w_out, sizes):
+    """grouped(a, w) for the three products of one moe_topk_ffn: the
+    platform switch (the Pallas kernel on a TPU, lax.ragged_dot anywhere
+    else) where pgm.refuses takes both weight shapes, lax.ragged_dot
+    alone where it names a rule — chosen from the operands and the trace
+    mesh, never from a knob, and told to the Tracer (lowered_bodies:
+    'grouped_kernel' | 'ragged_dot') as kv_block_attention tells its
+    own."""
+    from . import pallas_grouped_matmul as pgm
+    tracer = getattr(ctx, 'tracer', None)
+    if tracer is None:      # shape inference: no Tracer, and any body will do
+        kernel = False
+    else:
+        h = jax.ShapeDtypeStruct((rows.shape[0], w_in.shape[2]), w_out.dtype)
+        kernel = (pgm.refuses(rows, w_in, sizes)
+                  or pgm.refuses(h, w_out, sizes)) is None
+        tracer.lowered_bodies.append(
+            ('moe_topk_ffn', 'grouped_kernel' if kernel else 'ragged_dot'))
+    body = pgm.kernel_or_ragged_dot if kernel else pgm.ragged_dot
+    return lambda a, w: body(a, w, sizes)
+
+
 @register('moe_topk_ffn', diff_inputs=('X', 'RouterW', 'WGate', 'WUp',
                                        'WDown'))
 def _moe_topk_ffn(ctx, ins):
@@ -86,10 +108,24 @@ def _moe_topk_ffn(ctx, ins):
     WDown_e(silu(WGate_e x) * WUp_e x). No capacity and no
     [tokens, experts, capacity] tensor: the N * k (token, expert) pairs
     are sorted by expert and every expert multiplies its own contiguous
-    rows (lax.ragged_dot, operands in the weights' dtype, float32
+    rows (a grouped matmul, operands in the weights' dtype, float32
     accumulation), so no pair is dropped however uneven the routing.
     Each token's k partial results are summed in its own top-k order, so
     a row's output does not depend on what else is in the batch.
+
+    Two bodies for the three grouped matmuls (_grouped_body). Every
+    platform but a TPU — and on a TPU float32 experts, a width that is
+    no multiple of 128 or a sharded trace (pgm.refuses names the rule) —
+    multiplies with lax.ragged_dot. Compiled for a TPU,
+    ops/pallas_grouped_matmul.py's kernel streams each expert's weights
+    through VMEM once, group boundaries read from cumsum(sizes) and the
+    row tiles behind sum(sizes) skipped: the same products on the same
+    bfloat16 operands, summed in float32 (on the chip its results equal
+    ragged_dot's to the bit at the three benchmark configurations'
+    shapes: PERF.md, PR 41). silu(gate) * up stays on the float32
+    results and h is cast to WDown's dtype in front of the third product
+    in both. The gradient is ragged_dot's on every platform.
+    lowered_bodies says 'grouped_kernel' / 'ragged_dot', one entry an op.
 
     The op HOLDS the H = WGate.shape[0] experts [expert_offset,
     expert_offset + H) of the E the router scores (one chip's share of
@@ -139,9 +175,7 @@ def _moe_topk_ffn(ctx, ins):
         rows = x.astype(w_gate.dtype)[order // k]      # sorted by expert
         sizes = jnp.bincount(expert, length=e).astype(jnp.int32)
     with jax.named_scope('experts'):
-        def grouped(a, w):
-            return jax.lax.ragged_dot(a, w, sizes,
-                                      preferred_element_type=jnp.float32)
+        grouped = _grouped_body(ctx, rows, w_gate, w_down, sizes)
         h = swiglu(grouped(rows, w_gate), grouped(rows, w_up))
         y = grouped(h.astype(w_down.dtype), w_down)    # [N * k, D]
     with jax.named_scope('combine'):

@@ -519,3 +519,29 @@ def test_kernel_compiles_for_v5e(one_chip, s, nb, bs, d, h, maxb, dtype):
                         sds((nb, bs, d), dtype), sds((s,), np.int32),
                         sds((s, maxb), np.int32)).compile()
     assert 'tpu_custom_call' in compiled.as_text()
+
+
+# moe_topk_ffn's grouped matmul kernel (ops/pallas_grouped_matmul.py,
+# ISSUE 41; tests/test_grouped_matmul.py has the rest) lives here for the
+# chip's compiler, which one test file loads: every (rows, K, N, held
+# experts) the three MoE cells' programs multiply — a decode step's pairs
+# and a 512-token slice's, the gate / up product and the down product
+@pytest.mark.parametrize('m,k,n,e', [
+    (1024, 2048, 768, 32), (1024, 768, 2048, 32),      # joyai_llm_flash
+    (4096, 2048, 768, 32), (4096, 768, 2048, 32),
+    (256, 2048, 1024, 64), (256, 1024, 2048, 64),      # olmoe_1b_7b
+    (4096, 2048, 1024, 64), (4096, 1024, 2048, 64),
+    (512, 6144, 2048, 16), (512, 2048, 6144, 16),      # k_exaone_236b_a23b
+    (4096, 6144, 2048, 16), (4096, 2048, 6144, 16)])
+def test_grouped_matmul_compiles_for_v5e(one_chip, m, k, n, e):
+    from paddle_tpu.ops import pallas_grouped_matmul as pgm
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    rows, w, sizes = (sds((m, k), jnp.bfloat16), sds((e, k, n), jnp.bfloat16),
+                      sds((e,), np.int32))
+    assert pgm.refuses(rows, w, sizes) is None
+    # the platform switch, lowered for the described chip: its TPU body
+    compiled = jax.jit(pgm.kernel_or_ragged_dot).lower(
+        rows, w, sizes).compile()
+    assert compiled.as_text().count('tpu_custom_call') == 1
