@@ -23,7 +23,13 @@ this one process — a chip belongs to one process at a time):
               kv_block_attention at the benchmark's decode shape (128 slots,
               128 x 16-row pages a slot, d_model 512, 8 heads, ragged pos),
               the op as a TPU program lowers it — the paged Pallas kernel —
-              against its float32 jax.numpy body.
+              against its float32 jax.numpy body; then the same over the
+              three MoE cells' bfloat16 pools at their slots, rows and
+              heads (olmoe 16 heads of D 2048; k_exaone 64 / 8 heads of D
+              1024, full and window layers; qwen3 16 / 2 heads of D 512),
+              where the kernel multiplies the pages as stored under a
+              query in three bfloat16 pieces: held to 2e-6 relative, which
+              a query of ONE piece must miss by far (ISSUE 43).
 
   M  MoE      OLMoE at its published widths (hidden 2048, 16 heads of 128,
               64 experts of 1024 top-8, vocab 50,304; 6 layers, 4 slots of
@@ -121,6 +127,20 @@ FULL = {
     'attn': ((2, 4, 512, 64, False), (1, 2, 4096, 64, True)),
     'paged': dict(slots=128, num_blocks=16385, block_size=16, d_model=512,
                   n_head=8, max_blocks=128),
+    # the benchmark's three bfloat16 K/V pools as their cells hold them —
+    # slots, page, row and heads — under tables of 4,096 positions or a
+    # few more (the jnp body gathers every slot's whole span); k_exaone's
+    # window layers beside its full one
+    'paged_bf16': {
+        'olmoe': dict(slots=32, num_blocks=8193, block_size=16,
+                      d_model=2048, n_head=16, max_blocks=256),
+        'exaone': dict(slots=64, num_blocks=16385, block_size=16,
+                       d_model=1024, n_head=64, n_kv_head=8, max_blocks=256),
+        'exaone_window': dict(slots=64, num_blocks=16385, block_size=16,
+                              d_model=1024, n_head=64, n_kv_head=8,
+                              window=128, max_blocks=256),
+        'qwen3': dict(slots=128, num_blocks=36865, block_size=16,
+                      d_model=512, n_head=16, n_kv_head=2, max_blocks=288)},
     'olmoe': dict(vocab=50304, d_model=2048, n_head=16, n_layer=6,
                   n_expert=64, d_expert=1024, top_k=8, max_slots=4,
                   max_cache_len=2048, block_size=16, chunk_sizes=(32, 128)),
@@ -162,6 +182,12 @@ TOY = {
     'attn': ((1, 2, 512, 64, False),),
     'paged': dict(slots=16, num_blocks=401, block_size=8, d_model=128,
                   n_head=2, max_blocks=40),
+    'paged_bf16': {
+        'toy': dict(slots=16, num_blocks=321, block_size=16, d_model=128,
+                    n_head=2, max_blocks=20),
+        'toy_window': dict(slots=16, num_blocks=321, block_size=16,
+                           d_model=256, n_head=4, n_kv_head=2, window=128,
+                           max_blocks=20)},
     'olmoe': dict(vocab=128, d_model=64, n_head=4, n_layer=2, n_expert=8,
                   d_expert=32, top_k=2, max_slots=4, max_cache_len=64,
                   block_size=8, chunk_sizes=(8, 16)),
@@ -958,35 +984,50 @@ class Smoke(object):
         return {'cases': cases}
 
     def _paged(self):
-        """kv_block_attention's two bodies on the device, side by side.
-        On a TPU the op itself, as a program lowers it: the compiled
+        """kv_block_attention's two bodies on the device, side by side: the
+        float32 pool of cfg['paged'], whose line this is, and under
+        'bfloat16' every pool of cfg['paged_bf16']."""
+        import jax.numpy as jnp
+        out = self._paged_case(self.cfg['paged'], jnp.float32)
+        out['bfloat16'] = {
+            name: self._paged_case(c, jnp.bfloat16)
+            for name, c in sorted(self.cfg['paged_bf16'].items())}
+        return out
+
+    def _paged_case(self, c, dtype):
+        """On a TPU the op itself, as a program lowers it: the compiled
         program must hold the paged Pallas kernel. On the cpu rehearsal
         the op lowers to the jnp body, so the kernel is called directly
         in interpret mode. Half the slots live at ragged positions — 0,
         the page edges, the kernel's 256-row block edge, the last row of
         a full table — on shuffled pages; the rest idle on the trash
-        block."""
+        block. A bfloat16 pool (ISSUE 43) goes to the MXU as it lies
+        under a float32 query in three bfloat16 pieces: float32-exact as
+        the float32 pool's HIGHEST products are, so both are held to the
+        jnp body alike — and the same kernel given the query ROUNDED to
+        bfloat16, one piece, has to come out far over that bound."""
         import jax
         import jax.numpy as jnp
         import numpy as np
         from paddle_tpu.ops import decode_ops
         from paddle_tpu.ops import pallas_paged_attention as ppa
-        c = self.cfg['paged']
         S, NB, BS, D, H, MAXB = (c[k] for k in (
             'slots', 'num_blocks', 'block_size', 'd_model', 'n_head',
             'max_blocks'))
+        n_kv, window = c.get('n_kv_head', H), c.get('window', 0)
         keys = jax.random.split(jax.random.key(25), 3)
-        kc = jax.random.normal(keys[0], (NB, BS, D), jnp.float32)
-        vc = jax.random.normal(keys[1], (NB, BS, D), jnp.float32)
-        q = jax.random.normal(keys[2], (S, D), jnp.float32)
+        kc = jax.random.normal(keys[0], (NB, BS, D), dtype)
+        vc = jax.random.normal(keys[1], (NB, BS, D), dtype)
+        q = jax.random.normal(keys[2], (S, H * (D // n_kv)), jnp.float32)
         rng = np.random.RandomState(25)
         last = MAXB * BS - 1
         live = [0, last, BS - 1, BS, BS + 1, 255, 256] + [
             int(x) for x in rng.randint(0, last + 1, S // 2 - 7)]
         pos, table = _ragged_slots(rng, live, S, NB, BS, MAXB)
         args = (q, kc, vc, jnp.asarray(pos), jnp.asarray(table))
+        attrs = {'n_head': H, 'n_kv_head': n_kv, 'window': window}
         ctx = types.SimpleNamespace(         # core/lowering.py OpCtx
-            attr=lambda name, default=None: {'n_head': H}.get(name, default),
+            attr=lambda name, default=None: attrs.get(name, default),
             abstract=False,
             tracer=types.SimpleNamespace(lowered_bodies=[]))
 
@@ -1002,7 +1043,8 @@ class Smoke(object):
                                      'hold the paged kernel')
         else:
             kernel = jax.jit(lambda *a: ppa.paged_attention(
-                *a, n_head=H, scale=(D // H) ** -0.5, interpret=True))
+                *a, n_head=H, n_kv_head=n_kv, window=window,
+                scale=(D // n_kv) ** -0.5, interpret=True))
         got = np.asarray(kernel(*args))
         with jax.default_matmul_precision('highest'):
             want = np.asarray(jax.jit(
@@ -1015,11 +1057,26 @@ class Smoke(object):
         # both bodies are float32 throughout and differ by the online
         # softmax's rounding (~2e-7); a dropped page of a slot's ~64, or
         # one row too many or too few of up to 2048, moves it by >= 1e-4
-        if rel > 1e-5:
+        # — and a bfloat16 pool's kernel that dropped the last piece of
+        # its query by ~5e-6
+        bound = 1e-5 if dtype == jnp.float32 else 2e-6
+        if rel > bound:
             raise AssertionError('paged kernel vs jnp body: max abs %.3g, '
                                  'relative %.3g' % (err, rel))
-        return {'shape': [S, NB, BS, D, H, MAXB], 'live_slots': len(live),
-                'max_abs_err': err, 'max_rel_err': rel}
+        out = {'shape': [S, NB, BS, D, H, MAXB], 'live_slots': len(live),
+               'max_abs_err': err, 'max_rel_err': rel}
+        if dtype != jnp.float32:
+            out.update(n_kv_head=n_kv, window=window)
+            rounded = np.asarray(kernel(
+                q.astype(jnp.bfloat16).astype(jnp.float32), *args[1:]))
+            one = float(np.abs(rounded - want).max()
+                        / np.abs(want).max())
+            if one < 20 * bound:
+                raise AssertionError(
+                    'a query of ONE bfloat16 piece reads %.3g relative: '
+                    'the comparison cannot tell it apart' % one)
+            out['one_piece_query_rel_err'] = one
+        return out
 
 
     def _latent_paged(self):

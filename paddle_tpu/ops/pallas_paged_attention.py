@@ -17,7 +17,12 @@ D stays on the lanes. The query becomes block-diagonal [n_head, D] (head
 h non-zero only in its own d_head lanes), so every head's scores are one
 [n_head, D] x [D, PAGES*BS] product and the output one [n_head, PAGES*BS]
 x [PAGES*BS, D] product whose diagonal blocks are kept: any d_head that
-divides D works, and nothing is re-laid-out.
+divides D works, and nothing is re-laid-out. Both products are exact to
+float32 in the form the pool's dtype calls for (`_times`): a float32
+pool's at HIGHEST; a bfloat16 pool's pages go to the MXU as they lie in
+VMEM, under the float32 query and softmax weights split into three
+bfloat16 pieces (`_pieces`) — the same result, a sixth of the passes,
+and no [rows, D] block lifted to float32.
 
 Contract (the op's): rows j > pos get -inf before the running max, so
 their weight is exactly zero, and V rows past `pos` are zeroed before
@@ -62,7 +67,12 @@ _BLOCK_ROWS = 256
 # Both in-kernel products at full float32: the jnp body's einsums come
 # out of XLA's TPU backend float32-exact, and Mosaic's default would
 # round the operands to bfloat16 (3e-3 relative on the chip against
-# 5e-7, for a third less time: PERF.md, PR 24's reading)
+# 5e-7, for a third less time: PERF.md, PR 24's reading). On this chip
+# HIGHEST is six bfloat16 passes of the MXU, a1 b1 + a1 b2 + a2 b1 +
+# a1 b3 + a2 b2 + a3 b1 over three-way splits of both operands: what a
+# float32 pool needs, and three passes over zeros for a page that came
+# out of a bfloat16 pool (b2 = b3 = 0) — `_times` gives that pool the
+# other three as ONE pass (PERF.md, PR 43)
 _PRECISION = lax.Precision.HIGHEST
 
 
@@ -206,6 +216,44 @@ def _softmax_step(m, l, acc, sc, weigh):
     return m_new, l, alpha * acc + weigh(p)
 
 
+def _pieces(x):
+    """float32 x [n, w] as three bfloat16 pieces, one under the other
+    [3 n, w]: hi = bf16(x), mid = bf16(x - hi), lo = bf16(x - hi - mid).
+    Three 8-bit significands hold float32's 24, so hi + mid + lo is x."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return jnp.concatenate([hi, mid, lo], axis=0)
+
+
+def _times(a, b, contract):
+    """a times b, contracting (a's dimension, b's), exact to float32 in
+    the form b's dtype calls for. b float32 (a too): one product at
+    HIGHEST. b bfloat16 as a pool stores it, a the `_pieces` of a float32
+    operand: ONE pass of the stack over b under float32 sums, its three
+    row groups added, smallest first — HIGHEST's three terms that do not
+    multiply a zero, and nothing of b lifted to float32."""
+    dims = ((contract[:1], contract[1:]), ((), ()))
+    if b.dtype == jnp.float32:
+        return lax.dot_general(a, b, dims, precision=_PRECISION,
+                               preferred_element_type=jnp.float32)
+    by3 = lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+    n = by3.shape[0] // 3
+    return by3[2 * n:] + by3[n:2 * n] + by3[:n]
+
+
+def _query_rows(n_head, dtype):
+    """Rows of a slot's query block inside `_kernel`: n_head, and over a
+    bfloat16 pool whole sublane tiles of 16 (heads past n_head are zero
+    rows), so that the three `_pieces` lie one under the other at tile
+    boundaries."""
+    if dtype == jnp.float32:
+        return n_head
+    tile = _SUBLANES[jnp.dtype(jnp.bfloat16)]
+    return -(-n_head // tile) * tile
+
+
 def _kernel(pos_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
             *, n_head, n_kv_head, window, scale, bs, maxb, pages):
     n_slot = q_ref.shape[0]
@@ -213,6 +261,9 @@ def _kernel(pos_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
     dh = d // n_kv_head
     group = n_head // n_kv_head
     rows = pages * bs
+    # the operands of both products follow the pool's dtype (`_times`)
+    split = kbuf.dtype != jnp.float32
+    n_head = _query_rows(n_head, kbuf.dtype)
 
     def first_page(s):
         """The page that holds the first row slot s attends — with a
@@ -260,6 +311,8 @@ def _kernel(pos_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
             q = jnp.concatenate(
                 [q_ref[s].astype(jnp.float32)] * n_kv_head, axis=1)
         qbd = jnp.where(own, q, 0.0)
+        if split:
+            qbd = _pieces(qbd)              # once a slot: [3 n_head, D]
 
         def seen(i, j):
             """Whether row j of block i holds a position the slot
@@ -272,17 +325,13 @@ def _kernel(pos_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
         def block(i, carry):
             m, l, acc, buf = carry
             _load_next_then_wait(each_page, s, i, nblk, n_slot, buf)
-            k = kbuf[buf].astype(jnp.float32)                   # [rows, D]
-            sc = lax.dot_general(
-                qbd, k, (((1,), (1,)), ((), ())), precision=_PRECISION,
-                preferred_element_type=jnp.float32) * scale     # [H, rows]
+            sc = _times(qbd, kbuf[buf], (1, 1)) * scale         # [H, rows]
             sc = jnp.where(seen(i, col), sc, -jnp.inf)
 
             def weigh(p):
-                v = jnp.where(seen(i, row), vbuf[buf].astype(jnp.float32),
-                              0.0)
-                return jnp.dot(p, v, precision=_PRECISION,
-                               preferred_element_type=jnp.float32)  # [H, D]
+                v = jnp.where(seen(i, row), vbuf[buf], 0.0)     # [rows, D]
+                return _times(_pieces(p) if split else p, v,
+                              (1, 0))                           # [H, D]
 
             return _softmax_step(m, l, acc, sc, weigh) + (1 - buf,)
 
@@ -320,10 +369,14 @@ def paged_attention(q, k_cache, v_cache, pos, table, *, n_head, scale,
     kernel = functools.partial(_kernel, n_head=n_head, n_kv_head=n_kv_head,
                                window=int(window), scale=scale, bs=bs,
                                maxb=maxb, pages=pages)
+    pad = 0
     if n_kv_head != n_head:
         # one [n_head, d_head] tile stack per slot: a free re-view here,
         # a re-layout inside the kernel
         q = q.reshape(n_slot, n_head, d // n_kv_head)
+        pad = _query_rows(n_head, k_cache.dtype) - n_head
+        if pad:
+            q = jnp.pad(q, ((0, 0), (0, pad), (0, 0)))
     qspec = pl.BlockSpec(q.shape, lambda i, *_: (0,) * q.ndim)
     out = pl.pallas_call(
         kernel,
@@ -343,6 +396,8 @@ def paged_attention(q, k_cache, v_cache, pos, table, *, n_head, scale,
         name='kv_block_paged_attention',
         interpret=interpret,
     )(pos, table.reshape(-1), q, k_cache, v_cache)
+    if pad:
+        out = out[:, :n_head]
     return out.reshape(n_slot, -1)
 
 

@@ -324,6 +324,121 @@ def test_window_drops_exactly_the_rows_it_passed():
                                      **kw)), rtol=1e-4, atol=1e-2)
 
 
+# -- a bfloat16 pool: ONE pass over the query's three pieces (ISSUE 43) -------
+#
+# What a bfloat16 pool stores goes to the MXU as it lies, under a float32
+# query (and float32 softmax weights) split into three bfloat16 pieces: the
+# float32 product, exactly. Held to the jnp body in float32 at HIGHEST to
+# 2e-6 of the largest output — the online softmax's own rounding is 1e-7
+# to 1e-6 here — on inputs a dropped piece shows on: every query element a
+# whole 24-bit mantissa under an exponent of its own out of 2^-10 .. 2^10,
+# scores spread over +-8 so that softmax weights reach down past 1e-6.
+# The controls drop the last piece (16 of the 24 bits stay) and the last
+# two (plain bfloat16: what PR 24 refused) and must FAIL that bound.
+
+EXACT = 2e-6
+# (n_head, n_kv_head, d_head): as many K/V heads as query heads, below and
+# at bfloat16's sublane tile of 16 rows; grouped heads of 256 — 8 / 2
+# below the tile, 16 / 2 (qwen3_next_80b_a3b's) at it
+_PIECE_HEADS = {'h2': (2, 2, 128), 'h16': (16, 16, 128),
+                'h8_kv2': (8, 2, 256), 'h16_kv2': (16, 2, 256)}
+
+
+def _wide_range_case(n_head, n_kv, dh, window):
+    """(q, K pool, V pool, pos, table) over a bfloat16 pool for
+    _WINDOW_POS's slots, and the jnp body's answer in float32."""
+    bs, maxb = 16, 24
+    s, d = len(_WINDOW_POS), n_kv * dh
+    rng = np.random.RandomState(43)
+    nb = s * maxb + 1
+    q = rng.randn(s, n_head, dh) * np.exp2(rng.randint(-10, 11,
+                                                       (s, n_head, dh)))
+    # a head's scores: scale * q . k, k of unit variance — spread 3
+    q *= 3 * dh ** 0.5 / np.linalg.norm(q, axis=-1, keepdims=True)
+    q = q.reshape(s, n_head * dh).astype(np.float32)
+    assert (q.view(np.uint32) & 0xffff).any()       # not bfloat16 values
+    table = rng.permutation(np.arange(1, nb)).reshape(s, maxb) \
+        .astype(np.int32)
+    args = (jnp.asarray(q),
+            jnp.asarray(rng.randn(nb, bs, d), jnp.bfloat16),
+            jnp.asarray(rng.randn(nb, bs, d), jnp.bfloat16),
+            jnp.asarray(_WINDOW_POS, jnp.int32), jnp.asarray(table))
+    # the last slot's head 0 over its 384 rows: weights down past 1e-6
+    rows = np.asarray(decode_ops._block_view(args[1], args[4][-1])
+                      .astype(jnp.float32))[:384, :dh]
+    weights = np.exp(rows @ q[-1, :dh] * dh ** -0.5)
+    assert weights.min() < 1e-6 * weights.max()
+    kw = dict(n_head=n_head, n_kv_head=n_kv, window=window)
+    with jax.default_matmul_precision('highest'):
+        want = np.asarray(_body_gw(*args, **kw))
+    return args, kw, want
+
+
+def _relative(got, want):
+    assert got.shape == want.shape and np.isfinite(got).all()
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+@pytest.mark.parametrize('window', [0, 128])
+@pytest.mark.parametrize('heads', sorted(_PIECE_HEADS))
+def test_bfloat16_pool_kernel_is_float32_exact(heads, window):
+    args, kw, want = _wide_range_case(*_PIECE_HEADS[heads], window=window)
+    assert _relative(np.asarray(_kernel_gw(*args, **kw)), want) <= EXACT
+
+
+@pytest.mark.parametrize('kept', [1, 2])
+@pytest.mark.parametrize('heads', ['h16', 'h16_kv2'])
+def test_a_dropped_piece_fails_the_float32_bound(heads, kept, monkeypatch):
+    """The control: the same kernel with the query's (and the weights')
+    last piece, or last two, zeroed — kept = 1 is a plain bfloat16
+    operand — is told apart by the bound the kernel is held to."""
+    args, kw, want = _wide_range_case(*_PIECE_HEADS[heads], window=0)
+    whole = ppa._pieces
+
+    def fewer(x):
+        stack = whole(x)
+        n = x.shape[0]
+        return stack.at[kept * n:].set(0)
+    monkeypatch.setattr(ppa, '_pieces', fewer)
+    d_head = want.shape[1] // kw['n_head']
+    got = np.asarray(jax.jit(lambda *a: ppa.paged_attention(
+        *a, scale=d_head ** -0.5, interpret=True, **kw))(*args))
+    assert _relative(got, want) > (2 if kept == 2 else 100) * EXACT
+
+
+# a float32 pool's kernel is the parent's program (ISSUE 43): the text of
+# paged_attention's jaxpr, the kernel's own equations inside it, hashed as
+# the parent of PR 43 traced it — HIGHEST on float32 operands, token for
+# token. (tests/test_decode_ids.py's StableHLO pins are of toy widths,
+# which the kernel refuses: they hold the jnp body.)
+_PARENT_FLOAT32_JAXPR = {
+    'the_benchmarks': ((128, 16385, 16, 512, 8, 8, 0, 128),
+                       '45dfc5df143a63f0'),
+    'one_head_pages_of_8': ((8, 257, 8, 128, 1, 1, 0, 32),
+                            'dec517288b541ff4'),
+    'grouped_heads': ((8, 257, 16, 256, 4, 2, 0, 32), 'ea383203c0d2508a'),
+    'grouped_heads_window': ((8, 257, 16, 256, 4, 2, 128, 32),
+                             'e27b07a2f53777f5'),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_PARENT_FLOAT32_JAXPR))
+def test_float32_pool_kernel_is_the_parents_program(case):
+    import hashlib
+    import re
+    (s, nb, bs, d, h, n_kv, window, maxb), want = _PARENT_FLOAT32_JAXPR[case]
+    jaxpr = jax.make_jaxpr(functools.partial(
+        ppa.paged_attention, n_head=h, n_kv_head=n_kv, window=window,
+        scale=(d // n_kv) ** -0.5))(
+            _sds((s, h * (d // n_kv)), np.float32),
+            _sds((nb, bs, d), np.float32), _sds((nb, bs, d), np.float32),
+            _sds((s,), np.int32), _sds((s, maxb), np.int32))
+    text = jaxpr.pretty_print(source_info=False, name_stack=False)
+    # the call's own name and where it was written: this file's lines
+    text = re.sub(r'name_and_src_info=[^\n]*', '', text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+
+
 # -- which body ---------------------------------------------------------------
 
 def _sds(shape, dtype):
@@ -504,6 +619,8 @@ def one_chip():
     (8, 257, 16, 512, 8, 32, np.float32),          # chip_smoke phase B's
     (8, 257, 16, 128, 2, 32, jnp.bfloat16),
     (8, 257, 8, 128, 1, 32, np.float32),
+    # olmoe_1b_7b: 16 heads of 128, as many K/V heads
+    (32, 8193, 16, 2048, 16, 256, jnp.bfloat16),
     # k_exaone_236b_a23b: 64 query / 8 K/V heads, a full and a window layer
     (64, 49153, 16, 1024, (64, 8, 0), 768, jnp.bfloat16),
     (64, 2625, 16, 1024, (64, 8, 128), 768, jnp.bfloat16),
