@@ -1140,22 +1140,39 @@ class Smoke(object):
         if rel > 2e-2:
             raise AssertionError('latent paged kernel vs jnp body: max abs '
                                  '%.3g, relative %.3g' % (err, rel))
-        return {'shape': [S, NB, BS, W, DV, H, MAXB],
-                'live_slots': len(live), 'max_abs_err': err,
-                'max_rel_err': rel}
+        # how often the kernel's full-block body engages and what a call
+        # takes (ISSUE 44): on the slots above, and with EVERY slot live
+        # at the rows joyai_llm_flash.reason_closed holds them at
+        busy = [int(x) for x in
+                rng.randint(min(600, last), min(2400, last) + 1, S)]
+        cell = _ragged_slots(rng, busy, S, NB, BS, MAXB)
+
+        def timed(live, slots):
+            return {'full_block_share': ppa.full_block_share(live),
+                    'ms_a_call': _timed(kernel, args[:2] + slots,
+                                        calls=30)[1] * 1e3}
+
+        return dict({'shape': [S, NB, BS, W, DV, H, MAXB],
+                     'live_slots': len(live), 'max_abs_err': err,
+                     'max_rel_err': rel,
+                     'every_slot_at_the_cells_rows': timed(
+                         busy, tuple(map(jnp.asarray, cell)))},
+                    **timed(live, args[2:]))
 
 
-def _timed(fn, args, reps=6):
-    """(fn's result, median seconds a call): calls in threes, the clock
-    stopped behind the last one's result."""
+def _timed(fn, args, reps=6, calls=3):
+    """(fn's result, median seconds a call): `calls` calls back to back,
+    the clock stopped behind the last one's result. The stop is ~0.5 ms
+    of host time a lap: a call under a few ms wants `calls` in the
+    tens."""
     import jax
     import numpy as np
     out = jax.block_until_ready(fn(*args))
     laps = []
     for _ in range(reps):
         t0 = time.perf_counter()
-        jax.block_until_ready([fn(*args) for _ in range(3)])
-        laps.append((time.perf_counter() - t0) / 3)
+        jax.block_until_ready([fn(*args) for _ in range(calls)])
+        laps.append((time.perf_counter() - t0) / calls)
     return out, float(np.median(laps))
 
 

@@ -37,7 +37,16 @@ whole row and sums its first v_width channels — attention in the absorbed
 form), so a page is copied once and multiplied twice, on operands of the
 pool's dtype. Same contract, same double buffering across slots; one grid
 step a slot, because n_head rows of the pool's width a slot make the whole
-batch's query too large to hold at once.
+batch's query too large to hold at once. A slot's FULL blocks and its
+last one take different bodies there (ISSUE 44): every block before the
+last holds a compute block's pages of rows that are all <= pos, so its
+copies are issued in straight-line code and waited for once and its
+products take the rows unmasked, where the last block — the one that can
+hold fewer pages or a row past pos — keeps the page loops and both masks.
+A select of every row and a loop of a constant trip count are the
+identity, so the result is the one-bodied kernel's to the bit; the two
+loops' scalar work overlapped nothing and was a quarter of a full block's
+time (the masks, measured, none of it: PERF.md, PR 44).
 
 What the paging contract is made of is written ONCE and both kernels call
 it: the rule a pool's pages obey (`_pool_rule`), the clamping of `pos`
@@ -55,6 +64,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.extend.core import Primitive
 from jax.experimental import pallas as pl
@@ -169,17 +179,24 @@ def _each_page(tab_ref, first, count, pools, buf, act, bs):
     """Start, or wait for (`act`), one async copy a page: the `count`
     pages named by the table from entry `first` on, out of each pool of
     `pools` — (pool in HBM, its [2, rows, D] buffer, the semaphore of a
-    half) — into rows j * bs of the buffer's half `buf`."""
+    half) — into rows j * bs of the buffer's half `buf`. A traced count
+    is a loop of one page a trip; a Python int is that many copies in
+    straight-line code, their rows static."""
+    static = isinstance(count, int)
+
     def body(j, carry):
         page = tab_ref[first + j]
         for pool, dst, sem_of in pools:
+            row = j * bs if static else pl.multiple_of(j * bs, bs)
             copy = pltpu.make_async_copy(
-                pool.at[page],
-                dst.at[buf, pl.ds(pl.multiple_of(j * bs, bs), bs), :],
-                sem_of(buf))
+                pool.at[page], dst.at[buf, pl.ds(row, bs), :], sem_of(buf))
             getattr(copy, act)()
         return carry
-    lax.fori_loop(0, count, body, 0)
+    if static:
+        for j in range(count):
+            body(j, 0)
+    else:
+        lax.fori_loop(0, count, body, 0)
 
 
 def _load_next_then_wait(each_page, s, i, nblk, n_slot, buf):
@@ -414,7 +431,14 @@ def _latent_kernel(pos_ref, tab_ref, q_ref, c_hbm, o_ref, cbuf, sem, parity,
     the pool's dtype as operands with float32 sums (bfloat16 x bfloat16
     is the MXU's own product: n_head x D x 2 operations a cached row is
     4.5 times a K/V row's, and six passes of it would be the step); a
-    float32 pool keeps full float32."""
+    float32 pool keeps full float32.
+
+    A slot is its FULL blocks, then its last one. Block i is full where
+    (i + 1) * rows <= pos: `pages` pages, every row a position <= pos —
+    all of a slot's blocks but the last. Told from `pos` alone, on the
+    side that starts a block's copies and the side that waits for them
+    alike (`each_page`), so the bytes a half's semaphore is given and
+    the bytes it is asked for are one count."""
     s = pl.program_id(0)
     n_slot = pl.num_programs(0)
     _, n_head, d = q_ref.shape
@@ -424,43 +448,74 @@ def _latent_kernel(pos_ref, tab_ref, q_ref, c_hbm, o_ref, cbuf, sem, parity,
 
     pools = ((c_hbm, cbuf, lambda buf: sem.at[buf]),)
 
-    def each_page(s, i, buf, act):
-        """The copies of block i of slot s into half `buf`: its pages
-        that hold a position <= pos."""
-        held = pos_ref[s] // bs + 1
-        _each_page(tab_ref, s * maxb + i * pages,
-                   jnp.minimum(held - i * pages, pages), pools, buf, act, bs)
+    def each_page(s, i, buf, act, full=None):
+        """The copies of block i of slot s into half `buf`. A full
+        block's are `pages`, started in straight-line code and waited
+        for ONCE, by the bytes of the whole half (a DMA semaphore counts
+        bytes, and a wait needs neither the table nor a page's address);
+        any other block's are its pages that hold a position <= pos, a
+        loop trip each. `full` True: the caller knows."""
+        def some(count):
+            _each_page(tab_ref, s * maxb + i * pages, count, pools, buf, act,
+                       bs)
+
+        def whole():
+            if act == 'start':
+                some(pages)
+            else:
+                half = cbuf.at[buf]
+                pltpu.make_async_copy(half, half, sem.at[buf]).wait()
+
+        if full:
+            return whole()
+        full = (i + 1) * rows <= pos_ref[s]
+        pl.when(full)(whole)
+
+        @pl.when(jnp.logical_not(full))
+        def _():
+            some(pos_ref[s] // bs + 1 - i * pages)
 
     @pl.when(s == 0)
     def _():
         parity[0] = 0
         each_page(0, 0, 0, 'start')
 
-    col = lax.broadcasted_iota(jnp.int32, (n_head, rows), 1)
-    row = lax.broadcasted_iota(jnp.int32, (rows, v_width), 0)
     pos = pos_ref[s]
     nblk = pos // rows + 1
     q = (q_ref[0] * scale).astype(mult)                         # [H, D]
 
-    def block(i, carry):
+    def block(i, carry, full):
+        """Block i out of half `buf`: a full one (every block before
+        the slot's last) takes its rows as they lie; the last one masks
+        what lies past pos, scores and values both."""
         m, l, acc, buf = carry
-        _load_next_then_wait(each_page, s, i, nblk, n_slot, buf)
+        if full:
+            each_page(s, i + 1, 1 - buf, 'start')
+            each_page(s, i, buf, 'wait', full=True)
+        else:
+            _load_next_then_wait(each_page, s, i, nblk, n_slot, buf)
         c = cbuf[buf]                                           # [rows, D]
         sc = lax.dot_general(
             q, c, (((1,), (1,)), ((), ())), precision=precision,
             preferred_element_type=jnp.float32)                 # [H, rows]
-        sc = jnp.where(i * rows + col <= pos, sc, -jnp.inf)
+        if not full:
+            col = lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            sc = jnp.where(i * rows + col <= pos, sc, -jnp.inf)
 
         def weigh(p):
-            v = jnp.where(i * rows + row <= pos, c[:, :v_width],
-                          jnp.zeros((), mult))
+            v = c[:, :v_width]
+            if not full:
+                row = lax.broadcasted_iota(jnp.int32, v.shape, 0)
+                v = jnp.where(i * rows + row <= pos, v, jnp.zeros((), mult))
             return jnp.dot(p.astype(mult), v, precision=precision,
                            preferred_element_type=jnp.float32)  # [H, dv]
 
         return _softmax_step(m, l, acc, sc, weigh) + (1 - buf,)
 
-    _, l, acc, buf = lax.fori_loop(
-        0, nblk, block, _softmax_start(n_head, v_width) + (parity[0],))
+    carry = lax.fori_loop(
+        0, nblk - 1, functools.partial(block, full=True),
+        _softmax_start(n_head, v_width) + (parity[0],))
+    _, l, acc, buf = block(nblk - 1, carry, full=False)
     parity[0] = buf
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
@@ -502,6 +557,16 @@ def latent_paged_attention(q, cache, pos, table, *, n_head, v_width, scale,
         interpret=interpret,
     )(pos, table.reshape(-1), q, cache)
     return out.reshape(n_slot, -1)
+
+
+def full_block_share(pos):
+    """How often the latent kernel's full-block body engages: of the
+    blocks it runs for slots at positions `pos` (the live ones), the
+    share that are full — sum(pos // rows) over sum(pos // rows + 1).
+    Plain numpy, for a probe to print beside the kernel's time; nothing
+    that serves reads it."""
+    full = np.asarray(pos, np.int64) // _BLOCK_ROWS
+    return float(full.sum() / (full + 1).sum())
 
 
 # The platform switch. lax.platform_dependent (ops/quant_ops.py's idiom)

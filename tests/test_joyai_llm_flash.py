@@ -432,15 +432,31 @@ def test_latent_attention_lowers_under_its_scopes():
 
 # -- the latent paged kernel ---------------------------------------------
 
-def _latent_case(dtype, s=5, h=4, w=256, dv=128, bs=16, maxb=40, seed=0):
+# where the kernel's two bodies meet (ISSUE 44): both sides of a page's
+# edge, of the 256-row compute block's (a slot of 255 is one last block
+# of 16 pages, of 256 a full block and a last of one row) and of the
+# second block's, and the last row of a 48-page table
+_BOUNDARIES = (0, 15, 255, 256, 511, 512, 639, 767)
+_LATENT_CASES = {'ragged': {}, 'boundaries': dict(at=_BOUNDARIES, maxb=48)}
+
+
+def _latent_case(dtype, at=(0, 15, 300, 511, 639), h=4, w=256, dv=128,
+                 bs=16, maxb=40, seed=0):
     rng = np.random.default_rng(seed)
+    s = len(at)
     nb = s * maxb + 1
     pool = jnp.asarray(rng.normal(size=(nb, bs, w)), dtype)
     q = jnp.asarray(rng.normal(size=(s, h * w)), jnp.float32)
-    pos = jnp.asarray([0, 15, 300, 511, 639][:s], jnp.int32)
+    pos = jnp.asarray(at, jnp.int32)
     table = jnp.asarray(
         rng.permutation(nb - 1)[:s * maxb].reshape(s, maxb) + 1, jnp.int32)
     return q, pool, pos, table
+
+
+def _latent_kernel(q, pool, pos, table):
+    return np.asarray(ppa.latent_paged_attention(
+        q, pool, pos, table, n_head=4, v_width=128, scale=0.07,
+        interpret=True))
 
 
 def _latent_jnp(q, pool, pos, table, h, dv, scale):
@@ -451,19 +467,59 @@ def _latent_jnp(q, pool, pos, table, h, dv, scale):
             ctx, q, pool, pool, pos, table))
 
 
+@pytest.mark.parametrize('case', sorted(_LATENT_CASES))
 @pytest.mark.parametrize('dtype,tol', [('float32', 1e-5), ('bfloat16', 2e-2)])
-def test_latent_kernel_is_the_jnp_body(dtype, tol):
+def test_latent_kernel_is_the_jnp_body(dtype, tol, case):
     """Interpret mode: one copy of a page, scores over the whole row,
     the sum over its first v_width channels, ragged positions on shuffled
     pages — float32 to rounding, bfloat16 to its operands' rounding (the
     kernel rounds the query and the weights to the pool's dtype)."""
-    q, pool, pos, table = _latent_case(dtype)
-    got = np.asarray(ppa.latent_paged_attention(
-        q, pool, pos, table, n_head=4, v_width=128, scale=0.07,
-        interpret=True))
+    q, pool, pos, table = _latent_case(dtype, **_LATENT_CASES[case])
+    got = _latent_kernel(q, pool, pos, table)
     want = _latent_jnp(q, pool, pos, table, 4, 128, 0.07)
-    assert got.shape == (5, 4 * 128) and np.isfinite(got).all()
+    assert got.shape == (len(pos), 4 * 128) and np.isfinite(got).all()
     assert np.abs(got - want).max() <= tol * np.abs(want).max()
+
+
+# sha256 of the interpret-mode output's bytes as the PARENT of PR 44
+# (fe973ca: one body for every block, both masks on each) gives them for
+# the same case: a full block's rows are all <= pos, the select of every
+# row is the identity and sixteen copies are sixteen copies, so the two
+# bodies are the one body to the bit
+_PARENT_LATENT_SHA256 = {
+    ('boundaries', 'bfloat16'): '107ca8fadca9d463',
+    ('boundaries', 'float32'): '7c996fcd404c5756',
+    ('ragged', 'bfloat16'): '40b30a2e01906550',
+    ('ragged', 'float32'): '5f377fe9cab1f6e1',
+}
+
+
+@pytest.mark.parametrize('case,dtype', sorted(_PARENT_LATENT_SHA256))
+def test_latent_kernels_two_bodies_are_the_parents_one_to_the_bit(case,
+                                                                  dtype):
+    import hashlib
+    got = _latent_kernel(*_latent_case(dtype, **_LATENT_CASES[case]))
+    assert hashlib.sha256(got.tobytes()).hexdigest()[:16] == \
+        _PARENT_LATENT_SHA256[case, dtype]
+
+
+@pytest.mark.parametrize('dtype', ['float32', 'bfloat16'])
+def test_nan_past_pos_never_reaches_the_latent_kernels_output(dtype):
+    """Every pool row no slot attends — the rows past each slot's pos in
+    its last page, every page past it and the trash block — set to NaN:
+    the output is the clean pool's exactly. A full block has no such row
+    (which is why it needs no mask); the last block masks scores and
+    values both."""
+    q, pool, pos, table = _latent_case(dtype, **_LATENT_CASES['boundaries'])
+    attended = np.zeros(pool.shape[:2], bool)
+    for slot, p in enumerate(np.asarray(pos)):
+        for j in range(p + 1):
+            attended[table[slot, j // 16], j % 16] = True
+    assert not attended[0].any() and not attended.all(1).all()
+    poisoned = jnp.where(attended[..., None], pool, jnp.nan)
+    got = _latent_kernel(q, poisoned, pos, table)
+    assert np.isfinite(got).all()
+    assert np.array_equal(got, _latent_kernel(q, pool, pos, table))
 
 
 def test_the_kernels_say_by_name_what_they_do_not_take():

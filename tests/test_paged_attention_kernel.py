@@ -422,21 +422,43 @@ _PARENT_FLOAT32_JAXPR = {
 }
 
 
-@pytest.mark.parametrize('case', sorted(_PARENT_FLOAT32_JAXPR))
-def test_float32_pool_kernel_is_the_parents_program(case):
+# and a bfloat16 pool's (ISSUE 44, whose latent kernel shares _each_page,
+# _load_next_then_wait and the online softmax with this one and gives
+# _each_page a second form): hashed as the parent of PR 44 traced it, so
+# that "the other decode cells run the parent's program" is a test
+_PARENT_BFLOAT16_JAXPR = {
+    'olmoes': ((32, 8193, 16, 2048, 16, 16, 0, 256), 'a5e26fd590f2a121'),
+    'grouped_heads_window': ((8, 257, 16, 256, 4, 2, 128, 32),
+                             '6cd624c043ec7e2d'),
+}
+
+
+def _jaxpr_hash(shape, dtype):
     import hashlib
     import re
-    (s, nb, bs, d, h, n_kv, window, maxb), want = _PARENT_FLOAT32_JAXPR[case]
+    s, nb, bs, d, h, n_kv, window, maxb = shape
     jaxpr = jax.make_jaxpr(functools.partial(
         ppa.paged_attention, n_head=h, n_kv_head=n_kv, window=window,
         scale=(d // n_kv) ** -0.5))(
             _sds((s, h * (d // n_kv)), np.float32),
-            _sds((nb, bs, d), np.float32), _sds((nb, bs, d), np.float32),
+            _sds((nb, bs, d), dtype), _sds((nb, bs, d), dtype),
             _sds((s,), np.int32), _sds((s, maxb), np.int32))
     text = jaxpr.pretty_print(source_info=False, name_stack=False)
     # the call's own name and where it was written: this file's lines
     text = re.sub(r'name_and_src_info=[^\n]*', '', text)
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == want
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize('case', sorted(_PARENT_FLOAT32_JAXPR))
+def test_float32_pool_kernel_is_the_parents_program(case):
+    shape, want = _PARENT_FLOAT32_JAXPR[case]
+    assert _jaxpr_hash(shape, np.float32) == want
+
+
+@pytest.mark.parametrize('case', sorted(_PARENT_BFLOAT16_JAXPR))
+def test_bfloat16_pool_kernel_is_the_parents_program(case):
+    shape, want = _PARENT_BFLOAT16_JAXPR[case]
+    assert _jaxpr_hash(shape, jnp.bfloat16) == want
 
 
 # -- which body ---------------------------------------------------------------
