@@ -477,7 +477,7 @@ class Smoke(object):
             warmup_s = time.perf_counter() - t0
             attention = pred.stats.snapshot()['attention']
             con = concurrent(pred)
-            # both arms start from an empty prefix cache (bench.py's rule):
+            # both arms start from an empty prefix cache:
             # else the sequential arm re-serves prompts the first arm cached
             pred.block_manager.evict_all_prefixes()
             seq = [list(pred.generate(p, max_new_tokens=max_new,

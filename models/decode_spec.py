@@ -1,11 +1,12 @@
-"""What the pre-norm-era decoder builders (models/olmoe.py,
-models/exaone_moe.py) share when they build a decode spec for
-`inference.export_decode`: the step and chunked-prefill programs' feeds
-and samples, the block pools, the matrix / linear / norm / embedding
-helpers, the cache ops each program uses, and the spec dict. A model
-gives `DecodeSpecBuilder.build` its own `block(b, x, i, nfd, pos)` — one
-decoder layer over x ([S, D] with nfd 1, [1, C, D] with 2), writing and
-attending through `b.write` / `b.attend` — and `logits(b, x)`.
+"""What every decoder builder of models/ shares when it builds a decode
+spec for `inference.export_decode`: the step, chunked-prefill, verify and
+row programs' feeds and samples, the block pools, the matrix / linear /
+norm / embedding helpers, the cache ops each program uses, and the spec
+dict. A model gives `DecodeSpecBuilder.build` its own
+`block(b, x, i, nfd, pos)` — one decoder layer over x ([S, D] with nfd 1,
+[R, C, D] with 2), writing and attending through `b.write` / `b.attend` —
+and `logits(b, x)`; models/transformer.py, whose embedding is not
+`b.embed`, its `embed(b, ids)` as well.
 
 Layers come in two kinds (inference/kv_blocks.py): a full-attention
 layer's cache vars live in the pool every position of a request stays
@@ -31,6 +32,16 @@ row's; a chunk program is told the slot its row prefills by one feed more,
 `b.rows` what the program being built knows of its rows). Such a spec has
 no row program, and its 'recurrent' entry names the variables that no
 block pair copies.
+
+Three more things are a SPEC's and not a model's, and are said here once:
+the int8 pool (`kv_cache_dtype='int8'`: int8 pages, one float32 scale a
+cache position in `kv_ks_<i>` / `kv_vs_<i>`, the `_quant` ops), the verify
+program of speculative decoding (`draft_k=K`: [S, K + 1] rows scored in
+one dispatch) and `mp_shard=k` (the pools' D axis over the 'mp' mesh axis;
+a model annotates its weights through `b.shard` and pins its activations
+through `b.hint`). The ops behind the first two take full layers only:
+beside a window, a latent pool or recurrent layers either is refused here,
+by the layer kind's name.
 """
 from __future__ import annotations
 
@@ -41,27 +52,7 @@ import numpy as np
 import paddle_tpu as fluid
 from paddle_tpu.inference.kv_blocks import window_blocks_per_slot
 from paddle_tpu.ops.decode_ops import chunk_row_program
-
-
-def chunk_row_shape(chunk_progs, view_len, recurrent=False):
-    """(C, R) of the ONE row program a spec with these chunked-prefill
-    programs ({size: entry}) holds beside them — its largest chunk built
-    once more at [R, C], slices of R different prompts in one dispatch —
-    or None: ops/decode_ops.chunk_row_program's rule, given what the
-    largest chunk program's attention ops ARE (type, heads, window) and
-    the `view_len` positions a slot's table spans. Every decode spec
-    builder asks here; nothing is asked of a caller. A spec with
-    `recurrent` layers has none (ROADMAP.md Reach 3: its pad rows and its
-    riders' states are not built)."""
-    if recurrent:
-        return None
-    ops = chunk_progs[max(chunk_progs)]['program'].global_block().ops
-    return chunk_row_program(
-        list(chunk_progs),
-        [(op.type, op.attr('n_head'), op.attr('n_kv_head'),
-          op.attr('window')) for op in ops
-         if re.fullmatch(r'kv_\w*attention\w*', op.type)],
-        view_len)
+from paddle_tpu.parallel import shard_parameter
 
 
 def chunk_positions(start, C, R):
@@ -103,15 +94,18 @@ def last_logits(x, clen, C, R, D, logits_fn):
 class DecodeSpecBuilder(object):
     def __init__(self, vocab, d_model, kv_width, n_layer, max_slots,
                  max_cache_len, block_size, chunk_sizes, num_blocks, eos_id,
-                 kv_cache_dtype, weights_dtype, rms_eps, init_std,
-                 window_layers=(), window=0, v_width=0, recurrent=None,
-                 embed_std=None):
-        if kv_cache_dtype not in ('float32', 'bfloat16'):
-            raise ValueError("kv_cache_dtype must be 'float32' or "
-                             "'bfloat16', got %r" % (kv_cache_dtype,))
+                 kv_cache_dtype, weights_dtype='float32', rms_eps=1e-6,
+                 init_std=0.02, window_layers=(), window=0, v_width=0,
+                 recurrent=None, embed_std=None, draft_k=0, mp_shard=0):
+        if kv_cache_dtype not in ('float32', 'bfloat16', 'int8'):
+            raise ValueError("kv_cache_dtype must be 'float32', 'bfloat16' "
+                             "or 'int8', got %r" % (kv_cache_dtype,))
         self.S, self.T, self.D, self.BS = (int(max_slots),
                                            int(max_cache_len), int(d_model),
                                            int(block_size))
+        if not 0 <= int(draft_k) <= self.T - 2:
+            raise ValueError('draft_k must be in [0, max_cache_len - 2], '
+                             'got %r' % (draft_k,))
         if not 1 <= self.BS <= self.T:
             raise ValueError('block_size must be in [1, max_cache_len]')
         self.MAXB = -(-self.T // self.BS)
@@ -127,6 +121,8 @@ class DecodeSpecBuilder(object):
                                                    int(n_layer))
         self.eos_id = int(eos_id)
         self.kv_cache_dtype, self.weights_dtype = kv_cache_dtype, weights_dtype
+        self.int8 = kv_cache_dtype == 'int8'
+        self.draft_k, self.mp = int(draft_k), int(mp_shard or 0)
         self.rms_eps, self.init_std = float(rms_eps), float(init_std)
         # the embedding table's own scale (models/qwen3_next.py says why a
         # model whose mixers write O(1) updates seeds one): init_std's
@@ -149,6 +145,16 @@ class DecodeSpecBuilder(object):
                           for i, states in (recurrent or {}).items()}
         if set(self.recurrent) & self.window_layers:
             raise ValueError('a layer is recurrent or a window layer')
+        # the _quant and the verify ops attend full layers' K and V pools
+        for what, asked in (("kv_cache_dtype='int8'", self.int8),
+                            ('draft_k=%d' % self.draft_k, self.draft_k)):
+            for kind, has in (('window layers', self.window_layers),
+                              ('a latent pool (v_width)', self.v_width),
+                              ('recurrent layers', self.recurrent)):
+                if asked and has:
+                    raise ValueError('%s is not built beside %s: its ops '
+                                     'take full layers only' % (what, kind))
+        self.param_shardings, self.state_shardings = {}, {}
         self.startup = fluid.Program()
         self._io = None      # the cache ops of the program being built
         self.rows = None     # what that program knows of its rows
@@ -182,31 +188,71 @@ class DecodeSpecBuilder(object):
                     0.0, self.embed_std)))
         return fluid.layers.cast(x, 'float32')
 
+    def shard(self, var, spec):
+        """With `mp_shard`, `var` partitioned as `spec` over the mesh
+        (parallel/api.shard_parameter), noted for export_decode."""
+        if self.mp:
+            shard_parameter(var, spec)
+            self.param_shardings[var.name] = tuple(spec)
+        return var
+
+    def hint(self, x, spec=()):
+        """Replicate (or re-shard) an activation at a contraction
+        boundary; the identity without `mp_shard`."""
+        return fluid.layers.sharding_hint(x, spec) if self.mp else x
+
     def cache_names(self, i):
-        """Layer i's pools, in the order `write` takes their rows; a
-        recurrent layer's per-slot states, in the order `state` gives."""
+        """Layer i's pools, in the order `write` takes their rows (the
+        int8 pool: then each one's scales); a recurrent layer's per-slot
+        states, in the order `state` gives."""
         if i in self.recurrent:
             return ['rec_%s_%d' % (name, i) for name in self.recurrent[i]]
         if self.v_width:
             return ['kv_c_%d' % i]
-        return ['kv_k_%d' % i, 'kv_v_%d' % i]
+        return (['kv_k_%d' % i, 'kv_v_%d' % i]
+                + ['kv_ks_%d' % i, 'kv_vs_%d' % i] * self.int8)
 
     def _caches(self, i):
-        zero = fluid.initializer.ConstantInitializer(0.0)
+        def var(name, shape, dtype, value=0.0):
+            return fluid.layers.create_parameter(
+                shape, dtype, attr=fluid.ParamAttr(name=name, trainable=False),
+                default_initializer=fluid.initializer.ConstantInitializer(
+                    value))
+        names = self.cache_names(i)
         if i in self.recurrent:
-            return tuple(fluid.layers.create_parameter(
-                [self.S] + [int(n) for n in shape], dtype,
-                attr=fluid.ParamAttr(name=name, trainable=False),
-                default_initializer=zero)
-                for name, (shape, dtype) in zip(
-                    self.cache_names(i), self.recurrent[i].values()))
+            return tuple(var(name, [self.S] + [int(n) for n in shape], dtype)
+                         for name, (shape, dtype) in zip(
+                             names, self.recurrent[i].values()))
         nb = self.NBW if i in self.window_layers else self.NB
-        return tuple(fluid.layers.create_parameter(
-            [nb, self.BS, self.kv_width], self.kv_cache_dtype,
-            attr=fluid.ParamAttr(name=name, trainable=False),
-            default_initializer=zero) for name in self.cache_names(i))
+        pools = names[:2] if self.int8 else names
+        made = [var(name, [nb, self.BS, self.kv_width], self.kv_cache_dtype)
+                for name in pools]
+        if self.mp:                 # the D axis over the mesh, scales whole
+            for pool in made:
+                self.shard(pool, (None, None, 'mp'))
+                self.state_shardings[pool.name] = (None, None, 'mp')
+        return tuple(made + [var(name, [nb, self.BS], 'float32', 1.0)
+                             for name in names[len(pools):]])
 
     # -- the cache ops of the program being built ------------------------
+    def _ops(self, write, write_quant, attend, attend_quant, pos, tables,
+             fresh=False):
+        """The write / attend layers of one kind of program (step, chunk,
+        verify) bound to its position and table feeds, as `write` and
+        `attend` below call them: the float pair, or over the int8 pool
+        the _quant pair (`fresh`: the chunk's, which attends the rows it
+        was handed beside the pages they were rounded into)."""
+        if self.int8:
+            return {'write': lambda c, s, kv: write_quant(c, s, kv, pos,
+                                                          tables[0]),
+                    'attend': lambda q, kc, ks, vc, vs, k, v, **kw:
+                        attend_quant(q, kc, ks, vc, vs,
+                                     *((k, v) if fresh else ()),
+                                     pos, tables[0], **kw)}
+        return {'write': lambda c, kv, kind: write(c, kv, pos, tables[kind]),
+                'attend': lambda q, kc, vc, kind, **kw:
+                    attend(q, kc, vc, pos, tables[kind], **kw)}
+
     def state(self, i):
         """Recurrent layer i's per-slot state variables ([max_slots, ...],
         cache_names' order), and through `self.rows` what the program
@@ -217,16 +263,28 @@ class DecodeSpecBuilder(object):
             raise ValueError('layer %d keeps no recurrent state' % i)
         return self._caches(i)
 
+    def pools(self, i):
+        """Layer i's pools, declared before its rows are there to write:
+        for a block whose pools come ahead of its own weights in the
+        startup program (models/transformer.py), which draws the weights
+        in the order it first met their names."""
+        return self._caches(i)
+
     def write(self, i, *rows):
         """Layer i's pools with this program's rows written, one tensor
         of rows a pool (cache_names' order: K and V, or the one latent
-        row): the pools, a tuple as long."""
+        row): the pools, a tuple as long, each as `attend` takes it (an
+        int8 pool: its pages, its scales and the rows as handed in)."""
         caches = self._caches(i)
-        if len(rows) != len(caches):
+        n = len(caches) // 2 if self.int8 else len(caches)
+        if len(rows) != n:
             raise ValueError('layer %d keeps %d pool(s) %r, got %d tensors '
-                             'of rows' % (i, len(caches),
-                                          self.cache_names(i), len(rows)))
+                             'of rows' % (i, n, self.cache_names(i)[:n],
+                                          len(rows)))
         write = self._io['write']
+        if self.int8:
+            return tuple(tuple(write(c, s, r)) + (r,)
+                         for c, s, r in zip(caches[:n], caches[n:], rows))
         kind = i in self.window_layers
         return tuple(write(c, r, kind) for c, r in zip(caches, rows))
 
@@ -236,73 +294,97 @@ class DecodeSpecBuilder(object):
         is a window layer); over a latent pool — `kcache` and `vcache`
         the same pool — the op is told where in the row the value
         lies."""
+        kw = {'n_head': n_head, 'scale': scale}
+        if self.int8:
+            (kc, ks, k), (vc, vs, v) = kcache, vcache
+            return self._io['attend'](q, kc, ks, vc, vs, k, v, **kw)
+        # what the step's and the chunks' float ops alone take: said
+        # where it is not their default
         kind = i in self.window_layers
-        latent = {'v_width': self.v_width} if self.v_width else {}
-        return self._io['attend'](
-            q, kcache, vcache, kind, n_head=n_head, n_kv_head=n_kv_head,
-            window=self.window if kind else 0, scale=scale, **latent)
+        if n_kv_head is not None:
+            kw['n_kv_head'] = n_kv_head
+        if kind:
+            kw['window'] = self.window
+        if self.v_width:
+            kw['v_width'] = self.v_width
+        return self._io['attend'](q, kcache, vcache, kind, **kw)
 
     # -- the programs ----------------------------------------------------
-    def build(self, block, logits):
+    def build(self, block, logits, embed=None):
+        """The spec of a model whose layer i is `block(b, x, i, nfd, pos)`
+        and whose head is `logits(b, x)`, over `embed(b, ids)` (`b.embed`
+        unless the model has its own). Programs are opened step, chunks
+        ascending, verify, row program, and each creates its parameters
+        in the model's order: the startup program draws the weights in
+        the order it first met them."""
         S, MAXB, D = self.S, self.MAXB, self.D
         L = fluid.layers
         windowed = bool(self.window_layers)
+        embed = embed or DecodeSpecBuilder.embed
 
-        def table_feed(name, rows):
-            return L.data(name=name, shape=[rows, MAXB],
-                          append_batch_size=False, dtype='int32')
+        def data(name, shape, dtype='int32'):
+            return L.data(name=name, shape=shape, append_batch_size=False,
+                          dtype=dtype)
 
-        # ---- decode step: [S] slots advance one token through the pool
-        step_p = fluid.Program()
-        with fluid.program_guard(step_p, self.startup):
-            tokens = L.data(name='tokens', shape=[S, 1],
-                            append_batch_size=False, dtype='int64')
-            pos = L.data(name='pos', shape=[S, 1],
-                         append_batch_size=False, dtype='int32')
-            tables = [table_feed('block_tables', S)]
+        def table_feeds(name, rows):
+            return [data(name % kind, [rows, MAXB])
+                    for kind in ['block'] + ['window'] * windowed]
+
+        def step_program(R, write, write_quant, attend, attend_quant):
+            """[S] slots advance R tokens through the pool: the decode
+            step (R = 1, x [S, D]) and the verify program (R = draft_k +
+            1, x [S, R, D]), whose pad rows carry pos = MAXB * BS, the
+            span guard's trash route: a pad row can never land in a
+            SHARED full prefix block the way pos = max_cache_len could
+            when that is not block-aligned."""
+            pad_pos = MAXB * self.BS if R > 1 else 0
+            sp = fluid.Program()
+            with fluid.program_guard(sp, self.startup):
+                tokens = data('tokens', [S, R], 'int64')
+                pos = data('pos', [S, R])
+                tables = table_feeds('%s_tables', S)
+                self.rows = {'block_tables': tables[0]}
+                self._io = self._ops(write, write_quant, attend,
+                                     attend_quant, pos, tables)
+                x = embed(self, tokens)
+                # a pad row's pos lies past every table of max_cache_len
+                # rows a block may gather from by position: an
+                # out-of-range gather is NaN-filled, the pad rows' NaN
+                # k / v would land in the TRASH BLOCK, and 0 * NaN in a
+                # real row's masked attention would poison the batch
+                at = L.clip(pos, 0, self.T - 1) if R > 1 else pos
+                for i in range(self.n_layer):
+                    x = block(self, x, i, 2 if R > 1 else 1, at)
+                out = logits(self, x)                 # [S, V] / [S, R, V]
+            samples = {'tokens': np.zeros((S, R), np.int64),
+                       'pos': np.full((S, R), pad_pos, np.int32),
+                       'block_tables': np.zeros((S, MAXB), np.int32)}
             if windowed:
-                tables.append(table_feed('window_tables', S))
-            self.rows = {'block_tables': tables[0]}
-            self._io = {
-                'write': lambda c, kv, kind: L.kv_block_write(
-                    c, kv, pos, tables[kind]),
-                'attend': lambda q, kc, vc, kind, **kw:
-                    L.kv_block_attention(q, kc, vc, pos, tables[kind],
-                                         **kw)}
-            x = self.embed(tokens)                               # [S, D]
-            for i in range(self.n_layer):
-                x = block(self, x, i, 1, pos)
-            step_logits = logits(self, x)                        # [S, V]
-        step_feeds = (['tokens', 'pos', 'block_tables']
-                      + ['window_tables'] * windowed)
+                samples['window_tables'] = np.zeros((S, MAXB), np.int32)
+            return {'program': sp,
+                    'feeds': (['tokens', 'pos', 'block_tables']
+                              + ['window_tables'] * windowed),
+                    'samples': samples, 'fetches': [out.name]}
 
         # ---- chunked prefill: one CHUNK of one prompt a row; every
-        # chunk size at ONE row and, where the shapes give one
-        # (chunk_row_shape), the largest once more at R rows
+        # chunk size at ONE row and, where the shapes give one, the
+        # largest once more at R rows
         def chunk_program(C, R=1):
             cp = fluid.Program()
             with fluid.program_guard(cp, self.startup):
-                chunk_ids = L.data(name='chunk_ids', shape=[R, C],
-                                   append_batch_size=False, dtype='int64')
-                start = L.data(name='start', shape=[R, 1],
-                               append_batch_size=False, dtype='int32')
-                clen = L.data(name='chunk_len', shape=[R, 1],
-                              append_batch_size=False, dtype='int32')
-                btabs = [table_feed('block_table', R)]
-                if windowed:
-                    btabs.append(table_feed('window_table', R))
+                chunk_ids = data('chunk_ids', [R, C], 'int64')
+                start = data('start', [R, 1])
+                clen = data('chunk_len', [R, 1])
+                btabs = table_feeds('%s_table', R)
                 self.rows = {'start': start, 'chunk_len': clen}
                 if self.recurrent:
-                    self.rows['state_slot'] = L.data(
-                        name='state_slot', shape=[R, 1],
-                        append_batch_size=False, dtype='int32')
-                self._io = {
-                    'write': lambda c, kv, kind: L.kv_block_chunk_write(
-                        c, kv, start, btabs[kind]),
-                    'attend': lambda q, kc, vc, kind, **kw:
-                        L.kv_block_chunk_attention(
-                            q, kc, vc, start, btabs[kind], **kw)}
-                x = self.embed(chunk_ids)                       # [R, C, D]
+                    self.rows['state_slot'] = data('state_slot', [R, 1])
+                self._io = self._ops(
+                    L.kv_block_chunk_write, L.kv_block_chunk_write_quant,
+                    L.kv_block_chunk_attention,
+                    L.kv_block_chunk_attention_quant, start, btabs,
+                    fresh=True)
+                x = embed(self, chunk_ids)                      # [R, C, D]
                 posv = chunk_positions(start, C, R)          # [C] / [R, C]
                 for i in range(self.n_layer):
                     x = block(self, x, i, 2, posv)
@@ -324,25 +406,36 @@ class DecodeSpecBuilder(object):
                 'samples': samples,
                 'fetches': [chunk_logits.name]}
 
+        step = step_program(1, L.kv_block_write, L.kv_block_write_quant,
+                            L.kv_block_attention,
+                            L.kv_block_attention_quant)
         chunk_progs = {C: chunk_program(C) for C in self.chunks}
-        rows = chunk_row_shape(chunk_progs, MAXB * self.BS,
-                               recurrent=bool(self.recurrent))
+        verify = self.draft_k and step_program(
+            self.draft_k + 1, L.kv_block_verify_write,
+            L.kv_block_verify_write_quant, L.kv_block_verify_attention,
+            L.kv_block_verify_attention_quant)
+        # the row program, after everything else: (C, R) by
+        # ops/decode_ops.chunk_row_program's rule, given what the largest
+        # chunk program's attention ops ARE (type, heads, window) and the
+        # positions a slot's table spans. A spec with recurrent layers has
+        # none (ROADMAP.md Reach 3: its pad rows and its riders' states
+        # are not built)
+        rows = None if self.recurrent else chunk_row_program(
+            list(chunk_progs),
+            [(op.type, op.attr('n_head'), op.attr('n_kv_head'),
+              op.attr('window'))
+             for op in chunk_progs[self.chunks[-1]]['program']
+             .global_block().ops
+             if re.fullmatch(r'kv_\w*attention\w*', op.type)],
+            MAXB * self.BS)
         chunk_rows = (dict(chunk_program(*rows), size=rows[0], rows=rows[1])
                       if rows is not None else None)
         self._io = self.rows = None
 
-        samples = {'tokens': np.zeros((S, 1), np.int64),
-                   'pos': np.zeros((S, 1), np.int32),
-                   'block_tables': np.zeros((S, MAXB), np.int32)}
-        if windowed:
-            samples['window_tables'] = np.zeros((S, MAXB), np.int32)
         spec = {'startup': self.startup,
                 'block_size': self.BS, 'num_blocks': self.NB,
                 'max_blocks_per_slot': MAXB,
-                'step': {'program': step_p, 'feeds': step_feeds,
-                         'samples': samples,
-                         'fetches': [step_logits.name]},
-                'chunk': chunk_progs,
+                'step': step, 'chunk': chunk_progs,
                 'cache_vars': [n for i in range(self.n_layer)
                                for n in self.cache_names(i)],
                 'max_slots': S, 'max_cache_len': self.T,
@@ -352,6 +445,12 @@ class DecodeSpecBuilder(object):
             spec['cache_kind'] = 'latent'
         if chunk_rows is not None:
             spec['chunk_rows'] = chunk_rows
+        if verify:
+            spec['verify'], spec['draft_k'] = verify, self.draft_k
+        if self.mp:
+            spec['mesh_axes'] = {'mp': self.mp}
+            spec['param_shardings'] = dict(self.param_shardings)
+            spec['state_shardings'] = dict(self.state_shardings)
         if self.recurrent:
             spec['recurrent'] = {
                 'cache_vars': [n for i in sorted(self.recurrent)

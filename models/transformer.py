@@ -232,7 +232,7 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
        'step':    {'program', 'feeds', 'samples', 'fetches'},
        'chunk':   {chunk_size: {'program', 'feeds', 'samples', 'fetches'}},
        'chunk_rows': {..., 'size': C, 'rows': R},   # where the shapes
-                                     # give one (decode_spec.chunk_row_shape)
+                                     # give one (DecodeSpecBuilder.build)
        'cache_vars': [names],        # the KV pool [NB, block_size, d_model]
        'block_size', 'num_blocks', 'max_blocks_per_slot',
        'max_slots', 'max_cache_len', 'eos_id', 'vocab'}
@@ -274,358 +274,92 @@ def build_decode_spec(vocab=67, d_model=32, n_head=4, n_layer=2, d_ff=64,
     the same paged cache (KV written speculatively for every fed row,
     row i attending j <= pos[s, i], so row i's logits match the plain
     step's at the same accepted prefix). The verify program is built
-    LAST and shares every weight by name, so the step/chunk programs
-    (and the weights the per-op rng streams draw) are byte-for-byte
-    what a draft_k=0 build produces. The serving tier drafts host-side
-    and rolls rejected rows back (inference/decoding.py).
+    after the step and the chunk programs and shares every weight by
+    name, so those programs (and the weights the per-op rng streams
+    draw) are byte-for-byte what a draft_k=0 build produces. The serving
+    tier drafts host-side and rolls rejected rows back
+    (inference/decoding.py).
+
+    The feeds, the samples, the programs' order, the pools and the spec
+    dict are models/decode_spec.py DecodeSpecBuilder's, as for every
+    other decoder of models/; this file gives it the post-LN block, the
+    scaled embedding under the sinusoid position table, and the head.
     """
-    import numpy as np
-    from paddle_tpu.parallel import shard_parameter
-    from .decode_spec import (chunk_positions, chunk_row_shape,
-                              last_logits)
+    from .decode_spec import DecodeSpecBuilder
     PA = fluid.ParamAttr
-    if kv_cache_dtype not in ('float32', 'bfloat16', 'int8'):
-        raise ValueError("kv_cache_dtype must be 'float32', 'bfloat16' "
-                         "or 'int8', got %r" % (kv_cache_dtype,))
-    if not 0 <= int(draft_k) <= int(max_cache_len) - 2:
-        raise ValueError('draft_k must be in [0, max_cache_len - 2], '
-                         'got %r' % (draft_k,))
-    kv_int8 = kv_cache_dtype == 'int8'
-    S, T, D = int(max_slots), int(max_cache_len), int(d_model)
-    BS = int(block_size)
+    L = fluid.layers
+    T, D = int(max_cache_len), int(d_model)
     if D % n_head or D % 2:
         raise ValueError("d_model must be even and divisible by n_head")
-    if not 1 <= BS <= T:
-        raise ValueError("block_size must be in [1, max_cache_len]")
-    MAXB = -(-T // BS)                     # logical blocks per slot
-    NB = int(num_blocks) if num_blocks is not None else S * MAXB + 1
-    if NB < 2:
-        raise ValueError("num_blocks must be >= 2 (block 0 is the "
-                         "reserved trash block)")
-    chunks = sorted({int(c) for c in chunk_sizes})
-    if not chunks or chunks[0] < 1 or chunks[-1] > T:
-        raise ValueError("chunk_sizes must be in [1, max_cache_len]")
     mp = int(mp_shard or 0)
-    if mp:
-        if n_head % mp or d_ff % mp:
-            raise ValueError(
-                'mp_shard=%d must divide n_head=%d and d_ff=%d (the D '
-                'axis shards by whole head groups)' % (mp, n_head, d_ff))
-    startup = fluid.Program()
+    if mp and (n_head % mp or d_ff % mp):
+        raise ValueError(
+            'mp_shard=%d must divide n_head=%d and d_ff=%d (the D '
+            'axis shards by whole head groups)' % (mp, n_head, d_ff))
     pe = _pe_table(T, D)
-    cache_vars = []
-    for i in range(n_layer):
-        cache_vars += ['kv_k_%d' % i, 'kv_v_%d' % i]
-        if kv_int8:
-            cache_vars += ['kv_ks_%d' % i, 'kv_vs_%d' % i]
 
-    # name -> partition spec for export_decode (collected from the
-    # shard_parameter annotations as each program is built)
-    param_shardings = {}
-    state_shardings = {}
+    def param(x, name):
+        return x.block.program.global_block().var(name)
 
-    def _shard(var, spec):
-        if mp:
-            shard_parameter(var, spec)
-            param_shardings[var.name] = tuple(spec)
-        return var
+    def fc(b, x, size, nfd, name, bias=None, act=None):
+        """x through the matrix `name` (and bias `bias`), its columns
+        over the mesh where the spec is sharded."""
+        out = L.fc(x, size, num_flatten_dims=nfd, act=act,
+                   param_attr=PA(name=name),
+                   bias_attr=PA(name=bias) if bias else False)
+        b.shard(param(x, name), (None, 'mp'))
+        return out
 
-    def _hint(x, spec=()):
-        """Replicate (or re-shard) an activation at a contraction
-        boundary; identity when unsharded."""
-        return fluid.layers.sharding_hint(x, spec) if mp else x
+    def embed(b, ids):
+        # the position table first: the startup program's order
+        L.create_parameter(
+            [T, D], 'float32', attr=PA(name='pos_enc_w', trainable=False),
+            default_initializer=fluid.initializer.NumpyArrayInitializer(pe))
+        x = L.embedding(ids, size=[vocab, D], param_attr=PA(name='dec_emb_w'))
+        b.shard(param(x, 'dec_emb_w'), (None, 'mp'))
+        return L.scale(x, scale=float(D ** 0.5))
 
-    def const_param(name, shape, init, dtype='float32', spec=None):
-        p = fluid.layers.create_parameter(
-            shape, dtype, attr=PA(name=name, trainable=False),
-            default_initializer=init)
-        if spec is not None:
-            _shard(p, spec)
-        return p
-
-    def caches(i):
-        zero = fluid.initializer.ConstantInitializer(0.0)
-        dt = 'int8' if kv_int8 else kv_cache_dtype
-        cspec = (None, None, 'mp') if mp else None
-        k = const_param('kv_k_%d' % i, [NB, BS, D], zero, dt, spec=cspec)
-        v = const_param('kv_v_%d' % i, [NB, BS, D], zero, dt, spec=cspec)
-        if mp:
-            state_shardings['kv_k_%d' % i] = (None, None, 'mp')
-            state_shardings['kv_v_%d' % i] = (None, None, 'mp')
-        if not kv_int8:
-            return k, v
-        one = fluid.initializer.ConstantInitializer(1.0)
-        return (k, v, const_param('kv_ks_%d' % i, [NB, BS], one),
-                const_param('kv_vs_%d' % i, [NB, BS], one))
-
-    def pe_param():
-        return const_param(
-            'pos_enc_w', [T, D], fluid.initializer.NumpyArrayInitializer(pe))
-
-    def qkv(x, i, nfd):
-        def proj(tag):
-            w_attr = PA(name='l%d_%s_w' % (i, tag))
-            out = fluid.layers.fc(x, D, num_flatten_dims=nfd,
-                                  param_attr=w_attr, bias_attr=False)
-            return out
-        q, k, v = proj('q'), proj('k'), proj('v')
-        if mp:
-            gb = x.block.program.global_block()
-            for tag in ('q', 'k', 'v'):
-                _shard(gb.var('l%d_%s_w' % (i, tag)), (None, 'mp'))
-        return q, k, v
-
-    def block_tail(x, a, i, nfd):
-        """Shared residual+LN+FFN tail; `nfd` = 1 (step, [S, D]) or 2
-        (chunk / verify, [1, C, D] / [S, R, D]) — same [D]-shaped params
-        either way. The mp replicate hints: attention context gathers
-        before the o projection, h before f2, and each projection output
-        before its LN — every contraction stays full-width."""
-        a = _hint(a)
-        o = fluid.layers.fc(a, D, num_flatten_dims=nfd,
-                            param_attr=PA(name='l%d_o_w' % i),
-                            bias_attr=False)
-        if mp:
-            _shard(a.block.program.global_block().var('l%d_o_w' % i),
-                   (None, 'mp'))
-        o = _hint(o)
-        x = fluid.layers.layer_norm(
-            x + o, begin_norm_axis=nfd, param_attr=PA(name='l%d_ln1_s' % i),
-            bias_attr=PA(name='l%d_ln1_b' % i))
+    def block(b, x, i, nfd, pos):
+        """One post-LN decoder layer over x ([S, D] with nfd 1; [R, C, D]
+        of a chunk, [S, R, D] of the verify program with 2) — the same
+        [D]-shaped parameters either way. The first adds the position
+        table's rows at `pos` to the scaled embedding: the SAME table in
+        every program, so positional values agree bit for bit. The mp
+        replicate hints: attention context gathers before the o
+        projection, h before f2, and each projection output before its LN
+        — every contraction stays full-width."""
+        if i == 0:
+            rows = L.gather(param(x, 'pos_enc_w'), pos)
+            if nfd == 2:
+                rows = L.reshape(rows, shape=list(x.shape))
+            x = b.hint(L.elementwise_add(x, rows))
+        b.pools(i)      # before q, k, v: the order the startup program draws in
+        p = 'l%d_' % i
+        q, k, v = (fc(b, x, D, nfd, p + tag + '_w') for tag in 'qkv')
+        kcache, vcache = b.write(i, k, v)
+        a = b.hint(b.attend(i, q, kcache, vcache, n_head))
+        o = b.hint(fc(b, a, D, nfd, p + 'o_w'))
+        x = L.layer_norm(x + o, begin_norm_axis=nfd,
+                         param_attr=PA(name=p + 'ln1_s'),
+                         bias_attr=PA(name=p + 'ln1_b'))
         # pin the LN output replicated too: left unconstrained, GSPMD may
         # shard it over 'mp' and the next projection's contraction turns
         # into a partial-sum all-reduce — reordered accumulation, bit
         # drift vs the single-chip artifact
-        x = _hint(x)
-        h = fluid.layers.fc(x, d_ff, num_flatten_dims=nfd, act='relu',
-                            param_attr=PA(name='l%d_f1_w' % i),
-                            bias_attr=PA(name='l%d_f1_b' % i))
-        if mp:
-            gb = x.block.program.global_block()
-            _shard(gb.var('l%d_f1_w' % i), (None, 'mp'))
-            _shard(gb.var('l%d_f1_b' % i), ('mp',))
-        h = _hint(h)
-        f = fluid.layers.fc(h, D, num_flatten_dims=nfd,
-                            param_attr=PA(name='l%d_f2_w' % i),
-                            bias_attr=PA(name='l%d_f2_b' % i))
-        if mp:
-            gb = h.block.program.global_block()
-            _shard(gb.var('l%d_f2_w' % i), (None, 'mp'))
-        f = _hint(f)
-        return _hint(fluid.layers.layer_norm(
-            x + f, begin_norm_axis=nfd, param_attr=PA(name='l%d_ln2_s' % i),
-            bias_attr=PA(name='l%d_ln2_b' % i)))
+        x = b.hint(x)
+        h = fc(b, x, d_ff, nfd, p + 'f1_w', bias=p + 'f1_b', act='relu')
+        b.shard(param(x, p + 'f1_b'), ('mp',))
+        f = b.hint(fc(b, b.hint(h), D, nfd, p + 'f2_w', bias=p + 'f2_b'))
+        return b.hint(L.layer_norm(x + f, begin_norm_axis=nfd,
+                                   param_attr=PA(name=p + 'ln2_s'),
+                                   bias_attr=PA(name=p + 'ln2_b')))
 
-    def embed(ids):
-        x = fluid.layers.embedding(ids, size=[vocab, D],
-                                   param_attr=PA(name='dec_emb_w'))
-        if mp:
-            _shard(x.block.program.global_block().var('dec_emb_w'),
-                   (None, 'mp'))
-        return fluid.layers.scale(x, scale=float(D ** 0.5))
+    def logits(b, x):       # [S, D] / one chunk row [1, D] / [S, R, D]
+        return b.hint(fc(b, x, vocab, len(x.shape) - 1, 'out_w'))
 
-    def out_logits(x, nfd=1):
-        lg = fluid.layers.fc(x, vocab, num_flatten_dims=nfd,
-                             param_attr=PA(name='out_w'), bias_attr=False)
-        if mp:
-            _shard(x.block.program.global_block().var('out_w'),
-                   (None, 'mp'))
-        return _hint(lg)
-
-    # ---- decode-step program: [S] slots advance one token through the
-    # block pool (tables fed from the host scheduler) ----------------------
-    step_p = fluid.Program()
-    with fluid.program_guard(step_p, startup):
-        tokens = fluid.layers.data(name='tokens', shape=[S, 1],
-                                   append_batch_size=False, dtype='int64')
-        pos = fluid.layers.data(name='pos', shape=[S, 1],
-                                append_batch_size=False, dtype='int32')
-        tables = fluid.layers.data(name='block_tables', shape=[S, MAXB],
-                                   append_batch_size=False, dtype='int32')
-        table = pe_param()
-        x = embed(tokens)                                       # [S, D]
-        x = fluid.layers.elementwise_add(x,
-                                         fluid.layers.gather(table, pos))
-        x = _hint(x)
-        for i in range(n_layer):
-            if kv_int8:
-                kcache, vcache, kscale, vscale = caches(i)
-                q, k, v = qkv(x, i, 1)
-                kcache, kscale = fluid.layers.kv_block_write_quant(
-                    kcache, kscale, k, pos, tables)
-                vcache, vscale = fluid.layers.kv_block_write_quant(
-                    vcache, vscale, v, pos, tables)
-                a = fluid.layers.kv_block_attention_quant(
-                    q, kcache, kscale, vcache, vscale, pos, tables,
-                    n_head)
-            else:
-                kcache, vcache = caches(i)
-                q, k, v = qkv(x, i, 1)
-                kcache = fluid.layers.kv_block_write(kcache, k, pos,
-                                                     tables)
-                vcache = fluid.layers.kv_block_write(vcache, v, pos,
-                                                     tables)
-                a = fluid.layers.kv_block_attention(q, kcache, vcache,
-                                                    pos, tables, n_head)
-            x = block_tail(x, a, i, 1)
-        step_logits = out_logits(x)                             # [S, V]
-
-    # ---- chunked-prefill programs: one CHUNK of one prompt a row; every
-    # chunk size at ONE row, and where the shapes allow it (chunk_rows,
-    # below) the largest once more at R rows: slices of R different
-    # prompts in one dispatch ------------------------------------------
-    def chunk_program(C, R=1):
-        cp = fluid.Program()
-        with fluid.program_guard(cp, startup):
-            chunk_ids = fluid.layers.data(name='chunk_ids', shape=[R, C],
-                                          append_batch_size=False,
-                                          dtype='int64')
-            start = fluid.layers.data(name='start', shape=[R, 1],
-                                      append_batch_size=False,
-                                      dtype='int32')
-            clen = fluid.layers.data(name='chunk_len', shape=[R, 1],
-                                     append_batch_size=False,
-                                     dtype='int32')
-            btab = fluid.layers.data(name='block_table', shape=[R, MAXB],
-                                     append_batch_size=False,
-                                     dtype='int32')
-            table = pe_param()
-            x = embed(chunk_ids)                               # [R, C, D]
-            posv = chunk_positions(start, C, R)              # [C] / [R, C]
-            pe_c = fluid.layers.gather(table, posv)            # [R*C, D]
-            x = fluid.layers.elementwise_add(
-                x, fluid.layers.reshape(pe_c, shape=[R, C, D]))
-            x = _hint(x)
-            for i in range(n_layer):
-                if kv_int8:
-                    kcache, vcache, kscale, vscale = caches(i)
-                    q, k, v = qkv(x, i, 2)
-                    kcache, kscale = \
-                        fluid.layers.kv_block_chunk_write_quant(
-                            kcache, kscale, k, start, btab)
-                    vcache, vscale = \
-                        fluid.layers.kv_block_chunk_write_quant(
-                            vcache, vscale, v, start, btab)
-                    a = fluid.layers.kv_block_chunk_attention_quant(
-                        q, kcache, kscale, vcache, vscale, k, v, start,
-                        btab, n_head)
-                else:
-                    kcache, vcache = caches(i)
-                    q, k, v = qkv(x, i, 2)
-                    kcache = fluid.layers.kv_block_chunk_write(
-                        kcache, k, start, btab)
-                    vcache = fluid.layers.kv_block_chunk_write(
-                        vcache, v, start, btab)
-                    a = fluid.layers.kv_block_chunk_attention(
-                        q, kcache, vcache, start, btab, n_head)
-                x = block_tail(x, a, i, 2)
-            chunk_logits = last_logits(x, clen, C, R, D,
-                                       out_logits)             # [R, V]
-        return {
-            'program': cp,
-            'feeds': ['chunk_ids', 'start', 'chunk_len', 'block_table'],
-            'samples': {'chunk_ids': np.zeros((R, C), np.int64),
-                        'start': np.zeros((R, 1), np.int32),
-                        'chunk_len': np.ones((R, 1), np.int32),
-                        'block_table': np.zeros((R, MAXB), np.int32)},
-            'fetches': [chunk_logits.name]}
-
-    chunk_progs = {C: chunk_program(C) for C in chunks}
-
-    # ---- verify program (ISSUE 17, built LAST so the op-creation rng
-    # order of step/chunk — and thus the weights — is untouched):
-    # [S, R] rows (R = draft_k + 1) score in one dispatch; pad rows
-    # carry pos = MAXB * BS, the span guard's trash route, so a pad row
-    # can never land in a SHARED full prefix block the way pos = T
-    # could when T is not block-aligned --------------------------------
-    verify = None
-    if draft_k:
-        R = int(draft_k) + 1
-        vp = fluid.Program()
-        with fluid.program_guard(vp, startup):
-            vtok = fluid.layers.data(name='tokens', shape=[S, R],
-                                     append_batch_size=False,
-                                     dtype='int64')
-            vpos = fluid.layers.data(name='pos', shape=[S, R],
-                                     append_batch_size=False,
-                                     dtype='int32')
-            vtab = fluid.layers.data(name='block_tables',
-                                     shape=[S, MAXB],
-                                     append_batch_size=False,
-                                     dtype='int32')
-            table = pe_param()
-            x = embed(vtok)                                 # [S, R, D]
-            # clamp the PE GATHER index only (pad rows carry
-            # pos = MAXB * BS, past the PE table): an unclamped OOB
-            # gather is NaN-filled under jnp.take, the pad rows' NaN
-            # k/v would land in the TRASH BLOCK, and 0 * NaN in every
-            # real row's masked attention would poison the whole batch
-            pe_idx = fluid.layers.clip(vpos, 0, T - 1)
-            pe_r = fluid.layers.gather(table, pe_idx)       # [S*R, D]
-            x = fluid.layers.elementwise_add(
-                x, fluid.layers.reshape(pe_r, shape=[S, R, D]))
-            x = _hint(x)
-            for i in range(n_layer):
-                if kv_int8:
-                    kcache, vcache, kscale, vscale = caches(i)
-                    q, k, v = qkv(x, i, 2)
-                    kcache, kscale = \
-                        fluid.layers.kv_block_verify_write_quant(
-                            kcache, kscale, k, vpos, vtab)
-                    vcache, vscale = \
-                        fluid.layers.kv_block_verify_write_quant(
-                            vcache, vscale, v, vpos, vtab)
-                    a = fluid.layers.kv_block_verify_attention_quant(
-                        q, kcache, kscale, vcache, vscale, vpos, vtab,
-                        n_head)
-                else:
-                    kcache, vcache = caches(i)
-                    q, k, v = qkv(x, i, 2)
-                    kcache = fluid.layers.kv_block_verify_write(
-                        kcache, k, vpos, vtab)
-                    vcache = fluid.layers.kv_block_verify_write(
-                        vcache, v, vpos, vtab)
-                    a = fluid.layers.kv_block_verify_attention(
-                        q, kcache, vcache, vpos, vtab, n_head)
-                x = block_tail(x, a, i, 2)
-            verify_logits = out_logits(x, nfd=2)            # [S, R, V]
-        verify = {'program': vp,
-                  'feeds': ['tokens', 'pos', 'block_tables'],
-                  'samples': {'tokens': np.zeros((S, R), np.int64),
-                              'pos': np.full((S, R), MAXB * BS,
-                                             np.int32),
-                              'block_tables': np.zeros((S, MAXB),
-                                                       np.int32)},
-                  'fetches': [verify_logits.name]}
-
-    # ---- the row program, after everything else for the same reason: the
-    # largest chunk once more at [R, C], where the shapes give one
-    # (decode_spec.chunk_row_shape: chunks (32, 128) -> 128 x 4) ----------
-    rows = chunk_row_shape(chunk_progs, MAXB * BS)
-
-    spec = {'startup': startup,
-            'block_size': BS, 'num_blocks': NB,
-            'max_blocks_per_slot': MAXB,
-            'step': {'program': step_p,
-                     'feeds': ['tokens', 'pos', 'block_tables'],
-                     'samples': {'tokens': np.zeros((S, 1), np.int64),
-                                 'pos': np.zeros((S, 1), np.int32),
-                                 'block_tables': np.zeros((S, MAXB),
-                                                          np.int32)},
-                     'fetches': [step_logits.name]},
-            'chunk': chunk_progs,
-            'cache_vars': list(cache_vars),
-            'max_slots': S, 'max_cache_len': T,
-            'eos_id': int(eos_id), 'vocab': int(vocab),
-            'kv_cache_dtype': kv_cache_dtype}
-    if rows is not None:
-        spec['chunk_rows'] = dict(chunk_program(*rows), size=rows[0],
-                                  rows=rows[1])
-    if verify is not None:
-        spec['verify'] = verify
-        spec['draft_k'] = int(draft_k)
-    if mp:
-        spec['mesh_axes'] = {'mp': mp}
-        spec['param_shardings'] = dict(param_shardings)
-        spec['state_shardings'] = dict(state_shardings)
-    return spec
+    return DecodeSpecBuilder(
+        vocab=vocab, d_model=D, kv_width=D, n_layer=n_layer,
+        max_slots=max_slots, max_cache_len=T, block_size=block_size,
+        chunk_sizes=chunk_sizes, num_blocks=num_blocks, eos_id=eos_id,
+        kv_cache_dtype=kv_cache_dtype, draft_k=draft_k,
+        mp_shard=mp).build(block, logits, embed)

@@ -3,7 +3,7 @@
 Writes deterministic pseudo-JPEG records — a label plus a
 zlib-compressed uint8 image buffer — into multi-chunk RecordIO shards,
 and provides the decode+augment function the feeder-saturation A/B
-(bench.py data_plane metric, scripts/data_plane_smoke.py) runs through
+(scripts/data_plane_smoke.py) runs through
 the worker pool. The decode cost profile matches what a real image
 pipeline stresses:
 

@@ -417,8 +417,8 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                    logits [R, vocab] — so that the slices R DIFFERENT
                    admitting requests have due in one scheduler tick go
                    to the device in one dispatch. A builder adds it
-                   where the shapes give one (models/decode_spec.
-                   chunk_row_shape: at most 512 prompt tokens and 4 rows
+                   where the shapes give one (models/decode_spec.py
+                   `build`: at most 512 prompt tokens and 4 rows
                    a dispatch, the chunk attention over the gathered
                    view — as many K/V heads as query heads, no window —
                    with the rows' scores in its budget: chunks (32, 128)

@@ -1008,8 +1008,8 @@ def _bench_cli(argv):
     # serve.py bench ARTIFACT_DIR IN.npz N_REQUESTS [TIMEOUT_MS]
     # replays IN.npz N times through the dynamic batcher and prints
     # throughput + latency percentiles, with a sequential
-    # one-request-per-run reference — serving perf measurable without the
-    # full bench.py harness.
+    # one-request-per-run reference — serving perf measurable without a
+    # benchmark harness.
     if len(argv) not in (5, 6):
         print("usage: serve.py bench ARTIFACT_DIR IN.npz N_REQUESTS "
               "[TIMEOUT_MS]", file=sys.stderr)
@@ -1191,7 +1191,7 @@ def _fleet_cli(argv):
     # frame protocol), replay IN.npz N times through FleetRouter.submit
     # with least-outstanding-work routing, and print fleet throughput,
     # latency percentiles and the per-replica table as JSON — serving-
-    # fleet perf measurable without the full bench.py harness.
+    # fleet perf measurable without a benchmark harness.
     # Batching/compiled artifacts: IN.npz holds one request's feed
     # arrays. Decode artifacts: the decode-CLI convention — 'prompts'
     # [N, L] int64 (0-padded) + optional 'lens' [N]; requests cycle

@@ -365,27 +365,13 @@ def _batch_norm(ctx, ins):
     kvec = inv * scale
     bvec = bias - m * kvec
 
-    def fma(x, kvec, bvec):
-        # pre-folded FMA y = x*k + (bias - m*k). In bf16 this rounds x*k
-        # before the mean cancels, adding ~|m|*2^-8 absolute error — but
-        # a bf16 x ALREADY carries (|m|+sigma)*2^-8 quantization from the
-        # producing conv, so the floor is unchanged in order; the
-        # centered (x-m)*k form measured 2.5% slower e2e for no floor
-        # improvement (PERF_NOTES.md)
-        return (x * kvec.astype(x.dtype).reshape(bshape)
-                + bvec.astype(x.dtype).reshape(bshape))
-    import os
-    if os.environ.get('PTPU_PALLAS_BN', '0') not in ('', '0') \
-            and layout == 'NCHW' and x.ndim == 4:
-        from . import pallas_bn
-        # the kernel where the program is COMPILED for tpu, the FMA on
-        # every other platform — decided at lowering, not by which
-        # devices this process happens to see
-        y = jax.lax.platform_dependent(
-            x, kvec, bvec, default=fma,
-            tpu=lambda x, k, b: pallas_bn.fused_bn_apply(x, k, b, None))
-    else:
-        y = fma(x, kvec, bvec)
+    # pre-folded FMA y = x*k + (bias - m*k). In bf16 this rounds x*k before
+    # the mean cancels, adding ~|m|*2^-8 absolute error — but a bf16 x
+    # ALREADY carries (|m|+sigma)*2^-8 quantization from the producing
+    # conv, so the floor is unchanged in order; the centered (x-m)*k form
+    # measured 2.5% slower e2e for no floor improvement (PERF_NOTES.md)
+    y = (x * kvec.astype(x.dtype).reshape(bshape)
+         + bvec.astype(x.dtype).reshape(bshape))
     return {'Y': [y], 'MeanOut': [mean_out],
             'VarianceOut': [var_out],
             'SavedMean': [m], 'SavedVariance': [inv]}

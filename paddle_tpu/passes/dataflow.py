@@ -24,7 +24,7 @@ What it computes (all static, no tracing, no device):
   * a bytes-from-shape static peak-memory estimator per program and per
     export batch bucket — the number ROADMAP's pod-scale planning needs
     BEFORE compiling (shard-layout decisions), and the ``peak_bytes_est``
-    field bench.py now emits.
+    field of an exported signature.
   * a donation-safety certifier: the static proof that lets reloaded
     (warm-started) executables donate state buffers again — recovering
     the one-copy-per-step tax PERF_NOTES round 8 recorded when the
@@ -33,7 +33,7 @@ What it computes (all static, no tracing, no device):
 Consumers: Executor.run/run_steps (donation certificate for the
 compile-cache warm path), transpiler.memory_optimize (liveness report),
 tools/program_doctor.py (the CLI over the model zoo), inference/export
-(per-bucket peak-bytes in signature.json), bench.py.
+(per-bucket peak-bytes in signature.json).
 
     from paddle_tpu.passes import dataflow
     dfa = dataflow.analyze_program(prog, feed_names=['x'],

@@ -96,7 +96,7 @@ class HorizontalFusePass(Pass):
       min_group   smallest sibling set worth widening (default 2).
 
     PTPU_HFUSE=0 disables the rewrite (report carries disabled=True) —
-    the A/B switch bench.py's ablation mode flips in one session.
+    the A/B switch of an ablation run.
     """
 
     name = 'horizontal_fuse'

@@ -54,13 +54,8 @@ JAX_PLATFORMS=cpu python scripts/elastic_resume_smoke.py
 echo "== data plane smoke (sharded streaming input: serial-vs-pooled feeder A/B >=3x with bit-identical epochs, exactly-once journal resume, host-stall < 2% on the smallnet loop) =="
 JAX_PLATFORMS=cpu python scripts/data_plane_smoke.py
 
-echo "== slow tier (threaded stress, Poisson serving scenario) =="
+echo "== slow tier (threaded stress) =="
 python -m pytest tests/ -q -m slow
-
-echo "== bench smoke (tiny config; device-time off: XLA:CPU runs conv scan bodies ~10x slower) =="
-PTPU_BENCH_ONLY=resnet PTPU_BENCH_BATCH=16 PTPU_BENCH_STEPS=3 \
-PTPU_BENCH_DEVICE_TIME=0 \
-JAX_PLATFORMS=cpu python bench.py
 
 echo "== serving bench smoke (serve.py bench on a tiny artifact) =="
 python scripts/serve_bench_smoke.py

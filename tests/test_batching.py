@@ -553,21 +553,3 @@ def test_threaded_stress(artifacts):
     assert not errors, errors[:3]
     assert snap['requests'] == 40
     assert snap['queue_depth'] == 0
-
-
-@pytest.mark.slow
-def test_bench_poisson_serving_scenario(monkeypatch):
-    """The bench.py serving scenario end-to-end in a tiny configuration
-    (Poisson arrivals, auto-calibrated rate)."""
-    import bench
-    monkeypatch.setenv('PTPU_BENCH_SMOKE_BUCKETS', '1,4')
-    monkeypatch.setenv('PTPU_BENCH_SMOKE_REQS', '16')
-    monkeypatch.setenv('PTPU_BENCH_SMOKE_TIMEOUT_MS', '5')
-    line = bench._bench_image_serving(
-        'smoke_serving_img_s', lambda images: fluid.layers.fc(
-            images, 4, act='softmax'),
-        'SMOKE', 1.0, 'self', dshape=(DIM,))
-    assert line['metric'] == 'smoke_serving_img_s'
-    assert line['value'] > 0
-    assert line['p99_ms'] >= line['p50_ms'] > 0
-    assert 0 < line['occupancy'] <= 1.0

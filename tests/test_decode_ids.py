@@ -467,21 +467,107 @@ _PARENT_STABLEHLO = {
 _ROW_MODULES = {'transformer_base_lm': ['prefill_chunk_00016x4'],
                 'olmoe_1b_7b': ['prefill_chunk_00016x4'],
                 'k_exaone_236b_a23b': []}
+# what models.transformer.build_decode_spec alone offers, one build each
+_VARIANTS = {'bfloat16': dict(kv_cache_dtype='bfloat16'),
+             'int8': dict(kv_cache_dtype='int8'),
+             'draft_k2': dict(draft_k=2), 'mp2': dict(mp_shard=2)}
+_MODULE = {'k_exaone_236b_a23b': 'exaone_moe',
+           'joyai_llm_flash': 'joyai_llm_flash',
+           'qwen3_next_80b_a3b': 'qwen3_next'}
+# recorded on the parent of PR 45 (260716d), before models/transformer.py
+# moved onto DecodeSpecBuilder: transformer_base_lm's row module and every
+# module of its bfloat16, int8, draft_k=2 (the verify module with it) and
+# mp_shard=2 builds, and what the builder's other models export that the
+# table above does not hold
+_PARENT_STABLEHLO_45 = {
+    'joyai_llm_flash/decode_blockcopy': '842426bc95f02e01',
+    'joyai_llm_flash/decode_step': 'ce202f3e29000790',
+    'joyai_llm_flash/decode_zeros': '91b42ee05ce347c2',
+    'joyai_llm_flash/prefill_chunk_00008': '713044675ef21ea5',
+    'joyai_llm_flash/prefill_chunk_00016': '3bea14a129ad6576',
+    'olmoe_1b_7b/prefill_chunk_00016x4': '75839f03abba323d',
+    'qwen3_next_80b_a3b/decode_blockcopy': '9f5f587c18554e27',
+    'qwen3_next_80b_a3b/decode_step': '2a88114c1cffb19a',
+    'qwen3_next_80b_a3b/decode_zeros': '0543b1fdf5d768e7',
+    'qwen3_next_80b_a3b/prefill_chunk_00008': '71023751d0fdbf2d',
+    'qwen3_next_80b_a3b/prefill_chunk_00016': '24c1d3952e41e2a5',
+    'transformer_base_lm+bfloat16/decode_blockcopy':
+        '1d8ac103f86c5154',
+    'transformer_base_lm+bfloat16/decode_step': '26abcc27884b9fdc',
+    'transformer_base_lm+bfloat16/decode_zeros': '5a67c68311d3b990',
+    'transformer_base_lm+bfloat16/prefill_chunk_00008':
+        'ab8a299bcb6a9bc5',
+    'transformer_base_lm+bfloat16/prefill_chunk_00016':
+        '39b41c724900b813',
+    'transformer_base_lm+bfloat16/prefill_chunk_00016x4':
+        'a35d3990161b6a2f',
+    'transformer_base_lm+draft_k2/decode_blockcopy':
+        '981e930e2bdfa797',
+    'transformer_base_lm+draft_k2/decode_step': '4a90334ca35afd6e',
+    'transformer_base_lm+draft_k2/decode_verify': 'af25300c9df06327',
+    'transformer_base_lm+draft_k2/decode_zeros': 'a07c8904ffbd40c2',
+    'transformer_base_lm+draft_k2/prefill_chunk_00008':
+        '5c076e84cced5158',
+    'transformer_base_lm+draft_k2/prefill_chunk_00016':
+        '1754ec5d1c3d3932',
+    'transformer_base_lm+draft_k2/prefill_chunk_00016x4':
+        '910aed065ac1e53a',
+    'transformer_base_lm+int8/decode_blockcopy': '400f09046012f2fc',
+    'transformer_base_lm+int8/decode_step': '638d3437d48e83a4',
+    'transformer_base_lm+int8/decode_zeros': 'baf0611b912571e8',
+    'transformer_base_lm+int8/prefill_chunk_00008': 'c0b2dd3386558a34',
+    'transformer_base_lm+int8/prefill_chunk_00016': 'aa617c76f5b9851c',
+    'transformer_base_lm+mp2/decode_blockcopy': '7b9b29bd740602df',
+    'transformer_base_lm+mp2/decode_step': '5117d2eda729b0ef',
+    'transformer_base_lm+mp2/decode_zeros': '20e8fd6f13a2791f',
+    'transformer_base_lm+mp2/prefill_chunk_00008': 'dc470a83baff6e5d',
+    'transformer_base_lm+mp2/prefill_chunk_00016': '8484674f0344955f',
+    'transformer_base_lm+mp2/prefill_chunk_00016x4':
+        'ccc0b66f27b26b73',
+    'transformer_base_lm/prefill_chunk_00016x4': '910aed065ac1e53a'}
+# and, for each of the five decode models and each variant, what the startup
+# program leaves in the scope at random_seed 11 (name, shape, dtype, bytes
+# of every variable) and the artifact's signature (programs, feeds,
+# fetches, parameters, state): (weights, signature)
+_PARENT_WEIGHTS_AND_SIGNATURE = {
+    'joyai_llm_flash':
+        ('1188206486089f03', 'b53382801d07ad12'),
+    'k_exaone_236b_a23b':
+        ('62a712e6740b3df1', '3b8838cd6ce04ec7'),
+    'olmoe_1b_7b':
+        ('96e3d61335d27c56', 'd1a707e1d7aa678f'),
+    'qwen3_next_80b_a3b':
+        ('ff48f602a5acd0c9', '286b0c291897c5b3'),
+    'transformer_base_lm':
+        ('f84f62cd8c337808', '5ac0247650a955e4'),
+    'transformer_base_lm+bfloat16':
+        ('399a39d6d0c69f6d', 'd5ffe821734b0c45'),
+    'transformer_base_lm+draft_k2':
+        ('f84f62cd8c337808', 'aae7f43ee8588903'),
+    'transformer_base_lm+int8':
+        ('33c7095112af711c', '297665ebb96396e7'),
+    'transformer_base_lm+mp2':
+        ('f84f62cd8c337808', 'c6f8ff8d26558ef2')}
 
 
 def _rehearsal_spec(config, **kw):
-    """The three decode configurations at toy widths, the rehearsal's
-    chunks (8, 16) unless `kw` says otherwise."""
+    """The decode configurations at toy widths, the rehearsal's chunks
+    (8, 16) unless `kw` says otherwise; 'config+variant' is
+    transformer_base_lm with one of `_VARIANTS`' arguments."""
+    config, _, variant = config.partition('+')
     if config == 'transformer_base_lm':
         from models.transformer import build_decode_spec
         args = dict(vocab=97, d_model=32, n_head=4, n_layer=2, d_ff=64,
                     max_slots=4, max_cache_len=48, eos_id=1,
                     chunk_sizes=(8, 16), block_size=4)
+        args.update(_VARIANTS[variant] if variant else {})
     elif config == 'olmoe_1b_7b':
         from models.olmoe import build_decode_spec
         args = dict(_OLMOE)
     else:
-        from models.exaone_moe import build_decode_spec
+        import importlib
+        build_decode_spec = importlib.import_module(
+            'models.' + _MODULE[config]).build_decode_spec
         args = {}
     args.update(kw)
     return build_decode_spec(**args)
@@ -507,11 +593,20 @@ def _location_free(text):
     return '\n'.join(out)
 
 
-@pytest.fixture(scope='module')
-def stablehlo(tmp_path_factory):
-    """{module directory: hash of its text} of one spec's export, made
-    once a configuration."""
+def _digest(*parts):
     import hashlib
+    h = hashlib.sha256()
+    for part in parts:
+        h.update(part if isinstance(part, bytes) else str(part).encode())
+    return h.hexdigest()[:16]
+
+
+@pytest.fixture(scope='module')
+def exported(tmp_path_factory):
+    """One spec's export, made once a configuration: {'modules': {module
+    directory: hash of its text}, 'weights': digest of the scope the
+    startup program left, 'signature': digest of the artifact's
+    signature}."""
     from jax import export as jexport
     tmp = tmp_path_factory.mktemp('stablehlo')
     made = {}
@@ -525,23 +620,50 @@ def stablehlo(tmp_path_factory):
                 spec['startup'].random_seed = 11
                 fluid.Executor(fluid.CPUPlace()).run(spec['startup'],
                                                      scope=scope)
+                weights = []
+                for name in sorted(scope.local_var_names()):
+                    a = np.asarray(scope.get(name))
+                    weights += [name, a.shape, a.dtype.name, a.tobytes()]
                 export_decode(spec, art, scope=scope, precompile=False)
-            made[config] = {}
+            modules = {}
             for d in sorted(os.listdir(art)):
                 path = os.path.join(art, d, 'module.jaxexport')
                 if os.path.exists(path):
                     with open(path, 'rb') as f:
                         text = jexport.deserialize(f.read()).mlir_module()
-                    made[config][d] = hashlib.sha256(
-                        _location_free(text).encode()).hexdigest()[:16]
+                    modules[d] = _digest(_location_free(text))
+            with open(os.path.join(art, decoding._DECODE_SIGNATURE)) as f:
+                sig = json.load(f)
+            made[config] = {
+                'modules': modules, 'weights': _digest(*weights),
+                'signature': _digest(json.dumps(sig, sort_keys=True))}
         return made[config]
     return get
 
 
-@pytest.mark.parametrize('module', sorted(_PARENT_STABLEHLO))
+@pytest.fixture(scope='module')
+def stablehlo(exported):
+    return lambda config: exported(config)['modules']
+
+
+_PINNED = dict(_PARENT_STABLEHLO, **_PARENT_STABLEHLO_45)
+
+
+@pytest.mark.parametrize('module', sorted(_PARENT_STABLEHLO)
+                         + sorted(_PARENT_STABLEHLO_45))
 def test_every_one_row_program_is_the_parents_stablehlo(stablehlo, module):
     config, d = module.split('/')
-    assert stablehlo(config)[d] == _PARENT_STABLEHLO[module]
+    assert stablehlo(config)[d] == _PINNED[module]
+
+
+@pytest.mark.parametrize('config', sorted(_PARENT_WEIGHTS_AND_SIGNATURE))
+def test_startup_weights_and_signature_are_the_parents(exported, config):
+    """The same variables under the same names drawn from the same seed,
+    and an artifact that declares the same programs, feeds, fetches,
+    parameters and state: to the scheduler, the parent's."""
+    got = exported(config)
+    assert (got['weights'], got['signature']) \
+        == _PARENT_WEIGHTS_AND_SIGNATURE[config]
 
 
 @pytest.mark.parametrize('config', sorted(_ROW_MODULES))
@@ -586,3 +708,27 @@ def test_which_spec_holds_a_row_program_follows_from_its_shapes(config, kw,
     for name in one['feeds']:
         assert rows['samples'][name].shape \
             == (want[1],) + one['samples'][name].shape[1:]
+
+
+@pytest.mark.parametrize('asked', [dict(kv_cache_dtype='int8'),
+                                   dict(draft_k=2)], ids=['int8', 'draft_k'])
+@pytest.mark.parametrize('beside,kind', [
+    (dict(window_layers=(0,), window=8), 'window layers'),
+    (dict(v_width=16), 'latent pool'),
+    (dict(recurrent={1: {'state': ((2, 4), 'float32')}}),
+     'recurrent layers')], ids=['window', 'latent', 'recurrent'])
+def test_the_builder_refuses_by_name_what_its_ops_do_not_take(asked, beside,
+                                                              kind):
+    """The int8 pool's and the verify program's ops attend a full layer's
+    K and V pools and nothing else: beside another kind of layer memory
+    the spec fails where it is built, the layer kind in the message, and
+    not at export (or at the first dispatch)."""
+    from models.decode_spec import DecodeSpecBuilder
+    args = dict(vocab=64, d_model=32, kv_width=32, n_layer=2, max_slots=2,
+                max_cache_len=32, block_size=8, chunk_sizes=(8,),
+                num_blocks=None, eos_id=1, kv_cache_dtype='float32')
+    DecodeSpecBuilder(**dict(args, **beside))       # alone: fine
+    DecodeSpecBuilder(**dict(args, **asked))
+    what = 'int8' if 'kv_cache_dtype' in asked else 'draft_k=2'
+    with pytest.raises(ValueError, match='%s.*%s' % (what, kind)):
+        DecodeSpecBuilder(**dict(args, **beside, **asked))

@@ -1,7 +1,7 @@
 """GoogLeNet + SE-ResNeXt model families build and train (parity with the
 reference's benchmark/paddle/image/googlenet.py and
 benchmark/fluid/models/se_resnext.py; the committed Xeon numbers they
-bench against live in bench.py / BASELINE.md)."""
+bench against live in BASELINE.md)."""
 import numpy as np
 
 import paddle_tpu as fluid

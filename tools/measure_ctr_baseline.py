@@ -6,8 +6,7 @@ work, so the honest denominator is the same model on the benchmark host's
 CPU).
 
 Run:  python tools/measure_ctr_baseline.py
-Prints one JSON line; the accepted value is committed in BASELINE.md and
-consumed by bench.py as BASELINE_CTR_CPU_SAMPLES_S.
+Prints one JSON line; the accepted value is committed in BASELINE.md.
 """
 import json
 import os
