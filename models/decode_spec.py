@@ -33,6 +33,24 @@ row's; a chunk program is told the slot its row prefills by one feed more,
 no row program, and its 'recurrent' entry names the variables that no
 block pair copies.
 
+A layer may also keep NOTHING and attend ANOTHER layer's pools
+(`shared_pools`: {reader layer: owner layer}; models/phi4_flash.py, whose
+cross-decoder's seven layers read one layer's K/V): `cache_names` of a
+reader is empty, it writes nothing, `pools` hands it the owner's pools as
+the owner's `write` left them and `attend` reads them through the owner's
+table (inside the owner's window, if it has one), so the spec's
+'cache_vars', the pools' bytes and the block accounting see ONE layer. A
+spec's 'shared_pools' names the readers. A layer that keeps nothing and
+attends nothing either is named in `no_cache`.
+
+Where the layers from `last_only_from` on keep nothing (each a reader or in
+`no_cache`), a chunk program owes them to the position its logits are read
+at and no other: it runs the layers below over the slice's C positions and
+those from there on over the row's LAST VALID position (x [1, 1, D] at
+start + chunk_len - 1, which is where their `attend` then reads the cache;
+`b.at_last` hands a block what a layer below left for it, at that
+position). The step is unchanged.
+
 Three more things are a SPEC's and not a model's, and are said here once:
 the int8 pool (`kv_cache_dtype='int8'`: int8 pages, one float32 scale a
 cache position in `kv_ks_<i>` / `kv_vs_<i>`, the `_quant` ops), the verify
@@ -40,8 +58,9 @@ program of speculative decoding (`draft_k=K`: [S, K + 1] rows scored in
 one dispatch) and `mp_shard=k` (the pools' D axis over the 'mp' mesh axis;
 a model annotates its weights through `b.shard` and pins its activations
 through `b.hint`). The ops behind the first two take full layers only:
-beside a window, a latent pool or recurrent layers either is refused here,
-by the layer kind's name.
+beside a window, a latent pool, recurrent layers or a shared pool either
+is refused here, by the kind's name; so is `mp_shard` beside a shared pool
+(a reader's query heads would have to follow the owner's partition).
 """
 from __future__ import annotations
 
@@ -96,7 +115,8 @@ class DecodeSpecBuilder(object):
                  max_cache_len, block_size, chunk_sizes, num_blocks, eos_id,
                  kv_cache_dtype, weights_dtype='float32', rms_eps=1e-6,
                  init_std=0.02, window_layers=(), window=0, v_width=0,
-                 recurrent=None, embed_std=None, draft_k=0, mp_shard=0):
+                 recurrent=None, embed_std=None, draft_k=0, mp_shard=0,
+                 shared_pools=None, no_cache=(), last_only_from=None):
         if kv_cache_dtype not in ('float32', 'bfloat16', 'int8'):
             raise ValueError("kv_cache_dtype must be 'float32', 'bfloat16' "
                              "or 'int8', got %r" % (kv_cache_dtype,))
@@ -145,15 +165,48 @@ class DecodeSpecBuilder(object):
                           for i, states in (recurrent or {}).items()}
         if set(self.recurrent) & self.window_layers:
             raise ValueError('a layer is recurrent or a window layer')
+        # {reader: owner}: layers that keep nothing and attend the pools
+        # of a layer below them
+        self.shared_pools = {int(i): int(o)
+                             for i, o in (shared_pools or {}).items()}
+        self.no_cache = frozenset(int(i) for i in no_cache)
+        if self.no_cache & (set(self.recurrent) | self.window_layers
+                            | set(self.shared_pools)):
+            raise ValueError('a no_cache layer is of no other kind')
+        self.last_only_from = (None if last_only_from is None
+                               else int(last_only_from))
+        if self.last_only_from is not None and not all(
+                i in self.shared_pools or i in self.no_cache
+                for i in range(self.last_only_from, self.n_layer)):
+            raise ValueError(
+                'last_only_from=%d: a layer from there on keeps a cache or '
+                'a state, which every position of a slice has to write'
+                % self.last_only_from)
+        for i, o in self.shared_pools.items():
+            if (not 0 <= o < i < self.n_layer or o in self.shared_pools
+                    or o in self.recurrent or i in self.recurrent
+                    or i in self.window_layers):
+                raise ValueError(
+                    'shared_pools: layer %d cannot attend layer %d\'s '
+                    'pools (a reader lies above its owner, keeps nothing '
+                    'of its own, and the owner keeps K/V rows)' % (i, o))
         # the _quant and the verify ops attend full layers' K and V pools
         for what, asked in (("kv_cache_dtype='int8'", self.int8),
                             ('draft_k=%d' % self.draft_k, self.draft_k)):
             for kind, has in (('window layers', self.window_layers),
                               ('a latent pool (v_width)', self.v_width),
-                              ('recurrent layers', self.recurrent)):
+                              ('recurrent layers', self.recurrent),
+                              ('a shared pool', self.shared_pools),
+                              ('layers at a row\'s last position only '
+                               '(last_only_from)',
+                               self.last_only_from is not None)):
                 if asked and has:
                     raise ValueError('%s is not built beside %s: its ops '
                                      'take full layers only' % (what, kind))
+        if self.mp and self.shared_pools:
+            raise ValueError('mp_shard=%d is not built beside a shared '
+                             'pool: a reader\'s heads would have to follow '
+                             'its owner\'s partition' % self.mp)
         self.param_shardings, self.state_shardings = {}, {}
         self.startup = fluid.Program()
         self._io = None      # the cache ops of the program being built
@@ -204,7 +257,10 @@ class DecodeSpecBuilder(object):
     def cache_names(self, i):
         """Layer i's pools, in the order `write` takes their rows (the
         int8 pool: then each one's scales); a recurrent layer's per-slot
-        states, in the order `state` gives."""
+        states, in the order `state` gives; none for a layer that attends
+        another's (`shared_pools`)."""
+        if i in self.shared_pools or i in self.no_cache:
+            return []
         if i in self.recurrent:
             return ['rec_%s_%d' % (name, i) for name in self.recurrent[i]]
         if self.v_width:
@@ -250,8 +306,9 @@ class DecodeSpecBuilder(object):
                                      *((k, v) if fresh else ()),
                                      pos, tables[0], **kw)}
         return {'write': lambda c, kv, kind: write(c, kv, pos, tables[kind]),
-                'attend': lambda q, kc, vc, kind, **kw:
-                    attend(q, kc, vc, pos, tables[kind], **kw)}
+                'attend': lambda q, kc, vc, kind, at=None, **kw:
+                    attend(q, kc, vc, pos if at is None else at,
+                           tables[kind], **kw)}
 
     def state(self, i):
         """Recurrent layer i's per-slot state variables ([max_slots, ...],
@@ -267,14 +324,19 @@ class DecodeSpecBuilder(object):
         """Layer i's pools, declared before its rows are there to write:
         for a block whose pools come ahead of its own weights in the
         startup program (models/transformer.py), which draws the weights
-        in the order it first met their names."""
-        return self._caches(i)
+        in the order it first met their names. A layer that attends
+        another's (`shared_pools`) gets its OWNER's, which hold what the
+        owner's `write` put there: the owner lies below it in every
+        program."""
+        return self._caches(self.shared_pools.get(i, i))
 
     def write(self, i, *rows):
         """Layer i's pools with this program's rows written, one tensor
         of rows a pool (cache_names' order: K and V, or the one latent
         row): the pools, a tuple as long, each as `attend` takes it (an
         int8 pool: its pages, its scales and the rows as handed in)."""
+        if not self.cache_names(i):
+            raise ValueError('layer %d keeps no pool' % i)
         caches = self._caches(i)
         n = len(caches) // 2 if self.int8 else len(caches)
         if len(rows) != n:
@@ -293,7 +355,10 @@ class DecodeSpecBuilder(object):
         """Layer i's attention over its pools (inside its window, if it
         is a window layer); over a latent pool — `kcache` and `vcache`
         the same pool — the op is told where in the row the value
-        lies."""
+        lies. A layer of `shared_pools` attends through its owner's table
+        and window; a layer a chunk program runs at its rows' last
+        position only (`last_only_from`), at that position."""
+        i = self.shared_pools.get(i, i)
         kw = {'n_head': n_head, 'scale': scale}
         if self.int8:
             (kc, ks, k), (vc, vs, v) = kcache, vcache
@@ -307,7 +372,25 @@ class DecodeSpecBuilder(object):
             kw['window'] = self.window
         if self.v_width:
             kw['v_width'] = self.v_width
+        if self.rows.get('last_pos') is not None:
+            kw['at'] = self.rows['last_pos']
         return self._io['attend'](q, kcache, vcache, kind, **kw)
+
+    def at_last(self, x):
+        """`x` [1, C, W], something a layer below left for the layers from
+        `last_only_from` on, where the program being built runs them: as
+        it is in the step, and in a chunk program at its row's last valid
+        position, [1, 1, W] (chunk_len - 1; a pad row's, chunk_len 0, is
+        position 0: unread)."""
+        if self.rows.get('last_pos') is None:
+            return x
+        L = fluid.layers
+        _, C, W = (int(n) for n in x.shape)
+        at = L.clip(L.elementwise_sub(
+            L.reshape(self.rows['chunk_len'], shape=[1]),
+            L.fill_constant([1], 'int32', 1)), 0, C - 1)
+        return L.reshape(L.gather(L.reshape(x, shape=[C, W]), at),
+                         shape=[1, 1, W])
 
     # -- the programs ----------------------------------------------------
     def build(self, block, logits, embed=None):
@@ -387,9 +470,23 @@ class DecodeSpecBuilder(object):
                 x = embed(self, chunk_ids)                      # [R, C, D]
                 posv = chunk_positions(start, C, R)          # [C] / [R, C]
                 for i in range(self.n_layer):
+                    if i == self.last_only_from:
+                        # the rest at the row's last valid position
+                        if R > 1:
+                            raise NotImplementedError(
+                                'last_only_from beside a row program: each '
+                                'row owes its logits the one-row expression')
+                        posv = L.clip(L.elementwise_sub(
+                            L.elementwise_add(start, clen),
+                            L.fill_constant([1], 'int32', 1)), 0, self.T - 1)
+                        self.rows['last_pos'] = posv            # [1, 1]
+                        x = self.at_last(x)                  # [1, 1, D]
                     x = block(self, x, i, 2, posv)
-                chunk_logits = last_logits(
-                    x, clen, C, R, D, lambda row: logits(self, row))
+                if self.rows.get('last_pos') is not None:
+                    chunk_logits = logits(self, L.reshape(x, shape=[1, D]))
+                else:
+                    chunk_logits = last_logits(
+                        x, clen, C, R, D, lambda row: logits(self, row))
             samples = {'chunk_ids': np.zeros((R, C), np.int64),
                        'start': np.zeros((R, 1), np.int32),
                        'chunk_len': np.ones((R, 1), np.int32),
@@ -451,6 +548,8 @@ class DecodeSpecBuilder(object):
             spec['mesh_axes'] = {'mp': self.mp}
             spec['param_shardings'] = dict(self.param_shardings)
             spec['state_shardings'] = dict(self.state_shardings)
+        if self.shared_pools:
+            spec['shared_pools'] = dict(self.shared_pools)
         if self.recurrent:
             spec['recurrent'] = {
                 'cache_vars': [n for i in sorted(self.recurrent)

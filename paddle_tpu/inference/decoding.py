@@ -350,6 +350,10 @@ class DecodeStats(object):
         # dispatched whose state the step left alone because they were
         # idle (a free slot, a slot between two slices of its prompt)
         self.recurrent_state_bytes = 0
+        # layers that keep no cache and attend a pool they do not own
+        # (the signature's 'shared_pools'): each reads the owner's rows
+        # once more a step, and the pools' bytes count them once
+        self.shared_pool_readers = 0
         self.state_resets = 0
         self.state_rows_kept = 0
         self.cow_blocks = 0      # blocks copied for beam copy-on-write
@@ -563,6 +567,7 @@ class DecodeStats(object):
         bs = self.block_source()
         snap['cache_row_bytes'] = int(self.cache_row_bytes)
         snap['pool_bytes'] = dict(self.pool_bytes)
+        snap['shared_pool_readers'] = int(self.shared_pool_readers)
         snap['blocks_in_use'] = int(bs['blocks_in_use'])
         snap['blocks_peak'] = int(bs['blocks_peak'])
         snap['blocks_total'] = int(bs['num_blocks'])
@@ -1593,6 +1598,8 @@ class DecodingPredictor(object):
             self._sig)
         self.stats.recurrent_state_bytes = self.stats.pool_bytes.get(
             'recurrent', 0)
+        self.stats.shared_pool_readers = len(
+            self._sig['block'].get('shared_pools') or ())
 
     def _ask(self, fetches, program, logits):
         """Ask for the device-to-host copy of one dispatch's ids (fetch

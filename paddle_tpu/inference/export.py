@@ -447,6 +447,11 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
                    [1, max_blocks]. Every other cache var is a full
                    layer's. Such a spec has no verify program, and the
                    loader refuses beams and prefix reuse on it by name.
+      shared_pools (optional) {reader layer: owner layer}: layers that
+                   keep no cache and attend the owner's pools
+                   (models/decode_spec.py); written into the signature,
+                   where DecodeStats reads how many readers there are.
+                   Not beside a verify program or a mesh.
       recurrent    (optional) {'cache_vars'}: the state vars of RECURRENT
                    layers (a linear-attention layer's rule state and
                    convolution tail: models/decode_spec.py), which are no
@@ -551,6 +556,12 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
             "with kv_cache_dtype=%r — rebuild the decode spec with the "
             "requested cache dtype (build_decode_spec(kv_cache_dtype=...))"
             % (kv_cache_dtype, spec_kv))
+    if spec.get('shared_pools') and (spec.get('verify') is not None
+                                     or spec.get('mesh_axes')
+                                     or spec_kv == 'int8'):
+        raise ValueError('a spec with a shared pool has no verify program, '
+                         'no mesh and no int8 pool: their ops and '
+                         'partitions take a layer\'s own pools')
     scope = scope if scope is not None else global_scope()
     state_names = list(spec['cache_vars'])
     state_specs = []
@@ -700,6 +711,11 @@ def export_decode(spec, out_dir, scope=None, precompile=None,
     if recurrent is not None:
         sig['block']['recurrent'] = {
             'cache_vars': list(recurrent['cache_vars'])}
+    if spec.get('shared_pools'):
+        # layers that keep nothing and attend another layer's pools
+        # ({reader: owner}): the state list above holds the owner's once
+        sig['block']['shared_pools'] = {
+            str(i): int(o) for i, o in sorted(spec['shared_pools'].items())}
     if shard is not None:
         sig['mesh'] = {'axes': {a: int(n) for a, n in
                                 shard['axes'].items()},

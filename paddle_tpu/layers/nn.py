@@ -1595,22 +1595,31 @@ def gated_rms_norm(input, gate, epsilon=1e-6, param_attr=None, name=None):
     return out
 
 
-def causal_conv_step(input, weight, tail, block_table):
+def _conv_inputs(input, weight, tail, bias, **rows):
+    inputs = dict({'X': input, 'Weight': weight, 'Tail': tail}, **rows)
+    if bias is not None:    # an op without it is the one qwen3_next holds
+        inputs['Bias'] = bias
+    return inputs
+
+
+def causal_conv_step(input, weight, tail, block_table, bias=None):
     """One token a slot through a causal depthwise convolution and SiLU
     (ops/linear_attention_ops.py): `input` [max_slots, W], `weight` [K, W],
     `tail` [max_slots, K - 1, W] the slot's last K - 1 inputs, updated in
     place where the row is LIVE — its row of `block_table` does not start
-    with the trash block. Returns (out [max_slots, W] float32, tail)."""
+    with the trash block; `bias` [W], where the convolution has one, is
+    added before the SiLU. Returns (out [max_slots, W] float32, tail)."""
     helper = LayerHelper('causal_conv_step')
     out = helper.create_variable_for_type_inference('float32')
     helper.append_op(type='causal_conv_step',
-                     inputs={'X': input, 'Weight': weight, 'Tail': tail,
-                             'BlockTable': block_table},
+                     inputs=_conv_inputs(input, weight, tail, bias,
+                                         BlockTable=block_table),
                      outputs={'Out': out, 'TailOut': tail}, attrs={})
     return out, tail
 
 
-def causal_conv_chunk(input, weight, tail, start, chunk_len, state_slot):
+def causal_conv_chunk(input, weight, tail, start, chunk_len, state_slot,
+                      bias=None):
     """C tokens a row through causal_conv_step's convolution: `input`
     [R, C, W] from the tail of slot `state_slot` [R, 1] (zero where
     `start` is 0: the request begins here), which is left holding the last
@@ -1620,11 +1629,51 @@ def causal_conv_chunk(input, weight, tail, start, chunk_len, state_slot):
     helper = LayerHelper('causal_conv_chunk')
     out = helper.create_variable_for_type_inference('float32')
     helper.append_op(type='causal_conv_chunk',
-                     inputs={'X': input, 'Weight': weight, 'Tail': tail,
-                             'Start': start, 'ChunkLen': chunk_len,
-                             'StateSlot': state_slot},
+                     inputs=_conv_inputs(input, weight, tail, bias,
+                                         Start=start, ChunkLen=chunk_len,
+                                         StateSlot=state_slot),
                      outputs={'Out': out, 'TailOut': tail}, attrs={})
     return out, tail
+
+
+def _scan_inputs(x, dt, b, c, a_log, dt_bias, d, state, **rows):
+    return dict({'X': x, 'Dt': dt, 'B': b, 'C': c, 'ALog': a_log,
+                 'DtBias': dt_bias, 'D': d, 'State': state}, **rows)
+
+
+def selective_scan_step(x, dt, b, c, a_log, dt_bias, d, state, block_table):
+    """One token a slot through a Mamba layer's selective scan
+    (ops/state_space_ops.py): x, dt [max_slots, channels] (the convolved
+    input; the projected step size before `dt_bias`), b, c [max_slots,
+    d_state], a_log [d_state, channels], dt_bias, d [channels], against the
+    per-slot float32 `state` [max_slots, d_state, channels], updated in
+    place where the row is LIVE (causal_conv_step's rule). Returns (out
+    [max_slots, channels] float32 — h C + D x, before any gate — state)."""
+    helper = LayerHelper('selective_scan_step')
+    out = helper.create_variable_for_type_inference('float32')
+    helper.append_op(type='selective_scan_step',
+                     inputs=_scan_inputs(x, dt, b, c, a_log, dt_bias, d,
+                                         state, BlockTable=block_table),
+                     outputs={'Out': out, 'StateOut': state}, attrs={})
+    return out, state
+
+
+def selective_scan_chunk(x, dt, b, c, a_log, dt_bias, d, state, start,
+                         chunk_len, state_slot):
+    """C tokens a row through selective_scan_step's recurrence, position
+    after position, from the state of slot `state_slot` [R, 1] (zero where
+    `start` is 0) to the state after `chunk_len` tokens, written back to
+    that slot: x, dt [R, C, channels], b, c [R, C, d_state]. Returns (out
+    [R, C, channels] float32, state)."""
+    helper = LayerHelper('selective_scan_chunk')
+    out = helper.create_variable_for_type_inference('float32')
+    helper.append_op(type='selective_scan_chunk',
+                     inputs=_scan_inputs(x, dt, b, c, a_log, dt_bias, d,
+                                         state, Start=start,
+                                         ChunkLen=chunk_len,
+                                         StateSlot=state_slot),
+                     outputs={'Out': out, 'StateOut': state}, attrs={})
+    return out, state
 
 
 def _delta_inputs(q, k, v, a, b, a_log, dt_bias, state):

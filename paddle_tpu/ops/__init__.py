@@ -20,4 +20,5 @@ from . import detection_ops   # noqa: F401
 from . import moe_ops         # noqa: F401
 from . import llm_ops         # noqa: F401
 from . import linear_attention_ops  # noqa: F401
+from . import state_space_ops  # noqa: F401
 from . import pipeline_ops    # noqa: F401

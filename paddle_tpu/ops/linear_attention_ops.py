@@ -303,12 +303,19 @@ def _gated_delta_chunk(ctx, ins):
             'StateOut': [_put_rows(state, slot, s1)]}
 
 
-def _conv(full, weight, n):
-    """silu(sum_j weight[j] * full[..., t + j, :]) for t < n: full [..., n
-    + K - 1, W] (the carried tail, then the inputs), weight [K, W]."""
+def _conv(full, weight, n, bias=None):
+    """silu(sum_j weight[j] * full[..., t + j, :] (+ bias)) for t < n: full
+    [..., n + K - 1, W] (the carried tail, then the inputs), weight [K, W],
+    bias [W] where the convolution has one."""
     w = weight.astype(jnp.float32)
     out = sum(w[j] * full[..., j:j + n, :] for j in range(w.shape[0]))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     return _silu(out)
+
+
+def _bias(ins):
+    return (ins.get('Bias') or [None])[0]
 
 
 @register('causal_conv_step', no_grad=True, lod='none')
@@ -317,12 +324,13 @@ def _causal_conv_step(ctx, ins):
     X [S, W], Weight [K, W] (row j multiplies the input K - 1 - j
     positions back), Tail [S, K - 1, W] the slot's last K - 1 inputs
     (TailOut aliases it), BlockTable as gated_delta_step's: an idle row's
-    tail is left as it is. Out [S, W] float32."""
+    tail is left as it is. Bias [W], where given, is added before the
+    SiLU. Out [S, W] float32."""
     tail = ins['Tail'][0]
     x = ins['X'][0].astype(jnp.float32)
     full = jnp.concatenate([tail.astype(jnp.float32), x[:, None, :]], axis=1)
     live = live_rows(ins['BlockTable'][0])[:, None, None]
-    return {'Out': [_conv(full, ins['Weight'][0], 1)[:, 0, :]],
+    return {'Out': [_conv(full, ins['Weight'][0], 1, _bias(ins))[:, 0, :]],
             'TailOut': [_where(live, full[:, 1:].astype(tail.dtype),
                                tail)]}
 
@@ -332,7 +340,8 @@ def _causal_conv_chunk(ctx, ins):
     """C tokens a row through the same convolution, from the tail of the
     row's slot (zero where Start is 0); the tail written back is the last
     K - 1 inputs before position ChunkLen. X [R, C, W], Tail [S, K - 1,
-    W], Start, ChunkLen, StateSlot [R, 1] int32. Out [R, C, W] float32."""
+    W], Start, ChunkLen, StateSlot [R, 1] int32, Bias as the step's. Out
+    [R, C, W] float32."""
     tail = ins['Tail'][0]
     x = ins['X'][0].astype(jnp.float32)
     start, clen, slot = (ins[n][0].reshape(-1)
@@ -342,7 +351,7 @@ def _causal_conv_chunk(ctx, ins):
     keep = tail.shape[1]
     new = jax.vmap(lambda f, n: jax.lax.dynamic_slice_in_dim(f, n, keep, 0))(
         full, jnp.minimum(jnp.maximum(clen, 0), x.shape[1]))
-    return {'Out': [_conv(full, ins['Weight'][0], x.shape[1])],
+    return {'Out': [_conv(full, ins['Weight'][0], x.shape[1], _bias(ins))],
             'TailOut': [_put_rows(tail, slot, new)]}
 
 

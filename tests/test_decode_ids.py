@@ -473,7 +473,8 @@ _VARIANTS = {'bfloat16': dict(kv_cache_dtype='bfloat16'),
              'draft_k2': dict(draft_k=2), 'mp2': dict(mp_shard=2)}
 _MODULE = {'k_exaone_236b_a23b': 'exaone_moe',
            'joyai_llm_flash': 'joyai_llm_flash',
-           'qwen3_next_80b_a3b': 'qwen3_next'}
+           'qwen3_next_80b_a3b': 'qwen3_next',
+           'phi4_mini_flash_reasoning': 'phi4_flash'}
 # recorded on the parent of PR 45 (260716d), before models/transformer.py
 # moved onto DecodeSpecBuilder: transformer_base_lm's row module and every
 # module of its bfloat16, int8, draft_k=2 (the verify module with it) and
@@ -646,11 +647,22 @@ def stablehlo(exported):
     return lambda config: exported(config)['modules']
 
 
-_PINNED = dict(_PARENT_STABLEHLO, **_PARENT_STABLEHLO_45)
+# recorded in PR 47, which brought models/phi4_flash.py (the builder's
+# shared_pools / no_cache / last_only_from arguments with it: every table
+# above was recorded WITHOUT them and holds with them): that model's modules
+# at its toy widths, for the PRs after it
+_STABLEHLO_47 = {
+    'phi4_mini_flash_reasoning/decode_blockcopy': 'b1b733455362e885',
+    'phi4_mini_flash_reasoning/decode_step': 'd098e911b96ee945',
+    'phi4_mini_flash_reasoning/decode_zeros': '8ef474a32769d8d4',
+    'phi4_mini_flash_reasoning/prefill_chunk_00008': '5a8223a29dbb3664',
+    'phi4_mini_flash_reasoning/prefill_chunk_00016': '56bfbb3268e413bf'}
+_PINNED = dict(_PARENT_STABLEHLO, **_PARENT_STABLEHLO_45, **_STABLEHLO_47)
 
 
 @pytest.mark.parametrize('module', sorted(_PARENT_STABLEHLO)
-                         + sorted(_PARENT_STABLEHLO_45))
+                         + sorted(_PARENT_STABLEHLO_45)
+                         + sorted(_STABLEHLO_47))
 def test_every_one_row_program_is_the_parents_stablehlo(stablehlo, module):
     config, d = module.split('/')
     assert stablehlo(config)[d] == _PINNED[module]

@@ -647,7 +647,11 @@ def one_chip():
     (64, 49153, 16, 1024, (64, 8, 0), 768, jnp.bfloat16),
     (64, 2625, 16, 1024, (64, 8, 128), 768, jnp.bfloat16),
     # qwen3_next_80b_a3b: 16 query / 2 K/V heads of 256
-    (128, 36865, 16, 512, (16, 2, 0), 288, jnp.bfloat16)])
+    (128, 36865, 16, 512, (16, 2, 0), 288, jnp.bfloat16),
+    # phi4_mini_flash_reasoning: differential attention's 40 padded query
+    # heads over 10 [k_1 | k_2] tiles of 128, the full and a window layer
+    (64, 18433, 16, 1280, (40, 10, 0), 288, jnp.bfloat16),
+    (64, 4161, 16, 1280, (40, 10, 512), 288, jnp.bfloat16)])
 def test_kernel_compiles_for_v5e(one_chip, s, nb, bs, d, h, maxb, dtype):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -730,3 +734,39 @@ def test_gated_delta_rule_compiles_for_v5e(one_chip, tokens):
     if not tokens:
         assert ctx.tracer.lowered_bodies == [('gated_delta_step', 'kernel')]
         assert compiled.as_text().count('tpu_custom_call') == 1
+
+
+# a Mamba layer's selective scan (ops/state_space_ops.py, ISSUE 47;
+# tests/test_phi4_flash.py has the rest) at phi4_mini_flash_reasoning's
+# widths — 64 slots x [16, 5120] float32 of state; the step, and the chunk
+# form over a 512-token and a 128-token slice — for the chip's compiler,
+# which one test file loads
+@pytest.mark.parametrize('tokens', [0, 128, 512])
+def test_selective_scan_compiles_for_v5e(one_chip, tokens):
+    import types
+    from paddle_tpu.ops import state_space_ops as sso
+    slots, di, n = 64, 5120, 16
+    ctx = types.SimpleNamespace(attr=lambda n, d=None: d)
+
+    def sds(shape, dt=np.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    lead = (1, tokens) if tokens else (slots,)
+    ins = {'X': sds(lead + (di,)), 'Dt': sds(lead + (di,)),
+           'B': sds(lead + (n,)), 'C': sds(lead + (n,)),
+           'ALog': sds((n, di)), 'DtBias': sds((di,)), 'D': sds((di,))}
+    if tokens:
+        ins.update({k: sds((1, 1), np.int32)
+                    for k in ('Start', 'ChunkLen', 'StateSlot')})
+        op = sso._selective_scan_chunk
+    else:
+        ins['BlockTable'] = sds((slots, 288), np.int32)
+        op = sso._selective_scan_step
+
+    def fn(ins, state):
+        out = op(ctx, dict({k: [v] for k, v in ins.items()}, State=[state]))
+        return out['Out'][0], out['StateOut'][0]
+    compiled = jax.jit(fn, donate_argnums=1).lower(
+        ins, sds((slots, n, di))).compile()
+    # the state (21 MB) is updated in place, and a slice's discretised
+    # terms ([512, 5120, 16] float32: 168 MB) are never built
+    assert compiled.memory_analysis().temp_size_in_bytes < 64e6
