@@ -124,6 +124,7 @@ fixed <checkout>/.compile_cache; artifacts go under --out
 (default <checkout>/chip_smoke_out). No network, no git, no child process.
 """
 import argparse
+import functools
 import gc
 import json
 import os
@@ -183,7 +184,9 @@ FULL = {
                          width=640, v_width=512, n_head=32, max_blocks=288),
     # Qwen3-Next: the benchmark configuration's own file and artifact
     'qwen': dict(seed=42, logit_prompts=(300, 1500, 4000, 417), new=64,
-                 rule_prompts=48),
+                 rule_prompts=48,
+                 # (tokens, key heads, value heads, dk, dv): a slice's rule
+                 chunk_rule=(512, 16, 32, 128, 128)),
     # Phi-4-mini-flash: the benchmark configuration's own file and artifact
     'phi': dict(seed=42, logit_prompts=(300, 1500, 4000, 417), new=64,
                 rule_prompts=48),
@@ -228,7 +231,7 @@ TOY = {
     'latent_paged': dict(slots=16, num_blocks=321, block_size=16, width=256,
                          v_width=128, n_head=4, max_blocks=20),
     'qwen': dict(seed=42, logit_prompts=(5, 21, 40, 100), new=8,
-                 rule_prompts=4),
+                 rule_prompts=4, chunk_rule=(128, 1, 2, 128, 128)),
     'phi': dict(seed=42, logit_prompts=(5, 21, 40, 100), new=8,
                 rule_prompts=4),
     'grouped': {'toy.step': (16, 2, 8, 4, 128, 256, 2),
@@ -630,6 +633,10 @@ class Smoke(object):
         with DecodingPredictor(art) as pred:
             attention = pred.stats.snapshot()['attention']
             experts = pred.expert_bodies
+            chunk_bodies = {
+                prog: by_op.get('gated_delta_chunk')
+                for prog, by_op in pred.attention_bodies.items()
+                if prog.startswith('chunk_')}
             tokens, logits = served_logits(pred, prompts, q['new'])
             streams = [pred.submit(p, max_new_tokens=int(v['max_new_tokens']))
                        for p in rule_prompts]
@@ -667,6 +674,8 @@ class Smoke(object):
                'rows': len(want), 'logit_std': float(want.std()),
                'served': row_errors(np.concatenate(logits)),
                'step_attention': attention, 'expert_bodies': experts,
+               'chunk_rule_bodies': chunk_bodies,
+               'chunk_rule': self._chunk_rule(*q['chunk_rule']),
                'recurrent_state_bytes': snap['recurrent_state_bytes'],
                'state_resets': snap['state_resets'],
                'state_rows_kept': snap['state_rows_kept'],
@@ -712,6 +721,9 @@ class Smoke(object):
                 'largest_margins': margins[:8]}
         if self.cfg is not FULL:      # the bounds are the chip's
             return out
+        if any(set(b or ()) != {'kernel'} for b in chunk_bodies.values()):
+            raise AssertionError('a chunk program\'s rule is not the kernel: '
+                                 '%s' % json.dumps(chunk_bodies))
         if not out['served']['row_error_p50'] <= QWEN_LOGIT_TOL:
             raise AssertionError('served logits: median row error over the '
                                  'bound: %s' % json.dumps(out))
@@ -725,6 +737,50 @@ class Smoke(object):
         if not out['rule']['lower_precision']['over_margin_eps']:
             raise AssertionError('the cell\'s rule would pass the reference '
                                  'one precision down: %s' % json.dumps(out))
+        return out
+
+    def _chunk_rule(self, tokens, hk, hv, dk, dv):
+        """The chunked rule alone at a slice's shape (one row, a carried
+        state, the chunk three quarters full): the Pallas kernel
+        (ops/pallas_delta_chunk.py; interpret mode off the chip) against
+        delta_chunk as XLA lowers it — milliseconds a call of each, and
+        their largest difference, which must be float32 rounding."""
+        import numpy as np
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.ops import linear_attention_ops as lao
+        from paddle_tpu.ops import pallas_delta_chunk as pdc
+        rng = np.random.RandomState(tokens)
+        n = lambda *s: jnp.asarray(rng.randn(*s).astype(np.float32))
+        take = 3 * tokens // 4
+        real = (jnp.arange(tokens) < take)[None, :, None]
+        q = lao.l2_normalize(n(1, tokens, hk, dk)) * dk ** -0.5
+        k = jnp.where(real[..., None], lao.l2_normalize(n(1, tokens, hk, dk)),
+                      0.0)
+        args = (q.reshape(1, tokens, -1), k.reshape(1, tokens, -1),
+                n(1, tokens, hv * dv),
+                jnp.where(real, -jnp.abs(n(1, tokens, hv)) * 0.05, 0.0),
+                jnp.where(real, jax.nn.sigmoid(n(1, tokens, hv)), 0.0),
+                n(2, hv, dk, dv), jnp.full((1,), 5, jnp.int32),
+                jnp.full((1,), take, jnp.int32), jnp.ones((1,), jnp.int32))
+        on_chip = self.cfg is FULL
+        want, jnp_s = _timed(jax.jit(functools.partial(
+            pdc.jnp_chunk, sub=64)), args, calls=10 if on_chip else 1)
+        got, kernel_s = _timed(jax.jit(functools.partial(
+            pdc.delta_chunk, interpret=not on_chip)), args,
+            calls=10 if on_chip else 1)
+        err = max(float(jnp.abs(got[0][:, :take] - want[0][:, :take]).max()),
+                  float(jnp.abs(got[1] - want[1]).max()))
+        scale = float(jnp.abs(want[1]).max())
+        out = {'tokens': tokens, 'chunk_len': take, 'heads': [hk, hv],
+               'kernel_ms_a_call': kernel_s * 1e3,
+               'jnp_ms_a_call': jnp_s * 1e3, 'max_abs_err': err,
+               'state_scale': scale,
+               'rows_past_chunk_len_zero':
+                   not bool(jnp.any(got[0][:, take:]))}
+        if err > 2e-5 * max(scale, 1.0) or not out['rows_past_chunk_len_zero']:
+            raise AssertionError('the chunk kernel is not delta_chunk: %s'
+                                 % json.dumps(out))
         return out
 
     def phase_f(self):

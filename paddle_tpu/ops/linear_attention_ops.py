@@ -16,11 +16,13 @@ in two forms. The STEP (gated_delta_step) takes one token a slot against
 the carried state; both products are taken from the OLD state —
 o = e^g S^T q + (k . q) delta — so the new state is written in one pass
 and never read (on a TPU by a Pallas kernel that holds a slot's state in
-fast memory meanwhile: ops/pallas_delta_rule.py). The CHUNK (gated_delta_chunk) takes C tokens of one
-request from a carried state to a carried state in the chunked form of
-the same recurrence, sub-chunks of up to 64 tokens: inside a sub-chunk
-with G the running sum of g and L = strictly-lower(k_beta k^T * e^{G_i -
-G_j}), T = (I + L)^-1 (a unit lower triangular solve),
+fast memory meanwhile: ops/pallas_delta_rule.py). The CHUNK
+(gated_delta_chunk) takes C tokens of one request from a carried state to
+a carried state in the chunked form of the same recurrence (on a TPU by a
+Pallas kernel too, where the shapes allow: ops/pallas_delta_chunk.py),
+sub-chunks of up to 64 tokens: inside a sub-chunk with G the running sum
+of g and L = strictly-lower(k_beta k^T * e^{G_i - G_j}), T = (I + L)^-1 (a
+unit lower triangular solve),
 
     v_new = T v_beta - T (k_beta e^G) S
     o     = (q e^G) S + lower(q k^T * e^{G_i - G_j}) v_new
@@ -93,24 +95,32 @@ def decay_and_strength(a, b, a_log, dt_bias):
     return g, jax.lax.logistic(b.astype(jnp.float32))
 
 
-def _heads(q, k, v, n_key_head, n_value_head):
-    """q, k [..., Hk * dk], v [..., Hv * dv] -> normalised, scaled and
-    repeated per VALUE head: q, k [..., Hv, dk], v [..., Hv, dv]; value
-    head h reads key head h // (Hv / Hk)."""
+def _key_heads(q, k, v, n_key_head, n_value_head):
+    """q, k [..., Hk * dk], v [..., Hv * dv] -> normalised and scaled per
+    KEY head: q, k [..., Hk, dk], v [..., Hv, dv]."""
     lead = q.shape[:-1]
     dk = q.shape[-1] // n_key_head
-    rep = n_value_head // n_key_head
     q = l2_normalize(q.astype(jnp.float32).reshape(lead + (n_key_head, dk)))
     k = l2_normalize(k.astype(jnp.float32).reshape(lead + (n_key_head, dk)))
     q = q * (float(dk) ** -0.5)
     v = v.astype(jnp.float32).reshape(lead + (n_value_head, -1))
+    return q, k, v
 
-    def per_value_head(x):      # [..., Hk, dk] -> [..., Hv, dk]
-        x = jnp.broadcast_to(x[..., :, None, :],
-                             lead + (n_key_head, rep, dk))
-        return x.reshape(lead + (n_value_head, dk))
 
-    return per_value_head(q), per_value_head(k), v
+def per_value_head(x, n_value_head):
+    """[..., Hk, dk] -> [..., Hv, dk]: value head h reads key head
+    h // (Hv / Hk)."""
+    lead, (n_key_head, dk) = x.shape[:-2], x.shape[-2:]
+    x = jnp.broadcast_to(x[..., :, None, :],
+                         lead + (n_key_head, n_value_head // n_key_head, dk))
+    return x.reshape(lead + (n_value_head, dk))
+
+
+def _heads(q, k, v, n_key_head, n_value_head):
+    """_key_heads, q and k repeated per VALUE head: [..., Hv, dk]."""
+    q, k, v = _key_heads(q, k, v, n_key_head, n_value_head)
+    return (per_value_head(q, n_value_head),
+            per_value_head(k, n_value_head), v)
 
 
 def live_rows(table):
@@ -156,8 +166,10 @@ def _solve_triangular(unit, rhs):
 
 
 # The unit lower triangular solve of the chunked rule, by platform
-# (pallas_delta_rule.py's idiom): on a TPU XLA expands lax's triangular
-# solve into blocked products at HIGHEST; on the cpu the same op is a LAPACK
+# (pallas_delta_rule.py's idiom): on a TPU XLA inverts the diagonal blocks
+# in a custom call (InvertDiagBlocksLowerTriangular, 0.69 ms a layer at the
+# benchmark's slice: PERF.md 6, PR 48 — the chunk KERNEL forms the inverse
+# itself and never comes here); on the cpu the same op is a LAPACK
 # call, and a decode artifact's serialized cpu executable that holds one
 # crashed the process that loaded it (SIGSEGV in the first chunk dispatch of
 # a warm benchmark rehearsal) — there the rows are solved one by one.
@@ -285,10 +297,30 @@ def _gated_delta_chunk(ctx, ins):
     ChunkLen tokens, written back to that slot. Q, K [R, C, Hk * dk], V
     [R, C, Hv * dv], A, B [R, C, Hv], ALog, DtBias [Hv], State [S, Hv,
     dk, dv], Start, ChunkLen, StateSlot [R, 1] int32. Out [R, C, Hv *
-    dv] float32 (rows from ChunkLen on are unread)."""
+    dv] float32 (rows from ChunkLen on are unread).
+
+    Two bodies, chosen as gated_delta_step chooses (ops/
+    pallas_delta_chunk.py `refuses`: a float32 state, heads of whole
+    128-lane blocks, a chunk of whole kernel sub-chunks; no mesh in the
+    trace): a primitive whose TPU rule is the Pallas kernel — a row's
+    value head through all its sub-chunks with its state in fast memory,
+    the inverse formed exactly inside — and whose rule on every other
+    platform is delta_chunk; else delta_chunk alone. The op tells its
+    Tracer which (lowered_bodies: 'kernel' | 'jnp')."""
+    from ..parallel.mesh import current_trace_mesh
+    from . import pallas_delta_chunk as pdc
     hk, hv = int(ctx.attr('n_key_head')), int(ctx.attr('n_value_head'))
+    sub = int(ctx.attr('sub_chunk', _SUB_CHUNK))
     state = ins['State'][0]
-    q, k, v = _heads(ins['Q'][0], ins['K'][0], ins['V'][0], hk, hv)
+    tracer = getattr(ctx, 'tracer', None)
+    kernel = (tracer is not None and current_trace_mesh() is None
+              and pdc.refuses(state, ins['Q'][0],
+                              ins['Q'][0].shape[1]) is None)
+    if tracer is not None:
+        tracer.lowered_bodies.append(
+            ('gated_delta_chunk', 'kernel' if kernel else 'jnp'))
+    q, k, v = (_key_heads if kernel else _heads)(
+        ins['Q'][0], ins['K'][0], ins['V'][0], hk, hv)
     g, beta = decay_and_strength(ins['A'][0], ins['B'][0],
                                  ins['ALog'][0], ins['DtBias'][0])
     start, clen, slot = (ins[n][0].reshape(-1)
@@ -296,9 +328,13 @@ def _gated_delta_chunk(ctx, ins):
     real = (jnp.arange(k.shape[1])[None, :] < clen[:, None])[..., None]
     g, beta = _where(real, g, 0.0), _where(real, beta, 0.0)
     k = _where(real[..., None], k, 0.0)
+    if kernel:
+        flat = lambda x: x.reshape(x.shape[:2] + (-1,))
+        o, new = pdc.kernel_or_jnp(flat(q), flat(k), flat(v), g, beta, state,
+                                   start, clen, slot, sub)
+        return {'Out': [o], 'StateOut': [new]}
     s0 = _slot_rows(state, slot, start).astype(jnp.float32)
-    o, s1 = delta_chunk(q, k, v, g, beta, s0,
-                        sub=int(ctx.attr('sub_chunk', _SUB_CHUNK)))
+    o, s1 = delta_chunk(q, k, v, g, beta, s0, sub=sub)
     return {'Out': [o.reshape(o.shape[:2] + (-1,))],
             'StateOut': [_put_rows(state, slot, s1)]}
 

@@ -635,9 +635,15 @@ def exported(tmp_path_factory):
                     modules[d] = _digest(_location_free(text))
             with open(os.path.join(art, decoding._DECODE_SIGNATURE)) as f:
                 sig = json.load(f)
+            # since PR 48 gated_delta_chunk says which body it lowered:
+            # the digests were recorded without that entry, so it is
+            # taken out here and held to its own test
+            said = {size: entry['attention'].pop('gated_delta_chunk', None)
+                    for size, entry in sig['chunk'].items()}
             made[config] = {
                 'modules': modules, 'weights': _digest(*weights),
-                'signature': _digest(json.dumps(sig, sort_keys=True))}
+                'signature': _digest(json.dumps(sig, sort_keys=True)),
+                'chunk_rule': said}
         return made[config]
     return get
 
@@ -676,6 +682,18 @@ def test_startup_weights_and_signature_are_the_parents(exported, config):
     got = exported(config)
     assert (got['weights'], got['signature']) \
         == _PARENT_WEIGHTS_AND_SIGNATURE[config]
+
+
+@pytest.mark.parametrize('config', sorted(_PARENT_WEIGHTS_AND_SIGNATURE))
+def test_only_the_chunked_delta_rule_says_a_body_of_its_own(exported,
+                                                            config):
+    """What PR 48 added to a signature: each chunk program of the one model
+    that lowers gated_delta_chunk names the body it lowered — at the toy
+    widths the kernel refuses, the parent's expression (its StableHLO is
+    pinned above) — and no other model's signature gained a word."""
+    said = exported(config)['chunk_rule']
+    want = {'jnp': 3} if config == 'qwen3_next_80b_a3b' else None
+    assert said == {'8': want, '16': want}
 
 
 @pytest.mark.parametrize('config', sorted(_ROW_MODULES))

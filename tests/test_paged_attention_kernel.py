@@ -736,6 +736,40 @@ def test_gated_delta_rule_compiles_for_v5e(one_chip, tokens):
         assert compiled.as_text().count('tpu_custom_call') == 1
 
 
+# the chunked rule as a program exported for a TPU lowers it (ISSUE 48): with
+# a Tracer the op takes the primitive whose TPU rule is the Pallas chunk
+# kernel (ops/pallas_delta_chunk.py), at the cell's widths and both slices
+@pytest.mark.parametrize('tokens', [128, 512])
+def test_gated_delta_chunk_kernel_compiles_for_v5e(one_chip, tokens):
+    import types
+    from paddle_tpu.ops import linear_attention_ops as lao
+    slots, hk, hv, dk, dv = 128, 16, 32, 128, 128
+    ctx = types.SimpleNamespace(
+        attr=lambda n, d=None: {'n_key_head': hk, 'n_value_head': hv}.get(
+            n, d), tracer=types.SimpleNamespace(lowered_bodies=[]))
+
+    def sds(shape, dt=np.float32):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    lead = (1, tokens)
+    ins = {'Q': sds(lead + (hk * dk,)), 'K': sds(lead + (hk * dk,)),
+           'V': sds(lead + (hv * dv,)), 'A': sds(lead + (hv,)),
+           'B': sds(lead + (hv,)), 'ALog': sds((hv,)), 'DtBias': sds((hv,))}
+    ins.update({n: sds((1, 1), np.int32)
+                for n in ('Start', 'ChunkLen', 'StateSlot')})
+
+    def fn(ins, state):
+        out = lao._gated_delta_chunk(
+            ctx, dict({k: [v] for k, v in ins.items()}, State=[state]))
+        return out['Out'][0], out['StateOut'][0]
+    compiled = jax.jit(fn, donate_argnums=1).lower(
+        ins, sds((slots, hv, dk, dv))).compile()
+    assert ctx.tracer.lowered_bodies == [('gated_delta_chunk', 'kernel')]
+    assert compiled.as_text().count('tpu_custom_call') == 1
+    # the state is updated in place, and nothing of the jnp body's
+    # [sub-chunks, rows, heads, n, n] temporaries is left
+    assert compiled.memory_analysis().temp_size_in_bytes < 40e6
+
+
 # a Mamba layer's selective scan (ops/state_space_ops.py, ISSUE 47;
 # tests/test_phi4_flash.py has the rest) at phi4_mini_flash_reasoning's
 # widths — 64 slots x [16, 5120] float32 of state; the step, and the chunk

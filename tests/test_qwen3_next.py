@@ -638,6 +638,171 @@ def pdr_refuses_toy():
                                                        jnp.float32))
 
 
+def _chunk_kernel_inputs(rng, rows, c, takes, hk=1, hv=2, dk=128, dv=128,
+                         slots=3):
+    """What the op hands the chunk kernel: q, k normalised per KEY head,
+    g, beta and k zero from ChunkLen on."""
+    n = lambda *s: jnp.asarray(rng.randn(*s).astype(np.float32))
+    q = lao.l2_normalize(n(rows, c, hk, dk)) * dk ** -0.5
+    k = lao.l2_normalize(n(rows, c, hk, dk))
+    g = -jnp.abs(n(rows, c, hv)) * 0.1
+    beta = jax.nn.sigmoid(n(rows, c, hv))
+    clen = jnp.asarray(takes, jnp.int32)
+    real = (jnp.arange(c)[None, :] < clen[:, None])[..., None]
+    return (q.reshape(rows, c, -1),
+            jnp.where(real[..., None], k, 0.0).reshape(rows, c, -1),
+            n(rows, c, hv * dv), jnp.where(real, g, 0.0),
+            jnp.where(real, beta, 0.0), n(slots, hv, dk, dv), clen)
+
+
+# (C, ChunkLen a row, Start a row, slot a row): the carried-state cases of
+# test_chunked_rule_is_the_recurrence_from_a_carried_state at lengths the
+# kernel takes (inside a sub-chunk, nothing at all), a chunk that ends AT a
+# sub-chunk's edge and a whole one, Start == 0 over a dirty slot, a slot
+# outside [0, S), two rows with different slots
+_CHUNK_KERNEL_CASES = [
+    (256, (177,), (5,), (1,)), (128, (0,), (5,), (1,)),
+    (256, (128,), (5,), (1,)), (128, (128,), (5,), (1,)),
+    (384, (260,), (0,), (2,)), (128, (100,), (0,), (7,)),
+    (128, (100,), (3,), (-1,)), (256, (256, 40), (0, 9), (2, 0))]
+
+
+@pytest.mark.parametrize('c, takes, starts, at', _CHUNK_KERNEL_CASES)
+def test_chunk_kernel_is_the_jnp_body_and_the_recurrence(c, takes, starts,
+                                                         at):
+    """ops/pallas_delta_chunk.py in interpret mode against delta_chunk (the
+    expression XLA lowers) AND against delta_step token by token: the real
+    rows' outputs, ZERO rows from ChunkLen on, the state left at ChunkLen in
+    the row's slot and every other slot's as it was."""
+    from paddle_tpu.ops import pallas_delta_chunk as pdc
+    rng = np.random.RandomState(c + sum(takes))
+    rows, hv, dk = len(takes), 2, 128
+    q, k, v, g, beta, state, clen = _chunk_kernel_inputs(rng, rows, c, takes)
+    assert pdc.refuses(state, q, c) is None
+    args = (q, k, v, g, beta, state, jnp.asarray(starts, jnp.int32), clen,
+            jnp.asarray(at, jnp.int32))
+    o, new = pdc.delta_chunk(*args, interpret=True)
+    want_o, want = pdc.jnp_chunk(*args, sub=64)
+    touched = [s for s in at if 0 <= s < state.shape[0]]
+    np.testing.assert_allclose(np.asarray(new), np.asarray(want), rtol=1e-5,
+                               atol=2e-5)
+    others = [s for s in range(state.shape[0]) if s not in touched]
+    np.testing.assert_array_equal(np.asarray(new)[others],
+                                  np.asarray(state)[others])
+    for r, take in enumerate(takes):
+        np.testing.assert_allclose(np.asarray(o)[r, :take],
+                                   np.asarray(want_o)[r, :take], rtol=1e-5,
+                                   atol=2e-5)
+        assert not np.asarray(o)[r, take:].any()
+        # the recurrence, from the state the row starts from
+        s = (state[min(max(at[r], 0), state.shape[0] - 1)][None]
+             if starts[r] else jnp.zeros_like(state[:1]))
+        head = lambda x, d: lao.per_value_head(
+            x[r].reshape(c, 1, -1, d), hv)
+        qs, ks = head(q, dk), head(k, dk)
+        vs = v[r].reshape(c, 1, hv, -1)
+        outs = []
+        for t in range(take):
+            out, s = lao.delta_step(qs[t], ks[t], vs[t], g[r, t][None],
+                                    beta[r, t][None], s)
+            outs.append(np.asarray(out[0]).reshape(-1))
+        if take:
+            np.testing.assert_allclose(np.asarray(o)[r, :take],
+                                       np.stack(outs), rtol=2e-5, atol=2e-5)
+            assert np.abs(np.stack(outs)).max() > 0.05
+        if 0 <= at[r] < state.shape[0]:
+            np.testing.assert_allclose(np.asarray(new)[at[r]],
+                                       np.asarray(s[0]), rtol=2e-5,
+                                       atol=2e-5)
+
+
+@pytest.mark.parametrize('hard', ['beta_one', 'no_decay', 'parallel_keys',
+                                  'all_three'])
+def test_the_kernels_inverse_is_the_row_by_row_solve(hard):
+    """(I + L)^-1 as the kernel forms it — substitution inside 16-row
+    blocks, merged by products — against _solve_by_rows where the solve is
+    hardest: beta at 1, no decay, keys nearly parallel (L's entries near
+    1: a truncated series, or powers of L, would lose every digit)."""
+    from paddle_tpu.ops import pallas_delta_chunk as pdc
+    from jax.experimental import pallas as pl
+    rng = np.random.RandomState(len(hard))
+    n, d = 128, 128
+    k = rng.randn(n, d).astype(np.float32)
+    if hard in ('parallel_keys', 'all_three'):
+        k = k[:1] + 0.05 * k
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    beta = (np.ones(n) if hard in ('beta_one', 'all_three')
+            else rng.uniform(0.2, 0.9, n)).astype(np.float32)
+    g = (np.zeros(n) if hard in ('no_decay', 'all_three')
+         else -rng.uniform(0.0, 0.2, n)).astype(np.float32)
+    G = np.cumsum(g)
+    full = (beta[:, None] * k) @ k.T * np.exp(G[:, None] - G[None, :])
+    lower = jnp.asarray(np.tril(full, -1).astype(np.float32))
+    rhs = jnp.asarray(rng.randn(n, d).astype(np.float32))
+
+    def kernel(lower_ref, out_ref):
+        out_ref[...] = pdc.unit_lower_inverses([lower_ref[...]])[0]
+    inverse = pl.pallas_call(
+        kernel, out_shape=jax.ShapeDtypeStruct((n, n), jnp.float32),
+        interpret=True)(lower)
+    got = jnp.matmul(inverse, rhs, precision=jax.lax.Precision.HIGHEST)
+    want = lao._solve_by_rows(lower + jnp.eye(n), rhs)
+    exact = np.linalg.solve(np.asarray(lower, np.float64) + np.eye(n),
+                            np.asarray(rhs, np.float64))
+    scale = np.abs(exact).max()
+    assert np.abs(np.asarray(got) - exact).max() <= max(
+        4 * np.abs(np.asarray(want) - exact).max(), 2e-6 * scale)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=2e-5 * scale)
+
+
+def test_the_chunk_kernel_says_by_name_what_it_does_not_take():
+    from paddle_tpu.ops import pallas_delta_chunk as pdc
+    sds = jax.ShapeDtypeStruct
+    state = sds((128, 32, 128, 128), jnp.float32)
+    q = sds((1, 512, 16 * 128), jnp.float32)
+    assert pdc.refuses(state, q, 512) is None
+    assert pdc.refuses(state, q, 128) is None
+    assert 'not float32' in pdc.refuses(
+        sds((8, 32, 128, 128), jnp.bfloat16), q, 512)
+    assert 'lane' in pdc.refuses(sds((8, 4, 8, 128), jnp.float32),
+                                 sds((1, 128, 16), jnp.float32), 128)
+    assert 'lane' in pdc.refuses(sds((8, 4, 128, 64), jnp.float32),
+                                 sds((1, 128, 256), jnp.float32), 128)
+    assert 'value heads' in pdc.refuses(sds((8, 3, 128, 128), jnp.float32),
+                                        sds((1, 128, 256), jnp.float32), 128)
+    assert 'sub-chunks' in pdc.refuses(state, q, 64)
+    assert 'fast memory' in pdc.refuses(state, q, 8192)
+    # the toy's 8-wide heads and 16-token chunks: the jnp expression alone
+    assert pdc.refuses(sds((8, 4, 8, 8), jnp.float32),
+                       sds((1, 16, 16), jnp.float32), 16)
+
+
+def test_a_chunk_the_kernel_takes_is_exported_with_both_bodies(tmp_path):
+    """Heads of 128 and slices of whole 128-token sub-chunks: each chunk program's
+    rule lowers to the primitive that carries the kernel for a TPU and
+    delta_chunk for everything else — the signature says so, on the cpu
+    attention_bodies reads jnp, and the artifact serves the reference's
+    logits through slices that carry the state."""
+    art, w = _export(tmp_path / 'art', lin_dk=128, lin_dv=128, max_slots=4,
+                     chunk_sizes=(128, 256), max_cache_len=512)
+    with open(os.path.join(art, decoding._DECODE_SIGNATURE)) as f:
+        sig = json.load(f)
+    for size in ('128', '256'):
+        assert sig['chunk'][size]['attention']['gated_delta_chunk'] \
+            == {'kernel': 3}
+    assert sig['step']['attention']['gated_delta_step'] == {'kernel': 3}
+    prompts = _prompts((300,))         # a whole slice of 256, then 44 of 128
+    with DecodingPredictor(art) as pred:
+        for prog in ('chunk_128', 'chunk_256'):
+            assert pred.attention_bodies[prog]['gated_delta_chunk'] \
+                == {'jnp': 3}
+        tokens, logits = served_logits(pred, prompts, 4)
+    seq = np.concatenate([prompts[0], np.asarray(tokens[0][:-1], np.int64)])
+    want = np.asarray(ref.logits(w, seq, **dict(REF, dk=128, dv=128)))[299:]
+    assert np.abs(want - logits[0]).max() <= F32_TOL
+
+
 def test_bfloat16_is_what_the_stated_precision_costs(tmp_path):
     """The stated precision (bfloat16 weights and K/V, float32 state) moves
     the served logits by far more than float32 rounding and far less than
