@@ -57,7 +57,11 @@ this one process — a chip belongs to one process at a time):
               the EXPANDED form while the programs absorb; the step's
               attention the latent paged kernel (one 640-wide pool a
               layer), which is also run against its jnp body at the
-              benchmark's shape (128 slots, 288 pages a slot, bfloat16).
+              benchmark's shape (128 slots, 288 pages a slot, bfloat16),
+              timed there on three sets of slots (the ragged ones, every
+              slot at the cell's 600-2,400 rows, every slot at 255 rows:
+              one block a slot, the per-slot floor) and, given --parent,
+              compared bit for bit with the parent commit's kernel.
 
   Q  linear   Qwen3-Next AS THE BENCHMARK HOLDS IT (benchmark/configs/
               qwen3_next_80b_a3b.json through its own build_spec: 12
@@ -305,9 +309,10 @@ FALLBACK = re.compile(r'fall(ing|s)? back|fallback|unusable|unavailable',
 
 
 class Smoke(object):
-    def __init__(self, cfg, out_dir, dev, n_dev):
+    def __init__(self, cfg, out_dir, dev, n_dev, parent=None):
         from paddle_tpu.core import compile_cache
         self.cfg, self.out_dir, self.dev, self.n_dev = cfg, out_dir, dev, n_dev
+        self.parent = parent
         self.cc = compile_cache
 
     def phase(self, name, fn):
@@ -1366,23 +1371,63 @@ class Smoke(object):
             raise AssertionError('latent paged kernel vs jnp body: max abs '
                                  '%.3g, relative %.3g' % (err, rel))
         # how often the kernel's full-block body engages and what a call
-        # takes (ISSUE 44): on the slots above, and with EVERY slot live
-        # at the rows joyai_llm_flash.reason_closed holds them at
-        busy = [int(x) for x in
-                rng.randint(min(600, last), min(2400, last) + 1, S)]
-        cell = _ragged_slots(rng, busy, S, NB, BS, MAXB)
+        # takes (ISSUE 44): on the slots above, with EVERY slot live at
+        # the rows joyai_llm_flash.reason_closed holds them at, and with
+        # every slot at 255 rows (ISSUE 49: one block a slot, the last
+        # block's body and a grid step — what a slot costs before its
+        # first full block)
+        def every_slot(rows):
+            rows = [min(r, last) for r in rows]
+            return rows, tuple(map(jnp.asarray, _ragged_slots(
+                rng, rows, S, NB, BS, MAXB)))
+
+        cell = every_slot([int(x) for x in rng.randint(600, 2401, S)])
 
         def timed(live, slots):
             return {'full_block_share': ppa.full_block_share(live),
                     'ms_a_call': _timed(kernel, args[:2] + slots,
                                         calls=30)[1] * 1e3}
 
+        # the bits of the parent commit's kernel (--parent: its checkout)
+        # at PR 44's five shapes — a deeper copy pipeline changes when a
+        # page arrives, and only the chip can see a half read too early
+        equal = None
+        if self.parent:
+            theirs = _module_at(os.path.join(
+                self.parent, 'paddle_tpu', 'ops',
+                'pallas_paged_attention.py'), 'parents_paged_attention')
+            parents = jax.jit(lambda *a: theirs.latent_paged_attention(
+                *a, n_head=H, v_width=DV, scale=attrs['scale'],
+                interpret=self.dev.platform != 'tpu'))
+            equal = {}
+            for name, slots in [('rows_600_2400', cell[1])] + [
+                    ('rows_%d' % r, every_slot([r] * S)[1])
+                    for r in (1535, 2815, 1400, 255)]:
+                equal[name] = bool(np.array_equal(
+                    np.asarray(kernel(*args[:2] + slots)),
+                    np.asarray(parents(*args[:2] + slots))))
+            if not all(equal.values()):
+                raise AssertionError(
+                    'latent paged kernel: not the bits of the kernel at '
+                    '%s: %s' % (self.parent, equal))
         return dict({'shape': [S, NB, BS, W, DV, H, MAXB],
                      'live_slots': len(live), 'max_abs_err': err,
                      'max_rel_err': rel,
-                     'every_slot_at_the_cells_rows': timed(
-                         busy, tuple(map(jnp.asarray, cell)))},
+                     'every_slot_at_the_cells_rows': timed(*cell),
+                     'every_slot_at_255_rows': timed(*every_slot([255] * S)),
+                     'equal_to_the_parents_kernel': equal},
                     **timed(live, args[2:]))
+
+
+def _module_at(path, name):
+    """The module in the file `path`, under `name`: a second copy of one
+    of this package's modules (another commit's) beside the imported
+    one."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
 
 
 def _timed(fn, args, reps=6, calls=3):
@@ -1424,6 +1469,10 @@ def main(argv=None):
     ap.add_argument('--phases', default='ACBMXJQFKG',
                     help='the phases to run, of A C B M X J Q F K G (C '
                     'needs 4 chips)')
+    ap.add_argument('--parent', default=None,
+                    help='a checkout of the parent commit (git archive): '
+                    'phase J compares the latent paged kernel with its '
+                    'kernel bit for bit')
     args = ap.parse_args(argv)
     if args.cpu_rehearsal:
         os.environ['JAX_PLATFORMS'] = 'cpu'
@@ -1455,7 +1504,7 @@ def main(argv=None):
     open(os.path.join(args.out, 'lines.jsonl'), 'w').close()
 
     smoke = Smoke(TOY if args.cpu_rehearsal else FULL, args.out, devs[0],
-                  len(devs))
+                  len(devs), parent=args.parent)
     for name in 'ACBMXJQFKG':
         if name in args.phases.upper() and (name != 'C' or len(devs) >= 4):
             smoke.phase(name, getattr(smoke, 'phase_' + name.lower()))

@@ -35,9 +35,10 @@ A second kernel, `latent_paged_attention` (ISSUE 40), reads a LATENT pool:
 one row a position that is key and value both (every query head scores the
 whole row and sums its first v_width channels — attention in the absorbed
 form), so a page is copied once and multiplied twice, on operands of the
-pool's dtype. Same contract, same double buffering across slots; one grid
-step a slot, because n_head rows of the pool's width a slot make the whole
-batch's query too large to hold at once. A slot's FULL blocks and its
+pool's dtype. Same contract; one grid step a slot, because n_head rows of
+the pool's width a slot make the whole batch's query too large to hold at
+once; and its copies run TWO blocks ahead over three page halves, across
+slot boundaries (ISSUE 49: `_latent_kernel`). A slot's FULL blocks and its
 last one take different bodies there (ISSUE 44): every block before the
 last holds a compute block's pages of rows that are all <= pos, so its
 copies are issued in straight-line code and waited for once and its
@@ -51,12 +52,12 @@ time (the masks, measured, none of it: PERF.md, PR 44).
 What the paging contract is made of is written ONCE and both kernels call
 it: the rule a pool's pages obey (`_pool_rule`), the clamping of `pos`
 and the table (`_clamped`), a page's async copy through the table
-(`_each_page`), the order in which a block waits for its pages while the
-next ones load (`_load_next_then_wait`) and the online softmax
-(`_softmax_start`, `_softmax_step`). What differs stays in each kernel:
-which rows a slot attends (a window), how the query meets the row
-(block-diagonal heads over K and V pools; every head over one whole
-latent row) and the grid (one step for all slots; a step a slot).
+(`_each_page`) and the online softmax (`_softmax_start`,
+`_softmax_step`). What differs stays in each kernel: which rows a slot
+attends (a window), how the query meets the row (block-diagonal heads
+over K and V pools; every head over one whole latent row), the grid (one
+step for all slots; a step a slot) and how far ahead of the block that
+computes the copies run (one block, `_load_next_then_wait`; two).
 """
 from __future__ import annotations
 
@@ -178,8 +179,8 @@ def _clamped(pos, table, n_block, bs):
 def _each_page(tab_ref, first, count, pools, buf, act, bs):
     """Start, or wait for (`act`), one async copy a page: the `count`
     pages named by the table from entry `first` on, out of each pool of
-    `pools` — (pool in HBM, its [2, rows, D] buffer, the semaphore of a
-    half) — into rows j * bs of the buffer's half `buf`. A traced count
+    `pools` — (pool in HBM, its [halves, rows, D] buffer, the semaphore of
+    a half) — into rows j * bs of the buffer's half `buf`. A traced count
     is a loop of one page a trip; a Python int is that many copies in
     straight-line code, their rows static."""
     static = isinstance(count, int)
@@ -418,27 +419,43 @@ def paged_attention(q, k_cache, v_cache, pos, table, *, n_head, scale,
     return out.reshape(n_slot, -1)
 
 
-def _latent_kernel(pos_ref, tab_ref, q_ref, c_hbm, o_ref, cbuf, sem, parity,
+def _latent_kernel(pos_ref, tab_ref, q_ref, c_hbm, o_ref, cbuf, sem, at,
                    *, v_width, scale, bs, maxb, pages):
     """`_kernel` over ONE pool whose row is both key and value: every
     query head (the rows of q_ref[0], [n_head, D]) scores the whole
     D-wide row and sums its first v_width channels, so a page is copied
     once and multiplied twice. One grid step a slot — n_head rows of D a
     slot make the whole batch's query too large to hold at once, so the
-    pipeline brings a slot's in while the last one computes — with the
-    page buffers and their parity kept across steps: after a slot's last
-    block the NEXT slot's first is already loading. The products take
-    the pool's dtype as operands with float32 sums (bfloat16 x bfloat16
-    is the MXU's own product: n_head x D x 2 operations a cached row is
-    4.5 times a K/V row's, and six passes of it would be the step); a
-    float32 pool keeps full float32.
+    pipeline brings a slot's in while the last one computes. The products
+    take the pool's dtype as operands with float32 sums (bfloat16 x
+    bfloat16 is the MXU's own product: n_head x D x 2 operations a cached
+    row is 4.5 times a K/V row's, and six passes of it would be the
+    step); a float32 pool keeps full float32.
 
     A slot is its FULL blocks, then its last one. Block i is full where
     (i + 1) * rows <= pos: `pages` pages, every row a position <= pos —
-    all of a slot's blocks but the last. Told from `pos` alone, on the
-    side that starts a block's copies and the side that waits for them
-    alike (`each_page`), so the bytes a half's semaphore is given and
-    the bytes it is asked for are one count."""
+    all of a slot's blocks but the last, and never the last. Told from
+    `pos` alone, on the side that starts a block's copies and the side
+    that waits for them alike (`each_page`), so the bytes a half's
+    semaphore is given and the bytes it is asked for are one count.
+
+    The copies run TWO blocks ahead (ISSUE 49). The call's blocks are ONE
+    sequence, slot after slot, over three page halves: block n computes
+    out of half n % 3 while n + 1 and n + 2 load, and n + 2 is started
+    between n's two products — behind the wait, in the products' own
+    basic block, the compiler lays the sixteen descriptors' scalar work
+    beside the matrix unit's (0.73 -> 0.68 us a full block on the chip;
+    started in front of the wait, where a look-ahead of ONE block has
+    to start them to cover their 0.9 us, they overlap nothing, and the
+    third half alone reads 0.72: PERF.md, PR 49). Two ahead of a slot's block i is its block i + 2 while it has
+    one; from its second-to-last block on it is another slot's — the
+    next one's first, then its second or, behind a slot of one block,
+    the first of the slot after — which is what `at` (SMEM, kept across
+    grid steps: the half the next block computes out of, then the slot
+    and block of the first block NOT yet started) is for. Nothing is
+    started past the last slot's last block, and a block waits for its
+    own copies before it reads them, so none is left in flight when the
+    call ends."""
     s = pl.program_id(0)
     n_slot = pl.num_programs(0)
     _, n_head, d = q_ref.shape
@@ -453,11 +470,14 @@ def _latent_kernel(pos_ref, tab_ref, q_ref, c_hbm, o_ref, cbuf, sem, parity,
         block's are `pages`, started in straight-line code and waited
         for ONCE, by the bytes of the whole half (a DMA semaphore counts
         bytes, and a wait needs neither the table nor a page's address);
-        any other block's are its pages that hold a position <= pos, a
-        loop trip each. `full` True: the caller knows."""
+        a last block's are its pages that hold a position <= pos, a loop
+        trip each. `full` True or False: the caller knows which."""
         def some(count):
             _each_page(tab_ref, s * maxb + i * pages, count, pools, buf, act,
                        bs)
+
+        def last():
+            some(pos_ref[s] // bs + 1 - i * pages)
 
         def whole():
             if act == 'start':
@@ -466,38 +486,62 @@ def _latent_kernel(pos_ref, tab_ref, q_ref, c_hbm, o_ref, cbuf, sem, parity,
                 half = cbuf.at[buf]
                 pltpu.make_async_copy(half, half, sem.at[buf]).wait()
 
-        if full:
-            return whole()
-        full = (i + 1) * rows <= pos_ref[s]
-        pl.when(full)(whole)
+        if full is None:
+            full = (i + 1) * rows <= pos_ref[s]
+            pl.when(full)(whole)
+            pl.when(jnp.logical_not(full))(last)
+        elif full:
+            whole()
+        else:
+            last()
 
-        @pl.when(jnp.logical_not(full))
+    def start_next(buf):
+        """Start the call's first unstarted block, if it has one, into
+        half `buf`, and move `at` on: to the slot's next block or, past
+        its last, the next slot's first."""
+        to, i = at[1], at[2]
+
+        @pl.when(to < n_slot)
         def _():
-            some(pos_ref[s] // bs + 1 - i * pages)
+            each_page(to, i, buf, 'start')
+            more = (i + 1) * rows <= pos_ref[to]
+            at[1] = jnp.where(more, to, to + 1)
+            at[2] = jnp.where(more, i + 1, 0)
 
     @pl.when(s == 0)
     def _():
-        parity[0] = 0
-        each_page(0, 0, 0, 'start')
+        at[0] = at[1] = at[2] = 0
+        start_next(0)
+        start_next(1)
 
     pos = pos_ref[s]
     nblk = pos // rows + 1
     q = (q_ref[0] * scale).astype(mult)                         # [H, D]
 
-    def block(i, carry, full):
-        """Block i out of half `buf`: a full one (every block before
+    def two_on(full):
+        """Block i + 2 of this slot: a full one, or its last."""
+        return lambda i, buf: each_page(s, i + 2, buf, 'start', full=full)
+
+    def next_slots(i, buf):
+        """Behind this slot's second-to-last block: the next slot's
+        first, which is where `at` takes over."""
+        at[1] = s + 1
+        at[2] = 0
+        start_next(buf)
+
+    def block(i, carry, full, ahead):
+        """Block i out of half `buf`, with the block two on started
+        (`ahead`) between its products: a full one (every block before
         the slot's last) takes its rows as they lie; the last one masks
         what lies past pos, scores and values both."""
         m, l, acc, buf = carry
-        if full:
-            each_page(s, i + 1, 1 - buf, 'start')
-            each_page(s, i, buf, 'wait', full=True)
-        else:
-            _load_next_then_wait(each_page, s, i, nblk, n_slot, buf)
+        each_page(s, i, buf, 'wait', full=full)
         c = cbuf[buf]                                           # [rows, D]
         sc = lax.dot_general(
             q, c, (((1,), (1,)), ((), ())), precision=precision,
             preferred_element_type=jnp.float32)                 # [H, rows]
+        # the half block n - 1 has just left is n + 2's
+        ahead(i, jnp.where(buf == 0, 2, buf - 1))
         if not full:
             col = lax.broadcasted_iota(jnp.int32, sc.shape, 1)
             sc = jnp.where(i * rows + col <= pos, sc, -jnp.inf)
@@ -510,13 +554,24 @@ def _latent_kernel(pos_ref, tab_ref, q_ref, c_hbm, o_ref, cbuf, sem, parity,
             return jnp.dot(p.astype(mult), v, precision=precision,
                            preferred_element_type=jnp.float32)  # [H, dv]
 
-        return _softmax_step(m, l, acc, sc, weigh) + (1 - buf,)
+        return _softmax_step(m, l, acc, sc, weigh) + (
+            jnp.where(buf == 2, 0, buf + 1),)
 
-    carry = lax.fori_loop(
-        0, nblk - 1, functools.partial(block, full=True),
-        _softmax_start(n_head, v_width) + (parity[0],))
-    _, l, acc, buf = block(nblk - 1, carry, full=False)
-    parity[0] = buf
+    # the full blocks by what lies two on — a full block of this slot (the
+    # sixteen descriptors in the products' own basic block), then its
+    # last block, then the next slot's first: a trip each of the last two
+    # where the slot has the block
+    carry = _softmax_start(n_head, v_width) + (at[0],)
+    for first, end, ahead in (
+            (0, nblk - 3, two_on(True)),
+            (jnp.maximum(nblk - 3, 0), nblk - 2, two_on(False)),
+            (jnp.maximum(nblk - 2, 0), nblk - 1, next_slots)):
+        carry = lax.fori_loop(
+            first, end, functools.partial(block, full=True, ahead=ahead),
+            carry)
+    _, l, acc, buf = block(nblk - 1, carry, full=False,
+                           ahead=lambda i, buf: start_next(buf))
+    at[0] = buf
     o_ref[0] = (acc / l).astype(o_ref.dtype)
 
 
@@ -548,9 +603,9 @@ def latent_paged_attention(q, cache, pos, table, *, n_head, v_width, scale,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((1, n_head, int(v_width)),
                                    lambda i, *_: (i, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((2, rows, d), cache.dtype),
-                            pltpu.SemaphoreType.DMA((2,)),
-                            pltpu.SMEM((1,), jnp.int32)]),
+            scratch_shapes=[pltpu.VMEM((3, rows, d), cache.dtype),
+                            pltpu.SemaphoreType.DMA((3,)),
+                            pltpu.SMEM((3,), jnp.int32)]),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('arbitrary',)),
         name='kv_block_latent_paged_attention',
