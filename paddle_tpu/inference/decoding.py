@@ -27,11 +27,17 @@ vLLM-style preallocated, block-paged KV cache):
    in steady state, and zero compiles at all in a warm fresh process
    (AOT sidecars per program, `tools/cache_ctl.py prewarm`).
 2. **Iteration-level scheduling** — new requests join the running batch
-   at step boundaries (one prefill slice a tick, interleaved with the
-   running batch's steps — the largest bucket's slices that several
-   admitting requests have due in one tick in ONE dispatch of the row
-   program — then their slot decodes with everyone else);
-   finished sequences (eos / max_new_tokens) free
+   at step boundaries: a prompt admits a prefill slice a tick,
+   interleaved with the running batch's steps, then its slot decodes
+   with everyone else. While any row decodes, a tick's slices — oldest
+   admission first — add up by bucket size to no more than ONE call of
+   the largest chunk program holds (rows x largest chunk prompt tokens,
+   read from the artifact): a tick is step + one such call however
+   many admit at once. A slice that finds no room is DELAYED a tick,
+   never reshaped to fill a remainder, so a request's slices are the
+   buckets it takes alone (`_prefill_tick`; `stats.slices_deferred`);
+   the largest bucket's slices that go in one tick share ONE dispatch
+   of the row program. Finished sequences (eos / max_new_tokens) free
    their slot immediately for the next waiting request. The scheduler
    runs ONE STEP AHEAD of its reads: a tick dispatches the next step
    before it reads what the tick before dispatched (its step, and the
@@ -282,7 +288,9 @@ class DecodeStats(object):
     # seconds python's collector ran on ANY thread (it holds the GIL);
     # `dispatches` CALLS: steps + verify ticks + chunk-program calls
     # (stats.chunk_dispatches: one for all the slices a row program took);
-    # `rows` tokens emitted. The CPU clock of a thread is a system call
+    # `rows` tokens emitted; `slices` the prefill slices the tick
+    # dispatched and `deferred` the due ones its budget left for the next
+    # (_prefill_tick). The CPU clock of a thread is a system call
     # (0.3 us on a plain kernel; on a sandboxed one 6 us alone, tens
     # beside busy threads, and the clock moves in steps of 10 ms), so
     # it is READ at the end of a tick only where CPU_EVERY_S have passed
@@ -295,7 +303,7 @@ class DecodeStats(object):
     # `wait_s` the share it was neither running nor waiting for the device
     TICK_ROW = np.dtype([(k, np.float64) for k in (
         't0', 'wall_s', 'cpu_s', 'wait_s', 'gc_s', 'dispatches', 'rows',
-        'cpu_wall_s', 'tick')])
+        'cpu_wall_s', 'tick', 'slices', 'deferred')])
     TICK_RING = 1 << 16
     CPU_EVERY_S = 0.02
 
@@ -366,6 +374,11 @@ class DecodeStats(object):
         # requests have due in one tick ride ONE call of the row program
         # where the artifact has one, so chunk_dispatches <= chunk_slices
         self.chunk_dispatches = 0
+        # due slices that WAITED a tick: one count per slice per tick in
+        # which the tick's prefill budget (_prefill_tick) had no room
+        # for it. slices_deferred / (slices_deferred + chunk_slices) is
+        # the share of (slice, tick) pairs that ended in a wait
+        self.slices_deferred = 0
         # slices whose result the host read: the prompts' last ones.
         # 1 - slice_reads / chunk_slices of the slices cost no wait
         self.slice_reads = 0
@@ -422,6 +435,7 @@ class DecodeStats(object):
             self.blockcopies = 0
             self.chunk_slices = 0
             self.chunk_dispatches = 0
+            self.slices_deferred = 0
             self.slice_reads = 0
             self.steps_ahead = 0
             self.wasted_rows = 0
@@ -445,7 +459,8 @@ class DecodeStats(object):
                 # report pre-reset prefix hits / peaks
                 self.block_reset()
 
-    def log_tick(self, tick, t0, wall_s, wait_s, gc_s, dispatches, rows):
+    def log_tick(self, tick, t0, wall_s, wait_s, gc_s, dispatches, rows,
+                 slices=0, deferred=0):
         """One tick the scheduler was busy in, logged from its thread at
         the tick's end: its wall time into busy_s and its TICK_ROW into
         the ring, under the one hold of the lock the tick has always
@@ -461,7 +476,8 @@ class DecodeStats(object):
         with self._lock:
             self.busy_s += wall_s
             self._ticks[self._n_ticks & (self.TICK_RING - 1)] = (
-                t0, wall_s, cpu, wait_s, gc_s, dispatches, rows, span, tick)
+                t0, wall_s, cpu, wait_s, gc_s, dispatches, rows, span, tick,
+                slices, deferred)
             self._n_ticks += 1
 
     def _last_rows(self, last):
@@ -546,6 +562,7 @@ class DecodeStats(object):
                     'blockcopies': int(self.blockcopies),
                     'chunk_slices': int(self.chunk_slices),
                     'chunk_dispatches': int(self.chunk_dispatches),
+                    'slices_deferred': int(self.slices_deferred),
                     'slice_reads': int(self.slice_reads),
                     'steps_ahead': int(self.steps_ahead),
                     'wasted_rows': int(self.wasted_rows),
@@ -1911,10 +1928,11 @@ class DecodingPredictor(object):
         wait is nothing where the host is the slower side, and where the
         device is, the wait is the pipeline — step k+1 is queued behind
         the step k waited for. Then the waiting requests admit — into
-        the slots that read has just freed — and one prefill slice per
-        admitting request is dispatched (_prefill_tick: the slices of
-        several requests in ONE call where the artifact has a row
-        program); the donated
+        the slots that read has just freed — and the admitting requests'
+        next prefill slices are dispatched, oldest first, as many as one
+        call of the largest chunk program holds while any row decodes
+        (_prefill_tick has the budget; the slices of several requests
+        in ONE call where the artifact has a row program); the donated
         state threads step, slices, step, ... in order. A tick is
         max(host work, device work).
 
@@ -1941,6 +1959,7 @@ class DecodingPredictor(object):
         wait0, gc0 = self._wait_s, _serve.gc_seconds()
         made0 = stats.steps + stats.verify_steps + stats.chunk_dispatches
         rows0 = stats.tokens
+        slices0, deferred0 = stats.chunk_slices, stats.slices_deferred
         busy = self._unread is not None
         with _span('decode/expire'):
             if self._draining:
@@ -1976,7 +1995,9 @@ class DecodingPredictor(object):
                 self._tick, t0, time.perf_counter() - t0,
                 self._wait_s - wait0, _serve.gc_seconds() - gc0,
                 stats.steps + stats.verify_steps + stats.chunk_dispatches
-                - made0, stats.tokens - rows0)
+                - made0, stats.tokens - rows0,
+                stats.chunk_slices - slices0,
+                stats.slices_deferred - deferred0)
 
     def _results_first(self):
         """Whether the host must see what a tick dispatched before it can
@@ -2206,20 +2227,45 @@ class DecodingPredictor(object):
         return admitted
 
     def _prefill_tick(self):
-        """One chunked-prefill slice per ADMITTING request, dispatched
-        and not waited for: the uncovered prompt span (a prefix hit
-        skips the covered span's compute AND storage) admits in
-        fixed-size slices, one per scheduler iteration, interleaved with
-        the running batch's decode steps — a max-length prompt never
-        stalls every stream's inter-token latency for its whole prefill.
+        """At most ONE LARGEST CHUNK CALL'S WORTH of prefill a tick,
+        dispatched and not waited for: the uncovered prompt span (a
+        prefix hit skips the covered span's compute AND storage) admits
+        in fixed-size slices, at most one of a request per scheduler
+        iteration, interleaved with the running batch's decode steps —
+        a max-length prompt never stalls every stream's inter-token
+        latency for its whole prefill, and neither do several prompts
+        admitted at once.
 
-        COLLECT, THEN DISPATCH. Each slice due — in slot order, under
-        its own 'decode/prefill_slice' span — is its request's next
-        `take` tokens, in the bucket it takes alone. Which route follows
-        from what the scheduler sees. A slice RIDES THE ROW PROGRAM
-        where the artifact has one, the request is greedy, the slice's
-        own bucket is the largest — the row program's — and another
-        such slice is due: groups of up to R rows, one call a group, the
+        COLLECT, THEN DISPATCH. Each slice due — in ADMISSION order
+        (req.seq; _active_requests() is in slot order, which is not
+        it), under its own 'decode/prefill_slice' span — is its
+        request's next `take` tokens, in the bucket it takes alone.
+
+        THE BUDGET (_prefill_budget: one call of the largest chunk
+        program while any row decodes, else none). The slices that go
+        are those whose BUCKET SIZES (the program's rows, not `take`)
+        fit it, oldest first: a slice that does not fit what is left
+        waits for the next tick (stats.slices_deferred: one count per
+        slice per tick waited), and a later, smaller one that fits
+        goes. The oldest always fits — no bucket is larger than the
+        largest — so a tick with work due dispatches some and no
+        request starves: those ahead of it finish in finitely many
+        slices. A tick's device time is then step + one such call,
+        however many admit at once, where it was step + admitting
+        requests x slice. A slice is DELAYED, NEVER RESHAPED to fill a
+        remainder: a request's slices are the buckets it takes alone,
+        in order, whoever admits beside it, so what it is served does
+        not tell who did (the module's determinism contract). A request
+        that waits stays `prefilling`: it holds its slots and blocks,
+        expires, cancels and sheds as any admitting request, and its
+        window table and recurrent state are touched when its slice is
+        dispatched.
+
+        Which route a slice that goes takes follows from what the
+        scheduler sees. It RIDES THE ROW PROGRAM where the artifact has
+        one, the request is greedy, the slice's own bucket is the
+        largest — the row program's — and another such slice goes this
+        tick: groups of up to R rows, one call a group, the
         span holding the row's bookkeeping only (_write_row, _sliced)
         and the call following the group's last span. Every other slice
         takes its own bucket's one-row program, dispatched inside its
@@ -2244,14 +2290,24 @@ class DecodingPredictor(object):
         tokens of this tick's step are out."""
         R = self._rows      # 1 on an artifact without a row program
         largest = self._chunks[-1]
+        admitting = sorted((r for r in self._active_requests()
+                            if r.prefilling), key=lambda r: r.seq)
+        if not admitting:
+            return []
+        left = self._prefill_budget()
         due = []            # (request, bucket, take, last, rides)
-        for req in self._active_requests():
-            if req.prefilling:
-                remaining = int(req.prompt.size) - req.next_start
-                size = select_bucket(self._chunks, min(remaining, largest))
-                due.append((req, size, min(size, remaining),
-                            size >= remaining,
-                            R > 1 and size == largest and req.beam is None))
+        for req in admitting:
+            remaining = int(req.prompt.size) - req.next_start
+            size = select_bucket(self._chunks, min(remaining, largest))
+            if size > left:
+                continue    # waits for the next tick, its slice as it is
+            left -= size
+            due.append((req, size, min(size, remaining),
+                        size >= remaining,
+                        R > 1 and size == largest and req.beam is None))
+        if len(due) < len(admitting):
+            with self.stats._lock:
+                self.stats.slices_deferred += len(admitting) - len(due)
         rowed = sum(rides for *_, rides in due)
         rowed -= rowed % R == 1
         lasts, group, taken = [], [], 0
@@ -2274,6 +2330,19 @@ class DecodingPredictor(object):
                     lasts.append((read, rows))
                 group = []
         return lasts
+
+    def _prefill_budget(self):
+        """The prompt tokens, by bucket size, that the slices of one
+        tick may add up to. While any request holds a decoding row
+        (anyone not `prefilling`): rows x largest chunk, what ONE call
+        of the artifact's largest chunk program holds — read from the
+        artifact, nothing to set — because the bound is those streams'
+        inter-token gap. With none (the first tick of a ramp, an idle
+        replica hit by a burst) there is no gap to keep and deferring
+        would only add to time-to-first-token: no bound."""
+        if any(not r.prefilling for r in self._active_requests()):
+            return self._rows * self._chunks[-1]
+        return float('inf')
 
     def _prefill_slice(self, req, size, take, last):
         """Dispatch one slice of `req`'s prompt through the one-row

@@ -211,10 +211,11 @@ def serving_report():
         hdr += " %11s %11s %11s %6s" % ('tickp50(ms)', 'tickp99(ms)',
                                         'tickmax(ms)', 'offcpu')
         if blocks:
-            # prefill slices, and the chunk-program calls that carried
-            # them (fewer where slices rode the row program together)
-            hdr += " %11s %6s %6s %6s %6s" % ('blocks', 'pfxhit', 'cow',
-                                              'slices', 'calls')
+            # prefill slices, the chunk-program calls that carried them
+            # (fewer where slices rode the row program together) and the
+            # ticks a due slice waited for room in a tick's prefill budget
+            hdr += " %11s %6s %6s %6s %6s %6s" % (
+                'blocks', 'pfxhit', 'cow', 'slices', 'calls', 'waits')
             # bytes a cached position takes over all layers, and the
             # pools' bytes by kind (kv, latent, window, recurrent)
             hdr += " %7s %s" % ('row(B)', 'pools(MB)')
@@ -237,20 +238,20 @@ def serving_report():
                 s.get('tick_max_ms', 0.0), s.get('tick_offcpu_share', 0.0))
             if blocks:
                 if 'blocks_in_use' in s:
-                    row += " %11s %6.2f %6d %6d %6d" % (
+                    row += " %11s %6.2f %6d %6d %6d %6d" % (
                         '%d/%d' % (s['blocks_in_use'],
                                    s.get('blocks_total', 0)),
                         s.get('prefix_hit_rate', 0.0),
                         s.get('cow_blocks', 0),
                         s.get('chunk_slices', 0),
-                        s.get('chunk_dispatches', 0))
+                        s.get('chunk_dispatches', 0),
+                        s.get('slices_deferred', 0))
                     row += " %7d %s" % (
                         s.get('cache_row_bytes', 0),
                         ' '.join('%s:%.0f' % (k, v / 1e6) for k, v in
                                  sorted(s.get('pool_bytes', {}).items())))
                 else:
-                    row += " %11s %6s %6s %6s %6s" % ('-', '-', '-', '-',
-                                                      '-')
+                    row += " %11s %6s %6s %6s %6s %6s" % (('-',) * 6)
             print(row)
     return out
 

@@ -4,7 +4,15 @@ live rows between ticks and re-writes a row only at an event of that row;
 `watch_feed(pred)` rebuilds all of them from the request objects alone —
 the loop over every live row that the scheduler ran each tick before —
 behind every `_step_feed`, and requires the kept ones to equal the
-rebuilt ones, idle rows included."""
+rebuilt ones, idle rows included.
+
+And of its per-tick PREFILL BUDGET (ISSUE 52): `watch_slices(pred)` lists,
+for every tick that had a slice due, what was due and what went;
+`fits_first` is the rule they are held to, `alone_slices` the slices a
+prompt takes with nobody beside it. `gated(pred)` holds the scheduler so
+that a test decides what one tick finds waiting."""
+import threading
+
 import numpy as np
 
 
@@ -81,3 +89,79 @@ def watch_feed(pred):
         return out
     pred._step_feed = checked
     return watch
+
+
+def alone_slices(chunks, plen, covered=0):
+    """[(bucket, start)] of the prefill slices a prompt of `plen` tokens
+    takes served alone: the smallest bucket that holds what remains, the
+    largest while more remains than it holds."""
+    out, at = [], covered
+    while at < plen:
+        size = next((c for c in chunks if c >= plen - at), chunks[-1])
+        out.append((size, at))
+        at += size
+    return out
+
+
+def fits_first(due, budget):
+    """The entries of `due` — (request, bucket, start), oldest admission
+    first — that go in one tick of `budget` prompt tokens: each in turn
+    where its bucket fits what is left, so the oldest always, and a
+    smaller one behind one that did not fit."""
+    went, left = [], budget
+    for entry in due:
+        if entry[1] <= left:
+            went.append(entry)
+            left -= entry[1]
+    return went
+
+
+def watch_slices(pred):
+    """Behind every _prefill_tick with a slice due (on the scheduler's
+    thread): {'decoding': whether any request held a decoding row, 'due':
+    [(request seq, the bucket it takes alone, start)] oldest admission
+    first, 'went': the same of every slice dispatched, in dispatch order}.
+    Returns the list it appends to."""
+    ticks = []
+    prefill_tick = pred._prefill_tick
+    prefill_slice, write_row = pred._prefill_slice, pred._write_row
+
+    def tick():
+        active = pred._active_requests()
+        due = [(r.seq,) + alone_slices(pred._chunks, int(r.prompt.size),
+                                       r.next_start)[0]
+               for r in sorted(active, key=lambda r: r.seq) if r.prefilling]
+        if due:
+            ticks.append({'decoding': any(not r.prefilling for r in active),
+                          'due': due, 'went': []})
+        return prefill_tick()
+
+    def one_row(req, size, take, last):
+        ticks[-1]['went'].append((req.seq, size, req.next_start))
+        return prefill_slice(req, size, take, last)
+
+    def a_row(k, req, take, last):
+        ticks[-1]['went'].append((req.seq, pred._chunks[-1],
+                                  req.next_start))
+        return write_row(k, req, take, last)
+    pred._prefill_tick = tick
+    pred._prefill_slice, pred._write_row = one_row, a_row
+    return ticks
+
+
+def gated(pred, before=None):
+    """Run `before(pred)` on the scheduler's own thread in front of
+    every tick, and hold the FIRST tick until the test has queued
+    its whole batch and set the gate this returns: tick 1 then admits
+    what it had drained before it was held (the first request alone, or
+    nothing beside a running batch) and tick 2 finds every other one
+    waiting — admissions staggered, and the same in every run."""
+    run_tick, gate = pred._run_tick, threading.Event()
+
+    def tick(waiting):
+        assert gate.wait(60)
+        if before is not None:
+            before(pred)
+        run_tick(waiting)
+    pred._run_tick = tick
+    return gate

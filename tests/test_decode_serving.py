@@ -17,7 +17,8 @@ from paddle_tpu.inference import (DecodingPredictor, export_decode,
                                   ServerOverloaded, DeadlineExceeded)
 from paddle_tpu.inference.decoding import TokenStream
 
-from decode_feed_check import watch_feed
+from decode_feed_check import (alone_slices, fits_first, watch_feed,
+                               watch_slices, gated as _gated)
 
 VOCAB, SLOTS, CACHE, CHUNKS, BLOCK = 37, 4, 64, (4, 8), 4
 
@@ -350,24 +351,6 @@ def test_warm_fresh_subprocess_zero_compiles(artifact):
 
 # -- one step ahead (ISSUE 31): a tick reads what the tick before dispatched --
 
-def _gated(pred, before=None):
-    """Run `before(pred)` on the scheduler's own thread in front of
-    every tick, and hold the FIRST tick until the test has queued
-    its whole batch: tick 1 then admits the first request alone and tick
-    2 finds every other one waiting — admissions staggered, and the same
-    in every run."""
-    import threading
-    run_tick, gate = pred._run_tick, threading.Event()
-
-    def tick(waiting):
-        assert gate.wait(60)
-        if before is not None:
-            before(pred)
-        run_tick(waiting)
-    pred._run_tick = tick
-    return gate
-
-
 def _mixed_batch(seed=31):
     """Seven prompts over 4 slots: one of 19 tokens (three slices of the
     (4, 8) chunks), short ones beside it, three more than there are
@@ -693,13 +676,15 @@ def test_slices_due_together_ride_one_call_and_change_no_token(wide, n):
 
 
 def test_a_mixed_tick_of_rows(wide):
-    """One tick with seven slices due: six of the largest bucket —
-    greedy rows, a row admitted on a prefix hit (start > 0), a row whose
-    request is cancelled between its dispatch and its read (the read
-    drops it: _holds) — in two calls of the row program, four rows and
-    two, and one of the small bucket in a call of its own program. Every
-    other transcript is the solo one, every feed equals its rebuild,
-    every block comes back."""
+    """One tick with seven slices due beside a decoding row: six of the
+    largest bucket — greedy rows, a row admitted on a prefix hit (start
+    > 0), a row whose request is cancelled between its dispatch and its
+    read (the read drops it: _holds) — and one of the small bucket. The
+    tick's budget is one call of the row program (4 x 8 tokens): the four
+    oldest go in it, the other three wait a tick and then ride with the
+    13-token prompt's second slice, three rows in the row program and the
+    small bucket's in a call of its own. Every other transcript is the
+    solo one, every feed equals its rebuild, every block comes back."""
     rng = np.random.RandomState(43)
     shared = rng.randint(2, VOCAB, 8)                   # two full pages
     hit = np.concatenate([shared[:4], rng.randint(2, VOCAB, 5)])
@@ -742,13 +727,15 @@ def test_a_mixed_tick_of_rows(wide):
         assert watch.steps > 0 and _pool_is_empty_of(pred, 8)
     assert [g for k, g in enumerate(got) if k != 3] \
         == [s for k, s in enumerate(solo) if k != 3]
-    # six slices of the largest bucket due in one tick: four rows, then
-    # two; the hit's row starts behind its shared page. The 3-token
-    # prompt's slice, the first request's and the 13-token prompt's
-    # second (alone in its tick) are calls of their own
-    assert calls == [(4, [0, 0, 0, 0]), (2, [0, 4])]
+    # six slices of the largest bucket due in one tick: four rows go,
+    # the budget is spent; a tick later the 13-token prompt's second
+    # slice (the oldest), the 8-token prompt's and the hit's, whose row
+    # starts behind its shared page. The 3-token prompt's slice and the
+    # first request's are calls of their own
+    assert calls == [(4, [0, 0, 0, 0]), (3, [8, 0, 4])]
     assert snap['prefix_hits'] == 1
-    assert snap['chunk_slices'] == 9 and snap['chunk_dispatches'] == 5
+    assert snap['chunk_slices'] == 9 and snap['chunk_dispatches'] == 4
+    assert snap['slices_deferred'] == 3
     assert snap['steps_ahead'] == snap['steps']     # no beam: a tick late
     # the cancelled row was dispatched and never read
     assert snap['slice_reads'] == 7
@@ -761,8 +748,10 @@ def _pool_is_empty_of(pred, slots):
 
 
 def test_a_beam_among_greedy_rows_keeps_the_one_row_program(wide):
-    """A beam admitted in one tick with four greedy prompts: the greedy
-    slices are one call of the row program, read once and ids only; the
+    """A beam admitted in one tick with four greedy prompts: the tick's
+    budget holds four slices — the beam's and the three greedy ones
+    ahead of the last, which waits a tick. The greedy slices are one call
+    of the row program, read once and ids only; the
     beam's slice is a call of its own bucket's one-row program, its
     [1, V] logits row copied — hypotheses AND scores as served alone, to
     the bit; the greedy transcripts the solo ones."""
@@ -794,14 +783,15 @@ def test_a_beam_among_greedy_rows_keeps_the_one_row_program(wide):
         assert watch.steps > 0 and _pool_is_empty_of(pred, 8)
     np.testing.assert_array_equal(got_ids, ids)
     np.testing.assert_array_equal(got_scores, scores)
-    # five slices due in one tick: the four greedy ones are one call of
-    # the row program, read without its logits; the beam's is its own,
-    # and the only chunk call whose logits anybody reads
+    # five slices due in one tick, four go: the three greedy ones are one
+    # call of the row program, read without its logits; the beam's is its
+    # own, and the only chunk call whose logits anybody reads
     chunk_reads = [r for r in reads if r[0].startswith('chunk')]
     assert ('chunk_8x4', None) in chunk_reads
     assert [r for r in chunk_reads if r[1] is not None] \
         == [('chunk_8', (1, VOCAB))]
-    assert snap['chunk_dispatches'] <= snap['chunk_slices'] - 3
+    assert snap['chunk_dispatches'] <= snap['chunk_slices'] - 2
+    assert snap['slices_deferred'] >= 1
 
 
 def test_an_artifact_without_a_row_program_serves_as_it_did(rowless, wide):
@@ -854,12 +844,12 @@ def test_warmup_runs_the_row_program_on_pad_rows(wide):
         assert pred.generate(_row_prompts(1)[0], max_new_tokens=4)
 
 
-def test_a_window_artifact_never_batches(tmp_path):
-    """Window layers (and grouped heads) keep every chunk op on the body
-    that has no rows: the spec holds no row program, and several
-    admissions in one tick are a call each."""
+@pytest.fixture(scope='module')
+def windowed(tmp_path_factory):
+    """A toy artifact with sliding-window layers (grouped heads, a second
+    table): four slots, chunks (8, 16), no row program."""
     from models.exaone_moe import build_decode_spec
-    art = str(tmp_path / 'window')
+    art = str(tmp_path_factory.mktemp('decode_window') / 'art')
     scope = fluid.core.Scope()
     with fluid.scope_guard(scope), fluid.unique_name.guard():
         spec = build_decode_spec(n_layer=2, kv_cache_dtype='float32',
@@ -867,9 +857,16 @@ def test_a_window_artifact_never_batches(tmp_path):
         assert 'window' in spec and 'chunk_rows' not in spec
         fluid.Executor(fluid.CPUPlace()).run(spec['startup'], scope=scope)
         export_decode(spec, art, scope=scope, precompile=False)
+    return art
+
+
+def test_a_window_artifact_never_batches(windowed):
+    """Window layers (and grouped heads) keep every chunk op on the body
+    that has no rows: the spec holds no row program, and several
+    admissions in one tick are a call each."""
     rng = np.random.RandomState(5)
     prompts = [rng.randint(2, 120, k) for k in (5, 20, 9)]
-    with DecodingPredictor(art) as pred:
+    with DecodingPredictor(windowed) as pred:
         assert pred._row_mod is None
         solo = [pred.generate(p, max_new_tokens=4) for p in prompts]
         pred.stats.reset()
@@ -880,6 +877,220 @@ def test_a_window_artifact_never_batches(tmp_path):
             + [list(s.result(120)) for s in streams] == solo
         snap = pred.stats.snapshot()
     assert snap['chunk_dispatches'] == snap['chunk_slices'] == 4
+
+
+# -- a tick's prefill budget (ISSUE 52): one largest chunk call's worth --------
+
+# per artifact: (prompt lengths — the first decodes, the others admit in
+# ONE tick, each of two or three slices; its tokens; theirs; vocabulary)
+_BUDGET_CASES = {
+    # rows 1, chunks (4, 8): a tick holds 8 prompt tokens by bucket
+    'rowless': ((3, 19, 17, 23, 9, 20, 12, 18), 40, 6, VOCAB),
+    # rows 4: a tick holds 32, one call of the row program
+    'wide': ((3, 19, 17, 23, 9, 20, 12, 18), 40, 6, VOCAB),
+    # window layers, rows 1, chunks (8, 16): a tick holds 16
+    'windowed': ((5, 40, 33, 20), 60, 5, 120),
+}
+
+
+def _onto_a_decoding_batch(pred, prompts, first_new, max_new):
+    """prompts[0] decodes for `first_new` tokens — past every other
+    prompt's prefill — and the others are found waiting by ONE tick.
+    Returns (transcripts, watch_slices' ticks, the snapshot, the tick
+    log, watch_feed's count)."""
+    pred.block_manager.evict_all_prefixes()
+    pred.stats.reset()
+    feed, ticks = watch_feed(pred), watch_slices(pred)
+    first, head, gate = _held_behind(pred, prompts[0], first_new)
+    streams = [pred.submit(p, max_new_tokens=max_new) for p in prompts[1:]]
+    gate.set()
+    got = [[head] + list(first)] + [list(s.result(120)) for s in streams]
+    assert pred.drain(60)
+    return got, ticks, pred.stats.snapshot(), pred.stats.tick_log(), feed
+
+
+@pytest.fixture(scope='module', params=sorted(_BUDGET_CASES))
+def budgeted(request):
+    """Several prompts of several slices admitted at once onto a decoding
+    batch, on an artifact without a row program, with one, and with
+    window layers: served alone, together under the tick's budget, and
+    together in the parent's order (no budget: every due slice, every
+    tick)."""
+    lens, first_new, max_new, vocab = _BUDGET_CASES[request.param]
+    rng = np.random.RandomState(52)
+    prompts = [rng.randint(2, vocab, n) for n in lens]
+    news = [first_new] + [max_new] * (len(prompts) - 1)
+    out = {'prompts': prompts, 'news': news}
+    art = request.getfixturevalue(request.param)
+    with DecodingPredictor(art) as pred:
+        pred.stats.reset()
+        out['solo'] = [list(pred.generate(p, max_new_tokens=n))
+                       for p, n in zip(prompts, news)]
+        out['solo_snap'] = pred.stats.snapshot()
+        out['budget'] = pred._rows * pred._chunks[-1]
+        out['chunks'] = pred._chunks
+    with DecodingPredictor(art) as pred:
+        (out['got'], out['ticks'], out['snap'], out['log'],
+         out['feed']) = _onto_a_decoding_batch(pred, prompts, first_new,
+                                               max_new)
+        out['left_clean'] = _pool_is_empty_of(pred, pred.max_slots)
+    with DecodingPredictor(art) as pred:
+        pred._prefill_budget = lambda: float('inf')
+        out['parent'], out['parent_ticks'] = _onto_a_decoding_batch(
+            pred, prompts, first_new, max_new)[:2]
+    return out
+
+
+def _tokens(went):
+    return sum(bucket for _, bucket, _ in went)
+
+
+def test_a_ticks_slices_fit_one_call_of_the_largest_chunk(budgeted):
+    """While a row decodes, the bucket sizes of the slices a tick
+    dispatches add up to no more than rows x largest chunk — where the
+    parent's order, on the same traffic, dispatched more."""
+    bound = [t for t in budgeted['ticks'] if t['decoding']]
+    assert len(bound) > 3 and budgeted['feed'].steps > len(bound)
+    assert all(0 < _tokens(t['went']) <= budgeted['budget'] for t in bound)
+    assert any(len(t['went']) < len(t['due']) for t in bound)
+    assert max(_tokens(t['went']) for t in budgeted['parent_ticks']
+               if t['decoding']) > budgeted['budget']
+    assert all(t['went'] == t['due'] for t in budgeted['parent_ticks'])
+
+
+def test_slices_go_oldest_first_and_the_head_always_goes(budgeted):
+    """A tick's slices are chosen in admission order, each where its own
+    bucket fits what is left: the oldest always goes, one that does not
+    fit waits, a smaller one behind it may go."""
+    for t in budgeted['ticks']:
+        if t['decoding']:
+            assert t['went'] == fits_first(t['due'], budgeted['budget'])
+            assert t['went'][0] == t['due'][0]
+        seqs = [seq for seq, _, _ in t['due']]
+        assert seqs == sorted(seqs)
+
+
+def test_a_deferred_request_keeps_the_slices_it_takes_alone(budgeted):
+    """Delayed, never reshaped: every prompt finishes, in the buckets and
+    at the starts it has with nobody beside it."""
+    by_request = {}
+    for t in budgeted['ticks']:
+        for seq, bucket, start in t['went']:
+            by_request.setdefault(seq, []).append((bucket, start))
+    want = [alone_slices(budgeted['chunks'], len(p))
+            for p in budgeted['prompts']]
+    assert [by_request[seq] for seq in sorted(by_request)] == want
+    assert budgeted['snap']['chunk_slices'] == sum(len(w) for w in want)
+    assert budgeted['snap']['requests'] == len(want)
+    assert budgeted['left_clean']
+
+
+def test_transcripts_under_the_budget_are_the_solo_and_the_parents(budgeted):
+    """Bit-identical to each request served alone, and to all of them
+    served in the parent's order."""
+    assert budgeted['got'] == budgeted['solo'] == budgeted['parent']
+    assert [len(t) for t in budgeted['got']] == budgeted['news']
+
+
+def test_slices_deferred_counts_what_waited(budgeted):
+    """One count per due slice per tick it waited, in the counter and in
+    the tick log's column; 0 where one request admits at a time."""
+    waited = sum(len(t['due']) - len(t['went']) for t in budgeted['ticks'])
+    snap, log = budgeted['snap'], budgeted['log']
+    assert snap['slices_deferred'] == waited > 0
+    assert log['deferred'].sum() == waited
+    assert log['slices'].sum() == snap['chunk_slices']
+    assert np.all(log['deferred'][log['slices'] == 0] == 0)
+    assert budgeted['solo_snap']['slices_deferred'] == 0
+    assert budgeted['solo_snap']['chunk_slices'] == snap['chunk_slices']
+
+
+@pytest.mark.parametrize('which', sorted(_BUDGET_CASES))
+def test_with_no_decoding_row_every_due_slice_goes_in_one_tick(request,
+                                                               which):
+    """The first tick of a ramp: nobody decodes, there is no gap to keep,
+    and every admitted prompt's first slice goes — more than the budget
+    holds; once a row decodes the budget holds again."""
+    lens, _, max_new, vocab = _BUDGET_CASES[which]
+    rng = np.random.RandomState(53)
+    # a one-slice prompt among them: it decodes from the second tick on
+    prompts = [rng.randint(2, vocab, n) for n in (3,) + lens[1:]]
+    with DecodingPredictor(request.getfixturevalue(which)) as pred:
+        solo = [list(pred.generate(p, max_new_tokens=max_new))
+                for p in prompts]
+        pred.block_manager.evict_all_prefixes()
+        pred.stats.reset()
+        ticks, gate = watch_slices(pred), _gated(pred)
+        streams = [pred.submit(p, max_new_tokens=max_new) for p in prompts]
+        gate.set()
+        assert [list(s.result(120)) for s in streams] == solo
+        budget = pred._rows * pred._chunks[-1]
+        log = pred.stats.tick_log()
+    first = ticks[0]
+    assert not first['decoding'] and first['went'] == first['due']
+    assert len(first['went']) == len(prompts)
+    assert _tokens(first['went']) > budget
+    assert log['deferred'][log['slices'] > 0][0] == 0
+    assert ticks[1]['decoding'] and _tokens(ticks[1]['went']) <= budget
+    assert len(ticks[1]['went']) < len(ticks[1]['due'])
+
+
+@pytest.mark.parametrize('which', ['rowless', 'windowed'])
+@pytest.mark.parametrize('how', ['cancel', 'expire'])
+def test_a_deferred_request_that_ends_mid_prefill_gives_everything_back(
+        request, which, how):
+    """A request that has waited for room in a tick and is cancelled, or
+    passes its deadline, before its first slice: it fails as any
+    admitting request does, its slots and blocks (the window layers' too)
+    come back, and the requests around it are served what they are served
+    alone."""
+    lens, first_new, max_new, vocab = _BUDGET_CASES[which]
+    rng = np.random.RandomState(54)
+    prompts = [rng.randint(2, vocab, n) for n in lens[:4]]
+    ended = []
+    with DecodingPredictor(request.getfixturevalue(which)) as pred:
+        solo = [list(pred.generate(p, max_new_tokens=max_new))
+                for p in prompts[1:]]
+        pred.block_manager.evict_all_prefixes()
+        pred.stats.reset()
+        streams = []
+
+        def end_the_youngest(pred):
+            # on the scheduler's thread, in front of a tick: the last
+            # admitted request holds a slot, has waited, has no slice yet
+            req = next((r for r in pred._active_requests()
+                        if r.stream is streams[-1]), None) \
+                if streams else None
+            if ended or req is None or not pred.stats.slices_deferred:
+                return
+            assert req.prefilling and req.next_start == 0 and req.tables
+            ended.append(list(req.slots))
+            if how == 'cancel':
+                req.stream.cancel()
+            else:
+                req.deadline = time.perf_counter() - 1.0
+        first, _, gate = _held_behind(pred, prompts[0], first_new,
+                                      before=end_the_youngest)
+        streams += [pred.submit(p, max_new_tokens=max_new)
+                    for p in prompts[1:]]
+        gate.set()
+        assert [list(s.result(120)) for s in streams[:-1]] == solo[:-1]
+        with pytest.raises(RuntimeError if how == 'cancel'
+                           else DeadlineExceeded,
+                           match='cancelled' if how == 'cancel'
+                           else 'slot freed'):
+            streams[-1].result(120)
+        first.result(120)
+        assert pred.drain(60)
+        snap = pred.stats.snapshot()
+        assert _pool_is_empty_of(pred, pred.max_slots)
+        if pred._window:
+            assert pred.block_manager.stats()['window_blocks_in_use'] == 0
+    assert len(ended) == 1 and len(ended[0]) == 1
+    assert snap['expired'] == (how == 'expire')
+    # nothing of its prompt was ever dispatched
+    assert snap['chunk_slices'] == sum(
+        len(alone_slices(pred._chunks, len(p))) for p in prompts[:-1])
 
 
 # -- TokenStream: a delivery is one C call (queue.SimpleQueue) -----------------
@@ -1004,7 +1215,8 @@ def test_tick_log_has_one_row_a_busy_tick(ticked):
     assert len(log) == len(ticked['added']) > 3
     assert list(log.dtype.names) == ['t0', 'wall_s', 'cpu_s', 'wait_s',
                                      'gc_s', 'dispatches', 'rows',
-                                     'cpu_wall_s', 'tick']
+                                     'cpu_wall_s', 'tick', 'slices',
+                                     'deferred']
     # a row carries its tick's number, the 'tick' stat of its span: an
     # idle tick has a span and no row, so the numbers may skip
     assert np.all(np.diff(log['tick']) >= 1)
@@ -1027,6 +1239,8 @@ def test_tick_log_rows_add_up(ticked):
     assert log['dispatches'].sum() \
         == snap['steps'] + snap['chunk_dispatches']
     assert snap['chunk_dispatches'] < snap['chunk_slices']
+    assert log['slices'].sum() == snap['chunk_slices']
+    assert log['deferred'].sum() == snap['slices_deferred']
     assert log['rows'].sum() == snap['tokens'] \
         == sum(len(t) for t in ticked['tokens'])
     read = ~np.isnan(log['cpu_s'])

@@ -31,7 +31,8 @@ from benchmark.reference import qwen3_next as ref
 from models.qwen3_next import (FULL, LINEAR, build_decode_spec,
                                decay_log_range, layer_types)
 
-from decode_feed_check import watch_feed
+from decode_feed_check import (fits_first, gated, watch_feed,
+                               watch_slices)
 
 TOY = dict(vocab=128, d_model=64, n_head=4, n_kv_head=2, d_head=16,
            rotary_dim=4, n_layer=4, lin_key_heads=2, lin_value_heads=4,
@@ -384,6 +385,42 @@ def test_seven_slots_step_between_the_slices_of_an_eighth(served):
     # the 40-token prompt's three slices, one of them its first
     assert snap['state_resets'] == 8
     assert snap['state_rows_kept'] >= 2        # idle between its slices
+
+
+def test_slices_that_wait_for_a_ticks_budget_carry_their_slots_state(served):
+    """Three prompts of three, three and two slices admitted in ONE tick
+    beside a decoding row: a tick holds one largest chunk (16 tokens by
+    bucket), so slices wait — a slot's state is touched only by the slice
+    that is dispatched, and idles through the steps in between. Tokens
+    and the state each slot is left with are those of the request served
+    alone."""
+    art = served[0]
+    late = _prompts([40, 33, 21], seed=52)
+    want = [_solo(art, p, 8) for p in late]
+    with DecodingPredictor(art) as pred:
+        feed, ticks = watch_feed(pred), watch_slices(pred)
+        first = pred.submit(_prompts([5], seed=53)[0], max_new_tokens=60)
+        assert next(iter(first)) is not None        # it decodes
+        gate = gated(pred)
+        streams = [pred.submit(p, max_new_tokens=8) for p in late]
+        gate.set()
+        got = [list(s.result(120)) for s in streams]
+        first.result(120)
+        assert pred.drain(60)
+        rows = [_state_rows(pred, slot) for slot in (1, 2, 3)]
+        snap = pred.stats.snapshot()
+        budget = pred._rows * pred._chunks[-1]
+    assert budget == 16 and feed.steps > 8
+    assert got == [tokens for tokens, _ in want]
+    for have, (_, want_rows) in zip(rows, want):
+        _assert_rows_equal(have, want_rows)
+    bound = [t for t in ticks if t['decoding']]
+    assert all(t['went'] == fits_first(t['due'], budget) for t in bound)
+    waited = sum(len(t['due']) - len(t['went']) for t in ticks)
+    assert snap['slices_deferred'] == waited >= 8
+    # a state is born once a prompt, in the slice that starts it
+    assert snap['state_resets'] == 4 and snap['chunk_slices'] == 9
+    assert snap['state_rows_kept'] >= waited
 
 
 def test_a_slots_second_tenant_finds_nothing_of_the_first(served):
