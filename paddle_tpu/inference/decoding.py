@@ -128,6 +128,7 @@ _maybe_profiler = _serve._maybe_profiler
 # is running.
 _span = _serve.span
 _REQUEST_SEQ = itertools.count(1)   # process-wide: joins one request's spans
+_NAN = float('nan')
 select_bucket = _batching.select_bucket
 ServerOverloaded = _batching.ServerOverloaded
 DeadlineExceeded = _batching.DeadlineExceeded
@@ -255,6 +256,29 @@ def _percentiles(values, qs):
     return [round(float(p), 3) for p in np.percentile(arr, qs)]
 
 
+def _emit_gaps(ticks):
+    """(gaps in s, rows) over tick-log rows in time order: the `emit_t`
+    differences between ADJACENT ticks that both delivered a step's
+    tokens, and the rows the closing tick delivered to — the gap each of
+    those rows' streams saw. A busy tick without a delivery (nobody was
+    decoding a tick earlier) bounds no gap."""
+    t = ticks['emit_t']
+    both = ~(np.isnan(t[1:]) | np.isnan(t[:-1]))
+    return np.diff(t)[both], ticks['emit_rows'][1:][both]
+
+
+def _weighted_percentiles(values, weights, qs):
+    """_percentiles with each value counted `weights` times: the
+    smallest value at or under which that share of the weight lies."""
+    if not len(values) or not weights.sum():
+        return [0.0 for _ in qs]
+    order = np.argsort(values)
+    cum = np.cumsum(weights[order])
+    at = np.searchsorted(cum, np.asarray(qs) / 100.0 * cum[-1])
+    return [round(float(v) * 1e3, 3)
+            for v in values[order][np.minimum(at, len(cum) - 1)]]
+
+
 def _one_row(ids, logits, row=0):
     """(token, [V] logits or None) of row `row` of a chunk program's [R]
     ids and [R, V] logits (R = 1: a one-request program's)."""
@@ -278,7 +302,12 @@ class DecodeStats(object):
 
     The tick log (`tick_log()`) holds one row for every scheduler tick
     that `busy_s` counts, with tracing off: was the scheduler's thread
-    working or waiting in it, and for what."""
+    working or waiting in it, and for what; when it delivered its step's
+    tokens, and how much prefill it dispatched. The request log
+    (`request_log()`) holds one row for every request that ended, of the
+    same make: how long it queued, prefilled and waited for its first
+    token's read, its longest inter-token gap, and the ticks of the tick
+    log that admitted it and delivered its first token."""
 
     # one row a tick: `tick` its number (the 'tick' stat of its
     # 'decode/tick' span: the row and the span are joined on it); `t0`
@@ -303,14 +332,35 @@ class DecodeStats(object):
     # `wait_s` the share it was neither running nor waiting for the device
     TICK_ROW = np.dtype([(k, np.float64) for k in (
         't0', 'wall_s', 'cpu_s', 'wait_s', 'gc_s', 'dispatches', 'rows',
-        'cpu_wall_s', 'tick', 'slices', 'deferred')])
+        'cpu_wall_s', 'tick', 'slices', 'deferred', 'emit_t', 'emit_rows',
+        'wait_step_s', 'wait_slice_s', 'slice_tokens')])
     TICK_RING = 1 << 16
     CPU_EVERY_S = 0.02
+    # one row a request, written where it ends: `request` its sequence
+    # number (the 'request' stat of its spans: row and spans join on it);
+    # on time.perf_counter(), NaN where the request never got there:
+    # `t_submit`, `t_admit`, `t_last_slice` (the dispatch of its prompt's
+    # last slice), `t_first` (its first token delivered), `t_end`;
+    # `prompt_len`, `prefix_covered` (prompt tokens a prefix hit spared);
+    # `slices` its prompt took and `deferred`, the ticks its due slices
+    # waited under the prefill budget (its share of slices_deferred);
+    # `tokens` delivered (a beam: per hypothesis); `gap_max_s` its longest
+    # inter-token gap; `admit_tick` / `first_tick`, the `tick` of the
+    # tick log's rows that admitted it and delivered its first token (NaN
+    # where none did); `outcome`, one of DONE ... FAILED
+    REQUEST_ROW = np.dtype([(k, np.float64) for k in (
+        'request', 't_submit', 't_admit', 't_last_slice', 't_first',
+        't_end', 'prompt_len', 'prefix_covered', 'slices', 'deferred',
+        'tokens', 'gap_max_s', 'admit_tick', 'first_tick', 'outcome')])
+    REQUEST_RING = 1 << 14
+    DONE, CANCELLED, EXPIRED, SHED, FAILED = range(5)
 
     def __init__(self, window=8192):
         self._lock = threading.Lock()
         self._ticks = np.zeros(self.TICK_RING, self.TICK_ROW)
         self._n_ticks = 0        # ticks logged since reset()
+        self._requests = np.zeros(self.REQUEST_RING, self.REQUEST_ROW)
+        self._n_requests = 0     # requests logged since reset()
         # the scheduler's thread alone: its CPU clock where it was last
         # read, when that was, and the busy seconds logged since
         self._cpu_at = None
@@ -449,6 +499,7 @@ class DecodeStats(object):
             self.adv_tokens = 0
             self.adv_events = 0
             self._n_ticks = 0
+            self._n_requests = 0
             # the next reading of the CPU clock starts a new span of
             # busy time: none reaches back across a reset
             self._cpu_at = None
@@ -460,7 +511,8 @@ class DecodeStats(object):
                 self.block_reset()
 
     def log_tick(self, tick, t0, wall_s, wait_s, gc_s, dispatches, rows,
-                 slices=0, deferred=0):
+                 slices=0, deferred=0, emit_t=float('nan'), emit_rows=0,
+                 wait_slice_s=0.0, slice_tokens=0):
         """One tick the scheduler was busy in, logged from its thread at
         the tick's end: its wall time into busy_s and its TICK_ROW into
         the ring, under the one hold of the lock the tick has always
@@ -477,18 +529,33 @@ class DecodeStats(object):
             self.busy_s += wall_s
             self._ticks[self._n_ticks & (self.TICK_RING - 1)] = (
                 t0, wall_s, cpu, wait_s, gc_s, dispatches, rows, span, tick,
-                slices, deferred)
+                slices, deferred, emit_t, emit_rows, wait_s - wait_slice_s,
+                wait_slice_s, slice_tokens)
             self._n_ticks += 1
 
+    def log_request(self, row, **counts):
+        """One ended request's REQUEST_ROW into the ring, and the
+        counters its end moves (`counts`: field -> increment), under ONE
+        hold of the lock."""
+        with self._lock:
+            for field, n in counts.items():
+                setattr(self, field, getattr(self, field) + n)
+            self._requests[self._n_requests & (self.REQUEST_RING - 1)] = row
+            self._n_requests += 1
+
+    @staticmethod
+    def _tail(ring, n, last):
+        # with the lock held: VIEWS of the last `last` of the `n` rows
+        # logged into `ring` (a power of two long), in time order — two
+        # where they lie across the ring's seam
+        k = min(n, len(ring), last)
+        a = (n - k) & (len(ring) - 1)
+        if a + k <= len(ring):
+            return [ring[a:a + k]]
+        return [ring[a:], ring[:a + k - len(ring)]]
+
     def _last_rows(self, last):
-        # with the lock held: VIEWS of the last `last` logged rows in time
-        # order — two where they lie across the ring's seam
-        n, ring = self._n_ticks, self.TICK_RING
-        k = min(n, ring, last)
-        a = (n - k) & (ring - 1)
-        if a + k <= ring:
-            return [self._ticks[a:a + k]]
-        return [self._ticks[a:], self._ticks[:a + k - ring]]
+        return self._tail(self._ticks, self._n_ticks, last)
 
     def tick_log(self, since=None):
         """A copy of the logged ticks (TICK_ROW records, at most the
@@ -499,6 +566,17 @@ class DecodeStats(object):
         with self._lock:
             log = np.concatenate(self._last_rows(self.TICK_RING))
         return log if since is None else log[log['t0'] >= since]
+
+    def request_log(self, since=None):
+        """A copy of the request log (REQUEST_ROW records, at most the
+        last REQUEST_RING requests that ENDED, in the order they ended);
+        `since`: those submitted at or after that time.perf_counter()
+        instant. Copies the ring (2 MB once it is full) with the stats
+        lock held, as tick_log does."""
+        with self._lock:
+            log = np.concatenate(self._tail(
+                self._requests, self._n_requests, self.REQUEST_RING))
+        return log if since is None else log[log['t_submit'] >= since]
 
     def record_failure(self, request_id, kind):
         """One tagged request's shed/expiry: lands in the bounded
@@ -512,12 +590,30 @@ class DecodeStats(object):
 
     def snapshot(self):
         # the tick columns look at the last `window` ticks, as the
-        # latency percentiles do: a poller copies four columns of them
-        # under the lock (~20 us), never the ring
+        # latency percentiles do, and the first-token parts at the last
+        # `window` requests that ended: a poller copies six columns of
+        # the one and four of the other under the lock (0.15-0.3 ms), never
+        # a ring
         with self._lock:
             rows = self._last_rows(self._itl.maxlen)
             log = {k: np.concatenate([r[k] for r in rows])
-                   for k in ('wall_s', 'cpu_s', 'wait_s', 'cpu_wall_s')}
+                   for k in ('wall_s', 'cpu_s', 'wait_s', 'cpu_wall_s',
+                             'emit_t', 'emit_rows')}
+            rows = self._tail(self._requests, self._n_requests,
+                              self._itl.maxlen)
+            t_submit, t_admit, t_slice, t_first = (
+                np.concatenate([r[k] for r in rows])
+                for k in ('t_submit', 't_admit', 't_last_slice', 't_first'))
+        # a request's time to its first token, in the scheduler's three
+        # parts: queued, its prompt's slices, the read of the last one
+        got = ~np.isnan(t_first)
+        parts = {}
+        for name, a, b in (('queue', t_submit, t_admit),
+                           ('prefill', t_admit, t_slice),
+                           ('read', t_slice, t_first)):
+            parts['ttft_%s_p50_ms' % name], parts['ttft_%s_p99_ms' % name] \
+                = _percentiles((b - a)[got], [50, 99])
+        gap50, gap99 = _weighted_percentiles(*_emit_gaps(log), [50, 99])
         wall = log['wall_s']
         tick50, tick99, tick_max = _percentiles(wall, [50, 99, 100])
         read = float(np.nansum(log['cpu_wall_s']))
@@ -577,7 +673,14 @@ class DecodeStats(object):
                     # for the device (the GIL, a lock, the run queue)
                     'tick_p50_ms': tick50, 'tick_p99_ms': tick99,
                     'tick_max_ms': tick_max,
-                    'tick_offcpu_share': round(offcpu, 4)}
+                    'tick_offcpu_share': round(offcpu, 4),
+                    # the gap between two steps' deliveries, weighted by
+                    # the rows that saw it: what a decoding stream feels
+                    # of the tick (a tick's wait for a prompt's last
+                    # slice lies behind its deliveries: in the tick, in
+                    # no stream's gap)
+                    'emit_gap_p50_ms': gap50, 'emit_gap_p99_ms': gap99}
+            snap.update(parts)
             if self.block_source is None:    # not wired to a pool yet
                 return snap
         # outside the stats lock: the BlockManager takes its own
@@ -800,7 +903,8 @@ class _Request(object):
                  'tables', 'wtable', 'next_start', 'prefilling', 'shared',
                  'match',
                  'match_epoch', 'draft_strikes', 'draft_cooldown',
-                 'request_id', 'seq')
+                 'request_id', 'seq', 't_admit', 't_last_slice', 'covered',
+                 'slices', 'deferred', 'admit_tick', 'first_tick')
 
     def __init__(self, prompt, max_new, beam, stream, deadline_ms,
                  request_id=None):
@@ -840,6 +944,13 @@ class _Request(object):
         # speculative decoding (ISSUE 17): acceptance-aware backoff
         self.draft_strikes = 0            # consecutive all-rejected ticks
         self.draft_cooldown = 0           # plain ticks before re-drafting
+        # what the request log's row holds beside the fields above
+        # (DecodeStats.REQUEST_ROW), stamped where each thing happens
+        self.t_admit = self.t_last_slice = _NAN
+        self.admit_tick = self.first_tick = _NAN
+        self.covered = 0                  # prompt tokens a prefix hit spared
+        self.slices = 0                   # prefill slices dispatched
+        self.deferred = 0                 # ticks a due slice of it waited
 
 
 def _req_span(name, req, **stats):
@@ -849,12 +960,6 @@ def _req_span(name, req, **stats):
     if req.request_id is not None:
         stats['request_id'] = str(req.request_id)
     return _span(name, request=req.seq, **stats)
-
-
-def _fail(req, exc):
-    """Resolve a request's stream to an error, on the record."""
-    with _req_span('decode/finish', req, outcome=type(exc).__name__):
-        req.stream._fail(exc)
 
 
 class _DecodeModule(object):
@@ -1241,6 +1346,9 @@ class DecodingPredictor(object):
         # when each slot's stream last had a delivery (perf_counter): the
         # inter-token samples of a whole step come from one subtraction
         self._t_last = np.zeros(S, np.float64)
+        # and the longest such sample of the slot's tenant so far: the
+        # request log's `gap_max_s` (zeroed with its first token)
+        self._gap_max = np.zeros(S, np.float64)
         # requests whose rows the next step's feed re-writes from the
         # request itself, in the order they became decoding rows: every
         # request for its first step, and for every step those the
@@ -1252,9 +1360,18 @@ class DecodingPredictor(object):
         # step's read and rows or None, [(read, [(request, row)])] of
         # the chunk calls that held a prompt's last slice), or None
         self._unread = None
-        # seconds inside _to_host's block_until_ready, ever: the tick
-        # log's `wait_s` is its gain over a tick
-        self._wait_s = 0.0
+        # seconds inside _to_host's block_until_ready, ever, and those of
+        # them spent on a chunk program's read (a prompt's last slice):
+        # the tick log's `wait_s` / `wait_slice_s` are their gains over a
+        # tick, `wait_step_s` the rest
+        self._wait_s = self._wait_slice_s = 0.0
+        # the instant of the last step read's deliveries (_advance's
+        # `now`, a verify tick's), the rows step reads delivered to,
+        # ever, and the prompt tokens by bucket size of the slices
+        # dispatched, ever: the tick log's `emit_t`, and `emit_rows` and
+        # `slice_tokens` as gains over a tick
+        self._emit_t = _NAN
+        self._emit_rows = self._slice_tokens = 0
         self._closed = False
         self._draining = False
         self._idle_evt = threading.Event()
@@ -1650,7 +1767,10 @@ class DecodingPredictor(object):
         with _span('decode/device_wait', program=program):
             t0 = time.perf_counter()
             jax.block_until_ready(copied)
-            self._wait_s += time.perf_counter() - t0
+            waited = time.perf_counter() - t0
+            self._wait_s += waited
+            if program.startswith('chunk'):     # not 'step' or 'verify'
+                self._wait_slice_s += waited
         with _span('decode/d2h', program=program,
                    bytes=sum(int(f.nbytes) for f in copied),
                    fetch='logits' if logits else 'ids'):
@@ -1871,12 +1991,10 @@ class DecodingPredictor(object):
         one past the last position written) of `rows`, give back the
         window-layer blocks no live window reaches any more and add
         those the dispatch writes (BlockManager.window_advance)."""
-        with _span('decode/window_release', slots=len(rows)) as sp:
-            released = sum(
+        with _span('decode/window_release'):
+            for req, first, end in rows:
                 self._blocks.window_advance(
                     req.wtable, first - self._window + 1, end)
-                for req, first, end in rows)
-            sp.set_metadata(blocks=released)
 
     def _window_row(self, req):
         """One request's window-layer table row [1, max_blocks], trash
@@ -1957,6 +2075,8 @@ class DecodingPredictor(object):
         stats = self.stats
         t0 = time.perf_counter()
         wait0, gc0 = self._wait_s, _serve.gc_seconds()
+        wait_slice0, emit0 = self._wait_slice_s, self._emit_rows
+        tokens0 = self._slice_tokens
         made0 = stats.steps + stats.verify_steps + stats.chunk_dispatches
         rows0 = stats.tokens
         slices0, deferred0 = stats.chunk_slices, stats.slices_deferred
@@ -1978,8 +2098,8 @@ class DecodingPredictor(object):
             self._fail_all(e, waiting)
             step = None     # dispatched on the state that failed
         if not self._draining:
-            with _span('decode/admit') as sp:
-                sp.set_metadata(admitted=self._admit(waiting))
+            with _span('decode/admit'):
+                self._admit(waiting)
         busy = busy or any(s is not None for s in self._slots)
         try:
             lasts = self._prefill_tick()
@@ -1991,13 +2111,17 @@ class DecodingPredictor(object):
         except Exception as e:
             self._fail_all(e, waiting)
         if busy:
+            emit_rows = self._emit_rows - emit0
             stats.log_tick(
                 self._tick, t0, time.perf_counter() - t0,
                 self._wait_s - wait0, _serve.gc_seconds() - gc0,
                 stats.steps + stats.verify_steps + stats.chunk_dispatches
                 - made0, stats.tokens - rows0,
                 stats.chunk_slices - slices0,
-                stats.slices_deferred - deferred0)
+                stats.slices_deferred - deferred0,
+                self._emit_t if emit_rows else _NAN, emit_rows,
+                self._wait_slice_s - wait_slice0,
+                self._slice_tokens - tokens0)
 
     def _results_first(self):
         """Whether the host must see what a tick dispatched before it can
@@ -2029,15 +2153,12 @@ class DecodingPredictor(object):
         while waiting:
             req = waiting.popleft()
             self._drop_match(req)
-            with self.stats._lock:
-                self.stats.queue_depth -= 1
-                self.stats.shed += 1
-                self.stats.drained += 1
             self.stats.record_failure(req.request_id, 'drained')
-            _fail(req, ServerOverloaded(
+            self._fail(req, ServerOverloaded(
                 'request shed: endpoint draining for scale-in%s'
                 % (' (request %s)' % req.request_id
-                   if req.request_id else '')))
+                   if req.request_id else '')), DecodeStats.SHED,
+                queue_depth=-1, shed=1, drained=1)
 
     def _settle_quietly(self):
         """Before every in-flight request is failed (close, a dispatch
@@ -2054,21 +2175,17 @@ class DecodingPredictor(object):
         self._settle_quietly()
         for req in self._active_requests():
             self._release(req)
-            _fail(req, err)
+            self._fail(req, err, DecodeStats.FAILED)
         for req in waiting:
             self._drop_match(req)
-            with self.stats._lock:
-                self.stats.queue_depth -= 1
-            _fail(req, err)
+            self._fail(req, err, DecodeStats.FAILED, queue_depth=-1)
         while True:
             try:
                 req = self._queue.get_nowait()
             except queue.Empty:
                 return
             if req is not _STOP:
-                with self.stats._lock:
-                    self.stats.queue_depth -= 1
-                _fail(req, err)
+                self._fail(req, err, DecodeStats.FAILED, queue_depth=-1)
 
     def _expire(self, waiting):
         now = time.perf_counter()
@@ -2079,19 +2196,17 @@ class DecodingPredictor(object):
             if cancelled or (req.deadline is not None
                              and now > req.deadline):
                 self._drop_match(req)
-                with self.stats._lock:
-                    self.stats.queue_depth -= 1
-                    if not cancelled:
-                        self.stats.expired += 1
                 if cancelled:
-                    _fail(req, RuntimeError('request cancelled'))
+                    self._fail(req, RuntimeError('request cancelled'),
+                               DecodeStats.CANCELLED, queue_depth=-1)
                 else:
                     self.stats.record_failure(req.request_id, 'expired')
-                    _fail(req, DeadlineExceeded(
+                    self._fail(req, DeadlineExceeded(
                         'request expired after %.1f ms in queue%s'
                         % ((now - req.t_submit) * 1e3,
                            ' (request %s)' % req.request_id
-                           if req.request_id else '')))
+                           if req.request_id else '')),
+                        DecodeStats.EXPIRED, queue_depth=-1, expired=1)
             else:
                 alive.append(req)
         waiting.clear()
@@ -2103,25 +2218,28 @@ class DecodingPredictor(object):
                                          and now > req.deadline):
                 self._release(req)
                 if req.stream._cancelled:
-                    _fail(req, RuntimeError('request cancelled'))
+                    self._fail(req, RuntimeError('request cancelled'),
+                               DecodeStats.CANCELLED)
                 else:
-                    with self.stats._lock:
-                        self.stats.expired += 1
                     self.stats.record_failure(req.request_id, 'expired')
-                    _fail(req, DeadlineExceeded(
+                    self._fail(req, DeadlineExceeded(
                         'deadline elapsed mid-decode after %d token(s); '
                         'slot freed%s'
                         % (req.produced,
                            ' (request %s)' % req.request_id
-                           if req.request_id else '')))
+                           if req.request_id else '')),
+                        DecodeStats.EXPIRED, expired=1)
 
     def _admit_span(self, req, covered):
-        """The marker of one admission: how long the request queued."""
+        """The marker of one admission: how long the request queued. The
+        same clock reading, the tick and the prefix hit go into the
+        request's row of the request log."""
+        req.t_admit, req.admit_tick = time.perf_counter(), self._tick
+        req.covered = int(covered)
         return _span('decode/admit_request', request=req.seq,
-                     waited_us=int((time.perf_counter() - req.t_submit)
-                                   * 1e6),
+                     waited_us=int((req.t_admit - req.t_submit) * 1e6),
                      prompt_len=int(req.prompt.size),
-                     prefix_covered=int(covered))
+                     prefix_covered=req.covered)
 
     def _first_token(self, req, tok, logits):
         """Emit a request's first token: greedy, `tok` — the id its
@@ -2138,7 +2256,7 @@ class DecodingPredictor(object):
             self._record_emit(req, now)
             req.stream._push(tok)
             if tok == self._eos or req.produced >= req.max_new:
-                self._finish_greedy(req)
+                self._finish_greedy(req, now)
             return
         if len(req.slots) > 1:
             base = req.tables[0]
@@ -2154,7 +2272,7 @@ class DecodingPredictor(object):
         req.produced = 1
         self._record_emit(req, now, count=req.beam)
         if all(req.finished) or req.produced >= req.max_new:
-            self._finish_beam(req)
+            self._finish_beam(req, now)
 
     # -- admission, prefill slices, the step (ISSUE 13) --------------------
     def _admit(self, waiting):
@@ -2165,14 +2283,13 @@ class DecodingPredictor(object):
         skips allocating (and later prefilling) the covered span; the
         match is cached on the request across attempts, so its refs pin
         the matched blocks against eviction while the request waits at
-        the head of the queue. Returns how many it admitted."""
-        admitted = 0
+        the head of the queue."""
         while waiting:
             req = waiting[0]
             need = req.beam or 1
             free = self._free_slots()
             if len(free) < need:
-                return admitted
+                return
             plen = int(req.prompt.size)
             if req.match is None or (not req.match[0] and
                                      req.match_epoch
@@ -2195,18 +2312,15 @@ class DecodingPredictor(object):
                     self._blocks.blocks_for(plen) - len(shared))
             except BlockPoolExhausted:
                 if self._active_requests():
-                    return admitted  # head-of-line waits for free blocks
+                    return  # head-of-line waits for free blocks
                 # nothing running will ever free blocks: this prompt can
                 # never fit — shed loudly instead of deadlocking
                 waiting.popleft()
                 self._drop_match(req)
-                with self.stats._lock:
-                    self.stats.queue_depth -= 1
-                    self.stats.shed += 1
-                _fail(req, ServerOverloaded(
+                self._fail(req, ServerOverloaded(
                     'KV block pool exhausted: prompt of %d token(s) '
                     'needs more blocks than the pool can free'
-                    % plen))
+                    % plen), DecodeStats.SHED, queue_depth=-1, shed=1)
                 continue
             with self._admit_span(req, covered):
                 waiting.popleft()
@@ -2223,8 +2337,6 @@ class DecodingPredictor(object):
                 for i, s in enumerate(req.slots):
                     self._slots[s] = (req, i)
                 self._active = None
-            admitted += 1
-        return admitted
 
     def _prefill_tick(self):
         """At most ONE LARGEST CHUNK CALL'S WORTH of prefill a tick,
@@ -2300,6 +2412,7 @@ class DecodingPredictor(object):
             remaining = int(req.prompt.size) - req.next_start
             size = select_bucket(self._chunks, min(remaining, largest))
             if size > left:
+                req.deferred += 1
                 continue    # waits for the next tick, its slice as it is
             left -= size
             due.append((req, size, min(size, remaining),
@@ -2312,8 +2425,11 @@ class DecodingPredictor(object):
         rowed -= rowed % R == 1
         lasts, group, taken = [], [], 0
         for req, size, take, last, rides in due:
+            self._slice_tokens += size
             with _span('decode/prefill_slice', request=req.seq, size=size,
                        take=take, start=req.next_start, last=int(last)):
+                if last:
+                    req.t_last_slice = time.perf_counter()
                 if rides and taken < rowed:
                     self._write_row(len(group), req, take, last)
                     self._sliced(req, take, last)
@@ -2375,6 +2491,7 @@ class DecodingPredictor(object):
         request's first token from the device: the slice writes it into
         the request's slot of the ids row."""
         req.next_start += take
+        req.slices += 1
         if last:
             req.prefilling = False
             req.dispatched = 1
@@ -2571,12 +2688,10 @@ class DecodingPredictor(object):
                 return rows
             victim = max(victims, key=lambda r: r.t_submit)
             self._release(victim)
-            with self.stats._lock:
-                self.stats.shed += 1
-            _fail(victim, MidStreamEvicted(
+            self._fail(victim, MidStreamEvicted(
                 'evicted under KV block-pool pressure after %d '
                 'token(s): pool fully pinned by older requests'
-                % victim.produced))
+                % victim.produced), DecodeStats.SHED, shed=1)
 
     def _ensure_writable(self, req, bi, p, cow):
         """Make the block backing logical position p of beam `bi`
@@ -2662,8 +2777,14 @@ class DecodingPredictor(object):
         block)."""
         with _span('decode/step', active=len(rows)):
             ids, logits = self._to_host(read)
-            with _span('decode/advance', rows=len(rows)):
+            with _span('decode/advance') as sp:
+                before = self._emit_t
                 self._advance(ids, logits, rows)
+                if self._emit_t > before:   # not the first delivery ever
+                    # every decoding stream's gap, for a trace's reader
+                    # to pick the long ones by
+                    sp.set_metadata(
+                        gap_us=int((self._emit_t - before) * 1e6))
 
     def _step_feed(self, waiting, drafted):
         """The plain step's feed over the block pool, KEPT BETWEEN TICKS
@@ -2728,9 +2849,11 @@ class DecodingPredictor(object):
         counted (stats.wasted_rows): a row IS the slot table's tuple,
         so one identity test tells. Greedy rows cost one `tolist()`, one
         hold of the stats lock in which the whole step's inter-token
-        samples come from the kept last-delivery times, then per row an
-        append, the stream's put (one C call) and the finish check —
-        nothing else."""
+        samples come from the kept last-delivery times (and each slot's
+        longest so far, the request log's `gap_max_s`, from one maximum
+        over them), then per row an append, the stream's put (one C call)
+        and the finish check — nothing else. `now` is the tick log's
+        `emit_t`."""
         now = time.perf_counter()
         toks = ids.tolist()     # once a step, not once a row
         slots = self._slots
@@ -2755,8 +2878,12 @@ class DecodingPredictor(object):
             stats.tokens += n
             stats.adv_tokens += n
             stats.adv_events += n
-            stats._itl.extend((now - self._t_last[at]).tolist())
+            gaps = now - self._t_last[at]
+            stats._itl.extend(gaps.tolist())
+            self._gap_max[at] = np.maximum(self._gap_max[at], gaps)
             self._t_last[at] = now
+        if held:
+            self._emit_t, self._emit_rows = now, self._emit_rows + held
         eos = self._eos
         for req, s in zip(greedy, at):
             tok = toks[s]
@@ -2764,7 +2891,7 @@ class DecodingPredictor(object):
             req.produced += 1
             req.stream._push(tok)
             if tok == eos or req.produced >= req.max_new:
-                self._finish_greedy(req)
+                self._finish_greedy(req, now)
         for req in beams:
             # the history move is a table permutation on the host
             parents = self._score_beam(req, logits)
@@ -2781,7 +2908,7 @@ class DecodingPredictor(object):
             req.produced += 1
             self._record_emit(req, now, count=req.beam)
             if all(req.finished) or req.produced >= req.max_new:
-                self._finish_beam(req)
+                self._finish_beam(req, now)
 
     # -- speculative decoding (ISSUE 17) -----------------------------------
     def _collect_drafts(self):
@@ -2869,7 +2996,7 @@ class DecodingPredictor(object):
         self._record_emit(req, now, count=len(emitted), events=1)
         req.stream._push_many(emitted)
         if emitted[-1] == self._eos or req.produced >= req.max_new:
-            self._finish_greedy(req)
+            self._finish_greedy(req, now)
         return emitted
 
     def _verify(self, drafted, waiting):
@@ -2916,8 +3043,9 @@ class DecodingPredictor(object):
         for i in range(0, len(cow), self._S):
             self._dispatch_blockcopy(cow[i:i + self._S])
         ids, _ = self._to_host(self._dispatch_verify(tokens, pos, tables))
-        with _span('decode/advance', rows=len(rows)):
+        with _span('decode/advance'):
             now = time.perf_counter()
+            self._emit_t, self._emit_rows = now, self._emit_rows + len(rows)
             ids = ids.tolist()
             for req, bi, p, span in rows:
                 s = req.slots[0]
@@ -2973,24 +3101,44 @@ class DecodingPredictor(object):
                                   else events)
         s = req.slots[0]
         if req.t_first is None:
-            req.t_first = now
+            req.t_first, req.first_tick = now, self._tick
             self.stats._ttft.append(now - req.t_submit)
+            self._gap_max[s] = 0.0
         else:
-            self.stats._itl.append(now - float(self._t_last[s]))
+            gap = now - float(self._t_last[s])
+            self.stats._itl.append(gap)
+            self._gap_max[s] = max(self._gap_max[s], gap)
         self._t_last[s] = now
 
-    def _finish_greedy(self, req):
+    def _log_end(self, req, outcome, now, **counts):
+        """`req` has ended at `now`: its row of the request log, with the
+        counters its end moves, under one hold of the stats lock."""
+        first = req.t_first is not None
+        self.stats.log_request(
+            (req.seq, req.t_submit, req.t_admit, req.t_last_slice,
+             req.t_first if first else _NAN, now, req.prompt.size,
+             req.covered, req.slices, req.deferred, req.produced,
+             self._gap_max[req.slots[0]] if first else 0.0,
+             req.admit_tick, req.first_tick, outcome), **counts)
+
+    def _fail(self, req, exc, outcome, **counts):
+        """Resolve a request's stream to an error, on the record: the
+        'decode/finish' span, the request log's row (`outcome`: CANCELLED,
+        EXPIRED, SHED or FAILED) and the counters (`counts`)."""
+        with _req_span('decode/finish', req, outcome=type(exc).__name__):
+            self._log_end(req, outcome, time.perf_counter(), **counts)
+            req.stream._fail(exc)
+
+    def _finish_greedy(self, req, now):
         with _req_span('decode/finish', req, outcome='done'):
             self._release(req)
-            with self.stats._lock:
-                self.stats.requests += 1
+            self._log_end(req, DecodeStats.DONE, now, requests=1)
             req.stream._finish(list(req.tokens))
 
-    def _finish_beam(self, req):
+    def _finish_beam(self, req, now):
         with _req_span('decode/finish', req, outcome='done'):
             self._release(req)
-            with self.stats._lock:
-                self.stats.requests += 1
+            self._log_end(req, DecodeStats.DONE, now, requests=1)
             ids = np.asarray(req.hyps, np.int64)
             scores = np.asarray(req.scores, np.float64)
             req.stream._finish((ids, scores))
@@ -3006,7 +3154,7 @@ class DecodingPredictor(object):
         self._settle_quietly()
         for req in self._active_requests():
             self._release(req)
-            _fail(req, exc)
+            self._fail(req, exc, DecodeStats.FAILED)
         for req in waiting:
             # cached prefix matches hold block ids of the manager the
             # rebuild below discards: a stale HIT would map dead blocks

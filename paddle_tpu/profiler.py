@@ -166,7 +166,14 @@ def serving_report():
     log (`DecodeStats.tick_log`), its last 8,192 ticks: a scheduler
     tick's p50 / p99 / longest wall time and `offcpu`, the share of the
     ticks' time the scheduler's thread was neither on a CPU nor waiting
-    for the device."""
+    for the device; `gapp50` / `gapp99` the gap between two steps'
+    deliveries, counted once a row delivered to — what a decoding stream
+    feels of the tick, which a tick's wait for a prompt's last slice is
+    not in. The last six columns part the time to a first token over the
+    source's request log (`DecodeStats.request_log`), its last 8,192
+    requests that ended: queued (`queue`), from admission to the dispatch
+    of the prompt's last slice (`pfill`), from there to the token
+    delivered (`read`), p50 and p99 each."""
     out = {}
     rows = []
     decode_rows = []
@@ -210,6 +217,12 @@ def serving_report():
         # share of it its thread neither ran nor waited for the device
         hdr += " %11s %11s %11s %6s" % ('tickp50(ms)', 'tickp99(ms)',
                                         'tickmax(ms)', 'offcpu')
+        # what a decoding stream feels of the tick, and where a first
+        # token's time went: queue, prefill, the last slice's read
+        hdr += " %10s %10s" % ('gapp50(ms)', 'gapp99(ms)')
+        hdr += " %8s %8s %8s %8s %8s %8s" % (
+            'queuep50', 'queuep99', 'pfillp50', 'pfillp99', 'readp50',
+            'readp99')
         if blocks:
             # prefill slices, the chunk-program calls that carried them
             # (fewer where slices rode the row program together) and the
@@ -236,6 +249,11 @@ def serving_report():
             row += " %11.2f %11.2f %11.2f %6.2f" % (
                 s.get('tick_p50_ms', 0.0), s.get('tick_p99_ms', 0.0),
                 s.get('tick_max_ms', 0.0), s.get('tick_offcpu_share', 0.0))
+            row += " %10.2f %10.2f" % (s.get('emit_gap_p50_ms', 0.0),
+                                       s.get('emit_gap_p99_ms', 0.0))
+            row += " %8.2f %8.2f %8.2f %8.2f %8.2f %8.2f" % tuple(
+                s.get('ttft_%s_p%d_ms' % (part, q), 0.0)
+                for part in ('queue', 'prefill', 'read') for q in (50, 99))
             if blocks:
                 if 'blocks_in_use' in s:
                     row += " %11s %6.2f %6d %6d %6d %6d" % (

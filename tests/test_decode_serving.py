@@ -313,10 +313,13 @@ def test_serving_report_decode_rows(artifact, capsys):
         assert name, out
         snap = out[name[0]]
     for key in ('tokens', 'tokens_s', 'prefills', 'steps', 'occupancy',
-                'ttft_p50_ms', 'ttft_p99_ms', 'itl_p50_ms', 'itl_p99_ms'):
+                'ttft_p50_ms', 'ttft_p99_ms', 'itl_p50_ms', 'itl_p99_ms',
+                'emit_gap_p50_ms', 'emit_gap_p99_ms', 'ttft_queue_p50_ms',
+                'ttft_prefill_p99_ms', 'ttft_read_p99_ms'):
         assert key in snap
     text = capsys.readouterr().out
     assert 'Decode source' in text and 'ttftp99(ms)' in text
+    assert 'gapp99(ms)' in text and 'pfillp50' in text
 
 
 def test_warm_fresh_subprocess_zero_compiles(artifact):
@@ -933,6 +936,7 @@ def budgeted(request):
         (out['got'], out['ticks'], out['snap'], out['log'],
          out['feed']) = _onto_a_decoding_batch(pred, prompts, first_new,
                                                max_new)
+        out['requests'] = pred.stats.request_log()
         out['left_clean'] = _pool_is_empty_of(pred, pred.max_slots)
     with DecodingPredictor(art) as pred:
         pred._prefill_budget = lambda: float('inf')
@@ -1205,6 +1209,7 @@ def ticked(artifact):
         log, snap = pred.stats.tick_log(), pred.stats.snapshot()
         assert isinstance(pred.stats, DecodeStats)
     return {'log': log, 'snap': snap, 'added': added, 'tokens': tokens,
+            'requests': pred.stats.request_log(), 'prompts': _prompts(32, 6),
             'busy_s': pred.stats.busy_s, 'emptied': emptied,
             't_first': t_first, 'stats': pred.stats}
 
@@ -1216,7 +1221,9 @@ def test_tick_log_has_one_row_a_busy_tick(ticked):
     assert list(log.dtype.names) == ['t0', 'wall_s', 'cpu_s', 'wait_s',
                                      'gc_s', 'dispatches', 'rows',
                                      'cpu_wall_s', 'tick', 'slices',
-                                     'deferred']
+                                     'deferred', 'emit_t', 'emit_rows',
+                                     'wait_step_s', 'wait_slice_s',
+                                     'slice_tokens']
     # a row carries its tick's number, the 'tick' stat of its span: an
     # idle tick has a span and no row, so the numbers may skip
     assert np.all(np.diff(log['tick']) >= 1)
@@ -1235,6 +1242,11 @@ def test_tick_log_rows_add_up(ticked):
     log, snap = ticked['log'], ticked['snap']
     assert np.all(log['wait_s'] >= 0) and np.all(log['gc_s'] >= 0)
     assert np.all(log['wait_s'] <= log['wall_s'] + 1e-6)
+    # parted by what was waited for: the step's ids, a prompt's last slice
+    np.testing.assert_allclose(log['wait_step_s'] + log['wait_slice_s'],
+                               log['wait_s'], rtol=0, atol=1e-12)
+    assert np.all(log['wait_step_s'] >= 0) and np.all(log['wait_slice_s'] >= 0)
+    assert log['wait_slice_s'].sum() > 0 and log['wait_step_s'].sum() > 0
     # calls, not slices: six prompts at once ride the row program
     assert log['dispatches'].sum() \
         == snap['steps'] + snap['chunk_dispatches']
@@ -1259,6 +1271,40 @@ def test_tick_log_rows_add_up(ticked):
     # of this toy's 0.2 ms ticks may pass the busy seconds it spans)
     assert np.all(log['cpu_s'][read] >= 0)
     assert np.all(log['cpu_s'][read][1:] <= np.diff(ends) + 1e-4)
+
+
+def test_tick_log_says_when_a_step_delivered_and_to_how_many(ticked):
+    """`emit_t` is the instant a tick's step read stamped on its
+    deliveries, NaN where the tick read no step; `emit_rows` the rows it
+    delivered to: every token but the requests' first, which come from
+    their prompts' last slices and are in `rows` alone."""
+    log = ticked['log']
+    read = ~np.isnan(log['emit_t'])
+    assert np.array_equal(read, log['emit_rows'] > 0) and read.sum() > 3
+    assert np.all(log['emit_t'][read] >= log['t0'][read])
+    assert np.all(log['emit_t'][read] <= (log['t0'] + log['wall_s'])[read])
+    assert np.all(np.diff(log['emit_t'][read]) > 0)
+    firsts = len(ticked['tokens'])
+    assert log['emit_rows'].sum() == log['rows'].sum() - firsts \
+        == sum(len(t) - 1 for t in ticked['tokens'])
+    assert np.all(log['emit_rows'] <= log['rows'])
+    # a last slice is waited for in the tick that delivers its first token
+    assert np.all((log['rows'] - log['emit_rows'])[log['wait_slice_s'] > 0]
+                  >= 1)
+
+
+def test_tick_log_slice_tokens_are_the_buckets_dispatched(budgeted):
+    """`slice_tokens`: the prompt tokens, by BUCKET size, of the slices a
+    tick dispatched — what the tick's budget counts — against a run whose
+    slices are known tick by tick."""
+    log, ticks = budgeted['log'], budgeted['ticks']
+    went = [_tokens(t['went']) for t in ticks]
+    sliced = log[log['slices'] > 0]
+    assert sliced['slice_tokens'].tolist() == went
+    assert np.all(log['slice_tokens'][log['slices'] == 0] == 0)
+    assert log['slice_tokens'].sum() == sum(
+        bucket for p in budgeted['prompts']
+        for bucket, _ in alone_slices(budgeted['chunks'], len(p)))
 
 
 def test_the_cpu_clock_is_read_every_tick_where_it_is_always_due(artifact):
@@ -1351,6 +1397,282 @@ def test_reset_starts_the_cpu_clocks_readings_anew():
     stats.log_tick(4, 4.0, 1e-3, 0.0, 0.0, 1, 1)
     log = stats.tick_log()
     assert np.isnan(log['cpu_s'][0]) and log['cpu_wall_s'][1] == 1e-3
+
+
+# -- the request log: one row for every request that ended ---------------------
+
+_REQUEST_COLUMNS = ['request', 't_submit', 't_admit', 't_last_slice',
+                    't_first', 't_end', 'prompt_len', 'prefix_covered',
+                    'slices', 'deferred', 'tokens', 'gap_max_s',
+                    'admit_tick', 'first_tick', 'outcome']
+_TIMES = _REQUEST_COLUMNS[1:6]
+
+
+def test_request_log_has_one_row_a_request(ticked):
+    """Six requests served to their end: six rows, each with the request's
+    times in the order they happened, what its prompt took and what it
+    was delivered."""
+    from paddle_tpu.inference.decoding import DecodeStats
+    reqs, snap = ticked['requests'], ticked['snap']
+    assert list(reqs.dtype.names) == _REQUEST_COLUMNS
+    assert len(reqs) == len(ticked['tokens']) == snap['requests']
+    assert len(set(reqs['request'])) == len(reqs)
+    assert np.all(reqs['outcome'] == DecodeStats.DONE)
+    times = np.stack([reqs[k] for k in _TIMES])
+    assert not np.isnan(times).any() and np.all(np.diff(times, axis=0) >= 0)
+    assert reqs['t_submit'].min() >= ticked['t_first']
+    # the rows are in the order the requests ENDED; `request` is the order
+    # they were submitted in
+    assert np.all(np.diff(reqs['t_end']) >= 0)
+    by_seq = reqs[np.argsort(reqs['request'])]
+    assert by_seq['prompt_len'].tolist() == [len(p) for p in ticked['prompts']]
+    assert by_seq['tokens'].tolist() == [len(t) for t in ticked['tokens']]
+    assert np.all(reqs['prefix_covered'] == 0)
+    assert reqs['slices'].sum() == snap['chunk_slices']
+    assert reqs['deferred'].sum() == snap['slices_deferred']
+    assert by_seq['slices'].tolist() == [
+        len(alone_slices(CHUNKS, len(p))) for p in ticked['prompts']]
+    assert np.all(reqs['gap_max_s'] > 0)
+    assert np.all(reqs['gap_max_s'] <= reqs['t_end'] - reqs['t_first'] + 1e-9)
+
+
+def test_request_log_joins_the_tick_log_on_tick_numbers(ticked):
+    """`admit_tick` and `first_tick` are `tick`s of the tick log's rows:
+    the tick that admitted the request holds its `t_admit`, the one that
+    delivered its first token its `t_first` and counts it in `rows`."""
+    reqs, log = ticked['requests'], ticked['log']
+    rows = {int(r['tick']): r for r in log}
+    assert len(rows) == len(log)
+    for req in reqs:
+        admit, first = rows[int(req['admit_tick'])], \
+            rows[int(req['first_tick'])]
+        assert admit['t0'] <= req['t_admit'] <= admit['t0'] + admit['wall_s']
+        assert first['t0'] <= req['t_first'] <= first['t0'] + first['wall_s']
+        assert first['rows'] - first['emit_rows'] >= 1
+        assert first['wait_slice_s'] > 0
+        assert req['first_tick'] > req['admit_tick']
+        # its last slice went in the tick before the one that read it
+        assert req['t_last_slice'] < first['t0']
+
+
+def test_request_log_counts_each_requests_slices_and_waits(budgeted):
+    """Several prompts of several slices admitted at once: `slices` is
+    what each takes alone, `deferred` the ticks its due slices waited —
+    they add up to chunk_slices and slices_deferred."""
+    reqs, snap = budgeted['requests'], budgeted['snap']
+    by_seq = reqs[np.argsort(reqs['request'])]
+    assert by_seq['slices'].tolist() == [
+        len(alone_slices(budgeted['chunks'], len(p)))
+        for p in budgeted['prompts']]
+    assert reqs['slices'].sum() == snap['chunk_slices']
+    assert reqs['deferred'].sum() == snap['slices_deferred'] > 0
+    waited = {}
+    for t in budgeted['ticks']:
+        went = {seq for seq, _, _ in t['went']}
+        for seq, _, _ in t['due']:
+            waited[seq] = waited.get(seq, 0) + (seq not in went)
+    assert by_seq['deferred'].tolist() == [waited[seq] for seq in
+                                           sorted(waited)]
+    assert by_seq['tokens'].tolist() == budgeted['news']
+    # the oldest never waits; whoever waited got its first token later
+    assert by_seq['deferred'][0] == 0
+
+
+def _end_done(pred):
+    return [pred.submit(_prompts(61, 1)[0], max_new_tokens=3)], ['DONE']
+
+
+def _end_cancelled_waiting(pred):
+    gate = _gated(pred)
+    stream = pred.submit(_prompts(62, 1)[0], max_new_tokens=3)
+    stream.cancel()
+    gate.set()
+    return [stream], ['CANCELLED']
+
+
+def _end_cancelled_decoding(pred):
+    stream, head, gate = _held_behind(pred, _prompts(17, 1)[0], 57)
+    stream.cancel()
+    gate.set()
+    return [stream], ['CANCELLED']
+
+
+def _end_expired_waiting(pred):
+    return [pred.submit(_prompts(63, 1)[0], max_new_tokens=3,
+                        deadline_ms=0.0)], ['EXPIRED']
+
+
+def _end_expired_decoding(pred):
+    def past_due(pred):
+        for req in pred._active_requests():
+            if req.produced >= 3:
+                req.deadline = 0.0
+    _gated(pred, before=past_due).set()
+    return [pred.submit(_prompts(17, 1)[0], max_new_tokens=57,
+                        deadline_ms=3.6e6)], ['EXPIRED']
+
+
+def _end_drained(pred):
+    """One decoding, one found waiting by the tick that drains."""
+    import threading
+    first, head, gate = _held_behind(pred, _prompts(17, 1)[0], 6)
+    waiting = pred.submit(_prompts(64, 1)[0], max_new_tokens=3)
+    drain = threading.Thread(target=pred.drain, args=(60,))
+    drain.start()
+    while not pred._draining:
+        time.sleep(0.001)
+    gate.set()
+    drain.join(60)
+    assert not drain.is_alive()
+    return [first, waiting], ['DONE', 'SHED']
+
+
+def _end_failed(pred):
+    """_fail_all: the chunk programs break under two admitting requests."""
+    def boom(*args, **kw):
+        raise RuntimeError('chunk program broke')
+    for m in list(pred._chunk_mods.values()) + [pred._row_mod]:
+        m.call = boom
+    return [pred.submit(p, max_new_tokens=3)
+            for p in _prompts(65, 2)], ['FAILED', 'FAILED']
+
+
+def _end_closed(pred):
+    """close(): one decoding, one still queued behind a held scheduler."""
+    import threading
+    first, head, gate = _held_behind(pred, _prompts(17, 1)[0], 57)
+    queued = pred.submit(_prompts(66, 1)[0], max_new_tokens=3)
+    close = threading.Thread(target=pred.close)
+    close.start()
+    while not pred._closed:
+        time.sleep(0.001)
+    gate.set()
+    close.join(60)
+    assert not close.is_alive()
+    return [first, queued], ['FAILED', 'FAILED']
+
+
+@pytest.mark.parametrize('how', [
+    _end_done, _end_cancelled_waiting, _end_cancelled_decoding,
+    _end_expired_waiting, _end_expired_decoding, _end_drained, _end_failed,
+    _end_closed], ids=lambda f: f.__name__[5:])
+def test_request_log_has_one_row_whatever_the_end(artifact, how):
+    """Done, cancelled, expired, shed by a drain, failed by _fail_all or
+    by close(): exactly one row a request, its outcome named, its times
+    NaN from where it never got and in order up to there."""
+    from paddle_tpu.inference.decoding import DecodeStats
+    with DecodingPredictor(artifact) as pred:
+        streams, want = how(pred)
+        for s in streams:
+            s.exception(120)
+        assert all(s.done() for s in streams)
+    reqs = pred.stats.request_log()
+    assert len(reqs) == len(streams) == len(set(reqs['request']))
+    by_seq = reqs[np.argsort(reqs['request'])]
+    assert by_seq['outcome'].tolist() == [getattr(DecodeStats, w)
+                                          for w in want]
+    for req, stream in zip(by_seq, streams):
+        times = np.array([req[k] for k in _TIMES])
+        got = ~np.isnan(times)
+        assert got[0] and got[-1] and np.all(np.diff(times[got]) >= 0)
+        # never admitted: no slice, no token, no tick; admitted: a tick
+        if np.isnan(req['t_admit']):
+            assert np.isnan(req['admit_tick']) and req['slices'] == 0
+            assert np.isnan(req['t_last_slice'])
+        else:
+            assert req['admit_tick'] >= 1
+        assert np.isnan(req['t_first']) == np.isnan(req['first_tick']) \
+            == (req['tokens'] == 0)
+        if req['outcome'] == DecodeStats.DONE:
+            assert req['tokens'] == len(stream.result(0))
+    if how is _end_cancelled_decoding:
+        assert reqs['tokens'][0] >= 1 and reqs['gap_max_s'][0] >= 0
+    if how is _end_expired_decoding:
+        assert reqs['tokens'][0] == 3 and reqs['gap_max_s'][0] > 0
+
+
+def test_gap_max_is_the_longest_gap_the_stream_was_handed(artifact):
+    """`gap_max_s` against stamps taken where each token enters the
+    request's stream (the consumer's side of the scheduler), with one
+    tick held for 60 ms in the middle of the answer: within a
+    millisecond."""
+    stamps, held = [], []
+
+    def hold_one_tick(pred):
+        if not held and any(r.produced == 3
+                            for r in pred._active_requests()):
+            held.append(time.sleep(0.06))
+    with DecodingPredictor(artifact) as pred:
+        gate = _gated(pred, before=hold_one_tick)
+        stream = pred.submit(_prompts(17, 1)[0], max_new_tokens=8)
+        push = stream._push
+
+        def stamped(tok):
+            stamps.append(time.perf_counter())
+            push(tok)
+        stream._push = stamped
+        gate.set()
+        assert len(stream.result(120)) == 8 == len(stamps)
+    row, = pred.stats.request_log()
+    longest = np.diff(stamps).max()
+    assert longest >= 0.06
+    assert row['gap_max_s'] == pytest.approx(longest, abs=1e-3)
+    assert row['t_first'] == pytest.approx(stamps[0], abs=1e-3)
+    assert row['t_end'] == pytest.approx(stamps[-1], abs=1e-3)
+
+
+def test_reset_empties_both_rings(artifact):
+    with DecodingPredictor(artifact) as pred:
+        pred.generate(_prompts(67, 1)[0], max_new_tokens=3)
+        assert pred.drain(60)
+        assert len(pred.stats.request_log()) == 1
+        assert len(pred.stats.tick_log()) > 1
+        pred.stats.reset()
+        assert len(pred.stats.request_log()) == 0
+        assert len(pred.stats.tick_log()) == 0
+        snap = pred.stats.snapshot()
+    assert snap['emit_gap_p99_ms'] == 0 == snap['ttft_prefill_p99_ms']
+
+
+def test_request_ring_wraps_without_growing():
+    from paddle_tpu.inference.decoding import DecodeStats
+    stats = DecodeStats()
+    ring, held = stats.REQUEST_RING, stats._requests
+    row = np.zeros((), stats.REQUEST_ROW)
+    for k in range(ring + 100):
+        row['request'], row['t_submit'] = k, float(k)
+        stats.log_request(row.item(), requests=1)
+    log = stats.request_log()
+    assert stats._requests is held and len(held) == ring == len(log)
+    assert log['request'][0] == 100 and log['request'][-1] == ring + 99
+    assert np.all(np.diff(log['request']) == 1)
+    assert stats.requests == ring + 100      # the counters ride the hold
+    assert len(stats.request_log(since=ring)) == 100
+    stats.request_log()['request'][:] = -1      # a copy
+    assert stats.request_log()['request'][0] == 100
+
+
+def test_snapshot_reads_the_gaps_and_the_first_tokens_parts(ticked):
+    """emit_gap_*: the gaps between adjacent ticks' deliveries, each
+    counted once a row the closing tick delivered to; ttft_*: the three
+    parts of the requests' time to their first token, which add up."""
+    log, reqs, snap = ticked['log'], ticked['requests'], ticked['snap']
+    t, rows = log['emit_t'], log['emit_rows']
+    both = ~np.isnan(t[1:]) & ~np.isnan(t[:-1])
+    seen = np.repeat(np.diff(t)[both], rows[1:][both].astype(int)) * 1e3
+    for q in (50, 99):
+        assert snap['emit_gap_p%d_ms' % q] == pytest.approx(
+            np.percentile(seen, q, method='inverted_cdf'), abs=1e-3)
+    assert 0 < snap['emit_gap_p50_ms'] <= snap['emit_gap_p99_ms']
+    parts = {'queue': reqs['t_admit'] - reqs['t_submit'],
+             'prefill': reqs['t_last_slice'] - reqs['t_admit'],
+             'read': reqs['t_first'] - reqs['t_last_slice']}
+    for name, seconds in parts.items():
+        for q in (50, 99):
+            assert snap['ttft_%s_p%d_ms' % (name, q)] == pytest.approx(
+                np.percentile(seconds, q) * 1e3, abs=1e-3)
+    np.testing.assert_allclose(sum(parts.values()),
+                               reqs['t_first'] - reqs['t_submit'], atol=1e-9)
 
 
 def test_the_collector_hook_is_installed_once(artifact):

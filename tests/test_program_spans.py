@@ -130,9 +130,12 @@ def decode_trace(decode_art, tmp_path_factory):
             streams = [pred.submit(p, max_new_tokens=4,
                                    request_id='gw-7' if i == 1 else None)
                        for i, p in enumerate(prompts)]
-            return [list(s.result(120)) for s in streams]
+            tokens = [list(s.result(120)) for s in streams]
+            assert pred.drain(60)       # the last tick's row is written
+            return tokens, pred.stats.request_log(), pred.stats.tick_log()
 
-    trace, tokens = _traced(tmp_path_factory.mktemp('decode_trace'), serve)
+    trace, (tokens, trace.requests, trace.ticks) = _traced(
+        tmp_path_factory.mktemp('decode_trace'), serve)
     assert all(tokens)
     return trace
 
@@ -219,6 +222,48 @@ def test_one_request_stat_joins_a_requests_spans(decode_trace):
     assert {s[4]['request_id'] for s in tagged} == {'gw-7'}
     assert {s[0] for s in tagged} == {'decode/first_token', 'decode/finish'}
     assert {s[4]['request'] for s in tagged} == {seqs[1]}
+
+
+def test_the_request_logs_rows_join_the_spans_and_the_tick_log(decode_trace):
+    """A request's row of the request log carries the `request` stat of
+    its spans, and the `tick` stats of the ticks that admitted it and
+    delivered its first token — the numbers the tick log's rows carry."""
+    reqs = decode_trace.requests
+    submits = {s[4]['request']: s for s in decode_trace.named('decode/submit')}
+    assert sorted(reqs['request']) == sorted(submits) and len(reqs) == 3
+    ticks = set(decode_trace.ticks['tick'])
+    for req in reqs:
+        seq = int(req['request'])
+        mine = {name: [s for s in decode_trace.named(name)
+                       if s[4].get('request') == seq]
+                for name in ('decode/admit_request', 'decode/prefill_slice',
+                             'decode/first_token', 'decode/finish')}
+        assert req['prompt_len'] == submits[seq][4]['prompt_len']
+        assert req['slices'] == len(mine['decode/prefill_slice'])
+        admit, = mine['decode/admit_request']
+        assert admit[4]['waited_us'] == int(
+            (req['t_admit'] - req['t_submit']) * 1e6)
+        first, = mine['decode/first_token']
+        for span, column in ((admit, 'admit_tick'), (first, 'first_tick')):
+            tick = decode_trace.parent_of(span, ('decode/tick',))
+            assert tick[4]['tick'] == req[column] and req[column] in ticks
+        assert mine['decode/finish'][0][4]['outcome'] == 'done'
+        assert req['outcome'] == 0 and req['tokens'] == 4
+
+
+def test_advance_says_the_gap_since_the_delivery_before(decode_trace):
+    """While a trace runs the step read's 'decode/advance' carries
+    `gap_us`, this delivery's stamp minus the previous one's: the
+    difference of the tick log's `emit_t` between the two ticks."""
+    emit_t = {int(r['tick']): r['emit_t'] for r in decode_trace.ticks}
+    gaps, stamped = [], []
+    for adv in decode_trace.named('decode/advance'):
+        tick = decode_trace.parent_of(adv, ('decode/tick',))[4]['tick']
+        stamped.append(emit_t[tick])
+        gaps.append(adv[4].get('gap_us'))
+    assert len(gaps) > 3 and gaps[0] is None and None not in gaps[1:]
+    assert not np.isnan(stamped).any()
+    assert gaps[1:] == [int(d * 1e6) for d in np.diff(stamped)]
 
 
 def test_step_d2h_bytes_is_the_ids(decode_trace):
