@@ -1413,8 +1413,10 @@ class DecodingPredictor(object):
         wrote into the signature, with 'kernel' — the body a module
         holds for a TPU — read as 'jnp' anywhere else (over a latent
         pool, one row a position with the values inside it:
-        'latent_kernel' / 'latent_jnp'). Empty for an
-        artifact exported before the signature carried it."""
+        'latent_kernel' / 'latent_jnp'); a chunk program's entry also
+        holds its kv_block_chunk_write ops' body, 'pages' | 'rows'.
+        Empty for an artifact exported before the signature carried
+        it."""
         return self._bodies('attention',
                             lambda body: body.replace('kernel', 'jnp'))
 

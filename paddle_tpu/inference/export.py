@@ -895,7 +895,9 @@ def _export_decode_program(entry, program, param_args, param_specs,
     Pallas kernel, which only kv_block_attention has and reports to its
     Tracer as it lowers (ops/decode_ops.py); every other body, and
     every body on another platform, is the 'jnp' expression over the
-    gathered view. A program with routed layers also has 'experts': the
+    gathered view; a chunk program's entry also says how its
+    kv_block_chunk_write ops write ({'pages': 18} | 'rows': ISSUE 54). A
+    program with routed layers also has 'experts': the
     body of each moe_topk_ffn's grouped matmuls where the module is
     compiled for a TPU ({'moe_topk_ffn': {'grouped_kernel': 10}} — the
     Pallas weight-streaming kernel, ops/pallas_grouped_matmul.py; or

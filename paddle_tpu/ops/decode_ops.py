@@ -607,6 +607,9 @@ def _quantize_kv_rows(kv):
 # (ppa.latent_paged_attention, ppa.refuses_latent) and, in the chunk
 # form, always the paged jnp body (_chunk_attention_blocked); the op tells its Tracer (lowered_bodies) and
 # export_decode writes it into the signature.
+# What WRITES it how (ISSUE 54): the step one row a slot (kv_block_write:
+# one index pair a row, nothing to group); a chunk a PAGE at a time where
+# C % BS == 0 and it starts on a page (kv_block_chunk_write), else a row.
 # ---------------------------------------------------------------------------
 
 def _block_view(cache, table_row):
@@ -915,35 +918,68 @@ def _chunk_attention_blocked(ctx, q, kc, vc, start, table):
     return out.reshape(1, c, n_head * dv).astype(q.dtype)
 
 
+def _chunk_write_rows(cache, kv, start, table):
+    """kv_block_chunk_write with one index pair a ROW: cache [NB, BS, D],
+    kv [R, C, D] of the pool's dtype, start [R] int32, table [R, MAXB]."""
+    r, c, d = kv.shape
+    pos = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    bidx, boff = _block_scatter_idx(jnp.repeat(table, c, axis=0),
+                                    pos.reshape(-1), cache.shape[1])
+    return cache.at[bidx, boff].set(kv.reshape(r * c, d))
+
+
+def _chunk_write_pages(cache, kv, start, table):
+    """kv_block_chunk_write with one index a PAGE, for chunks of whole
+    pages that start on a page's first row: row r's C positions are the
+    C / BS whole pages that hold positions start[r], start[r] + BS, ... —
+    each the block _block_scatter_idx gives that position."""
+    r, c, d = kv.shape
+    bs = cache.shape[1]
+    pos = start[:, None] + jnp.arange(0, c, bs, dtype=jnp.int32)[None, :]
+    bidx, _ = _block_scatter_idx(jnp.repeat(table, c // bs, axis=0),
+                                 pos.reshape(-1), bs)
+    return cache.at[bidx].set(kv.reshape(r * (c // bs), bs, d))
+
+
 @register('kv_block_chunk_write', no_grad=True, lod='none')
 def _kv_block_chunk_write(ctx, ins):
     """Chunked-prefill write: KV [R, C, D] — row r the K or V rows of
-    chunk positions start[r]..start[r]+C-1 of ONE slot — scatter into
-    the block pool through that slot's table row (Cache [NB, BS, D],
-    Start [R, 1] int32, BlockTable [R, MAXB] int32). Positions beyond a
-    chunk's true length carry pad garbage into the slot's own tail block
-    (or the trash block past the allocated span) — never attended before
-    a decode step overwrites them, the prefill contract in block form;
-    a pad ROW (the trash table) writes the trash block only. With R = 1
-    the expression is the one-slot one, unchanged. Out aliases Cache."""
+    chunk positions start[r]..start[r]+C-1 of ONE slot — into the block
+    pool through that slot's table row (Cache [NB, BS, D], Start [R, 1]
+    int32, BlockTable [R, MAXB] int32). Positions beyond a chunk's true
+    length carry pad garbage into the slot's own tail block (or the trash
+    block past the allocated span) — never attended before a decode step
+    overwrites them, the prefill contract in block form; a pad ROW (the
+    trash table) writes the trash block only. Out aliases Cache.
+
+    Two bodies, chosen from the shapes the lowering sees and never from a
+    knob, and told to the Tracer (lowered_bodies: 'pages' | 'rows'). A
+    chunk of whole pages (C % BS == 0: every chunk program a
+    configuration exports) whose rows all start on a page's first row —
+    every slice the scheduler sends: prefix hits cover whole blocks and
+    only a prompt's last slice is short — IS C / BS pages of its table
+    and is written with one index a page (_chunk_write_pages: on a TPU a
+    scatter costs by the index, 150 ns each, not by the byte); the same
+    program sent a `start` inside a page takes the scatter of one index
+    pair a row behind a branch on start % BS, and a C that is not whole
+    pages holds that scatter alone (_chunk_write_rows). Outside the trash
+    block the pool is the same to the bit either way, and either way it
+    is updated in place."""
     cache = ins['Cache'][0]
-    kv = ins['KV'][0]
     table = ins['BlockTable'][0]
-    r, c = kv.shape[0], kv.shape[1]
-    if r == 1:
-        start = ins['Start'][0].reshape(()).astype(jnp.int32)
-        pos = start + jnp.arange(c, dtype=jnp.int32)
-        bidx, boff = _block_scatter_idx(
-            jnp.broadcast_to(table[0], (c, table.shape[1])), pos,
-            cache.shape[1])
-        return {'Out': [cache.at[bidx, boff].set(
-            kv[0].astype(cache.dtype))]}
-    start = ins['Start'][0].reshape(r, 1).astype(jnp.int32)
-    pos = start + jnp.arange(c, dtype=jnp.int32)[None, :]       # [R, C]
-    bidx, boff = _block_scatter_idx(
-        jnp.repeat(table, c, axis=0), pos.reshape(-1), cache.shape[1])
-    return {'Out': [cache.at[bidx, boff].set(
-        kv.reshape(r * c, -1).astype(cache.dtype))]}
+    kv = ins['KV'][0].astype(cache.dtype)
+    start = ins['Start'][0].reshape(kv.shape[0]).astype(jnp.int32)
+    bs = cache.shape[1]
+    pages = kv.shape[1] % bs == 0
+    tracer = getattr(ctx, 'tracer', None)
+    if tracer is not None:
+        tracer.lowered_bodies.append(
+            ('kv_block_chunk_write', 'pages' if pages else 'rows'))
+    if not pages:
+        return {'Out': [_chunk_write_rows(cache, kv, start, table)]}
+    return {'Out': [jax.lax.cond(
+        jnp.all(start % bs == 0), _chunk_write_pages, _chunk_write_rows,
+        cache, kv, start, table)]}
 
 
 @register('kv_block_chunk_attention', no_grad=True, lod='none')

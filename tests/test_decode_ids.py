@@ -640,10 +640,17 @@ def exported(tmp_path_factory):
             # taken out here and held to its own test
             said = {size: entry['attention'].pop('gated_delta_chunk', None)
                     for size, entry in sig['chunk'].items()}
+            # ... and since PR 54 kv_block_chunk_write: the same
+            wrote = {size: entry['attention'].pop('kv_block_chunk_write',
+                                                  None)
+                     for size, entry in dict(
+                         sig['chunk'], **(
+                             {'rows': sig['chunk_rows']}
+                             if 'chunk_rows' in sig else {})).items()}
             made[config] = {
                 'modules': modules, 'weights': _digest(*weights),
                 'signature': _digest(json.dumps(sig, sort_keys=True)),
-                'chunk_rule': said}
+                'chunk_rule': said, 'chunk_write': wrote}
         return made[config]
     return get
 
@@ -663,7 +670,38 @@ _STABLEHLO_47 = {
     'phi4_mini_flash_reasoning/decode_zeros': '8ef474a32769d8d4',
     'phi4_mini_flash_reasoning/prefill_chunk_00008': '5a8223a29dbb3664',
     'phi4_mini_flash_reasoning/prefill_chunk_00016': '56bfbb3268e413bf'}
-_PINNED = dict(_PARENT_STABLEHLO, **_PARENT_STABLEHLO_45, **_STABLEHLO_47)
+# recorded in PR 54, which made kv_block_chunk_write write a chunk of whole
+# pages a page at a time behind a branch on start % BS (ops/decode_ops.py):
+# every chunk module that holds the op — all but the int8 pool's, whose
+# _quant form kept its body — is another text, and these take the place of
+# their entries in the three tables above; the step, blockcopy and zeros
+# modules are the parents' still
+_STABLEHLO_54 = {
+    'joyai_llm_flash/prefill_chunk_00008': '6c8b984035e0ba8e',
+    'joyai_llm_flash/prefill_chunk_00016': 'f4694b9c0591101a',
+    'k_exaone_236b_a23b/prefill_chunk_00008': '126ad49c6156f6ed',
+    'k_exaone_236b_a23b/prefill_chunk_00016': '1c0f8b3d7f756d4b',
+    'olmoe_1b_7b/prefill_chunk_00008': 'b74f7621ed3d87ff',
+    'olmoe_1b_7b/prefill_chunk_00016': '0c69ccdc56ab451a',
+    'olmoe_1b_7b/prefill_chunk_00016x4': '6b0be1bb5da776d2',
+    'phi4_mini_flash_reasoning/prefill_chunk_00008': 'e578b8a81cc7d857',
+    'phi4_mini_flash_reasoning/prefill_chunk_00016': '463246618f050bff',
+    'qwen3_next_80b_a3b/prefill_chunk_00008': '998643bfdff3b679',
+    'qwen3_next_80b_a3b/prefill_chunk_00016': '4aee1c42c323f0e8',
+    'transformer_base_lm+bfloat16/prefill_chunk_00008': 'e8b88ec4727a55d6',
+    'transformer_base_lm+bfloat16/prefill_chunk_00016': '2a235b441a0d0c38',
+    'transformer_base_lm+bfloat16/prefill_chunk_00016x4': 'a97d5a51b10d036f',
+    'transformer_base_lm+draft_k2/prefill_chunk_00008': 'd398a376f8da1138',
+    'transformer_base_lm+draft_k2/prefill_chunk_00016': '2ca9f799d0074412',
+    'transformer_base_lm+draft_k2/prefill_chunk_00016x4': 'b69dcd330a841bb2',
+    'transformer_base_lm+mp2/prefill_chunk_00008': 'b185f84d030c0ac0',
+    'transformer_base_lm+mp2/prefill_chunk_00016': '35e2d51661e93c71',
+    'transformer_base_lm+mp2/prefill_chunk_00016x4': '18ef9c3ed76dc94f',
+    'transformer_base_lm/prefill_chunk_00008': 'd398a376f8da1138',
+    'transformer_base_lm/prefill_chunk_00016': '2ca9f799d0074412',
+    'transformer_base_lm/prefill_chunk_00016x4': 'b69dcd330a841bb2'}
+_PINNED = {**_PARENT_STABLEHLO, **_PARENT_STABLEHLO_45, **_STABLEHLO_47,
+           **_STABLEHLO_54}
 
 
 @pytest.mark.parametrize('module', sorted(_PARENT_STABLEHLO)
@@ -694,6 +732,22 @@ def test_only_the_chunked_delta_rule_says_a_body_of_its_own(exported,
     said = exported(config)['chunk_rule']
     want = {'jnp': 3} if config == 'qwen3_next_80b_a3b' else None
     assert said == {'8': want, '16': want}
+
+
+@pytest.mark.parametrize('config', sorted(_PARENT_WEIGHTS_AND_SIGNATURE))
+def test_every_chunk_program_says_how_it_writes_its_pages(exported, config):
+    """What PR 54 added to a signature: each chunk program — the row
+    program too — names the body its kv_block_chunk_write ops lowered,
+    one entry a K pool, a V pool or a latent pool of a caching layer. The
+    toy chunks (8, 16) are whole pages of every toy block size, so it is
+    'pages'; the int8 pool's _quant form reports nothing."""
+    wrote = exported(config)['chunk_write']
+    pools = {'joyai_llm_flash': 3, 'k_exaone_236b_a23b': 10,
+             'olmoe_1b_7b': 4, 'qwen3_next_80b_a3b': 2}.get(config, 4)
+    want = None if config.endswith('+int8') else {'pages': pools}
+    sizes = {'8', '16'} | ({'rows'} if config.split('+')[0] in (
+        'transformer_base_lm', 'olmoe_1b_7b') and want else set())
+    assert wrote == dict.fromkeys(sizes, want)
 
 
 @pytest.mark.parametrize('config', sorted(_ROW_MODULES))

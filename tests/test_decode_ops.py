@@ -564,6 +564,171 @@ def test_chunk_ops_with_rows_are_the_one_row_ops_row_by_row(rows, starts):
     assert np.isfinite(out).all()
 
 
+def _row_scatter(cache, kv, start, tables):
+    """kv_block_chunk_write as it was before it wrote pages — one index
+    pair a row — kept as the plain reference of the page write."""
+    import jax.numpy as jnp
+    from paddle_tpu.ops import decode_ops
+    r, c = kv.shape[0], kv.shape[1]
+    pos = start.reshape(r, 1).astype(jnp.int32) \
+        + jnp.arange(c, dtype=jnp.int32)[None, :]
+    bidx, boff = decode_ops._block_scatter_idx(
+        jnp.repeat(tables, c, axis=0), pos.reshape(-1), cache.shape[1])
+    return cache.at[bidx, boff].set(
+        kv.reshape(r * c, -1).astype(cache.dtype))
+
+
+def _slot_table(first_block, held):
+    """One slot's table row: column j names block first_block + j where
+    `held` has j, the trash block elsewhere (a column the window gave
+    back, or one not allocated yet)."""
+    row = np.zeros(_MAXB, np.int32)
+    for j in held:
+        row[j] = first_block + j
+    return row
+
+
+# (C, D, pool dtype, rows of the call, [(start, held table columns)] of its
+# real rows — the rest are pad rows on the trash table)
+_PAGE_WRITES = {
+    'aligned': (8, _D, 'float32', 1, [(4, range(5))]),
+    'aligned_at_zero': (8, _D, 'float32', 1, [(0, range(5))]),
+    'inside_a_page': (8, _D, 'float32', 1, [(6, range(5))]),
+    'last_row_of_a_page': (8, _D, 'float32', 1, [(3, range(5))]),
+    'ends_with_the_table': (8, _D, 'float32', 1, [(12, range(5))]),
+    'runs_past_the_table': (8, _D, 'float32', 1, [(16, range(5))]),
+    'inside_a_page_past_the_table': (8, _D, 'float32', 1, [(14, range(5))]),
+    'all_past_the_table': (8, _D, 'float32', 1, [(24, range(5))]),
+    # a short chunk: the slot holds pages up to its prompt's end only, the
+    # pad positions beyond land in its tail page and the trash block
+    'short_chunk': (8, _D, 'float32', 1, [(4, range(2))]),
+    'short_chunk_inside_a_page': (8, _D, 'float32', 1, [(5, range(3))]),
+    'bfloat16': (8, _D, 'bfloat16', 1, [(8, range(5))]),
+    'bfloat16_inside_a_page': (8, _D, 'bfloat16', 1, [(9, range(5))]),
+    # a latent pool: ONE row a position, key and value both
+    'latent_row': (16, 40, 'bfloat16', 1, [(4, range(5))]),
+    'latent_row_inside_a_page': (4, 40, 'bfloat16', 1, [(7, range(5))]),
+    # a window layer's table: the columns the window has passed were given
+    # back and name the trash block
+    'window_table': (8, _D, 'bfloat16', 1, [(8, [2, 3, 4])]),
+    'window_table_starts_given_back': (8, _D, 'float32', 1,
+                                       [(4, [2, 3, 4])]),
+    'window_table_inside_a_page': (8, _D, 'float32', 1, [(6, [2, 3])]),
+    'four_rows': (8, _D, 'float32', 4,
+                  [(0, range(5)), (4, range(5)), (8, range(5)),
+                   (12, range(5))]),
+    'four_rows_inside_pages': (8, _D, 'bfloat16', 4,
+                               [(1, range(5)), (6, range(3)),
+                                (11, range(5)), (15, range(5))]),
+    'four_rows_two_pad': (8, _D, 'float32', 4,
+                          [(4, range(5)), (7, range(4))]),
+    'three_rows_one_pad_latent': (4, 40, 'bfloat16', 3,
+                                  [(16, range(5)), (2, range(1))]),
+}
+
+
+@pytest.mark.parametrize('case', sorted(_PAGE_WRITES))
+def test_a_chunk_of_whole_pages_is_written_as_the_row_scatter_wrote_it(case):
+    """C % BS == 0: kv_block_chunk_write reads the C / BS + 1 pages a row
+    touches, lays the chunk over them and writes them back — and leaves
+    every block but the trash block (0, never read) as the row scatter
+    leaves it, to the bit, whatever `start`: page-aligned, inside a page,
+    past the table's span. The blocks that changed are exactly the
+    slot's own that hold a chunk position."""
+    import jax.numpy as jnp
+    c, d, dtype, rows, real = _PAGE_WRITES[case]
+    assert c % _BS == 0
+    rng = np.random.RandomState(len(case))
+    pool = jnp.asarray(rng.randn(_NB, _BS, d).astype(np.float32)
+                       ).astype(dtype)
+    tables = np.zeros((rows, _MAXB), np.int32)
+    start = np.zeros((rows, 1), np.int32)
+    for r, (at, held) in enumerate(real):
+        tables[r] = _slot_table(1 + r * _MAXB, held)
+        start[r, 0] = at
+    kv = jnp.asarray(rng.randn(rows, c, d).astype(np.float32))
+    write, _ = _chunk_ops()
+    got = np.asarray(write(pool, kv, jnp.asarray(start),
+                           jnp.asarray(tables)))
+    want = np.asarray(_row_scatter(pool, kv, jnp.asarray(start),
+                                   jnp.asarray(tables)))
+    assert got.dtype == want.dtype == np.asarray(pool).dtype
+    np.testing.assert_array_equal(got[1:].view(np.uint8),
+                                  want[1:].view(np.uint8))
+    changed = np.flatnonzero(
+        (got.view(np.uint8) != np.asarray(pool).view(np.uint8)
+         ).any(axis=(1, 2)))
+    own = {int(tables[r, (at + i) // _BS]) for r, (at, _) in enumerate(real)
+           for i in range(c) if (at + i) // _BS < _MAXB}
+    assert set(changed) - {0} == own - {0}
+
+
+def _scatters(jaxpr):
+    """(pool, index, update) shapes of every scatter of a jaxpr, those of
+    its inner jaxprs (a jitted library function's) too."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name.startswith('scatter'):
+            out.append(tuple(v.aval.shape for v in eqn.invars))
+        for v in eqn.params.values():
+            if hasattr(v, 'jaxpr'):
+                out += _scatters(v.jaxpr)
+    return out
+
+
+@pytest.mark.parametrize('rows,c,bs,d,dtype,body', [
+    (1, 512, 16, 1280, 'bfloat16', 'pages'),    # a cell's largest chunk
+    (1, 32, 16, 640, 'bfloat16', 'pages'),      # its smallest, latent rows
+    (4, 128, 16, 512, 'float32', 'pages'),      # the row program
+    (1, 6, 4, 16, 'float32', 'rows')])          # no cell's: C % BS != 0
+def test_the_lowered_chunk_write_updates_a_page_an_index(rows, c, bs, d,
+                                                         dtype, body):
+    """What the lowering holds, from shapes alone: at C % BS == 0 a
+    branch on start % BS whose aligned side is ONE scatter of R * C / BS
+    index rows and page-shaped windows — not R * C index pairs, which is
+    the other side and all a C of no whole pages has; the op names the
+    body to its Tracer; and a program that donates the pool gets it back
+    as its output buffer."""
+    import re
+    import types
+    import jax
+    from paddle_tpu.ops import decode_ops
+    ctx = types.SimpleNamespace(
+        attr=lambda n, default=None: default,
+        tracer=types.SimpleNamespace(lowered_bodies=[]))
+
+    def write(cache, kv, start, tables):
+        return decode_ops._kv_block_chunk_write(ctx, {
+            'Cache': [cache], 'KV': [kv], 'Start': [start],
+            'BlockTable': [tables]})['Out'][0]
+
+    nb, maxb = 3 * rows * (c // bs + 2), c // bs + 2
+    args = (jax.ShapeDtypeStruct((nb, bs, d), dtype),
+            jax.ShapeDtypeStruct((rows, c, d), 'float32'),
+            jax.ShapeDtypeStruct((rows, 1), 'int32'),
+            jax.ShapeDtypeStruct((rows, maxb), 'int32'))
+    jaxpr = jax.make_jaxpr(write)(*args).jaxpr
+    assert ctx.tracer.lowered_bodies == [('kv_block_chunk_write', body)]
+    by_row = ((nb, bs, d), (rows * c, 2), (rows * c, d))
+    conds = [e for e in jaxpr.eqns if e.primitive.name == 'cond']
+    if body == 'pages':
+        n = rows * c // bs
+        unaligned, aligned = (_scatters(b.jaxpr)
+                              for b in conds[0].params['branches'])
+        # (a cond's branches are a tuple: _scatters(jaxpr) stays outside)
+        assert len(conds) == 1 and not _scatters(jaxpr)
+        assert aligned == [((nb, bs, d), (n, 1), (n, bs, d))]
+        assert unaligned == [by_row]
+    else:
+        assert not conds and _scatters(jaxpr) == [by_row]
+    # donated, the pool is the program's output buffer (that no copy of
+    # it is made on the way is the TPU compiler's to show:
+    # tests/test_paged_attention_kernel.py — XLA:CPU scatters a bfloat16
+    # pool through float32 and copies out of a conditional)
+    text = jax.jit(write, donate_argnums=0).lower(*args).compile().as_text()
+    assert re.search(r'input_output_alias=\{ \{\}: \(0, \{\}', text)
+
+
 def test_chunk_rows_exist_over_the_gathered_view_only(monkeypatch):
     """Grouped heads, a window or scores past the budget send ONE row to
     the blocked body; more rows are refused by name, as they are by the

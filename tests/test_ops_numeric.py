@@ -78,7 +78,9 @@ class TestSoftmax(OpTest):
     op_type = 'softmax'
 
     def setup_method(self, m):
-        x = np.random.rand(5, 7).astype(np.float32)
+        # seeded: test_grad's finite differences miss max_relative_error
+        # on an unlucky draw (it failed one whole run in PR 54)
+        x = np.random.RandomState(77).rand(5, 7).astype(np.float32)
         self.inputs = {'X': x}
         self.outputs = {'Out': _softmax_np(x)}
 

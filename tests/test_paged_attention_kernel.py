@@ -580,7 +580,11 @@ def test_signature_names_the_body_each_attention_op_holds(arts, art, body):
     sig = _signature(arts[art])
     assert sig['step']['attention'] == {'kv_block_attention': {body: 2}}
     for chunk in sig['chunk'].values():
-        assert chunk['attention'] == {'kv_block_chunk_attention': {'jnp': 2}}
+        # ... and the body its K and V writes took (ISSUE 54): chunks of
+        # 8 and 16 over pages of 8 are whole pages
+        assert chunk['attention'] == {
+            'kv_block_chunk_attention': {'jnp': 2},
+            'kv_block_chunk_write': {'pages': 4}}
     with open(os.path.join(arts[art], decoding._STEP_DIR,
                            'module.jaxexport'), 'rb') as f:
         assert (b'tpu_custom_call' in f.read()) == (body == 'kernel')
@@ -664,6 +668,49 @@ def test_kernel_compiles_for_v5e(one_chip, s, nb, bs, d, h, maxb, dtype):
                         sds((nb, bs, d), dtype), sds((s,), np.int32),
                         sds((s, maxb), np.int32)).compile()
     assert 'tpu_custom_call' in compiled.as_text()
+
+
+# kv_block_chunk_write's page write (ops/decode_ops.py, ISSUE 54;
+# tests/test_decode_ops.py has the rest) lives here for the chip's compiler,
+# which one test file loads: a chunk program donates its pools, and the
+# write has to update them in place — a copy of phi4's full pool is 3.7 ms
+# a layer and the cell runs at 16.5 of 17.2 GB
+@pytest.mark.parametrize('nb,d,dtype,maxb,rows,c', [
+    (18433, 1280, jnp.bfloat16, 288, 1, 512),   # phi4: layer 17's pool
+    (4161, 1280, jnp.bfloat16, 288, 1, 512),    # ... a window layer's
+    (4161, 1280, jnp.bfloat16, 288, 1, 32),
+    (8193, 2048, jnp.bfloat16, 256, 1, 512),    # olmoe_1b_7b
+    (49153, 1024, jnp.bfloat16, 768, 1, 512),   # k_exaone_236b_a23b
+    (2625, 1024, jnp.bfloat16, 768, 1, 128),    # ... a window layer's
+    (36865, 640, jnp.bfloat16, 288, 1, 512),    # joyai_llm_flash: latent
+    (36865, 512, jnp.bfloat16, 288, 1, 512),    # qwen3_next_80b_a3b
+    (16385, 512, np.float32, 128, 4, 128),      # transformer_base_lm
+    (16385, 512, np.float32, 128, 1, 32)])
+def test_the_page_write_keeps_the_pool_in_place_on_v5e(one_chip, nb, d,
+                                                       dtype, maxb, rows,
+                                                       c):
+    import re
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def write(cache, kv, start, tables):
+        return decode_ops._kv_block_chunk_write(_attrs(1), {
+            'Cache': [cache], 'KV': [kv], 'Start': [start],
+            'BlockTable': [tables]})['Out'][0]
+    text = jax.jit(write, donate_argnums=0).lower(
+        sds((nb, 16, d), dtype), sds((rows, c, d), np.float32),
+        sds((rows, 1), np.int32), sds((rows, maxb), np.int32)
+    ).compile().as_text()
+    assert re.search(r'input_output_alias=\{ \{\}: \(0, \{\}', text)
+    pool = '%s[%d,16,%d]' % ('f32' if dtype is np.float32 else 'bf16', nb, d)
+    # nothing but parameters, the two updates and the branch between them
+    # has the pool's shape: no copy, no convert, no select over it
+    made = re.findall(r'^\s*(?:ROOT )?%%\S+ = %s\S* ([\w-]+)\('
+                      % re.escape(pool), text, re.M)
+    assert made and set(made) <= {
+        'parameter', 'scatter', 'fusion', 'bitcast', 'conditional',
+        'get-tuple-element'}, made
 
 
 # moe_topk_ffn's grouped matmul kernel (ops/pallas_grouped_matmul.py,
