@@ -98,6 +98,22 @@ this one process — a chip belongs to one process at a time):
               (verify.margin_eps) on its 48 verify prompts served together,
               for the served tokens and for the bfloat16 reference's.
 
+  H  hybrid   granite-4.0-h-micro AS THE BENCHMARK HOLDS IT (benchmark/
+              configs/granite_4_0_h_micro.json through its own build_spec:
+              the WHOLE model — 40 layers, 36 Mamba-2 (SSD) with a [64, 64,
+              128] float32 state a slot, 4 NoPE attention layers of 32 / 8
+              heads of 64 padded to tiles of 128, four multipliers, 100,352
+              tied vocabulary rows, 64 slots of 4,608 positions), one
+              artifact, phase F's two comparisons: LOGITS of prompts of
+              300, 1,500 and 4,000 tokens (the SSD chunk's matrix form,
+              its state and the convolution's tail carried over 1, 3 and 8
+              slices) and their decode steps against
+              benchmark/reference/granite_hybrid.py (the recurrence
+              position after position from a zero state, published heads),
+              held to a bound that the reference in bfloat16 throughout
+              fails; and the cell's own token rule on its 48 verify
+              prompts (~20 min).
+
   G  grouped  moe_topk_ffn's grouped matmuls (ISSUE 41) at the three MoE
               cells' real shapes — joyai_llm_flash 32 held experts of
               2048 x 768, olmoe_1b_7b 64 of 2048 x 1024, k_exaone_236b_a23b
@@ -194,6 +210,9 @@ FULL = {
     # Phi-4-mini-flash: the benchmark configuration's own file and artifact
     'phi': dict(seed=42, logit_prompts=(300, 1500, 4000, 417), new=64,
                 rule_prompts=48),
+    # granite-4.0-h-micro: the benchmark configuration's own file and artifact
+    'granite': dict(seed=42, logit_prompts=(300, 1500, 4000, 417), new=64,
+                    rule_prompts=48, more_rule_seeds=(43, 44, 45, 46)),
     # (tokens, top k, experts routed over, experts held, K, N, layers)
     'grouped': {'joyai.step': (128, 8, 256, 32, 2048, 768, 4),
                 'joyai.slice': (512, 8, 256, 32, 2048, 768, 4),
@@ -238,6 +257,8 @@ TOY = {
                  rule_prompts=4, chunk_rule=(128, 1, 2, 128, 128)),
     'phi': dict(seed=42, logit_prompts=(5, 21, 40, 100), new=8,
                 rule_prompts=4),
+    'granite': dict(seed=42, logit_prompts=(5, 21, 40, 100), new=8,
+                    rule_prompts=4, more_rule_seeds=(43,)),
     'grouped': {'toy.step': (16, 2, 8, 4, 128, 256, 2),
                 'toy.slice': (96, 2, 8, 8, 256, 128, 2)},
 }
@@ -303,6 +324,19 @@ QWEN_LOGIT_TOL = 0.04
 # seeded at init_std the served TOKENS differed at margins up to 0.147:
 # benchmark/configs/phi4_mini_flash_reasoning.json assumed.embed_std.
 PHI_LOGIT_TOL = 0.03
+# Phase H's bound, on the same median, for granite_4_0_h_micro as the
+# benchmark holds it (the whole model, 40 layers, 36 of them Mamba-2; my chip
+# run, PR 55, call 5): the row reads 0.0128 (p90 0.0140, worst 0.0156), the
+# reference in bfloat16 throughout 0.0781 — it must fail — and the reference
+# with the recurrence's state rounded to bfloat16 after every position
+# 0.0052 (reported: this bound does not see it; tests/test_granite_hybrid.py
+# holds it at float32 widths). 0.03 is 2.3 times the served reading and
+# under two fifths of the control's. The cell's token rule over five draws of
+# its 48 prompts: served 7-22 mismatches, none over margin_eps 0.03 (largest
+# 0.0084); the control 69-94, 8-16 of them over it on every draw. Call 1
+# read 0.118 served: the TPU compiler miscompiled the heads' un-padding
+# (models/granite_hybrid.py, BOTH ARE A PRODUCT WITH ONE CONSTANT).
+GRANITE_LOGIT_TOL = 0.03
 # a phase that warns one of these did not run the path it claims to prove
 FALLBACK = re.compile(r'fall(ing|s)? back|fallback|unusable|unavailable',
                       re.I)
@@ -792,13 +826,35 @@ class Smoke(object):
         """Phi-4-mini-flash-reasoning as the benchmark holds it
         (benchmark/configs/phi4_mini_flash_reasoning.json through its own
         build_spec; the file's rehearsal sizes off the chip), ONE artifact,
-        two comparisons, as phase Q makes them.
+        two comparisons (`_state_space_phase`), held to PHI_LOGIT_TOL."""
+        from benchmark.configs import phi4_mini_flash_reasoning as model
+        return self._state_space_phase('phi4_mini_flash_reasoning', model,
+                                       'phi', PHI_LOGIT_TOL)
+
+    def phase_h(self):
+        """granite-4.0-h-micro as the benchmark holds it (benchmark/configs/
+        granite_4_0_h_micro.json through its own build_spec: all 40 layers
+        at published widths; the file's rehearsal sizes off the chip), ONE
+        artifact, the same two comparisons, held to GRANITE_LOGIT_TOL: the
+        SSD chunk's matrix form with its state carried over 1, 3 and 8
+        slices and the step's recurrence against a reference that runs the
+        recurrence position after position from a zero state, padded heads
+        of 128 in the paged kernel against published heads of 64."""
+        from benchmark.configs import granite_4_0_h_micro as model
+        return self._state_space_phase('granite_4_0_h_micro', model,
+                                       'granite', GRANITE_LOGIT_TOL)
+
+    def _state_space_phase(self, config, model, key, bound):
+        """A whole state-space hybrid as the benchmark holds it (the
+        configuration's file through its own build_spec; the file's
+        rehearsal sizes off the chip), ONE artifact, two comparisons, as
+        phase Q makes them.
 
         LOGITS: prompts of 300, 1,500 (3 slices) and 4,000 (8 slices)
         tokens and one of the traffic's own, prefilled slice by slice and
         decoded through cache and state, against the reference's full
-        forward pass — held to PHI_LOGIT_TOL, which the reference in
-        bfloat16 throughout must fail; the reference with the scan's state
+        forward pass — held to `bound`, which the reference in bfloat16
+        throughout must fail; the reference with the recurrence's state
         rounded to bfloat16 after every token is read and reported.
 
         THE CELL'S TOKEN RULE (the file's verify.margin_eps; no routing,
@@ -809,26 +865,33 @@ class Smoke(object):
         same rows — what margin_eps is read from."""
         import numpy as np
         import jax.numpy as jnp
-        from benchmark.configs import phi4_mini_flash_reasoning as model
         from paddle_tpu.inference import DecodingPredictor
         from paddle_tpu.testing.decode_logits import served_logits
-        cfg, art, weights = self._benchmark_artifact(
-            'phi4_mini_flash_reasoning', model, 'phi_art')
-        q = self.cfg['phi']
+        cfg, art, weights = self._benchmark_artifact(config, model,
+                                                     key + '_art')
+        q = self.cfg[key]
         rng = np.random.RandomState(q['seed'])
         vocab = model.vocab_size(cfg)
         prompts = [rng.randint(2, vocab, n) for n in q['logit_prompts']]
         v = cfg['verify']
         lens = list(v['prompt_lens'])[:q['rule_prompts']]
-        rule_prompts = [rng.randint(2, vocab, n).astype(np.int64)
-                        for n in lens]
+        # the rule's prompts: the phase's own seed first, then a draw of
+        # their own for each of `more_rule_seeds` (the control has to be
+        # refused on MOST draws, not on one)
+        draws = {q['seed']: rng}
+        draws.update((s, np.random.RandomState(s))
+                     for s in q.get('more_rule_seeds', ()))
+        rule_prompts = {s: [r.randint(2, vocab, n).astype(np.int64)
+                            for n in lens] for s, r in draws.items()}
         with DecodingPredictor(art) as pred:
             attention = pred.stats.snapshot()['attention']
             bodies = pred.attention_bodies
             tokens, logits = served_logits(pred, prompts, q['new'])
-            streams = [pred.submit(p, max_new_tokens=int(v['max_new_tokens']))
-                       for p in rule_prompts]
-            served = [list(s.result(1800)) for s in streams]
+            served = {}
+            for s, ps in rule_prompts.items():
+                streams = [pred.submit(
+                    p, max_new_tokens=int(v['max_new_tokens'])) for p in ps]
+                served[s] = [list(st.result(1800)) for st in streams]
             snap = pred.stats.snapshot()
             peak = (self.dev.memory_stats() or {}).get('peak_bytes_in_use')
         if self.cfg is FULL and attention != 'kernel':
@@ -842,7 +905,7 @@ class Smoke(object):
             seq = np.concatenate([p, np.asarray(t[:-1], np.int64)])
             for name, over in controls.items():
                 # a copy: a view would keep the pass's whole [rows, 200064]
-                # result alive (3.3 GB of host memory for 4,000 tokens)
+                # result alive (3.3 GB of host memory for phi4's 4,000 tokens)
                 rows[name].append(np.array(model.reference_logits(
                     cfg, weights, seq, **over)[len(p) - 1:len(seq)]))
         want = np.concatenate(rows.pop('reference'))
@@ -851,11 +914,11 @@ class Smoke(object):
             err = np.abs(want - got).max(axis=-1)
             return {'row_error_p%d' % p: float(np.percentile(err, p))
                     for p in (50, 90, 99, 100)}
-        out = {'bound': PHI_LOGIT_TOL, 'logit_prompts': q['logit_prompts'],
+        out = {'bound': bound, 'logit_prompts': q['logit_prompts'],
                'rows': len(want), 'logit_std': float(want.std()),
                'served': row_errors(np.concatenate(logits)),
                'step_attention': attention, 'attention_bodies': bodies,
-               'shared_pool_readers': snap['shared_pool_readers'],
+               'shared_pool_readers': snap.get('shared_pool_readers', 0),
                'pool_bytes': snap['pool_bytes'],
                'recurrent_state_bytes': snap['recurrent_state_bytes'],
                'state_resets': snap['state_resets'],
@@ -865,51 +928,61 @@ class Smoke(object):
 
         # the cell's rule on the served tokens and on the control's
         eps = float(v['margin_eps'])
-        rule = {'served': [], 'lower_precision': []}
-        margins, total = [], 0
-        for p, toks in zip(rule_prompts, served):
-            seq = np.concatenate([p, np.asarray(toks, np.int64)])
-            padded = np.zeros(int(v['pad_to']), np.int64)
-            padded[:len(seq)] = seq
-            mine = slice(len(p) - 1, len(p) - 1 + len(toks))
-            plain = np.array(model.reference_logits(cfg, weights,
-                                                    padded)[mine])
-            low = model.reference_logits(
-                cfg, weights, padded,
-                compute_dtype=jnp.bfloat16)[mine].argmax(-1)
-            for row, tok, low_tok in zip(plain, toks, low):
-                total += 1
-                top2 = np.partition(row, -2)[-2:]
-                margins.append(float(top2[1] - top2[0]))
-                for name, chosen in (('served', int(tok)),
-                                     ('lower_precision', int(low_tok))):
-                    if int(np.argmax(row)) != chosen:
-                        rule[name].append(margins[-1])
-        out['rule'] = {
-            'prompts': len(rule_prompts), 'rows': total, 'margin_eps': eps,
-            'rows_under_margin_eps': sum(m <= eps for m in margins),
-            'margin_p10_p25_p50': [float(np.percentile(margins, p))
-                                   for p in (10, 25, 50)]}
-        for name, wrong in rule.items():
-            wrong = sorted(wrong, reverse=True)
-            out['rule'][name] = {
-                'mismatches': len(wrong),
-                'over_margin_eps': sum(m > eps for m in wrong),
-                'largest_margins': wrong[:8]}
+
+        def rule_of(seed):
+            rule = {'served': [], 'lower_precision': []}
+            margins = []
+            for p, toks in zip(rule_prompts[seed], served[seed]):
+                seq = np.concatenate([p, np.asarray(toks, np.int64)])
+                padded = np.zeros(int(v['pad_to']), np.int64)
+                padded[:len(seq)] = seq
+                mine = slice(len(p) - 1, len(p) - 1 + len(toks))
+                plain = np.array(model.reference_logits(cfg, weights,
+                                                        padded)[mine])
+                low = model.reference_logits(
+                    cfg, weights, padded,
+                    compute_dtype=jnp.bfloat16)[mine].argmax(-1)
+                for row, tok, low_tok in zip(plain, toks, low):
+                    top2 = np.partition(row, -2)[-2:]
+                    margins.append(float(top2[1] - top2[0]))
+                    for name, chosen in (('served', int(tok)),
+                                         ('lower_precision', int(low_tok))):
+                        if int(np.argmax(row)) != chosen:
+                            rule[name].append(margins[-1])
+            said = {
+                'prompts': len(rule_prompts[seed]), 'rows': len(margins),
+                'margin_eps': eps,
+                'rows_under_margin_eps': sum(m <= eps for m in margins),
+                'margin_p10_p25_p50': [float(np.percentile(margins, p))
+                                       for p in (10, 25, 50)]}
+            for name, wrong in rule.items():
+                wrong = sorted(wrong, reverse=True)
+                said[name] = {
+                    'mismatches': len(wrong),
+                    'over_margin_eps': sum(m > eps for m in wrong),
+                    'largest_margins': wrong[:8]}
+            return said
+        rules = {s: rule_of(s) for s in rule_prompts}
+        out['rule'] = rules[q['seed']]
+        if len(rules) > 1:
+            out['rule_by_seed'] = {s: r for s, r in rules.items()
+                                   if s != q['seed']}
         if self.cfg is not FULL:      # the bounds are the chip's
             return out
-        if not out['served']['row_error_p50'] <= PHI_LOGIT_TOL:
+        if not out['served']['row_error_p50'] <= bound:
             raise AssertionError('served logits: median row error over the '
                                  'bound: %s' % json.dumps(out))
-        if not out['lower_precision']['row_error_p50'] > PHI_LOGIT_TOL:
+        if not out['lower_precision']['row_error_p50'] > bound:
             raise AssertionError('the bound would pass the reference one '
                                  'precision down: %s' % json.dumps(out))
-        if out['rule']['served']['over_margin_eps']:
+        if any(r['served']['over_margin_eps'] for r in rules.values()):
             raise AssertionError('a served token fails the cell\'s rule: %s'
                                  % json.dumps(out))
-        if not out['rule']['lower_precision']['over_margin_eps']:
+        if 2 * sum(bool(r['lower_precision']['over_margin_eps'])
+                   for r in rules.values()) <= len(rules):
             raise AssertionError('the cell\'s rule would pass the reference '
-                                 'one precision down: %s' % json.dumps(out))
+                                 'one precision down on half the draws or '
+                                 'more: %s' % json.dumps(out))
         return out
 
     def _logit_phase(self, model, bound):
@@ -1466,8 +1539,8 @@ def main(argv=None):
                     help='directory for artifacts and lines.jsonl')
     ap.add_argument('--cpu-rehearsal', action='store_true',
                     help='toy sizes on the host cpu; never a chip pass')
-    ap.add_argument('--phases', default='ACBMXJQFKG',
-                    help='the phases to run, of A C B M X J Q F K G (C '
+    ap.add_argument('--phases', default='ACBMXJQFHKG',
+                    help='the phases to run, of A C B M X J Q F H K G (C '
                     'needs 4 chips)')
     ap.add_argument('--parent', default=None,
                     help='a checkout of the parent commit (git archive): '
@@ -1505,7 +1578,7 @@ def main(argv=None):
 
     smoke = Smoke(TOY if args.cpu_rehearsal else FULL, args.out, devs[0],
                   len(devs), parent=args.parent)
-    for name in 'ACBMXJQFKG':
+    for name in 'ACBMXJQFHKG':
         if name in args.phases.upper() and (name != 'C' or len(devs) >= 4):
             smoke.phase(name, getattr(smoke, 'phase_' + name.lower()))
     result = {'ok': not args.cpu_rehearsal, 'phases': args.phases.upper(),

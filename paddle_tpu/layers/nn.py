@@ -1676,6 +1676,46 @@ def selective_scan_chunk(x, dt, b, c, a_log, dt_bias, d, state, start,
     return out, state
 
 
+def ssd_step(x, dt, b, c, a_log, dt_bias, d, state, block_table, n_head):
+    """One token a slot through a Mamba-2 (SSD) layer's recurrence
+    (ops/state_space_ops.py): x [max_slots, n_head * d_head] (the convolved
+    input), dt [max_slots, n_head] (the projected step size before
+    `dt_bias`), b, c [max_slots, d_state] (shared by every head), a_log,
+    dt_bias, d [n_head], against the per-slot float32 `state` [max_slots,
+    n_head, d_head, d_state], updated in place where the row is LIVE
+    (causal_conv_step's rule). Returns (out [max_slots, n_head * d_head]
+    float32 — S C + D x, before the gate — state)."""
+    helper = LayerHelper('ssd_step')
+    out = helper.create_variable_for_type_inference('float32')
+    helper.append_op(type='ssd_step',
+                     inputs=_scan_inputs(x, dt, b, c, a_log, dt_bias, d,
+                                         state, BlockTable=block_table),
+                     outputs={'Out': out, 'StateOut': state},
+                     attrs={'n_head': int(n_head)})
+    return out, state
+
+
+def ssd_chunk(x, dt, b, c, a_log, dt_bias, d, state, start, chunk_len,
+              state_slot, n_head, sub_chunk=256):
+    """C tokens a row through ssd_step's recurrence in its dual (matrix)
+    form, sub-chunks of `sub_chunk` positions, from the state of slot
+    `state_slot` [R, 1] (zero where `start` is 0) to the state after
+    `chunk_len` tokens, written back to that slot: x [R, C, n_head *
+    d_head], dt [R, C, n_head], b, c [R, C, d_state]. Returns (out [R, C,
+    n_head * d_head] float32, state)."""
+    helper = LayerHelper('ssd_chunk')
+    out = helper.create_variable_for_type_inference('float32')
+    helper.append_op(type='ssd_chunk',
+                     inputs=_scan_inputs(x, dt, b, c, a_log, dt_bias, d,
+                                         state, Start=start,
+                                         ChunkLen=chunk_len,
+                                         StateSlot=state_slot),
+                     outputs={'Out': out, 'StateOut': state},
+                     attrs={'n_head': int(n_head),
+                            'sub_chunk': int(sub_chunk)})
+    return out, state
+
+
 def _delta_inputs(q, k, v, a, b, a_log, dt_bias, state):
     return {'Q': q, 'K': k, 'V': v, 'A': a, 'B': b, 'ALog': a_log,
             'DtBias': dt_bias, 'State': state}

@@ -655,7 +655,10 @@ def one_chip():
     # phi4_mini_flash_reasoning: differential attention's 40 padded query
     # heads over 10 [k_1 | k_2] tiles of 128, the full and a window layer
     (64, 18433, 16, 1280, (40, 10, 0), 288, jnp.bfloat16),
-    (64, 4161, 16, 1280, (40, 10, 512), 288, jnp.bfloat16)])
+    (64, 4161, 16, 1280, (40, 10, 512), 288, jnp.bfloat16),
+    # granite_4_0_h_micro: 32 query heads of 64 padded to 128 over the 4
+    # tiles [k_2t | k_2t+1] of its 8 K/V heads
+    (64, 18433, 16, 512, (32, 4, 0), 288, jnp.bfloat16)])
 def test_kernel_compiles_for_v5e(one_chip, s, nb, bs, d, h, maxb, dtype):
     def sds(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -684,6 +687,7 @@ def test_kernel_compiles_for_v5e(one_chip, s, nb, bs, d, h, maxb, dtype):
     (2625, 1024, jnp.bfloat16, 768, 1, 128),    # ... a window layer's
     (36865, 640, jnp.bfloat16, 288, 1, 512),    # joyai_llm_flash: latent
     (36865, 512, jnp.bfloat16, 288, 1, 512),    # qwen3_next_80b_a3b
+    (18433, 512, jnp.bfloat16, 288, 1, 512),    # granite_4_0_h_micro
     (16385, 512, np.float32, 128, 4, 128),      # transformer_base_lm
     (16385, 512, np.float32, 128, 1, 32)])
 def test_the_page_write_keeps_the_pool_in_place_on_v5e(one_chip, nb, d,
@@ -817,37 +821,68 @@ def test_gated_delta_chunk_kernel_compiles_for_v5e(one_chip, tokens):
     assert compiled.memory_analysis().temp_size_in_bytes < 40e6
 
 
-# a Mamba layer's selective scan (ops/state_space_ops.py, ISSUE 47;
-# tests/test_phi4_flash.py has the rest) at phi4_mini_flash_reasoning's
-# widths — 64 slots x [16, 5120] float32 of state; the step, and the chunk
-# form over a 512-token and a 128-token slice — for the chip's compiler,
-# which one test file loads
-@pytest.mark.parametrize('tokens', [0, 128, 512])
-def test_selective_scan_compiles_for_v5e(one_chip, tokens):
+# the two state-space op families (ops/state_space_ops.py; the rest is in
+# tests/test_phi4_flash.py, ISSUE 47, and tests/test_granite_hybrid.py, ISSUE
+# 55) at their configurations' widths — the step, and the chunk form over a
+# 512-token and a 128-token slice — for the chip's compiler, which one test
+# file loads
+def _compile_scan_for_v5e(one_chip, step, chunk, tokens, wide, narrow,
+                          state, attrs=()):
+    """The compiled module of a scan op at 64 slots: `step` for tokens 0,
+    `chunk` over one row of `tokens`; {input: trailing shape} `wide` a
+    token, `narrow` a layer, the per-slot `state` donated."""
     import types
-    from paddle_tpu.ops import state_space_ops as sso
-    slots, di, n = 64, 5120, 16
-    ctx = types.SimpleNamespace(attr=lambda n, d=None: d)
+    attrs = dict(attrs)
+    ctx = types.SimpleNamespace(attr=lambda name, d=None: attrs.get(name, d))
 
     def sds(shape, dt=np.float32):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
-    lead = (1, tokens) if tokens else (slots,)
-    ins = {'X': sds(lead + (di,)), 'Dt': sds(lead + (di,)),
-           'B': sds(lead + (n,)), 'C': sds(lead + (n,)),
-           'ALog': sds((n, di)), 'DtBias': sds((di,)), 'D': sds((di,))}
+    lead = (1, tokens) if tokens else (64,)
+    ins = {k: sds(lead + shape) for k, shape in wide.items()}
+    ins.update({k: sds(shape) for k, shape in narrow.items()})
     if tokens:
         ins.update({k: sds((1, 1), np.int32)
                     for k in ('Start', 'ChunkLen', 'StateSlot')})
-        op = sso._selective_scan_chunk
     else:
-        ins['BlockTable'] = sds((slots, 288), np.int32)
-        op = sso._selective_scan_step
+        ins['BlockTable'] = sds((64, 288), np.int32)
+    op = chunk if tokens else step
 
     def fn(ins, state):
         out = op(ctx, dict({k: [v] for k, v in ins.items()}, State=[state]))
         return out['Out'][0], out['StateOut'][0]
-    compiled = jax.jit(fn, donate_argnums=1).lower(
-        ins, sds((slots, n, di))).compile()
+    return jax.jit(fn, donate_argnums=1).lower(
+        ins, sds((64,) + state)).compile()
+
+
+@pytest.mark.parametrize('tokens', [0, 128, 512])
+def test_selective_scan_compiles_for_v5e(one_chip, tokens):
+    """phi4_mini_flash_reasoning's Mamba-1 scan: 64 slots x [16, 5120]
+    float32 of state."""
+    from paddle_tpu.ops import state_space_ops as sso
+    di, n = 5120, 16
+    compiled = _compile_scan_for_v5e(
+        one_chip, sso._selective_scan_step, sso._selective_scan_chunk,
+        tokens, {'X': (di,), 'Dt': (di,), 'B': (n,), 'C': (n,)},
+        {'ALog': (n, di), 'DtBias': (di,), 'D': (di,)}, (n, di))
     # the state (21 MB) is updated in place, and a slice's discretised
     # terms ([512, 5120, 16] float32: 168 MB) are never built
     assert compiled.memory_analysis().temp_size_in_bytes < 64e6
+
+
+@pytest.mark.parametrize('tokens', [0, 128, 512])
+def test_ssd_compiles_for_v5e(one_chip, tokens):
+    """granite_4_0_h_micro's Mamba-2 / SSD ops: 64 slots x [64, 64, 128]
+    float32 of state, 134 MB a layer; the chunk's matrix form in two
+    sub-chunks of 256."""
+    from paddle_tpu.ops import state_space_ops as sso
+    heads, p, n = 64, 64, 128
+    compiled = _compile_scan_for_v5e(
+        one_chip, sso._ssd_step, sso._ssd_chunk, tokens,
+        {'X': (heads * p,), 'Dt': (heads,), 'B': (n,), 'C': (n,)},
+        {'ALog': (heads,), 'DtBias': (heads,), 'D': (heads,)},
+        (heads, p, n), attrs={'n_head': heads, 'sub_chunk': 256})
+    # the states are updated in place: no second copy, and a sub-chunk's
+    # [64, 256, 256] decay (16.8 MB) is the largest temporary
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 64 * heads * p * n * 4
+    assert mem.temp_size_in_bytes < 64e6
