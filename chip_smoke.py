@@ -33,7 +33,9 @@ this one process — a chip belongs to one process at a time):
 
   M  MoE      OLMoE at its published widths (hidden 2048, 16 heads of 128,
               64 experts of 1024 top-8, vocab 50,304; 6 layers, 4 slots of
-              2048 positions, bfloat16 weights and pool): 4 seeded prompts
+              4096 positions under the benchmark's chunks 32 / 128 / 512,
+              bfloat16 weights and pool; fails unless chunk_512 attends
+              with the blocked body, ISSUE 56): 4 seeded prompts
               of 100-1500 tokens prefilled in chunks and 96 tokens decoded
               through the block cache, the programs' LOGITS (fetch 1 of
               chunk and step, through the predictor's own dispatch)
@@ -183,7 +185,10 @@ FULL = {
                       d_model=512, n_head=16, n_kv_head=2, max_blocks=288)},
     'olmoe': dict(vocab=50304, d_model=2048, n_head=16, n_layer=6,
                   n_expert=64, d_expert=1024, top_k=8, max_slots=4,
-                  max_cache_len=2048, block_size=16, chunk_sizes=(32, 128)),
+                  max_cache_len=4096, block_size=16,
+                  chunk_sizes=(32, 128, 512)),
+    # the benchmark's view and chunks (since PR 56): a 1,500-token prompt
+    # is chunk_512 slices at start 0, 512 and a short last one at 1,024
     'olmoe_prompts': (100, 1500), 'olmoe_new': 96, 'olmoe_seeds': (26, 27),
     # K-EXAONE-236B-A23B, the benchmark configuration's share and widths
     'exaone': dict(vocab=19200, d_model=6144, n_head=64, n_kv_head=8,
@@ -591,7 +596,22 @@ class Smoke(object):
 
     # -- the routed block ---------------------------------------------------
     def phase_m(self):
-        return self._logit_phase('olmoe', OLMOE_LOGIT_TOL)
+        """OLMoE at published widths under the benchmark's chunks and
+        view: its largest chunk's attention is the blocked body (scores
+        past the budget, PR 56), the smaller ones' the gathered view."""
+        out = self._logit_phase('olmoe', OLMOE_LOGIT_TOL)
+        took = {prog: by_op.get('kv_block_chunk_attention')
+                for prog, by_op in out['seeds'][0]['attention_bodies'].items()
+                if prog.startswith('chunk_')}
+        out['chunk_attention'] = took
+        largest = 'chunk_%d' % max(self.cfg['olmoe']['chunk_sizes'])
+        if self.cfg is FULL and set(took[largest] or ()) != {'blocked'}:
+            raise AssertionError(
+                '%s attends with %s, not the blocked body (served logits '
+                '%s from the reference at the median row, a seed)'
+                % (largest, json.dumps(took[largest]),
+                   [o['served']['row_error_p50'] for o in out['seeds']]))
+        return out
 
     def phase_x(self):
         """K-EXAONE at published widths: grouped K/V heads and the window
@@ -1062,6 +1082,7 @@ class Smoke(object):
         with DecodingPredictor(art) as pred:
             attention = pred.stats.snapshot()['attention']
             experts = pred.expert_bodies
+            bodies = pred.attention_bodies
             tokens, logits = served_logits(pred, prompts,
                                            self.cfg[model + '_new'])
         if self.cfg is FULL and attention != (
@@ -1126,7 +1147,8 @@ class Smoke(object):
                                        for p in (10, 25, 50)],
                 'served': against_reference(np.concatenate(logits)),
                 'lower_precision': against_reference(np.concatenate(low)),
-                'step_attention': attention, 'expert_bodies': experts}
+                'step_attention': attention, 'expert_bodies': experts,
+                'attention_bodies': bodies}
 
     # -- the kernels -------------------------------------------------------
     def phase_k(self):

@@ -1413,7 +1413,9 @@ class DecodingPredictor(object):
         wrote into the signature, with 'kernel' — the body a module
         holds for a TPU — read as 'jnp' anywhere else (over a latent
         pool, one row a position with the values inside it:
-        'latent_kernel' / 'latent_jnp'); a chunk program's entry also
+        'latent_kernel' / 'latent_jnp'); a chunk program's
+        kv_block_chunk_attention says 'gathered' | 'blocked' (chosen
+        from shapes, the same on every platform), and its entry also
         holds its kv_block_chunk_write ops' body, 'pages' | 'rows'.
         Empty for an artifact exported before the signature carried
         it."""

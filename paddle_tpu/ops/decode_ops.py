@@ -588,25 +588,33 @@ def _quantize_kv_rows(kv):
 # never read) bits cannot perturb any active slot — the masked-
 # idle-slot determinism contract.
 #
-# What reads the pool how (ISSUE 25). Every op here gathers a slot's
-# whole logical view [MAXB * BS, D] through _block_view and runs the
-# jnp expression above over it, masking rows past pos — on those bodies
-# a slot's output is the same BIT FOR BIT however its history is paged.
-# ONE op has a second body: where a program is compiled for a TPU,
-# the step's kv_block_attention is the Pallas kernel of
-# pallas_paged_attention.py, which copies pages 0 .. pos // BS of each
-# slot straight from the pool through its table row and never sees a
-# row past pos. Same function, float32 throughout, another summation
-# order (online softmax): on a TPU the step rounds differently from the
-# chunk / verify / _quant bodies, which keep the gathered
-# view and stay the reference the kernel is tested against. The choice
-# is made from what the lowering sees — the platform the program is
-# compiled for, the pool's dtype and page shape (ppa.supports), a trace
-# mesh — never from a knob. A LATENT pool (ISSUE 40: attr v_width, K and
-# V the same pages, every head reading one row) has a kernel of its own
-# (ppa.latent_paged_attention, ppa.refuses_latent) and, in the chunk
-# form, always the paged jnp body (_chunk_attention_blocked); the op tells its Tracer (lowered_bodies) and
-# export_decode writes it into the signature.
+# What reads the pool how (ISSUE 25). The plain body of every op here
+# gathers a slot's whole logical view [MAXB * BS, D] through _block_view
+# and runs the jnp expression above over it, masking rows past pos — on
+# those bodies a slot's output is the same BIT FOR BIT however its
+# history is paged; the verify and _quant ops have no other. TWO ops
+# have a second body that reads the pages a slot has written and no
+# more. Where a program is compiled for a TPU, the step's
+# kv_block_attention is the Pallas kernel of pallas_paged_attention.py,
+# which copies pages 0 .. pos // BS of each slot straight from the pool
+# through its table row and never sees a row past pos. And the chunk's
+# kv_block_chunk_attention reads pages 0 .. (start + C - 1) //
+# _CHUNK_KEY_BLOCK a key block at a time (_chunk_attention_blocked)
+# wherever the gathered view is the SLOWER body (_gathered_view_fits,
+# ISSUE 56): grouped K/V heads, a window, a latent pool, or scores past
+# _CHUNK_SCORES_BYTES — the view costs by max_cache_len whatever the
+# slot has written, the blocks by what it has. Same function, float32
+# throughout, another summation order (online softmax): such a body
+# rounds differently from the gathered view, which stays the reference
+# both are tested against. The choice is made from what the lowering
+# sees — the platform the program is compiled for, the pool's dtype and
+# page shape (ppa.supports), a trace mesh, the chunk's and the view's
+# shapes — never from a knob. A LATENT pool (ISSUE 40: attr v_width, K
+# and V the same pages, every head reading one row) has a kernel of its
+# own (ppa.latent_paged_attention, ppa.refuses_latent) and, in the chunk
+# form, always the blocked body. Each of the two ops tells its Tracer
+# which body it took (lowered_bodies) and export_decode writes it into
+# the signature.
 # What WRITES it how (ISSUE 54): the step one row a slot (kv_block_write:
 # one index pair a row, nothing to group); a chunk a PAGE at a time where
 # C % BS == 0 and it starts on a page (kv_block_chunk_write), else a row.
@@ -789,10 +797,15 @@ def _chunk_attention_body(ctx, q, kview, vview, start, d):
 
 
 # What the chunk op decides from the shapes it is given
-# (_kv_block_chunk_attention): the float32 [C, n_head, T'] scores of the
-# gathered view — of all its R rows together — may take this many bytes
-# and no more, and the blocked body reads this many positions at a time.
-_CHUNK_SCORES_BYTES = 256 << 20
+# (_kv_block_chunk_attention): the gathered view is taken while the
+# float32 [C, n_head, T'] scores of all its R rows together are this
+# many bytes or fewer — the size above which it is the slower body, not
+# a memory guard (ISSUE 56, a v5e, six layers: at 128 MiB — C 512, 16
+# heads, 4,096 positions — the view takes 3.59 ms whatever `start` is
+# and the blocks 0.55 - 1.93 by `start`; at 32 MiB and under the view is
+# level at `start` 0 and ahead from the second key block on) — and the
+# blocked body reads this many positions at a time.
+_CHUNK_SCORES_BYTES = 64 << 20
 _CHUNK_KEY_BLOCK = 512
 # The ONE row program of a decode spec (chunk_row_program): a dispatch
 # carries at most this many prompt tokens — the largest chunk of the MoE
@@ -808,7 +821,9 @@ def _gathered_view_fits(rows, c, n_head, n_kv, window, view_len):
     """Whether `rows` chunk rows of c positions take the gathered-view
     body (_chunk_attention_body): as many K/V heads as query heads,
     nothing windowed, and the rows' float32 [c, n_head, view_len] scores
-    within _CHUNK_SCORES_BYTES together."""
+    within _CHUNK_SCORES_BYTES together — past it the view, which costs
+    by view_len whatever the slot has written, is slower than the blocks
+    that hold what it has."""
     return (n_kv == n_head and not window
             and 4 * rows * c * n_head * view_len <= _CHUNK_SCORES_BYTES)
 
@@ -993,14 +1008,17 @@ def _kv_block_chunk_attention(ctx, ins):
     step op's (_head_attrs).
 
     Two bodies, chosen from what the lowering sees and never from a
-    knob: the gathered view under one softmax (_chunk_attention_body)
-    where query and K/V heads are as many, nothing is windowed and the
-    view's [R, C, n_head, T'] float32 scores fit _CHUNK_SCORES_BYTES
-    (_gathered_view_fits); else the pages a block of positions at a time
-    under an online softmax (_chunk_attention_blocked): another
-    summation order; a latent pool (attr v_width) takes the second
-    always, both products on operands of the pool's dtype. Only the
-    gathered view has rows: with R = 1 it is
+    knob, and told to the Tracer (lowered_bodies: 'gathered' |
+    'blocked'): the gathered view under one softmax
+    (_chunk_attention_body) where query and K/V heads are as many,
+    nothing is windowed and the view's [R, C, n_head, T'] float32 scores
+    are within _CHUNK_SCORES_BYTES (_gathered_view_fits: the size up to
+    which the whole view is the faster body, whatever the slot has
+    written); else the pages the slot HAS written, a block of positions
+    at a time under an online softmax (_chunk_attention_blocked):
+    another summation order; a latent pool (attr v_width) takes the
+    second always, both products on operands of the pool's dtype. Only
+    the gathered view has rows: with R = 1 it is
     the one-slot expression, unchanged, with more it is that function
     per row (one vmap); the blocked body's trip count depends on
     `start`, so it keeps R = 1 and refuses more by name."""
@@ -1013,6 +1031,11 @@ def _kv_block_chunk_attention(ctx, ins):
     n_head, n_kv, dh, _, window = _head_attrs(ctx, d)
     gathered = (_v_width(ctx, dh) == dh and _gathered_view_fits(
         r, q.shape[1], n_head, n_kv, window, tables.shape[1] * kc.shape[1]))
+    tracer = getattr(ctx, 'tracer', None)
+    if tracer is not None:
+        tracer.lowered_bodies.append(
+            ('kv_block_chunk_attention',
+             'gathered' if gathered else 'blocked'))
     if r == 1:
         table = tables[0]
         if not gathered:

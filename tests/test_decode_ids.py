@@ -641,16 +641,27 @@ def exported(tmp_path_factory):
             said = {size: entry['attention'].pop('gated_delta_chunk', None)
                     for size, entry in sig['chunk'].items()}
             # ... and since PR 54 kv_block_chunk_write: the same
+            chunks = dict(sig['chunk'], **({'rows': sig['chunk_rows']}
+                                           if 'chunk_rows' in sig else {}))
             wrote = {size: entry['attention'].pop('kv_block_chunk_write',
                                                   None)
-                     for size, entry in dict(
-                         sig['chunk'], **(
-                             {'rows': sig['chunk_rows']}
-                             if 'chunk_rows' in sig else {})).items()}
+                     for size, entry in chunks.items()}
+            # ... and since PR 56 kv_block_chunk_attention says
+            # 'gathered' | 'blocked' where the digests hold 'jnp', the
+            # word export_decode gave an op that said nothing: put back
+            # for the digest, and held to its own test
+            attended = {}
+            for size, entry in chunks.items():
+                body = entry['attention'].get('kv_block_chunk_attention')
+                attended[size] = body
+                if body is not None:
+                    entry['attention']['kv_block_chunk_attention'] = {
+                        'jnp': sum(body.values())}
             made[config] = {
                 'modules': modules, 'weights': _digest(*weights),
                 'signature': _digest(json.dumps(sig, sort_keys=True)),
-                'chunk_rule': said, 'chunk_write': wrote}
+                'chunk_rule': said, 'chunk_write': wrote,
+                'chunk_attention': attended}
         return made[config]
     return get
 
@@ -748,6 +759,56 @@ def test_every_chunk_program_says_how_it_writes_its_pages(exported, config):
     sizes = {'8', '16'} | ({'rows'} if config.split('+')[0] in (
         'transformer_base_lm', 'olmoe_1b_7b') and want else set())
     assert wrote == dict.fromkeys(sizes, want)
+
+
+@pytest.mark.parametrize('config', sorted(_PARENT_WEIGHTS_AND_SIGNATURE))
+def test_every_chunk_program_says_which_body_its_attention_took(exported,
+                                                                config):
+    """What PR 56 added to a signature: each chunk program — the row
+    program too — names the body its kv_block_chunk_attention ops
+    lowered, one entry a caching layer: the gathered view where heads are
+    ungrouped, nothing is windowed and the scores are inside the budget
+    (transformer_base_lm and olmoe at these toy sizes, all three of their
+    programs), the blocked body under grouped heads, a window or a latent
+    pool; the int8 pool's _quant form has the one body and says nothing."""
+    took = exported(config)['chunk_attention']
+    base = config.split('+')[0]
+    layers = {'joyai_llm_flash': 3, 'k_exaone_236b_a23b': 5,
+              'qwen3_next_80b_a3b': 1}.get(config, 2)
+    want = (None if config.endswith('+int8') else
+            {'gathered' if base in ('transformer_base_lm', 'olmoe_1b_7b')
+             else 'blocked': layers})
+    sizes = {'8', '16'} | ({'rows'} if base in (
+        'transformer_base_lm', 'olmoe_1b_7b') and want else set())
+    assert took == dict.fromkeys(sizes, want)
+
+
+def test_a_chunk_whose_scores_pass_the_budget_is_exported_blocked(tmp_path):
+    """An OLMoE-shaped spec (ungrouped heads, toy widths) over a view long
+    enough that its LARGEST chunk's scores pass _CHUNK_SCORES_BYTES —
+    4 x 512 x 4 heads x 8,704 positions = 68 MiB — as olmoe_1b_7b's
+    chunk_512 does at published widths: that program's signature entry
+    (what DecodingPredictor.attention_bodies reads) says 'blocked', one
+    an attention layer, the smaller chunks' (4 and 17 MiB) 'gathered',
+    and such a spec holds no row program (its largest chunk is a whole
+    dispatch)."""
+    args = dict(_OLMOE, max_slots=1, max_cache_len=8704, block_size=16,
+                chunk_sizes=(32, 128, 512))
+    art = str(tmp_path / 'art')
+    scope = fluid.core.Scope()
+    with fluid.scope_guard(scope), fluid.unique_name.guard():
+        from models.olmoe import build_decode_spec
+        spec = build_decode_spec(**args)
+        fluid.Executor(fluid.CPUPlace()).run(spec['startup'], scope=scope)
+        export_decode(spec, art, scope=scope, precompile=False)
+    with open(os.path.join(art, decoding._DECODE_SIGNATURE)) as f:
+        sig = json.load(f)
+    assert 'chunk_rows' not in sig
+    n_layer = args['n_layer']
+    took = {size: entry['attention']['kv_block_chunk_attention']
+            for size, entry in sig['chunk'].items()}
+    assert took == {'32': {'gathered': n_layer}, '128': {'gathered': n_layer},
+                    '512': {'blocked': n_layer}}
 
 
 @pytest.mark.parametrize('config', sorted(_ROW_MODULES))

@@ -756,8 +756,79 @@ def test_chunk_rows_exist_over_the_gathered_view_only(monkeypatch):
         decode_ops._one_row_only('kv_block_chunk_write_quant', kv)
 
 
+def _benchmark_chunk_shapes(config):
+    """What a chunk attention of one of the benchmark's configurations is
+    handed, read from benchmark/configs/<config>.json and not typed in:
+    (chunk sizes ascending, n_head, n_kv_head, its window layers' window
+    or 0, max_cache_len). A latent pool (kv_lora_rank) is ONE row a
+    position that every head reads."""
+    import json
+    import os
+    path = os.path.join(os.path.dirname(__file__), '..', 'benchmark',
+                        'configs', config + '.json')
+    with open(path) as f:
+        cfg = json.load(f)
+    n_head = int(cfg.get('num_attention_heads', cfg.get('n_head')))
+    n_kv = (1 if 'kv_lora_rank' in cfg
+            else int(cfg.get('num_key_value_heads', n_head)))
+    return (sorted(int(c) for c in cfg['chunk_sizes']), n_head, n_kv,
+            int(cfg.get('sliding_window') or 0), int(cfg['max_cache_len']))
+
+
+@pytest.mark.parametrize('config,chunk,rows,layer,mib,body', [
+    # ungrouped heads, no window: the scores' size decides
+    ('transformer_base_lm', 32, 1, 'full', 2, 'gathered'),
+    ('transformer_base_lm', 128, 1, 'full', 8, 'gathered'),
+    ('transformer_base_lm', 128, 4, 'full', 32, 'gathered'),  # the row program
+    ('olmoe_1b_7b', 32, 1, 'full', 8, 'gathered'),
+    ('olmoe_1b_7b', 128, 1, 'full', 32, 'gathered'),
+    ('olmoe_1b_7b', 512, 1, 'full', 128, 'blocked'),
+    # grouped heads, a window or a latent pool: blocked at any size
+    ('k_exaone_236b_a23b', 128, 1, 'full', 384, 'blocked'),
+    ('k_exaone_236b_a23b', 512, 1, 'full', 1536, 'blocked'),
+    ('k_exaone_236b_a23b', 128, 1, 'window', 384, 'blocked'),
+    ('k_exaone_236b_a23b', 512, 1, 'window', 1536, 'blocked'),
+    ('joyai_llm_flash', 128, 1, 'full', 72, 'blocked'),
+    ('joyai_llm_flash', 512, 1, 'full', 288, 'blocked'),
+    ('qwen3_next_80b_a3b', 128, 1, 'full', 36, 'blocked'),
+    ('qwen3_next_80b_a3b', 512, 1, 'full', 144, 'blocked'),
+    ('phi4_mini_flash_reasoning', 128, 1, 'full', 90, 'blocked'),
+    ('phi4_mini_flash_reasoning', 512, 1, 'full', 360, 'blocked'),
+    ('phi4_mini_flash_reasoning', 512, 1, 'window', 360, 'blocked'),
+    ('granite_4_0_h_micro', 128, 1, 'full', 72, 'blocked'),
+    ('granite_4_0_h_micro', 512, 1, 'full', 288, 'blocked'),
+])
+def test_the_chunk_attentions_body_follows_from_the_benchmarks_shapes(
+        config, chunk, rows, layer, mib, body):
+    """_gathered_view_fits over (R, C, n_head, n_kv_head, window,
+    view_len) at every chunk program the benchmark's configurations
+    export: the gathered view while the scores are 64 MiB or fewer — the
+    size above which it is the slower body (ISSUE 56's probe) — so
+    olmoe's chunk_512 (128 MiB) is blocked and its smaller chunks and
+    transformer_base_lm's three programs stay as they were; the other
+    five configurations never reach the budget."""
+    from paddle_tpu.ops import decode_ops
+    chunks, n_head, n_kv, window, view_len = _benchmark_chunk_shapes(config)
+    assert chunk in chunks
+    if layer == 'window':
+        assert window
+    else:
+        window = 0
+    assert 4 * rows * chunk * n_head * view_len == mib << 20
+    assert decode_ops._CHUNK_SCORES_BYTES == 64 << 20
+    fits = decode_ops._gathered_view_fits(rows, chunk, n_head, n_kv, window,
+                                          view_len)
+    assert ('gathered' if fits else 'blocked') == body
+
+
 @pytest.mark.parametrize('chunks,heads,want', [
     ((32, 128), (8, 8, 0), (128, 4)),       # transformer_base_lm
+    # ... and the benchmark's own two ungrouped configurations, chunks,
+    # heads and max_cache_len read from their files: the row program
+    # exists on the gathered view alone, and transformer_base_lm's
+    # 4 x 128 rows (32 MiB) are inside the budget of ISSUE 56
+    ('transformer_base_lm', None, (128, 4)),
+    ('olmoe_1b_7b', None, None),            # its largest chunk is 512: R = 1
     ((32, 128, 512), (16, 16, 0), None),    # olmoe_1b_7b as published
     ((128, 512), (64, 8, 128), None),       # k_exaone_236b_a23b
     ((8, 16), (4, 4, 0), (16, 4)),          # the rehearsal's chunks
@@ -769,9 +840,13 @@ def test_chunk_rows_exist_over_the_gathered_view_only(monkeypatch):
 ])
 def test_the_row_programs_shape_follows_from_shapes(chunks, heads, want):
     from paddle_tpu.ops.decode_ops import chunk_row_program
+    view_len = 2048
+    if isinstance(chunks, str):
+        chunks, n_head, n_kv, _, view_len = _benchmark_chunk_shapes(chunks)
+        heads = (n_head, n_kv, 0)
     n_head, n_kv, window = heads
     ops = [('kv_block_chunk_attention', n_head, n_kv, window)] * 3
-    assert chunk_row_program(chunks, ops, 2048) == want
+    assert chunk_row_program(chunks, ops, view_len) == want
     # one layer that cannot take rows, and none can: the int8 pool's form,
     # a window layer among full ones
     assert chunk_row_program(

@@ -111,11 +111,14 @@ def _whole_view_attention(q, kview, vview, start, n_head, n_kv, window):
     return np.einsum('ckgt,tkd->ckgd', w, vh).reshape(1, c, n_head * dh)
 
 
-def _chunk_op(attrs, q, kc, vc, start, table):
-    """kv_block_chunk_attention as a program lowers it."""
+def _chunk_op(attrs, q, kc, vc, start, table, said=None):
+    """kv_block_chunk_attention as a program lowers it; `said`: the list
+    its Tracer keeps of the bodies ops took."""
     import types
     from paddle_tpu.ops import decode_ops
     ctx = types.SimpleNamespace(attr=lambda n, d=None: attrs.get(n, d))
+    if said is not None:
+        ctx.tracer = types.SimpleNamespace(lowered_bodies=said)
     return np.asarray(decode_ops._kv_block_chunk_attention(ctx, {
         'Q': [jnp.asarray(q)], 'KCache': [jnp.asarray(kc)],
         'VCache': [jnp.asarray(vc)],
@@ -173,12 +176,63 @@ def test_chunk_attention_chooses_its_body_from_the_scores_size(
     vc = rng.randn(maxb + 1, bs, n_head * dh).astype(np.float32)
     q = rng.randn(1, c, n_head * dh).astype(np.float32)
     table = np.arange(1, maxb + 1, dtype=np.int32)
-    got = _chunk_op({'n_head': n_head}, q, kc, vc, start, table)
+    said = []
+    got = _chunk_op({'n_head': n_head}, q, kc, vc, start, table, said)
     assert len(calls) == (budget == 'too_large')
+    # ... and says which to its Tracer, one entry an op
+    assert said == [('kv_block_chunk_attention',
+                     'blocked' if calls else 'gathered')]
     want = _whole_view_attention(
         q, kc[1:].reshape(-1, n_head * dh), vc[1:].reshape(-1, n_head * dh),
         start, n_head, n_head, 0)
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize('start,chunk_len', [
+    (0, 32), (32, 32), (64, 32),        # on a key block's edge
+    (8, 32), (40, 32), (56, 32),        # inside a key block, inside a page
+    (32, 9), (80, 1), (0, 20), (48, 25),    # a prompt's last slice
+], ids=lambda v: str(v))
+def test_both_chunk_bodies_are_one_function_at_ungrouped_heads(
+        start, chunk_len, monkeypatch):
+    """OLMoE's head shape — as many K/V heads as query heads, heads of
+    128, a bfloat16 pool — on the body its chunk_512 takes since ISSUE 56
+    (scores past the budget): float32 'highest' over a PERMUTED table,
+    `start` on and off a key block's edge (32 positions here: four pages
+    of 8), and a last slice of chunk_len < C whose pad rows reach past the
+    slot's allocated span, where the table names the trash block and the
+    trash block holds other slots' finite garbage — the real rows against
+    the attention written out over the whole view in float64, and against
+    the gathered-view body at float32's own tolerance."""
+    from paddle_tpu.ops import decode_ops
+    monkeypatch.setattr(decode_ops, '_CHUNK_KEY_BLOCK', 32)
+    rng = np.random.RandomState(1000 * start + chunk_len)
+    bs, maxb, c, n_head, dh = 8, 16, 32, 4, 128
+    d = n_head * dh
+    written = start + chunk_len                 # positions the slot holds
+    own = -(-written // bs)                     # pages allocated to it
+    table = np.zeros(maxb, np.int32)            # past them: the trash block
+    table[:own] = 1 + rng.permutation(3 * maxb)[:own]
+    kc = jnp.asarray(rng.randn(3 * maxb + 1, bs, d), jnp.bfloat16)
+    vc = jnp.asarray(rng.randn(3 * maxb + 1, bs, d), jnp.bfloat16)
+    q = rng.randn(1, c, d).astype(np.float32)
+    kview, vview = (np.asarray(pool, np.float32)[table].reshape(-1, d)
+                    for pool in (kc, vc))
+    want = _whole_view_attention(q, kview, vview, start, n_head, n_head, 0)
+    attrs = {'n_head': n_head}
+    said = []
+    gathered = _chunk_op(attrs, q, kc, vc, start, table, said)
+    monkeypatch.setattr(decode_ops, '_CHUNK_SCORES_BYTES',
+                        4 * c * n_head * maxb * bs - 1)
+    blocked = _chunk_op(attrs, q, kc, vc, start, table, said)
+    assert [body for _, body in said] == ['gathered', 'blocked']
+    assert blocked.dtype == np.float32 and np.isfinite(blocked).all()
+    # a real row attends the slot's own pages only: rows past chunk_len
+    # are pad, and nobody reads them
+    np.testing.assert_allclose(blocked[:, :chunk_len], want[:, :chunk_len],
+                               rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(gathered[:, :chunk_len],
+                               want[:, :chunk_len], rtol=2e-5, atol=2e-6)
 
 
 def test_quantized_chunk_attention_refuses_grouped_heads_by_name():

@@ -148,7 +148,7 @@ def test_the_signature_says_which_bodies_serve(served):
         assert bodies['chunk_%d' % size] == {
             'ssd_chunk': {'jnp': n_mamba},
             'kv_block_chunk_write': {'pages': 2 * n_attn},
-            'kv_block_chunk_attention': {'jnp': n_attn}}
+            'kv_block_chunk_attention': {'blocked': n_attn}}
     assert spec['recurrent']['cache_vars'] == [
         'rec_%s_%d' % (name, i) for i, t in enumerate(kinds) if t == MAMBA
         for name in ('ssm', 'conv')]

@@ -583,7 +583,7 @@ def test_signature_names_the_body_each_attention_op_holds(arts, art, body):
         # ... and the body its K and V writes took (ISSUE 54): chunks of
         # 8 and 16 over pages of 8 are whole pages
         assert chunk['attention'] == {
-            'kv_block_chunk_attention': {'jnp': 2},
+            'kv_block_chunk_attention': {'gathered': 2},
             'kv_block_chunk_write': {'pages': 4}}
     with open(os.path.join(arts[art], decoding._STEP_DIR,
                            'module.jaxexport'), 'rb') as f:
