@@ -79,6 +79,120 @@ def row_width(kv_lora_rank, d_rope):
     return -(-(int(kv_lora_rank) + int(d_rope)) // _LANES) * _LANES
 
 
+def _ffn(b, x, prefix, width, nfd):
+    return b.linear(
+        fluid.layers.swiglu(b.linear(x, prefix + 'gate_w', width, nfd),
+                            b.linear(x, prefix + 'up_w', width, nfd)),
+        prefix + 'down_w', int(b.D), nfd)
+
+
+def _cols(x, lo, hi):
+    return fluid.layers.slice(x, axes=[len(x.shape) - 1], starts=[lo],
+                              ends=[hi])
+
+
+def _per_head(x, w):
+    """x [N, H, a] times w [H, a, b], head by head: [N, H, b]."""
+    L = fluid.layers
+    return L.transpose(L.matmul(L.transpose(x, perm=[1, 0, 2]), w),
+                       perm=[1, 0, 2])
+
+
+def latent_attention(b, xn, i, nfd, pos, n_head, q_lora_rank, kv_lora_rank,
+                     d_nope, d_rope, d_v, rope_theta):
+    """a = W_o Attn of the normed rows xn ([S, D] with nfd 1, [1, C, D]
+    with 2), absorbed, through layer i's latent pool (`b` a
+    DecodeSpecBuilder with v_width = kv_lora_rank and rows row_width(...)
+    wide). SHARED by the models that serve latent attention (this one and
+    models/kimi_linear.py), which differ in two arguments: `q_lora_rank`
+    None is ONE full-rank query projection l<i>_q_w in place of q_a, its
+    norm and q_b — still under the scope q_lora, so the scope holds the
+    query projection whatever its rank and latent_proj_device_share stays
+    whole — and `rope_theta` None leaves the d_rope channels of the query
+    and of the key UNROTATED (no position term: mla_use_nope)."""
+    L = fluid.layers
+    H, R, DN, DR, DV = (int(n_head), int(kv_lora_rank), int(d_nope),
+                        int(d_rope), int(d_v))
+    W = row_width(R, DR)
+    pad = W - R - DR
+    scale = float(DN + DR) ** -0.5
+    p = 'l%d_' % i
+    lead = [int(m) for m in xn.shape[:-1]]
+    n = math.prod(lead)
+
+    def rotate(x, heads):
+        if rope_theta is None:
+            return x
+        return L.rotary_embedding(x, pos, heads, rope_theta,
+                                  interleave=True)
+
+    with fluid.name_scope('q_lora'):
+        if q_lora_rank is None:
+            q = b.linear(xn, p + 'q_w', H * (DN + DR), nfd)
+        else:
+            cq = b.norm(b.linear(xn, p + 'q_a_w', int(q_lora_rank), nfd),
+                        p + 'q_a_norm_w')
+            q = b.linear(cq, p + 'q_b_w', H * (DN + DR), nfd)
+        q = L.reshape(q, shape=[n, H, DN + DR])
+        q_rope = L.reshape(rotate(
+            L.reshape(_cols(q, DN, DN + DR), shape=[n, H * DR]), H),
+            shape=[n, H, DR])
+    with fluid.name_scope('kv_down'):
+        ckr = b.linear(xn, p + 'kv_a_w', R + DR, nfd)
+        row = [b.norm(_cols(ckr, 0, R), p + 'kv_a_norm_w'),
+               rotate(_cols(ckr, R, R + DR), 1)]
+        if pad:
+            row.append(L.fill_constant(lead + [pad], 'float32', 0.0))
+        (pool,) = b.write(i, L.concat(row, axis=len(lead)))
+    # the published kv_b_proj, [r, H x (dn + dv)], taken apart per head
+    w_ukv = L.reshape(b.matrix(p + 'kv_b_w', [R, H * (DN + DV)]),
+                      shape=[R, H, DN + DV])
+    with fluid.name_scope('q_absorb'):
+        q_lat = _per_head(_cols(q, 0, DN), L.transpose(
+            _cols(w_ukv, 0, DN), perm=[1, 2, 0]))        # [n, H, r]
+        parts = [q_lat, q_rope]
+        if pad:
+            parts.append(L.fill_constant([n, H, pad], 'float32', 0.0))
+        q_abs = L.reshape(L.concat(parts, axis=2),
+                          shape=lead + [H * W])
+    o_lat = b.attend(i, q_abs, pool, pool, H, n_kv_head=1, scale=scale)
+    with fluid.name_scope('v_expand'):
+        o = _per_head(L.reshape(o_lat, shape=[n, H, R]), L.transpose(
+            _cols(w_ukv, DN, DN + DV), perm=[1, 0, 2]))  # [n, H, dv]
+        o = L.reshape(o, shape=lead + [H * DV])
+    return b.linear(o, p + 'o_w', int(b.D), nfd)
+
+
+def sigmoid_routed_ffn(b, hn, i, nfd, d_dense, first_dense, n_expert,
+                       d_expert, top_k, n_shared, bias_std, **routed):
+    """FFN of layer i over the normed rows hn: a dense SwiGLU of width
+    d_dense below `first_dense`, else models/exaone_moe.py's routed layer
+    op for op — a sigmoid router with a selection bias (seeded N(0,
+    bias_std)) over `n_expert` experts of width d_expert, `routed` being
+    moe_topk_ffn's own norm_topk_prob, routed_scaling_factor, num_held and
+    expert_offset — plus an unweighted shared expert of width n_shared *
+    d_expert. Shared as latent_attention is."""
+    L = fluid.layers
+    PA = fluid.ParamAttr
+    Normal = fluid.initializer.NormalInitializer
+    p = 'l%d_' % i
+    if i < first_dense:
+        return _ffn(b, hn, p + 'ff_', int(d_dense), nfd)
+    m = L.moe_topk_ffn(
+        hn, n_expert, d_expert, top_k, dtype=b.weights_dtype,
+        param_attr=PA(name=p + 'moe', trainable=False,
+                      initializer=Normal(0.0, b.init_std)),
+        scoring='sigmoid',
+        router_bias=PA(name=p + 'moe_router_bias', trainable=False,
+                       initializer=Normal(0.0, bias_std)), **routed)
+    if n_shared:
+        with fluid.name_scope('shared_expert'):
+            m = L.elementwise_add(
+                m, _ffn(b, hn, p + 'shared_',
+                        int(n_shared) * int(d_expert), nfd))
+    return m
+
+
 def build_decode_spec(vocab=128, d_model=64, n_head=4, q_lora_rank=24,
                       kv_lora_rank=32, d_nope=16, d_rope=8, d_v=16,
                       n_layer=3, d_dense=96, first_dense=1, n_expert=16,
@@ -111,97 +225,28 @@ def build_decode_spec(vocab=128, d_model=64, n_head=4, q_lora_rank=24,
     held = int(n_expert if n_held is None else n_held)
     if not 1 <= top_k <= n_expert:
         raise ValueError('top_k must be in [1, n_expert]')
-    W = row_width(R, DR)
-    pad = W - R - DR
-    scale = float(DN + DR) ** -0.5
     L = fluid.layers
-    PA = fluid.ParamAttr
-    Normal = fluid.initializer.NormalInitializer
-
-    def ffn(b, x, prefix, width, nfd):
-        return b.linear(L.swiglu(b.linear(x, prefix + 'gate_w', width, nfd),
-                                 b.linear(x, prefix + 'up_w', width, nfd)),
-                        prefix + 'down_w', D, nfd)
-
-    def cols(x, lo, hi):
-        return L.slice(x, axes=[len(x.shape) - 1], starts=[lo], ends=[hi])
-
-    def per_head(x, w):
-        """x [N, H, a] times w [H, a, b], head by head: [N, H, b]."""
-        return L.transpose(L.matmul(L.transpose(x, perm=[1, 0, 2]), w),
-                           perm=[1, 0, 2])
-
-    def attention(b, xn, i, nfd, pos):
-        """a = W_o Attn of the normed rows xn ([S, D] with nfd 1,
-        [1, C, D] with 2), absorbed, through layer i's latent pool."""
-        p = 'l%d_' % i
-        lead = [int(m) for m in xn.shape[:-1]]
-        n = math.prod(lead)
-        with fluid.name_scope('q_lora'):
-            cq = b.norm(b.linear(xn, p + 'q_a_w', int(q_lora_rank), nfd),
-                        p + 'q_a_norm_w')
-            q = L.reshape(b.linear(cq, p + 'q_b_w', H * (DN + DR), nfd),
-                          shape=[n, H, DN + DR])
-            q_rope = L.reshape(L.rotary_embedding(
-                L.reshape(cols(q, DN, DN + DR), shape=[n, H * DR]), pos, H,
-                rope_theta, interleave=True), shape=[n, H, DR])
-        with fluid.name_scope('kv_down'):
-            ckr = b.linear(xn, p + 'kv_a_w', R + DR, nfd)
-            row = [b.norm(cols(ckr, 0, R), p + 'kv_a_norm_w'),
-                   L.rotary_embedding(cols(ckr, R, R + DR), pos, 1,
-                                      rope_theta, interleave=True)]
-            if pad:
-                row.append(L.fill_constant(lead + [pad], 'float32', 0.0))
-            (pool,) = b.write(i, L.concat(row, axis=len(lead)))
-        # the published kv_b_proj, [r, H x (dn + dv)], taken apart per head
-        w_ukv = L.reshape(b.matrix(p + 'kv_b_w', [R, H * (DN + DV)]),
-                          shape=[R, H, DN + DV])
-        with fluid.name_scope('q_absorb'):
-            q_lat = per_head(cols(q, 0, DN), L.transpose(
-                cols(w_ukv, 0, DN), perm=[1, 2, 0]))        # [n, H, r]
-            parts = [q_lat, q_rope]
-            if pad:
-                parts.append(L.fill_constant([n, H, pad], 'float32', 0.0))
-            q_abs = L.reshape(L.concat(parts, axis=2),
-                              shape=lead + [H * W])
-        o_lat = b.attend(i, q_abs, pool, pool, H, n_kv_head=1, scale=scale)
-        with fluid.name_scope('v_expand'):
-            o = per_head(L.reshape(o_lat, shape=[n, H, R]), L.transpose(
-                cols(w_ukv, DN, DN + DV), perm=[1, 0, 2]))  # [n, H, dv]
-            o = L.reshape(o, shape=lead + [H * DV])
-        return b.linear(o, p + 'o_w', D, nfd)
 
     def block(b, x, i, nfd, pos):
         p = 'l%d_' % i
         with fluid.name_scope('latent_attention'):
-            a = attention(b, b.norm(x, p + 'input_norm_w'), i, nfd, pos)
+            a = latent_attention(
+                b, b.norm(x, p + 'input_norm_w'), i, nfd, pos, n_head=H,
+                q_lora_rank=q_lora_rank, kv_lora_rank=R, d_nope=DN,
+                d_rope=DR, d_v=DV, rope_theta=rope_theta)
         h = L.elementwise_add(x, a)
         hn = b.norm(h, p + 'post_attn_norm_w')
-        if i < first_dense:
-            m = ffn(b, hn, p + 'ff_', int(d_dense), nfd)
-        else:
-            m = L.moe_topk_ffn(
-                hn, n_expert, d_expert, top_k,
-                norm_topk_prob=norm_topk_prob, dtype=weights_dtype,
-                param_attr=PA(name=p + 'moe', trainable=False,
-                              initializer=Normal(0.0, init_std)),
-                scoring='sigmoid',
-                router_bias=PA(name=p + 'moe_router_bias', trainable=False,
-                               initializer=Normal(0.0, bias_std)),
-                routed_scaling_factor=routed_scaling_factor,
-                num_held=held, expert_offset=expert_offset)
-            if n_shared:
-                with fluid.name_scope('shared_expert'):
-                    m = L.elementwise_add(
-                        m, ffn(b, hn, p + 'shared_',
-                               int(n_shared) * int(d_expert), nfd))
-        return L.elementwise_add(h, m)
+        return L.elementwise_add(h, sigmoid_routed_ffn(
+            b, hn, i, nfd, d_dense, first_dense, n_expert, d_expert, top_k,
+            n_shared, bias_std, norm_topk_prob=norm_topk_prob,
+            routed_scaling_factor=routed_scaling_factor, num_held=held,
+            expert_offset=expert_offset))
 
     def logits(b, x):
         return b.linear(b.norm(x, 'final_norm_w'), 'lm_head_w', vocab, 1)
 
     return DecodeSpecBuilder(
-        vocab=vocab, d_model=D, kv_width=W, n_layer=n_layer,
+        vocab=vocab, d_model=D, kv_width=row_width(R, DR), n_layer=n_layer,
         max_slots=max_slots, max_cache_len=max_cache_len,
         block_size=block_size, chunk_sizes=chunk_sizes,
         num_blocks=num_blocks, eos_id=eos_id,

@@ -333,7 +333,7 @@ class DecodeStats(object):
     TICK_ROW = np.dtype([(k, np.float64) for k in (
         't0', 'wall_s', 'cpu_s', 'wait_s', 'gc_s', 'dispatches', 'rows',
         'cpu_wall_s', 'tick', 'slices', 'deferred', 'emit_t', 'emit_rows',
-        'wait_step_s', 'wait_slice_s', 'slice_tokens')])
+        'wait_step_s', 'wait_slice_s', 'slice_tokens', 'slices_carried')])
     TICK_RING = 1 << 16
     CPU_EVERY_S = 0.02
     # one row a request, written where it ends: `request` its sequence
@@ -429,6 +429,11 @@ class DecodeStats(object):
         # for it. slices_deferred / (slices_deferred + chunk_slices) is
         # the share of (slice, tick) pairs that ended in a wait
         self.slices_deferred = 0
+        # slices dispatched with start != 0: a prompt's second and later
+        # ones, which attend the pages the earlier ones wrote and, on a
+        # recurrent layer, start from the state and the convolution tail
+        # they left (slices_carried / chunk_slices: the share that carry)
+        self.slices_carried = 0
         # slices whose result the host read: the prompts' last ones.
         # 1 - slice_reads / chunk_slices of the slices cost no wait
         self.slice_reads = 0
@@ -486,6 +491,7 @@ class DecodeStats(object):
             self.chunk_slices = 0
             self.chunk_dispatches = 0
             self.slices_deferred = 0
+            self.slices_carried = 0
             self.slice_reads = 0
             self.steps_ahead = 0
             self.wasted_rows = 0
@@ -512,7 +518,7 @@ class DecodeStats(object):
 
     def log_tick(self, tick, t0, wall_s, wait_s, gc_s, dispatches, rows,
                  slices=0, deferred=0, emit_t=float('nan'), emit_rows=0,
-                 wait_slice_s=0.0, slice_tokens=0):
+                 wait_slice_s=0.0, slice_tokens=0, slices_carried=0):
         """One tick the scheduler was busy in, logged from its thread at
         the tick's end: its wall time into busy_s and its TICK_ROW into
         the ring, under the one hold of the lock the tick has always
@@ -530,7 +536,7 @@ class DecodeStats(object):
             self._ticks[self._n_ticks & (self.TICK_RING - 1)] = (
                 t0, wall_s, cpu, wait_s, gc_s, dispatches, rows, span, tick,
                 slices, deferred, emit_t, emit_rows, wait_s - wait_slice_s,
-                wait_slice_s, slice_tokens)
+                wait_slice_s, slice_tokens, slices_carried)
             self._n_ticks += 1
 
     def log_request(self, row, **counts):
@@ -659,6 +665,7 @@ class DecodeStats(object):
                     'chunk_slices': int(self.chunk_slices),
                     'chunk_dispatches': int(self.chunk_dispatches),
                     'slices_deferred': int(self.slices_deferred),
+                    'slices_carried': int(self.slices_carried),
                     'slice_reads': int(self.slice_reads),
                     'steps_ahead': int(self.steps_ahead),
                     'wasted_rows': int(self.wasted_rows),
@@ -2084,6 +2091,7 @@ class DecodingPredictor(object):
         made0 = stats.steps + stats.verify_steps + stats.chunk_dispatches
         rows0 = stats.tokens
         slices0, deferred0 = stats.chunk_slices, stats.slices_deferred
+        carried0 = stats.slices_carried
         busy = self._unread is not None
         with _span('decode/expire'):
             if self._draining:
@@ -2125,7 +2133,8 @@ class DecodingPredictor(object):
                 stats.slices_deferred - deferred0,
                 self._emit_t if emit_rows else _NAN, emit_rows,
                 self._wait_slice_s - wait_slice0,
-                self._slice_tokens - tokens0)
+                self._slice_tokens - tokens0,
+                stats.slices_carried - carried0)
 
     def _results_first(self):
         """Whether the host must see what a tick dispatched before it can
@@ -2422,9 +2431,11 @@ class DecodingPredictor(object):
             due.append((req, size, min(size, remaining),
                         size >= remaining,
                         R > 1 and size == largest and req.beam is None))
-        if len(due) < len(admitting):
+        carried = sum(req.next_start != 0 for req, *_ in due)
+        if len(due) < len(admitting) or carried:
             with self.stats._lock:
                 self.stats.slices_deferred += len(admitting) - len(due)
+                self.stats.slices_carried += carried
         rowed = sum(rides for *_, rides in due)
         rowed -= rowed % R == 1
         lasts, group, taken = [], [], 0

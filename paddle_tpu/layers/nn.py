@@ -1729,7 +1729,10 @@ def gated_delta_step(q, k, v, a, b, a_log, dt_bias, state, block_table,
     and write-strength projections; a_log, dt_bias [n_value_head]),
     against the per-slot float32 `state` [max_slots, n_value_head, d_k,
     d_v], updated in place where the row is LIVE (causal_conv_step's
-    rule). q and k are L2-normalised per head inside the op. Returns
+    rule). q and k are L2-normalised per head inside the op. With a
+    [max_slots, n_value_head * d_k] and dt_bias [n_value_head * d_k] the
+    decay is PER KEY CHANNEL (Kimi Delta Attention: the state's rows
+    decay apart; a_log stays one scalar a head). Returns
     (out [max_slots, n_value_head * d_v] float32, state)."""
     helper = LayerHelper('gated_delta_step')
     out = helper.create_variable_for_type_inference('float32')
@@ -1749,7 +1752,10 @@ def gated_delta_chunk(q, k, v, a, b, a_log, dt_bias, state, start,
     `sub_chunk` tokens), from the state of slot `state_slot` [R, 1] (zero
     where `start` is 0) to the state after `chunk_len` tokens, written
     back to that slot: q, k [R, C, n_key_head * d_k], v [R, C,
-    n_value_head * d_v], a, b [R, C, n_value_head]. Returns (out [R, C,
+    n_value_head * d_v], a, b [R, C, n_value_head] (a [R, C,
+    n_value_head * d_k]: gated_delta_step's per-channel decay, exact while
+    `sub_chunk` tokens' summed log-decay stays under
+    ops/linear_attention_ops.CHANNEL_DECAY_LIMIT). Returns (out [R, C,
     n_value_head * d_v] float32, state)."""
     helper = LayerHelper('gated_delta_chunk')
     out = helper.create_variable_for_type_inference('float32')

@@ -28,6 +28,31 @@ unit lower triangular solve),
     o     = (q e^G) S + lower(q k^T * e^{G_i - G_j}) v_new
     S    <- e^{G_last} S + (k e^{G_last - G})^T v_new
 
+A PER-CHANNEL DECAY (Kimi Delta Attention, arXiv:2510.26692; models/
+kimi_linear.py). Where A comes [..., Hv * dk] (DtBias [Hv * dk], ALog still
+[Hv]) g is a VECTOR over a head's d_k key channels and `S <- e^g S` scales
+the state's ROWS, S <- Diag(e^g) S; everything else is the rule above. The
+step folds the decay into the key and the query it reads the old state with
+(m = S^T (k e^g), o = S^T (q e^g) + (k . q) delta). In the chunked form
+(delta_chunk_channels) the decay e^{G_i - G_j} no longer factors out of
+k k^T and q k^T — it lies INSIDE the contraction over d_k — so the products
+are taken between (k beta e^G), (q e^G) and (k e^{-G}), G counted from the
+sub-chunk's first token: a REFERENCE POINT a sub-chunk short enough that
+e^{-G} stays finite. float32 holds e^88; the chunked form is exact while a
+sub-chunk's summed log-decay stays under CHANNEL_DECAY_LIMIT = 80 in every
+channel (64 tokens at e^g >= 0.287 a token; the strongest decay models/
+kimi_linear.py seeds is ~0.55 a token, 38 over a sub-chunk), and a factor
+that underflows on the other side (e^G < 1e-35) multiplies a pair whose
+true weight is as small. PAST THE LIMIT e^{-G} would reach inf and 0 * inf
+poison the state: the decay is data (a projection of the token), so the op
+looks — min G over the call, one reduction — and a call with any channel
+past the limit takes the TOKEN-BY-TOKEN recurrence instead (delta_step
+scanned over the chunk behind a lax.cond: a few times slower, exact at any
+decay, e^g <= 1 is all it raises). Weights that forget faster than the
+seeds are served right, not fast. tests/test_kimi_linear.py holds the
+bodies to the recurrence at the strongest seeded decay, at the limit itself
+and past it.
+
 WHO OWNS A STATE ROW. The step's row r IS slot r, and a row steps its state
 only where it is LIVE — its block table's first entry is not the trash
 block (inference/kv_blocks.TRASH_BLOCK, 0): an idle row of the step rides
@@ -60,6 +85,10 @@ from jax.interpreters import mlir
 from ..core.registry import register
 
 _SUB_CHUNK = 64
+# a sub-chunk's summed per-channel log-decay up to which
+# delta_chunk_channels keeps its chunked form (e^{-G}: float32 overflows at
+# e^88.7); past it the call takes the recurrence
+CHANNEL_DECAY_LIMIT = 80.0
 _HI = jax.lax.Precision.HIGHEST
 _TRASH_BLOCK = 0        # inference/kv_blocks.TRASH_BLOCK
 
@@ -89,7 +118,12 @@ def l2_normalize(x, eps=1e-6):
 
 
 def decay_and_strength(a, b, a_log, dt_bias):
-    """(g, beta) of value heads from their projections a, b [..., Hv]."""
+    """(g, beta) of value heads from their projections a, b [..., Hv]; with
+    a [..., Hv * dk] (and dt_bias [Hv * dk]) g is per key CHANNEL,
+    [..., Hv, dk], a head's channels under its one a_log."""
+    if a.shape[-1] != a_log.shape[0]:
+        a = a.reshape(a.shape[:-1] + (a_log.shape[0], -1))
+        a_log, dt_bias = a_log[:, None], dt_bias.reshape(a.shape[-2:])
     g = -jnp.exp(a_log.astype(jnp.float32)) * _softplus(
         a.astype(jnp.float32) + dt_bias.astype(jnp.float32))
     return g, jax.lax.logistic(b.astype(jnp.float32))
@@ -131,7 +165,16 @@ def live_rows(table):
 def delta_step(q, k, v, g, beta, state):
     """One token a row: q, k [S, Hv, dk], v [S, Hv, dv], g, beta [S, Hv],
     state [S, Hv, dk, dv] float32 -> (o [S, Hv, dv], new state). Both
-    products read the OLD state."""
+    products read the OLD state. g [S, Hv, dk] decays a head's state row
+    by row (the module's PER-CHANNEL DECAY)."""
+    if g.ndim == k.ndim:
+        decay = jnp.exp(g)                                  # [S, Hv, dk]
+        m = jnp.sum(state * (k * decay)[..., :, None], axis=-2)
+        sq = jnp.sum(state * (q * decay)[..., :, None], axis=-2)
+        delta = beta[..., None] * (v - m)
+        o = sq + jnp.sum(k * q, axis=-1, keepdims=True) * delta
+        return o, (state * decay[..., :, None]
+                   + k[..., :, None] * delta[..., None, :])
     decay = jnp.exp(g)[..., None]                           # [S, Hv, 1]
     m = jnp.sum(state * k[..., :, None], axis=-2) * decay   # (e^g S)^T k
     sq = jnp.sum(state * q[..., :, None], axis=-2) * decay
@@ -184,6 +227,34 @@ mlir.register_lowering(
     _solve_p, mlir.lower_fun(_solve_by_rows, multiple_results=False))
 
 
+def _sub_chunks_of(R, C, H, sub):
+    """(n, split): the tokens of a sub-chunk of a chunk of C, and the
+    function that lays [R, C, H, ...] out as [C / n, R, H, n, ...]."""
+    n = min(int(sub), C)
+    if C % n:
+        raise ValueError('a chunk of %d tokens is no whole number of '
+                         'sub-chunks of %d' % (C, n))
+
+    def split(x):
+        x = x.reshape((R, C // n, n, H) + x.shape[3:])
+        return x.transpose((1, 0, 3, 2) + tuple(range(4, x.ndim)))
+    return n, split
+
+
+def _sub_chunk(S, xs):
+    """One sub-chunk of either chunked form from the state S [R, H, dk,
+    dv] it starts at: (the state it leaves, its outputs). s_decay is
+    [.., 1, 1] under a head's scalar decay and [.., dk, 1] per channel."""
+    value_c, k_cum_c, qk_c, q_dec_c, k_tail_c, s_decay_c = xs
+    v_new = value_c - jnp.einsum('rhnk,rhkv->rhnv', k_cum_c, S,
+                                 precision=_HI)
+    o = (jnp.einsum('rhnk,rhkv->rhnv', q_dec_c, S, precision=_HI)
+         + jnp.einsum('rhij,rhjv->rhiv', qk_c, v_new, precision=_HI))
+    S = S * s_decay_c + jnp.einsum('rhnk,rhnv->rhkv', k_tail_c, v_new,
+                                   precision=_HI)
+    return S, o
+
+
 def delta_chunk(q, k, v, g, beta, state, sub=_SUB_CHUNK):
     """C tokens a row from `state` [R, Hv, dk, dv] on: q, k [R, C, Hv, dk],
     v [R, C, Hv, dv], g, beta [R, C, Hv] -> (o [R, C, Hv, dv], the state
@@ -191,16 +262,7 @@ def delta_chunk(q, k, v, g, beta, state, sub=_SUB_CHUNK):
     state as it is."""
     R, C, H, dk = k.shape
     dv = v.shape[-1]
-    n = min(int(sub), C)
-    if C % n:
-        raise ValueError('a chunk of %d tokens is no whole number of '
-                         'sub-chunks of %d' % (C, n))
-    nc = C // n
-
-    def split(x):       # [R, C, H, ...] -> [nc, R, H, n, ...]
-        x = x.reshape((R, nc, n, H) + x.shape[3:])
-        return x.transpose((1, 0, 3, 2) + tuple(range(4, x.ndim)))
-
+    n, split = _sub_chunks_of(R, C, H, sub)
     q, k, v = split(q), split(k), split(v)
     g, beta = split(g), split(beta)                         # [nc, R, H, n]
     G = jax.lax.cumsum(g, axis=g.ndim - 1)
@@ -224,21 +286,67 @@ def delta_chunk(q, k, v, g, beta, state, sub=_SUB_CHUNK):
     k_tail = k * jnp.exp(g_last - G)[..., None]
     s_decay = jnp.exp(g_last)[..., None]                    # [.., 1, 1]
 
-    def one(S, xs):
-        value_c, k_cum_c, qk_c, q_dec_c, k_tail_c, s_decay_c = xs
-        v_new = value_c - jnp.einsum('rhnk,rhkv->rhnv', k_cum_c, S,
-                                     precision=_HI)
-        o = (jnp.einsum('rhnk,rhkv->rhnv', q_dec_c, S, precision=_HI)
-             + jnp.einsum('rhij,rhjv->rhiv', qk_c, v_new, precision=_HI))
-        S = S * s_decay_c + jnp.einsum('rhnk,rhnv->rhkv', k_tail_c, v_new,
-                                       precision=_HI)
-        return S, o
-
-    state, o = jax.lax.scan(one, state,
+    # a function of this call's own: jax lowers a scan body once per
+    # function object, and the exported programs are pinned with one body
+    # a layer (tests/test_decode_ids.py)
+    state, o = jax.lax.scan(lambda S, xs: _sub_chunk(S, xs), state,
                             (value, k_cum, qk, q_dec, k_tail, s_decay))
     # [nc, R, H, n, dv] -> [R, C, H, dv]
     o = o.transpose(1, 0, 3, 2, 4).reshape(R, C, H, dv)
     return o, state
+
+
+def _recurrence(q, k, v, g, beta, state):
+    """delta_step over C tokens a row, one at a time: q, k, g [R, C, Hv,
+    dk], v [R, C, Hv, dv], beta [R, C, Hv] -> (o [R, C, Hv, dv], the state
+    after). Exact at any decay."""
+    def one(S, xs):
+        o, S = delta_step(*xs, S)
+        return S, o
+    state, o = jax.lax.scan(
+        one, state, tuple(x.swapaxes(0, 1) for x in (q, k, v, g, beta)))
+    return o.swapaxes(0, 1), state
+
+
+def delta_chunk_channels(q, k, v, g, beta, state, sub=_SUB_CHUNK):
+    """delta_chunk under a PER-CHANNEL decay g [R, C, Hv, dk] (the
+    module's paragraph of that name): the same sub-chunks, solve and scan,
+    with the decay inside every contraction over d_k and the state decayed
+    row by row — exact while `sub` tokens' summed log-decay stays under
+    CHANNEL_DECAY_LIMIT in every channel; a call that passes it anywhere
+    returns _recurrence's answer instead (the chunked form's, which may
+    hold inf by then, is dropped)."""
+    R, C, H, dk = k.shape
+    dv = v.shape[-1]
+    n, split = _sub_chunks_of(R, C, H, sub)
+    whole = (q, k, v, g, beta, state)
+    q, k, v, g, beta = split(q), split(k), split(v), split(g), split(beta)
+    G = jax.lax.cumsum(g, axis=g.ndim - 2)                  # [.., n, dk]
+    shrink = jnp.exp(G)
+    k_grown = k * jnp.exp(-G)
+    k_beta = k * beta[..., None] * shrink                   # k beta e^G
+    q_dec = q * shrink
+    idx = jnp.arange(n)
+    kk = jnp.einsum('...id,...jd->...ij', k_beta, k_grown, precision=_HI)
+    unit = _where(idx[:, None] > idx[None, :], kk, 0.0) \
+        + jnp.eye(n, dtype=jnp.float32)
+    solved = _solve_p.bind(
+        unit, jnp.concatenate([v * beta[..., None], k_beta], axis=-1))
+    value, k_cum = solved[..., :dv], solved[..., dv:]
+    qk = _where(idx[:, None] >= idx[None, :],
+                jnp.einsum('...id,...jd->...ij', q_dec, k_grown,
+                           precision=_HI), 0.0)
+    g_last = G[..., -1:, :]                                 # [.., 1, dk]
+    k_tail = k * jnp.exp(g_last - G)
+    s_decay = jnp.exp(g_last).swapaxes(-1, -2)              # [.., dk, 1]
+
+    state, o = jax.lax.scan(_sub_chunk, state,
+                            (value, k_cum, qk, q_dec, k_tail, s_decay))
+    o = o.transpose(1, 0, 3, 2, 4).reshape(R, C, H, dv)
+    # the chunked form's results are operands of the branch that keeps
+    # them, so its time stays outside the conditional in a device trace
+    return jax.lax.cond(jnp.min(G) >= -CHANNEL_DECAY_LIMIT,
+                        lambda: (o, state), lambda: _recurrence(*whole))
 
 
 def _slot_rows(state, slot, start):
@@ -263,6 +371,7 @@ def _gated_delta_step(ctx, ins):
     (float32; Out aliases it, in place on the persistable state),
     BlockTable [S, MAXB]: a row whose first entry is the trash block is
     idle and its state is left as it is. Out [S, Hv * dv] float32.
+    A [S, Hv * dk] with DtBias [Hv * dk] is the PER-CHANNEL decay.
 
     Two bodies (ops/pallas_delta_rule.py): where the state is float32 in
     whole tiles and the trace has no mesh, a primitive whose TPU rule is
@@ -280,7 +389,8 @@ def _gated_delta_step(ctx, ins):
                                  ins['ALog'][0], ins['DtBias'][0])
     live = live_rows(ins['BlockTable'][0])
     tracer = getattr(ctx, 'tracer', None)
-    kernel = (tracer is not None and pdr.refuses(state) is None
+    kernel = (tracer is not None
+              and pdr.refuses(state, per_channel=g.ndim == k.ndim) is None
               and current_trace_mesh() is None)
     if tracer is not None:
         tracer.lowered_bodies.append(
@@ -297,7 +407,12 @@ def _gated_delta_chunk(ctx, ins):
     ChunkLen tokens, written back to that slot. Q, K [R, C, Hk * dk], V
     [R, C, Hv * dv], A, B [R, C, Hv], ALog, DtBias [Hv], State [S, Hv,
     dk, dv], Start, ChunkLen, StateSlot [R, 1] int32. Out [R, C, Hv *
-    dv] float32 (rows from ChunkLen on are unread).
+    dv] float32 (rows from ChunkLen on are unread). A [R, C, Hv * dk]
+    with DtBias [Hv * dk] is the PER-CHANNEL decay, whose one body is
+    delta_chunk_channels ('jnp': the kernel holds a sub-chunk's decay as
+    an [n, n] matrix outside its products, which a decay inside the
+    contraction over d_k does not allow; a call whose decay passes
+    CHANNEL_DECAY_LIMIT takes the recurrence token by token).
 
     Two bodies, chosen as gated_delta_step chooses (ops/
     pallas_delta_chunk.py `refuses`: a float32 state, heads of whole
@@ -313,7 +428,9 @@ def _gated_delta_chunk(ctx, ins):
     sub = int(ctx.attr('sub_chunk', _SUB_CHUNK))
     state = ins['State'][0]
     tracer = getattr(ctx, 'tracer', None)
+    per_channel = ins['A'][0].shape[-1] != hv
     kernel = (tracer is not None and current_trace_mesh() is None
+              and not per_channel
               and pdc.refuses(state, ins['Q'][0],
                               ins['Q'][0].shape[1]) is None)
     if tracer is not None:
@@ -326,7 +443,8 @@ def _gated_delta_chunk(ctx, ins):
     start, clen, slot = (ins[n][0].reshape(-1)
                          for n in ('Start', 'ChunkLen', 'StateSlot'))
     real = (jnp.arange(k.shape[1])[None, :] < clen[:, None])[..., None]
-    g, beta = _where(real, g, 0.0), _where(real, beta, 0.0)
+    g = _where(real[..., None] if per_channel else real, g, 0.0)
+    beta = _where(real, beta, 0.0)
     k = _where(real[..., None], k, 0.0)
     if kernel:
         flat = lambda x: x.reshape(x.shape[:2] + (-1,))
@@ -334,7 +452,8 @@ def _gated_delta_chunk(ctx, ins):
                                    start, clen, slot, sub)
         return {'Out': [o], 'StateOut': [new]}
     s0 = _slot_rows(state, slot, start).astype(jnp.float32)
-    o, s1 = delta_chunk(q, k, v, g, beta, s0, sub=sub)
+    o, s1 = (delta_chunk_channels if per_channel else delta_chunk)(
+        q, k, v, g, beta, s0, sub=sub)
     return {'Out': [o.reshape(o.shape[:2] + (-1,))],
             'StateOut': [_put_rows(state, slot, s1)]}
 

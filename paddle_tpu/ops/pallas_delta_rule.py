@@ -20,6 +20,13 @@ queries'), so that a head's key is a static lane of the block; v, the decay
 e^g and beta come as rows [S, H, dv] (the two per-head scalars broadcast
 over dv by the caller: 16 KiB a slot beside a 2 MiB state).
 
+A PER-CHANNEL decay (g [S, H, dk]: Kimi Delta Attention, models/
+kimi_linear.py) scales the state's ROWS, and d_k lies on sublanes: e^g comes
+as a third COLUMN block beside the keys and the queries, [S, dk, 3 H], and
+multiplies the state once as it is read — both products and the new state
+take the decayed copy — instead of the [1, dv] row that multiplied the
+sums. The same two crossings of the bus.
+
 An idle row (live == 0) copies its state through and computes nothing: the
 block is written back whatever the program does.
 
@@ -43,18 +50,19 @@ _VMEM_LIMIT_BYTES = 32 << 20
 _STATE_BLOCK_BYTES = 4 << 20
 
 
-def refuses(state):
+def refuses(state, per_channel=False):
     """None where the kernel takes a state of this shape and dtype, else
-    why not (the op then lowers the jnp body on every platform)."""
+    why not (the op then lowers the jnp body on every platform).
+    `per_channel`: the decay comes as a third column block."""
     _, n_head, dk, dv = state.shape
     if state.dtype != jnp.float32:
         return 'the state is %s, not float32' % state.dtype
     if dk % _SUBLANES or dv % _LANES:
         return ('a head\'s state [%d, %d] is no whole number of (%d, %d) '
                 'tiles' % (dk, dv, _SUBLANES, _LANES))
-    if 2 * n_head > _LANES:
-        return '%d heads\' keys and queries do not fit %d lanes' % (
-            n_head, _LANES)
+    if (2 + bool(per_channel)) * n_head > _LANES:
+        return '%d heads\' keys and queries%s do not fit %d lanes' % (
+            n_head, ' and decays' * bool(per_channel), _LANES)
     if n_head * dk * dv * 4 > _STATE_BLOCK_BYTES:
         return 'a slot\'s state is over %d bytes' % _STATE_BLOCK_BYTES
     return None
@@ -85,40 +93,74 @@ def _kernel(live_ref, kq_ref, v_ref, decay_ref, beta_ref, s_ref, o_ref,
             so_ref[0, h] = state * decay + k * delta
 
 
+def _channel_kernel(live_ref, kqd_ref, v_ref, beta_ref, s_ref, o_ref,
+                    so_ref, *, n_head):
+    """_kernel under a per-channel decay: the third column block of
+    `kqd_ref` [dk, 3 H] is e^g, and scales the state's rows as they are
+    read."""
+    slot = pl.program_id(0)
+
+    @pl.when(live_ref[slot] == 0)
+    def _():
+        so_ref[...] = s_ref[...]
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live_ref[slot] != 0)
+    def _():
+        kqd = kqd_ref[0]                                 # [dk, 3 H]
+        for h in range(n_head):
+            k = kqd[:, h:h + 1]                          # [dk, 1]
+            q = kqd[:, n_head + h:n_head + h + 1]
+            state = s_ref[0, h] * kqd[:, 2 * n_head + h:2 * n_head + h + 1]
+            m = jnp.sum(state * k, axis=0, keepdims=True)
+            sq = jnp.sum(state * q, axis=0, keepdims=True)
+            delta = beta_ref[0, h:h + 1, :] * (v_ref[0, h:h + 1, :] - m)
+            o_ref[0, h:h + 1, :] = sq + jnp.sum(k * q, axis=0,
+                                                keepdims=True) * delta
+            so_ref[0, h] = state + k * delta
+
+
 def delta_step(q, k, v, g, beta, state, live, *, interpret=False):
     """linear_attention_ops.delta_step with the idle rows' states left as
-    they are: q, k [S, H, dk], v [S, H, dv], g, beta [S, H], state [S, H,
-    dk, dv] float32, live [S] bool -> (o [S, H, dv], new state). `refuses`
-    must give None."""
+    they are: q, k [S, H, dk], v [S, H, dv], g, beta [S, H] (g [S, H, dk]:
+    a per-channel decay), state [S, H, dk, dv] float32, live [S] bool ->
+    (o [S, H, dv], new state). `refuses` must give None."""
     n_slot, n_head, dk, dv = state.shape
-    # heads on lanes: [S, dk, 2 H], the keys' heads then the queries'
-    kq = jnp.concatenate([k, q], axis=1).transpose(0, 2, 1)
+    per_channel = g.ndim == k.ndim
     rows = (n_slot, n_head, dv)
-    decay = jnp.broadcast_to(jnp.exp(g)[..., None], rows)
-    beta = jnp.broadcast_to(beta[..., None], rows)
+    as_rows = lambda x: jnp.broadcast_to(x[..., None], rows)
+    # heads on lanes, [S, dk, 2 H]: the keys' heads then the queries' (a
+    # per-channel decay: [S, dk, 3 H], e^g's behind them); the per-head
+    # scalars as rows: the decay (where it is one) and beta
+    if per_channel:
+        columns, scalars = [k, q, jnp.exp(g)], [as_rows(beta)]
+    else:
+        columns, scalars = [k, q], [as_rows(jnp.exp(g)), as_rows(beta)]
+    kq = jnp.concatenate(columns, axis=1).transpose(0, 2, 1)
     row_spec = pl.BlockSpec((1, n_head, dv), lambda s, live: (s, 0, 0))
     state_spec = pl.BlockSpec((1, n_head, dk, dv),
                               lambda s, live: (s, 0, 0, 0))
     o, new = pl.pallas_call(
-        functools.partial(_kernel, n_head=n_head),
+        functools.partial(_channel_kernel if per_channel else _kernel,
+                          n_head=n_head),
         out_shape=(jax.ShapeDtypeStruct(rows, jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n_slot,),
             in_specs=[
-                pl.BlockSpec((1, dk, 2 * n_head),
+                pl.BlockSpec((1, dk, len(columns) * n_head),
                              lambda s, live: (s, 0, 0)),
-                row_spec, row_spec, row_spec, state_spec],
+                row_spec] + [row_spec] * len(scalars) + [state_spec],
             out_specs=(row_spec, state_spec)),
-        # operands count the prefetched scalar: the state is the sixth
-        input_output_aliases={5: 1},
+        # operands count the prefetched scalar: the state is the last
+        input_output_aliases={3 + len(scalars): 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=('parallel',),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         name='gated_delta_step',
         interpret=interpret,
-    )(live.astype(jnp.int32), kq, v, decay, beta, state)
+    )(live.astype(jnp.int32), kq, v, *scalars, state)
     return o, new
 
 

@@ -226,9 +226,11 @@ def serving_report():
         if blocks:
             # prefill slices, the chunk-program calls that carried them
             # (fewer where slices rode the row program together) and the
-            # ticks a due slice waited for room in a tick's prefill budget
-            hdr += " %11s %6s %6s %6s %6s %6s" % (
-                'blocks', 'pfxhit', 'cow', 'slices', 'calls', 'waits')
+            # ticks a due slice waited for room in a tick's prefill budget;
+            # the slices that started past their prompt's first token
+            hdr += " %11s %6s %6s %6s %6s %6s %7s" % (
+                'blocks', 'pfxhit', 'cow', 'slices', 'calls', 'waits',
+                'carried')
             # bytes a cached position takes over all layers, and the
             # pools' bytes by kind (kv, latent, window, recurrent)
             hdr += " %7s %s" % ('row(B)', 'pools(MB)')
@@ -256,20 +258,21 @@ def serving_report():
                 for part in ('queue', 'prefill', 'read') for q in (50, 99))
             if blocks:
                 if 'blocks_in_use' in s:
-                    row += " %11s %6.2f %6d %6d %6d %6d" % (
+                    row += " %11s %6.2f %6d %6d %6d %6d %7d" % (
                         '%d/%d' % (s['blocks_in_use'],
                                    s.get('blocks_total', 0)),
                         s.get('prefix_hit_rate', 0.0),
                         s.get('cow_blocks', 0),
                         s.get('chunk_slices', 0),
                         s.get('chunk_dispatches', 0),
-                        s.get('slices_deferred', 0))
+                        s.get('slices_deferred', 0),
+                        s.get('slices_carried', 0))
                     row += " %7d %s" % (
                         s.get('cache_row_bytes', 0),
                         ' '.join('%s:%.0f' % (k, v / 1e6) for k, v in
                                  sorted(s.get('pool_bytes', {}).items())))
                 else:
-                    row += " %11s %6s %6s %6s %6s %6s" % (('-',) * 6)
+                    row += " %11s %6s %6s %6s %6s %6s %7s" % (('-',) * 7)
             print(row)
     return out
 

@@ -1223,7 +1223,7 @@ def test_tick_log_has_one_row_a_busy_tick(ticked):
                                      'cpu_wall_s', 'tick', 'slices',
                                      'deferred', 'emit_t', 'emit_rows',
                                      'wait_step_s', 'wait_slice_s',
-                                     'slice_tokens']
+                                     'slice_tokens', 'slices_carried']
     # a row carries its tick's number, the 'tick' stat of its span: an
     # idle tick has a span and no row, so the numbers may skip
     assert np.all(np.diff(log['tick']) >= 1)
